@@ -1,0 +1,832 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py                 # every phase, on a machine with a TPU
+    python3 chip_smoke.py --only kernels  # a subset, while debugging
+
+Drives the main paths once through the entry points a user calls, at the full
+width of the models the repo supports, with random weights made from a seed:
+
+- ``ppo`` / ``ppo-resume`` / ``impala``: ``python -m tpu_rl local`` — learner
+  on the chip, storage/manager/workers on the CPU — at the reference's
+  published width (batch 128 x seq 5 x hidden 64, CartPole-v1), unthrottled
+  workers; then the same command on the same ``--result-dir``, which must
+  resume from the committed checkpoint. The first phase run twice is also
+  the cold vs warm reading of the compile cache.
+- ``colocated``: ``python -m tpu_rl local --env-mode colocated``.
+- ``kernels``: every Pallas kernel compiled (not interpreted) against its
+  plain-jnp reference at a stated tolerance.
+- ``wide-lstm-f32`` / ``wide-lstm-bf16`` / ``longctx-flash``: the widest
+  models, through ``LearnerService`` fed by the real shm store.
+- ``multichip``: with >= 4 devices, the data-parallel, colocated, sebulba and
+  ring-attention paths over four chips; otherwise "skipped: N device(s)".
+
+One process owns the chip, so this parent never imports jax: every phase that
+needs the chip is a child process, run one after another. Each phase reports
+the platform, device kind and count it ran on, wall and compile seconds, and
+asserts on what the run recorded (``result_dir/backend-<role>.json``,
+telemetry, checkpoints) instead of trusting an exit code. With no TPU the
+script fails at once, prints no result, and exits nonzero.
+
+The last line of stdout is ``{"ok": true, "device": {...}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import math
+import os
+import shutil
+import signal
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+import traceback
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SEED = 21
+
+# The reference's published width (configs/params.example.json), unthrottled:
+# 2 workers x 64 envs fill a 128-window batch every 5 ticks.
+REF = dict(
+    env="CartPole-v1", hidden_size=64, seq_len=5, batch_size=128,
+    worker_step_sleep=0.0, worker_num_envs=64, loss_log_interval=2,
+    model_save_interval=4, telemetry_interval_s=0.5,
+)
+# The widest models the repo supports (the bench matrix's saturating rows).
+WIDE = dict(
+    algo="IMPALA", batch_size=1024, seq_len=16, hidden_size=1024,
+    obs_shape=(64,), action_space=8,
+)
+LONGCTX = dict(
+    algo="PPO", model="transformer", compute_dtype="bfloat16",
+    attention_impl="flash", batch_size=16, seq_len=2048, hidden_size=512,
+    n_heads=8, n_layers=4, obs_shape=(64,), action_space=8,
+)
+# name -> (LearnerService config, kernel path its train step must take on TPU)
+LEARNER_PHASES = {
+    # Multi-tile shape: the measured-win gate keeps the scan (models/cells.py).
+    "wide-lstm-f32": (WIDE, "lstm_scan"),
+    "wide-lstm-bf16": ({**WIDE, "compute_dtype": "bfloat16"}, "lstm_scan"),
+    "longctx-flash": (LONGCTX, "attn_flash_pallas"),
+}
+PHASES = (
+    "native", "ppo", "ppo-resume", "impala", "colocated", "kernels",
+    *LEARNER_PHASES, "multichip",
+)
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+def check(cond, msg: str) -> None:
+    if not cond:
+        raise PhaseFailed(msg)
+
+
+# ------------------------------------------------------------ child plumbing
+def run_child(argv: list[str], timeout: float, log_path: str) -> int:
+    """Run one child in its own process group, output to ``log_path``; the
+    whole group is killed on timeout so nothing this script starts outlives
+    it."""
+    env = dict(
+        os.environ,
+        PYTHONPATH=HERE + os.pathsep + os.environ.get("PYTHONPATH", ""),
+    )
+    with open(log_path, "w") as log:
+        proc = subprocess.Popen(
+            argv, stdout=log, stderr=subprocess.STDOUT, cwd=HERE, env=env,
+            start_new_session=True,
+        )
+        try:
+            return proc.wait(timeout=timeout)
+        except subprocess.TimeoutExpired:
+            return -1
+        finally:
+            try:
+                os.killpg(proc.pid, signal.SIGKILL)
+            except ProcessLookupError:
+                pass
+            proc.wait()
+
+
+def tail(path: str, n: int = 40) -> str:
+    try:
+        with open(path, errors="replace") as f:
+            return "".join(f.readlines()[-n:])
+    except OSError:
+        return ""
+
+
+def read_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def device_of(rec: dict) -> dict:
+    return {
+        "platform": rec["platform"], "kind": rec["device_kind"],
+        "count": rec["device_count"],
+    }
+
+
+def all_finite(obj) -> bool:
+    if isinstance(obj, dict):
+        return all(all_finite(v) for v in obj.values())
+    if isinstance(obj, (list, tuple)):
+        return all(all_finite(v) for v in obj)
+    return not isinstance(obj, float) or math.isfinite(obj)
+
+
+def check_backend(
+    rec: dict, expect_path: str | None, require_tpu: bool
+) -> dict:
+    """A role's ``backend-<role>.json``: it ran on the TPU, its main program
+    took the expected kernel path and — where that path is a kernel — holds
+    Mosaic custom calls (interpreted kernels, in the CPU tests, lower to
+    plain ops). Returns the fields every phase reports."""
+    who = rec["role"]
+    if require_tpu:
+        check(rec["platform"] == "tpu", f"{who} ran on {rec['platform']}")
+    if expect_path is not None:
+        want_mosaic = rec["platform"] == "tpu" and expect_path.endswith("_pallas")
+        check(
+            expect_path in rec.get("paths", ())
+            and (rec.get("mosaic_calls", 0) > 0 or not want_mosaic),
+            f"{who} program took {rec.get('paths')} with "
+            f"{rec.get('mosaic_calls')} Mosaic calls; expected {expect_path}",
+        )
+    return {
+        **device_of(rec),
+        **{k: rec.get(k) for k in (
+            "compile_s", "cache_hits", "cache_misses", "paths", "mosaic_calls",
+        )},
+    }
+
+
+def gauge_values(telemetry: dict, name: str) -> list[float]:
+    """Every value of one gauge/counter across telemetry.json's sources."""
+    out = []
+    for src in telemetry.get("sources", []):
+        for kind in ("gauges", "counters"):
+            out += [v for n, _labels, v in src.get(kind, []) if n == name]
+    return out
+
+
+# ------------------------------------------------------------------- phases
+def phase_native() -> dict:
+    """Build native/codec.cpp from the tracked source (native/build/ is
+    ignored by git), or say that the Python codec is in use."""
+    from tpu_rl.runtime import native
+
+    if native.available():
+        return {"codec": "native", "lib": native.LIB._name}
+    print("[native] g++ build unavailable: the Python codec is in use")
+    return {"codec": "python"}
+
+
+def phase_cli(
+    algo: str,
+    result_dir: str,
+    updates: int,
+    params: dict,
+    cli: tuple[str, ...] = (),
+    role: str = "learner",
+    expect_path: str | None = "lstm_pallas",
+    expect_mesh: dict | None = None,
+    resume: bool = False,
+    require_tpu: bool = True,
+    timeout: float = 420.0,
+) -> dict:
+    """One ``python -m tpu_rl local`` run, checked from what it left in
+    ``result_dir``. Runs in this (jax-free) process: the CLI spawns the
+    chip-owning child itself."""
+    os.makedirs(result_dir, exist_ok=True)
+    p_path = os.path.join(result_dir, f"params-{algo}.json")
+    with open(p_path, "w") as f:
+        json.dump({**params, "algo": algo}, f)
+    log = os.path.join(result_dir, f"cli-{time.time_ns()}.log")
+    argv = [
+        sys.executable, "-m", "tpu_rl", "local", "--params", p_path,
+        "--result-dir", result_dir, "--max-updates", str(updates),
+        "--seed", str(SEED), *cli,
+    ]
+    t0 = time.time()
+    rc = run_child(argv, timeout, log)
+    wall = time.time() - t0
+    check(rc == 0, f"{' '.join(argv)} exited {rc}\n{tail(log)}")
+    with open(log, errors="replace") as f:
+        out = f.read()
+
+    rec = read_json(os.path.join(result_dir, f"backend-{role}.json"))
+    res = {"wall_s": round(wall, 1), **check_backend(rec, expect_path, require_tpu)}
+    if expect_mesh is not None:
+        check(rec["mesh"] == expect_mesh, f"mesh {rec['mesh']}")
+    telem = read_json(os.path.join(result_dir, "telemetry.json"))
+    want = updates * (2 if resume else 1)
+    if role == "learner":
+        done = max(gauge_values(telem, "learner-update-index"), default=0)
+        check(done >= want, f"learner reached update {done} < {want}")
+        check(
+            max(gauge_values(telem, "worker-policy-version"), default=-1) > 0,
+            "no worker acted on a broadcast policy (worker-policy-version)",
+        )
+        check(
+            sum(gauge_values(telem, "learner-nonfinite-updates")) == 0,
+            "non-finite learner updates",
+        )
+        check("[learner] update" in out, "no loss line from the learner")
+        res["updates"] = int(done)
+    else:
+        done = max(gauge_values(telem, "colocated-updates"), default=0)
+        check(done >= want, f"{role} reached update {done} < {want}")
+        check(f"[{role}] done:" in out, f"no done line\n{tail(log)}")
+        res["updates"] = int(done)
+    with open(os.path.join(result_dir, "learn.jsonl")) as f:
+        diag = [json.loads(line) for line in f if line.strip()]
+    check(diag and all_finite(diag), "learn.jsonl empty or non-finite")
+    marks = glob.glob(os.path.join(result_dir, "models", f"{algo}_*", "COMMITTED"))
+    check(marks, "no committed checkpoint")
+    res["checkpoints"] = len(marks)
+    if resume:
+        with open(os.path.join(result_dir, "learner_resume.jsonl")) as f:
+            resumed = [json.loads(line) for line in f if line.strip()]
+        check(
+            resumed and resumed[-1]["idx"] >= updates,
+            f"no resume from a committed checkpoint: {resumed}",
+        )
+        res["resumed_from"] = resumed[-1]["idx"]
+    return res
+
+
+def phase_learner(
+    cfg_kw: dict,
+    updates: int = 5,
+    expect_path: str | None = None,
+    require_tpu: bool = True,
+    pallas_mode: str | None = None,
+) -> dict:
+    """The production ``LearnerService`` in THIS process, fed through the
+    real shm store by a feeder thread (no children): consume -> assemble ->
+    H2D -> train step, ``updates`` times (the first carries the compile)."""
+    import numpy as np
+
+    from tpu_rl.config import Config
+    from tpu_rl.data.layout import BatchLayout
+    from tpu_rl.data.shm_ring import OnPolicyStore, alloc_handles
+    from tpu_rl.runtime.learner_service import LearnerService
+    from tpu_rl.types import BATCH_FIELDS
+
+    if pallas_mode is not None:
+        from tpu_rl.models import cells
+
+        cells.set_pallas_mode(pallas_mode)
+    result_dir = tempfile.mkdtemp(prefix="chip_smoke_learner_")
+    cfg = Config.from_dict(
+        dict(cfg_kw, loss_log_interval=1, result_dir=result_dir)
+    )
+    layout = BatchLayout.from_config(cfg)
+    handles = alloc_handles(layout, capacity=cfg.batch_size)
+    rng = np.random.default_rng(SEED)
+    n_act = cfg.action_space
+    pool = []
+    for _ in range(8):
+        w = {}
+        for f in BATCH_FIELDS:
+            shape = (layout.seq_len, layout.width(f))
+            if f == "act":
+                w[f] = rng.integers(0, n_act, size=shape).astype(np.float32)
+            elif f == "is_fir":
+                w[f] = np.zeros(shape, np.float32)
+                w[f][0] = 1.0
+            elif f == "log_prob":
+                w[f] = np.full(shape, -math.log(n_act), np.float32)
+            else:
+                w[f] = rng.standard_normal(shape).astype(np.float32) * 0.1
+        pool.append(w)
+    stop = threading.Event()
+
+    def feed() -> None:
+        store = OnPolicyStore(handles, layout)
+        i = 0
+        while not stop.is_set():
+            if store.put(pool[i % len(pool)]):
+                i += 1
+            else:
+                time.sleep(0.001)  # store full: the learner is consuming
+
+    feeder = threading.Thread(target=feed, daemon=True)
+    feeder.start()
+    svc = LearnerService(
+        cfg, handles, model_port=29970, stop_event=stop, max_updates=updates,
+        publish_interval=10**9, seed=SEED,
+    )
+    t0 = time.time()
+    try:
+        svc.run()
+    finally:
+        stop.set()
+        feeder.join(timeout=10)
+    wall = time.time() - t0
+    rec = read_json(os.path.join(result_dir, "backend-learner.json"))
+    shutil.rmtree(result_dir, ignore_errors=True)
+    res = {"wall_s": round(wall, 1), **check_backend(rec, expect_path, require_tpu)}
+    check(
+        svc.last_losses and all_finite(svc.last_losses),
+        f"losses {svc.last_losses}",
+    )
+    check(svc.n_nonfinite_updates == 0, "non-finite updates")
+    steps = svc.timer.elapsed.get("learner-step-time", ())
+    check(len(steps) == updates, f"{len(steps)} dispatches, wanted {updates}")
+    return {**res, "updates": updates, "loss": svc.last_losses.get("loss")}
+
+
+def _rel_err(got, want) -> float:
+    """Max abs error over the reference's max abs value, over a pytree."""
+    import jax
+    import numpy as np
+
+    worst = 0.0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if not np.isfinite(g).all():
+            return float("inf")
+        worst = max(worst, float(np.abs(g - w).max() / (np.abs(w).max() + 1e-9)))
+    return worst
+
+
+# Tolerances (max abs error / max abs reference). The f32 kernels run their
+# matmuls at the TPU's default precision — one bf16 pass with f32
+# accumulation, exactly as the XLA scan / act paths they replace do — so each
+# case is held to two references:
+# - ``err``: the jnp reference at precision="highest". Each operand carries
+#   a 2^-8 rounding into contractions up to 1024 deep and 16 recurrent steps
+#   (measured on a TPU v5e: 2.4e-3..9.6e-3). A wrong gate order, mask or
+#   carry is an O(1) error.
+# - ``err_vs_default``: the same reference at the default precision, i.e.
+#   what XLA computes on this chip with the same operand rounding. Measured:
+#   fused act <= 2e-7 (one step, same arithmetic), LSTM 6e-4..1.0e-3 (the
+#   recurrence amplifies accumulation-order and transcendental differences).
+#   Computing anything in a lower precision than the XLA path would fail it.
+# bf16 flash attention is compared with an f32 reference on the same bf16
+# inputs; the kernel rounds the probabilities and the output to bf16
+# (measured 3.4e-3..5.3e-3).
+TOL_F32 = 2e-2
+TOL_LSTM_SAME = 5e-3
+TOL_ACT_SAME = 1e-5
+TOL_BF16 = 3e-2
+
+
+def kernel_checks(
+    lstm_shapes=((128, 5, 64, "auto"), (256, 16, 256, "auto"), (1024, 16, 1024, "force")),
+    act_shapes=((8, 4, 64, 2), (256, 4, 64, 2), (8, 64, 1024, 8), (256, 64, 1024, 8)),
+    flash_shapes=((16, 2048, 8, 64, True), (1, 512, 8, 64, False)),
+    interpret: bool = False,
+) -> list[dict]:
+    """Each kernel against its plain-jnp reference; one result row per case,
+    failures recorded (not raised) so one chip call reports every kernel."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_rl.models import cells
+    from tpu_rl.ops import pallas_lstm as pk
+    from tpu_rl.utils.platform import program_paths
+
+    rng = np.random.default_rng(SEED)
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s).astype(np.float32))  # noqa: E731
+    rows = []
+
+    def case(name, fn, ref, args, tol, tol_same, mosaic=True):
+        row = {"kernel": name, "tol": tol, "tol_vs_default": tol_same}
+        t0 = time.time()
+        try:
+            jfn = jax.jit(fn)
+            n_mosaic = program_paths(jfn.lower(*args))["mosaic_calls"]
+            got = jax.block_until_ready(jfn(*args))
+            with jax.default_matmul_precision("highest"):
+                want = jax.block_until_ready(jax.jit(ref)(*args))
+            row.update(
+                err=_rel_err(got, want),
+                err_vs_default=_rel_err(got, jax.jit(ref)(*args)),
+                mosaic_calls=n_mosaic,
+            )
+            row["ok"] = (
+                row["err"] <= tol
+                and row["err_vs_default"] <= tol_same
+                and (n_mosaic > 0 or interpret or not mosaic)
+            )
+        except Exception as e:  # noqa: BLE001 — report every kernel
+            row.update(ok=False, error=f"{type(e).__name__}: {str(e)[:1500]}")
+        row["wall_s"] = round(time.time() - t0, 1)
+        rows.append(row)
+        print(f"[kernels] {json.dumps(row)}", flush=True)
+
+    # ---- LSTM forward + fused backward vs the scan
+    for B, S, H, mode in lstm_shapes:
+        xp, wh = f32(B, S, 4 * H) * 0.5, f32(H, 4 * H) / np.sqrt(H)
+        h0, c0 = f32(B, H) * 0.5, f32(B, H) * 0.5
+        keep = jnp.asarray((rng.random((B, S)) > 0.1).astype(np.float32))
+        w_h, w_c = f32(B, S, H), f32(B, S, H)
+
+        def loss(unroll, xp, wh, h0, c0):
+            hs, cs = unroll(xp, wh, h0, c0)
+            return (hs * w_h).sum() + (cs * w_c).sum()
+
+        def kern(xp, wh, h0, c0):
+            return pk.lstm_unroll(xp, wh, h0, c0, keep, interpret)
+
+        def scan(xp, wh, h0, c0):
+            return pk._scan_forward(xp, wh, h0, c0, keep, want_cs=True)
+
+        every = (0, 1, 2, 3)
+        cells.set_pallas_mode(mode)  # "force": multi-tile fused backward too
+        try:
+            case(
+                f"lstm fwd+bwd B{B}/S{S}/H{H} ({mode}, tiles "
+                f"{pk.batch_tile(B, S, H)}/{pk.bwd_batch_tile(B, S, H)})",
+                jax.value_and_grad(lambda *a: loss(kern, *a), argnums=every),
+                jax.value_and_grad(lambda *a: loss(scan, *a), argnums=every),
+                (xp, wh, h0, c0), TOL_F32, TOL_LSTM_SAME,
+            )
+        finally:
+            cells.set_pallas_mode("auto")
+
+    # ---- fused act step vs the flax actor
+    from tpu_rl.config import Config
+    from tpu_rl.models.families import build_family
+    from tpu_rl.ops.pallas_act import fused_act_step
+
+    for rows_n, D, H, A in act_shapes:
+        cfg = Config.from_dict(dict(
+            algo="PPO", hidden_size=H, obs_shape=(D,), action_space=A,
+        ))
+        family = build_family(cfg)
+        params = family.init_params(jax.random.key(SEED), seq_len=cfg.seq_len)
+
+        def ref(actor_params, obs, h, c, _actor=family.actor):
+            logits, _v, (h2, c2) = _actor.apply(
+                actor_params, obs, (h, c), method="act"
+            )
+            return logits, h2, c2
+
+        case(
+            f"fused act rows{rows_n}/obs{D}/H{H}/A{A}",
+            lambda p, o, h, c: fused_act_step(p, o, h, c, interpret), ref,
+            (params["actor"], f32(rows_n, D), f32(rows_n, H) * 0.5, f32(rows_n, H) * 0.5),
+            TOL_F32, TOL_ACT_SAME,
+        )
+
+    # ---- library flash attention with the dispatch's tiles vs full attention
+    from tpu_rl.parallel.sequence import (
+        _select_block_size,
+        flash_attention_tpu,
+        full_attention,
+    )
+
+    for B, T, NH, D, grad in flash_shapes:
+        q, k, v = (f32(B, T, NH, D).astype(jnp.bfloat16) for _ in range(3))
+        firsts = np.zeros((B, T), np.int32)
+        firsts[:, 0] = 1
+        firsts[:, T // 3] = 1  # an episode seam inside the window
+        seg = jnp.asarray(np.cumsum(firsts, axis=1))
+        pos = jnp.asarray(np.tile(np.arange(T), (B, 1)))
+        w_o = f32(B, T, NH, D)
+        n_ref = min(B, 2)  # the f32 reference holds (n, H, T, T) scores
+
+        def flash(q, k, v, n=B):
+            return flash_attention_tpu(
+                q[:n], k[:n], v[:n], pos[:n], seg[:n], causal=True
+            )
+
+        def full(q, k, v, n=B):  # f32 reference on the same bf16 inputs
+            q, k, v = (x[:n].astype(jnp.float32) for x in (q, k, v))
+            return full_attention(q, k, v, pos[:n], seg[:n], causal=True)
+
+        def grads(impl, n):
+            def loss(q, k, v):
+                return (impl(q, k, v, n).astype(jnp.float32) * w_o[:n]).sum()
+
+            # rows are independent: compare the first n_ref rows' gradients
+            return lambda q, k, v: tuple(
+                g[:n_ref] for g in jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+            )
+
+        got_fn, ref_fn = (grads(flash, B), grads(full, n_ref)) if grad else (flash, full)
+        case(
+            f"flash {'fwd+bwd' if grad else 'fwd'} B{B}/T{T}/H{NH}/D{D} bf16 "
+            f"(tiles {_select_block_size(T, D)})",
+            got_fn, ref_fn, (q, k, v), TOL_BF16, TOL_BF16,
+            # off-TPU the dispatch substitutes full attention by design
+            mosaic=jax.default_backend() == "tpu",
+        )
+    return rows
+
+
+def phase_kernels(require_tpu: bool = True) -> dict:
+    import jax
+
+    from tpu_rl.utils.platform import CompileClock, backend_info, enable_compile_cache
+
+    enable_compile_cache()
+    clock = CompileClock()
+    info = backend_info()
+    if require_tpu:
+        check(info["platform"] == "tpu", f"kernels ran on {info['platform']}")
+        from jax.experimental.pallas import tpu as pltpu
+
+        tpu = pltpu.get_tpu_info()
+        print(
+            f"[kernels] {info['device_kind']}: VMEM "
+            f"{tpu.vmem_capacity_bytes / 2**20:.0f} MiB per core "
+            "(pltpu.get_tpu_info)", flush=True,
+        )
+    t0 = time.time()
+    rows = kernel_checks(interpret=jax.default_backend() != "tpu")
+    bad = [r for r in rows if not r["ok"]]
+    check(not bad, f"{len(bad)} kernel case(s) failed: {[r['kernel'] for r in bad]}")
+    return {
+        **device_of(info), "wall_s": round(time.time() - t0, 1),
+        **clock.stats(), "cases": len(rows),
+        "max_err": max(r["err"] for r in rows),
+    }
+
+
+# name -> (config, kernel path expected on TPU, (data, seq) mesh or None=DP)
+MULTICHIP_CASES = {
+    "dp-lstm-island": (
+        dict(algo="PPO", hidden_size=64, seq_len=5, batch_size=128,
+             obs_shape=(4,), action_space=2),
+        "lstm_pallas", None,
+    ),
+    "dp-flash-island": (LONGCTX, "attn_flash_pallas", None),
+    "ring-2x2": (
+        {**LONGCTX, "attention_impl": "ring", "mesh_data": 2, "mesh_seq": 2},
+        None, (2, 2),
+    ),
+}
+
+
+def multichip_steps(
+    n: int = 4, cases: dict | None = None, require_tpu: bool = True
+) -> dict:
+    """In one process over ``n`` chips: the tiny-shape DP / ring steps of
+    ``__graft_entry__`` on the real devices, then the shard_map kernel
+    islands (LSTM, flash) and ring attention at real widths, with the batch
+    and the parameters checked to live on every device, not device 0."""
+    import jax
+
+    import __graft_entry__ as graft
+    from tpu_rl.utils.platform import CompileClock, backend_info, enable_compile_cache
+
+    enable_compile_cache()
+    clock = CompileClock()
+    info = backend_info()
+    check(info["device_count"] >= n, str(info))
+    if require_tpu:
+        check(info["platform"] == "tpu", str(info))
+    t0 = time.time()
+    graft.multichip_steps(n)
+
+    import numpy as np
+
+    from tpu_rl.algos.registry import get_algo
+    from tpu_rl.config import Config
+    from tpu_rl.data.layout import BatchLayout
+    from tpu_rl.parallel import (
+        make_mesh, make_parallel_train_step, make_sp_mesh, make_sp_train_step,
+        replicate, shard_batch,
+    )
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_rl.types import Batch
+    from tpu_rl.utils.platform import program_paths
+
+    def batch_for(cfg, family):
+        lay = BatchLayout.from_config(cfg)
+        rng = np.random.default_rng(SEED)
+        zb = Batch.zeros(
+            cfg.batch_size, cfg.seq_len, cfg.obs_shape, cfg.action_space,
+            cfg.hidden_size, continuous=family.continuous,
+            hx_width=lay.hx, cx_width=lay.cx,
+        )
+        firsts = np.zeros(zb.is_fir.shape, np.float32)
+        firsts[:, 0] = 1.0
+        return zb.replace(
+            obs=rng.standard_normal(zb.obs.shape).astype(np.float32),
+            act=rng.integers(0, cfg.action_space, size=zb.act.shape).astype(np.float32),
+            rew=rng.standard_normal(zb.rew.shape).astype(np.float32) * 0.1,
+            log_prob=np.full(zb.log_prob.shape, -math.log(cfg.action_space), np.float32),
+            is_fir=firsts,
+        )
+
+    out = {}
+    for name, (kw, path, sp) in (cases or MULTICHIP_CASES).items():
+        cfg = Config.from_dict(kw)
+        if sp:
+            mesh = make_sp_mesh(*sp)
+            family, state, step = get_algo(cfg.algo).build(
+                cfg, jax.random.key(SEED), mesh=mesh
+            )
+            pstep = make_sp_train_step(step, mesh, cfg)
+            batch = jax.device_put(
+                batch_for(cfg, family), NamedSharding(mesh, P("data", "seq"))
+            )
+        else:
+            mesh = make_mesh(n)
+            family, state, step = get_algo(cfg.algo).build(cfg, jax.random.key(SEED))
+            pstep = make_parallel_train_step(step, mesh, cfg)
+            batch = shard_batch(batch_for(cfg, family), mesh)
+        state = replicate(state, mesh)
+        key = replicate(jax.random.key(1), mesh)
+        on = lambda leaf: {s.device for s in leaf.addressable_shards}  # noqa: E731
+        check(len(on(batch.obs)) == n, f"{name}: batch on {on(batch.obs)}")
+        check(
+            all(len(on(leaf)) == n for leaf in jax.tree.leaves(state)),
+            f"{name}: train state not on every device",
+        )
+        paths = program_paths(pstep.lower(state, batch, key))
+        if path is not None and info["platform"] == "tpu":
+            check(
+                path in paths["paths"] and paths["mosaic_calls"] > 0,
+                f"{name}: took {paths}; expected {path}",
+            )
+        for _ in range(3):
+            state, metrics = pstep(state, batch, key)
+        loss = float(jax.device_get(metrics["loss"]))
+        check(math.isfinite(loss), f"{name}: loss {loss}")
+        if info["platform"] == "tpu":  # the CPU backend reports no stats
+            mem = [d.memory_stats()["bytes_in_use"] for d in jax.devices()[:n]]
+            check(all(m > 0 for m in mem), f"{name}: bytes_in_use {mem}")
+        out[name] = {"loss": loss, **paths}
+        print(f"[multichip] {name} {json.dumps(out[name])}", flush=True)
+    return {
+        **device_of(info), "wall_s": round(time.time() - t0, 1),
+        **clock.stats(), "steps": out,
+    }
+
+
+def phase_multichip(work: str, n_devices: int) -> dict:
+    """Four chips through the CLI (each run spawns its own chip owner), then
+    the in-process steps in one child."""
+    if n_devices < 4:
+        print(f"[multichip] skipped: {n_devices} device(s)")
+        return {"skipped": f"{n_devices} device(s)"}
+    res = {}
+    res["local-mesh4"] = phase_cli(
+        "PPO", os.path.join(work, "mc-local"), 8, REF, cli=("--mesh-data", "4"),
+        expect_mesh={"data": 4},
+    )
+    colo = {**REF, "loss_log_interval": 50, "model_save_interval": 100}
+    res["colocated-mesh4"] = phase_cli(
+        "PPO", os.path.join(work, "mc-colo"), 200, colo, role="colocated",
+        cli=("--env-mode", "colocated", "--mesh-data", "4"),
+        expect_mesh={"data": 4},
+    )
+    res["sebulba-2+2"] = phase_cli(
+        "PPO", os.path.join(work, "mc-seb"), 200, colo, role="sebulba",
+        cli=("--env-mode", "colocated", "--sebulba-split", "2"),
+        expect_mesh={"data": 2},
+    )
+    res["steps"] = run_phase_child("multichip-steps", work, timeout=600)
+    return res
+
+
+# ------------------------------------------------------------------- driver
+def child_main(phase: str, out_path: str) -> None:
+    """Body of one chip-owning child: run the phase, write its result."""
+    if phase == "probe":
+        from tpu_rl.utils.platform import backend_info
+
+        res = backend_info()
+    elif phase == "kernels":
+        res = phase_kernels()
+    elif phase == "multichip-steps":
+        res = multichip_steps()
+    else:
+        cfg_kw, expect = LEARNER_PHASES[phase]
+        res = phase_learner(cfg_kw, expect_path=expect)
+    with open(out_path, "w") as f:
+        json.dump(res, f)
+
+
+# Child log lines worth showing in the parent's (size-limited) output.
+ECHO = ("[kernels]", "[multichip]", "[learner]", "multichip_steps:")
+
+
+def run_phase_child(phase: str, work: str, timeout: float) -> dict:
+    out_path = os.path.join(work, f"{phase}.json")
+    log = os.path.join(work, f"{phase}.log")
+    rc = run_child(
+        [sys.executable, os.path.abspath(__file__), "--child", phase,
+         "--out", out_path],
+        timeout, log,
+    )
+    with open(log, errors="replace") as f:
+        for line in f:
+            if line.startswith(ECHO):
+                print(line, end="")
+    check(rc == 0, f"phase child {phase} exited {rc}\n{tail(log)}")
+    return read_json(out_path)
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--only", help="comma-separated phases of: " + ",".join(PHASES))
+    ap.add_argument("--logs-dir", help="keep every phase's logs and records here")
+    ap.add_argument("--child", help=argparse.SUPPRESS)
+    ap.add_argument("--out", help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+    if args.child:
+        child_main(args.child, args.out)
+        return 0
+
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        print("chip_smoke: JAX_PLATFORMS=cpu — this script needs a TPU", file=sys.stderr)
+        return 2
+    import tpu_rl  # noqa: F401 — fails here when run outside the repo
+
+    only = args.only.split(",") if args.only else list(PHASES)
+    unknown = set(only) - set(PHASES)
+    if unknown:
+        ap.error(f"unknown phase(s) {sorted(unknown)}")
+    work = tempfile.mkdtemp(prefix="chip_smoke_")
+    try:
+        try:
+            probe = run_phase_child("probe", work, timeout=180)
+        except PhaseFailed as e:
+            print(f"chip_smoke: device probe failed: {e}", file=sys.stderr)
+            return 2
+        if probe["platform"] != "tpu":
+            print(
+                f"chip_smoke: JAX found no accelerator (platform "
+                f"{probe['platform']!r}) — this script needs a TPU",
+                file=sys.stderr,
+            )
+            return 2
+        device = device_of(probe)
+        print(f"chip_smoke: device {json.dumps(device)}", flush=True)
+
+        colo = {**REF, "loss_log_interval": 50, "model_save_interval": 100}
+        ppo_dir = os.path.join(work, "ppo")
+        runners = {
+            "native": phase_native,
+            "ppo": lambda: phase_cli("PPO", ppo_dir, 8, REF),
+            "ppo-resume": lambda: phase_cli("PPO", ppo_dir, 8, REF, resume=True),
+            "impala": lambda: phase_cli("IMPALA", os.path.join(work, "impala"), 8, REF),
+            "colocated": lambda: phase_cli(
+                "PPO", os.path.join(work, "colo"), 300, colo, role="colocated",
+                cli=("--env-mode", "colocated"),
+            ),
+            "kernels": lambda: run_phase_child("kernels", work, timeout=600),
+            **{
+                name: (lambda n=name: run_phase_child(n, work, timeout=600))
+                for name in LEARNER_PHASES
+            },
+            "multichip": lambda: phase_multichip(work, device["count"]),
+        }
+        results, failed = {}, []
+        for name in PHASES:
+            if name not in only:
+                continue
+            t0 = time.time()
+            try:
+                results[name] = runners[name]()
+                verdict = "skipped" if "skipped" in results[name] else "ok"
+                print(
+                    f"chip_smoke: phase {name} {verdict} "
+                    f"{json.dumps(results[name])}", flush=True,
+                )
+            except Exception as e:  # noqa: BLE001 — report it, run the rest
+                failed.append(name)
+                why = str(e) if isinstance(e, PhaseFailed) else traceback.format_exc()
+                results[name] = {"failed": why[-3000:]}
+                print(
+                    f"chip_smoke: phase {name} FAILED after "
+                    f"{time.time() - t0:.0f}s:\n{why}", flush=True,
+                )
+        if "ppo" in results and "ppo-resume" in results and not failed:
+            print(
+                f"chip_smoke: compile cache cold {results['ppo']['compile_s']}s "
+                f"({results['ppo']['cache_misses']} misses) -> warm "
+                f"{results['ppo-resume']['compile_s']}s "
+                f"({results['ppo-resume']['cache_hits']} hits)", flush=True,
+            )
+        print("chip_smoke: summary " + json.dumps({"phases": results, "failed": failed}))
+        print(json.dumps({"ok": not failed, "device": device}))
+        return 1 if failed else 0
+    finally:
+        if args.logs_dir:
+            shutil.copytree(
+                work, args.logs_dir, dirs_exist_ok=True,
+                ignore=shutil.ignore_patterns("models", "history", "telemetry"),
+            )
+        shutil.rmtree(work, ignore_errors=True)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

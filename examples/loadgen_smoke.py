@@ -59,6 +59,9 @@ def main() -> int:
     p.add_argument("--result-dir", default=None)
     args = p.parse_args()
 
+    # This parent and its two replica processes all run jax, and one process
+    # owns a chip: the smoke is a CPU one unless the caller says otherwise.
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
     from tpu_rl.config import Config

@@ -44,10 +44,10 @@ PORT = 29980
 # --------------------------------------------------------------- child bodies
 def pod_child(pid: int, nprocs: int, workdir: str, updates: int) -> None:
     """One virtual pod host running the fused pod-Anakin loop."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     from tpu_rl.config import Config
     from tpu_rl.runtime.colocated import ColocatedLoop
 
@@ -79,10 +79,10 @@ def pod_child(pid: int, nprocs: int, workdir: str, updates: int) -> None:
 
 def sebulba_child(workdir: str, updates: int) -> None:
     """Single-process sebulba split: 2 actor + 2 learner devices."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     from tpu_rl.config import Config
     from tpu_rl.runtime.sebulba import SebulbaLoop
 

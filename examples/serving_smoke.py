@@ -43,6 +43,9 @@ def main() -> int:
                    help="timed acts per client thread")
     args = p.parse_args()
 
+    # This parent and its two replica processes all run jax, and one process
+    # owns a chip: the smoke is a CPU one unless the caller says otherwise.
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -41,8 +41,6 @@ os.environ["XLA_FLAGS"] = (
 )
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 import numpy as np  # noqa: E402
 
 from tpu_rl.config import Config  # noqa: E402

@@ -1,0 +1,120 @@
+"""chip_smoke.py's contract off the chip: without a TPU it exits nonzero at
+once, names the reason and prints no result; its phase bodies take their
+sizes as arguments and pass tiny on the CPU with the kernels interpreted."""
+
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+import chip_smoke
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SCRIPT = os.path.join(REPO, "chip_smoke.py")
+
+
+def _run(cwd, script, **env):
+    base = {k: v for k, v in os.environ.items()
+            if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    return subprocess.run(
+        [sys.executable, script], capture_output=True, text=True, timeout=300,
+        cwd=cwd, env={**base, **env},
+    )
+
+
+def test_explicit_cpu_fails_at_once_naming_the_reason():
+    r = _run(REPO, SCRIPT, JAX_PLATFORMS="cpu")
+    assert r.returncode != 0
+    assert "JAX_PLATFORMS=cpu" in r.stderr and "needs a TPU" in r.stderr
+    assert r.stdout == ""  # no result line
+
+
+def test_no_accelerator_found_fails_without_a_result():
+    """JAX_PLATFORMS unset and no chip: JAX's own fallback would hand the
+    probe the CPU — the script refuses it instead of degrading."""
+    r = _run(REPO, SCRIPT)
+    assert r.returncode != 0
+    assert "found no accelerator" in r.stderr
+    assert r.stdout == ""
+
+
+def test_alone_in_a_directory_fails_without_a_result(tmp_path):
+    shutil.copy(SCRIPT, tmp_path / "chip_smoke.py")
+    r = _run(str(tmp_path), str(tmp_path / "chip_smoke.py"))
+    assert r.returncode != 0
+    assert "tpu_rl" in r.stderr
+    assert r.stdout == ""
+
+
+def test_kernel_checks_pass_tiny_interpreted():
+    rows = chip_smoke.kernel_checks(
+        lstm_shapes=((8, 3, 16, "auto"), (16, 3, 16, "force")),
+        act_shapes=((8, 4, 16, 2),),
+        flash_shapes=((2, 128, 2, 16, True), (1, 128, 2, 16, False)),
+        interpret=True,
+    )
+    assert len(rows) == 5
+    assert all(r["ok"] for r in rows), rows
+
+
+def test_learner_phase_passes_tiny_interpreted():
+    """The LearnerService-through-shm phase body, kernels interpreted, no
+    device assertion; it still fails when the train step took another path
+    than the one expected."""
+    cfg = dict(algo="IMPALA", batch_size=8, seq_len=4, hidden_size=16,
+               obs_shape=(4,), action_space=2)
+    try:
+        res = chip_smoke.phase_learner(
+            cfg, updates=3, expect_path="lstm_pallas", require_tpu=False,
+            pallas_mode="interpret",
+        )
+        assert res["platform"] == "cpu" and res["updates"] == 3
+        assert res["paths"] == ["lstm_pallas"]
+        assert res["compile_s"] > 0 and res["loss"] == res["loss"]
+        with pytest.raises(chip_smoke.PhaseFailed, match="expected lstm_scan"):
+            chip_smoke.phase_learner(
+                cfg, updates=1, expect_path="lstm_scan", require_tpu=False,
+                pallas_mode="interpret",
+            )
+    finally:
+        from tpu_rl.models import cells
+
+        cells.set_pallas_mode("auto")
+
+
+@pytest.mark.slow
+def test_cli_phase_fresh_then_resume_on_the_cpu(tmp_path):
+    """The distributed CLI phase body end to end (worker -> manager ->
+    storage -> learner, committed checkpoint, second invocation resumes)."""
+    small = {**chip_smoke.REF, "hidden_size": 16, "batch_size": 16,
+             "worker_num_envs": 8, "learner_device": "cpu"}
+    work = str(tmp_path / "ppo")
+    cpu = dict(require_tpu=False, expect_path="lstm_scan")
+    res = chip_smoke.phase_cli("PPO", work, 8, small, **cpu)
+    assert res["updates"] >= 8 and res["checkpoints"] >= 1
+    res = chip_smoke.phase_cli("PPO", work, 8, small, resume=True, **cpu)
+    assert res["updates"] >= 16 and res["resumed_from"] >= 8
+
+
+@pytest.mark.slow
+def test_multichip_steps_pass_tiny_on_virtual_devices():
+    """The four-chip phase body on four virtual CPU devices: DP kernel
+    islands and 2x2 ring attention at tiny widths, batch and train state
+    checked to live on every device."""
+    tiny = dict(algo="PPO", model="transformer", batch_size=8, seq_len=128,
+                hidden_size=32, n_heads=2, n_layers=1, obs_shape=(4,),
+                action_space=2)
+    cases = {
+        "dp-lstm-island": (
+            dict(algo="PPO", hidden_size=16, seq_len=5, batch_size=16,
+                 obs_shape=(4,), action_space=2), "lstm_pallas", None),
+        "dp-flash-island": (
+            {**tiny, "attention_impl": "flash"}, "attn_flash_pallas", None),
+        "ring-2x2": (
+            {**tiny, "attention_impl": "ring", "mesh_data": 2, "mesh_seq": 2},
+            None, (2, 2)),
+    }
+    res = chip_smoke.multichip_steps(4, cases=cases, require_tpu=False)
+    assert set(res["steps"]) == set(cases)

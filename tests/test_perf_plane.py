@@ -175,18 +175,28 @@ def test_recompile_rebind_freezes_old_count():
     assert tracker.recompiles == 2
 
 
-def test_device_peak_flops_env_override_and_table(monkeypatch):
+def test_device_peak_flops_cpu_env_and_tpu_table(monkeypatch):
+    """TPU_RL_PEAK_FLOPS is the CPU-smoke denominator only; on a TPU the
+    table decides and an unknown device_kind is an error, not None."""
     monkeypatch.setenv("TPU_RL_PEAK_FLOPS", "2.5e13")
     assert device_peak_flops() == 2.5e13
-    monkeypatch.setenv("TPU_RL_PEAK_FLOPS", "junk")
 
-    class FakeDev:
+    class V5p:
+        platform = "tpu"
         device_kind = "TPU v5p"
 
-    assert device_peak_flops(FakeDev()) == 459e12
+    assert device_peak_flops(V5p()) == 459e12  # env ignored off the CPU
+
+    class Unknown:
+        platform = "tpu"
+        device_kind = "TPU v9 mystery"
+
+    with pytest.raises(ValueError, match="TPU v9 mystery"):
+        device_peak_flops(Unknown())
     monkeypatch.delenv("TPU_RL_PEAK_FLOPS")
 
     class Cpu:
+        platform = "cpu"
         device_kind = "cpu"
 
     assert device_peak_flops(Cpu()) is None

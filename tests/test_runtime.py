@@ -40,7 +40,7 @@ def _cluster_cfg(tmp_path, **kw):
         hidden_size=16,
         worker_step_sleep=0.0,
         learner_device="cpu",  # deterministic CI: never touch a (possibly
-        # held or tunnel-flaky) real accelerator from the test cluster
+        # held) real accelerator from the test cluster
         rollout_lag_sec=30.0,  # no stale drops on slow CI hosts
         time_horizon=100,
         result_dir=None,

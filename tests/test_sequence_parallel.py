@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from tpu_rl.parallel.mesh import shard_map
 
 from tpu_rl.parallel.sequence import (
     SEQ_AXIS,
@@ -43,7 +42,7 @@ def _sharded_attn(impl, mesh, n_seq):
     qspec = P(None, SEQ_AXIS, None, None)  # (B, T, H, D)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(qspec, qspec, qspec, spec, spec),
         out_specs=qspec,
@@ -305,7 +304,7 @@ class TestFlashImpl:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_block_size_selection(self):
-        """The measured-win tile rule (bench_flash.json sweep), asserted on
+        """The flash tile rule (gcd(512, T), min 128), asserted on
         the PRODUCTION selector the dispatch calls: uniform gcd(512, T)
         tiles when >= 128 (the kernel's minimum), library defaults (None)
         otherwise. Every selected edge must divide T (grid exactness)."""

@@ -372,7 +372,14 @@ def main(argv: list[str] | None = None) -> int:
         sup.loop()
     finally:
         sup.stop()
-    return 0
+    failures = sup.failures()
+    for c in failures:
+        print(
+            f"[supervisor] {c.name} failed (exit code {c.proc.exitcode}, "
+            f"restart budget {'exhausted' if c.exhausted else 'left'})",
+            file=sys.stderr,
+        )
+    return 1 if failures else 0
 
 
 if __name__ == "__main__":

@@ -105,7 +105,9 @@ class ReplicaSet:
             self.machines.learner_ip,
             self.machines.model_port,
             self.machines.learner_port,
-            cpu_only=(self.cfg.learner_device == "cpu"),
+            # Elastic replicas act on the CPU: the chip (if any) belongs to
+            # the learner, which serves replica 0 on it.
+            cpu_only=True,
         )
         self._children[i] = child
         return child

@@ -54,7 +54,7 @@ from tpu_rl.config import FINGERPRINT_FIELDS as _FINGERPRINT_FIELDS
 
 # Marker filename inside a committed checkpoint dir. Its presence is the
 # commit point; its content is the run-meta JSON. Orbax ignores foreign
-# files in the directory on restore (probed against orbax 0.7.0).
+# files in the directory on restore (probed against orbax 0.11.32).
 COMMIT_MARKER = "COMMITTED"
 
 
@@ -172,12 +172,23 @@ def restore_actor_params(model_dir: str, algo: str):
     found = _ckpt_dirs(os.path.abspath(model_dir), algo)
     if not found:
         return None
+    import numpy as np
     import orbax.checkpoint as ocp
 
     with ocp.PyTreeCheckpointer() as ckpt:
         for _idx, path in reversed(found):
             try:
-                raw = ckpt.restore(path)
+                # Explicit numpy restore: the learner saved device arrays
+                # whose recorded sharding names ITS devices (the chip); the
+                # caller is a CPU process that must not need them.
+                tree = ckpt.metadata(path).item_metadata.tree
+                raw = ckpt.restore(
+                    path,
+                    restore_args=jax.tree.map(
+                        lambda _: ocp.RestoreArgs(restore_type=np.ndarray),
+                        tree,
+                    ),
+                )
             except Exception:
                 continue  # lost a GC race or damaged tree: try the previous
             # TrainState nests under "params"/"actor"; SACState keeps
@@ -237,7 +248,9 @@ class Checkpointer:
             # the chief saves, every host restores through its own handle).
             # Default orbax inserts cross-host barriers around every
             # save/restore, so a chief-gated save would deadlock the pod —
-            # scope the barrier set to this process alone.
+            # scope the barrier set to this process alone. (Re-checked on
+            # orbax 0.11.32: without the scoping the pod's first save never
+            # commits, tests/test_colocated_multihost.py.)
             from orbax.checkpoint import options as ocp_options
 
             mp = ocp_options.MultiprocessingOptions(

@@ -3,8 +3,8 @@
 The learner's hot loop previously ran its entire data plane in series with
 the device step — sample shared memory, assemble the batch, transfer it to
 the device, and only then dispatch ``train_step`` — even though the on-chip
-``@ref`` steps complete in 0.12-0.26 ms (``BENCH_r05.json``), so the chip
-idled while numpy copies and H2D transfers ran. IMPALA's core argument is
+``@ref`` steps are sub-millisecond, so the chip idled while numpy copies and
+H2D transfers ran. IMPALA's core argument is
 that the learner must never starve (Espeholt et al., 1802.01561), and the
 Podracer architectures get their throughput precisely by overlapping data
 arrival with the update step (Hessel et al., 2104.06272).

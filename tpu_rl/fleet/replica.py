@@ -98,7 +98,7 @@ class InferenceReplica(InferenceService):
         rep, bsh = replicated(mesh), batch_sharding(mesh)
         steps = {
             rows: jax.jit(
-                self._step_fn(jnp),
+                self._step_fn(jnp, n_devices=n),
                 # Params replicated, batch-shaped operands split on "data",
                 # PRNG key replicated; outputs inherit GSPMD's propagation.
                 in_shardings=(rep, bsh, bsh, bsh, bsh, rep),
@@ -206,7 +206,9 @@ def replica_main(
     # import-deadlock breaker — one of them sees a partially initialized
     # module and the replica dies (a crash loop on scale-out respawns).
     import tpu_rl.obs.perf  # noqa: F401
+    from tpu_rl.utils.platform import BackendRecord
 
+    backend = BackendRecord(f"inference-{replica_id}", cfg)
     family = build_family(cfg)
     params = family.init_params(
         jax.random.key(seed * 6151 + replica_id), seq_len=cfg.seq_len
@@ -278,3 +280,4 @@ def replica_main(
         sub.close()
         if pub is not None:
             pub.close()
+        backend.close()

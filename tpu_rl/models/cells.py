@@ -13,7 +13,11 @@ The LSTM core of the reference model zoo
 Kernel dispatch is controlled by :func:`set_pallas_mode`:
 ``"auto"`` (default) uses the kernel on TPU backends when the tile fits VMEM,
 ``"interpret"`` forces the kernel in interpreter mode (CPU tests),
-``"off"`` always uses the scan.
+``"off"`` always uses the scan. The choice is made from the devices the
+traced PROGRAM runs on — the registered data mesh, else a plain
+single-device jit on the default backend — never from how many chips the
+host happens to have, and is readable from the lowered program
+(``lstm_pallas`` / ``lstm_scan`` named scopes, ``utils.platform``).
 """
 
 from __future__ import annotations
@@ -52,12 +56,12 @@ def set_data_mesh(mesh) -> None:
 
 
 def _use_pallas(
-    batch: int, seq: int, hidden: int, mesh_active: bool = False
+    batch: int, seq: int, hidden: int, platform: str
 ) -> tuple[bool, bool]:
-    """-> (use_kernel, interpret). ``batch`` is the per-device shard size;
-    ``mesh_active`` says THIS trace will wrap the kernel in shard_map (a
-    registered-but-unusable mesh, e.g. a non-divisible init trace, must NOT
-    count: an unwrapped Mosaic call cannot live in a multi-device program)."""
+    """-> (use_kernel, interpret). ``batch`` is the per-device shard size
+    and ``platform`` the platform of the devices the traced program runs
+    on. The caller decides separately whether the call can be placed at all
+    (a multi-device program needs the shard_map island)."""
     from tpu_rl.ops.pallas_lstm import batch_tile, bwd_batch_tile
 
     if _PALLAS_MODE == "off":
@@ -67,29 +71,34 @@ def _use_pallas(
         # interpreter has no VMEM), so equivalence tests can never silently
         # degrade into scan-vs-scan.
         return True, True
+    if platform != "tpu":
+        return False, False
     if _PALLAS_MODE == "force":
         # Benchmark override: real kernel wherever a tiling fits.
-        if batch_tile(batch, seq, hidden) is None:
-            return False, False
-        if jax.default_backend() != "tpu":
-            return False, False
-        return len(jax.devices()) == 1 or mesh_active, False
-    if (
-        batch_tile(batch, seq, hidden) != batch
-        or bwd_batch_tile(batch, seq, hidden) != batch
-    ):
-        # Measured-win gate (bench_lstm_kernel.json): the fused kernel beats
-        # the scan only when the WHOLE batch is one VMEM tile for both passes
-        # (fwd+grad 1.75x at B128/H64, 1.56x at B256/H256). Multi-tile grids
-        # starve the MXU (fwd 0.82x, fwd+grad 1.0x at B1024/H1024) and
-        # no-tile-fits shapes can't run at all — both keep the scan, whose
-        # per-step matmuls always see the full batch.
-        return False, False
-    if jax.default_backend() != "tpu":
-        return False, False
-    # Single device: plain pallas_call. Multi-device: only inside the
-    # shard_map island of this trace.
-    return len(jax.devices()) == 1 or mesh_active, False
+        return batch_tile(batch, seq, hidden) is not None, False
+    # Measured-win gate (bench_lstm_kernel.json): the fused kernel beats
+    # the scan only when the WHOLE batch is one VMEM tile for both passes
+    # (fwd+grad 1.75x at B128/H64, 1.56x at B256/H256). Multi-tile grids
+    # starve the MXU (fwd 0.82x, fwd+grad 1.0x at B1024/H1024) and
+    # no-tile-fits shapes can't run at all — both keep the scan, whose
+    # per-step matmuls always see the full batch.
+    return (
+        batch_tile(batch, seq, hidden) == batch
+        and bwd_batch_tile(batch, seq, hidden) == batch
+    ), False
+
+
+def _program_devices() -> tuple[str, int]:
+    """(platform, data-axis width) of the program being traced: the
+    registered data mesh when there is one (``make_parallel_train_step``,
+    the colocated/sebulba programs), else a plain ``jax.jit`` — one device
+    of the default backend, however many chips the host has."""
+    mesh = _DATA_MESH
+    if mesh is None:
+        return jax.default_backend(), 1
+    from tpu_rl.parallel.mesh import DATA_AXIS
+
+    return mesh.devices.flat[0].platform, mesh.shape.get(DATA_AXIS, 1)
 
 
 class LSTMCell(nn.Module):
@@ -165,17 +174,16 @@ class LSTMCell(nn.Module):
             else jnp.ones((B, S), x.dtype)
         )
 
-        mesh = _DATA_MESH
-        n_data = 1
-        if mesh is not None and _PALLAS_MODE in ("auto", "interpret", "force"):
-            from tpu_rl.parallel.mesh import DATA_AXIS
-
-            n_data = mesh.shape.get(DATA_AXIS, 1)
-            if B % n_data != 0:
-                mesh, n_data = None, 1  # init/act traces: fall through
+        platform, n_data = _program_devices()
+        tiles = B % n_data == 0
         use_kernel, interpret = _use_pallas(
-            B // n_data, S, self.hidden, mesh_active=mesh is not None and n_data > 1
+            B // n_data if tiles else B, S, self.hidden, platform
         )
+        if not tiles:
+            # A multi-device program whose batch does not tile the mesh
+            # (init/act traces): no island, and an unwrapped Mosaic call has
+            # no GSPMD partitioning rule — only the interpreter may run.
+            use_kernel, n_data = use_kernel and interpret, 1
         if self.dtype is not None and _PALLAS_MODE != "interpret":
             # bf16 compute: the f32-only fused kernel would first cast its
             # operands up, forfeiting the MXU-rate win that motivated bf16 —
@@ -193,18 +201,18 @@ class LSTMCell(nn.Module):
                 carry0[1].astype(jnp.float32),
                 keep.astype(jnp.float32),
             )
-            if mesh is not None and n_data > 1:
+            if n_data > 1:
                 from jax.sharding import PartitionSpec as P
 
-                from tpu_rl.parallel.mesh import DATA_AXIS, shard_map
+                from tpu_rl.parallel.mesh import DATA_AXIS
 
                 def _local_unroll(xp_, wh_, h0_, c0_, keep_):
                     return lstm_unroll(xp_, wh_, h0_, c0_, keep_, interpret)
 
                 bspec = P(DATA_AXIS)  # shard every operand's leading (batch) dim
-                hs, cs = shard_map(
+                hs, cs = jax.shard_map(
                     _local_unroll,
-                    mesh=mesh,
+                    mesh=_DATA_MESH,
                     in_specs=(bspec, P(), bspec, bspec, bspec),
                     out_specs=(bspec, bspec),
                     # No collectives inside; pallas out_shapes carry no vma

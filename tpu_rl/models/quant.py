@@ -143,16 +143,17 @@ def tree_bytes(tree: Any) -> int:
 
 
 # ------------------------------------------------------- act-step dispatch
-def make_act_fn(cfg, family):
+def make_act_fn(cfg, family, n_devices: int = 1):
     """Resolve ``Config.act_kernel`` to the act callable serving consumers
     jit (``InferenceService._step_fn``, the worker's local act path).
+    ``n_devices`` is the width of the program the caller will jit it into.
 
     ``"xla"`` -> ``family.act`` unchanged. ``"pallas"`` -> the fused
     torso→LSTM-cell→policy-head kernel where the family supports it;
-    unsupported families (transformer, SAC, continuous) and non-TPU
-    backends without interpret mode fall back to ``family.act`` — the
-    knob is a fast path, never a correctness gate."""
-    if getattr(cfg, "act_kernel", "xla") != "pallas":
+    unsupported families (transformer, SAC, continuous), multi-device GSPMD
+    programs and non-TPU backends without interpret mode fall back to
+    ``family.act`` — the knob is a fast path, never a correctness gate."""
+    if getattr(cfg, "act_kernel", "xla") != "pallas" or n_devices > 1:
         return family.act
     from tpu_rl.ops.pallas_act import make_fused_act
 

@@ -93,11 +93,9 @@ class MultiHeadAttention(nn.Module):
             and T % self.mesh.shape[SEQ_AXIS] == 0
         )
         if tiles_mesh and self.attention_impl in ("ring", "ulysses"):
-            from tpu_rl.parallel.mesh import shard_map
-
             qs = P(DATA_AXIS, SEQ_AXIS, None, None)
             ps = P(DATA_AXIS, SEQ_AXIS)
-            attn = shard_map(
+            attn = jax.shard_map(
                 functools.partial(impl, axis_name=SEQ_AXIS, causal=True),
                 mesh=self.mesh,
                 in_specs=(qs, qs, qs, ps, ps),

@@ -21,9 +21,10 @@ telemetry on reports them continuously:
   expected rebuilds don't masquerade as drift.
 - :func:`device_peak_flops` / :data:`PEAK_FLOPS` — the single source of
   truth for bf16 peak by device kind; ``bench.py`` imports these from here
-  so live and offline MFU can never disagree on the denominator.
-  ``TPU_RL_PEAK_FLOPS`` (env, FLOPs/s per device) overrides for backends
-  with no table entry — it's what lets CPU smokes exercise the MFU path.
+  so live and offline MFU can never disagree on the denominator. A TPU
+  whose kind is not in the table is an error. ``TPU_RL_PEAK_FLOPS`` (env,
+  FLOPs/s per device) is the CPU-smoke denominator only — it's what lets
+  CPU smokes exercise the MFU path, and it is ignored on any other backend.
 - :func:`device_memory_bytes` — in-use/peak watermarks from
   ``device.memory_stats()``; backends that report none (CPU) fall back to
   process RSS with a module-tracked high-water mark.
@@ -51,7 +52,7 @@ from tpu_rl.obs import flightrec
 
 # bf16 peak FLOPs/s per chip by device kind (public spec sheets). MFU is
 # reported against bf16 peak regardless of compute dtype (standard MFU
-# convention); unknown kinds (e.g. CPU test runs) -> None -> mfu omitted.
+# convention); the CPU has no entry -> None -> mfu omitted.
 PEAK_FLOPS = {
     "TPU v5 lite": 197e12,  # v5e
     "TPU v5e": 197e12,
@@ -62,24 +63,26 @@ PEAK_FLOPS = {
 
 
 def device_peak_flops(device=None) -> float | None:
-    """Peak bf16 FLOPs/s for one device, or None when unknown. The
-    ``TPU_RL_PEAK_FLOPS`` env var (float, per-device) wins over the table —
-    set it to give CPU runs a denominator for smoke-testing the MFU path."""
-    env = os.environ.get("TPU_RL_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
+    """Peak bf16 FLOPs/s for one device. On the CPU: the
+    ``TPU_RL_PEAK_FLOPS`` env var (float, per-device — the documented
+    denominator for smoke-testing the MFU path) or None. On an accelerator:
+    the table entry, and a ``device_kind`` the table does not know is an
+    error, never an override or a silent None."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
+    if device.platform == "cpu":
+        env = os.environ.get("TPU_RL_PEAK_FLOPS")
+        return float(env) if env else None
     kind = device.device_kind
     for k, v in PEAK_FLOPS.items():
         if kind.startswith(k) or k in kind:
             return v
-    return None
+    raise ValueError(
+        f"no peak-FLOPs entry for device_kind {kind!r}; add it to "
+        "tpu_rl.obs.perf.PEAK_FLOPS with its source"
+    )
 
 
 def compiled_flops(compiled) -> float:
@@ -90,8 +93,6 @@ def compiled_flops(compiled) -> float:
         cost = compiled.cost_analysis() or {}
     except Exception:  # noqa: BLE001 — backends may not implement it
         return 0.0
-    if isinstance(cost, (list, tuple)):  # some versions return [dict]
-        cost = cost[0] if cost else {}
     return float(cost.get("flops", 0.0) or 0.0)
 
 

@@ -19,11 +19,11 @@ because the act contract discards it.
 Dispatch honors :func:`tpu_rl.models.cells.set_pallas_mode`: ``"interpret"``
 runs the kernel in the Pallas interpreter (CPU equivalence tests — the
 parity pin in tests/test_pallas_act.py), ``"off"`` disables it, ``"auto"``/
-``"force"`` use the compiled kernel on single-device TPU backends when the
-working set fits VMEM. Multi-device GSPMD programs (``InferenceReplica``
-with ``inference_mesh_data > 1``) always fall back: the Mosaic custom call
-has no automatic SPMD partitioning rule (same constraint as
-``pallas_lstm``'s shard_map gating).
+``"force"`` use the compiled kernel on a TPU backend when the working set
+fits VMEM. The act programs that take this path are single-device jits;
+multi-device GSPMD programs (``InferenceReplica`` with
+``inference_mesh_data > 1``) never ask for it (``models.quant.make_act_fn``):
+the Mosaic custom call has no automatic SPMD partitioning rule.
 
 Sampling and the carry-reset mask stay OUTSIDE the kernel, shared with the
 XLA path, so a given (params, obs, key) produces the identical action from
@@ -36,17 +36,33 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from tpu_rl.ops.pallas_lstm import _VMEM_BUDGET_BYTES, _compiler_params
+from tpu_rl.ops.pallas_lstm import (
+    _VMEM_BUDGET_BYTES,
+    _block_bytes,
+    _compiler_params,
+)
 
 
 def act_fits_vmem(rows: int, obs_dim: int, hidden: int, n_actions: int) -> bool:
     """Whole act step in one VMEM-resident program? (No grid: the serving
-    batch is one tile.) Weights + activations, counted once; Mosaic's
-    scoped-VMEM ceiling is raised by ``_compiler_params`` as in the LSTM
-    kernel."""
-    weights = obs_dim * hidden + hidden * 4 * hidden * 2 + hidden * n_actions
-    acts = rows * (obs_dim + hidden * 8 + n_actions * 2)
-    return (weights + acts) * 4 <= _VMEM_BUDGET_BYTES
+    batch is one tile, every operand is resident once.) Padded weight and
+    activation blocks against the scoped-VMEM limit ``_compiler_params``
+    requests, as in the LSTM kernel."""
+    h4 = 4 * hidden
+    weights = (
+        _block_bytes(obs_dim, hidden)
+        + 2 * _block_bytes(hidden, h4)
+        + _block_bytes(hidden, n_actions)
+        + 2 * _block_bytes(1, hidden)
+        + _block_bytes(1, h4)
+    )
+    acts = (
+        _block_bytes(rows, obs_dim)
+        + 4 * _block_bytes(rows, hidden)  # h, c in; h2, c2 out
+        + 2 * _block_bytes(rows, n_actions)  # raw + log-softmax
+        + 4 * _block_bytes(rows, h4)  # x, z and gate temporaries
+    )
+    return weights + acts <= _VMEM_BUDGET_BYTES
 
 
 def _act_kernel(
@@ -84,6 +100,7 @@ def _act_kernel(
     c2_ref[:] = c2
 
 
+@jax.named_scope("act_pallas")  # read back by utils.platform.program_paths
 def fused_act_step(actor_params, obs, h, c, interpret: bool):
     """Run the fused kernel on an (already dequantized, f32) actor param
     tree. Returns (log-softmax logits, h2, c2) — the same triple
@@ -122,7 +139,7 @@ def _kernel_choice(rows: int, obs_dim: int, hidden: int, n_actions: int):
         return False, False
     if _PALLAS_MODE == "interpret":
         return True, True
-    if jax.default_backend() != "tpu" or len(jax.devices()) != 1:
+    if jax.default_backend() != "tpu":
         return False, False
     if not act_fits_vmem(rows, obs_dim, hidden, n_actions):
         return False, False

@@ -27,29 +27,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Max VMEM footprint for one batch tile before we refuse. ~3/8 of a TPU
-# v5e/v4 core's 128 MB VMEM: fits_vmem counts each buffer once, while Mosaic
-# double-buffers the streamed blocks (xp/hs/cs/acts) across grid steps, so
-# the true high-water mark is < 2x this budget. Wide-hidden workloads (e.g.
-# H=1024: 16 MB of recurrent weights alone) tile their batch via
+# Scoped VMEM requested for one kernel call, and the budget the tile pickers
+# size against. A TPU v5e core reports 128 MiB of VMEM
+# (``pltpu.get_tpu_info().vmem_capacity_bytes``, printed by chip_smoke.py);
+# Mosaic's default scoped limit is 16 MiB, below one wide-hidden tile's
+# working set (wh alone is 16 MiB at H=1024), so the limit is raised and the
+# rest is left to the compiler. Wide-hidden workloads tile their batch via
 # ``batch_tile`` instead of falling back to the scan.
-_VMEM_BUDGET_BYTES = 48 * 1024 * 1024
+_VMEM_BUDGET_BYTES = 106 * 1024 * 1024
+
+_LANES, _SUBLANES = 128, 8
+
+
+def _block_bytes(*shape: int) -> int:
+    """f32 bytes one block occupies in VMEM: the minor dim pads to 128
+    lanes and the second-minor to 8 sublanes — a ``(S, bt, 1)`` keep block
+    costs as much as ``(S, bt, 128)``, and H=64 rows cost H=128."""
+    *lead, rows, cols = shape
+    n = 4 * (-(-rows // _SUBLANES) * _SUBLANES) * (-(-cols // _LANES) * _LANES)
+    for d in lead:
+        n *= d
+    return n
 
 
 def _compiler_params(interpret: bool):
-    """Mosaic params shared by the forward and backward kernels: raise the
-    scoped-VMEM ceiling above the default (~16 MB), which is below one
-    wide-hidden tile's working set (wh alone is 16 MB at H=1024). fits_vmem
-    counts each buffer once; with double-buffered streaming the true
-    high-water is < 2x budget + weights, well under the 128 MB core VMEM."""
+    """Mosaic params shared by the forward, backward and act kernels."""
     if interpret:
         return None
-    cp_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cp_cls is None:
-        return None
-    return cp_cls(vmem_limit_bytes=int(2.2 * _VMEM_BUDGET_BYTES))
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_BUDGET_BYTES)
 
 
 def _make_kernel(save_acts: bool):
@@ -96,6 +101,7 @@ def _make_kernel(save_acts: bool):
     return kernel
 
 
+@jax.named_scope("lstm_pallas")  # read back by utils.platform.program_paths
 def _pallas_forward(xp, wh, h0, c0, keep, interpret: bool, save_acts: bool):
     """xp (B,S,4H), keep (B,S) -> (hs, cs[, acts]) in batch-major layout
     (the kernel runs time-major internally).
@@ -150,11 +156,24 @@ def _pallas_forward(xp, wh, h0, c0, keep, interpret: bool, save_acts: bool):
     return tuple(jnp.moveaxis(o, 0, 1) for o in outs)
 
 
+def _fits(blocks: int, batch: int, hidden: int) -> bool:
+    """``blocks`` = padded bytes of every pipelined block of one grid step.
+    Mosaic double-buffers each of them (the grid-invariant wh block too),
+    and the step body keeps a few (Bt, 4H) gate temporaries live."""
+    return 2 * blocks + 6 * _block_bytes(batch, 4 * hidden) <= _VMEM_BUDGET_BYTES
+
+
 def fits_vmem(batch: int, seq: int, hidden: int) -> bool:
-    """Does ONE batch tile of this size fit the per-tile VMEM budget?"""
-    # xp + acts dominate: 2 * B*S*4H floats, plus hs/cs and weights.
-    floats = batch * seq * hidden * (4 + 4 + 1 + 1) + hidden * 4 * hidden
-    return floats * 4 <= _VMEM_BUDGET_BYTES
+    """Does ONE forward batch tile of this size fit the VMEM budget?"""
+    h4 = 4 * hidden
+    blocks = (
+        2 * _block_bytes(seq, batch, h4)  # xp, acts
+        + 2 * _block_bytes(seq, batch, hidden)  # hs, cs
+        + _block_bytes(seq, batch, 1)  # keep
+        + 2 * _block_bytes(batch, hidden)  # h0, c0
+        + _block_bytes(hidden, h4)  # wh
+    )
+    return _fits(blocks, batch, hidden)
 
 
 def _best_tile(batch: int, fits) -> int | None:
@@ -180,12 +199,18 @@ def batch_tile(batch: int, seq: int, hidden: int) -> int | None:
 
 
 def bwd_batch_tile(batch: int, seq: int, hidden: int) -> int | None:
-    """Backward-kernel batch tile. The backward working set per row is
-    acts + cs + dhs + dcs + dxp ~ 11 H-floats per step, plus the wh block."""
+    """Backward-kernel batch tile (acts, cs, dhs, dcs in; dxp out)."""
+    h4 = 4 * hidden
 
     def fits(d: int) -> bool:
-        floats = d * seq * hidden * 11 + hidden * 4 * hidden
-        return floats * 4 <= _VMEM_BUDGET_BYTES
+        blocks = (
+            2 * _block_bytes(seq, d, h4)  # acts, dxp
+            + 3 * _block_bytes(seq, d, hidden)  # cs, dhs, dcs
+            + _block_bytes(seq, d, 1)  # keep
+            + 4 * _block_bytes(d, hidden)  # h0, c0, dh0, dc0
+            + _block_bytes(hidden, h4)  # wh
+        )
+        return _fits(blocks, d, hidden)
 
     return _best_tile(batch, fits)
 
@@ -236,6 +261,7 @@ def _mixed_dot_bwd(dtype, res, g):
 mixed_dot.defvjp(_mixed_dot_fwd, _mixed_dot_bwd)
 
 
+@jax.named_scope("lstm_scan")
 def _scan_forward(xp, wh, h0, c0, keep, matmul_dtype=None, want_cs=False):
     """Plain ``lax.scan`` forward over the precomputed input projection —
     the measured winner for UNdifferentiated unrolls (the fused kernel is
@@ -370,6 +396,7 @@ def _bwd_kernel(
     dc0_ref[:] = dc
 
 
+@jax.named_scope("lstm_pallas")
 def _pallas_backward(wh, h0, c0, keep, hs, cs, acts, dhs, dcs, interpret):
     """Batch-tiled fused backward; same grid scheme as the forward. Returns
     (dxp, dh0, dc0); the weight gradient is computed by the caller from dxp
@@ -429,13 +456,12 @@ def _bwd(interpret, res, ct):
     # genuinely fused kernel pair, not kernel-fwd + scan-bwd.
     from tpu_rl.models.cells import _PALLAS_MODE
 
+    # (A compiled forward kernel ran to get here, so the device is a TPU.)
     bwd_tile = bwd_batch_tile(B, S, H)
-    if interpret or (
-        jax.default_backend() == "tpu"
-        and (
-            bwd_tile == B
-            or (_PALLAS_MODE == "force" and bwd_tile is not None)
-        )
+    if (
+        interpret
+        or bwd_tile == B
+        or (_PALLAS_MODE == "force" and bwd_tile is not None)
     ):
         dxp, dh0, dc0 = _pallas_backward(
             wh, h0, c0, keep, hs, cs, acts, dhs, dcs, interpret
@@ -494,9 +520,10 @@ def _bwd(interpret, res, ct):
         jnp.moveaxis(keep, 1, 0)[::-1],
     )
     zero = jnp.zeros((B, H), jnp.float32)
-    (dh0, dc0, dwh), dz_rev = jax.lax.scan(
-        step, (zero, zero, jnp.zeros_like(wh)), xs
-    )
+    with jax.named_scope("lstm_scan"):
+        (dh0, dc0, dwh), dz_rev = jax.lax.scan(
+            step, (zero, zero, jnp.zeros_like(wh)), xs
+        )
     dxp = jnp.moveaxis(dz_rev[::-1], 0, 1)  # (B, S, 4H)
     return dxp, dwh, dh0, dc0, None
 

@@ -44,8 +44,7 @@ def make_parallel_train_step(
     sharded ``P(None, "data")``), an inner ``lax.scan`` folds a fresh RNG key
     per update, and the last update's metrics are returned. Per-update math is
     identical to K separate calls; what changes is that fixed per-dispatch
-    overhead (host dispatch, or RTT through a remote-execution tunnel) is
-    paid once per K updates instead of per update."""
+    host overhead is paid once per K updates instead of per update."""
     if cfg is not None:
         check_divisible(cfg.batch_size, mesh)
 

@@ -23,25 +23,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 DATA_AXIS = "data"
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Version-portable ``shard_map``: newer jax exposes ``jax.shard_map``
-    with a ``check_vma`` knob; this jax line (0.4.x) has
-    ``jax.experimental.shard_map.shard_map`` where the same knob is spelled
-    ``check_rep``. All repo islands route through here so the call sites
-    stay on the current spelling."""
-    kw = {}
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def make_mesh(
     n_data: int | None = None, devices: Sequence[jax.Device] | None = None
 ) -> Mesh:

@@ -46,14 +46,16 @@ _NEG_INF = -1e30  # finite -inf stand-in: keeps exp()/max() NaN-free
 
 
 def _select_block_size(T: int, head_dim: int = 64) -> int | None:
-    """Tile edge for the Pallas flash kernel at sequence length T, by the
-    measured-win rule from the on-chip sweep (bench_flash.json): gcd(512, T)
-    — the largest power-of-two divisor of T capped at 512 — when that is at
-    least the kernel's 128 minimum; None = use library defaults.
+    """Tile edge for the Pallas flash kernel at sequence length T:
+    gcd(512, T) — the largest power-of-two divisor of T capped at 512 — when
+    that is at least the kernel's 128 minimum; None = use library defaults
+    (128 everywhere). Not measured on current code: 512 tiles compile and
+    match the reference on a TPU v5e (chip_smoke.py); their speed against
+    the defaults is ROADMAP S2's to re-measure.
 
-    The sweep covered head_dim 64 (bf16); 512-edge backward tiles scale
-    VMEM linearly with head_dim, so past 128 the override could exceed VMEM
-    where the library defaults still compile — defaults win there."""
+    512-edge backward tiles scale VMEM linearly with head_dim, so past 128
+    the override could exceed VMEM where the library defaults still compile
+    — defaults win there."""
     if head_dim > 128:
         return None
     blk = math.gcd(512, T)
@@ -391,6 +393,7 @@ def _all_gather_seq(x: jax.Array, axis_name: str) -> jax.Array:
     return g.reshape(x.shape[0], -1)
 
 
+@jax.named_scope("attn_full")  # read back by utils.platform.program_paths
 def full_attention(
     q: jax.Array,
     k: jax.Array,
@@ -628,15 +631,25 @@ def flash_attention_tpu(
 
     Off-TPU (CPU tests, the virtual mesh) Mosaic kernels cannot run, so this
     falls back to :func:`full_attention` — bit-compatible masking, different
-    arithmetic order. Under a data-parallel mesh the Mosaic call cannot be
-    auto-partitioned by GSPMD, so — per the LSTM-kernel pattern in
-    ``models/cells.py`` — the kernel runs as a ``shard_map`` island over the
-    ``"data"`` axis whenever ``make_parallel_train_step`` has registered its
-    mesh (including the 1-device case, so the single-chip bench exercises
-    the same island multi-chip uses). The sharded LONG-CONTEXT (seq-axis)
-    path remains ``ring``/``ulysses``.
+    arithmetic order. The program's devices decide the placement, as for the
+    LSTM kernel (``models/cells.py``): no registered data mesh means a plain
+    single-device jit and a bare kernel call; under
+    ``make_parallel_train_step``'s mesh the Mosaic call cannot be
+    auto-partitioned by GSPMD, so it runs as a ``shard_map`` island over the
+    ``"data"`` axis (including the 1-device mesh, so one chip exercises the
+    island four chips use). Which path a program took is readable from its
+    lowering (``attn_flash_pallas`` / ``attn_full`` named scopes). The
+    sharded LONG-CONTEXT (seq-axis) path remains ``ring``/``ulysses``.
     """
-    if jax.default_backend() != "tpu":
+    from tpu_rl.models import cells
+
+    mesh = cells._DATA_MESH
+    platform, n_data = cells._program_devices()
+    tiles = q.shape[0] % n_data == 0
+    if platform != "tpu" or not tiles:
+        # not tiles: a multi-device program whose batch does not tile the
+        # mesh (init trace) — a bare Mosaic custom call has no GSPMD
+        # partitioning rule, so take the partitionable jnp path.
         return full_attention(q, k, v, q_pos, seg, causal=causal)
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         SegmentIds,
@@ -645,14 +658,11 @@ def flash_attention_tpu(
 
     scale = 1.0 / np.sqrt(q.shape[-1])
     # The library's get_default() is 128 everywhere ("TODO: select better
-    # parameters" upstream) — measured 3x slower than necessary at the
-    # long-context workload shape. On-chip sweep (bench_flash.json, v5e,
-    # B16 T2048 H8 D64 bf16, fwd+bwd ms): 128->44.8, 256->22.2, 512->15.0,
-    # 1024->14.4, 2048->compile failure. 512 is within 4% of the best,
-    # fits VMEM with margin at wider heads, and must divide T, so:
+    # parameters" upstream); the tile edge must divide T.
     blk = _select_block_size(q.shape[1], head_dim=q.shape[-1])
     bs = _uniform_block_sizes(blk) if blk is not None else None
 
+    @jax.named_scope("attn_flash_pallas")
     def kernel(q, k, v, seg):
         # our layout (B, T, H, D) -> kernel layout (B, H, T, D)
         qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
@@ -666,36 +676,21 @@ def flash_attention_tpu(
         )
         return o.transpose(0, 2, 1, 3)
 
-    from tpu_rl.models import cells
+    if mesh is None:
+        return kernel(q, k, v, seg)
+    from jax.sharding import PartitionSpec as P
 
-    mesh = cells._DATA_MESH
-    mesh_tiles = (
-        mesh is not None
-        and DATA_AXIS in mesh.shape
-        and q.shape[0] % mesh.shape[DATA_AXIS] == 0
-    )
-    if mesh_tiles:
-        from jax.sharding import PartitionSpec as P
-
-        from tpu_rl.parallel.mesh import shard_map
-
-        qs = P(DATA_AXIS, None, None, None)
-        return shard_map(
-            kernel,
-            mesh=mesh,
-            in_specs=(qs, qs, qs, P(DATA_AXIS, None)),
-            out_specs=qs,
-            # No collectives inside; pallas out_shapes carry no vma
-            # annotations, so varying-axis checking must be off (same as
-            # the cells.py LSTM island).
-            check_vma=False,
-        )(q, k, v, seg)
-    if len(jax.devices()) > 1:
-        # Multi-device program with no registered/tiling mesh (init trace,
-        # eval outside make_parallel_train_step): a bare Mosaic custom call
-        # has no GSPMD partitioning rule, so take the partitionable jnp path.
-        return full_attention(q, k, v, q_pos, seg, causal=causal)
-    return kernel(q, k, v, seg)
+    qs = P(DATA_AXIS, None, None, None)
+    return jax.shard_map(
+        kernel,
+        mesh=mesh,
+        in_specs=(qs, qs, qs, P(DATA_AXIS, None)),
+        out_specs=qs,
+        # No collectives inside; pallas out_shapes carry no vma
+        # annotations, so varying-axis checking must be off (same as
+        # the cells.py LSTM island).
+        check_vma=False,
+    )(q, k, v, seg)
 
 
 ATTENTION_IMPLS = {

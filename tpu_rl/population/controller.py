@@ -139,6 +139,17 @@ class PopulationController:
         )
         self.spec = PopSpec.parse(cfg.pop_spec)
         self.spec.check_searchable()
+        from tpu_rl.runtime.runner import Supervisor, owner_on_cpu
+
+        if self.spec.k > 1 and not owner_on_cpu(cfg):
+            # One process per chip, and no chip partitioning: the members'
+            # learners would all open the same accelerator (the supervisor
+            # refuses the second owner). Fail here, before any spawn.
+            raise ValueError(
+                f"a population of k={self.spec.k} members would share one "
+                "accelerator; run the members on the CPU with "
+                "learner_device='cpu' (or JAX_PLATFORMS=cpu)"
+            )
         self.base = cfg
         self.machines = machines or MachinesConfig()
         self.max_updates = max_updates
@@ -168,8 +179,6 @@ class PopulationController:
             if cfg.env_mode == "distributed"
             else None
         )
-
-        from tpu_rl.runtime.runner import Supervisor
 
         self.sup = Supervisor.from_config(cfg)
         self.generation = 0
@@ -317,7 +326,7 @@ class PopulationController:
             f"member-{m.idx}",
             member_main,
             m.dir,
-            cpu_only=(cfg.learner_device == "cpu"),
+            cpu_only=owner_on_cpu(cfg),
             # A distributed member runs a nested fleet and therefore cannot
             # be a daemonic process (no grandchildren allowed).
             daemon=(cfg.env_mode == "colocated"),

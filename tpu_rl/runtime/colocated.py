@@ -94,6 +94,8 @@ class ColocatedLoop:
       on the device-resident outputs without any host hop.
     """
 
+    role = "colocated"  # bring-up record / log prefix
+
     def __init__(
         self,
         cfg: Config,
@@ -122,6 +124,9 @@ class ColocatedLoop:
             init_multihost(**cfg.multihost)
         self._chief = jax.process_index() == 0
         self._build_meshes()
+        from tpu_rl.utils.platform import BackendRecord
+
+        self._backend = BackendRecord(self.role, cfg, self.mesh)
         self.spec = get_spec(cfg.env)
         self._v_reset, self._v_step = make_vec_env(
             self.spec, cfg.batch_size, cfg.time_horizon
@@ -464,6 +469,7 @@ class ColocatedLoop:
         append_resume(self.cfg.result_dir, idx, self.run_epoch)
 
     def close(self) -> None:
+        self._backend.close()
         if self.ckpt is not None:
             self.ckpt.close()
             self.ckpt = None
@@ -568,6 +574,9 @@ class ColocatedLoop:
                 self._perf.capture(
                     self.program, state, carry, stats, k_roll, k_train
                 )
+            self._backend.add_program(
+                self.program, state, carry, stats, k_roll, k_train
+            )
             t_disp = time.perf_counter()
             state, carry, stats, metrics = self.program(
                 state, carry, stats, k_roll, k_train

@@ -254,14 +254,15 @@ class InferenceService:
             if router is not None:
                 router.close()
 
-    def _step_fn(self, jnp):
-        """The pure padded act program (shared by every jit variant).
+    def _step_fn(self, jnp, n_devices: int = 1):
+        """The pure padded act program (shared by every jit variant;
+        ``n_devices`` is the width of the mesh it will be jitted over).
         Serving-dtype params are dequantized INSIDE the program (the
         compiled step reads the narrow bytes from HBM and widens on chip);
         the act computation itself is the ``Config.act_kernel`` dispatch."""
         from tpu_rl.models.quant import dequantize_tree, make_act_fn
 
-        act = make_act_fn(self.cfg, self.family)
+        act = make_act_fn(self.cfg, self.family, n_devices)
 
         def _step(params, obs, h, c, first, key):
             params = dequantize_tree(params)

@@ -213,6 +213,7 @@ class LearnerService:
         # prefetch queue). Both None unless Config.learn_diag.
         self._diag = None
         self._diag_vers = None
+        self.last_losses: dict = {}  # newest loss-log readback
 
     # ------------------------------------------------------------------ run
     def run(self) -> None:
@@ -233,16 +234,43 @@ class LearnerService:
         off_policy = is_off_policy(cfg.algo)
         rng = np.random.default_rng(self.seed)
 
+        chain = max(1, cfg.learner_chain)
+        if self.max_updates is not None and chain > self.max_updates:
+            # A budget smaller than the chain would otherwise complete
+            # "successfully" with ZERO updates (the pre-dispatch budget
+            # check fires before the first dispatch). Clamp so a small
+            # budget performs real updates; callers wanting a hard error
+            # should validate their own run plans.
+            print(
+                f"[learner] learner_chain {chain} exceeds max_updates "
+                f"{self.max_updates}; clamping chain to "
+                f"{max(1, self.max_updates)}", flush=True,
+            )
+            chain = max(1, self.max_updates)
+
         # Compile target meshes first: the family needs the mesh when the
-        # transformer's ring/Ulysses attention is sequence-sharded.
+        # transformer's ring/Ulysses attention is sequence-sharded, and the
+        # bring-up record names the devices this learner runs on.
         mesh = None
         if cfg.mesh_seq > 1:
             from tpu_rl.parallel import make_sp_mesh
 
             mesh = make_sp_mesh(cfg.mesh_data, cfg.mesh_seq)
+        elif cfg.mesh_data > 1 or chain > 1:
+            # chain > 1 rides the same GSPMD wrapper even on one device
+            # (make_mesh(1)): the chained lax.scan program is what
+            # amortizes per-dispatch overhead, mesh width is orthogonal.
+            from tpu_rl.parallel.mesh import make_mesh
+
+            mesh = make_mesh(cfg.mesh_data)
+        from tpu_rl.utils.platform import BackendRecord
+
+        backend = BackendRecord("learner", cfg, mesh)
         spec = get_algo(cfg.algo)
         family, state, train_step = spec.build(
-            cfg, jax.random.key(self.seed), mesh=mesh
+            cfg,
+            jax.random.key(self.seed),
+            mesh=mesh if cfg.mesh_seq > 1 else None,
         )
 
         # ---- checkpoint resume (newest COMMITTED index wins) ----
@@ -288,23 +316,10 @@ class LearnerService:
         # the raw train step with the post-switch cfg and must re-apply the
         # same mesh/jit wrapping.
         self._place_global = None
-        chain = max(1, cfg.learner_chain)
-        if self.max_updates is not None and chain > self.max_updates:
-            # A budget smaller than the chain would otherwise complete
-            # "successfully" with ZERO updates (the pre-dispatch budget
-            # check fires before the first dispatch). Clamp so a small
-            # budget performs real updates; callers wanting a hard error
-            # should validate their own run plans.
-            print(
-                f"[learner] learner_chain {chain} exceeds max_updates "
-                f"{self.max_updates}; clamping chain to "
-                f"{max(1, self.max_updates)}", flush=True,
-            )
-            chain = max(1, self.max_updates)
         self._chain_mesh = None
         self._batch_sharding = None  # eager-placement target (prefetch feed)
         self._device = jax.devices()[0]
-        if mesh is not None:  # built above iff cfg.mesh_seq > 1
+        if cfg.mesh_seq > 1:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             from tpu_rl.parallel.dp import make_sp_train_step, replicate
@@ -316,14 +331,10 @@ class LearnerService:
             state = replicate(state, mesh)
             self._batch_sharding = NamedSharding(mesh, P(DATA_AXIS, SEQ_AXIS))
             self._setup_multihost_feed(self._batch_sharding)
-        elif cfg.mesh_data > 1 or chain > 1:
-            # chain > 1 rides the same GSPMD wrapper even on one device
-            # (make_mesh(1)): the chained lax.scan program is what
-            # amortizes per-dispatch overhead, mesh width is orthogonal.
+        elif mesh is not None:
             from tpu_rl.parallel.dp import make_parallel_train_step, replicate
-            from tpu_rl.parallel.mesh import batch_sharding, make_mesh
+            from tpu_rl.parallel.mesh import batch_sharding
 
-            mesh = make_mesh(cfg.mesh_data)
             if chain > 1:
                 self._chain_mesh = mesh
 
@@ -640,6 +651,7 @@ class LearnerService:
                     # and rebinds the recompile watch — BEFORE dispatch, so
                     # the donated buffers are still alive to lower against.
                     self._perf.capture(train_step, state, batch, sub_key)
+                backend.add_program(train_step, state, batch, sub_key)
                 t_step = time.perf_counter()
                 state, metrics = train_step(state, batch, sub_key)
                 step_secs = time.perf_counter() - t_step
@@ -752,7 +764,17 @@ class LearnerService:
                         self._emit_telemetry(telem_reg, telem_pub, timer, idx)
                 if _crossed(prev_idx, idx, cfg.loss_log_interval):
                     jax.block_until_ready(metrics)
-                    logger.log_losses(idx, {k: float(v) for k, v in metrics.items()})
+                    # Kept on self (harnesses read it after run()) and
+                    # printed, like the colocated loop's update line.
+                    self.last_losses = {k: float(v) for k, v in metrics.items()}
+                    print(
+                        f"[learner] update {idx}  "
+                        + "  ".join(
+                            f"{k} {v:.4f}" for k, v in self.last_losses.items()
+                        ),
+                        flush=True,
+                    )
+                    logger.log_losses(idx, self.last_losses)
                     logger.log_timers(idx, timer)
                     self._log_fleet_stat(logger)
                     logger.flush()
@@ -905,6 +927,7 @@ class LearnerService:
                 tracer.dump(os.path.join(cfg.result_dir, "trace.json"))
             pub.close()
             writer.close()
+            backend.close()
 
     # ------------------------------------------------------------- batching
     def _assemble(self, raws: list):

@@ -147,6 +147,8 @@ class SebulbaLoop(ColocatedLoop):
     and :meth:`run` drives the two lanes concurrently through a
     :class:`BoundedPipe` instead of one fused dispatch."""
 
+    role = "sebulba"
+
     # ---------------------------------------------------------- topology hooks
     def _build_meshes(self) -> None:
         if jax.process_count() > 1:
@@ -381,6 +383,7 @@ class SebulbaLoop(ColocatedLoop):
                 k_train = jax.random.fold_in(self._k_base, it)
                 if self._perf is not None:
                     self._perf.capture(self.train, state, batch, k_train)
+                self._backend.add_program(self.train, state, batch, k_train)
                 t_disp = time.perf_counter()
                 state, metrics = self.train(state, batch, k_train)
                 if diag_acc is not None and isinstance(metrics, dict):

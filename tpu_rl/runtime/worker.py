@@ -62,7 +62,6 @@ class Worker:
         model_port: int,
         stop_event=None,
         heartbeat=None,
-        initial_params=None,
         seed: int = 0,
         inference_port: int | list[int] | None = None,
     ):
@@ -71,7 +70,6 @@ class Worker:
         self.addr = (manager_ip, manager_port, learner_ip, model_port)
         self.stop_event = stop_event
         self.heartbeat = heartbeat
-        self.initial_params = initial_params
         self.seed = seed
         self.inference_port = inference_port
         self.fell_back = False  # currently acting locally after a timeout
@@ -162,7 +160,9 @@ class Worker:
         import jax.numpy as jnp
 
         from tpu_rl.models.families import build_family
+        from tpu_rl.utils.platform import enable_compile_cache
 
+        enable_compile_cache()
         cfg = self.cfg
         manager_ip, manager_port, learner_ip, model_port = self.addr
         # Fault injection (tpu_rl.chaos): delay:worker shims this worker's
@@ -243,9 +243,17 @@ class Worker:
 
         family = build_family(cfg)
         key = jax.random.key(self.seed * 9973 + self.worker_id)
-        if self.initial_params is not None:
-            params = self.initial_params  # checkpoint-resume parity
-        else:
+        # Warm start from the newest committed checkpoint when one exists
+        # (reference ``main.py:247-252``) — restored HERE, in the CPU-pinned
+        # child, never in the supervising parent, which must not open a
+        # backend beside the learner's. No checkpoint: random init until
+        # the learner's first broadcast.
+        params = None
+        if cfg.model_dir:
+            from tpu_rl.checkpoint import restore_actor_params
+
+            params = restore_actor_params(cfg.model_dir, cfg.algo)
+        if params is None:
             key, init_key = jax.random.split(key)
             params = family.init_params(init_key, seq_len=cfg.seq_len)
         # Local act path shares the serving kernel dispatch
@@ -658,7 +666,6 @@ def worker_main(
     model_port: int,
     stop_event,
     heartbeat,
-    initial_params=None,
     seed: int = 0,
     inference_port: int | list[int] | None = None,
 ) -> None:
@@ -672,7 +679,6 @@ def worker_main(
         model_port,
         stop_event,
         heartbeat,
-        initial_params,
-        seed,
+        seed=seed,
         inference_port=inference_port,
     ).run()
