@@ -136,8 +136,8 @@ def main() -> int:
         trace = json.loads(open(trace_path).read())
         spans = {e["name"] for e in trace["traceEvents"] if e["ph"] == "X"}
         print(f"[obs-smoke] trace.json spans: {sorted(spans)}", flush=True)
-        if "train-step" not in spans:
-            failures.append(f"trace.json has no train-step span: {spans}")
+        if "dispatch" not in spans:
+            failures.append(f"trace.json has no dispatch span: {spans}")
     except (OSError, ValueError, KeyError) as e:
         failures.append(f"trace.json invalid: {type(e).__name__}: {e}")
 

@@ -109,7 +109,7 @@ def main() -> int:
             chains.setdefault(ev["id"], []).append(ev["args"]["hop"])
     linked = [
         tid for tid, hops in chains.items()
-        if {"worker-tick", "storage-ingest", "train-step"} <= set(hops)
+        if {"worker-tick", "storage-ingest", "dispatch"} <= set(hops)
         and ("relay-in" in hops or "relay-out" in hops)
     ]
     print(

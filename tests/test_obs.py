@@ -587,7 +587,7 @@ def test_merge_clock_corrects_and_links_flows(tmp_path):
     """Two processes whose wall clocks disagree by 5 s, plus a learner: the
     merged timeline must place their spans in TRUE order (clock-corrected),
     chain the sampled rollout's hops with flow events, and close the chain
-    onto the first train-step after window-close, flagged synthesized."""
+    onto the first dispatch after window-close, flagged synthesized."""
     from tpu_rl.obs.merge import merge_traces
 
     R = 1_000_000_000_000  # reference epoch, ns
@@ -609,7 +609,7 @@ def test_merge_clock_corrects_and_links_flows(tmp_path):
     )
     learner = _trace_doc(
         "learner", 3, R + 2_000_000,
-        [("train-step", 0.0, 50.0, None)],
+        [("dispatch", 0.0, 50.0, None)],
     )
     merged = merge_traces([worker, storage, learner])
     assert merged["meta"]["roles"] == ["learner", "storage", "worker"]
@@ -620,14 +620,14 @@ def test_merge_clock_corrects_and_links_flows(tmp_path):
     assert xs["worker-tick"]["ts"] == pytest.approx(0.0)
     assert xs["storage-ingest"]["ts"] == pytest.approx(1500.0)
     assert xs["window-close"]["ts"] == pytest.approx(1600.0)
-    assert xs["train-step"]["ts"] == pytest.approx(2000.0)
+    assert xs["dispatch"]["ts"] == pytest.approx(2000.0)
     # docs get distinct pid lanes even if raw pids collided
     assert len({e["pid"] for e in merged["traceEvents"] if e["ph"] == "X"}) == 3
     flows = [e for e in merged["traceEvents"] if e.get("cat") == "lineage"]
     assert [f["ph"] for f in flows] == ["s", "t", "t", "f"]
     assert all(f["id"] == f"0x{tid42:x}" for f in flows)
     assert [f["args"]["hop"] for f in flows] == [
-        "worker-tick", "storage-ingest", "window-close", "train-step"
+        "worker-tick", "storage-ingest", "window-close", "dispatch"
     ]
     # only the synthesized learner hop is flagged; the finish binds encl.
     assert [f["args"]["synthesized"] for f in flows] == [
@@ -672,7 +672,7 @@ def test_merge_result_dir_and_cli(tmp_path):
             [("storage-ingest", 50.0, 5.0, {"trace_id": 1})],
         ),
         "trace.json": _trace_doc(  # the learner's dump name
-            "learner", 3, R, [("train-step", 100.0, 5.0, None)]
+            "learner", 3, R, [("dispatch", 100.0, 5.0, None)]
         ),
     }
     for name, doc in docs.items():

@@ -202,7 +202,7 @@ def test_cluster_telemetry_scrape_end_to_end(tmp_path):
     assert {"worker", "storage", "learner"} <= roles
     trace = json.loads((tmp_path / "run" / "trace.json").read_text())
     names = {ev["name"] for ev in trace["traceEvents"] if ev["ph"] == "X"}
-    assert {"queue-wait", "train-step"} <= names
+    assert {"feed-wait", "dispatch"} <= names
     assert os.path.getsize(tmp_path / "run" / "telemetry.json") > 0
 
     # ISSUE 5 acceptance: the storage edge auto-merged the fleet trace at
@@ -222,7 +222,7 @@ def test_cluster_telemetry_scrape_end_to_end(tmp_path):
         if ev.get("cat") == "lineage":
             chains.setdefault(ev["id"], []).append(ev["args"]["hop"])
     assert any(
-        {"worker-tick", "storage-ingest", "train-step"} <= set(hops)
+        {"worker-tick", "storage-ingest", "dispatch"} <= set(hops)
         and ("relay-in" in hops or "relay-out" in hops)
         for hops in chains.values()
     ), f"no fully-linked rollout chain: {chains}"

@@ -208,6 +208,23 @@ def test_process_and_device_memory_stats():
     in_use, peak = device_memory_bytes()
     assert in_use > 0 and peak >= in_use  # CPU backend: RSS fallback
 
+    class Chip:
+        """A TPU's two books: live buffers, and the programs' scratch."""
+
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    gb = 1e9
+    chip = Chip({"bytes_in_use": 0.3 * gb, "peak_bytes_in_use": 0.49 * gb,
+                 "peak_bytes_reserved": 7.38 * gb, "bytes_reserved": 0.0})
+    assert device_memory_bytes(chip) == (0.3 * gb, (0.49 + 7.38) * gb)
+    # a runtime that keeps one book only
+    assert device_memory_bytes(Chip({"bytes_in_use": 5.0, "peak_bytes_in_use": 9.0})) == (5.0, 9.0)
+    assert device_memory_bytes(Chip({"bytes_in_use": 5.0})) == (5.0, 5.0)
+
 
 # ---------------------------------------------------------------- slo parse
 def test_slo_spec_parse_grammar():

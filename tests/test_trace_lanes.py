@@ -1,0 +1,345 @@
+"""The chip owner's host lanes (ISSUE 23): one span primitive on the
+profiler's clock, a learner main lane that is covered exhaustively, a
+profiler window that opens once, and the gap attribution that reads it all
+(``benchmarks/hostplane.py``)."""
+
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from benchmarks import hostplane, trace
+from benchmarks.hostplane import Host, Span
+from benchmarks.trace import DeviceTrace, Event
+from tests.conftest import small_config
+from tpu_rl.obs.perf import ProfilerCapture
+from tpu_rl.obs.trace import TraceRecorder
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ------------------------------------------------- (a) capture round trip
+@pytest.mark.timeout(120)
+def test_spans_of_two_threads_round_trip_through_a_capture(tmp_path):
+    """Every span is in the capture under its lane, nested and ordered as it
+    ran, with its arguments — and the ring holds the same instants: placed
+    by its ``capture`` entry it agrees with the capture to microseconds."""
+    rec = TraceRecorder(capacity=256, pid=1, role="learner", annotate=True)
+    prof = ProfilerCapture(str(tmp_path / "prof"), tracer=rec)
+
+    def feeder():
+        for _ in range(3):
+            with rec.span("assemble", tid="feeder"):
+                time.sleep(0.002)
+                with rec.span("h2d-put", tid="feeder"):
+                    time.sleep(0.001)
+
+    try:
+        assert prof.start() is not None
+        th = threading.Thread(target=feeder)
+        th.start()
+        for i in range(4):
+            with rec.span("feed-wait"):
+                time.sleep(0.001)
+            with rec.span("dispatch", args={"update": i}):
+                time.sleep(0.001)
+        th.join()
+        path = prof.stop()
+    finally:
+        prof.close()
+    host = hostplane.load(path)
+    assert set(host.lanes) == {"main", "feeder"}
+    main = host.lane("main")
+    assert [s.name for s in main] == ["feed-wait", "dispatch"] * 4
+    assert [s.args.get("update") for s in main if s.name == "dispatch"] == [0, 1, 2, 3]
+    assert all(a.end <= b.start for a, b in zip(main, main[1:]))
+    fed = host.lane("feeder")
+    assert [s.name for s in fed] == ["assemble", "h2d-put"] * 3
+    for outer, inner in zip(fed[::2], fed[1::2]):
+        assert outer.start <= inner.start and inner.end <= outer.end  # nested
+    assert [s.name for s in hostplane._top_level(fed)] == ["assemble"] * 3
+
+    ring = hostplane.from_ring(rec.to_chrome())
+    assert set(ring.lanes) == {"main", "feeder"}
+    for lane in ("main", "feeder"):
+        a, b = host.lane(lane), ring.lane(lane)
+        assert [s.name for s in a] == [s.name for s in b]
+        for x, y in zip(a, b):
+            # the ring stamps inside the annotation, a few microseconds apart
+            assert abs(x.start - y.start) < 50e3 and abs(x.end - y.end) < 50e3
+    assert [s.args for s in ring.lane("main")] == [s.args for s in main]
+
+
+def test_a_ring_without_a_capture_or_past_it_reads_as_nothing():
+    rec = TraceRecorder(capacity=4, pid=1, annotate=True)
+    with rec.span("dispatch"):
+        pass
+    assert hostplane.from_ring(rec.to_chrome()) is None  # no capture entry
+    rec.add("capture", rec.now() - 1.0, 0.5, tid="profiler")
+    for _ in range(4):  # the ring lets go of the capture and all it covered
+        with rec.span("dispatch"):
+            pass
+    assert hostplane.from_ring(rec.to_chrome()) is None
+    assert hostplane.parse(b"") is None
+
+
+# --------------------------------------------- the primitive's bookkeeping
+def test_one_span_call_feeds_ring_timer_and_ledger():
+    from tpu_rl.obs.goodput import COMPUTE, IDLE, QUEUE_WAIT, GoodputLedger
+    from tpu_rl.utils.timer import ExecutionTimer
+
+    rec = TraceRecorder(capacity=8)
+    rec.timer, rec.ledger = ExecutionTimer(), GoodputLedger("learner")
+    with rec.span("dispatch", timer="learner-step-time", bucket=COMPUTE) as sp:
+        time.sleep(0.002)
+    assert sp.secs >= 0.002
+    assert rec.timer.mean_elapsed("learner-step-time") == sp.secs
+    assert rec.ledger.snapshot()["buckets"]["compute"] == sp.secs
+    # what the block learns decides the accounting, the site is found again
+    # by (lane, name), and a span that is not kept is no ring entry
+    with rec.span("feed-wait", timer="learner-queue-wait-time", bucket=QUEUE_WAIT) as sp:
+        sp.timed, sp.bucket, sp.keep = False, IDLE, False
+    assert rec.timer.mean_elapsed("learner-queue-wait-time") is None
+    buckets = rec.ledger.snapshot()["buckets"]
+    assert buckets["queue-wait"] == 0.0 and buckets["idle"] == sp.secs
+    with rec.span("feed-wait"):
+        pass
+    assert rec.timer.mean_elapsed("learner-queue-wait-time") is not None
+    assert [e["name"] for e in rec.to_chrome()["traceEvents"] if e["ph"] == "X"] == [
+        "dispatch", "feed-wait",
+    ]
+    # without a ring a span still times and accounts
+    bare = TraceRecorder(capacity=0)
+    with bare.span("fetch", tid="feeder") as sp:
+        pass
+    assert len(bare) == 0 and sp.secs >= 0.0
+
+
+def test_export_thread_keeps_the_file_current_without_the_main_lane(tmp_path):
+    rec = TraceRecorder(capacity=16, pid=9, role="learner")
+    path = tmp_path / "trace.json"
+    rec.start_export(str(path), period_s=0.02)
+    try:
+        for i in range(40):  # more than the ring holds
+            with rec.span(f"s{i % 3}", tid="main" if i % 2 else "feeder"):
+                pass
+        deadline = time.monotonic() + 10
+        while not path.exists() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert json.loads(path.read_text())["meta"]["role"] == "learner"
+        with rec.span("last"):  # the pass's own span goes out with the next
+            pass
+    finally:
+        rec.close_export()
+    doc = json.loads(path.read_text())
+    spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    lanes = {
+        e["tid"]: e["args"]["name"] for e in doc["traceEvents"]
+        if e["ph"] == "M" and e["name"] == "thread_name"
+    }
+    assert len(spans) <= 16 and set(lanes.values()) == {"main", "feeder", "exporter"}
+    # the file ends where the ring does (but for the passes' own spans: the
+    # last pass wrote the file before its span closed)
+    assert any(e["name"] == "trace-export" for e in spans)
+    tail = lambda evs: [  # noqa: E731
+        (e["name"], e["ts"]) for e in evs if e["ph"] == "X" and e["name"] != "trace-export"
+    ][-4:]
+    assert tail(spans) == tail(rec.to_chrome()["traceEvents"])
+    assert doc["meta"]["wall_anchor_ns"] == rec.wall_anchor_ns
+
+
+# ------------------------------- (e) roles without a chip stay off jax
+def test_the_recorder_of_a_role_without_a_chip_never_imports_jax():
+    """Worker, manager and storage build ``TraceRecorder()`` and call
+    ``add`` / ``span`` / ``dump``: that path must work where jax cannot even
+    be imported."""
+    code = (
+        "import importlib.util, sys\n"
+        "sys.modules['jax'] = None  # any 'import jax' now raises\n"
+        f"spec = importlib.util.spec_from_file_location('t', {ROOT + '/tpu_rl/obs/trace.py'!r})\n"
+        "t = importlib.util.module_from_spec(spec); spec.loader.exec_module(t)\n"
+        "r = t.TraceRecorder(capacity=4, role='worker')\n"
+        "r.add('worker-tick', r.now(), 0.001)\n"
+        "with r.span('storage-ingest', tid='main'): pass\n"
+        "assert len(r.to_chrome()['traceEvents']) == 4\n"
+        "try:\n"
+        "    t.TraceRecorder(annotate=True)\n"
+        "except ImportError:\n"
+        "    print('ok')\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=60
+    )
+    assert out.stdout.strip() == "ok", out.stderr
+
+
+# ----------------------------------- (d) attribution on a hand-built trace
+def _hand_trace():
+    """Three updates of 100 us; ops leave three gaps in the window [100, 300]:
+    [140, 200] under log-sync, [240, 270] under a feed wait while the feeder
+    assembles, [280, 300] under nothing."""
+    step = lambda t: Event("jit_step", t, 40)  # noqa: E731
+    dev = DeviceTrace(
+        "/device:TPU:0",
+        modules=[step(0), step(100), step(200), step(300)],
+        ops=[Event("fusion.1", 100, 40), Event("fusion.2", 200, 40),
+             Event("fusion.3", 270, 10)],
+    )
+    us = lambda name, a, b, **kw: Span(name, a, b - a, kw)  # noqa: E731
+    host = Host({
+        "main": [
+            us("dispatch", -10, -5, update=1), us("dispatch", 90, 95, update=2),
+            us("log-sync", 96, 195), us("dispatch", 196, 198, update=3),
+            us("feed-wait", 199, 268), us("dispatch", 268, 270, update=4),
+        ],
+        "feeder": [us("store-empty", 190, 245), us("assemble", 245, 266),
+                   us("h2d-put", 255, 266), us("queue-put", 266, 267)],
+    })
+    return trace.Trace([dev]), host
+
+
+def test_gaps_take_the_name_of_the_span_that_covers_them():
+    tr, host = _hand_trace()
+    assert tr.devices[0].window == (100, 300)
+    assert host.clock_ok(tr)
+    assert host.idle_gaps(tr) == [
+        ["log-sync", 60e-9], ["feed-wait>assemble", 30e-9], ["unattributed", 20e-9],
+    ]
+    # the bench's own gaps, in the same order
+    assert [s for _, s in host.idle_gaps(tr)] == [s for _, s in tr.breakdown()["idle_gaps"]]
+    # all of [140, 200] but the 2 ns between spans, [240, 270], none of [280, 300]
+    assert host.attributed_share(tr) == pytest.approx((58 + 30) / 110)
+    # per update (2 in the window): main outside waits and syncs, and waits
+    assert host.per_update_ms(tr, "main", but=hostplane.FEED_WAITS + hostplane.DEVICE_SYNCS) \
+        == pytest.approx((2 + 2) / 2 / 1e6)
+    assert host.lane_ns(tr, "main", names=hostplane.FEED_WAITS) == 69
+    assert host.per_update_ms(tr, "feeder", names=("assemble",)) == pytest.approx(21 / 2 / 1e6)
+
+
+@pytest.mark.parametrize("skew_ns", [+120, -60])
+def test_a_skewed_clock_fails_the_check_and_names_nothing(skew_ns):
+    """Host spans 120 ns late: a dispatch begins after its execution started.
+    60 ns early: a log-sync ends before the execution it waited for."""
+    tr, host = _hand_trace()
+    for spans in host.lanes.values():
+        for s in spans:
+            s.start += skew_ns
+    assert not host.clock_ok(tr)
+    assert {name for name, _ in host.idle_gaps(tr)} == {"unattributed"}
+    assert host.attributed_share(tr) == 0.0
+
+
+# --------------------------------- (b) (c) the learner's main lane, on CPU
+def _run_learner(tmp_path, port, n_updates, **kw):
+    from tpu_rl.data.layout import BatchLayout
+    from tpu_rl.data.shm_ring import OnPolicyStore, alloc_handles
+    from tpu_rl.runtime.learner_service import LearnerService
+    from tpu_rl.types import BATCH_FIELDS
+
+    B = 16
+    cfg = small_config(
+        env="CartPole-v1", algo="PPO", batch_size=B, seq_len=16, hidden_size=64,
+        learner_device="cpu", result_dir=str(tmp_path / "run"),
+        model_dir=str(tmp_path / "models"), model_save_interval=8,
+        loss_log_interval=4, telemetry_interval_s=0.05, **kw,
+    )
+    layout = BatchLayout.from_config(cfg)
+    handles = alloc_handles(layout, capacity=B)
+    store = OnPolicyStore(handles, layout)
+    rng = np.random.default_rng(7)
+    window = {}
+    for f in BATCH_FIELDS:
+        shape = (layout.seq_len, layout.width(f))
+        if f == "act":
+            window[f] = rng.integers(0, 2, size=shape).astype(np.float32)
+        elif f == "is_fir":
+            window[f] = np.zeros(shape, np.float32)
+            window[f][0] = 1.0
+        elif f == "log_prob":
+            window[f] = np.full(shape, -0.7, np.float32)
+        else:
+            window[f] = rng.standard_normal(shape).astype(np.float32) * 0.1
+    stop = threading.Event()
+
+    def feed():
+        while not stop.is_set():
+            if not store.put(window):
+                time.sleep(0.0005)
+
+    th = threading.Thread(target=feed, daemon=True)
+    th.start()
+    svc = LearnerService(
+        cfg, handles, model_port=port, stop_event=stop, max_updates=n_updates,
+        seed=0, stat_port=port + 1,
+    )
+    try:
+        svc.run()
+    finally:
+        stop.set()
+        th.join(timeout=30)
+    return svc, cfg
+
+
+@pytest.mark.timeout(300)
+def test_learner_main_lane_is_exhaustive_and_the_ledger_still_sums(tmp_path):
+    svc, cfg = _run_learner(tmp_path, 29731, n_updates=40)
+    doc = json.loads((tmp_path / "run" / "trace.json").read_text())
+    lanes = {
+        e["tid"]: e["args"]["name"] for e in doc["traceEvents"]
+        if e["ph"] == "M" and e["name"] == "thread_name"
+    }
+    by_lane: dict = {}
+    for e in doc["traceEvents"]:
+        if e["ph"] == "X":
+            by_lane.setdefault(lanes[e["tid"]], []).append(e)
+    assert {"main", "feeder", "publisher", "ckpt-writer", "exporter"} <= set(by_lane)
+    assert {e["name"] for e in by_lane["publisher"]} == {"publish-d2h", "publish-send"}
+    assert {e["name"] for e in by_lane["ckpt-writer"]} == {"ckpt-d2h", "ckpt-write"}
+    assert {"fetch", "assemble", "h2d-put", "queue-put"} <= {e["name"] for e in by_lane["feeder"]}
+    main = sorted(by_lane["main"], key=lambda e: e["ts"])
+    names = {e["name"] for e in main}
+    assert {
+        "feed-wait", "rng-split", "program-record", "dispatch", "diag-fold",
+        "account", "publish", "telemetry-emit", "log-sync", "log-write",
+        "diag-drain", "ckpt-save", "heartbeat",
+    } <= names
+    dispatches = [e for e in main if e["name"] == "dispatch"]
+    assert [e["args"]["update"] for e in dispatches] == list(range(1, 41))
+    # none nests in another, and from the first dispatch to the last they
+    # cover the wall time (the first dispatches compile: left out)
+    lo, hi = dispatches[5]["ts"], dispatches[-1]["ts"]
+    inside = [e for e in main if lo <= e["ts"] < hi]
+    assert all(a["ts"] + a["dur"] <= b["ts"] + 1e-3 for a, b in zip(inside, inside[1:]))
+    covered = sum(e["dur"] for e in inside)
+    assert covered / (hi - lo) >= 0.98, covered / (hi - lo)
+    # the same spans fed the timers the benchmark reads, and the ledger
+    assert svc.timer.mean_elapsed("learner-step-time") > 0
+    assert svc.timer.mean_elapsed("learner-queue-wait-time") >= 0
+    assert svc.timer.mean_elapsed("learner-batching-time") > 0
+    snap = svc.ledger.snapshot()
+    assert snap["overcommit_ratio"] <= 0.01
+    assert sum(snap["buckets"].values()) == pytest.approx(snap["elapsed_s"], rel=0.01)
+    assert snap["buckets"]["compute"] > 0 and snap["buckets"]["wire"] > 0
+    assert snap["buckets"]["ckpt"] > 0
+
+
+@pytest.mark.timeout(300)
+def test_the_profiler_window_opens_one_capture(tmp_path):
+    svc, cfg = _run_learner(
+        tmp_path, 29741, n_updates=3 + 4 + 10,
+        profile_dir=str(tmp_path / "prof"), profile_start=3, profile_steps=4,
+    )
+    assert svc._prof_capture.n_captures == 1
+    assert len(os.listdir(tmp_path / "prof")) == 1
+    host = hostplane.load(str(tmp_path / "prof"))
+    dispatched = [s.args["update"] for s in host.lane("main") if s.name == "dispatch"]
+    assert dispatched == [4, 5, 6, 7]  # opened after update 3, closed after 7
+    ring = hostplane.from_ring(svc._tracer.to_chrome())
+    assert [s.args["update"] for s in ring.lane("main") if s.name == "dispatch"] == dispatched
+    window = [s for s in ring.lane("main") if s.name == "profiler-window"]
+    assert len(window) == 2  # the start's span and the stop's, and no third

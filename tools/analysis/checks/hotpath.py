@@ -70,6 +70,16 @@ MANIFEST: dict[str, dict[str, str]] = {
         # recv/ingest pass): one float add, no allocation.
         "GoodputLedger.add": STRICT,
     },
+    "tpu_rl/obs/trace.py": {
+        # The span primitive rides every statement group of the learner's
+        # loop and its feeder/publisher/writer lanes: a clock pair, one small
+        # object, one ring tuple. The annotation's name is built once per
+        # site in the cold _site(), never here.
+        "TraceRecorder.span": STRICT,
+        "TraceRecorder.add": STRICT,
+        "Span.__enter__": STRICT,
+        "Span.__exit__": STRICT,
+    },
     "tpu_rl/runtime/worker.py": {
         "Worker.run": FMT,
     },

@@ -34,7 +34,15 @@ INVENTORY: dict[str, dict[str, frozenset[str]]] = {
     "tpu_rl/data/prefetch.py": {
         # _error: single-writer slot drained by the consumer after the
         # sentinel; queue handoff orders the publication.
-        "PrefetchPipeline._run": frozenset({"_error"}),
+        # keep: a flag of the feeder's own open Span (obs/trace.py), an
+        # object no other thread ever holds.
+        "PrefetchPipeline._run": frozenset({"_error", "keep"}),
+    },
+    "tpu_rl/obs/trace.py": {
+        # The trace.json writer: its cursor and fragments are its own
+        # (flush() runs here, and once more in close() after the join); it
+        # reads the ring under the recorder's lock.
+        "_Exporter._run": frozenset(),
     },
     "tpu_rl/checkpoint.py": {
         # Every shared write happens under self._cond by construction.

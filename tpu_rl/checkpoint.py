@@ -51,6 +51,7 @@ import jax.numpy as jnp
 # Config.validate can enforce the population plane's searchable-field rule
 # against it; re-exported here under the historical name.
 from tpu_rl.config import FINGERPRINT_FIELDS as _FINGERPRINT_FIELDS
+from tpu_rl.obs.trace import span_of
 
 # Marker filename inside a committed checkpoint dir. Its presence is the
 # commit point; its content is the run-meta JSON. Orbax ignores foreign
@@ -202,6 +203,9 @@ def restore_actor_params(model_dir: str, algo: str):
     return None
 
 
+LANE = "ckpt-writer"
+
+
 def _snapshot(state: Any) -> Any:
     """Donation-proof device-side copy with D2H started in the background
     (the AsyncPublisher recipe): the caller's buffers may be donated to the
@@ -234,7 +238,11 @@ class Checkpointer:
         algo: str,
         keep: int = 5,
         async_save: bool = False,
+        tracer=None,
     ):
+        # The owner's TraceRecorder: a save's blocking D2H and its disk write
+        # are the spans "ckpt-d2h" / "ckpt-write" of the lane "ckpt-writer".
+        self._span = span_of(tracer)
         self.model_dir = os.path.abspath(model_dir)
         self.algo = algo
         self.keep = max(1, int(keep))
@@ -310,7 +318,7 @@ class Checkpointer:
         meta = dict(meta or {})
         if not self.async_save:
             t0 = time.perf_counter()
-            self._write(jax.device_get(state), idx, meta)
+            self._write(self._to_host(state), idx, meta)
             self._record(time.perf_counter() - t0)
             return path
         snap = _snapshot(state)
@@ -334,7 +342,7 @@ class Checkpointer:
                 self._inflight = True
             t0 = time.perf_counter()
             try:
-                self._write(jax.device_get(snap), idx, meta)
+                self._write(self._to_host(snap), idx, meta)
                 dur: float | None = time.perf_counter() - t0
             except Exception as e:  # surfaced on the next save()/flush()
                 dur = None
@@ -346,21 +354,26 @@ class Checkpointer:
                     self._record(dur)
                 self._cond.notify_all()
 
+    def _to_host(self, state: Any) -> Any:
+        with self._span("ckpt-d2h", tid=LANE):
+            return jax.device_get(state)
+
     def _write(self, host_state: Any, idx: int, meta: dict) -> None:
         """The two-phase commit: orbax tree write, then the atomic marker."""
-        path = os.path.join(self.model_dir, f"{self.algo}_{idx}")
-        self._ckpt.save(path, host_state, force=True)
-        self._ckpt.wait_until_finished()
-        meta.setdefault("idx", idx)
-        meta.setdefault("algo", self.algo)
-        meta.setdefault("saved_at", time.time())
-        tmp = os.path.join(path, f".{COMMIT_MARKER}.tmp")
-        with open(tmp, "w") as f:
-            json.dump(meta, f)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, os.path.join(path, COMMIT_MARKER))
-        self._gc()
+        with self._span("ckpt-write", tid=LANE):
+            path = os.path.join(self.model_dir, f"{self.algo}_{idx}")
+            self._ckpt.save(path, host_state, force=True)
+            self._ckpt.wait_until_finished()
+            meta.setdefault("idx", idx)
+            meta.setdefault("algo", self.algo)
+            meta.setdefault("saved_at", time.time())
+            tmp = os.path.join(path, f".{COMMIT_MARKER}.tmp")
+            with open(tmp, "w") as f:
+                json.dump(meta, f)
+                f.flush()
+                os.fsync(f.fileno())
+            os.replace(tmp, os.path.join(path, COMMIT_MARKER))
+            self._gc()
 
     def _record(self, dur: float) -> None:
         self.n_saves += 1

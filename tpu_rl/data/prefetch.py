@@ -37,6 +37,13 @@ semantics.
 This module is host-only plumbing (threads + queue); JAX enters only through
 the ``assemble`` callable the learner supplies, so the data layer keeps its
 "never imports jax" property (see ``tpu_rl/config.py``).
+
+Both feeds time their work with the spans of the ``TraceRecorder`` the learner
+hands them (lane ``feeder``: ``fetch``, ``store-empty``, ``assemble`` — which
+encloses the ``h2d-put`` the learner's ``assemble`` callable opens — and
+``queue-put``); ``feed_secs``, the ``learner-batching-time`` sample, is the sum
+of the fetch and assemble spans of one dispatch. Without a recorder (the unit tests) a
+private one with no ring does the timing.
 """
 
 from __future__ import annotations
@@ -45,6 +52,14 @@ import queue
 import threading
 import time
 from typing import Callable
+
+LANE = "feeder"
+
+
+def _span_of(tracer):
+    from tpu_rl.obs.trace import span_of  # not at import: obs pulls in jax
+
+    return span_of(tracer)
 
 
 class UpdateRatioGate:
@@ -97,10 +112,13 @@ class SynchronousFeed:
 
     poll_sleep = 0.002  # caller sleeps this on a None get (store starving)
 
-    def __init__(self, fetch: Callable, assemble: Callable, chain: int = 1):
+    def __init__(
+        self, fetch: Callable, assemble: Callable, chain: int = 1, tracer=None
+    ):
         self._fetch = fetch
         self._assemble = assemble
         self._chain = max(1, chain)
+        self._span = _span_of(tracer)
         self._pending: list = []
         self._secs = 0.0  # fetch+assemble seconds toward the next dispatch
 
@@ -109,16 +127,17 @@ class SynchronousFeed:
         store cannot yet fill the dispatch. ``timeout`` is accepted for
         interface parity and ignored (fetch never blocks)."""
         while len(self._pending) < self._chain:
-            t0 = time.perf_counter()
-            raw = self._fetch()
+            with self._span("fetch", tid=LANE) as sp:
+                raw = self._fetch()
+                sp.keep = raw is not None
             if raw is None:
                 return None
-            self._secs += time.perf_counter() - t0
+            self._secs += sp.secs
             self._pending.append(raw)
-        t0 = time.perf_counter()
-        batch = self._assemble(self._pending)
+        with self._span("assemble", tid=LANE) as sp:
+            batch = self._assemble(self._pending)
         self._pending = []
-        secs, self._secs = self._secs + (time.perf_counter() - t0), 0.0
+        secs, self._secs = self._secs + sp.secs, 0.0
         return batch, secs
 
     def qsize(self) -> int:
@@ -148,12 +167,14 @@ class PrefetchPipeline:
         stop_event=None,
         idle_sleep: float = 0.002,
         name: str = "learner-prefetch",
+        tracer=None,
     ):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
         self._fetch = fetch
         self._assemble = assemble
         self._chain = max(1, chain)
+        self._span = _span_of(tracer)
         self._stop_event = stop_event
         self._idle_sleep = idle_sleep
         self._q: queue.Queue = queue.Queue(maxsize=depth)
@@ -170,36 +191,49 @@ class PrefetchPipeline:
         )
 
     def _run(self) -> None:
+        span = self._span
         pending: list = []
         feed_secs = 0.0
+        empty = None  # the open store-empty span while polls come back empty
         try:
             while not self._stopped():
-                t0 = time.perf_counter()
-                raw = self._fetch()
+                with span("fetch", tid=LANE) as sp:
+                    raw = self._fetch()
+                    sp.keep = raw is not None
                 if raw is None:
                     # store starving (or the update-ratio gate holding):
-                    # idle spans never count toward the dispatch's feed time
+                    # idle time never counts toward the dispatch's feed
+                    # time. One span per run of empty polls, not per poll.
+                    if empty is None:
+                        empty = span("store-empty", tid=LANE)
+                        empty.__enter__()
                     time.sleep(self._idle_sleep)
                     continue
-                feed_secs += time.perf_counter() - t0
+                if empty is not None:
+                    empty.__exit__(None, None, None)
+                    empty = None
+                feed_secs += sp.secs
                 pending.append(raw)
                 if len(pending) < self._chain:
                     continue
-                t0 = time.perf_counter()
-                batch = self._assemble(pending)
+                with span("assemble", tid=LANE) as sp:
+                    batch = self._assemble(pending)
                 pending = []
-                feed_secs += time.perf_counter() - t0
-                item = (batch, feed_secs)
+                item = (batch, feed_secs + sp.secs)
                 feed_secs = 0.0
                 # stop-aware put: a full queue must never deadlock shutdown
-                while not self._stopped():
-                    try:
-                        self._q.put(item, timeout=0.05)
-                        break
-                    except queue.Full:
-                        continue
+                with span("queue-put", tid=LANE):
+                    while not self._stopped():
+                        try:
+                            self._q.put(item, timeout=0.05)
+                            break
+                        except queue.Full:
+                            continue
         except BaseException as e:  # noqa: BLE001 — re-raised in the learner
             self._error = e
+        finally:
+            if empty is not None:
+                empty.__exit__(None, None, None)
 
     # ------------------------------------------------------------ consumer
     def get(self, timeout: float = 0.05):

@@ -17,7 +17,8 @@ module folds those rings onto ONE timeline:
    per trace id in corrected-time order and joined with Chrome flow events
    (``ph: s/t/f``), which Perfetto renders as linked arrows. The learner
    hop is synthesized: the shm data plane carries no per-window metadata,
-   so the chain is closed onto the first ``train-step`` span that begins
+   so the chain is closed onto the first ``dispatch`` span of the learner's
+   main lane (``LEARNER_HOP``: the train step's launch) that begins
    after the chain's ``window-close`` (flagged ``synthesized: true`` in the
    flow args — it is a plausible consumer, not a measured identity).
 
@@ -42,8 +43,10 @@ _HOP_ORDER = {
     "relay-out": 2,
     "storage-ingest": 3,
     "window-close": 4,
-    "train-step": 5,
+    "dispatch": 5,
 }
+# The learner-ring span a chain is closed onto: the train step's launch.
+LEARNER_HOP = "dispatch"
 
 
 def load_trace(path: str) -> dict | None:
@@ -116,7 +119,7 @@ def merge_traces(docs: list[dict]) -> dict:
                         chains.setdefault(trace_id, []).append(
                             (ts, _HOP_ORDER.get(name, 9), pid, tid, name, dur)
                         )
-                if name == "train-step":
+                if name == LEARNER_HOP:
                     train_steps.append((ts, pid, tid, dur))
             events.append(out)
 
@@ -128,17 +131,17 @@ def merge_traces(docs: list[dict]) -> dict:
         }
 
     # Close each chain onto a plausible learner consumer: the first
-    # train-step beginning at or after the chain's last measured hop.
+    # dispatch beginning at or after the chain's last measured hop.
     train_steps.sort()
     for hops in chains.values():
         hops.sort()
-        if not train_steps or hops[-1][4] == "train-step":
+        if not train_steps or hops[-1][4] == LEARNER_HOP:
             continue
         t_last = hops[-1][0]
         nxt = next((t for t in train_steps if t[0] >= t_last), None)
         if nxt is not None:
             ts, pid, tid, dur = nxt
-            hops.append((ts, _HOP_ORDER["train-step"], pid, tid, "train-step", dur))
+            hops.append((ts, _HOP_ORDER[LEARNER_HOP], pid, tid, LEARNER_HOP, dur))
 
     # Normalize the axis so the merged trace starts near zero.
     t0 = min(ev["ts"] for ev in events if ev.get("ph") == "X")
@@ -170,7 +173,7 @@ def merge_traces(docs: list[dict]) -> dict:
                 "args": {
                     "trace_id": trace_id,
                     "hop": name,
-                    "synthesized": name == "train-step",
+                    "synthesized": name == LEARNER_HOP,
                 },
             }
             if ph == "f":

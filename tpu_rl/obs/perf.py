@@ -26,8 +26,9 @@ telemetry on reports them continuously:
   FLOPs/s per device) is the CPU-smoke denominator only — it's what lets
   CPU smokes exercise the MFU path, and it is ignored on any other backend.
 - :func:`device_memory_bytes` — in-use/peak watermarks from
-  ``device.memory_stats()``; backends that report none (CPU) fall back to
-  process RSS with a module-tracked high-water mark.
+  ``device.memory_stats()`` (the peak counts live buffers and the programs'
+  reserved scratch); backends that report none (CPU) fall back to process
+  RSS with a module-tracked high-water mark.
 - :func:`process_self_stats` — RSS + open-fd count from ``/proc/self``
   (no psutil), cheap enough to refresh on the telemetry emit cadence.
 - :class:`ProfilerCapture` — the one gate every profiler path goes
@@ -134,7 +135,13 @@ def device_memory_bytes(device=None) -> tuple[float, float]:
         stats = None
     if stats:
         in_use = float(stats.get("bytes_in_use", 0.0))
-        peak = float(stats.get("peak_bytes_in_use", in_use))
+        # The TPU runtime keeps two books: live buffers (peak_bytes_in_use)
+        # and the scratch reserved for running programs
+        # (peak_bytes_reserved: activations and temporaries). Both peak
+        # while an update runs, so the peak is their sum.
+        peak = float(stats.get("peak_bytes_in_use", in_use)) + float(
+            stats.get("peak_bytes_reserved", 0.0)
+        )
         return in_use, peak
     rss, _ = process_self_stats()
     _rss_peak = max(_rss_peak, rss)
@@ -257,6 +264,44 @@ def maybe_perf_tracker(cfg) -> PerfTracker | None:
 
 
 # --------------------------------------------------------- profiler capture
+def _options():
+    """What a capture records. The Python tracer is off: at its default
+    level every Python call of every thread is an event, which slows the
+    host loop the capture is there to observe (PERF.md section 5 has the
+    traced-vs-untraced periods per option). ``TraceAnnotation``s — the
+    ``tpu_rl/<lane>/<name>`` spans of ``obs/trace.py`` — and the device
+    planes need host tracer level 1 only."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    return options
+
+
+def session_span_ns(trace_dir: str) -> tuple[int, int] | None:
+    """(start, stop) of a finished capture in unix nanoseconds, from the
+    ``Task Environment`` plane of its ``.xplane.pb``. Every timestamp in
+    the capture is relative to that start, and a ``TraceAnnotation`` is
+    stamped with ``time.time_ns()``'s clock: with the start, spans a
+    ``TraceRecorder`` stamped outside the capture land on its axis."""
+    import glob
+
+    import jax
+
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    if not files:
+        return None
+    for plane in jax.profiler.ProfileData.from_file(files[0]).planes:
+        if plane.name == "Task Environment":
+            stats = dict(plane.stats)
+            start = stats.get("profile_start_time")
+            stop = stats.get("profile_stop_time")
+            if start and stop:
+                return int(start), int(stop)
+    return None
+
+
 class ProfilerCapture:
     """Serialized ``jax.profiler`` trace capture into ``out_dir``.
 
@@ -267,14 +312,24 @@ class ProfilerCapture:
     409). A crash hook registered with the flight recorder guarantees
     ``stop_trace()`` runs on fatal exceptions, so the capture that was
     meant to explain the crash survives it.
+
+    To see host and device on one timeline, open the capture's directory in
+    TensorBoard's profile plugin or Perfetto: the ``tpu_rl/<lane>/<name>``
+    spans of ``obs/trace.py`` are on the host threads' lines, the device ops
+    on the chips' planes, on one clock.
     """
 
-    def __init__(self, out_dir: str, default_ms: int = 500):
+    def __init__(self, out_dir: str, default_ms: int = 500, tracer=None):
         self.out_dir = out_dir
         self.default_ms = int(default_ms)
         self._lock = threading.Lock()
         self._active: str | None = None  # trace dir while capturing
         self.n_captures = 0
+        # The chip owner's TraceRecorder: each finished capture is entered in
+        # its ring as the span "capture" of the lane "profiler", from the
+        # session's own start to its stop, so a ring dump carries the offset
+        # between the capture's time axis and the clock of its spans.
+        self._tracer = tracer
         flightrec.add_crash_hook(self._crash_stop)
 
     @property
@@ -293,7 +348,7 @@ class ProfilerCapture:
             )
             os.makedirs(path, exist_ok=True)
             try:
-                jax.profiler.start_trace(path)
+                jax.profiler.start_trace(path, profiler_options=_options())
             except Exception:  # noqa: BLE001 — profiling is best-effort
                 return None
             self._active = path
@@ -311,6 +366,15 @@ class ProfilerCapture:
             try:
                 jax.profiler.stop_trace()
                 self.n_captures += 1
+                session = (
+                    session_span_ns(path) if self._tracer is not None else None
+                )
+                if session is not None:
+                    start, stop_ns = session
+                    self._tracer.add(
+                        "capture", start / 1e9, (stop_ns - start) / 1e9,
+                        tid="profiler", args={"dir": path},
+                    )
             except Exception:  # noqa: BLE001
                 path = None
             finally:

@@ -91,8 +91,9 @@ class AsyncPublisher:
     A send failure re-raises out of the next ``publish()``.
     """
 
-    def __init__(self, pub: Pub):
+    def __init__(self, pub: Pub, tracer):
         self._pub = pub
+        self._span = tracer.span  # lane "publisher"
         self._cond = threading.Condition()
         self._pending = None
         self._error: BaseException | None = None
@@ -125,25 +126,31 @@ class AsyncPublisher:
                     return
                 (snap, ver, epoch), self._pending = self._pending, None
             try:
+                # The wait for the update that produced the weights, and for
+                # their transfer.
+                with self._span("publish-d2h", tid="publisher"):
+                    actor = jax.device_get(snap)
                 # "ver" is the learner update index that produced these
                 # weights: workers echo it through their rollouts so storage
                 # can measure per-worker policy staleness (tpu_rl.obs).
                 # "epoch" is the run epoch (bumped on every checkpoint
                 # resume): workers adopt and echo it so storage can fence
                 # out frames acted under a pre-crash learner incarnation.
-                self._pub.send(
-                    Protocol.Model,
-                    {
-                        "actor": jax.device_get(snap),
-                        "ver": ver,
-                        "epoch": epoch,
-                        # Clock-sync echo origin (t0): workers pair this with
-                        # their receive time and ship both back on their
-                        # Telemetry snapshots, closing the NTP round trip at
-                        # the storage edge (tpu_rl.obs.clocksync).
-                        "t_tx": time.time_ns(),
-                    },
-                )
+                with self._span("publish-send", tid="publisher"):
+                    self._pub.send(
+                        Protocol.Model,
+                        {
+                            "actor": actor,
+                            "ver": ver,
+                            "epoch": epoch,
+                            # Clock-sync echo origin (t0): workers pair this
+                            # with their receive time and ship both back on
+                            # their Telemetry snapshots, closing the NTP
+                            # round trip at the storage edge
+                            # (tpu_rl.obs.clocksync).
+                            "t_tx": time.time_ns(),
+                        },
+                    )
             except BaseException as e:  # noqa: BLE001 — surfaces in publish()
                 self._error = e
                 return
@@ -186,7 +193,7 @@ class LearnerService:
         self.stat_port = stat_port
         self._publisher: AsyncPublisher | None = None
         self._inference = None  # InferenceService when act_mode="remote"
-        self._tracer = None  # TraceRecorder when result_dir is set
+        self._tracer = None  # TraceRecorder of this process, set by run()
         self._perf = None  # PerfTracker when telemetry is on
         self._prof_capture = None  # ProfilerCapture when any capture path is
         # Idle-rebroadcast odometer: model publishes fired from the starving
@@ -229,6 +236,28 @@ class LearnerService:
 
         from tpu_rl.algos.registry import get_algo
         from tpu_rl.checkpoint import Checkpointer
+
+        # Span tracing (tpu_rl.obs.trace): every statement of the loop below
+        # runs inside one named span of the "main" lane; the feeder,
+        # publisher and checkpoint-writer threads have lanes of their own. A
+        # span is a jax.profiler.TraceAnnotation (on the device trace's clock
+        # whenever any capture is open), a ring entry, and — where the site
+        # names them — the ExecutionTimer window and the goodput bucket. The
+        # ring and its trace.json export (a thread of the recorder's own)
+        # exist only with a result_dir; the annotations always do.
+        from tpu_rl.obs import TraceRecorder, flightrec
+
+        tracer = self._tracer = TraceRecorder(
+            capacity=cfg.trace_capacity if cfg.result_dir is not None else 0,
+            pid=os.getpid(),
+            role="learner",
+            annotate=True,
+        )
+        if cfg.result_dir is not None:
+            flightrec.install(
+                "learner", cfg.result_dir, tracer=tracer, cfg=cfg
+            )
+            tracer.start_export(os.path.join(cfg.result_dir, "trace.json"))
         layout = BatchLayout.from_config(cfg)
         store = make_store(cfg, layout, handles=self.handles)
         off_policy = is_off_policy(cfg.algo)
@@ -290,6 +319,7 @@ class LearnerService:
                 cfg.algo,
                 keep=cfg.ckpt_keep,
                 async_save=cfg.ckpt_async,
+                tracer=tracer,
             )
             restored = ckpt.restore_run(
                 state, fingerprint=fingerprint, force=cfg.resume_force
@@ -386,7 +416,7 @@ class LearnerService:
         # Async broadcast rides the same switch as the feed pipeline so
         # learner_prefetch=0 is a FULLY serial A/B baseline.
         self._publisher = (
-            AsyncPublisher(pub) if cfg.learner_prefetch > 0 else None
+            AsyncPublisher(pub, tracer) if cfg.learner_prefetch > 0 else None
         )
         writer = make_writer(cfg.result_dir)
         logger = LearnerLogger(writer, cfg.algo)
@@ -395,34 +425,36 @@ class LearnerService:
         # the same port every other role's telemetry already converges on.
         # None when disabled: the hot loop then pays one `is None` check per
         # update and opens no extra socket (pinned by tests/test_obs.py).
+        from tpu_rl.obs.goodput import (
+            CKPT,
+            COMPUTE,
+            H2D,
+            IDLE,
+            QUEUE_WAIT,
+            RECOMPILE,
+            ROLLBACK,
+            WIRE,
+            GoodputLedger,
+        )
+
         telem_reg = telem_pub = None
         telem_last = float("-inf")
         self._perf = None
-        ledger = self.ledger = None
+        self.ledger = None
+        # With prefetch the pop wait is residual feed latency (queue-wait);
+        # the synchronous feed does the shm copy + H2D inside get(), so the
+        # same span is h2d there.
+        wait_bucket = QUEUE_WAIT if cfg.learner_prefetch > 0 else H2D
         if cfg.telemetry_enabled and self.stat_port is not None:
             from tpu_rl.obs import MetricsRegistry
-            from tpu_rl.obs.goodput import (
-                CKPT,
-                COMPUTE,
-                H2D,
-                IDLE,
-                QUEUE_WAIT,
-                RECOMPILE,
-                ROLLBACK,
-                WIRE,
-                GoodputLedger,
-            )
             from tpu_rl.obs.perf import PerfTracker
 
             telem_reg = MetricsRegistry(role="learner")
             # Goodput ledger (tpu_rl.obs.goodput): exhaustive wall-clock
             # attribution for THIS thread only — feeder / async-ckpt-writer /
             # async-publisher lanes overlap the device step and would
-            # double-count. With prefetch the pop wait is residual feed
-            # latency (queue-wait); the synchronous feed does the shm copy +
-            # H2D inside get(), so the same span is h2d there.
-            ledger = self.ledger = GoodputLedger("learner")
-            wait_bucket = QUEUE_WAIT if cfg.learner_prefetch > 0 else H2D
+            # double-count, so only "main"-lane span sites name a bucket.
+            self.ledger = tracer.ledger = GoodputLedger("learner")
             # Live performance plane (tpu_rl.obs.perf): FLOPs/MFU from a
             # one-time AOT cost analysis of train_step, recompile and
             # device-memory watermarks on the emit cadence. None when
@@ -434,21 +466,6 @@ class LearnerService:
             telem_pub = make_data_pub(
                 cfg, "127.0.0.1", self.stat_port, bind=False
             )
-        # Span tracing: ring buffer over the batch timeline (assemble ->
-        # queue-wait -> H2D -> train_step -> broadcast), dumped as Chrome
-        # trace-event JSON at result_dir/trace.json on every loss-log flush.
-        # The deep-dive companion is the jax.profiler window below
-        # (profile_dir/profile_start/profile_steps).
-        if cfg.result_dir is not None:
-            from tpu_rl.obs import TraceRecorder, flightrec
-
-            self._tracer = TraceRecorder(
-                capacity=cfg.trace_capacity, pid=os.getpid(), role="learner"
-            )
-            flightrec.install(
-                "learner", cfg.result_dir, tracer=self._tracer, cfg=cfg
-            )
-        tracer = self._tracer
         # Profiler capture gate (tpu_rl.obs.perf.ProfilerCapture): ONE
         # serialized gate for the config window below, `kill -USR2 <pid>`
         # (mirroring the flight recorder's SIGUSR1), and the telemetry
@@ -460,7 +477,8 @@ class LearnerService:
             from tpu_rl.obs.perf import ProfilerCapture
 
             prof_capture = self._prof_capture = ProfilerCapture(
-                cfg.profile_dir or os.path.join(cfg.result_dir, "prof")
+                cfg.profile_dir or os.path.join(cfg.result_dir, "prof"),
+                tracer=tracer,
             )
             prof_capture.install_sigusr2()
         # One timed window per DISPATCH; a chained dispatch carries
@@ -468,7 +486,7 @@ class LearnerService:
         # (examples/run_tpu_e2e_learner.py) can read the steady-state
         # windowed rates after run() — the window excludes idle polls and
         # dilutes the first dispatch's compile across the deque.
-        timer = self.timer = ExecutionTimer(
+        timer = self.timer = tracer.timer = ExecutionTimer(
             num_transition=cfg.seq_len * cfg.batch_size * chain
         )
         key = jax.random.key(self.seed + 1)
@@ -528,8 +546,10 @@ class LearnerService:
         # rather than their own random init. It answers any join request
         # already pending (a respawned learner typically finds the flag
         # raised: storage re-registered every worker while it was booting).
-        self._publish(pub, state, ver=start_idx)
-        self._consume_join_flag()
+        span = tracer.span
+        with span("publish", bucket=WIRE):
+            self._publish(pub, state, ver=start_idx)
+            self._consume_join_flag()
         last_pub_m = time.monotonic()
 
         if (
@@ -591,8 +611,15 @@ class LearnerService:
         # pops ONE device-ready dispatch batch per iteration.
         feed = self._make_feed(store, rng, chain)
         idx = start_idx
-        profiling = False
+        # The profiler window opens once and closes once: None until its
+        # capture opens, True while it runs, False for the rest of the run.
+        profiling = None if cfg.profile_dir is not None else False
         try:
+            # Between one dispatch and the next, every statement below runs
+            # inside exactly one span of the "main" lane (none nests in
+            # another), so an idle gap of the device has a name. Sites that
+            # feed a timer window or a goodput bucket say so; what names no
+            # bucket is the ledger's "overhead".
             while not self._stopped():
                 # A dispatch always advances the counter by `chain`, so stop
                 # before one that would exceed the budget (never overshoot;
@@ -608,231 +635,220 @@ class LearnerService:
                 # window. A successful pop's bounded wait IS counted — with
                 # prefetch it is the pipeline's residual feed latency, the
                 # honest critical-path cost of a dispatch.
-                t_wait = time.perf_counter()
-                item = feed.get(timeout=0.05)
+                with span(
+                    "feed-wait", timer="learner-queue-wait-time",
+                    bucket=wait_bucket,
+                ) as sp_wait:
+                    item = feed.get(timeout=0.05)
+                    if item is None:
+                        sp_wait.timed = False
+                        sp_wait.bucket = IDLE
                 if item is None:
-                    if self.heartbeat is not None:
-                        self.heartbeat.value = time.time()
-                    # Idle rebroadcast (chaos-plane hardening): a PUB frame
-                    # is lost to any SUB that connected after the send
-                    # (slow-joiner), so a worker restarted by the supervisor
-                    # — or a learner restarted mid-run — would act on a
-                    # stale/random policy until the next update-driven
-                    # publish. While the store starves, re-ship the current
-                    # weights + ver on a slow clock so joiners converge.
-                    if self._maybe_join_push(pub, state, ver=idx):
-                        last_pub_m = time.monotonic()
-                    elif cfg.rebroadcast_idle_s > 0:
-                        now_m = time.monotonic()
-                        if now_m - last_pub_m >= cfg.rebroadcast_idle_s:
-                            self._publish(pub, state, ver=idx)
+                    with span("idle-poll", bucket=IDLE):
+                        if self.heartbeat is not None:
+                            self.heartbeat.value = time.time()
+                        # Idle rebroadcast (chaos-plane hardening): a PUB
+                        # frame is lost to any SUB that connected after the
+                        # send (slow-joiner), so a worker restarted by the
+                        # supervisor — or a learner restarted mid-run —
+                        # would act on a stale/random policy until the next
+                        # update-driven publish. While the store starves,
+                        # re-ship the current weights + ver on a slow clock
+                        # so joiners converge.
+                        if self._maybe_join_push(pub, state, ver=idx):
                             last_pub_m = time.monotonic()
-                            self.n_rebroadcasts += 1
-                    self._note_ckpt(timer)
-                    if telem_reg is not None:
-                        now_m = time.monotonic()
-                        if now_m - telem_last >= cfg.telemetry_interval_s:
-                            telem_last = now_m
-                            self._emit_telemetry(
-                                telem_reg, telem_pub, timer, idx
-                            )
-                    if feed.poll_sleep:
-                        time.sleep(feed.poll_sleep)
-                    if ledger is not None:
-                        ledger.add(IDLE, time.perf_counter() - t_wait)
+                        elif cfg.rebroadcast_idle_s > 0:
+                            now_m = time.monotonic()
+                            if now_m - last_pub_m >= cfg.rebroadcast_idle_s:
+                                self._publish(pub, state, ver=idx)
+                                last_pub_m = time.monotonic()
+                                self.n_rebroadcasts += 1
+                        self._note_ckpt(timer)
+                        if telem_reg is not None:
+                            now_m = time.monotonic()
+                            if now_m - telem_last >= cfg.telemetry_interval_s:
+                                telem_last = now_m
+                                self._emit_telemetry(
+                                    telem_reg, telem_pub, timer, idx
+                                )
+                        if feed.poll_sleep:
+                            time.sleep(feed.poll_sleep)
                     continue
-                wait_secs = time.perf_counter() - t_wait
-                batch, feed_secs = item
-                key, sub_key = jax.random.split(key)
-                rc0 = self._perf.recompiles if self._perf is not None else 0
-                if self._perf is not None:
-                    # Identity check after the first call; first sight of a
-                    # (re)built train_step runs the one-time cost analysis
-                    # and rebinds the recompile watch — BEFORE dispatch, so
-                    # the donated buffers are still alive to lower against.
-                    self._perf.capture(train_step, state, batch, sub_key)
-                backend.add_program(train_step, state, batch, sub_key)
-                t_step = time.perf_counter()
-                state, metrics = train_step(state, batch, sub_key)
-                step_secs = time.perf_counter() - t_step
-                if track_nf:
-                    # Lazy device-side add — no host sync per dispatch; the
-                    # loss-log branch below reads it back with float().
-                    nf_acc = nf_acc + metrics["nonfinite-updates"]
-                if diag_acc is not None and isinstance(metrics, dict):
-                    # Detach diag BEFORE the loss logger's float() walk (it
-                    # is a nested pytree, not a scalar) and fold it with this
-                    # dispatch's per-row staleness — one async device
-                    # program, zero host syncs.
-                    diag = metrics.pop("diag", None)
-                    if diag is not None:
-                        vers = diag_vers.popleft() if diag_vers else None
-                        n_rows = (
-                            next(iter(diag["rows"].values())).shape[0]
-                            if diag["rows"]
-                            else 0
-                        )
-                        diag_acc.add(
-                            diag, _stale_rows(idx, vers, n_rows)
-                        )
-                if self._perf is not None:
-                    # The dispatch critical path (same window as the
-                    # learner-throughput timer) drives achieved FLOPs/s.
-                    self._perf.note(wait_secs + step_secs)
-                if tracer is not None:
-                    tracer.add("queue-wait", t_wait, wait_secs)
-                    tracer.add("train-step", t_step, step_secs)
+                with span("rng-split"):
+                    batch, feed_secs = item
+                    key, sub_key = jax.random.split(key)
+                with span("program-record"):
+                    rc0 = self._perf.recompiles if self._perf is not None else 0
+                    if self._perf is not None:
+                        # Identity check after the first call; first sight of
+                        # a (re)built train_step runs the one-time cost
+                        # analysis and rebinds the recompile watch — BEFORE
+                        # dispatch, so the donated buffers are still alive to
+                        # lower against.
+                        self._perf.capture(train_step, state, batch, sub_key)
+                    backend.add_program(train_step, state, batch, sub_key)
+                # learner-step-time is the host time of an asynchronous
+                # dispatch, not device time: it reads as the device's only
+                # when the dispatch queue is full and the call blocks.
+                with span(
+                    "dispatch", args={"update": idx + chain},
+                    timer="learner-step-time", bucket=COMPUTE,
+                ) as sp_step:
+                    state, metrics = train_step(state, batch, sub_key)
+                    if self._perf is not None and self._perf.recompiles > rc0:
+                        # A dispatch that retraced spent its span in XLA, not
+                        # in useful device math — divert it out of compute.
+                        sp_step.bucket = RECOMPILE
+                with span("diag-fold"):
+                    if track_nf:
+                        # Lazy device-side add — no host sync per dispatch;
+                        # the loss-log branch below reads it back.
+                        nf_acc = nf_acc + metrics["nonfinite-updates"]
+                    if diag_acc is not None and isinstance(metrics, dict):
+                        # Detach diag BEFORE the loss logger's float() walk
+                        # (it is a nested pytree, not a scalar) and fold it
+                        # with this dispatch's per-row staleness — one async
+                        # device program, zero host syncs.
+                        diag = metrics.pop("diag", None)
+                        if diag is not None:
+                            vers = diag_vers.popleft() if diag_vers else None
+                            n_rows = (
+                                next(iter(diag["rows"].values())).shape[0]
+                                if diag["rows"]
+                                else 0
+                            )
+                            diag_acc.add(
+                                diag, _stale_rows(idx, vers, n_rows)
+                            )
                 if self._inference is not None:
-                    # Snapshot (not reference): the NEXT dispatch donates
-                    # this state's buffers, and the serve thread must never
-                    # act on deleted arrays.
-                    self._inference.set_params(
-                        self._actor_snapshot(state), version=idx + chain
+                    with span("inference-swap"):
+                        # Snapshot (not reference): the NEXT dispatch donates
+                        # this state's buffers, and the serve thread must
+                        # never act on deleted arrays.
+                        self._inference.set_params(
+                            self._actor_snapshot(state), version=idx + chain
+                        )
+                with span("account"):
+                    # The dispatch critical path — queue-wait + step, the
+                    # throughput window — drives achieved FLOPs/s too.
+                    # learner-batching-time is the feed-side host work (shm
+                    # copies + assembly + H2D placement); with prefetch it
+                    # overlaps the device step, and overlap shows as
+                    # queue-wait << batching-time.
+                    critical_secs = sp_wait.secs + sp_step.secs
+                    if self._perf is not None:
+                        self._perf.note(critical_secs)
+                    timer.record("learner-batching-time", feed_secs)
+                    timer.record_gauge("learner-queue-depth", feed.qsize())
+                    timer.record(
+                        "learner-throughput", critical_secs,
+                        check_throughput=True,
                     )
-                # learner-batching-time is the feed-side host work (shm
-                # copies + assembly + H2D placement). With prefetch it
-                # overlaps the device step, so the per-dispatch critical
-                # path — the throughput window — is queue-wait + step;
-                # overlap shows as queue-wait << batching-time.
-                timer.record("learner-batching-time", feed_secs)
-                timer.record("learner-queue-wait-time", wait_secs)
-                timer.record("learner-step-time", step_secs)
-                if ledger is not None:
-                    ledger.add(wait_bucket, wait_secs)
-                    # A dispatch that retraced spent its span in XLA, not in
-                    # useful device math — divert it out of compute.
-                    recompiled = (
-                        self._perf is not None
-                        and self._perf.recompiles > rc0
-                    )
-                    ledger.add(RECOMPILE if recompiled else COMPUTE, step_secs)
-                timer.record_gauge("learner-queue-depth", feed.qsize())
-                timer.record(
-                    "learner-throughput",
-                    wait_secs + step_secs,
-                    check_throughput=True,
-                )
-                prev_idx, idx = idx, idx + chain
+                    prev_idx, idx = idx, idx + chain
 
-                progress = idx if anneal_absolute else idx - start_idx
-                if anneal_at is not None and progress >= anneal_at:
-                    # Rebuild the step with the cold-phase coefficients (one
-                    # extra jit compile; optimizer state carries over — the
-                    # on-policy families use rmsprop, whose accumulator is
-                    # lr-independent). std_floor/family changes are NOT
-                    # supported here: workers build their own family from the
-                    # original cfg and cannot re-floor mid-run.
-                    cfg = cfg.replace(
-                        entropy_coef=float(anneal["coef"]),
-                        lr=float(anneal.get("lr", cfg.lr)),
-                    )
-                    self.cfg = cfg
-                    train_step = _wrap(spec.make_train_step(cfg, family), cfg)
-                    anneal_at = None  # fire once
-                    print(
-                        f"[learner] update {idx}: entropy_coef -> "
-                        f"{cfg.entropy_coef}, lr -> {cfg.lr}", flush=True,
-                    )
+                    progress = idx if anneal_absolute else idx - start_idx
+                    if anneal_at is not None and progress >= anneal_at:
+                        # Rebuild the step with the cold-phase coefficients
+                        # (one extra jit compile; optimizer state carries
+                        # over — the on-policy families use rmsprop, whose
+                        # accumulator is lr-independent). std_floor/family
+                        # changes are NOT supported here: workers build their
+                        # own family from the original cfg and cannot
+                        # re-floor mid-run.
+                        cfg = cfg.replace(
+                            entropy_coef=float(anneal["coef"]),
+                            lr=float(anneal.get("lr", cfg.lr)),
+                        )
+                        self.cfg = cfg
+                        train_step = _wrap(
+                            spec.make_train_step(cfg, family), cfg
+                        )
+                        anneal_at = None  # fire once
+                        print(
+                            f"[learner] update {idx}: entropy_coef -> "
+                            f"{cfg.entropy_coef}, lr -> {cfg.lr}", flush=True,
+                        )
 
-                if cfg.profile_dir is not None:
+                if profiling is not False:
                     # Window is relative to THIS run's updates (resume-safe).
                     # start() returns None when a /prof or SIGUSR2 capture
-                    # is already in flight — the window then simply skips.
+                    # is already in flight — the window then waits for it.
                     rel = idx - start_idx
-                    if not profiling and rel >= cfg.profile_start:
-                        profiling = prof_capture.start() is not None
+                    if profiling is None and rel >= cfg.profile_start:
+                        with span("profiler-window"):
+                            if prof_capture.start() is not None:
+                                profiling = True
                     elif profiling and rel >= cfg.profile_start + cfg.profile_steps:
-                        jax.block_until_ready(metrics)
-                        prof_capture.stop()
-                        profiling = False
-                t_pub = time.perf_counter()
-                if _crossed(prev_idx, idx, self.publish_interval):
-                    self._publish(pub, state, ver=idx)
-                    self._consume_join_flag()  # this broadcast serves joiners
-                    last_pub_m = time.monotonic()
-                elif self._maybe_join_push(pub, state, ver=idx):
-                    last_pub_m = time.monotonic()
-                if ledger is not None:
-                    # Main-lane broadcast cost only (async dispatch + codec
-                    # handoff); the publisher thread's device_get + send
-                    # overlap the next step and stay off the ledger.
-                    ledger.add(WIRE, time.perf_counter() - t_pub)
-                if telem_reg is not None:
-                    now_m = time.monotonic()
-                    if now_m - telem_last >= cfg.telemetry_interval_s:
-                        telem_last = now_m
+                        with span("profiler-window"):
+                            jax.block_until_ready(metrics)
+                            prof_capture.stop()
+                            profiling = False
+                with span("publish", bucket=WIRE):
+                    # Main-lane broadcast cost only (snapshot copies + the
+                    # start of the D2H); the publisher thread's device_get +
+                    # send overlap the next step, on a lane of their own.
+                    if _crossed(prev_idx, idx, self.publish_interval):
+                        self._publish(pub, state, ver=idx)
+                        self._consume_join_flag()  # serves joiners too
+                        last_pub_m = time.monotonic()
+                    elif self._maybe_join_push(pub, state, ver=idx):
+                        last_pub_m = time.monotonic()
+                if (
+                    telem_reg is not None
+                    and time.monotonic() - telem_last >= cfg.telemetry_interval_s
+                ):
+                    with span("telemetry-emit"):
+                        telem_last = time.monotonic()
                         self._emit_telemetry(telem_reg, telem_pub, timer, idx)
                 if _crossed(prev_idx, idx, cfg.loss_log_interval):
-                    jax.block_until_ready(metrics)
-                    # Kept on self (harnesses read it after run()) and
-                    # printed, like the colocated loop's update line.
-                    self.last_losses = {k: float(v) for k, v in metrics.items()}
-                    print(
-                        f"[learner] update {idx}  "
-                        + "  ".join(
-                            f"{k} {v:.4f}" for k, v in self.last_losses.items()
-                        ),
-                        flush=True,
-                    )
-                    logger.log_losses(idx, self.last_losses)
-                    logger.log_timers(idx, timer)
-                    self._log_fleet_stat(logger)
-                    logger.flush()
-                    if tracer is not None:
-                        tracer.dump(os.path.join(cfg.result_dir, "trace.json"))
-                    if track_nf:
-                        # metrics is already host-synced (block_until_ready
-                        # above), so this read costs nothing extra.
-                        self.n_nonfinite_updates = float(nf_acc)
+                    with span("log-sync"):
+                        # The loop's one blocking read-back of the pipeline:
+                        # wait for this update, then fetch its scalars.
+                        jax.block_until_ready(metrics)
+                        # Kept on self (harnesses read it after run()) and
+                        # printed, like the colocated loop's update line.
+                        self.last_losses = {
+                            k: float(v) for k, v in metrics.items()
+                        }
+                        if track_nf:
+                            self.n_nonfinite_updates = float(nf_acc)
+                    with span("log-write"):
+                        print(
+                            f"[learner] update {idx}  "
+                            + "  ".join(
+                                f"{k} {v:.4f}"
+                                for k, v in self.last_losses.items()
+                            ),
+                            flush=True,
+                        )
+                        logger.log_losses(idx, self.last_losses)
+                        logger.log_timers(idx, timer)
+                        self._log_fleet_stat(logger)
+                        logger.flush()
                     diag_doc = None
                     if diag_acc is not None:
-                        # The plane's ONLY readback: derive the accumulated
-                        # sums into gauges + the learn.jsonl audit line,
-                        # then reset the on-device accumulator.
-                        diag_doc = diag_acc.drain(idx)
-                    if diag_doc is not None:
-                        if telem_reg is not None:
-                            _publish_diag(telem_reg, diag_doc)
-                        if cfg.result_dir is not None:
-                            from tpu_rl.obs.audit import append_jsonl
+                        with span("diag-drain"):
+                            # The plane's ONLY readback: derive the
+                            # accumulated sums into gauges + the learn.jsonl
+                            # audit line, then reset the on-device
+                            # accumulator.
+                            diag_doc = diag_acc.drain(idx)
+                            if diag_doc is not None:
+                                if telem_reg is not None:
+                                    _publish_diag(telem_reg, diag_doc)
+                                if cfg.result_dir is not None:
+                                    from tpu_rl.obs.audit import append_jsonl
 
-                            append_jsonl(
-                                cfg.result_dir,
-                                "learn.jsonl",
-                                _learn_record(idx, diag_doc),
-                            )
+                                    append_jsonl(
+                                        cfg.result_dir,
+                                        "learn.jsonl",
+                                        _learn_record(idx, diag_doc),
+                                    )
                     if watchdog is not None:
-                        sa_h = self.stat_array
-                        signals = {
-                            "loss": float(metrics["loss"]),
-                            "grad-norm": float(metrics.get("grad-norm", 0.0)),
-                        }
-                        if cfg.watchdog_diag and diag_doc is not None:
-                            # Algorithm-health channels: a KL spike is an
-                            # upward anomaly as-is; ESS collapses DOWNWARD,
-                            # so it enters negated to spike the z-score.
-                            g = diag_doc["global"]
-                            if "approx-kl" in g:
-                                signals["diag-approx-kl"] = float(
-                                    g["approx-kl"]
-                                )
-                            if "ess" in g:
-                                signals["diag-neg-ess"] = -float(g["ess"])
-                        if (
-                            sa_h is not None
-                            and len(sa_h) > SLOT_MEAN_REW
-                            and sa_h[SLOT_GAME_COUNT] > 0
-                        ):
-                            signals["mean-return"] = float(sa_h[SLOT_MEAN_REW])
-                        tripped = watchdog.observe(signals)
-                        # The guards contained these updates (params never
-                        # touched), but a sustained NaN stream means the data
-                        # or optimizer state is poisoned — count since the
-                        # last rollback, trip immediately at the threshold.
-                        if watchdog.note_nonfinite(
-                            self.n_nonfinite_updates - nf_base
-                        ):
-                            tripped = True
+                        with span("watchdog"):
+                            tripped = self._watchdog_tripped(
+                                watchdog, self.last_losses, diag_doc, nf_base
+                            )
                         if tripped:
                             if budget.exhausted():
                                 print(
@@ -843,21 +859,18 @@ class LearnerService:
                                     f"cleanly", flush=True,
                                 )
                                 break
-                            t_rb = time.perf_counter()
-                            rolled = self._rollback(
-                                ckpt, state, mesh, pub, fingerprint, key,
-                                watchdog.last_reason,
-                            )
-                            if ledger is not None:
-                                ledger.add(
-                                    ROLLBACK, time.perf_counter() - t_rb
+                            with span("rollback", bucket=ROLLBACK):
+                                rolled = self._rollback(
+                                    ckpt, state, mesh, pub, fingerprint, key,
+                                    watchdog.last_reason,
                                 )
+                                if rolled is not None:
+                                    state, idx, key = rolled
+                                    last_pub_m = time.monotonic()
+                                    watchdog.reset()
+                                    nf_base = self.n_nonfinite_updates
+                                    budget.record()
                             if rolled is not None:
-                                state, idx, key = rolled
-                                last_pub_m = time.monotonic()
-                                watchdog.reset()
-                                nf_base = self.n_nonfinite_updates
-                                budget.record()
                                 # Skip this iteration's save branch: the
                                 # restored index is already committed on
                                 # disk, re-saving it would race the
@@ -867,35 +880,37 @@ class LearnerService:
                     prev_idx, idx, cfg.model_save_interval
                 ):
                     # Async mode: snapshot + enqueue only; the D2H, orbax
-                    # write, commit marker, and GC run on the writer thread.
-                    t_ck = time.perf_counter()
-                    ckpt.save(state, idx, meta=_ckpt_meta())
-                    if ledger is not None:
-                        # The synchronous remnant of the save (device-side
-                        # snapshot + enqueue; the full blocking write when
-                        # async is off). Writer-thread time stays off-ledger.
-                        ledger.add(CKPT, time.perf_counter() - t_ck)
-                self._note_ckpt(timer)
-                if self.heartbeat is not None:
-                    self.heartbeat.value = time.time()
-                sa = self.stat_array
-                if (
-                    cfg.stop_at_reward is not None
-                    and sa is not None
-                    # window full: a real STAT_WINDOW-game mean, not a
-                    # lucky few-episode start
-                    and sa[SLOT_GAME_COUNT] >= STAT_WINDOW
-                    and sa[SLOT_MEAN_REW] >= cfg.stop_at_reward
-                ):
-                    logger.log_stat(
-                        int(sa[SLOT_GAME_COUNT]), float(sa[SLOT_MEAN_REW])
+                    # write, commit marker, and GC run on the writer thread
+                    # (lane "ckpt-writer"). This span is the synchronous
+                    # remnant of the save, or the full blocking write when
+                    # async is off.
+                    with span("ckpt-save", bucket=CKPT):
+                        ckpt.save(state, idx, meta=_ckpt_meta())
+                with span("heartbeat"):
+                    self._note_ckpt(timer)
+                    if self.heartbeat is not None:
+                        self.heartbeat.value = time.time()
+                    sa = self.stat_array
+                    solved = (
+                        cfg.stop_at_reward is not None
+                        and sa is not None
+                        # window full: a real STAT_WINDOW-game mean, not a
+                        # lucky few-episode start
+                        and sa[SLOT_GAME_COUNT] >= STAT_WINDOW
+                        and sa[SLOT_MEAN_REW] >= cfg.stop_at_reward
                     )
-                    logger.flush()
-                    print(
-                        f"[learner] fleet 50-game mean {sa[SLOT_MEAN_REW]:.1f} >= "
-                        f"stop_at_reward {cfg.stop_at_reward}: solved, "
-                        f"stopping at update {idx}", flush=True,
-                    )
+                    if solved:
+                        logger.log_stat(
+                            int(sa[SLOT_GAME_COUNT]), float(sa[SLOT_MEAN_REW])
+                        )
+                        logger.flush()
+                        print(
+                            f"[learner] fleet 50-game mean "
+                            f"{sa[SLOT_MEAN_REW]:.1f} >= stop_at_reward "
+                            f"{cfg.stop_at_reward}: solved, stopping at "
+                            f"update {idx}", flush=True,
+                        )
+                if solved:
                     break
         finally:
             # Feeder first (stops shm sampling), then the publisher (joins
@@ -923,8 +938,7 @@ class LearnerService:
                 # index reaches the aggregator even on early exit.
                 self._emit_telemetry(telem_reg, telem_pub, timer, idx)
                 telem_pub.close()
-            if tracer is not None and tracer.n_recorded:
-                tracer.dump(os.path.join(cfg.result_dir, "trace.json"))
+            tracer.close_export()  # trace.json's last state, then join
             pub.close()
             writer.close()
             backend.close()
@@ -987,35 +1001,31 @@ class LearnerService:
                 chain=chain,
                 depth=self.cfg.learner_prefetch,
                 stop_event=self.stop_event,
+                tracer=self._tracer,
             )
-        return SynchronousFeed(fetch, self._assemble_device, chain=chain)
+        return SynchronousFeed(
+            fetch, self._assemble_device, chain=chain, tracer=self._tracer
+        )
 
     def _assemble_device(self, raws: list):
         """Assemble + eager device placement with the step's input sharding,
         so the H2D transfer happens feed-side (overlapped under prefetch)
         instead of inside the jitted call's implicit transfer. Runs on the
-        feeder thread under prefetch — its trace spans land on the "feeder"
-        lane, where the overlap with the main lane's train-step is visible."""
+        feeder thread under prefetch, inside the feed's ``assemble`` span
+        (``data/prefetch.py``); the placement is its ``h2d-put`` child, so
+        the overlap with the main lane's dispatch is visible."""
         import jax
 
-        tracer = self._tracer
-        t0 = time.perf_counter()
         self._pop_vers(raws)
         batch = self._assemble(raws)
-        t1 = time.perf_counter()
-        if tracer is not None:
-            tracer.add("assemble", t0, t1 - t0, tid="feeder")
         if self._place_global is not None or self._chain_mesh is not None:
             # Already placed during assembly: host_local_batch_to_global /
             # shard_chained_batch both produce global device arrays.
             return batch
-        if self._batch_sharding is not None:
-            placed = jax.device_put(batch, self._batch_sharding)
-        else:
-            placed = jax.device_put(batch, self._device)
-        if tracer is not None:
-            tracer.add("h2d", t1, time.perf_counter() - t1, tid="feeder")
-        return placed
+        with self._tracer.span("h2d-put", tid="feeder"):
+            if self._batch_sharding is not None:
+                return jax.device_put(batch, self._batch_sharding)
+            return jax.device_put(batch, self._device)
 
     def _pop_vers(self, raws: list) -> None:
         """Detach each raw batch's ``"ver"`` staleness sidecar (a non-batch
@@ -1077,8 +1087,8 @@ class LearnerService:
         (``ver``) that produced it — workers echo it so storage can measure
         policy staleness. With the async publisher the caller only snapshots
         + starts the D2H; the blocking device_get and ZMQ send run on the
-        publisher thread."""
-        t0 = time.perf_counter()
+        publisher thread (lane "publisher"). Callers hold the main-lane span
+        this belongs to (``publish``, ``idle-poll`` or ``rollback``)."""
         actor = (
             state.actor_params
             if hasattr(state, "actor_params")
@@ -1098,11 +1108,6 @@ class LearnerService:
                     "t_tx": time.time_ns(),
                 },
             )
-        if self._tracer is not None:
-            # Async path: this span is the cheap dispatch cost the hot loop
-            # actually pays; the blocking device_get runs on the publisher
-            # thread, outside the batch timeline.
-            self._tracer.add("broadcast", t0, time.perf_counter() - t0)
 
     def _consume_join_flag(self) -> bool:
         """Clear a pending join request and count it answered. A PUB frame
@@ -1136,6 +1141,41 @@ class LearnerService:
         for dur in ckpt.drain_save_secs():
             timer.record("learner-ckpt-time", dur)
         timer.record_gauge("learner-ckpt-pending", float(ckpt.pending))
+
+    def _watchdog_tripped(
+        self, watchdog, losses: dict, diag_doc: dict | None, nf_base: float
+    ) -> bool:
+        """Feed the divergence watchdog this log interval's signals (the
+        loss-log read-back, the diag drain, the fleet's mean return) and say
+        whether it tripped."""
+        cfg = self.cfg
+        sa_h = self.stat_array
+        signals = {
+            "loss": losses["loss"],
+            "grad-norm": losses.get("grad-norm", 0.0),
+        }
+        if cfg.watchdog_diag and diag_doc is not None:
+            # Algorithm-health channels: a KL spike is an upward anomaly
+            # as-is; ESS collapses DOWNWARD, so it enters negated to spike
+            # the z-score.
+            g = diag_doc["global"]
+            if "approx-kl" in g:
+                signals["diag-approx-kl"] = float(g["approx-kl"])
+            if "ess" in g:
+                signals["diag-neg-ess"] = -float(g["ess"])
+        if (
+            sa_h is not None
+            and len(sa_h) > SLOT_MEAN_REW
+            and sa_h[SLOT_GAME_COUNT] > 0
+        ):
+            signals["mean-return"] = float(sa_h[SLOT_MEAN_REW])
+        tripped = watchdog.observe(signals)
+        # The guards contained these updates (params never touched), but a
+        # sustained NaN stream means the data or optimizer state is poisoned
+        # — count since the last rollback, trip immediately at the threshold.
+        if watchdog.note_nonfinite(self.n_nonfinite_updates - nf_base):
+            tripped = True
+        return tripped
 
     def _rollback(
         self, ckpt, state, mesh, pub, fingerprint, key, reason: str
