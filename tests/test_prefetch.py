@@ -218,6 +218,46 @@ def test_learner_fetch_no_gate_when_ratio_unset():
         assert fetch() == {"stub": 1}
 
 
+@pytest.mark.timeout(60)
+def test_a_sharded_batch_goes_from_the_host_to_each_chip():
+    """With a data mesh the assembled batch stays host memory until it is
+    placed with the step's sharding: no chip ever holds the whole batch."""
+    import jax
+
+    from tpu_rl.data.layout import BatchLayout
+    from tpu_rl.obs.trace import TraceRecorder
+    from tpu_rl.parallel.mesh import batch_sharding, make_mesh
+    from tpu_rl.runtime.learner_service import LearnerService
+    from tpu_rl.types import BATCH_FIELDS
+
+    cfg = small_config(algo="PPO", batch_size=8, seq_len=4)
+    layout = BatchLayout.from_config(cfg)
+    rng = np.random.default_rng(3)
+    raw = {
+        f: rng.standard_normal((8, 4, layout.width(f))).astype(np.float32)
+        for f in BATCH_FIELDS
+    }
+    svc = LearnerService(cfg, handles=None, model_port=0)
+    svc._place_global = svc._chain_mesh = None
+    svc._tracer = TraceRecorder(capacity=0)
+    svc._batch_sharding = batch_sharding(make_mesh(4))
+    svc._device = jax.devices()[0]
+
+    host = svc._to_batch(dict(raw))
+    assert all(isinstance(getattr(host, f), np.ndarray) for f in BATCH_FIELDS)
+    placed = svc._assemble_device([dict(raw, ver=np.zeros(8))])
+    for f in BATCH_FIELDS:
+        x = getattr(placed, f)
+        assert x.sharding == svc._batch_sharding and x.dtype == np.float32
+        assert {s.data.shape[0] for s in x.addressable_shards} == {2}
+        np.testing.assert_array_equal(np.asarray(x), raw[f])
+
+    svc._batch_sharding = None  # one chip: placed whole, as before
+    one = svc._assemble_device([dict(raw)])
+    assert one.obs.sharding.device_set == {svc._device}
+    np.testing.assert_array_equal(np.asarray(one.obs), raw["obs"])
+
+
 # ------------------------------------------------- service-level equivalence
 def _run_service_to_checkpoint(tmp_path, tag, port, prefetch, chain=2):
     """Run a LearnerService through the REAL OnPolicyStore shm path on a

@@ -235,6 +235,11 @@ def test_a_skewed_clock_fails_the_check_and_names_nothing(skew_ns):
 
 
 # --------------------------------- (b) (c) the learner's main lane, on CPU
+class _NullPub:
+    def send(self, _proto, _payload) -> None:
+        pass
+
+
 def _run_learner(tmp_path, port, n_updates, **kw):
     from tpu_rl.data.layout import BatchLayout
     from tpu_rl.data.shm_ring import OnPolicyStore, alloc_handles
@@ -326,6 +331,15 @@ def test_learner_main_lane_is_exhaustive_and_the_ledger_still_sums(tmp_path):
     assert sum(snap["buckets"].values()) == pytest.approx(snap["elapsed_s"], rel=0.01)
     assert snap["buckets"]["compute"] > 0 and snap["buckets"]["wire"] > 0
     assert snap["buckets"]["ckpt"] > 0
+    # the learner's registry says how the broadcast's latest-wins slot was
+    # used: one snapshot per update and the first broadcast, sent or superseded
+    from tpu_rl.obs import MetricsRegistry
+
+    reg = MetricsRegistry(role="learner")
+    svc._emit_telemetry(reg, _NullPub(), svc.timer, 40)
+    counters = {name: value for name, _labels, value in reg.snapshot()["counters"]}
+    assert counters["learner-publish-snapshots"] == 41
+    assert 1 <= counters["learner-publish-sent"] <= 41
 
 
 @pytest.mark.timeout(300)

@@ -19,11 +19,11 @@ TPU-first redesign:
   overlaps the current ``train_step`` (``tpu_rl/data/prefetch.py``; the
   Podracer overlap, Hessel et al. 2104.06272). ``learner_prefetch=0``
   restores the serial feed for A/B;
-- weight broadcast is an ASYNC host-copy snapshot of the actor tree only —
-  a device-side copy + ``copy_to_host_async``, with the blocking
-  ``device_get`` and the ZMQ send on a publisher thread — throttled by
-  ``publish_interval``, so host transfer never stalls the device pipeline
-  (SURVEY.md §7 hard-parts);
+- weight broadcast is an ASYNC snapshot of the actor tree only — ONE device
+  program copies the whole tree (``snapshot_tree``), and the D2H transfer,
+  its wait and the ZMQ send run on a publisher thread — throttled by
+  ``publish_interval``, so the loop dispatches ahead of the chip and host
+  transfer never stalls the device pipeline (SURVEY.md §7 hard-parts);
 - off-policy learners honor ``cfg.max_update_data_ratio`` (update:data
   ratio gate — the replay learner waits for fresh transitions instead of
   free-running against the ring, CLUSTER_R5_SAC.md);
@@ -32,6 +32,7 @@ TPU-first redesign:
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
@@ -72,16 +73,57 @@ def _crossed(prev: int, cur: int, interval: int) -> bool:
     return cur // interval > prev // interval
 
 
+@functools.cache
+def _snapshot_program(recycle: bool):
+    import jax
+    import jax.numpy as jnp
+
+    def snapshot(tree, into=None):
+        return jax.tree.map(jnp.copy, tree)
+
+    if recycle:  # ``into`` is donated: each output is written over its leaf
+        return jax.jit(snapshot, donate_argnums=1, keep_unused=True)
+    return jax.jit(snapshot)
+
+
+def snapshot_tree(tree, into=None):
+    """Donation-proof device copy of a whole pytree in ONE program launch.
+
+    The outputs share no buffer with the inputs (``copy`` is not forwarded
+    through jit), so the next ``train_step``'s donation of the state cannot
+    invalidate the snapshot. ``into`` is an earlier snapshot of the same tree
+    that nobody needs any more: it is donated, and the new snapshot is
+    written into its buffers instead of newly allocated ones. jit keeps one
+    executable per tree structure and placement: every algorithm's actor
+    tree, the replicated mesh tree and a multihost global tree each compile
+    their own on first use. (One ``jnp.copy`` per leaf is one launch per
+    leaf: for the transformer's 56-leaf actor more host time than the chip
+    needs for the update, PERF.md §6.)"""
+    if into is None:
+        return _snapshot_program(False)(tree)
+    return _snapshot_program(True)(tree, into)
+
+
 class AsyncPublisher:
     """Weight broadcast off the learner's critical path.
 
-    ``publish(actor)`` runs only cheap async dispatches on the caller:
-    a device-side ``jnp.copy`` of the actor tree (independent buffers, so
-    the next ``train_step``'s donation of the state cannot invalidate the
-    snapshot mid-copy) and ``copy_to_host_async`` to start the D2H DMA.
-    The blocking ``jax.device_get`` — which must wait for the update that
-    produced the weights AND the transfer — plus codec + ZMQ send happen on
-    this thread, overlapped with the learner's next dispatches.
+    ``publish(actor)`` runs one async dispatch on the caller and nothing
+    else: ``snapshot_tree``, the device-side copy of the actor tree. The D2H
+    transfer is started on this thread, for the snapshot it actually takes
+    from the slot (a superseded one is never transferred). The blocking
+    ``jax.device_get`` — which must wait for the update that produced the
+    weights AND the transfer — plus codec + ZMQ send happen here too,
+    overlapped with the learner's next dispatches; the device snapshot is
+    let go as soon as it is on the host.
+
+    The loop dispatches ahead of the chip, and a buffer is allocated at
+    dispatch: a snapshot still in the slot when the next one is made is
+    superseded, and the new one is written into its buffers, so at most two
+    device snapshots are alive (the slot's and the publisher's) however far
+    ahead the loop runs. That makes two programs, with and without a
+    snapshot to recycle; the first call for each placement of the tree (the
+    fresh state's, then the step outputs') runs both, so neither compiles
+    later in the run.
 
     Latest-wins slot (not a queue): under backpressure workers want the
     NEWEST weights, and per-snapshot order is irrelevant once superseded.
@@ -98,19 +140,27 @@ class AsyncPublisher:
         self._pending = None
         self._error: BaseException | None = None
         self._closed = False
+        self.n_snapshots = 0  # made on the caller's lane
+        self.n_sent = 0  # taken from the slot and sent; the rest were superseded
+        self._to_warm = 2  # placements whose recycling program has yet to run
         self._thread = threading.Thread(
             target=self._run, name="learner-publish", daemon=True
         )
         self._thread.start()
 
     def publish(self, actor, ver: int = -1, epoch: int = 0) -> None:
-        import jax
-        import jax.numpy as jnp
-
         if self._error is not None:
             raise self._error
-        snap = jax.tree.map(jnp.copy, actor)  # donation-proof device copy
-        jax.tree.map(lambda x: x.copy_to_host_async(), snap)
+        with self._cond:  # a snapshot still here will never be sent
+            superseded, self._pending = self._pending, None
+        if superseded is not None:
+            snap = snapshot_tree(actor, into=superseded[0])
+        else:
+            snap = snapshot_tree(actor)
+            if self._to_warm:
+                self._to_warm -= 1
+                snap = snapshot_tree(actor, into=snap)
+        self.n_snapshots += 1
         with self._cond:
             self._pending = (snap, ver, epoch)  # latest wins
             self._cond.notify()
@@ -126,10 +176,11 @@ class AsyncPublisher:
                     return
                 (snap, ver, epoch), self._pending = self._pending, None
             try:
-                # The wait for the update that produced the weights, and for
-                # their transfer.
+                # The transfer's start, the wait for the update that produced
+                # the weights, and for the transfer.
                 with self._span("publish-d2h", tid="publisher"):
                     actor = jax.device_get(snap)
+                snap = None  # only the host tree is needed through the send
                 # "ver" is the learner update index that produced these
                 # weights: workers echo it through their rollouts so storage
                 # can measure per-worker policy staleness (tpu_rl.obs).
@@ -151,6 +202,8 @@ class AsyncPublisher:
                             "t_tx": time.time_ns(),
                         },
                     )
+                with self._cond:
+                    self.n_sent += 1
             except BaseException as e:  # noqa: BLE001 — surfaces in publish()
                 self._error = e
                 return
@@ -784,9 +837,9 @@ class LearnerService:
                             prof_capture.stop()
                             profiling = False
                 with span("publish", bucket=WIRE):
-                    # Main-lane broadcast cost only (snapshot copies + the
-                    # start of the D2H); the publisher thread's device_get +
-                    # send overlap the next step, on a lane of their own.
+                    # Main-lane broadcast cost only (one launch: the snapshot
+                    # program); the publisher thread's D2H, device_get + send
+                    # overlap the next steps, on a lane of their own.
                     if _crossed(prev_idx, idx, self.publish_interval):
                         self._publish(pub, state, ver=idx)
                         self._consume_join_flag()  # serves joiners too
@@ -1055,7 +1108,7 @@ class LearnerService:
             self._place_global = sharding
 
     def _to_batch(self, raw: dict):
-        from tpu_rl.types import Batch, maybe_zero_carry
+        from tpu_rl.types import BATCH_FIELDS, Batch, maybe_zero_carry
 
         raw = maybe_zero_carry(self.cfg, raw)
         if self._place_global is not None:
@@ -1064,6 +1117,13 @@ class LearnerService:
             return Batch(
                 **host_local_batch_to_global(raw, self._place_global)
             )
+        if self._batch_sharding is not None:
+            # Stays on the host: ``_assemble_device`` sends each chip its
+            # rows. Through ``jnp.asarray`` the whole batch would land on
+            # chip 0 first and be cut up there by slicing programs that
+            # queue behind every update already dispatched, so chip 0 would
+            # hold one whole batch more per update the loop is ahead by.
+            return Batch(**{k: np.asarray(raw[k]) for k in BATCH_FIELDS})
         return Batch.from_mapping(raw)
 
     # ------------------------------------------------------------ broadcast
@@ -1071,23 +1131,20 @@ class LearnerService:
         """Donation-proof device copy of the actor tree, shaped as the
         ``{"actor": ...}`` pytree ``family.act`` consumes (the same contract
         workers build from the model broadcast)."""
-        import jax
-        import jax.numpy as jnp
-
         actor = (
             state.actor_params
             if hasattr(state, "actor_params")
             else state.params["actor"]
         )
-        return {"actor": jax.tree.map(jnp.copy, actor)}
+        return {"actor": snapshot_tree(actor)}
 
     def _publish(self, pub: Pub, state, ver: int = -1) -> None:
         """Ship the actor tree as host numpy (SAC broadcasts the actor only,
         reference ``sac/learning.py:145``), tagged with the update index
         (``ver``) that produced it — workers echo it so storage can measure
         policy staleness. With the async publisher the caller only snapshots
-        + starts the D2H; the blocking device_get and ZMQ send run on the
-        publisher thread (lane "publisher"). Callers hold the main-lane span
+        (one launch); the D2H, the blocking device_get and the ZMQ send run on
+        the publisher thread (lane "publisher"). Callers hold the main-lane span
         this belongs to (``publish``, ``idle-poll`` or ``rollback``)."""
         actor = (
             state.actor_params
@@ -1275,6 +1332,13 @@ class LearnerService:
         reg.counter("learner-rebroadcasts").set_total(self.n_rebroadcasts)
         reg.gauge("learner-run-epoch").set(self.run_epoch)
         reg.counter("learner-join-pushes").set_total(self.n_join_pushes)
+        if self._publisher is not None:
+            # sent / snapshots = the share of snapshots that reach the wire;
+            # the rest were superseded in the latest-wins slot.
+            reg.counter("learner-publish-snapshots").set_total(
+                self._publisher.n_snapshots
+            )
+            reg.counter("learner-publish-sent").set_total(self._publisher.n_sent)
         # Self-healing plane: exported whenever the guards are compiled in
         # (update_guard default-on), so the shipped SLO example rule
         # `counter:learner-nonfinite-updates==0` always has data.
