@@ -81,6 +81,26 @@ class TestProtocol:
         codec = parts[1][3]  # header byte 3 = codec id
         assert codec == Codec.RAW
 
+    def test_encode_refuses_what_no_receiver_would_take(self, monkeypatch):
+        """The cap every receiver enforces (peek / decode) holds on the send
+        side too; below it the frame's bytes are what they were."""
+        from tpu_rl.runtime import protocol
+
+        payload = {"actor": {"w": np.arange(2048, dtype=np.float32)}, "ver": 3}
+        before = encode(Protocol.Model, payload)
+        raw = len(protocol.pack(payload))
+        monkeypatch.setattr(protocol, "_MAX_RAW", raw)
+        slack = protocol._FRAMING_SLACK  # the sender's pre-check leaves room for framing
+        assert protocol.fits_frame(raw - slack) and not protocol.fits_frame(raw - slack + 1)
+        assert encode(Protocol.Model, payload) == before
+        proto, got = decode(before)
+        assert proto == Protocol.Model and np.array_equal(got["actor"]["w"], payload["actor"]["w"])
+        monkeypatch.setattr(protocol, "_MAX_RAW", raw - 1)
+        with pytest.raises(ValueError, match="exceeds the frame cap"):
+            encode(Protocol.Model, payload)
+        with pytest.raises(ValueError, match="exceeds cap"):  # and nobody decodes it
+            decode(before)
+
     def test_corrupt_frame_rejected(self):
         parts = encode(Protocol.Model, np.arange(1000))
         bad = bytearray(parts[1])

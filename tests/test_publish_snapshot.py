@@ -227,3 +227,92 @@ def test_snapshot_tree_keeps_structure_dtype_and_placement():
     for a, b in zip(jax.tree.leaves(snap), jax.tree.leaves(tree)):
         assert a.dtype == b.dtype and a.sharding == b.sharding
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ------------------------------------------------- a tree no frame can carry
+class RecordingPub:
+    def __init__(self):
+        self.frames = []
+
+    def send(self, proto, payload):
+        from tpu_rl.runtime.protocol import encode
+
+        self.frames.append(encode(proto, payload))
+
+
+def _service(act_mode: str, publisher=None):
+    from tpu_rl.config import Config
+    from tpu_rl.runtime.learner_service import LearnerService
+
+    svc = LearnerService(Config.from_dict({"act_mode": act_mode}), None, model_port=0)
+    svc._publisher = publisher
+    svc.run_epoch = 3
+    return svc
+
+
+class _State:
+    def __init__(self, actor):
+        self.params = {"actor": actor}
+
+
+@pytest.mark.parametrize("asynchronous", [False, True], ids=["sync", "async-publisher"])
+def test_a_tree_over_the_frame_cap_is_never_snapshotted_packed_or_sent(
+    monkeypatch, capsys, asynchronous
+):
+    from tpu_rl.runtime import learner_service, protocol
+
+    actor = _tree(6, cols=41)  # 6 leaves, 3444 bytes
+    nbytes = sum(x.nbytes for x in jax.tree.leaves(actor))
+    monkeypatch.setattr(protocol, "_MAX_RAW", nbytes + protocol._FRAMING_SLACK - 1)
+
+    def forbidden(*_a, **_k):
+        raise AssertionError("an oversize tree reached the snapshot or the codec")
+
+    monkeypatch.setattr(protocol, "pack", forbidden)
+    monkeypatch.setattr(learner_service, "snapshot_tree", forbidden)
+    pub = RecordingPub()
+
+    class Publisher:
+        publish = staticmethod(forbidden)
+
+    svc = _service("remote", Publisher() if asynchronous else None)
+    for ver in range(3):
+        svc._publish(pub, _State(actor), ver=ver)
+    assert svc.n_publish_oversize == 3 and pub.frames == []
+    out = capsys.readouterr().out
+    assert out.count("exceeds the broadcast frame cap") == 1  # logged once
+    assert str(nbytes) in out
+
+    # workers that act locally could never be given this policy
+    with pytest.raises(ValueError, match="act_mode='remote'"):
+        _service("local")._publish(pub, _State(actor), ver=0)
+
+
+def test_a_tree_under_the_frame_cap_is_sent_byte_for_byte_as_before(monkeypatch):
+    from tpu_rl.runtime import learner_service, protocol
+    from tpu_rl.runtime.protocol import encode
+
+    actor = _tree(6, cols=43)
+    nbytes = sum(x.nbytes for x in jax.tree.leaves(actor))
+    monkeypatch.setattr(learner_service.time, "time_ns", lambda: 1234567)
+    want = encode(Protocol.Model, {
+        "actor": jax.device_get(actor), "ver": 9, "epoch": 3, "t_tx": 1234567})
+    monkeypatch.setattr(protocol, "_MAX_RAW", nbytes + protocol._FRAMING_SLACK)  # just fits
+    for mode in ("local", "remote"):
+        pub = RecordingPub()
+        svc = _service(mode)
+        svc._publish(pub, _State(actor), ver=9)
+        assert pub.frames == [want] and svc.n_publish_oversize == 0
+    # and through the publisher thread: the same tree, version and epoch
+    pub = BlockedPub()
+    pub.gate.set()
+    publisher = AsyncPublisher(pub, TraceRecorder(capacity=0))
+    try:
+        svc = _service("local", publisher)
+        svc._publish(pub, _State(actor), ver=9)
+    finally:
+        publisher.close()
+    (proto, payload), = pub.sent
+    assert proto == Protocol.Model and (payload["ver"], payload["epoch"]) == (9, 3)
+    for a, b in zip(jax.tree.leaves(payload["actor"]), jax.tree.leaves(actor)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()

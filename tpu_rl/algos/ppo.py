@@ -130,23 +130,29 @@ def make_train_step(cfg: Config, family: ModelFamily):
             (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 state.params, batch
             )
-            grads, gnorm = clip_subtree_by_global_norm(grads, cfg.max_grad_norm)
-            if guard:
-                ok = update_ok(metrics["loss"], gnorm)
+            # Clip + RMSprop under one scope: the device trace reads the
+            # optimizer's share of an update from it (a pass over every
+            # parameter and its second moment, bandwidth-bound).
+            with jax.named_scope("opt_update"):
+                grads, gnorm = clip_subtree_by_global_norm(grads, cfg.max_grad_norm)
+                if guard:
+                    ok = update_ok(metrics["loss"], gnorm)
 
-                def _apply(grads=grads, state=state):
+                    def _apply(grads=grads, state=state):
+                        updates, opt_state = opt.update(
+                            grads, state.opt_state, state.params
+                        )
+                        return optax.apply_updates(state.params, updates), opt_state
+
+                    params, opt_state = guarded(
+                        ok, _apply, (state.params, state.opt_state)
+                    )
+                    nf = nf + (1.0 - ok.astype(jnp.float32))
+                else:
                     updates, opt_state = opt.update(
                         grads, state.opt_state, state.params
                     )
-                    return optax.apply_updates(state.params, updates), opt_state
-
-                params, opt_state = guarded(
-                    ok, _apply, (state.params, state.opt_state)
-                )
-                nf = nf + (1.0 - ok.astype(jnp.float32))
-            else:
-                updates, opt_state = opt.update(grads, state.opt_state, state.params)
-                params = optax.apply_updates(state.params, updates)
+                    params = optax.apply_updates(state.params, updates)
             state = state.replace(params=params, opt_state=opt_state)
             metrics["grad-norm"] = gnorm
         if guard:

@@ -39,6 +39,7 @@ FINGERPRINT_FIELDS = (
     "n_layers",
     "seq_len",
     "attention_impl",
+    "arch",
     "obs_shape",
     "action_space",
     "is_continuous",
@@ -52,6 +53,44 @@ FINGERPRINT_FIELDS = (
 
 def is_off_policy(algo: str) -> bool:
     return algo in OFF_POLICY_ALGOS
+
+
+# The keys of a GraniteMoeHybrid ``config.json`` that shape the policy core:
+# what ``models/granite_hybrid.py`` reads from ``Config.arch`` and the check
+# below holds together (the token embedding's and the experts' keys are
+# carried along unread).
+GRANITE_ARCH_KEYS = (
+    "hidden_size", "layer_types", "rms_norm_eps", "intermediate_size",
+    "residual_multiplier", "embedding_multiplier", "logits_scaling",
+    "mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_n_groups",
+    "mamba_d_conv", "mamba_expand", "mamba_chunk_size", "mamba_conv_bias",
+    "mamba_proj_bias", "num_attention_heads", "num_key_value_heads",
+    "attention_multiplier", "attention_bias",
+)
+
+
+def _check_granite_arch(arch: dict | None) -> None:
+    """What ``model="granite_hybrid"`` can build: the dense (no experts)
+    Mamba-2 + grouped-query attention arrangement without positions."""
+    assert isinstance(arch, dict), "model='granite_hybrid' needs arch (config.json keys)"
+    missing = [k for k in GRANITE_ARCH_KEYS if k not in arch]
+    assert not missing, f"arch lacks {missing}"
+    kinds = arch["layer_types"]
+    assert kinds and set(kinds) <= {"mamba", "attention"}, kinds
+    assert not arch.get("num_local_experts"), "expert layers are not built"
+    assert arch.get("position_embedding_type", "nope") == "nope", (
+        "only the published 'nope' (no positions) attention is built"
+    )
+    assert arch.get("hidden_act", "silu") == "silu", arch.get("hidden_act")
+    assert arch.get("normalization_function", "rmsnorm") == "rmsnorm"
+    inner = arch["mamba_n_heads"] * arch["mamba_d_head"]
+    assert inner == arch["mamba_expand"] * arch["hidden_size"], (
+        f"mamba_n_heads x mamba_d_head = {inner} is not mamba_expand x hidden_size"
+    )
+    assert arch["mamba_n_heads"] % arch["mamba_n_groups"] == 0
+    assert arch["hidden_size"] % arch["num_attention_heads"] == 0
+    assert arch["num_attention_heads"] % arch["num_key_value_heads"] == 0
+    assert arch["mamba_d_conv"] >= 2 and arch["mamba_chunk_size"] >= 1
 
 
 @dataclass
@@ -73,8 +112,10 @@ class Config:
 
     # model
     hidden_size: int = 64
-    # Policy backbone: "lstm" (reference parity) or "transformer" (new
-    # TPU-native long-context capability; on-policy algos only).
+    # Policy backbone: "lstm" (reference parity), "transformer" (new
+    # TPU-native long-context capability; on-policy algos only) or
+    # "granite_hybrid" (Mamba-2 + GQA attention layers of a published
+    # GraniteMoeHybrid config.json, given whole in ``arch``).
     model: str = "lstm"
     n_heads: int = 4
     n_layers: int = 2
@@ -84,6 +125,11 @@ class Config:
     # Worker-side attention context (sliding window) for transformer acting;
     # 0 = use seq_len.
     act_ctx: int = 0
+    # A published architecture's own config.json, under its published key
+    # names (model="granite_hybrid": GRANITE_ARCH_KEYS above). One
+    # mapping instead of a Config field per width: the widths of a catalog
+    # model are its source's to name, not this file's.
+    arch: dict | None = None
 
     # rollout
     time_horizon: int = 500
@@ -689,7 +735,17 @@ class Config:
             "float32",
             "bfloat16",
         ), f"compute_dtype must be float32 or bfloat16, got {self.compute_dtype!r}"
-        assert self.model in ("lstm", "transformer"), self.model
+        assert self.model in ("lstm", "transformer", "granite_hybrid"), self.model
+        if self.model == "granite_hybrid":
+            assert not is_off_policy(self.algo) and not self.algo.endswith(
+                "-Continuous"
+            ), "granite_hybrid backbone supports the discrete on-policy algorithms"
+            assert self.mesh_seq == 1, "granite_hybrid has no sequence-parallel path"
+            _check_granite_arch(self.arch)
+        else:
+            assert self.arch is None, (
+                f"arch is read by model='granite_hybrid' only, not {self.model!r}"
+            )
         # bfloat16 is wired for both backbones: the transformer via flax
         # module dtype (transformer.py), the LSTM families via
         # LSTMCell.dtype mixed precision (params f32, matmul compute bf16,

@@ -43,11 +43,11 @@ class BatchLayout:
 
         obs_dim = int(np.prod(cfg.obs_shape))
         hx_w = cx_w = None
-        if cfg.model == "transformer":
-            # Transformer training ignores the carry entirely, so the batch
-            # stores 1-float placeholders instead of shipping the worker's
-            # obs-history window over DCN/shm (the acting carry stays
-            # worker-local; see ModelFamily.carry_widths).
+        if cfg.model != "lstm":
+            # The transformer and granite_hybrid families keep their acting
+            # carry worker-local (ModelFamily.store_carry False: K/V caches,
+            # SSM states — megabytes per env), so the batch stores 1-float
+            # placeholders instead of shipping it over DCN/shm.
             hx_w, cx_w = 1, 1
         widths = field_widths(
             obs_dim,

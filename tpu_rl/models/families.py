@@ -197,6 +197,17 @@ def _act_transformer_window(
     return a[..., None].astype(jnp.float32), last, log_prob[..., None], h2, c2
 
 
+def _act_granite_hybrid(actor, params, obs, h, c, key):
+    """One recurrent step of the hybrid family: ``h`` holds the Mamba layers'
+    states and convolution tails, ``c`` the attention layers' K/V rings and a
+    step counter (``models/granite_hybrid.py``). The worker zeroes both at
+    episode starts, so no state crosses episodes."""
+    logits, _value, (h2, c2) = actor.apply(params["actor"], obs, h, c, method="act")
+    a = D.categorical_sample(key, logits)
+    log_prob = D.categorical_log_prob(logits, a)
+    return a[..., None].astype(jnp.float32), logits, log_prob[..., None], h2, c2
+
+
 def _act_sac_continuous(actor: SACContinuousActor, params, obs, h, c, key):
     mu, log_std, (h2, c2) = actor.apply(params["actor"], obs, (h, c), method="act")
     a, log_prob = D.tanh_normal_sample(key, mu, jnp.exp(log_std))
@@ -246,6 +257,24 @@ def build_family(cfg: Config, mesh=None) -> ModelFamily:
             store_carry=False,
         )
         return fam
+
+    if cfg.model == "granite_hybrid":
+        from tpu_rl.models.granite_hybrid import GraniteHybridActorCritic, carry_widths
+
+        assert cfg.algo in ("PPO", "IMPALA", "V-MPO"), (
+            "granite_hybrid backbone supports the discrete on-policy algorithms"
+        )
+        ctx = cfg.effective_act_ctx
+        actor = GraniteHybridActorCritic(
+            n_actions=n, arch=cfg.arch, act_ctx=ctx,
+            dtype=jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else None,
+        )
+        return ModelFamily(
+            cfg.algo, False, False, actor, None, obs_dim, n, cfg.arch["hidden_size"],
+            act=partial(_act_granite_hybrid, actor),
+            act_carry_widths=carry_widths(cfg.arch, ctx),
+            store_carry=False,
+        )
 
     if cfg.algo in ("PPO", "IMPALA", "V-MPO"):
         actor = DiscreteActorCritic(n_actions=n, **kw)

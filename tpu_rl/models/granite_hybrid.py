@@ -1,0 +1,457 @@
+"""Granite-4.0-H policy core: Mamba-2 layers with grouped-query attention
+layers between them, as ``GraniteMoeHybrid`` arranges them (dense: no experts).
+
+Widths come from ``Config.arch``, the model's own ``config.json`` under its
+published key names. Same ``unroll`` / ``act`` contract as the LSTM and
+transformer families, so PPO / IMPALA / V-MPO take it unchanged. Two
+departures from the published language model: an observation projection
+replaces the token embedding, and a policy and a value head replace the tied
+LM head.
+
+    x = embedding_multiplier * Dense(obs)
+    per layer:  x = x + residual_multiplier * mixer(RMSNorm(x))
+                x = x + residual_multiplier * W_out(silu(a) * b),  [a, b] = W_in RMSNorm(x)
+    logits = log_softmax(Dense(RMSNorm(x)) / logits_scaling);  value = Dense(RMSNorm(x))
+
+Mamba-2 mixer (SSD, arXiv:2405.21060): ``[z, xBC, dt] = W_in u``; a causal
+depthwise convolution and SiLU over ``xBC``; ``h_t = exp(dt_t A) h_{t-1} +
+dt_t x_t (x) B_t``, ``y_t = h_t C_t + D x_t``; ``W_out RMSNorm(y * silu(z))``.
+Attention: no positions, ``attention_multiplier`` as the softmax scale.
+
+Episode seams: ``is_fir[t]`` zeroes the state and the convolution's taps
+before ``t``. Training runs the chunked form of the recurrence, in which a
+seam is a same-segment mask on every decay factor (never ``-inf`` inside a
+cumulative sum, whose differences are NaN); acting runs the one-step form and
+relies on the worker zeroing the carry at episode starts. Every layer is
+rematerialised in the backward pass: one layer keeps ~150 KB per token.
+
+Acting carry (worker-local; ``store_carry=False``): ``h`` holds each Mamba
+layer's state and convolution tail, flattened; ``c`` each attention layer's
+K/V ring and one step counter, as the transformer family packs its caches. A
+training window starts from the ``h`` it is handed (zeros when the batch
+carries a placeholder) and from an empty attention context — the truncation
+``models/transformer.py`` documents.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from tpu_rl.parallel.sequence import flash_attention_tpu, segment_ids_from_firsts
+
+
+def carry_widths(arch: dict, ctx: int) -> tuple[int, int]:
+    """Widths of the flattened acting carry ``(h, c)``."""
+    conv_ch = _conv_channels(arch)
+    per_mamba = (
+        arch["mamba_n_heads"] * arch["mamba_d_head"] * arch["mamba_d_state"]
+        + (arch["mamba_d_conv"] - 1) * conv_ch
+    )
+    head_dim = arch["hidden_size"] // arch["num_attention_heads"]
+    per_attn = 2 * ctx * arch["num_key_value_heads"] * head_dim
+    kinds = arch["layer_types"]
+    return kinds.count("mamba") * per_mamba, kinds.count("attention") * per_attn + 1
+
+
+def _conv_channels(arch: dict) -> int:
+    inner = arch["mamba_n_heads"] * arch["mamba_d_head"]
+    return inner + 2 * arch["mamba_n_groups"] * arch["mamba_d_state"]
+
+
+def _rms_norm(x, scale, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    dtype: Any = None  # output dtype (statistics are float32)
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        return _rms_norm(x, scale, self.eps).astype(self.dtype or jnp.float32)
+
+
+@jax.named_scope("ssd_conv")
+def seam_conv(xbc, tail, seg, weight, bias):
+    """Causal depthwise convolution whose taps stop at an episode seam.
+    ``xbc`` (B, T, C); ``tail`` (B, K-1, C) the steps before the window
+    (segment 0); ``seg`` (B, T) int; ``weight`` (K, C). Float32."""
+    K = weight.shape[0]
+    T = xbc.shape[1]
+    xp = jnp.concatenate([tail, xbc], axis=1).astype(jnp.float32)
+    segp = jnp.concatenate([jnp.zeros_like(seg[:, : K - 1]), seg], axis=1)
+    out = jnp.broadcast_to(bias, xbc.shape).astype(jnp.float32)
+    for k in range(K):
+        same = segp[:, k : k + T] == seg
+        out = out + jnp.where(same[..., None], xp[:, k : k + T], 0.0) * weight[k]
+    return out
+
+
+@jax.named_scope("ssd_scan")
+def ssd_chunked(x, dt, A, B, C, D, seg, state0, chunk: int, dtype):
+    """The SSD recurrence over a whole window in matmul form.
+
+    ``x`` (b, T, h, p); ``dt`` (b, T, h) float32, after softplus; ``A`` (h,)
+    negative; ``B``, ``C`` (b, T, g, n); ``seg`` (b, T) int, 0 = the episode
+    ``state0`` (b, h, p, n) belongs to. Returns ``y`` (b, T, h, p) float32
+    and the state after the last step. Matmul operands in ``dtype``; decays,
+    cumulative sums and the carried state in float32."""
+    b, T, h, p = x.shape
+    g, n = B.shape[2:]
+    r = h // g
+    pad = (-T) % chunk
+    if pad:  # dt = 0: the state passes through, nothing is added
+        x, dt, B, C = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (x, dt, B, C)
+        )
+        seg = jnp.concatenate([seg, jnp.repeat(seg[:, -1:], pad, axis=1)], axis=1)
+    nc, Q = (T + pad) // chunk, chunk
+    cd = dtype or jnp.float32
+    f32 = jnp.float32
+    xc = x.reshape(b, nc, Q, h, p)
+    dtc = dt.reshape(b, nc, Q, h)
+    Bc = B.reshape(b, nc, Q, g, n).astype(cd)
+    Cc = C.reshape(b, nc, Q, g, n).astype(cd)
+    segc = seg.reshape(b, nc, Q)
+    # the segment a chunk is entered in: that of the step before it
+    seg_in = jnp.concatenate([jnp.zeros_like(segc[:, :1, 0]), segc[:, :-1, -1]], axis=1)
+
+    acum = jnp.cumsum((dtc * A).transpose(0, 1, 3, 2), axis=-1)  # (b, nc, h, Q)
+    dtx = xc.astype(f32) * dtc[..., None]  # (b, nc, Q, h, p)
+
+    def decay(exponent, keep):
+        return jnp.exp(jnp.where(keep, exponent, -jnp.inf))
+
+    # inside a chunk: step s reaches step l >= s of the same segment
+    reach = (segc[:, :, :, None] == segc[:, :, None, :]) & jnp.tril(jnp.ones((Q, Q), bool))
+    L = decay(acum[..., :, None] - acum[..., None, :], reach[:, :, None])  # (b,nc,h,l,s)
+    CB = jnp.einsum("bclgn,bcsgn->bcgls", Cc, Bc, preferred_element_type=f32)
+    M = (CB[:, :, :, None] * L.reshape(b, nc, g, r, Q, Q)).reshape(b, nc, h, Q, Q)
+    y = jnp.einsum(
+        "bchls,bcshp->bclhp", M.astype(cd), dtx.astype(cd), preferred_element_type=f32
+    )
+
+    # what each chunk adds to the state at its end
+    to_end = decay(acum[..., -1:] - acum, (segc == segc[:, :, -1:])[:, :, None])  # (b,nc,h,Q)
+    xw = (dtx * to_end.transpose(0, 1, 3, 2)[..., None]).astype(cd)
+    S = jnp.einsum(
+        "bcsgrp,bcsgn->bcgrpn", xw.reshape(b, nc, Q, g, r, p), Bc,
+        preferred_element_type=f32,
+    ).reshape(b, nc, h, p, n)
+    # what a chunk keeps of the state it is entered with: nothing past a seam
+    through = decay(acum[..., -1], (segc[:, :, -1] == seg_in)[:, :, None])  # (b, nc, h)
+
+    def across(state, c):
+        S_c, through_c = c
+        return through_c[..., None, None] * state + S_c, state
+
+    last, entered = jax.lax.scan(
+        across, state0.astype(f32),
+        (S.transpose(1, 0, 2, 3, 4), through.transpose(1, 0, 2)),
+    )
+    entered = entered.transpose(1, 0, 2, 3, 4)  # (b, nc, h, p, n): state before chunk c
+    into = decay(acum, (segc == seg_in[:, :, None])[:, :, None])  # (b, nc, h, Q)
+    y_in = jnp.einsum(
+        "bclgn,bcgrpn->bclgrp", Cc, entered.astype(cd).reshape(b, nc, g, r, p, n),
+        preferred_element_type=f32,
+    ).reshape(b, nc, Q, h, p)
+    y = y + y_in * into.transpose(0, 1, 3, 2)[..., None]
+    y = y + xc.astype(f32) * D[:, None]
+    return y.reshape(b, nc * Q, h, p)[:, :T], last
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Inverse softplus of step sizes log-uniform in [1e-3, 1e-1] (Mamba-2)."""
+    dt = jnp.exp(
+        jax.random.uniform(key, shape, dtype) * (np.log(0.1) - np.log(0.001))
+        + np.log(0.001)
+    )
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+class Mamba2Mixer(nn.Module):
+    arch: dict
+    dtype: Any = None
+
+    def setup(self):
+        a = self.arch
+        self.heads, self.d_head = a["mamba_n_heads"], a["mamba_d_head"]
+        self.groups, self.d_state = a["mamba_n_groups"], a["mamba_d_state"]
+        self.inner = self.heads * self.d_head
+        self.conv_ch = _conv_channels(a)
+        K = a["mamba_d_conv"]
+        proj = dict(use_bias=bool(a["mamba_proj_bias"]), dtype=self.dtype)
+        self.in_proj = nn.Dense(self.inner + self.conv_ch + self.heads, name="in_proj", **proj)
+        self.out_proj = nn.Dense(a["hidden_size"], name="out_proj", **proj)
+        self.conv_weight = self.param(
+            "conv_weight", nn.initializers.variance_scaling(1.0, "fan_in", "uniform", in_axis=0),
+            (K, self.conv_ch),
+        )
+        self.conv_bias = (
+            self.param("conv_bias", nn.initializers.zeros, (self.conv_ch,))
+            if a["mamba_conv_bias"] else jnp.zeros((self.conv_ch,))
+        )
+        self.dt_bias = self.param("dt_bias", _dt_bias_init, (self.heads,))
+        self.A_log = self.param("A_log", _a_log_init, (self.heads,))
+        self.D = self.param("D", nn.initializers.ones, (self.heads,))
+        self.norm_scale = self.param("norm_scale", nn.initializers.ones, (self.inner,))
+
+    def _split(self, u):
+        zxbcdt = self.in_proj(u)
+        z, xbc, dt = jnp.split(zxbcdt, [self.inner, self.inner + self.conv_ch], axis=-1)
+        return z, xbc, jax.nn.softplus(dt.astype(jnp.float32) + self.dt_bias)
+
+    def _heads(self, xbc):
+        """Convolved, activated ``xBC`` -> x (..., h, p), B and C (..., g, n)."""
+        gn = self.groups * self.d_state
+        x, B, C = jnp.split(jax.nn.silu(xbc), [self.inner, self.inner + gn], axis=-1)
+        lead = xbc.shape[:-1]
+        return (
+            x.reshape(*lead, self.heads, self.d_head),
+            B.reshape(*lead, self.groups, self.d_state),
+            C.reshape(*lead, self.groups, self.d_state),
+        )
+
+    def _out(self, y, z):
+        """Gated RMSNorm over each group's channels, then the output
+        projection. ``y`` float32 (..., inner)."""
+        lead = y.shape[:-1]
+        gated = (y * jax.nn.silu(z.astype(jnp.float32))).reshape(*lead, self.groups, -1)
+        normed = _rms_norm(gated, 1.0, self.arch["rms_norm_eps"]).reshape(*lead, self.inner)
+        return self.out_proj((normed * self.norm_scale).astype(self.dtype or jnp.float32))
+
+    def __call__(self, u, seg, state0, tail0):
+        """``u`` (B, T, d); ``state0`` (B, h, p, n), ``tail0`` (B, K-1, C):
+        the carry the window starts from. Returns the output and the carry
+        after the last step."""
+        z, xbc, dt = self._split(u)
+        x, B, C = self._heads(seam_conv(xbc, tail0, seg, self.conv_weight, self.conv_bias))
+        y, state = ssd_chunked(
+            x, dt, -jnp.exp(self.A_log), B, C, self.D, seg, state0,
+            self.arch["mamba_chunk_size"], self.dtype,
+        )
+        K = self.conv_weight.shape[0]
+        keep = (seg[:, -(K - 1):] == seg[:, -1:])[..., None]  # taps of the last episode only
+        tail = jnp.where(keep, xbc[:, -(K - 1):].astype(jnp.float32), 0.0)
+        return self._out(y.reshape(*y.shape[:2], self.inner), z), state, tail
+
+    def step(self, u, state, tail):
+        """One acting step: ``u`` (B, d)."""
+        z, xbc, dt = self._split(u)
+        window = jnp.concatenate([tail, xbc[:, None].astype(jnp.float32)], axis=1)
+        conv = jnp.einsum("bkc,kc->bc", window, self.conv_weight) + self.conv_bias
+        x, B, C = self._heads(conv)
+        r = self.heads // self.groups
+        x = x.astype(jnp.float32)
+        Bh, Ch = (jnp.repeat(a.astype(jnp.float32), r, axis=1) for a in (B, C))
+        keep = jnp.exp(dt * -jnp.exp(self.A_log))  # (B, h)
+        state = keep[..., None, None] * state + (dt[..., None] * x)[..., None] * Bh[:, :, None]
+        y = jnp.einsum("bhpn,bhn->bhp", state, Ch) + x * self.D[:, None]
+        return self._out(y.reshape(-1, self.inner), z), state, window[:, 1:]
+
+
+class GQAttention(nn.Module):
+    """Grouped-query attention without positions (``nope``), causal and
+    masked to the episode."""
+
+    arch: dict
+    dtype: Any = None
+
+    def setup(self):
+        a = self.arch
+        self.n_q, self.n_kv = a["num_attention_heads"], a["num_key_value_heads"]
+        self.head_dim = a["hidden_size"] // self.n_q
+        proj = dict(use_bias=bool(a["attention_bias"]), dtype=self.dtype)
+        self.q_proj = nn.Dense(self.n_q * self.head_dim, name="q_proj", **proj)
+        self.k_proj = nn.Dense(self.n_kv * self.head_dim, name="k_proj", **proj)
+        self.v_proj = nn.Dense(self.n_kv * self.head_dim, name="v_proj", **proj)
+        self.o_proj = nn.Dense(a["hidden_size"], name="o_proj", **proj)
+
+    def __call__(self, u, seg):
+        B, T, _ = u.shape
+        rep = self.n_q // self.n_kv
+        q = self.q_proj(u).reshape(B, T, self.n_q, self.head_dim)
+        # every key/value head serves `rep` consecutive query heads
+        k, v = (
+            jnp.repeat(p(u).reshape(B, T, self.n_kv, self.head_dim), rep, axis=2)
+            for p in (self.k_proj, self.v_proj)
+        )
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        o = flash_attention_tpu(
+            q, k, v, pos, seg, causal=True, sm_scale=self.arch["attention_multiplier"]
+        )
+        return self.o_proj(o.reshape(B, T, -1))
+
+    def step(self, u, k_cache, v_cache, count):
+        """One acting step over a K/V ring of ``ctx`` slots (B, ctx, kv, D);
+        ``count`` (B,) int: steps of this episode already cached. Without
+        positions the ring is an exact sliding window."""
+        B = u.shape[0]
+        ctx = k_cache.shape[1]
+        rep = self.n_q // self.n_kv
+        q = self.q_proj(u).reshape(B, self.n_kv, rep, self.head_dim)
+        k_new, v_new = (
+            p(u).reshape(B, 1, self.n_kv, self.head_dim) for p in (self.k_proj, self.v_proj)
+        )
+        write = (jnp.arange(ctx)[None] == jnp.mod(count, ctx)[:, None])[:, :, None, None]
+        k_cache = jnp.where(write, k_new.astype(k_cache.dtype), k_cache)
+        v_cache = jnp.where(write, v_new.astype(v_cache.dtype), v_cache)
+        valid = jnp.arange(ctx)[None] <= count[:, None]
+        scores = jnp.einsum(
+            "bgrd,btgd->bgrt", q, k_cache.astype(q.dtype), preferred_element_type=jnp.float32
+        ) * jnp.float32(self.arch["attention_multiplier"])
+        w = jax.nn.softmax(jnp.where(valid[:, None, None], scores, -jnp.inf), axis=-1)
+        o = jnp.einsum(
+            "bgrt,btgd->bgrd", w.astype(q.dtype), v_cache.astype(q.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        return self.o_proj(o.reshape(B, -1).astype(q.dtype)), k_cache, v_cache
+
+
+class HybridLayer(nn.Module):
+    """One published layer: the mixer of its kind, then the shared gated MLP,
+    each behind an RMSNorm and scaled by ``residual_multiplier``."""
+
+    arch: dict
+    kind: str  # "mamba" | "attention"
+    dtype: Any = None
+
+    def setup(self):
+        a = self.arch
+        norm = dict(eps=a["rms_norm_eps"], dtype=self.dtype)
+        self.input_norm = RMSNorm(name="input_norm", **norm)
+        self.post_norm = RMSNorm(name="post_norm", **norm)
+        mixer = Mamba2Mixer if self.kind == "mamba" else GQAttention
+        self.mixer = mixer(a, self.dtype, name=self.kind)
+        self.mlp_in = nn.Dense(
+            2 * a["intermediate_size"], use_bias=False, dtype=self.dtype, name="mlp_in"
+        )
+        self.mlp_out = nn.Dense(a["hidden_size"], use_bias=False, dtype=self.dtype, name="mlp_out")
+
+    def _mlp(self, x):
+        a, b = jnp.split(self.mlp_in(self.post_norm(x)), 2, axis=-1)
+        return x + self.arch["residual_multiplier"] * self.mlp_out(jax.nn.silu(a) * b)
+
+    def __call__(self, x, seg, *carry):
+        """Training window. ``carry``: the Mamba layer's (state0, tail0)."""
+        mixed, *carry = (
+            self.mixer(self.input_norm(x), seg, *carry)
+            if self.kind == "mamba" else (self.mixer(self.input_norm(x), seg),)
+        )
+        x = x + self.arch["residual_multiplier"] * mixed
+        return (self._mlp(x), *carry)
+
+    def step(self, x, *carry):
+        mixed, *carry = self.mixer.step(self.input_norm(x), *carry)
+        x = x + self.arch["residual_multiplier"] * mixed
+        return (self._mlp(x), *carry)
+
+
+class GraniteHybridActorCritic(nn.Module):
+    n_actions: int
+    arch: dict
+    act_ctx: int  # slots of the acting K/V ring
+    dtype: Any = None  # matmul operand dtype; the residual stream is float32
+    remat: bool = True  # tests only: the gradients must not depend on it
+
+    def setup(self):
+        a = self.arch
+        self.embed = nn.Dense(a["hidden_size"], name="embed", dtype=self.dtype)
+        layer = nn.remat(HybridLayer) if self.remat else HybridLayer
+        self.layers = [
+            layer(a, kind, self.dtype, name=f"layer{i}")
+            for i, kind in enumerate(a["layer_types"])
+        ]
+        self.norm_f = RMSNorm(a["rms_norm_eps"], name="norm_f")
+        self.logits_head = nn.Dense(self.n_actions, name="logits")
+        self.value_head = nn.Dense(1, name="value")
+        self.h_width, self.c_width = carry_widths(a, self.act_ctx)
+        self.state_shape = (a["mamba_n_heads"], a["mamba_d_head"], a["mamba_d_state"])
+        self.tail_shape = (a["mamba_d_conv"] - 1, _conv_channels(a))
+        self.kv_shape = (
+            self.act_ctx, a["num_key_value_heads"], a["hidden_size"] // a["num_attention_heads"]
+        )
+
+    def _embed(self, obs):
+        return self.arch["embedding_multiplier"] * self.embed(obs).astype(jnp.float32)
+
+    def _heads(self, x):
+        h = self.norm_f(x)
+        logits = self.logits_head(h) / self.arch["logits_scaling"]
+        return jax.nn.log_softmax(logits), self.value_head(h)
+
+    def _unpack_h(self, h):
+        """(B, h_width) -> one (state, tail) per Mamba layer, float32."""
+        n_state, n_tail = int(np.prod(self.state_shape)), int(np.prod(self.tail_shape))
+        per = h.reshape(h.shape[0], -1, n_state + n_tail)
+        return [
+            (
+                per[:, i, :n_state].reshape(-1, *self.state_shape),
+                per[:, i, n_state:].reshape(-1, *self.tail_shape),
+            )
+            for i in range(per.shape[1])
+        ]
+
+    @staticmethod
+    def _pack(pairs, B):
+        return jnp.concatenate(
+            [jnp.zeros((B, 0), jnp.float32)]
+            + [a.reshape(B, -1).astype(jnp.float32) for pair in pairs for a in pair],
+            axis=1,
+        )
+
+    def __call__(self, obs, carry0, firsts):
+        """``carry0 = (h, c)``: ``h`` of the acting width is the state the
+        window starts from; any other width (the batch's 1-float placeholder)
+        means zeros. ``c`` is returned as it came."""
+        B = obs.shape[0]
+        h0, c0 = carry0
+        if h0.shape[-1] != self.h_width:
+            h0 = jnp.zeros((B, self.h_width), jnp.float32)
+        seg = segment_ids_from_firsts(firsts)
+        x = self._embed(obs)
+        mamba = iter(self._unpack_h(h0))
+        carried = []
+        for layer in self.layers:
+            if layer.kind == "mamba":
+                x, state, tail = layer(x, seg, *next(mamba))
+                carried.append((state, tail))
+            else:
+                (x,) = layer(x, seg)
+        logits, value = self._heads(x)
+        return logits, value, (self._pack(carried, B), c0)
+
+    unroll = __call__
+
+    def act(self, obs, h, c):
+        """One step for every row of ``obs`` (B, obs_dim)."""
+        B = obs.shape[0]
+        count = c[:, -1].astype(jnp.int32)
+        kv = c[:, :-1].reshape(B, -1, 2, *self.kv_shape)  # (B, attention layers, k|v, ...)
+        x = self._embed(obs)
+        mamba, attn = iter(self._unpack_h(h)), 0
+        carried, caches = [], []
+        for layer in self.layers:
+            if layer.kind == "mamba":
+                x, state, tail = layer.step(x, *next(mamba))
+                carried.append((state, tail))
+            else:
+                x, k, v = layer.step(x, kv[:, attn, 0], kv[:, attn, 1], count)
+                caches.append((k, v))
+                attn += 1
+        logits, value = self._heads(x)
+        c2 = jnp.concatenate(
+            [self._pack(caches, B), (count + 1).astype(jnp.float32)[:, None]], axis=1
+        )
+        return logits, value, (self._pack(carried, B), c2)

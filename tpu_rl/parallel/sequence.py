@@ -402,11 +402,12 @@ def full_attention(
     seg: jax.Array,
     axis_name: str | None = None,
     causal: bool = True,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Single-device reference implementation (same contract, no sharding).
     This is also the implementation the transformer uses when no seq mesh is
-    in scope."""
-    scale = 1.0 / np.sqrt(q.shape[-1])
+    in scope. ``sm_scale``: the softmax scale, ``1/sqrt(head_dim)`` if None."""
+    scale = 1.0 / np.sqrt(q.shape[-1]) if sm_scale is None else sm_scale
     scores = _masked_block_scores(q, k, q_pos, q_pos, seg, seg, scale, causal)
     p = jax.nn.softmax(scores, axis=-1)
     # Output in q.dtype, matching ring/blockwise (which cast their f32
@@ -616,11 +617,13 @@ def flash_attention_tpu(
     seg: jax.Array,
     axis_name: str | None = None,
     causal: bool = True,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Single-device fused attention via the Pallas TPU flash-attention
     kernel that ships with JAX (``jax.experimental.pallas.ops.tpu
     .flash_attention``; custom-VJP fwd+bwd Mosaic kernels). Same contract as
-    :func:`full_attention`.
+    :func:`full_attention`. The kernel takes equal head counts: a caller
+    with grouped key/value heads repeats them first.
 
     Masking equivalence: the kernel takes ``causal`` (by global index) plus
     ``SegmentIds`` — identical to our ``q_pos >= k_pos`` + same-segment mask
@@ -650,13 +653,13 @@ def flash_attention_tpu(
         # not tiles: a multi-device program whose batch does not tile the
         # mesh (init trace) — a bare Mosaic custom call has no GSPMD
         # partitioning rule, so take the partitionable jnp path.
-        return full_attention(q, k, v, q_pos, seg, causal=causal)
+        return full_attention(q, k, v, q_pos, seg, causal=causal, sm_scale=sm_scale)
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         SegmentIds,
         flash_attention as _pallas_flash,
     )
 
-    scale = 1.0 / np.sqrt(q.shape[-1])
+    scale = 1.0 / np.sqrt(q.shape[-1]) if sm_scale is None else sm_scale
     # The library's get_default() is 128 everywhere ("TODO: select better
     # parameters" upstream); the tile edge must divide T.
     blk = _select_block_size(q.shape[1], head_dim=q.shape[-1])

@@ -59,7 +59,7 @@ from tpu_rl.runtime.mailbox import (
     SLOT_RUN_EPOCH,
 )
 from tpu_rl.runtime.manager import STAT_WINDOW
-from tpu_rl.runtime.protocol import Protocol
+from tpu_rl.runtime.protocol import Protocol, fits_frame
 from tpu_rl.runtime.transport import MODEL_HWM, Pub, make_data_pub
 from tpu_rl.utils.metrics import LearnerLogger, make_writer
 from tpu_rl.utils.timer import ExecutionTimer
@@ -261,6 +261,11 @@ class LearnerService:
         # in the membership table): the joiner gets weights+ver now instead
         # of waiting out rebroadcast_idle_s.
         self.n_join_pushes = 0
+        # Broadcasts not made because the actor tree cannot be framed
+        # (protocol.fits_frame): such a policy is served, not shipped
+        # (act_mode="remote"). None until the first publish has looked.
+        self.n_publish_oversize = 0
+        self._broadcast_fits: bool | None = None
         self._ckpt = None  # Checkpointer while cfg.model_dir is set
         # Self-healing plane (tpu_rl.heal): cumulative guard-skipped updates
         # (host mirror of the on-device accumulator, refreshed at the
@@ -1151,6 +1156,27 @@ class LearnerService:
             if hasattr(state, "actor_params")
             else state.params["actor"]
         )
+        if self._broadcast_fits is None:
+            import jax
+
+            nbytes = sum(x.nbytes for x in jax.tree.leaves(actor))
+            self._broadcast_fits = fits_frame(nbytes)
+            if not self._broadcast_fits:
+                if self.cfg.act_mode != "remote":
+                    raise ValueError(
+                        f"the actor tree ({nbytes} bytes) exceeds the model "
+                        "broadcast's frame cap: no worker could receive it. "
+                        "Set act_mode='remote' (the fleet acts through the "
+                        "learner-side inference service)."
+                    )
+                print(
+                    f"[learner] actor tree of {nbytes} bytes exceeds the "
+                    "broadcast frame cap: no weights are broadcast "
+                    "(act_mode='remote' serves them in-process)", flush=True,
+                )
+        if not self._broadcast_fits:
+            self.n_publish_oversize += 1  # no snapshot, no pack, no send
+            return
         if self._publisher is not None:
             self._publisher.publish(actor, ver, epoch=self.run_epoch)
         else:
@@ -1332,6 +1358,7 @@ class LearnerService:
         reg.counter("learner-rebroadcasts").set_total(self.n_rebroadcasts)
         reg.gauge("learner-run-epoch").set(self.run_epoch)
         reg.counter("learner-join-pushes").set_total(self.n_join_pushes)
+        reg.counter("learner-publish-oversize").set_total(self.n_publish_oversize)
         if self._publisher is not None:
             # sent / snapshots = the share of snapshots that reach the wire;
             # the rest were superseded in the latest-wins slot.

@@ -302,6 +302,19 @@ _MIN_COMPRESS = 128  # bytes; below this, framing overhead beats compression
 # covers the largest legitimate payload (a full model broadcast).
 _MAX_RAW = 1 << 30
 
+
+# What pack() adds to a tree's array bytes: names, dtypes and shapes of its
+# leaves, and the scalars sent with it.
+_FRAMING_SLACK = 1 << 20
+
+
+def fits_frame(array_bytes: int) -> bool:
+    """Whether a payload holding this many array bytes can be framed at all:
+    every receiver rejects a frame that declares more than ``_MAX_RAW``, so a
+    sender asks before it copies anything (:func:`pack` copies the payload,
+    the codec copies it again)."""
+    return array_bytes + _FRAMING_SLACK <= _MAX_RAW
+
 # Standard IEEE CRC-32 (zlib's C implementation; interoperates with the
 # native tpurl_crc32, which implements the same polynomial).
 _crc = zlib.crc32
@@ -386,6 +399,8 @@ def encode(
     ``utils/utils.py:244-245``), plus the optional trace-context trailer as a
     third part (see :func:`pack_trace`)."""
     raw = pack(payload)
+    if len(raw) > _MAX_RAW:  # no receiver would take it (peek / decode)
+        raise ValueError(f"payload of {len(raw)} bytes exceeds the frame cap {_MAX_RAW}")
     if len(raw) < _MIN_COMPRESS:
         codec, body = Codec.RAW, raw
     elif native.available():
