@@ -278,11 +278,10 @@ WORKLOADS: list[tuple[str, dict, int, int, int]] = [
         ),
         3, 20, 1,
     ),
-    # Pallas TPU fused-attention kernel (parallel/sequence.py
-    # flash_attention_tpu) at the same 2x batch the blockwise row buys, with
-    # gcd(512,T) uniform BlockSizes: the kernel keeps blockwise's O(T)
-    # memory; whether it beats the rows above is not measured on current
-    # code (ROADMAP S2).
+    # The library's splash kernel (parallel/sequence.py flash_attention_tpu,
+    # tiles from _splash_block_sizes) at the same 2x batch the blockwise row
+    # buys: the kernel keeps blockwise's O(T) memory. Its time per layer is
+    # examples/bench_flash_attention.py's to measure; the cells' is PERF.md's.
     (
         "PPO-transformer@longctx-flash",
         dict(

@@ -384,7 +384,13 @@ TOL_BF16 = 3e-2
 def kernel_checks(
     lstm_shapes=((128, 5, 64, "auto"), (256, 16, 256, "auto"), (1024, 16, 1024, "force")),
     act_shapes=((8, 4, 64, 2), (256, 4, 64, 2), (8, 64, 1024, 8), (256, 64, 1024, 8)),
-    flash_shapes=((16, 2048, 8, 64, True), (1, 512, 8, 64, False)),
+    # (B, T, heads, key/value heads, head dim, softmax scale, with gradients):
+    # tf-longctx's layer, a forward-only row, granite-4.0-h-micro's layer
+    flash_shapes=(
+        (16, 2048, 8, 8, 64, None, True),
+        (1, 512, 8, 8, 64, None, False),
+        (2, 4096, 32, 8, 64, 1.0 / 64, True),
+    ),
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -481,15 +487,16 @@ def kernel_checks(
             TOL_F32, TOL_ACT_SAME,
         )
 
-    # ---- library flash attention with the dispatch's tiles vs full attention
+    # ---- the library's splash kernel with the dispatch's tiles vs full attention
     from tpu_rl.parallel.sequence import (
-        _select_block_size,
+        _splash_block_sizes,
         flash_attention_tpu,
         full_attention,
     )
 
-    for B, T, NH, D, grad in flash_shapes:
-        q, k, v = (f32(B, T, NH, D).astype(jnp.bfloat16) for _ in range(3))
+    for B, T, NH, NKV, D, sm_scale, grad in flash_shapes:
+        q = f32(B, T, NH, D).astype(jnp.bfloat16)
+        k, v = (f32(B, T, NKV, D).astype(jnp.bfloat16) for _ in range(2))
         firsts = np.zeros((B, T), np.int32)
         firsts[:, 0] = 1
         firsts[:, T // 3] = 1  # an episode seam inside the window
@@ -500,12 +507,13 @@ def kernel_checks(
 
         def flash(q, k, v, n=B):
             return flash_attention_tpu(
-                q[:n], k[:n], v[:n], pos[:n], seg[:n], causal=True
+                q[:n], k[:n], v[:n], pos[:n], seg[:n], causal=True, sm_scale=sm_scale
             )
 
         def full(q, k, v, n=B):  # f32 reference on the same bf16 inputs
             q, k, v = (x[:n].astype(jnp.float32) for x in (q, k, v))
-            return full_attention(q, k, v, pos[:n], seg[:n], causal=True)
+            k, v = (jnp.repeat(x, NH // NKV, axis=2) for x in (k, v))
+            return full_attention(q, k, v, pos[:n], seg[:n], causal=True, sm_scale=sm_scale)
 
         def grads(impl, n):
             def loss(q, k, v):
@@ -517,9 +525,10 @@ def kernel_checks(
             )
 
         got_fn, ref_fn = (grads(flash, B), grads(full, n_ref)) if grad else (flash, full)
+        bs = _splash_block_sizes(T)
         case(
-            f"flash {'fwd+bwd' if grad else 'fwd'} B{B}/T{T}/H{NH}/D{D} bf16 "
-            f"(tiles {_select_block_size(T, D)})",
+            f"flash {'fwd+bwd' if grad else 'fwd'} B{B}/T{T}/H{NH}:{NKV}/D{D} bf16 "
+            f"(tiles {bs and (bs.block_q, bs.block_kv, bs.block_q_dkv, bs.block_kv_dkv)})",
             got_fn, ref_fn, (q, k, v), TOL_BF16, TOL_BF16,
             # off-TPU the dispatch substitutes full attention by design
             mosaic=jax.default_backend() == "tpu",

@@ -1,29 +1,23 @@
-"""Attention-impl microbench at the long-context workload shape.
+"""Attention microbench at the shapes the benchmark's cells run: the sweep
+behind ``tpu_rl/parallel/sequence._splash_block_sizes``.
 
-Round-4 on-chip bench showed the stock-default flash row LOSING to both
-full attention and blockwise at (B16, T2048, H8, D64):
+Times the PRODUCTION construction (``sequence._splash_mha``: scale
+folded into q, the transposes, ``vmap`` over rows, causal + segment mask),
+forward alone and forward + backward, over tile edges x {single-pass,
+two-pass backward} x compute sub-tiles, beside ``blockwise`` and
+the library kernel the dispatch called before PR 27 (``legacy@512``). The row
+``rule`` is what the dispatch picks. A variant the compiler refuses is a row
+with an ``error``, not a crash.
 
-    full 72.0 ms/step, blockwise 136.2, flash 190.7   (whole train step)
+Run ON the TPU (seconds per row):
 
-This isolates the attention op itself (fwd and fwd+grad) and sweeps the
-Pallas kernel's BlockSizes — the defaults are 128-everywhere with
-block_b=1 (`BlockSizes.get_default`, annotated "TODO: select better
-parameters"), which at this shape means a 128x16x16 grid of tiny tiles.
-The result decides the dispatch policy in
-`tpu_rl/parallel/sequence.flash_attention_tpu` (measured-win-only, the
-same lesson as the LSTM kernel: VERDICT r3 #5).
-
-Run ON the TPU:
-
-    PYTHONPATH=/root/repo python examples/bench_flash_attention.py
-
-Writes bench_flash.json next to the repo root (untracked: the sweep this
-file was written around is gone with the machine it ran on; ROADMAP S2
-re-measures).
+    python examples/bench_flash_attention.py [--out chiprun_out/bench_flash.json]
+        [--shapes tf-longctx,granite] [--only rule,legacy@512]
 """
 
 from __future__ import annotations
 
+import argparse
 import functools
 import json
 import os
@@ -38,23 +32,23 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from tpu_rl.parallel import sequence as seqlib
 
-B, T, H, D = 16, 2048, 8, 64
+# name -> (B, T, H, Hkv, D, sm_scale): one layer of each registered configuration
+SHAPES = {
+    "tf-longctx": (32, 2048, 8, 8, 64, None),
+    "granite": (2, 4096, 32, 8, 64, 1.0 / 64),
+}
 DTYPE = jnp.bfloat16
-WARMUP, ITERS = 3, 20
+WARMUP, ITERS = 2, 10
 
 
-def _inputs():
+def _inputs(B, T, H, Hkv, D):
     rng = np.random.default_rng(0)
-    shape = (B, T, H, D)
-    q = jnp.asarray(rng.normal(size=shape), DTYPE) * 0.1
-    k = jnp.asarray(rng.normal(size=shape), DTYPE) * 0.1
-    v = jnp.asarray(rng.normal(size=shape), DTYPE) * 0.1
-    # Two episode segments per row, seam mid-sequence — exercises the
-    # segment mask the real workload always carries.
-    firsts = np.zeros((B, T, 1), np.float32)
-    firsts[:, 0] = 1.0
-    firsts[:, T // 2] = 1.0
-    seg = seqlib.segment_ids_from_firsts(jnp.asarray(firsts))
+    q = jnp.asarray(rng.normal(size=(B, T, H, D)), DTYPE)
+    k, v = (jnp.asarray(rng.normal(size=(B, T, Hkv, D)), DTYPE) for _ in range(2))
+    # ~8 seams a row (the granite cell's traffic: episodes of mean T / 8)
+    firsts = rng.random((B, T, 1)) < 8.0 / T
+    firsts[:, 0] = True
+    seg = seqlib.segment_ids_from_firsts(jnp.asarray(firsts, jnp.float32))
     q_pos = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
     return q, k, v, q_pos, seg
 
@@ -80,69 +74,124 @@ def _time(fn, *args) -> float:
     return (time.perf_counter() - t0) / ITERS * 1e3
 
 
-def _flash_fn(block: int | None):
+def _tiles(bq, bkv, compute, fused, seq_minor=""):
+    """BlockSizes with (bq, bkv) tiles in both passes, ``compute`` columns per
+    inner step, and the operands named in ``seq_minor`` ("kv": k and v) laid
+    out (head dim, T) in HBM instead of (T, head dim)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes, QKVLayout
+
+    dq = {} if fused else dict(block_q_dq=bq, block_kv_dq=bkv)
+    layouts = {f"{x}_layout": QKVLayout.SEQ_MINOR for x in seq_minor}
+    return BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=min(compute, bkv),
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=min(compute, bkv),
+        use_fused_bwd_kernel=fused, **dq, **layouts,
+    )
+
+
+def _splash_fn(block_sizes, scale):
+    def fn(q, k, v, q_pos, seg):
+        return seqlib._splash_mha(
+            q, k, v, seg, causal=True, scale=scale, block_sizes=block_sizes
+        )
+
+    return fn
+
+
+def _legacy_fn(scale, rep):
+    """What ``flash_attention_tpu`` called until PR 27, as it called it."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
+        BlockSizes,
         SegmentIds,
         flash_attention,
     )
 
-    from tpu_rl.parallel.sequence import _uniform_block_sizes
-
-    bs = None if block is None else _uniform_block_sizes(min(block, T))
+    names = (
+        "block_q block_k_major block_k block_q_major_dkv block_k_major_dkv block_k_dkv "
+        "block_q_dkv block_k_major_dq block_k_dq block_q_dq"
+    ).split()
+    bs = BlockSizes(block_b=1, **dict.fromkeys(names, 512))
 
     def fn(q, k, v, q_pos, seg):
+        k, v = (jnp.repeat(x, rep, axis=2) for x in (k, v))
         qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
         seg32 = seg.astype(jnp.int32)
         o = flash_attention(
             qt, kt, vt, segment_ids=SegmentIds(q=seg32, kv=seg32),
-            causal=True, sm_scale=float(1.0 / np.sqrt(D)), block_sizes=bs,
+            causal=True, sm_scale=scale, block_sizes=bs,
         )
         return o.transpose(0, 2, 1, 3)
 
     return fn
 
 
-def main() -> None:
-    q, k, v, q_pos, seg = _inputs()
-    impls: dict[str, object] = {
-        "full": functools.partial(seqlib.full_attention, causal=True),
-        "blockwise": functools.partial(seqlib.blockwise_attention, causal=True),
-        "flash@128(default)": _flash_fn(None),
-        "flash@256": _flash_fn(256),
-        "flash@512": _flash_fn(512),
-        "flash@1024": _flash_fn(1024),
-        "flash@2048": _flash_fn(2048),
+def _impls(B, T, H, Hkv, D, sm_scale):
+    rep = H // Hkv
+    scale = float(1.0 / np.sqrt(D) if sm_scale is None else sm_scale)
+    impls = {
+        "rule": _splash_fn(seqlib._splash_block_sizes(T), scale),
+        "legacy@512": _legacy_fn(scale, rep),
     }
-    rows = []
-    for name, fn in impls.items():
-        row = {"name": name, "shape": [B, T, H, D], "dtype": "bfloat16"}
-        try:
-            fwd = jax.jit(fn)
-            row["fwd_ms"] = round(_time(fwd, q, k, v, q_pos, seg), 3)
+    # one edge everywhere; a whole-T tile (2048, 4096) does not fit VMEM
+    for edge in (512, 1024, 256):
+        for fused in (True, False):
+            for compute in sorted({min(512, edge), 256}, reverse=True):
+                name = f"splash@{edge}/c{compute}/{'fused' if fused else 'two-pass'}"
+                impls[name] = _splash_fn(_tiles(edge, edge, compute, fused), scale)
+    # long q tiles over short k/v tiles and the reverse, single pass
+    for bq, bkv in ((1024, 512), (2048, 512), (512, 1024), (512, 2048), (2048, 1024), (1024, 2048)):
+        impls[f"splash@q{bq}xkv{bkv}/c512/fused"] = _splash_fn(
+            _tiles(bq, bkv, 512, True), scale
+        )
+    impls["splash@1024/c1024/fused"] = _splash_fn(_tiles(1024, 1024, 1024, True), scale)
+    # operands transposed in HBM (head dim 64 fills half a 128-lane row)
+    for seq_minor in ("k", "v", "kv", "qkv"):
+        impls[f"splash@1024/c512/fused/{seq_minor}-seq-minor"] = _splash_fn(
+            _tiles(1024, 1024, 512, True, seq_minor), scale
+        )
+    if sm_scale is None and rep == 1:  # the plain-jnp tiled path, for scale
+        impls["blockwise"] = functools.partial(seqlib.blockwise_attention, causal=True)
+    return impls
 
-            def loss(q_, k_, v_):
-                return jnp.sum(fn(q_, k_, v_, q_pos, seg).astype(jnp.float32))
 
-            grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-            row["fwdbwd_ms"] = round(_time(grad, q, k, v), 3)
-        except Exception as e:  # noqa: BLE001 — record the failure, keep rows
-            row["error"] = f"{type(e).__name__}: {e}"[:300]
-        rows.append(row)
-        print(json.dumps(row), flush=True)
-
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--out", default=os.path.join(os.path.dirname(__file__), "..", "bench_flash.json"))
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--only", default="", help="comma-separated row names; all if empty")
+    args = ap.parse_args()
+    only = set(filter(None, args.only.split(",")))
     out = {
         "device_kind": jax.devices()[0].device_kind,
         "backend": jax.default_backend(),
         "warmup": WARMUP,
         "iters": ITERS,
-        "rows": rows,
+        "rows": [],
     }
-    path = os.path.join(os.path.dirname(__file__), "..", "bench_flash.json")
-    if jax.default_backend() != "tpu":
-        path = path.replace(".json", ".cpu.json")
-    with open(path, "w") as f:
-        json.dump(out, f, indent=1)
-    print("wrote", os.path.normpath(path))
+    for shape_name in args.shapes.split(","):
+        B, T, H, Hkv, D, sm_scale = SHAPES[shape_name]
+        q, k, v, q_pos, seg = _inputs(B, T, H, Hkv, D)
+        for name, fn in _impls(B, T, H, Hkv, D, sm_scale).items():
+            if only and name not in only:
+                continue
+            row = {"shape": shape_name, "name": name, "dims": [B, T, H, Hkv, D]}
+            try:
+                row["fwd_ms"] = round(_time(jax.jit(fn), q, k, v, q_pos, seg), 3)
+
+                def loss(q_, k_, v_, fn=fn):
+                    return jnp.sum(fn(q_, k_, v_, q_pos, seg).astype(jnp.float32))
+
+                grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+                row["fwdbwd_ms"] = round(_time(grad, q, k, v), 3)
+                row["bwd_ms"] = round(row["fwdbwd_ms"] - row["fwd_ms"], 3)
+            except Exception as e:  # noqa: BLE001 — record the failure, keep rows
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            out["rows"].append(row)
+            print(json.dumps(row), flush=True)
+            os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+            with open(args.out, "w") as f:  # after every row: a crash keeps the rest
+                json.dump(out, f, indent=1)
+    print("wrote", os.path.normpath(args.out))
 
 
 if __name__ == "__main__":

@@ -52,10 +52,14 @@ def test_kernel_checks_pass_tiny_interpreted():
     rows = chip_smoke.kernel_checks(
         lstm_shapes=((8, 3, 16, "auto"), (16, 3, 16, "force")),
         act_shapes=((8, 4, 16, 2),),
-        flash_shapes=((2, 128, 2, 16, True), (1, 128, 2, 16, False)),
+        flash_shapes=(
+            (2, 128, 2, 2, 16, None, True),
+            (1, 128, 2, 2, 16, None, False),
+            (2, 128, 4, 2, 16, 1.0 / 64, True),
+        ),
         interpret=True,
     )
-    assert len(rows) == 5
+    assert len(rows) == 6
     assert all(r["ok"] for r in rows), rows
 
 

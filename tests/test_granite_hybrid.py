@@ -377,11 +377,13 @@ def test_the_default_softmax_scale_is_bit_identical(impl):
     assert not np.allclose(default, impl(q, k, v, pos, seg, sm_scale=0.015625))
 
 
-def test_repeated_key_value_heads_equal_grouped_attention():
-    """Key/value head g serves query heads 2g and 2g+1."""
+@pytest.mark.parametrize("rep", [1, 2], ids=["unrepeated", "repeated"])
+def test_repeated_key_value_heads_equal_grouped_attention(rep):
+    """Key/value head g serves query heads 2g and 2g+1, whether the caller
+    repeats the heads or hands them over as they are."""
     q, k, v, pos, seg = qkv(2, seed=1)
     scale = 0.3
-    got = flash_attention_tpu(q, jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2),
+    got = flash_attention_tpu(q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
                               pos, seg, sm_scale=scale)
     grouped = q.reshape(2, 16, 2, 2, 8)
     scores = jnp.einsum("btgrd,bsgd->bgrts", grouped, k) * scale

@@ -236,62 +236,73 @@ class TestBlockwiseAttention:
         )
 
 
+def _segment_relative(seg):
+    """Positions that restart at every seam, as the transformer passes them."""
+    seg_np = np.asarray(seg)
+    idx = np.broadcast_to(np.arange(seg_np.shape[1], dtype=np.int32), seg_np.shape)
+    seam = np.concatenate(
+        [np.ones_like(seg_np[:, :1], bool), seg_np[:, 1:] != seg_np[:, :-1]], axis=1
+    )
+    starts = np.maximum.accumulate(np.where(seam, idx, 0), axis=1)
+    return jnp.asarray(idx - starts)
+
+
 class TestFlashImpl:
-    """The "flash" impl (Pallas TPU fused kernel, tpu_rl.parallel.sequence
-    .flash_attention_tpu). Mosaic kernels cannot execute on the CPU test
-    backend, so these tests pin the two facts the TPU path relies on:
-    (1) the kernel's argument encoding — causal-by-index + SegmentIds +
-    sm_scale — computes OUR mask contract (verified against mha_reference,
-    the library's pure-jnp spec of the kernel), and (2) off-TPU the impl
-    falls back to full_attention exactly."""
+    """The "flash" impl (the library's splash kernel, tpu_rl.parallel.sequence
+    .flash_attention_tpu). The Mosaic kernel cannot execute on the CPU test
+    backend, but its arithmetic can: the production construction
+    (``_splash_mha``: scale folded into q, causal-by-index +
+    ``SegmentIds``, grouped heads unrepeated, the rule's backward form) runs in
+    interpret mode against full_attention, forward and gradients. Off-TPU the
+    impl itself falls back to full_attention exactly."""
 
-    def _reference(self, q, k, v, seg):
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            SegmentIds,
-            mha_reference,
-        )
+    @pytest.mark.parametrize("sm_scale", [None, 1.0 / 64], ids=["scale-default", "scale-1/64"])
+    @pytest.mark.parametrize("positions", ["global", "segment-relative"])
+    @pytest.mark.parametrize("n_kv", [4, 2], ids=["equal-heads", "grouped-4:2"])
+    def test_splash_matches_full_attention(self, rng, n_kv, positions, sm_scale):
+        """T 256 in tiles of 128 (a diagonal, an interior and a skipped tile
+        per head), head dim 64, 4 segments a row. Segment-relative positions
+        must still equal causal-by-global-index: positions are monotone within
+        a segment and the segment mask kills every cross-segment pair."""
+        import dataclasses
 
-        scale = 1.0 / np.sqrt(q.shape[-1])
-        tr = lambda x: x.transpose(0, 2, 1, 3)
-        out = mha_reference(
-            tr(q), tr(k), tr(v), None,
-            segment_ids=SegmentIds(q=seg, kv=seg),
-            causal=True, sm_scale=float(scale),
-        )
-        return tr(out)
+        from tpu_rl.parallel.sequence import _splash_mha, _splash_block_sizes
 
-    def test_kernel_spec_matches_full_attention(self, rng):
-        """Global positions (the _inputs default)."""
-        q, k, v, pos, seg = _inputs(rng, T=32)
-        want = full_attention(q, k, v, pos, seg, causal=True)
-        got = self._reference(q, k, v, seg)
-        # mha_reference matmuls in bf16 precision; masking disagreements
-        # would produce O(1) differences, not 1e-2.
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=3e-2, atol=3e-2
-        )
+        T, H, D = 256, 4, 64
+        q, k, v, pos, seg = _inputs(rng, T=T, H=H, D=D, n_segments=4)
+        k, v = k[:, :, :n_kv], v[:, :, :n_kv]
+        if positions == "segment-relative":
+            pos = _segment_relative(seg)
+            assert int((np.asarray(pos) == 0).sum(axis=1).min()) >= 4
+        scale = 1.0 / np.sqrt(D) if sm_scale is None else sm_scale
+        rule = _splash_block_sizes(T)
+        tiles = dataclasses.replace(rule, **{
+            f.name: 128 for f in dataclasses.fields(rule)
+            if f.name.startswith("block_") and getattr(rule, f.name) is not None
+        })
+        cot = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
 
-    def test_kernel_spec_matches_segment_relative_positions(self, rng):
-        """The transformer passes SEGMENT-RELATIVE positions (restart at
-        seams); causal-by-global-index must still be equivalent because
-        positions are monotone within a segment and the segment mask kills
-        cross-segment pairs."""
-        q, k, v, _, seg = _inputs(rng, T=32, n_segments=4)
-        idx = np.broadcast_to(np.arange(32, dtype=np.int32), seg.shape)
-        seg_np = np.asarray(seg)
-        # position of each row within its segment
-        starts = np.zeros_like(idx)
-        for b in range(seg_np.shape[0]):
-            for t in range(1, 32):
-                starts[b, t] = (
-                    t if seg_np[b, t] != seg_np[b, t - 1] else starts[b, t - 1]
-                )
-        pos_rel = jnp.asarray(idx - starts)
-        want = full_attention(q, k, v, pos_rel, seg, causal=True)
-        got = self._reference(q, k, v, seg)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=3e-2, atol=3e-2
-        )
+        def splash(q, k, v):
+            out = _splash_mha(
+                q, k, v, seg, causal=True, scale=float(scale),
+                block_sizes=tiles, interpret=True,
+            )
+            return (out * cot).sum(), out
+
+        def full(q, k, v):
+            kr, vr = (jnp.repeat(x, H // n_kv, axis=2) for x in (k, v))
+            out = full_attention(q, kr, vr, pos, seg, causal=True, sm_scale=sm_scale)
+            return (out * cot).sum(), out
+
+        grad = lambda f: jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        got_g, got = grad(splash)
+        want_g, want = grad(full)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+        for name, g, w in zip(("dq", "dk", "dv"), got_g, want_g):
+            assert g.shape == w.shape
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-5, err_msg=name
+            )
 
     def test_falls_back_to_full_off_tpu(self, rng):
         from tpu_rl.parallel.sequence import flash_attention_tpu
@@ -303,29 +314,28 @@ class TestFlashImpl:
         got = flash_attention_tpu(q, k, v, pos, seg, causal=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
-    def test_block_size_selection(self):
-        """The flash tile rule (gcd(512, T), min 128), asserted on
-        the PRODUCTION selector the dispatch calls: uniform gcd(512, T)
-        tiles when >= 128 (the kernel's minimum), library defaults (None)
-        otherwise. Every selected edge must divide T (grid exactness)."""
-        from tpu_rl.parallel.sequence import (
-            _select_block_size,
-            _uniform_block_sizes,
-        )
+    @pytest.mark.parametrize("T", [128, 256, 384, 512, 1536, 2048, 4096])
+    def test_block_size_selection(self, T):
+        """The tile rule, asserted on the PRODUCTION selector the dispatch
+        calls: every edge divides T (grid exactness) and its compute edge,
+        and the backward has its tiles."""
+        from tpu_rl.parallel.sequence import _splash_block_sizes
 
-        for T, want in [(2048, 512), (512, 512), (384, 128), (256, 256),
-                        (128, 128), (1536, 512)]:
-            blk = _select_block_size(T)
-            assert blk == want and T % blk == 0, (T, blk, want)
-            bs = _uniform_block_sizes(blk)
-            assert bs.block_q == bs.block_k == bs.block_q_dq == blk
-            assert bs.has_backward_blocks  # fused bwd kernels get tiles too
-        for T in (100, 64, 96):  # < 128 or not 128-divisible -> None path
-            assert _select_block_size(T) is None
-        # wide heads: sweep only covered D<=128; defaults past that (the
-        # 512-edge backward tiles would scale VMEM past safe margins)
-        assert _select_block_size(2048, head_dim=128) == 512
-        assert _select_block_size(2048, head_dim=256) is None
+        bs = _splash_block_sizes(T)
+        assert bs.has_backward_blocks
+        for mem, comp in ((bs.block_kv, bs.block_kv_compute),
+                          (bs.block_kv_dkv, bs.block_kv_dkv_compute)):
+            assert T % mem == 0 and mem % comp == 0 and comp % 128 == 0, bs
+        for edge in (bs.block_q, bs.block_q_dkv, bs.block_q_dq, bs.block_kv_dq):
+            assert edge is None or (T % edge == 0 and edge % 128 == 0), bs
+
+    @pytest.mark.parametrize("T", [64, 96, 100, 200])
+    def test_untileable_length_takes_full_attention(self, T):
+        """T % 128 != 0: no tiles, and the dispatch has no library-default
+        branch left — it takes full_attention, as off-TPU."""
+        from tpu_rl.parallel.sequence import _splash_block_sizes
+
+        assert _splash_block_sizes(T) is None
 
     def test_transformer_flash_config_builds_and_matches_full(self, rng):
         from tests.conftest import small_config

@@ -280,11 +280,10 @@ class GQAttention(nn.Module):
 
     def __call__(self, u, seg):
         B, T, _ = u.shape
-        rep = self.n_q // self.n_kv
         q = self.q_proj(u).reshape(B, T, self.n_q, self.head_dim)
-        # every key/value head serves `rep` consecutive query heads
+        # every key/value head serves n_q // n_kv consecutive query heads
         k, v = (
-            jnp.repeat(p(u).reshape(B, T, self.n_kv, self.head_dim), rep, axis=2)
+            p(u).reshape(B, T, self.n_kv, self.head_dim)
             for p in (self.k_proj, self.v_proj)
         )
         pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))

@@ -45,35 +45,88 @@ SEQ_AXIS = "seq"
 _NEG_INF = -1e30  # finite -inf stand-in: keeps exp()/max() NaN-free
 
 
-def _select_block_size(T: int, head_dim: int = 64) -> int | None:
-    """Tile edge for the Pallas flash kernel at sequence length T:
-    gcd(512, T) — the largest power-of-two divisor of T capped at 512 — when
-    that is at least the kernel's 128 minimum; None = use library defaults
-    (128 everywhere). Not measured on current code: 512 tiles compile and
-    match the reference on a TPU v5e (chip_smoke.py); their speed against
-    the defaults is ROADMAP S2's to re-measure.
+def _splash_block_sizes(T: int):
+    """Tiles of the splash kernel (``jax.experimental.pallas.ops.tpu
+    .splash_attention.BlockSizes``) for sequence length T; None = the kernel
+    cannot tile T (not a multiple of its 128 lanes): the caller takes
+    :func:`full_attention`.
 
-    512-edge backward tiles scale VMEM linearly with head_dim, so past 128
-    the override could exceed VMEM where the library defaults still compile
-    — defaults win there."""
-    if head_dim > 128:
+    The rule: one edge everywhere, gcd(1024, T); 512 columns per inner step;
+    the single-pass backward (``use_fused_bwd_kernel``: dq, dk and dv from one
+    recomputation of the scores); q, k and v handed over as (head dim, T) —
+    head dim 64 fills half a 128-lane row, T fills it, and XLA folds the
+    ``swapaxes`` into the transpose the call needs anyway. The same choice won
+    at both measured shapes, so T alone enters the rule. One layer, bf16, head
+    dim 64, ms forward / forward + backward on a TPU v5e
+    (``examples/bench_flash_attention.py``, PR 27):
+
+    ================================  ===============  =====================
+    tiles                             B32 T2048 H8:8   B2 T4096 H32:8 (1/64)
+    ================================  ===============  =====================
+    the old library kernel, 512       5.08 / 22.66     4.07 / 16.80
+    256, single pass                  10.67 / 27.39    8.04 / 23.80
+    512, single pass                  5.50 / 14.80     3.71 / 11.32
+    512, two passes                   5.47 / 18.03     3.74 / 13.58
+    1024 / 512, single pass           4.93 / 13.77     3.15 / 9.41
+    **the same, q k v (head dim, T)** **3.81 / 12.69** **2.80 / 8.94**
+    the same, k v (head dim, T)       4.46 / 13.23     2.87 / 9.13
+    1024 / 256, single pass           5.04 / 14.16     3.21 / 9.63
+    1024 / 1024, single pass          5.26 / 13.98     3.41 / 9.59
+    1024, two passes                  4.92 / 16.56     3.16 / 11.30
+    q 512 x kv 1024                   5.26 / 14.40     3.39 / 10.07
+    q 1024 x kv 512                   5.49 / 15.04     3.56 / 10.72
+    q 1024 x kv 2048                  5.44 / 15.08     3.26 / 9.65
+    ================================  ===============  =====================
+
+    A whole-T tile (2048, 4096) does not fit VMEM."""
+    if T % 128:
         return None
-    blk = math.gcd(512, T)
-    return blk if blk >= 128 else None
+    from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes, QKVLayout
 
-
-def _uniform_block_sizes(blk: int):
-    """BlockSizes with one tile edge everywhere (fwd + both backward kernels).
-    Shared with examples/bench_flash_attention.py so the bench measures the
-    same construction the dispatch uses."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
-
+    edge = math.gcd(1024, T)
+    compute = min(512, edge)
     return BlockSizes(
-        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
-        block_q_dkv=blk, block_k_major_dq=blk, block_k_dq=blk,
-        block_q_dq=blk,
+        block_q=edge, block_kv=edge, block_kv_compute=compute,
+        block_q_dkv=edge, block_kv_dkv=edge, block_kv_dkv_compute=compute,
+        use_fused_bwd_kernel=True,
+        q_layout=QKVLayout.SEQ_MINOR, k_layout=QKVLayout.SEQ_MINOR,
+        v_layout=QKVLayout.SEQ_MINOR,
     )
+
+
+def _splash_mha(q, k, v, seg, *, causal, scale, block_sizes, interpret=False):
+    """The splash kernel on this module's layout: q (B, T, H, D), k and v
+    (B, T, Hkv, D) with ``H % Hkv == 0`` (every key/value head serves
+    ``H // Hkv`` consecutive query heads, unrepeated), seg (B, T). Causal by
+    index plus same-segment, built per trace: the block-sparse mask info is
+    numpy work on a (T / tile)^2 grid. The kernel takes no softmax scale, so
+    ``scale`` is folded into q first — exact in bf16 for a power of two (both
+    registered cells: 1/8 and 1/64); a general scale rounds q once more.
+    ``interpret=True`` runs the same construction on the CPU (tier-1 tests)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        CausalMask,
+        FullMask,
+        MultiHeadMask,
+        SegmentIds,
+        make_splash_mha,
+    )
+
+    T, H = q.shape[1], q.shape[2]
+    head_mask = (CausalMask if causal else FullMask)((T, T))
+    splash = make_splash_mha(
+        MultiHeadMask([head_mask] * H),
+        block_sizes=block_sizes,
+        head_shards=1,
+        q_seq_shards=1,
+        interpret=interpret,
+    )
+    # our layout (B, T, H, D) -> kernel layout (H, T, D), one batch row a call
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q * scale, k, v))
+    seg32 = seg.astype(jnp.int32)
+    o = jax.vmap(
+        lambda q, k, v, s: splash(q, k, v, segment_ids=SegmentIds(q=s, kv=s))
+    )(qt, kt, vt, seg32)
+    return o.transpose(0, 2, 1, 3)
 
 
 def make_sp_mesh(n_data: int, n_seq: int, devices=None) -> Mesh:
@@ -619,25 +672,26 @@ def flash_attention_tpu(
     causal: bool = True,
     sm_scale: float | None = None,
 ) -> jax.Array:
-    """Single-device fused attention via the Pallas TPU flash-attention
-    kernel that ships with JAX (``jax.experimental.pallas.ops.tpu
-    .flash_attention``; custom-VJP fwd+bwd Mosaic kernels). Same contract as
-    :func:`full_attention`. The kernel takes equal head counts: a caller
-    with grouped key/value heads repeats them first.
+    """Single-device fused attention via the splash kernel that ships with
+    JAX (``jax.experimental.pallas.ops.tpu.splash_attention``: Mosaic forward
+    and single-pass backward under a custom VJP; see
+    :func:`_splash_mha`). Same contract as :func:`full_attention`, and
+    ``k``, ``v`` may carry fewer heads than ``q`` (grouped-query attention:
+    ``H % Hkv == 0``, unrepeated).
 
-    Masking equivalence: the kernel takes ``causal`` (by global index) plus
-    ``SegmentIds`` — identical to our ``q_pos >= k_pos`` + same-segment mask
+    Masking equivalence: the kernel masks causally by global index plus
+    same-segment — identical to our ``q_pos >= k_pos`` + same-segment mask
     because positions are segment-relative and monotone within a segment, and
     the segment mask kills every cross-segment pair anyway
-    (``tests/test_sequence_parallel.py::TestFlashImpl`` pins this against
-    ``mha_reference``, the kernel's own pure-jnp spec).
+    (``tests/test_sequence_parallel.py::TestFlashImpl`` runs this kernel in
+    interpret mode against :func:`full_attention`, forward and gradients).
 
-    Off-TPU (CPU tests, the virtual mesh) Mosaic kernels cannot run, so this
-    falls back to :func:`full_attention` — bit-compatible masking, different
-    arithmetic order. The program's devices decide the placement, as for the
-    LSTM kernel (``models/cells.py``): no registered data mesh means a plain
-    single-device jit and a bare kernel call; under
-    ``make_parallel_train_step``'s mesh the Mosaic call cannot be
+    Off-TPU (CPU tests, the virtual mesh), or at a length the kernel cannot
+    tile (``T % 128``), this falls back to :func:`full_attention` —
+    bit-compatible masking, different arithmetic order. The program's devices
+    decide the placement, as for the LSTM kernel (``models/cells.py``): no
+    registered data mesh means a plain single-device jit and a bare kernel
+    call; under ``make_parallel_train_step``'s mesh the Mosaic call cannot be
     auto-partitioned by GSPMD, so it runs as a ``shard_map`` island over the
     ``"data"`` axis (including the 1-device mesh, so one chip exercises the
     island four chips use). Which path a program took is readable from its
@@ -648,36 +702,23 @@ def flash_attention_tpu(
 
     mesh = cells._DATA_MESH
     platform, n_data = cells._program_devices()
+    # a multi-device program whose batch does not tile the mesh (init trace):
+    # a bare Mosaic custom call has no GSPMD partitioning rule
     tiles = q.shape[0] % n_data == 0
-    if platform != "tpu" or not tiles:
-        # not tiles: a multi-device program whose batch does not tile the
-        # mesh (init trace) — a bare Mosaic custom call has no GSPMD
-        # partitioning rule, so take the partitionable jnp path.
+    bs = _splash_block_sizes(q.shape[1]) if tiles else None
+    if platform != "tpu" or bs is None:
+        # the partitionable jnp path takes equal head counts
+        rep = q.shape[2] // k.shape[2]
+        if rep > 1:
+            k, v = (jnp.repeat(x, rep, axis=2) for x in (k, v))
         return full_attention(q, k, v, q_pos, seg, causal=causal, sm_scale=sm_scale)
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        SegmentIds,
-        flash_attention as _pallas_flash,
-    )
-
-    scale = 1.0 / np.sqrt(q.shape[-1]) if sm_scale is None else sm_scale
-    # The library's get_default() is 128 everywhere ("TODO: select better
-    # parameters" upstream); the tile edge must divide T.
-    blk = _select_block_size(q.shape[1], head_dim=q.shape[-1])
-    bs = _uniform_block_sizes(blk) if blk is not None else None
+    scale = float(1.0 / np.sqrt(q.shape[-1]) if sm_scale is None else sm_scale)
 
     @jax.named_scope("attn_flash_pallas")
     def kernel(q, k, v, seg):
-        # our layout (B, T, H, D) -> kernel layout (B, H, T, D)
-        qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-        seg32 = seg.astype(jnp.int32)
-        o = _pallas_flash(
-            qt, kt, vt,
-            segment_ids=SegmentIds(q=seg32, kv=seg32),
-            causal=causal,
-            sm_scale=float(scale),
-            block_sizes=bs,
+        return _splash_mha(
+            q, k, v, seg, causal=causal, scale=scale, block_sizes=bs
         )
-        return o.transpose(0, 2, 1, 3)
 
     if mesh is None:
         return kernel(q, k, v, seg)
