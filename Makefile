@@ -3,7 +3,7 @@
 PY ?= python
 
 .PHONY: lint format-check analyze typecheck test native-build protocol-matrix \
-	relay-smoke diag-smoke obs-smoke trace-smoke chaos-smoke colocated-smoke \
+	obs-smoke trace-smoke chaos-smoke colocated-smoke \
 	resume-smoke slo-smoke loadgen-smoke serving-smoke heal-smoke \
 	pbt-smoke goodput-smoke autopilot-smoke sebulba-smoke history-smoke ci
 
@@ -57,23 +57,6 @@ protocol-matrix: native-build
 		tests/test_shm_transport.py tests/test_chaos.py \
 		-p no:cacheprovider
 
-# Fan-in A/B smoke: short raw-vs-decode run through the real Manager +
-# LearnerStorage. Asserts direction only (raw >= decode frames/s) — never a
-# committed number, so CI load can't make it flap. Full capture:
-# TPU_RL_BENCH_RELAY=1 python bench.py  (writes bench_relay[.cpu].json).
-relay-smoke:
-	JAX_PLATFORMS=cpu TPU_RL_BENCH_RELAY=1 TPU_RL_BENCH_RELAY_LIGHT=1 \
-		$(PY) bench.py > /dev/null
-
-# Learning-dynamics plane smoke: the chained train step with learn_diag on
-# vs off at a tiny budget. Asserts sanity only (no catastrophic overhead —
-# a host sync sneaking into the step reads as 2x, not 2%) — never the
-# committed <=2% number, so CI load can't make it flap. Full capture:
-# TPU_RL_BENCH_DIAG=1 python bench.py  (writes bench_diag[.cpu].json).
-diag-smoke:
-	JAX_PLATFORMS=cpu TPU_RL_BENCH_DIAG=1 TPU_RL_BENCH_DIAG_LIGHT=1 \
-		$(PY) bench.py > /dev/null
-
 # Telemetry-plane smoke: boot the smallest real cluster with the plane on,
 # scrape /metrics + /healthz mid-run, validate telemetry.json + trace.json.
 obs-smoke:
@@ -93,9 +76,7 @@ chaos-smoke:
 	JAX_PLATFORMS=cpu PYTHONPATH=. $(PY) examples/chaos_smoke.py
 
 # Colocated (Anakin) smoke: a short fused on-device CartPole run must learn
-# (best-window mean return over the bar) and the colocated-vs-distributed
-# bench row must emit with direction-consistent numbers. Full capture:
-# TPU_RL_BENCH_COLOCATED=1 python bench.py  (writes bench_colocated[.cpu].json).
+# (best-window mean return over the bar).
 colocated-smoke:
 	JAX_PLATFORMS=cpu PYTHONPATH=. $(PY) examples/colocated_smoke.py
 
@@ -166,15 +147,11 @@ sebulba-smoke:
 # Run-history smoke (ISSUE 20): chaos-kill cluster run with the history
 # plane on — /query shows run progress, the report renders the chaos
 # event overlay, self-compare is green, and doctored candidates (dropped
-# channel / 20x slower) gate red. Includes the light history-overhead
-# bench (zero-alloc plane-off hot path; full capture:
-# TPU_RL_BENCH_HISTORY=1 python bench.py -> bench_history[.cpu].json).
+# channel / 20x slower) gate red.
 history-smoke:
-	JAX_PLATFORMS=cpu TPU_RL_BENCH_HISTORY=1 TPU_RL_BENCH_HISTORY_LIGHT=1 \
-		$(PY) bench.py > /dev/null
 	JAX_PLATFORMS=cpu PYTHONPATH=. $(PY) examples/history_smoke.py
 
-ci: lint analyze typecheck test protocol-matrix relay-smoke diag-smoke obs-smoke \
+ci: lint analyze typecheck test protocol-matrix obs-smoke \
 	trace-smoke chaos-smoke colocated-smoke resume-smoke slo-smoke \
 	loadgen-smoke serving-smoke heal-smoke pbt-smoke goodput-smoke \
 	autopilot-smoke sebulba-smoke history-smoke

@@ -56,7 +56,7 @@ REF = dict(
     worker_step_sleep=0.0, worker_num_envs=64, loss_log_interval=2,
     model_save_interval=4, telemetry_interval_s=0.5,
 )
-# The widest models the repo supports (the bench matrix's saturating rows).
+# The widest LSTM learner the repo supports.
 WIDE = dict(
     algo="IMPALA", batch_size=1024, seq_len=16, hidden_size=1024,
     obs_shape=(64,), action_space=8,

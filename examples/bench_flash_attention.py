@@ -55,7 +55,7 @@ def _inputs(B, T, H, Hkv, D):
 
 def _force_done(out) -> None:
     # Read one scalar back to the host: the timed region ends when the
-    # device has finished (same as bench.py _sync).
+    # device has finished.
     s = jax.tree.map(lambda x: jnp.sum(x.astype(jnp.float32)), out)
     float(np.asarray(jax.device_get(jax.tree.leaves(s)[0])))
 

@@ -50,7 +50,8 @@ def _run(cell, params, x, firsts, carry0, mode: str, grad: bool, iters: int):
     fn = jax.jit(jax.grad(fwd) if grad else fwd)
     out = fn(params, x)  # compile
     jax.block_until_ready(out)
-    # device_get forces true chain completion (see bench.py _sync note)
+    # device_get forces true chain completion: block_until_ready on one
+    # output can return before the whole chain of dispatches has run
     t0 = time.perf_counter()
     for _ in range(iters):
         out = fn(params, x)

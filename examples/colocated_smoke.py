@@ -1,19 +1,13 @@
-"""Colocated-mode smoke: the fused on-device loop learns, and the A/B bench
-row emits — the `make ci` gate for ISSUE 7 (Anakin-mode colocated envs).
+"""Colocated-mode smoke: the fused on-device loop learns — the `make ci`
+gate for ISSUE 7 (Anakin-mode colocated envs).
 
-Two checks, both on the CPU backend:
-
-1. LEARNING: a short colocated PPO run on jittable CartPole (the
-   ``train_inline`` recipe: lr 3e-4, entropy 1e-3, reward_scale 0.1) must
-   lift the completed-episode mean return well above the random-policy
-   baseline (~22) within a small update budget. This exercises the whole
-   fused path end to end: act -> on-device env step -> window assembly ->
-   train_step under one jit, auto-reset, carry zeroing, on-device episode
-   stats.
-2. BENCH ROW: ``bench.run_colocated_compare`` in light mode (short windows,
-   no result file) must emit the colocated-vs-distributed row with the
-   expected schema and a direction-consistent speedup (the light mode
-   hard-asserts colocated >= distributed internally).
+On the CPU backend, a short colocated PPO run on jittable CartPole (the
+``train_inline`` recipe: lr 3e-4, entropy 1e-3, reward_scale 0.1) must
+lift the completed-episode mean return well above the random-policy
+baseline (~22) within a small update budget. This exercises the whole
+fused path end to end: act -> on-device env step -> window assembly ->
+train_step under one jit, auto-reset, carry zeroing, on-device episode
+stats.
 
 Usage:
     JAX_PLATFORMS=cpu PYTHONPATH=. python examples/colocated_smoke.py
@@ -22,7 +16,6 @@ Usage:
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import sys
 import time
@@ -61,46 +54,16 @@ def check_learning(updates: int, threshold: float, failures: list[str]) -> None:
         failures.append(f"too few episodes completed: {out['episodes']}")
 
 
-def check_bench_row(failures: list[str]) -> None:
-    os.environ["TPU_RL_BENCH_COLOCATED_LIGHT"] = "1"
-    from bench import run_colocated_compare
-
-    try:
-        result = run_colocated_compare()
-    except AssertionError as e:
-        failures.append(f"bench direction assert failed: {e}")
-        return
-    print(
-        "[colocated-smoke] bench row: "
-        + json.dumps({k: result[k] for k in (
-            "speedup", "colocated_tps", "distributed_tps_steady")}),
-        flush=True,
-    )
-    for key in (
-        "metric", "device_kind", "speedup", "colocated_tps",
-        "colocated_tps_best", "distributed_tps_steady", "rows",
-    ):
-        if key not in result:
-            failures.append(f"bench row missing key: {key}")
-    rows = result.get("rows", {})
-    if not rows.get("colocated") or "colocated_tps" not in rows["colocated"][0]:
-        failures.append(f"malformed colocated rows: {rows.get('colocated')}")
-
-
 def main() -> int:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--updates", type=int, default=1800,
                    help="learning-check update budget (default 1800)")
     p.add_argument("--threshold", type=float, default=RETURN_THRESHOLD,
                    help="best-window mean-return bar (default 60)")
-    p.add_argument("--skip-bench", action="store_true",
-                   help="learning check only")
     args = p.parse_args()
 
     failures: list[str] = []
     check_learning(args.updates, args.threshold, failures)
-    if not args.skip_bench:
-        check_bench_row(failures)
 
     if failures:
         for f in failures:

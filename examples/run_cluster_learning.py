@@ -154,7 +154,7 @@ def main() -> None:
             learner_chain=args.learner_chain,
             K_epoch=args.k_epoch,
             learner_device="cpu",  # deterministic on shared hosts; the
-            # real-TPU topology is separately recorded in RUN_LOCAL_TPU_r03.md
+            # real-TPU topology is chip_smoke.py's to prove
             rollout_lag_sec=5.0,
             time_horizon=500,
             result_dir=run_dir,

@@ -391,21 +391,3 @@ def test_append_resume_coerces_ints(tmp_path):
     assert append_resume(str(tmp_path), np.int64(5), np.int32(1)) is True
     rec = json.loads((tmp_path / "learner_resume.jsonl").read_text())
     assert rec["idx"] == 5 and rec["epoch"] == 1
-
-
-# ------------------------------------------------------- bench crosscheck
-@pytest.mark.slow
-def test_bench_goodput_crosscheck_agreement():
-    """Ledger step attribution vs the execution timer on a live learner:
-    the two observe identical dispatch boundaries, so they must agree
-    within ±5% (the bench row's acceptance direction)."""
-    import bench
-
-    row = bench.goodput_crosscheck(
-        updates=24, feeders=1, batch_size=16, hidden_size=16,
-        model_port=29897,
-    )
-    assert 0.95 <= row["agreement"] <= 1.05
-    assert row["ratios_sum"] == pytest.approx(1.0, abs=1e-6)
-    assert row["overcommit_ratio"] <= 0.01
-    assert row["goodput"] > 0

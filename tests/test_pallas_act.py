@@ -2,7 +2,7 @@
 act path in interpreter mode on CPU, plus the dispatch contract —
 ``make_act_fn`` must hand back the fused path only when asked AND in scope,
 and the fallback must be the literal ``family.act``. Real-TPU execution is
-covered by bench.py's serving matrix on hardware."""
+covered by chip_smoke.py's ``kernels`` phase."""
 
 import jax
 import jax.numpy as jnp

@@ -1,6 +1,6 @@
 """Fused Pallas LSTM kernel: forward + gradient equivalence against the
 lax.scan path, in interpreter mode on CPU (real-TPU execution is covered by
-bench.py / __graft_entry__ on hardware)."""
+chip_smoke.py's ``kernels`` phase)."""
 
 import jax
 import jax.numpy as jnp

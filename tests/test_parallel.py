@@ -116,8 +116,8 @@ def test_host_local_batch_to_global_single_process(devices):
 
 @pytest.mark.parametrize("algo", ["IMPALA", "SAC"])
 def test_chained_step_matches_sequential(algo):
-    """chain=K compiles K updates per dispatch (bench headline methodology;
-    dp.py make_parallel_train_step): the result must equal K sequential
+    """chain=K compiles K updates per dispatch (dp.py
+    make_parallel_train_step): the result must equal K sequential
     unchained updates run on the per-update batches with the same folded
     keys — chaining changes dispatch granularity, never math."""
     K = 3

@@ -91,18 +91,17 @@ def _small_step():
 
 @pytest.mark.timeout(120)
 def test_live_flops_and_mfu_agree_with_bench_methodology(monkeypatch):
-    """The tracker's one-time AOT capture vs bench.py's inline
+    """The tracker's one-time AOT capture vs an inline
     lower/compile/cost_analysis on the SAME jitted step: FLOPs must agree
     exactly (same program), achieved FLOPs/s within 15% (independent timing
     windows over the same dispatches)."""
     import jax
 
-    from bench import compiled_flops
-
     step, state, batch = _small_step()
     key = jax.random.PRNGKey(1)
 
-    flops_offline = compiled_flops(step.lower(state, batch, key).compile())
+    cost = step.lower(state, batch, key).compile().cost_analysis()
+    flops_offline = float(cost["flops"])
     monkeypatch.setenv("TPU_RL_PEAK_FLOPS", "1e12")
     tracker = PerfTracker(n_devices=1)
     assert tracker.capture(step, state, batch, key)

@@ -93,7 +93,7 @@ MANIFEST: dict[str, dict[str, str]] = {
     "tpu_rl/obs/learn.py": {
         # The learning-dynamics fold rides every learner dispatch (one
         # extra device program, zero syncs — the whole plane's overhead
-        # contract, bench_diag.cpu.json); the host-side wrapper must stay
+        # contract, tests/test_learn_diag.py); the host-side wrapper must stay
         # allocation-free so the cost is the device fold alone. drain() is
         # cold (log cadence) and deliberately NOT pinned.
         "DiagAccumulator.add": STRICT,

@@ -40,8 +40,8 @@ def set_pallas_mode(mode: str) -> None:
     """"auto": measured-win dispatch (kernel only where it beats the scan);
     "off": always scan; "interpret": kernel in interpreter mode (CPU tests);
     "force": real kernel wherever it FITS, ignoring the measured-win gate —
-    benchmarking only (bench_lstm_kernel.py times the raw kernel against the
-    scan to re-derive the gate)."""
+    benchmarking only (examples/bench_lstm_kernel.py times the raw kernel
+    against the scan to re-derive the gate)."""
     assert mode in ("auto", "interpret", "off", "force"), mode
     global _PALLAS_MODE
     _PALLAS_MODE = mode
@@ -78,10 +78,12 @@ def _use_pallas(
         return batch_tile(batch, seq, hidden) is not None, False
     # Measured-win gate (bench_lstm_kernel.json): the fused kernel beats
     # the scan only when the WHOLE batch is one VMEM tile for both passes
-    # (fwd+grad 1.75x at B128/H64, 1.56x at B256/H256). Multi-tile grids
-    # starve the MXU (fwd 0.82x, fwd+grad 1.0x at B1024/H1024) and
-    # no-tile-fits shapes can't run at all — both keep the scan, whose
-    # per-step matmuls always see the full batch.
+    # (fwd+grad 1.06x at B128/H64, 1.33x at B256/H256). Multi-tile grids
+    # starve the MXU (fwd+grad 0.90x at B1024/H1024) and no-tile-fits
+    # shapes can't run at all — both keep the scan, whose per-step matmuls
+    # always see the full batch. That record is host-clocked
+    # (examples/bench_lstm_kernel.py) and no cell has re-measured it
+    # (ROADMAP W4).
     return (
         batch_tile(batch, seq, hidden) == batch
         and bwd_batch_tile(batch, seq, hidden) == batch

@@ -2,8 +2,7 @@
 
 ``python -m tpu_rl.obs.compare <baseline_dir> <candidate_dir>`` compares
 every channel the two runs share (plus every channel either side is
-missing) and exits nonzero on regression — the CI gate the bench
-trajectory never had.
+missing) and exits nonzero on regression — a CI gate over run histories.
 
 Verdict semantics, per channel:
 

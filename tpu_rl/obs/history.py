@@ -33,8 +33,8 @@ because the readers — :mod:`tpu_rl.obs.report`,
 
 When the plane is off (:func:`maybe_history` returns None) nothing is
 constructed and every hot-path hook reduces to one ``is None`` check —
-the same cost contract as the telemetry plane itself, pinned by the
-``TPU_RL_BENCH_HISTORY`` tracemalloc bench.
+the same cost contract as the telemetry plane itself, whose plane-off
+ingest path ``tests/test_obs.py`` pins with ``tracemalloc``.
 """
 
 from __future__ import annotations

@@ -1,9 +1,9 @@
 """Live performance plane: MFU/FLOPs, recompiles, device memory, profiler.
 
-``bench.py`` already knows how to turn ``compiled.cost_analysis()`` into
-FLOPs-per-step and MFU — but only offline, one workload at a time. This
-module promotes those instruments into the running fleet so every role with
-telemetry on reports them continuously:
+``compiled.cost_analysis()`` gives FLOPs-per-step and, over a device time,
+MFU — offline, one program at a time. This module puts those instruments
+into the running fleet so every role with telemetry on reports them
+continuously:
 
 - :class:`PerfTracker` — attach to a jitted entry point (learner
   ``train_step``, the colocated fused program, the inference ``act`` step).
@@ -20,8 +20,8 @@ telemetry on reports them continuously:
   anneal switch) freezes the old count and restarts the baseline, so
   expected rebuilds don't masquerade as drift.
 - :func:`device_peak_flops` / :data:`PEAK_FLOPS` — the single source of
-  truth for bf16 peak by device kind; ``bench.py`` imports these from here
-  so live and offline MFU can never disagree on the denominator. A TPU
+  truth for bf16 peak by device kind, so live and offline MFU can never
+  disagree on the denominator. A TPU
   whose kind is not in the table is an error. ``TPU_RL_PEAK_FLOPS`` (env,
   FLOPs/s per device) is the CPU-smoke denominator only — it's what lets
   CPU smokes exercise the MFU path, and it is ignored on any other backend.
@@ -192,8 +192,8 @@ class PerfTracker:
       one-time AOT cost analysis and (re)binds the recompile watch.
     - ``note(dt)`` with the wall-clock dispatch interval. Donated buffers
       serialize consecutive dispatches, so in steady state the interval
-      converges to true device step time — the same quantity ``bench.py``
-      measures with an explicit sync over many iters.
+      approaches the device step time; it is a host clock all the same, and
+      the benchmark's ``step.mfu`` comes from the device trace (ROADMAP D7).
     - read ``flops_per_call`` / ``achieved_flops_per_s()`` / ``mfu()`` /
       ``recompiles`` at emit cadence.
     """

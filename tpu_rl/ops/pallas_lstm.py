@@ -264,10 +264,10 @@ mixed_dot.defvjp(_mixed_dot_fwd, _mixed_dot_bwd)
 @jax.named_scope("lstm_scan")
 def _scan_forward(xp, wh, h0, c0, keep, matmul_dtype=None, want_cs=False):
     """Plain ``lax.scan`` forward over the precomputed input projection —
-    the measured winner for UNdifferentiated unrolls (the fused kernel is
-    0.82-0.99x the scan on forward-only at every benched shape,
-    bench_lstm_kernel.json; it wins only when the fused backward is in
-    play).
+    the measured winner for UNdifferentiated unrolls at single-tile shapes
+    (the fused kernel is 0.89-0.91x the scan on forward-only there,
+    bench_lstm_kernel.json, a host-clocked record no cell has re-measured,
+    ROADMAP W4; it wins only when the fused backward is in play).
 
     ``matmul_dtype`` (e.g. ``jnp.bfloat16``) runs the recurrent matmul
     through :func:`mixed_dot` — MXU-rate compute in BOTH passes with f32
@@ -279,7 +279,7 @@ def _scan_forward(xp, wh, h0, c0, keep, matmul_dtype=None, want_cs=False):
     the ``lstm_unroll`` primal needs that (its custom_vjp output contract
     is (B,S,H) pairs); every other caller consumes just the final carry,
     and stacking cs for them would write an extra (B,S,H) buffer per
-    forward (~64 MB at the wide bench shape)."""
+    forward (~64 MB at B1024 S16 H1024)."""
     def step(carry, xs):
         h, c = carry
         xp_t, keep_t = xs
@@ -320,8 +320,8 @@ def lstm_unroll(xp, wh, h0, c0, keep, interpret=False):
     Measured-win dispatch (bench_lstm_kernel.json): this primal body runs
     only when the call is NOT differentiated (custom_vjp routes traced-for-AD
     calls through ``_fwd``), and forward-only is where the kernel loses
-    (0.82-0.99x the scan at every shape) — so the undifferentiated path
-    always scans. ``interpret`` (CPU equivalence tests) and the cells
+    (0.89-0.91x the scan at the single-tile shapes) — so the undifferentiated
+    path always scans. ``interpret`` (CPU equivalence tests) and the cells
     module's "force" benchmark mode still run the kernel so tests and the
     gate-deriving benchmark can never silently degrade into scan-vs-scan."""
     from tpu_rl.models.cells import _PALLAS_MODE

@@ -86,8 +86,7 @@ class ColocatedLoop:
     Two compiled entry points:
 
     - :attr:`rollout` — ``(params, carry, key) -> (carry, batch, done, ret)``:
-      the acting scan alone. Used by tests (assembler equivalence) and the
-      bench's pure-rollout row.
+      the acting scan alone. Used by tests (assembler equivalence).
     - :attr:`program` — ``(state, carry, stats, k_roll, k_train) ->
       (state, carry, stats, metrics)``: rollout + train fused. ``state``,
       ``carry`` and ``stats`` are donated; the steady-state loop re-dispatches

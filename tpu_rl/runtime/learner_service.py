@@ -18,7 +18,7 @@ TPU-first redesign:
   with the step's sharding, so the next dispatch's shm copy + H2D transfer
   overlaps the current ``train_step`` (``tpu_rl/data/prefetch.py``; the
   Podracer overlap, Hessel et al. 2104.06272). ``learner_prefetch=0``
-  restores the serial feed for A/B;
+  restores the serial feed, an A/B baseline no cell measures (ROADMAP D2);
 - weight broadcast is an ASYNC snapshot of the actor tree only — ONE device
   program copies the whole tree (``snapshot_tree``), and the D2H transfer,
   its wait and the ZMQ send run on a publisher thread — throttled by
@@ -540,10 +540,10 @@ class LearnerService:
             )
             prof_capture.install_sigusr2()
         # One timed window per DISPATCH; a chained dispatch carries
-        # chain x (seq x batch) transitions. Kept on self so harnesses
-        # (examples/run_tpu_e2e_learner.py) can read the steady-state
-        # windowed rates after run() — the window excludes idle polls and
-        # dilutes the first dispatch's compile across the deque.
+        # chain x (seq x batch) transitions. Kept on self: the lane gauges
+        # below and chip_smoke.py read the steady-state windowed rates after
+        # run() — the window excludes idle polls and dilutes the first
+        # dispatch's compile across the deque.
         timer = self.timer = tracer.timer = ExecutionTimer(
             num_transition=cfg.seq_len * cfg.batch_size * chain
         )

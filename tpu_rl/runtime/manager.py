@@ -17,7 +17,7 @@ forwarded verbatim via ``Pub.send_raw``. Per-frame relay cost drops from
 O(payload) (decode + re-encode) to O(1); the single full CRC+decode runs at
 the storage edge, the only consumer. Only the rare, tiny ``Stat`` frames are
 decoded here, for the windowed mean. ``relay_mode="decode"`` keeps the old
-decode-re-encode hop as the A/B baseline (``bench_relay.cpu.json``).
+decode-re-encode hop as an A/B baseline, unmeasured on a chip (ROADMAP D2).
 
 Sync loop instead of the reference's two asyncio tasks: one poll-drain-forward
 pass per iteration keeps ordering within a worker's stream and needs no

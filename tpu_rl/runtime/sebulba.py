@@ -188,8 +188,7 @@ class SebulbaLoop(ColocatedLoop):
             donate_argnums=(0,),
         )
         # No fused `program` in this mode: ColocatedLoop.program users
-        # (bench colocated rows, assembler-parity tests) run the Anakin
-        # class.
+        # (assembler-parity tests) run the Anakin class.
         self.program = None
 
     # -------------------------------------------------------------- jit bodies

@@ -58,8 +58,8 @@ def _endpoint(ip: str, port: int) -> str:
 # -------------------------------------------------------- batch validation
 # A drained deque is validated in ONE native call (tpurl_validate_batch in
 # native/codec.cpp — GIL released for the whole batch) instead of a Python
-# peek()/CRC pass per frame. The pure-Python per-frame path stays both as the
-# no-toolchain fallback and as the bench A/B baseline (native_batch=False).
+# peek()/CRC pass per frame. The pure-Python per-frame path stays as the
+# no-toolchain fallback (native_batch=False forces it).
 
 
 def _validate_raw(
@@ -184,7 +184,7 @@ class Sub:
         self._chaos = chaos
         # Validate drained batches through the native codec when it's loaded
         # (one ctypes call per drain instead of a Python peek per frame);
-        # False forces the pure-Python path — the bench A/B baseline.
+        # False forces the pure-Python path (no caller does; ROADMAP D2).
         self._native_batch = native_batch
         ep = _endpoint(ip, port)
         self.sock.bind(ep) if bind else self.sock.connect(ep)
