@@ -391,6 +391,8 @@ def kernel_checks(
         (1, 512, 8, 8, 64, None, False),
         (2, 4096, 32, 8, 64, 1.0 / 64, True),
     ),
+    # (B, T, heads, head dim, groups, state, chunk): granite-4.0-h-micro's scan
+    ssd_shapes=((2, 4096, 64, 64, 1, 128, 256),),
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -532,6 +534,36 @@ def kernel_checks(
             got_fn, ref_fn, (q, k, v), TOL_BF16, TOL_BF16,
             # off-TPU the dispatch substitutes full attention by design
             mosaic=jax.default_backend() == "tpu",
+        )
+
+    # ---- the Pallas scan pair vs the jnp body of ssd_chunked, every gradient
+    from tpu_rl.models.granite_hybrid import ssd_chunked
+    from tpu_rl.ops.pallas_ssd import head_block
+
+    for B, T, H, P, G, N, Q in ssd_shapes:
+        x = f32(B, T, H, P)
+        dt = jax.nn.softplus(f32(B, T, H) - 3.0)
+        A = -jnp.exp(f32(H) * 0.5)
+        Bm, Cm, D = f32(B, T, G, N) * N**-0.5, f32(B, T, G, N), f32(H)
+        state0 = f32(B, H, P, N)
+        firsts = rng.random((B, T)) < 8.0 / T  # ~8 episode seams a window
+        seg = jnp.asarray(np.cumsum(firsts, axis=1).astype(np.int32))
+        w_y, w_last = f32(B, T, H, P), f32(B, H, P, N)
+        hb = H if interpret else head_block(H, P, G, N, Q)
+
+        def loss(kernel, x, dt, A, Bm, Cm, D, state0):
+            y, last = ssd_chunked(
+                x, dt, A, Bm, Cm, D, seg, state0, Q, jnp.bfloat16, kernel=kernel)
+            return (y * w_y).sum() + (last * w_last).sum(), (y, last)
+
+        def grads(kernel):
+            return jax.value_and_grad(
+                lambda *a: loss(kernel, *a), argnums=tuple(range(7)), has_aux=True)
+
+        case(
+            f"ssd fwd+bwd B{B}/T{T}/H{H}x{P}/G{G}/N{N}/Q{Q} bf16 (head block {hb})",
+            grads((hb, interpret)), grads((None, False)),
+            (x, dt, A, Bm, Cm, D, state0), TOL_BF16, TOL_BF16,
         )
     return rows
 

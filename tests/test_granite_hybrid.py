@@ -3,7 +3,9 @@ widths on the CPU against the benchmark's plain reference
 (``benchmarks/reference/granite_hybrid.py``: one ``lax.scan`` step per time
 step, dense masked attention): outputs, the PPO loss and its gradients, the
 episode seams of the chunked scan, a carried-in state, acting step by step,
-and the shared attention entry point it added an argument to."""
+and the shared attention entry point it added an argument to. The seam, carry
+and rematerialisation tests run twice: on the ``jnp`` body of the scan and on
+its Pallas kernels in the interpreter (``ops/pallas_ssd.py``)."""
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +19,7 @@ from tpu_rl.algos.ppo import make_train_step, policy_outputs
 from tpu_rl.algos.registry import get_algo
 from tpu_rl.config import Config
 from tpu_rl.data.layout import BatchLayout
+from tpu_rl.models import cells
 from tpu_rl.models.families import ModelFamily, build_family
 from tpu_rl.models.granite_hybrid import GraniteHybridActorCritic
 from tpu_rl.parallel.sequence import flash_attention_tpu, full_attention
@@ -37,6 +40,14 @@ PARAMS = dict(algo="PPO", model="granite_hybrid", arch=ARCH, obs_shape=(OBS,),
 
 def config(**kw) -> Config:
     return Config.from_dict({**PARAMS, **kw})
+
+
+@pytest.fixture(params=["auto", "interpret"], ids=["jnp", "pallas"])
+def scan_form(request, monkeypatch):
+    """The form of the scan a test's programs are traced in (read while
+    tracing: a test jits what it runs inside this fixture's scope)."""
+    monkeypatch.setattr(cells, "_PALLAS_MODE", request.param)
+    return request.param
 
 
 @pytest.fixture(scope="module")
@@ -174,14 +185,21 @@ def test_ppo_loss_and_gradients_match_the_reference(family, actor, plain):
 
 
 @pytest.fixture(scope="module")
-def weighted(family):
-    """Sum of the outputs under per-step weights, and its gradient."""
+def weighted_by_form(family):
+    """Sum of the outputs under per-step weights, and its gradient: one
+    jitted program per form of the scan."""
 
     def f(p, batch, weights):
         _, _, value, logits = policy_outputs(family, {"actor": p}, Batch.from_mapping(batch))
         return jnp.sum(weights * (value + logits.sum(-1, keepdims=True))), (value, logits)
 
-    return jax.jit(jax.value_and_grad(f, has_aux=True))
+    return {form: jax.jit(jax.value_and_grad(lambda *a: f(*a), has_aux=True))
+            for form in ("auto", "interpret")}
+
+
+@pytest.fixture
+def weighted(weighted_by_form, scan_form):
+    return weighted_by_form[scan_form]
 
 
 @pytest.mark.parametrize("seams", [(8,), (13,), (13, 14), (0, 19)],
@@ -220,7 +238,7 @@ def mamba_carry(seed: int, rows: int):
     return pairs, np.concatenate([a.reshape(rows, -1) for pair in pairs for a in pair], axis=1)
 
 
-def test_a_window_starts_from_the_state_it_is_handed(family, actor, plain):
+def test_a_window_starts_from_the_state_it_is_handed(family, actor, plain, scan_form):
     """Non-zero ``carry0``: the system unrolls from the flattened acting
     carry, the reference from the same states; a seam at step 11 must drop
     both. The placeholder the stores hand over means zeros."""
@@ -263,7 +281,7 @@ def test_acting_step_by_step_equals_the_unroll(family, actor, system):
     assert float(c[0, -1]) == T - 13  # steps of the running episode in the ring
 
 
-def test_the_carry_an_unroll_returns_continues_the_recurrence(actor):
+def test_the_carry_an_unroll_returns_continues_the_recurrence(actor, scan_form):
     """Mamba layers only: a window unrolled in two halves, the second from
     the carry the first returned, equals the window unrolled whole — with a
     seam in the first half, whose taps and state must not leak through."""
@@ -289,7 +307,7 @@ def test_a_window_that_is_no_multiple_of_the_chunk(actor, system, plain):
     close(value, ref_value, 1e-4)
 
 
-def test_rematerialisation_does_not_change_the_gradients(family, actor):
+def test_rematerialisation_does_not_change_the_gradients(family, actor, scan_form):
     batch = make_batch(12)
     obs, fir = jnp.asarray(batch["obs"]), jnp.asarray(batch["is_fir"])
     carry = (jnp.zeros((B, 1)), jnp.zeros((B, 1)))
@@ -344,18 +362,24 @@ def test_what_the_family_refuses():
     assert resume_fingerprint(other) != resume_fingerprint(config())
 
 
-def test_the_update_program_names_its_paths(family, actor):
+def test_the_update_program_names_its_paths(family, actor, monkeypatch):
     from tpu_rl.utils.platform import program_paths
 
     cfg = config()
     params = {"actor": actor}
     state = TrainState(step=jnp.zeros((), jnp.int32), params=params,
                        opt_state=rmsprop(cfg).init(params))
-    lowered = jax.jit(make_train_step(cfg, family)).lower(
-        state, Batch.from_mapping(make_batch(14)), jax.random.key(1))
-    assert {"ssd_scan", "attn_full"} <= set(program_paths(lowered)["paths"])
+    def lower():
+        return jax.jit(make_train_step(cfg, family)).lower(
+            state, Batch.from_mapping(make_batch(14)), jax.random.key(1))
+
+    lowered = lower()
+    paths = set(program_paths(lowered)["paths"])
+    assert {"ssd_scan", "attn_full"} <= paths and "ssd_pallas" not in paths  # a CPU: the jnp body
     text = lowered.as_text(debug_info=True)
     assert "ssd_conv" in text and "opt_update" in text
+    monkeypatch.setattr(cells, "_PALLAS_MODE", "interpret")
+    assert {"ssd_scan", "ssd_pallas", "attn_full"} <= set(program_paths(lower())["paths"])
 
 
 # ------------------------------------------- the shared attention entry point

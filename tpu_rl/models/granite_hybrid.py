@@ -25,6 +25,16 @@ cumulative sum, whose differences are NaN); acting runs the one-step form and
 relies on the worker zeroing the carry at episode starts. Every layer is
 rematerialised in the backward pass: one layer keeps ~150 KB per token.
 
+Which form of the chunked recurrence trains where (``ssd_chunked``): on a TPU,
+at widths that tile (chunk and state multiples of 128, as published), one
+Pallas kernel per pass (``ops/pallas_ssd.py``, scope ``ssd_pallas`` inside
+``ssd_scan``), under a registered data mesh as a ``shard_map`` island over its
+``"data"`` axis; everywhere else — the CPU, the tests' 8-step chunks, a batch
+that does not tile the mesh — the ``jnp``/``einsum`` body ``_ssd_jnp``, which
+is also the kernels' oracle.
+``models.cells.set_pallas_mode`` overrides as for the LSTM: ``"interpret"``
+runs the kernels in the interpreter, ``"off"`` forces the ``jnp`` body.
+
 Acting carry (worker-local; ``store_carry=False``): ``h`` holds each Mamba
 layer's state and convolution tail, flattened; ``c`` each attention layer's
 K/V ring and one step counter, as the transformer family packs its caches. A
@@ -35,6 +45,7 @@ carries a placeholder) and from an empty attention context — the truncation
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -42,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_rl.models import cells
+from tpu_rl.ops import pallas_ssd
 from tpu_rl.parallel.sequence import flash_attention_tpu, segment_ids_from_firsts
 
 
@@ -94,25 +107,78 @@ def seam_conv(xbc, tail, seg, weight, bias):
     return out
 
 
+def _ssd_kernel_block(b: int, h: int, p: int, g: int, n: int, Q: int) -> tuple[int | None, bool]:
+    """(heads per grid step of the Pallas scan, interpret), or (None, False)
+    for the ``jnp`` body: the gate of ``models/cells.py`` (``set_pallas_mode``,
+    the platform of the program being traced) applied to the scan. The CPU,
+    widths that are no lane multiples and a batch that does not tile a
+    registered data mesh (init and act traces: a Mosaic call has no SPMD
+    rule outside its island) keep the ``jnp`` form."""
+    mode = cells._PALLAS_MODE
+    if mode == "off":
+        return None, False
+    if mode == "interpret":  # any width: whole windows of every head where none tiles
+        return pallas_ssd.head_block(h, p, g, n, Q) or h, True
+    platform, n_data = cells._program_devices()
+    if platform != "tpu" or b % n_data:
+        return None, False
+    return pallas_ssd.head_block(h, p, g, n, Q), False
+
+
+def _ssd_kernels(x, dt, A, B, C, D, seg, state0, chunk, dtype, hb, interpret):
+    """The Pallas pair (``ops/pallas_ssd.py``); under a registered data mesh
+    whose width the batch tiles, as a ``shard_map`` island over the
+    ``"data"`` axis, as the LSTM kernel and the flash kernel run there."""
+    scan = functools.partial(
+        pallas_ssd.scan_window, chunk=chunk, dtype=dtype, hb=hb, interpret=interpret)
+    mesh = cells._DATA_MESH
+    if mesh is not None and x.shape[0] % cells._program_devices()[1] == 0:
+        from jax.sharding import PartitionSpec as P
+
+        from tpu_rl.parallel.mesh import DATA_AXIS
+
+        rows = P(DATA_AXIS)  # every operand but A and D: its leading (batch) dim
+        # no collectives inside; pallas out_shapes carry no vma annotations
+        scan = jax.shard_map(
+            scan, mesh=mesh, in_specs=(rows, rows, P(), rows, rows, P(), rows, rows),
+            out_specs=(rows, rows), check_vma=False)
+    with jax.named_scope("ssd_pallas"):  # the backward's ops carry it too
+        return scan(x, dt, A, B, C, D, seg, state0)
+
+
 @jax.named_scope("ssd_scan")
-def ssd_chunked(x, dt, A, B, C, D, seg, state0, chunk: int, dtype):
+def ssd_chunked(x, dt, A, B, C, D, seg, state0, chunk: int, dtype, kernel=None):
     """The SSD recurrence over a whole window in matmul form.
 
     ``x`` (b, T, h, p); ``dt`` (b, T, h) float32, after softplus; ``A`` (h,)
     negative; ``B``, ``C`` (b, T, g, n); ``seg`` (b, T) int, 0 = the episode
     ``state0`` (b, h, p, n) belongs to. Returns ``y`` (b, T, h, p) float32
     and the state after the last step. Matmul operands in ``dtype``; decays,
-    cumulative sums and the carried state in float32."""
+    cumulative sums and the carried state in float32. ``kernel``: ``(heads a
+    grid step of the Pallas pair or None for the jnp body, interpret)``
+    where the caller and not the gate chooses (tests, ``chip_smoke.py``)."""
     b, T, h, p = x.shape
     g, n = B.shape[2:]
-    r = h // g
     pad = (-T) % chunk
     if pad:  # dt = 0: the state passes through, nothing is added
         x, dt, B, C = (
             jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (x, dt, B, C)
         )
         seg = jnp.concatenate([seg, jnp.repeat(seg[:, -1:], pad, axis=1)], axis=1)
-    nc, Q = (T + pad) // chunk, chunk
+    hb, interpret = kernel or _ssd_kernel_block(b, h, p, g, n, chunk)
+    if hb is None:
+        y, last = _ssd_jnp(x, dt, A, B, C, D, seg, state0, chunk, dtype)
+    else:
+        y, last = _ssd_kernels(x, dt, A, B, C, D, seg, state0, chunk, dtype, hb, interpret)
+    return y[:, :T], last
+
+
+def _ssd_jnp(x, dt, A, B, C, D, seg, state0, Q: int, dtype):
+    """``ssd_chunked`` on a window of whole chunks as ``einsum``s and one
+    ``lax.scan`` over the chunks: the CPU's path and the kernels' oracle."""
+    b, T, h, p = x.shape
+    g, n = B.shape[2:]
+    r, nc = h // g, T // Q
     cd = dtype or jnp.float32
     f32 = jnp.float32
     xc = x.reshape(b, nc, Q, h, p)
@@ -164,7 +230,7 @@ def ssd_chunked(x, dt, A, B, C, D, seg, state0, chunk: int, dtype):
     ).reshape(b, nc, Q, h, p)
     y = y + y_in * into.transpose(0, 1, 3, 2)[..., None]
     y = y + xc.astype(f32) * D[:, None]
-    return y.reshape(b, nc * Q, h, p)[:, :T], last
+    return y.reshape(b, T, h, p), last
 
 
 def _dt_bias_init(key, shape, dtype=jnp.float32):
@@ -181,6 +247,9 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 
 
 class Mamba2Mixer(nn.Module):
+    """``__call__`` (training) runs ``ssd_chunked``: the Pallas kernels on a
+    TPU, the ``jnp`` body elsewhere; ``step`` (acting) is the one-step form."""
+
     arch: dict
     dtype: Any = None
 
