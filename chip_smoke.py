@@ -391,8 +391,14 @@ def kernel_checks(
         (1, 512, 8, 8, 64, None, False),
         (2, 4096, 32, 8, 64, 1.0 / 64, True),
     ),
-    # (B, T, heads, head dim, groups, state, chunk): granite-4.0-h-micro's scan
-    ssd_shapes=((2, 4096, 64, 64, 1, 128, 256),),
+    # (B, T, heads, head dim, groups, state, chunk): granite-4.0-h-micro's
+    # scan, then nemotron-3-nano-30b-a3b's (8 B/C groups, chunks of 128; two of
+    # its four rows: the kernels' grid runs over rows, and the row's operands
+    # are compiled into the case as constants, 0.6 GB a row)
+    ssd_shapes=((2, 4096, 64, 64, 1, 128, 256), (2, 4096, 64, 64, 8, 128, 128)),
+    # (rows, experts held, in, out): one projection of nemotron-3-nano-30b-a3b's
+    # routed experts, ragged groups of ~768 rows an expert
+    gmm_shapes=((6144, 8, 2688, 1856),),
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -564,6 +570,29 @@ def kernel_checks(
             f"ssd fwd+bwd B{B}/T{T}/H{H}x{P}/G{G}/N{N}/Q{Q} bf16 (head block {hb})",
             grads((hb, interpret)), grads((None, False)),
             (x, dt, A, Bm, Cm, D, state0), TOL_BF16, TOL_BF16,
+        )
+
+    # ---- the grouped matmul's Pallas kernels vs ragged_dot, both gradients
+    from tpu_rl.ops.moe import grouped_matmul
+
+    for M, G, K, N in gmm_shapes:
+        lhs = (f32(M, K)).astype(jnp.bfloat16)
+        rhs = (f32(G, K, N) * K**-0.5).astype(jnp.bfloat16)
+        cuts = np.sort(rng.integers(0, M + 1, G - 1))  # ragged groups that fill the rows
+        sizes = jnp.asarray(np.diff(np.concatenate([[0], cuts, [M]])), jnp.int32)
+        w_out = f32(M, N).astype(jnp.bfloat16)
+
+        def gmm_grads(kernel, dtype):
+            # ragged_dot is a Mosaic kernel on a TPU too, and that one takes no
+            # bf16 operands at "highest" precision: the reference gets float32
+            return jax.value_and_grad(
+                lambda a, b: (grouped_matmul(a.astype(dtype), b.astype(dtype), sizes, kernel)
+                              * w_out).astype(jnp.float32).sum(), argnums=(0, 1))
+
+        case(
+            f"gmm fwd+bwd M{M}/G{G}/K{K}/N{N} bf16",
+            gmm_grads((True, interpret), jnp.bfloat16), gmm_grads((False, False), jnp.float32),
+            (lhs, rhs), TOL_BF16, TOL_BF16,
         )
     return rows
 

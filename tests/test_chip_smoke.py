@@ -57,10 +57,11 @@ def test_kernel_checks_pass_tiny_interpreted():
             (1, 128, 2, 2, 16, None, False),
             (2, 128, 4, 2, 16, 1.0 / 64, True),
         ),
-        ssd_shapes=((2, 32, 4, 8, 1, 16, 8),),
+        ssd_shapes=((2, 32, 4, 8, 1, 16, 8), (2, 32, 4, 8, 2, 16, 8)),
+        gmm_shapes=((512, 4, 64, 48),),
         interpret=True,
     )
-    assert len(rows) == 7
+    assert len(rows) == 9
     assert all(r["ok"] for r in rows), rows
 
 

@@ -1,0 +1,236 @@
+"""The sparse-expert block's pieces (``tpu_rl/ops/moe.py``) on the CPU: the
+router, the grouped matmul (``ragged_dot`` body against a per-expert loop, the
+Pallas kernel in the interpreter against the body, both operands' gradients),
+and the sort / gather / grouped-product / gather-back dispatch against every
+held expert applied densely under a mask, under forced imbalance too."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_rl.ops import moe
+
+D, F, HELD, TOTAL, K = 32, 24, 4, 16, 3
+
+
+def close(got, want, tol=1e-5):
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=tol)
+
+
+def operands(seed: int, rows: int, sizes):
+    rng = np.random.default_rng(seed)
+    lhs = jnp.asarray(rng.standard_normal((rows, D)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((len(sizes), D, F)) / np.sqrt(D), jnp.float32)
+    return lhs, rhs, jnp.asarray(sizes, jnp.int32)
+
+
+def loop_matmul(lhs, rhs, sizes):
+    """Group g's rows times ``rhs[g]``, one expert at a time; zeros after."""
+    out, start = jnp.zeros((lhs.shape[0], rhs.shape[2])), 0
+    for g, n in enumerate(np.asarray(sizes)):
+        out = out.at[start:start + n].set(lhs[start:start + n] @ rhs[g])
+        start += n
+    return out
+
+
+SIZES = {"even": [64, 64, 64, 64], "ragged": [5, 130, 0, 70], "one-group": [0, 0, 256, 0],
+         "empty": [0, 0, 0, 0], "partly-filled": [3, 0, 1, 9]}
+
+
+@pytest.mark.parametrize("sizes", SIZES.values(), ids=SIZES.keys())
+@pytest.mark.parametrize("kernel", [(False, False), (True, True)], ids=["ragged_dot", "pallas"])
+def test_grouped_matmul_equals_a_loop_over_the_experts(sizes, kernel):
+    """Forward and both operands' gradients over the groups' rows (past their
+    total ``ragged_dot`` gives zeros and the kernel writes nothing). ``pallas``:
+    the megablox kernels (product, transposed product) in the interpreter."""
+    lhs, rhs, sz = operands(0, 256, sizes)
+    w = jnp.asarray(np.random.default_rng(1).standard_normal((256, F)), jnp.float32)
+    total = int(sum(sizes))
+    live = (jnp.arange(256) < total)[:, None]
+
+    def weighted(f):
+        return jax.value_and_grad(
+            lambda a, b: jnp.sum(jnp.where(live, w * f(a, b, sz), 0.0)), argnums=(0, 1))
+
+    got, (d_lhs, d_rhs) = weighted(lambda a, b, s: moe.grouped_matmul(a, b, s, kernel))(lhs, rhs)
+    want, (r_lhs, r_rhs) = weighted(loop_matmul)(lhs, rhs)
+    close(got, want, 1e-3)
+    close(d_lhs[:total], r_lhs[:total], 1e-4)
+    close(d_rhs, r_rhs, 1e-4)
+    if not kernel[0]:
+        assert not np.asarray(d_lhs)[total:].any()
+
+
+def router_params(seed: int):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.standard_normal((D, TOTAL)) / np.sqrt(D), jnp.float32),
+            jnp.asarray(0.05 * rng.standard_normal(TOTAL), jnp.float32))
+
+
+def test_the_router_scores_every_expert_and_weights_the_chosen():
+    u = jnp.asarray(np.random.default_rng(2).standard_normal((40, D)), jnp.float32)
+    kernel, bias = router_params(3)
+    choice, weight = moe.route(u, kernel, bias, K, 2.5)
+    s = 1.0 / (1.0 + np.exp(-np.asarray(u) @ np.asarray(kernel)))
+    want = np.argsort(-(s + np.asarray(bias)), axis=-1, kind="stable")[:, :K]
+    assert np.array_equal(choice, want)
+    chosen = np.take_along_axis(s, want, axis=-1)
+    close(weight, 2.5 * chosen / chosen.sum(-1, keepdims=True), 1e-6)
+    close(weight.sum(-1), 2.5, 1e-5)
+
+
+def test_the_bias_moves_a_choice_and_gets_no_gradient():
+    """``b`` is read by the choice alone: raising one expert's bias puts it
+    among the chosen, its weight is still its unbiased score's share, and the
+    gradient of anything downstream with respect to ``b`` is zero while the
+    router's own weights get one."""
+    u = jnp.asarray(np.random.default_rng(4).standard_normal((40, D)), jnp.float32)
+    kernel, bias = router_params(5)
+    choice, _ = moe.route(u, kernel, bias, K, 1.0)
+    never = int(np.setdiff1d(np.arange(TOTAL), np.asarray(choice)[0])[0])
+    moved, weight = moe.route(u, kernel, bias.at[never].add(10.0), K, 1.0)
+    assert (np.asarray(moved) == never).any(axis=-1).all() and not (np.asarray(choice)[0] == never).any()
+    s = 1.0 / (1.0 + np.exp(-np.asarray(u) @ np.asarray(kernel)))
+    chosen = np.take_along_axis(s, np.asarray(moved), axis=-1)
+    close(weight, chosen / chosen.sum(-1, keepdims=True), 1e-6)
+
+    def downstream(kernel, bias):
+        _, w = moe.route(u, kernel, bias, K, 1.0)
+        return jnp.sum(w * jnp.arange(1.0, K + 1))
+
+    d_kernel, d_bias = jax.grad(downstream, argnums=(0, 1))(kernel, bias)
+    assert not np.asarray(d_bias).any() and np.abs(np.asarray(d_kernel)).max() > 1e-4
+
+
+def expert_weights(seed: int):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.standard_normal((HELD, D, F)) / np.sqrt(D), jnp.float32),
+            jnp.asarray(rng.standard_normal((HELD, F, D)) / np.sqrt(F), jnp.float32))
+
+
+def assignments(kind: str, n: int, first: int):
+    """(choice, weight) for ``n`` tokens; held experts are first..first+HELD."""
+    rng = np.random.default_rng(6)
+    weight = jnp.asarray(rng.random((n, K)) + 0.1, jnp.float32)
+    if kind == "random":
+        choice = np.stack([rng.permutation(TOTAL)[:K] for _ in range(n)])
+    elif kind == "all-on-one-held":  # every token's first choice is one held expert
+        others = [e for e in range(TOTAL) if not first <= e < first + HELD]
+        choice = np.stack([[first + 2, *rng.permutation(others)[: K - 1]] for _ in range(n)])
+    elif kind == "all-held":  # every assignment of every token lands here
+        choice = np.stack([first + rng.permutation(HELD)[:K] for _ in range(n)])
+    else:  # "none-held"
+        others = [e for e in range(TOTAL) if not first <= e < first + HELD]
+        choice = np.stack([rng.permutation(others)[:K] for _ in range(n)])
+    return jnp.asarray(choice, jnp.int32), weight
+
+
+@pytest.mark.parametrize("kind", ["random", "all-on-one-held", "all-held", "none-held"])
+@pytest.mark.parametrize("kernel", [(False, False), (True, True)], ids=["ragged_dot", "pallas"])
+def test_the_dispatch_drops_no_token_whatever_the_imbalance(kind, kernel):
+    """Sparse against dense-under-a-mask: output and the gradients of the
+    tokens, the weights and both expert projections. The row buffer holds
+    every assignment, so a fully one-sided routing loses nothing."""
+    n, first = 50, 8
+    u = jnp.asarray(np.random.default_rng(7).standard_normal((n, D)), jnp.float32)
+    w_in, w_out = expert_weights(8)
+    choice, weight = assignments(kind, n, first)
+    mix = jnp.asarray(np.random.default_rng(9).standard_normal((n, D)), jnp.float32)
+
+    def value_and_grads(f):
+        return jax.jit(jax.value_and_grad(
+            lambda u, wt, a, b: jnp.sum(mix * f(u, choice, wt, a, b, first)),
+            argnums=(0, 1, 2, 3)))(u, weight, w_in, w_out)
+
+    got, grads = value_and_grads(
+        lambda *a: moe.routed_experts(*a, kernel=kernel))
+    want, ref_grads = value_and_grads(moe.routed_experts_dense)
+    close(got, want, 1e-4)
+    for g, r in zip(grads, ref_grads):
+        close(g, r, 1e-4)
+    stats = moe.route_stats(choice, first, HELD)
+    expect = {"random": None, "all-on-one-held": n, "all-held": n * K, "none-held": 0}[kind]
+    if expect is not None:
+        assert float(stats["rows"]) == expect
+    if kind == "none-held":
+        assert not np.asarray(got).any() and float(stats["no-held-share"]) == 1.0
+    if kind == "all-on-one-held":
+        assert float(stats["rows-max"]) == n and float(stats["rows-mean"]) == n / HELD
+
+
+def test_route_stats_count_rows_per_held_expert():
+    choice = jnp.asarray([[8, 9, 0], [9, 1, 2], [9, 10, 11], [3, 4, 5]], jnp.int32)
+    stats = {k: float(v) for k, v in moe.route_stats(choice, 8, HELD).items()}
+    assert stats == pytest.approx({
+        "rows": 6.0, "rows-max": 3.0, "rows-mean": 1.5, "held-share": 0.5, "no-held-share": 0.25})
+
+
+def test_gather_and_its_inverse_are_each_others_transposes():
+    """``_dispatch``'s backward is a gather by the inverse permutation and a
+    sum over a token's slots: what autodiff's scatter-add computes."""
+    rng = np.random.default_rng(10)
+    n, k = 12, 3
+    u = jnp.asarray(rng.standard_normal((n, D)), jnp.float32)
+    order = jnp.asarray(rng.permutation(n * k), jnp.int32)
+    place = jnp.zeros_like(order).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
+    w = jnp.asarray(rng.standard_normal((n * k, D)), jnp.float32)
+    got = jax.grad(lambda u: jnp.sum(w * moe._dispatch(u, order, place, jnp.ones((n, k), bool))))(u)
+    want = jax.grad(lambda u: jnp.sum(w * u[order // k]))(u)
+    close(got, want)
+    rows = jnp.asarray(rng.standard_normal((n * k, D)), jnp.float32)
+    got = jax.grad(lambda r: jnp.sum(w * moe._collect(r, order, place)))(rows)
+    want = jax.grad(lambda r: jnp.sum(w * r[place]))(rows)
+    close(got, want)
+    close(moe._collect(moe._dispatch(u, order, place, jnp.ones((n, k), bool)), order, place), jnp.repeat(u, k, axis=0))
+
+
+def test_what_an_unwritten_row_holds_goes_nowhere(monkeypatch):
+    """The kernel never writes the rows past the held total, so they may hold
+    anything. With NaN put there after each product, the block's output and
+    every gradient are what they were: the combine and the dispatch's backward
+    select held assignments, they do not multiply by zero."""
+    n, first = 50, 8
+    u = jnp.asarray(np.random.default_rng(7).standard_normal((n, D)), jnp.float32)
+    w_in, w_out = expert_weights(8)
+    choice, weight = assignments("random", n, first)
+
+    def value_and_grads():
+        return jax.value_and_grad(
+            lambda u, wt, a, b: jnp.sum(moe.routed_experts(u, choice, wt, a, b, first) ** 2),
+            argnums=(0, 1, 2, 3))(u, weight, w_in, w_out)
+
+    want, ref_grads = value_and_grads()
+    plain = moe.grouped_matmul
+
+    @jax.custom_vjp
+    def poison(x, live):
+        return jnp.where(live, x, jnp.nan)
+
+    poison.defvjp(lambda x, live: (poison(x, live), live),
+                  lambda live, g: (jnp.where(live, g, jnp.nan), None))
+
+    def poisoned(lhs, rhs, sizes, kernel=None):
+        live = (jnp.arange(lhs.shape[0]) < jnp.sum(sizes))[:, None]
+        return poison(plain(lhs, rhs, sizes, kernel), live)
+
+    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+    got, grads = value_and_grads()
+    close(got, want)
+    for g, r in zip(grads, ref_grads):
+        assert np.isfinite(np.asarray(g)).all()
+        close(g, r)
+
+
+def test_the_gate_keeps_the_body_off_the_chip(monkeypatch):
+    from tpu_rl.models import cells
+
+    assert moe._gmm_gate(98304, 2688, 1856) == (False, False)  # a CPU
+    monkeypatch.setattr(cells, "_PALLAS_MODE", "interpret")
+    assert moe._gmm_gate(98304, 2688, 1856) == (True, True)
+    monkeypatch.setattr(cells, "_PALLAS_MODE", "off")
+    assert moe._gmm_gate(98304, 2688, 1856) == (False, False)
+    # the published widths tile: both projections, and their transposes
+    assert moe._gmm_tiles(98304, 2688, 1856) == (256, 896, 512)
+    assert moe._gmm_tiles(98304, 1856, 2688) == (256, 1856, 512)
+    assert moe._gmm_tiles(100, 2688, 1856) is None and moe._gmm_tiles(256, 100, 128) is None

@@ -15,12 +15,13 @@ import jax.numpy as jnp
 import optax
 
 from tpu_rl.algos.base import TrainState, rmsprop
-from tpu_rl.algos.ppo import policy_outputs
+from tpu_rl.algos.ppo import policy_outputs_routed
 from tpu_rl.config import Config
 from tpu_rl.heal.guards import guarded, update_ok
 from tpu_rl.models.families import ModelFamily
 from tpu_rl.obs.learn import (
     module_grad_norms,
+    route_scalars,
     rows_mean,
     tree_delta_norm,
     tree_norm,
@@ -34,7 +35,7 @@ def make_train_step(cfg: Config, family: ModelFamily):
     opt = rmsprop(cfg)
 
     def loss_fn(params, batch: Batch):
-        log_probs, entropy, value, logits = policy_outputs(family, params, batch)
+        log_probs, entropy, value, logits, routes = policy_outputs_routed(family, params, batch)
 
         v_lo, v_hi = cfg.value_target_clip or (None, None)
         ratio, advantages, values_target = vtrace(
@@ -109,7 +110,7 @@ def make_train_step(cfg: Config, family: ModelFamily):
                     "err": rows_mean(err),
                     "err2": rows_mean(jnp.square(err)),
                 },
-                "scalars": {},
+                "scalars": route_scalars(routes),
             }
         return loss, metrics
 

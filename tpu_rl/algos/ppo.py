@@ -19,6 +19,7 @@ from tpu_rl.heal.guards import guarded, update_ok
 from tpu_rl.models.families import ModelFamily
 from tpu_rl.obs.learn import (
     module_grad_norms,
+    route_scalars,
     rows_mean,
     tree_delta_norm,
     tree_norm,
@@ -32,7 +33,15 @@ from tpu_rl.types import Batch
 def policy_outputs(family: ModelFamily, params, batch: Batch):
     """Shared-torso forward for the on-policy families. Returns
     (log_probs (B,S,Alp), entropy (B,S,1), value (B,S,1), logits (B,S,A))."""
+    return policy_outputs_routed(family, params, batch)[:4]
+
+
+def policy_outputs_routed(family: ModelFamily, params, batch: Batch):
+    """``policy_outputs`` and, fifth, each expert layer's routing (the chosen
+    experts per step and the counters; ``models/nemotron_h.py``): an empty
+    list for a family without expert layers."""
     carry0 = (batch.hx[:, 0], batch.cx[:, 0])
+    routes = []
     if family.continuous:
         mu, std, value, _ = family.actor_unroll(
             params["actor"], batch.obs, carry0, batch.is_fir
@@ -41,13 +50,18 @@ def policy_outputs(family: ModelFamily, params, batch: Batch):
         entropy = jnp.mean(D.normal_entropy(std), axis=-1, keepdims=True)
         logits = jnp.zeros_like(mu)
     else:
-        logits, value, _ = family.actor_unroll(
-            params["actor"], batch.obs, carry0, batch.is_fir
-        )
+        if family.route_unroll is not None:
+            (logits, value, _), routes = family.route_unroll(
+                params["actor"], batch.obs, carry0, batch.is_fir
+            )
+        else:
+            logits, value, _ = family.actor_unroll(
+                params["actor"], batch.obs, carry0, batch.is_fir
+            )
         acts = batch.act[..., 0]
         log_probs = D.categorical_log_prob(logits, acts)[..., None]
         entropy = D.categorical_entropy(logits)[..., None]
-    return log_probs, entropy, value, logits
+    return log_probs, entropy, value, logits, routes
 
 
 def td_target_and_gae(cfg: Config, batch: Batch, value: jax.Array):
@@ -62,7 +76,7 @@ def make_train_step(cfg: Config, family: ModelFamily):
     opt = rmsprop(cfg)
 
     def loss_fn(params, batch: Batch):
-        log_probs, entropy, value, _ = policy_outputs(family, params, batch)
+        log_probs, entropy, value, _, routes = policy_outputs_routed(family, params, batch)
         td_target, advantage = td_target_and_gae(cfg, batch, value)
 
         ratio = jnp.exp(log_probs[:, :-1] - batch.log_prob[:, :-1])
@@ -115,7 +129,7 @@ def make_train_step(cfg: Config, family: ModelFamily):
                     "err": rows_mean(err),
                     "err2": rows_mean(jnp.square(err)),
                 },
-                "scalars": {},
+                "scalars": route_scalars(routes),
             }
         return loss, metrics
 

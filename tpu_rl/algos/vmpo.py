@@ -29,12 +29,13 @@ import jax.numpy as jnp
 import optax
 
 from tpu_rl.algos.base import TrainState, rmsprop
-from tpu_rl.algos.ppo import policy_outputs, td_target_and_gae
+from tpu_rl.algos.ppo import policy_outputs_routed, td_target_and_gae
 from tpu_rl.config import Config
 from tpu_rl.heal.guards import guarded, update_ok
 from tpu_rl.models.families import ModelFamily
 from tpu_rl.obs.learn import (
     module_grad_norms,
+    route_scalars,
     rows_mean,
     tree_delta_norm,
     tree_norm,
@@ -71,7 +72,7 @@ def make_train_step(cfg: Config, family: ModelFamily):
     opt = rmsprop(cfg)
 
     def loss_fn(params, batch: Batch, key: jax.Array):
-        log_probs, _entropy, value, logits = policy_outputs(family, params, batch)
+        log_probs, _entropy, value, logits, routes = policy_outputs_routed(family, params, batch)
         td_target, advantage = td_target_and_gae(cfg, batch, value)
 
         eta = jnp.exp(params["log_eta"])
@@ -163,6 +164,7 @@ def make_train_step(cfg: Config, family: ModelFamily):
                     # shape.
                     "eta": jax.lax.stop_gradient(eta),
                     "vmpo-alpha": jax.lax.stop_gradient(alpha),
+                    **route_scalars(routes),
                 },
             }
         return loss, metrics

@@ -93,6 +93,65 @@ def _check_granite_arch(arch: dict | None) -> None:
     assert arch["mamba_d_conv"] >= 2 and arch["mamba_chunk_size"] >= 1
 
 
+# The keys of a NemotronH ``config.json`` that shape the policy core
+# (``models/nemotron_h.py``); the optional group ``expert_parallel``
+# (``published_n_routed_experts``, ``chips``, ``rank``) states the deployment
+# whose one rank ``n_routed_experts`` counts.
+NEMOTRON_ARCH_KEYS = (
+    "hidden_size", "hybrid_override_pattern", "layer_norm_epsilon",
+    "mamba_num_heads", "mamba_head_dim", "ssm_state_size", "n_groups",
+    "conv_kernel", "chunk_size", "use_conv_bias", "mamba_proj_bias",
+    "num_attention_heads", "num_key_value_heads", "head_dim", "attention_bias",
+    "n_routed_experts", "num_experts_per_tok", "moe_intermediate_size",
+    "moe_shared_expert_intermediate_size", "n_shared_experts", "norm_topk_prob",
+    "routed_scaling_factor",
+)
+
+
+def _check_nemotron_arch(arch: dict | None) -> None:
+    """What ``model="nemotron_h"`` can build: Mamba-2 (``M``), attention
+    without positions (``*``) and sparse-expert (``E``) layers, one mixer a
+    layer; sigmoid routing in one group, the chosen scores normalised; ``relu2``
+    experts without bias."""
+    assert isinstance(arch, dict), "model='nemotron_h' needs arch (config.json keys)"
+    missing = [k for k in NEMOTRON_ARCH_KEYS if k not in arch]
+    assert not missing, f"arch lacks {missing}"
+    pattern = arch["hybrid_override_pattern"]
+    assert pattern and set(pattern) <= set("ME*"), (
+        f"layers {pattern!r}: only M (Mamba-2), E (experts) and * (attention) are built"
+    )
+    assert arch.get("mlp_hidden_act", "relu2") == "relu2", (
+        f"mlp_hidden_act {arch.get('mlp_hidden_act')!r}: only relu2 experts are built"
+    )
+    assert arch.get("mamba_hidden_act", "silu") == "silu", arch.get("mamba_hidden_act")
+    assert not arch.get("mlp_bias", False), "expert projections have no bias"
+    assert arch["n_shared_experts"] == 1, "one shared expert is built"
+    assert arch["norm_topk_prob"], "the chosen scores are always normalised"
+    assert arch.get("n_group", 1) == 1 and arch.get("topk_group", 1) == 1, (
+        "the router's group stage is not built"
+    )
+    assert arch["mamba_num_heads"] % arch["n_groups"] == 0
+    assert arch["num_attention_heads"] % arch["num_key_value_heads"] == 0
+    assert arch["conv_kernel"] >= 2 and arch["chunk_size"] >= 1
+    held = arch["n_routed_experts"]
+    share = arch.get("expert_parallel")
+    if share:
+        total, chips, rank = (
+            share[k] for k in ("published_n_routed_experts", "chips", "rank")
+        )
+        assert held * chips == total, (
+            f"{chips} chips x {held} routed experts held is not the published {total}"
+        )
+        assert 0 <= rank < chips, f"rank {rank} of {chips}"
+    else:
+        total = held
+    assert 1 <= arch["num_experts_per_tok"] <= total, arch["num_experts_per_tok"]
+
+
+# The families built from a published config.json in ``Config.arch``.
+ARCH_CHECKS = {"granite_hybrid": _check_granite_arch, "nemotron_h": _check_nemotron_arch}
+
+
 @dataclass
 class Config:
     """Hyperparameters. Field names/defaults match the reference's
@@ -115,7 +174,8 @@ class Config:
     # Policy backbone: "lstm" (reference parity), "transformer" (new
     # TPU-native long-context capability; on-policy algos only) or
     # "granite_hybrid" (Mamba-2 + GQA attention layers of a published
-    # GraniteMoeHybrid config.json, given whole in ``arch``).
+    # GraniteMoeHybrid config.json, given whole in ``arch``) or "nemotron_h"
+    # (Mamba-2, attention and sparse-expert layers of a NemotronH config.json).
     model: str = "lstm"
     n_heads: int = 4
     n_layers: int = 2
@@ -126,7 +186,8 @@ class Config:
     # 0 = use seq_len.
     act_ctx: int = 0
     # A published architecture's own config.json, under its published key
-    # names (model="granite_hybrid": GRANITE_ARCH_KEYS above). One
+    # names (model="granite_hybrid": GRANITE_ARCH_KEYS above; "nemotron_h":
+    # NEMOTRON_ARCH_KEYS). One
     # mapping instead of a Config field per width: the widths of a catalog
     # model are its source's to name, not this file's.
     arch: dict | None = None
@@ -735,16 +796,17 @@ class Config:
             "float32",
             "bfloat16",
         ), f"compute_dtype must be float32 or bfloat16, got {self.compute_dtype!r}"
-        assert self.model in ("lstm", "transformer", "granite_hybrid"), self.model
-        if self.model == "granite_hybrid":
+        assert self.model in ARCH_CHECKS or self.model in ("lstm", "transformer"), self.model
+        if self.model in ARCH_CHECKS:
             assert not is_off_policy(self.algo) and not self.algo.endswith(
                 "-Continuous"
-            ), "granite_hybrid backbone supports the discrete on-policy algorithms"
-            assert self.mesh_seq == 1, "granite_hybrid has no sequence-parallel path"
-            _check_granite_arch(self.arch)
+            ), f"{self.model} backbone supports the discrete on-policy algorithms"
+            assert self.mesh_seq == 1, f"{self.model} has no sequence-parallel path"
+            ARCH_CHECKS[self.model](self.arch)
         else:
             assert self.arch is None, (
-                f"arch is read by model='granite_hybrid' only, not {self.model!r}"
+                f"arch is read by model='granite_hybrid' and 'nemotron_h' only, "
+                f"not {self.model!r}"
             )
         # bfloat16 is wired for both backbones: the transformer via flax
         # module dtype (transformer.py), the LSTM families via

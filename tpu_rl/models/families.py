@@ -68,6 +68,9 @@ class ModelFamily:
     # inits from seq-step-0 states; transformers ignore the carry, so
     # shipping it would waste DCN bandwidth and shm).
     store_carry: bool = True
+    # Families with sparse-expert layers: ``(actor_params, obs, carry0,
+    # firsts) -> (the unroll's outputs, each expert layer's routing)``.
+    route_unroll: Callable[..., tuple[tuple, list]] | None = field(repr=False, default=None)
 
     @property
     def carry_widths(self) -> tuple[int, int]:
@@ -198,7 +201,8 @@ def _act_transformer_window(
 
 
 def _act_granite_hybrid(actor, params, obs, h, c, key):
-    """One recurrent step of the hybrid family: ``h`` holds the Mamba layers'
+    """One recurrent step of the hybrid families (granite_hybrid,
+    nemotron_h): ``h`` holds the Mamba layers'
     states and convolution tails, ``c`` the attention layers' K/V rings and a
     step counter (``models/granite_hybrid.py``). The worker zeroes both at
     episode starts, so no state crosses episodes."""
@@ -274,6 +278,22 @@ def build_family(cfg: Config, mesh=None) -> ModelFamily:
             act=partial(_act_granite_hybrid, actor),
             act_carry_widths=carry_widths(cfg.arch, ctx),
             store_carry=False,
+        )
+
+    if cfg.model == "nemotron_h":
+        from tpu_rl.models.nemotron_h import NemotronHActorCritic, carry_widths
+
+        ctx = cfg.effective_act_ctx
+        actor = NemotronHActorCritic(
+            n_actions=n, arch=cfg.arch, act_ctx=ctx,
+            dtype=jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else None,
+        )
+        return ModelFamily(
+            cfg.algo, False, False, actor, None, obs_dim, n, cfg.arch["hidden_size"],
+            act=partial(_act_granite_hybrid, actor),
+            act_carry_widths=carry_widths(cfg.arch, ctx),
+            store_carry=False,
+            route_unroll=partial(actor.apply, method="unroll_routed"),
         )
 
     if cfg.algo in ("PPO", "IMPALA", "V-MPO"):

@@ -248,28 +248,35 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 
 class Mamba2Mixer(nn.Module):
     """``__call__`` (training) runs ``ssd_chunked``: the Pallas kernels on a
-    TPU, the ``jnp`` body elsewhere; ``step`` (acting) is the one-step form."""
+    TPU, the ``jnp`` body elsewhere; ``step`` (acting) is the one-step form.
+    The widths are fields, so that a family whose ``config.json`` names them
+    otherwise (``models/nemotron_h.py``) builds the same mixer."""
 
-    arch: dict
+    hidden: int
+    heads: int
+    d_head: int
+    groups: int
+    d_state: int
+    d_conv: int
+    chunk: int
+    eps: float
+    conv_bias: bool = True
+    proj_bias: bool = False
     dtype: Any = None
 
     def setup(self):
-        a = self.arch
-        self.heads, self.d_head = a["mamba_n_heads"], a["mamba_d_head"]
-        self.groups, self.d_state = a["mamba_n_groups"], a["mamba_d_state"]
         self.inner = self.heads * self.d_head
-        self.conv_ch = _conv_channels(a)
-        K = a["mamba_d_conv"]
-        proj = dict(use_bias=bool(a["mamba_proj_bias"]), dtype=self.dtype)
+        self.conv_ch = self.inner + 2 * self.groups * self.d_state
+        proj = dict(use_bias=self.proj_bias, dtype=self.dtype)
         self.in_proj = nn.Dense(self.inner + self.conv_ch + self.heads, name="in_proj", **proj)
-        self.out_proj = nn.Dense(a["hidden_size"], name="out_proj", **proj)
+        self.out_proj = nn.Dense(self.hidden, name="out_proj", **proj)
         self.conv_weight = self.param(
             "conv_weight", nn.initializers.variance_scaling(1.0, "fan_in", "uniform", in_axis=0),
-            (K, self.conv_ch),
+            (self.d_conv, self.conv_ch),
         )
-        self.conv_bias = (
+        self.conv_b = (
             self.param("conv_bias", nn.initializers.zeros, (self.conv_ch,))
-            if a["mamba_conv_bias"] else jnp.zeros((self.conv_ch,))
+            if self.conv_bias else jnp.zeros((self.conv_ch,))
         )
         self.dt_bias = self.param("dt_bias", _dt_bias_init, (self.heads,))
         self.A_log = self.param("A_log", _a_log_init, (self.heads,))
@@ -297,7 +304,7 @@ class Mamba2Mixer(nn.Module):
         projection. ``y`` float32 (..., inner)."""
         lead = y.shape[:-1]
         gated = (y * jax.nn.silu(z.astype(jnp.float32))).reshape(*lead, self.groups, -1)
-        normed = _rms_norm(gated, 1.0, self.arch["rms_norm_eps"]).reshape(*lead, self.inner)
+        normed = _rms_norm(gated, 1.0, self.eps).reshape(*lead, self.inner)
         return self.out_proj((normed * self.norm_scale).astype(self.dtype or jnp.float32))
 
     def __call__(self, u, seg, state0, tail0):
@@ -305,10 +312,9 @@ class Mamba2Mixer(nn.Module):
         the carry the window starts from. Returns the output and the carry
         after the last step."""
         z, xbc, dt = self._split(u)
-        x, B, C = self._heads(seam_conv(xbc, tail0, seg, self.conv_weight, self.conv_bias))
+        x, B, C = self._heads(seam_conv(xbc, tail0, seg, self.conv_weight, self.conv_b))
         y, state = ssd_chunked(
-            x, dt, -jnp.exp(self.A_log), B, C, self.D, seg, state0,
-            self.arch["mamba_chunk_size"], self.dtype,
+            x, dt, -jnp.exp(self.A_log), B, C, self.D, seg, state0, self.chunk, self.dtype,
         )
         K = self.conv_weight.shape[0]
         keep = (seg[:, -(K - 1):] == seg[:, -1:])[..., None]  # taps of the last episode only
@@ -319,7 +325,7 @@ class Mamba2Mixer(nn.Module):
         """One acting step: ``u`` (B, d)."""
         z, xbc, dt = self._split(u)
         window = jnp.concatenate([tail, xbc[:, None].astype(jnp.float32)], axis=1)
-        conv = jnp.einsum("bkc,kc->bc", window, self.conv_weight) + self.conv_bias
+        conv = jnp.einsum("bkc,kc->bc", window, self.conv_weight) + self.conv_b
         x, B, C = self._heads(conv)
         r = self.heads // self.groups
         x = x.astype(jnp.float32)
@@ -332,20 +338,23 @@ class Mamba2Mixer(nn.Module):
 
 class GQAttention(nn.Module):
     """Grouped-query attention without positions (``nope``), causal and
-    masked to the episode."""
+    masked to the episode. Widths as fields: the head size need not be
+    ``hidden / n_q``."""
 
-    arch: dict
+    hidden: int
+    n_q: int
+    n_kv: int
+    head_dim: int
+    scale: float  # of the scores, before the softmax
+    bias: bool = False
     dtype: Any = None
 
     def setup(self):
-        a = self.arch
-        self.n_q, self.n_kv = a["num_attention_heads"], a["num_key_value_heads"]
-        self.head_dim = a["hidden_size"] // self.n_q
-        proj = dict(use_bias=bool(a["attention_bias"]), dtype=self.dtype)
+        proj = dict(use_bias=self.bias, dtype=self.dtype)
         self.q_proj = nn.Dense(self.n_q * self.head_dim, name="q_proj", **proj)
         self.k_proj = nn.Dense(self.n_kv * self.head_dim, name="k_proj", **proj)
         self.v_proj = nn.Dense(self.n_kv * self.head_dim, name="v_proj", **proj)
-        self.o_proj = nn.Dense(a["hidden_size"], name="o_proj", **proj)
+        self.o_proj = nn.Dense(self.hidden, name="o_proj", **proj)
 
     def __call__(self, u, seg):
         B, T, _ = u.shape
@@ -356,9 +365,7 @@ class GQAttention(nn.Module):
             for p in (self.k_proj, self.v_proj)
         )
         pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        o = flash_attention_tpu(
-            q, k, v, pos, seg, causal=True, sm_scale=self.arch["attention_multiplier"]
-        )
+        o = flash_attention_tpu(q, k, v, pos, seg, causal=True, sm_scale=self.scale)
         return self.o_proj(o.reshape(B, T, -1))
 
     def step(self, u, k_cache, v_cache, count):
@@ -378,7 +385,7 @@ class GQAttention(nn.Module):
         valid = jnp.arange(ctx)[None] <= count[:, None]
         scores = jnp.einsum(
             "bgrd,btgd->bgrt", q, k_cache.astype(q.dtype), preferred_element_type=jnp.float32
-        ) * jnp.float32(self.arch["attention_multiplier"])
+        ) * jnp.float32(self.scale)
         w = jax.nn.softmax(jnp.where(valid[:, None, None], scores, -jnp.inf), axis=-1)
         o = jnp.einsum(
             "bgrt,btgd->bgrd", w.astype(q.dtype), v_cache.astype(q.dtype),
@@ -400,8 +407,22 @@ class HybridLayer(nn.Module):
         norm = dict(eps=a["rms_norm_eps"], dtype=self.dtype)
         self.input_norm = RMSNorm(name="input_norm", **norm)
         self.post_norm = RMSNorm(name="post_norm", **norm)
-        mixer = Mamba2Mixer if self.kind == "mamba" else GQAttention
-        self.mixer = mixer(a, self.dtype, name=self.kind)
+        if self.kind == "mamba":
+            self.mixer = Mamba2Mixer(
+                hidden=a["hidden_size"], heads=a["mamba_n_heads"], d_head=a["mamba_d_head"],
+                groups=a["mamba_n_groups"], d_state=a["mamba_d_state"], d_conv=a["mamba_d_conv"],
+                chunk=a["mamba_chunk_size"], eps=a["rms_norm_eps"],
+                conv_bias=bool(a["mamba_conv_bias"]), proj_bias=bool(a["mamba_proj_bias"]),
+                dtype=self.dtype, name="mamba",
+            )
+        else:
+            self.mixer = GQAttention(
+                hidden=a["hidden_size"], n_q=a["num_attention_heads"],
+                n_kv=a["num_key_value_heads"],
+                head_dim=a["hidden_size"] // a["num_attention_heads"],
+                scale=a["attention_multiplier"], bias=bool(a["attention_bias"]),
+                dtype=self.dtype, name="attention",
+            )
         self.mlp_in = nn.Dense(
             2 * a["intermediate_size"], use_bias=False, dtype=self.dtype, name="mlp_in"
         )
@@ -479,10 +500,12 @@ class GraniteHybridActorCritic(nn.Module):
             axis=1,
         )
 
-    def __call__(self, obs, carry0, firsts):
+    def _unroll(self, obs, carry0, firsts):
         """``carry0 = (h, c)``: ``h`` of the acting width is the state the
         window starts from; any other width (the batch's 1-float placeholder)
-        means zeros. ``c`` is returned as it came."""
+        means zeros. ``c`` is returned as it came. Also returns what the
+        layers without a carry handed back beside their output (this family:
+        nothing)."""
         B = obs.shape[0]
         h0, c0 = carry0
         if h0.shape[-1] != self.h_width:
@@ -490,15 +513,19 @@ class GraniteHybridActorCritic(nn.Module):
         seg = segment_ids_from_firsts(firsts)
         x = self._embed(obs)
         mamba = iter(self._unpack_h(h0))
-        carried = []
+        carried, extras = [], []
         for layer in self.layers:
             if layer.kind == "mamba":
                 x, state, tail = layer(x, seg, *next(mamba))
                 carried.append((state, tail))
             else:
-                (x,) = layer(x, seg)
+                x, *more = layer(x, seg)
+                extras.extend(more)
         logits, value = self._heads(x)
-        return logits, value, (self._pack(carried, B), c0)
+        return logits, value, (self._pack(carried, B), c0), extras
+
+    def __call__(self, obs, carry0, firsts):
+        return self._unroll(obs, carry0, firsts)[:3]
 
     unroll = __call__
 
@@ -514,10 +541,12 @@ class GraniteHybridActorCritic(nn.Module):
             if layer.kind == "mamba":
                 x, state, tail = layer.step(x, *next(mamba))
                 carried.append((state, tail))
-            else:
+            elif layer.kind == "attention":
                 x, k, v = layer.step(x, kv[:, attn, 0], kv[:, attn, 1], count)
                 caches.append((k, v))
                 attn += 1
+            else:  # a layer that carries nothing from step to step
+                (x,) = layer.step(x)
         logits, value = self._heads(x)
         c2 = jnp.concatenate(
             [self._pack(caches, B), (count + 1).astype(jnp.float32)[:, None]], axis=1

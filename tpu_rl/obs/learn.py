@@ -99,6 +99,29 @@ def module_grad_norms(grads: Any) -> dict[str, jax.Array]:
     return {k: jnp.sqrt(v) for k, v in sq.items()}
 
 
+def route_scalars(routes: list) -> dict[str, jax.Array]:
+    """Per-update routing counters of a sparse-expert family, from what its
+    expert layers handed back (``ops/moe.route_stats``): routed rows computed
+    (summed over the layers); per layer, averaged: rows of the fullest held
+    expert, of the mean one, their ratio, the share of assignments that fell
+    on held experts and the share of tokens with no held expert. Empty for a
+    family without expert layers."""
+    if not routes:
+        return {}
+    stats = [r["stats"] for r in routes]
+    mean = lambda k: sum(s[k] for s in stats) / len(stats)  # noqa: E731
+    return {
+        "moe-rows": sum(s["rows"] for s in stats),
+        "moe-rows-max": mean("rows-max"),
+        "moe-rows-mean": mean("rows-mean"),
+        "moe-rows-max-over-mean": sum(
+            s["rows-max"] / jnp.maximum(s["rows-mean"], 1e-6) for s in stats
+        ) / len(stats),
+        "moe-held-share": mean("held-share"),
+        "moe-no-held-share": mean("no-held-share"),
+    }
+
+
 def tree_delta_norm(new: Any, old: Any) -> jax.Array:
     """Global norm of ``new - old`` over a param pytree (the applied update's
     magnitude; exactly 0 when a guard skipped the update)."""

@@ -29,12 +29,13 @@ _CACHE_DIR = os.path.join(
 # Pallas TPU kernels lower to this custom-call target; scan / XLA paths never
 # emit it. Kernel and fallback paths are wrapped in the named scopes below
 # (models/cells.py, models/granite_hybrid.py: ``ssd_pallas`` inside
-# ``ssd_scan`` when the scan took its kernels, ops/pallas_act.py,
+# ``ssd_scan`` when the scan took its kernels, ops/moe.py: ``moe_gmm_pallas``
+# inside ``moe_experts`` likewise, ops/pallas_act.py,
 # parallel/sequence.py), so one lowered
 # module answers both "what did the gate choose" and "did Mosaic get it".
 _MOSAIC_TARGET = "tpu_custom_call"
 _PATH_SCOPES = re.compile(
-    r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_full|ssd_scan|ssd_pallas)\b"
+    r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_full|ssd_scan|ssd_pallas|moe_experts|moe_gmm_pallas)\b"
 )
 
 
