@@ -33,6 +33,7 @@ The last line of stdout is ``{"ok": true, "device": {...}}``.
 from __future__ import annotations
 
 import argparse
+import functools
 import glob
 import json
 import math
@@ -399,6 +400,11 @@ def kernel_checks(
     # (rows, experts held, in, out): one projection of nemotron-3-nano-30b-a3b's
     # routed experts, ragged groups of ~768 rows an expert
     gmm_shapes=((6144, 8, 2688, 1856),),
+    # (tokens, choices, experts held, experts, in, out, share of the tokens
+    # that choose among the held experts only): nemotron-3-nano-30b-a3b's
+    # expert block at a quarter of its tokens, once under a fair routing (one
+    # trip of the walk) and once with most assignments held (several)
+    moe_shapes=((4096, 6, 8, 128, 2688, 1856, 0.0), (4096, 6, 8, 128, 2688, 1856, 0.75)),
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -593,6 +599,37 @@ def kernel_checks(
             f"gmm fwd+bwd M{M}/G{G}/K{K}/N{N} bf16",
             gmm_grads((True, interpret), jnp.bfloat16), gmm_grads((False, False), jnp.float32),
             (lhs, rhs), TOL_BF16, TOL_BF16,
+        )
+    # ---- the walk over the held assignments vs every held expert under a mask
+    from tpu_rl.ops import moe
+
+    for n, k, held, total, d, f, held_only in moe_shapes:
+        fair = np.stack([rng.permutation(total)[:k] for _ in range(n)])
+        here = np.stack([rng.permutation(held)[:k] for _ in range(n)])
+        choice = jnp.asarray(np.where(rng.random((n, 1)) < held_only, here, fair), jnp.int32)
+        chunk = moe.chunk_rows(n, k, held, total)
+        trips = int(moe.route_stats(choice, 0, held, chunk)["chunks"])
+        mix = f32(n, d)
+
+        def block(dense, u, weight, w_in, w_out):
+            """The block's output and, for a fixed cotangent, all four gradients."""
+            def weighted(*a):
+                if dense:
+                    y = moe.routed_experts_dense(a[0], choice, *a[1:], 0)
+                else:
+                    y = moe.routed_experts(
+                        a[0], choice, *a[1:], 0, jnp.bfloat16, (True, interpret), chunk)
+                return (y * mix).sum(), y
+            (_, y), grads = jax.value_and_grad(
+                weighted, argnums=(0, 1, 2, 3), has_aux=True)(u, weight, w_in, w_out)
+            return y, grads
+
+        case(
+            f"moe walk fwd+bwd N{n}/k{k}/held{held}of{total}/d{d}/f{f} bf16 "
+            f"(chunk {chunk}, {trips} trip{'s' * (trips != 1)})",
+            functools.partial(block, False), functools.partial(block, True),
+            (f32(n, d), jnp.asarray(rng.random((n, k)) + 0.1, jnp.float32),
+             f32(held, d, f) * d**-0.5, f32(held, f, d) * f**-0.5), TOL_BF16, TOL_BF16,
         )
     return rows
 

@@ -59,9 +59,11 @@ def test_kernel_checks_pass_tiny_interpreted():
         ),
         ssd_shapes=((2, 32, 4, 8, 1, 16, 8), (2, 32, 4, 8, 2, 16, 8)),
         gmm_shapes=((512, 4, 64, 48),),
+        moe_shapes=((300, 3, 4, 16, 64, 48, 0.0), (300, 3, 4, 16, 64, 48, 0.75)),
         interpret=True,
     )
-    assert len(rows) == 9
+    assert len(rows) == 11
+    assert "1 trip)" in rows[-2]["kernel"] and "2 trips)" in rows[-1]["kernel"]
     assert all(r["ok"] for r in rows), rows
 
 

@@ -1,8 +1,10 @@
 """The sparse-expert block's pieces (``tpu_rl/ops/moe.py``) on the CPU: the
 router, the grouped matmul (``ragged_dot`` body against a per-expert loop, the
 Pallas kernel in the interpreter against the body, both operands' gradients),
-and the sort / gather / grouped-product / gather-back dispatch against every
-held expert applied densely under a mask, under forced imbalance too."""
+and the walk over the sorted held assignments in row chunks (gather, grouped
+products, add into the tokens) against every held expert applied densely
+under a mask: under forced imbalance, at every count of trips, with groups
+that straddle a chunk's edge."""
 
 import jax
 import jax.numpy as jnp
@@ -126,95 +128,162 @@ def assignments(kind: str, n: int, first: int):
     return jnp.asarray(choice, jnp.int32), weight
 
 
-@pytest.mark.parametrize("kind", ["random", "all-on-one-held", "all-held", "none-held"])
-@pytest.mark.parametrize("kernel", [(False, False), (True, True)], ids=["ragged_dot", "pallas"])
-def test_the_dispatch_drops_no_token_whatever_the_imbalance(kind, kernel):
-    """Sparse against dense-under-a-mask: output and the gradients of the
-    tokens, the weights and both expert projections. The row buffer holds
-    every assignment, so a fully one-sided routing loses nothing."""
-    n, first = 50, 8
-    u = jnp.asarray(np.random.default_rng(7).standard_normal((n, D)), jnp.float32)
-    w_in, w_out = expert_weights(8)
-    choice, weight = assignments(kind, n, first)
-    mix = jnp.asarray(np.random.default_rng(9).standard_normal((n, D)), jnp.float32)
+def sparse_and_dense(u, choice, weight, w_in, w_out, first, kernel, chunk):
+    """Output and the gradients of the tokens, the weights and both expert
+    projections: the walk's, and the dense form's."""
+    mix = jnp.asarray(np.random.default_rng(9).standard_normal(u.shape), jnp.float32)
 
-    def value_and_grads(f):
-        return jax.jit(jax.value_and_grad(
-            lambda u, wt, a, b: jnp.sum(mix * f(u, choice, wt, a, b, first)),
-            argnums=(0, 1, 2, 3)))(u, weight, w_in, w_out)
+    def out_and_grads(f):
+        def weighted(u, wt, a, b):
+            y = f(u, choice, wt, a, b, first)
+            return jnp.sum(mix * y), y
 
-    got, grads = value_and_grads(
-        lambda *a: moe.routed_experts(*a, kernel=kernel))
-    want, ref_grads = value_and_grads(moe.routed_experts_dense)
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            weighted, argnums=(0, 1, 2, 3), has_aux=True))(u, weight, w_in, w_out)
+        return y, grads
+
+    return (out_and_grads(lambda *a: moe.routed_experts(*a, kernel=kernel, chunk=chunk)),
+            out_and_grads(moe.routed_experts_dense))
+
+
+def assert_sparse_is_dense(choice, weight, first, kernel, chunk):
+    u = jnp.asarray(np.random.default_rng(7).standard_normal((choice.shape[0], D)), jnp.float32)
+    (got, grads), (want, ref_grads) = sparse_and_dense(
+        u, choice, weight, *expert_weights(8), first, kernel, chunk)
     close(got, want, 1e-4)
     for g, r in zip(grads, ref_grads):
         close(g, r, 1e-4)
-    stats = moe.route_stats(choice, first, HELD)
+    return got
+
+
+CHUNK = 256  # one row tile: 400 tokens x 3 choices are up to five trips
+
+
+@pytest.mark.parametrize("kind", ["random", "all-on-one-held", "all-held", "none-held"])
+@pytest.mark.parametrize("kernel", [(False, False), (True, True)], ids=["ragged_dot", "pallas"])
+def test_the_dispatch_drops_no_token_whatever_the_imbalance(kind, kernel):
+    """Sparse against dense-under-a-mask. The walk takes as many trips as the
+    held rows need, so a fully one-sided routing loses nothing (five trips)
+    and one that sends nothing here takes none."""
+    n, first = 400, 8
+    choice, weight = assignments(kind, n, first)
+    got = assert_sparse_is_dense(choice, weight, first, kernel, CHUNK)
+    stats = moe.route_stats(choice, first, HELD, CHUNK)
     expect = {"random": None, "all-on-one-held": n, "all-held": n * K, "none-held": 0}[kind]
     if expect is not None:
         assert float(stats["rows"]) == expect
+        assert float(stats["chunks"]) == -(-expect // CHUNK)  # 2, 5, 0
+    else:
+        assert float(stats["chunks"]) == -(-float(stats["rows"]) // CHUNK) == 2
     if kind == "none-held":
         assert not np.asarray(got).any() and float(stats["no-held-share"]) == 1.0
     if kind == "all-on-one-held":
         assert float(stats["rows-max"]) == n and float(stats["rows-mean"]) == n / HELD
 
 
+def held_rows(counts, n: int, first: int):
+    """A routing of ``n`` tokens whose held expert ``e`` gets ``counts[e]``
+    rows: token ``i``'s first choice is held while ``i`` is under their total
+    (expert 0's tokens first), every other choice absent."""
+    rng = np.random.default_rng(11)
+    others = [e for e in range(TOTAL) if not first <= e < first + HELD]
+    held = np.repeat(np.arange(HELD), counts)
+    choice = np.stack([rng.permutation(others)[:K] for _ in range(n)])
+    choice[: len(held), 0] = first + held
+    return jnp.asarray(choice, jnp.int32), jnp.asarray(rng.random((n, K)) + 0.1, jnp.float32)
+
+
+EDGES = {
+    # held total an exact multiple of the chunk: no partly live last chunk
+    "exact-multiple": ([128, 128, 128, 128], 2),
+    "one-full-chunk": ([64, 64, 64, 64], 1),
+    # expert 1's rows 100..400 lie on both sides of row 256
+    "straddles-an-edge": ([100, 300, 50, 50], 2),
+    # expert 1 alone fills the middle chunk: experts 0, 2, 3 have no row in it
+    "no-rows-in-a-chunk": ([200, 400, 10, 90], 3),
+    "one-expert-many-chunks": ([0, 0, 600, 0], 3),
+    "one-row": ([0, 1, 0, 0], 1),
+}
+
+
+@pytest.mark.parametrize("counts, trips", EDGES.values(), ids=EDGES.keys())
+@pytest.mark.parametrize("kernel", [(False, False), (True, True)], ids=["ragged_dot", "pallas"])
+def test_a_group_may_lie_anywhere_across_the_chunks(counts, trips, kernel):
+    n, first = 700, 8
+    choice, weight = held_rows(counts, n, first)
+    assert_sparse_is_dense(choice, weight, first, kernel, CHUNK)
+    stats = moe.route_stats(choice, first, HELD, CHUNK)
+    assert float(stats["rows"]) == sum(counts) and float(stats["chunks"]) == trips
+    assert float(stats["rows-max"]) == max(counts)
+
+
+def test_a_trip_takes_its_part_of_every_group():
+    """``_trip``: the chunk's assignments in sorted order, each group's rows
+    inside the chunk (they add up to the chunk, or to what is left of the held
+    rows), and the live positions."""
+    sizes = jnp.asarray([200, 400, 10, 90], jnp.int32)
+    order = jnp.arange(1024, dtype=jnp.int32)[::-1]
+    parts = []
+    for c, want in enumerate([[200, 56, 0, 0], [0, 256, 0, 0], [0, 88, 10, 90], [0, 0, 0, 0]]):
+        at, part, live = moe._trip(c, order, sizes, CHUNK)
+        assert np.array_equal(at, order[c * CHUNK:(c + 1) * CHUNK]) and list(map(int, part)) == want
+        assert int(live.sum()) == sum(want) and bool(live[: sum(want)].all())
+        parts.append(part)
+    assert np.array_equal(sum(parts), sizes) and int(moe._trips(sizes, CHUNK)) == 3
+    assert int(moe._trips(jnp.zeros(4, jnp.int32), CHUNK)) == 0
+    assert int(moe._trips(jnp.asarray([256, 256, 0, 0]), CHUNK)) == 2
+
+
+def test_the_chunk_is_twice_the_rows_a_fair_router_sends():
+    # the cell: 16,384 tokens, 6 choices, 8 held of 128 -> 6,144 rows expected
+    assert moe.chunk_rows(16384, 6, 8, 128) == 12288 == 48 * moe.ROW_TILE
+    assert 6 * 16384 // moe.chunk_rows(16384, 6, 8, 128) == 8  # what all-held costs
+    assert moe.chunk_rows(16384, 6, 128, 128) == 6 * 16384  # every expert held: every row
+    assert moe.chunk_rows(40, 3, 4, 16) == moe.ROW_TILE  # never less than a row tile
+    assert moe.chunk_rows(1000, 3, 4, 16) == 1536  # 750 expected, doubled, whole tiles
+
+
 def test_route_stats_count_rows_per_held_expert():
     choice = jnp.asarray([[8, 9, 0], [9, 1, 2], [9, 10, 11], [3, 4, 5]], jnp.int32)
-    stats = {k: float(v) for k, v in moe.route_stats(choice, 8, HELD).items()}
+    stats = {k: float(v) for k, v in moe.route_stats(choice, 8, HELD, 4).items()}
     assert stats == pytest.approx({
-        "rows": 6.0, "rows-max": 3.0, "rows-mean": 1.5, "held-share": 0.5, "no-held-share": 0.25})
-
-
-def test_gather_and_its_inverse_are_each_others_transposes():
-    """``_dispatch``'s backward is a gather by the inverse permutation and a
-    sum over a token's slots: what autodiff's scatter-add computes."""
-    rng = np.random.default_rng(10)
-    n, k = 12, 3
-    u = jnp.asarray(rng.standard_normal((n, D)), jnp.float32)
-    order = jnp.asarray(rng.permutation(n * k), jnp.int32)
-    place = jnp.zeros_like(order).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
-    w = jnp.asarray(rng.standard_normal((n * k, D)), jnp.float32)
-    got = jax.grad(lambda u: jnp.sum(w * moe._dispatch(u, order, place, jnp.ones((n, k), bool))))(u)
-    want = jax.grad(lambda u: jnp.sum(w * u[order // k]))(u)
-    close(got, want)
-    rows = jnp.asarray(rng.standard_normal((n * k, D)), jnp.float32)
-    got = jax.grad(lambda r: jnp.sum(w * moe._collect(r, order, place)))(rows)
-    want = jax.grad(lambda r: jnp.sum(w * r[place]))(rows)
-    close(got, want)
-    close(moe._collect(moe._dispatch(u, order, place, jnp.ones((n, k), bool)), order, place), jnp.repeat(u, k, axis=0))
+        "rows": 6.0, "rows-max": 3.0, "rows-mean": 1.5, "held-share": 0.5, "no-held-share": 0.25,
+        "chunks": 2.0})
 
 
 def test_what_an_unwritten_row_holds_goes_nowhere(monkeypatch):
-    """The kernel never writes the rows past the held total, so they may hold
-    anything. With NaN put there after each product, the block's output and
-    every gradient are what they were: the combine and the dispatch's backward
-    select held assignments, they do not multiply by zero."""
-    n, first = 50, 8
+    """The kernel never writes the rows past the held total, so in the last
+    chunk they may hold anything. With NaN put there after each product and
+    each transposed product, the block's output and every gradient are what
+    they were: the combine and the backward select live rows, they do not
+    multiply by zero."""
+    n, first = 400, 8
     u = jnp.asarray(np.random.default_rng(7).standard_normal((n, D)), jnp.float32)
     w_in, w_out = expert_weights(8)
     choice, weight = assignments("random", n, first)
+    assert float(moe.route_stats(choice, first, HELD, CHUNK)["rows"]) % CHUNK  # a dead tail
 
     def value_and_grads():
         return jax.value_and_grad(
-            lambda u, wt, a, b: jnp.sum(moe.routed_experts(u, choice, wt, a, b, first) ** 2),
+            lambda u, wt, a, b: jnp.sum(
+                moe.routed_experts(u, choice, wt, a, b, first, chunk=CHUNK) ** 2),
             argnums=(0, 1, 2, 3))(u, weight, w_in, w_out)
 
     want, ref_grads = value_and_grads()
-    plain = moe.grouped_matmul
+    product, grads_of = moe.grouped_matmul, moe.grouped_grads
 
-    @jax.custom_vjp
-    def poison(x, live):
-        return jnp.where(live, x, jnp.nan)
-
-    poison.defvjp(lambda x, live: (poison(x, live), live),
-                  lambda live, g: (jnp.where(live, g, jnp.nan), None))
+    def poison(x, sizes):
+        return jnp.where((jnp.arange(x.shape[0]) < jnp.sum(sizes))[:, None], x, jnp.nan)
 
     def poisoned(lhs, rhs, sizes, kernel=None):
-        live = (jnp.arange(lhs.shape[0]) < jnp.sum(sizes))[:, None]
-        return poison(plain(lhs, rhs, sizes, kernel), live)
+        return poison(product(lhs, rhs, sizes, kernel), sizes)
+
+    def poisoned_grads(lhs, rhs, sizes, g, acc, kernel=None):
+        d_lhs, acc = grads_of(lhs, rhs, sizes, g, acc, kernel)
+        return poison(d_lhs, sizes), acc
 
     monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+    monkeypatch.setattr(moe, "grouped_grads", poisoned_grads)
     got, grads = value_and_grads()
     close(got, want)
     for g, r in zip(grads, ref_grads):
@@ -222,15 +291,27 @@ def test_what_an_unwritten_row_holds_goes_nowhere(monkeypatch):
         close(g, r)
 
 
+def test_the_walk_traces_no_conditional():
+    """The trip count is a traced bound of one loop: the program holds a
+    ``while`` and no ``cond`` (a reader of the device trace counts every
+    conditional as the optimizer's)."""
+    choice, weight = assignments("random", 400, 8)
+    u = jnp.zeros((400, D))
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda u, a, b: jnp.sum(moe.routed_experts(u, choice, weight, a, b, 8, chunk=CHUNK)),
+        argnums=(0, 1, 2)))(u, *expert_weights(8)))
+    assert text.count("while[") == 2 and "cond[" not in text.replace("cond_jaxpr", "")
+
+
 def test_the_gate_keeps_the_body_off_the_chip(monkeypatch):
     from tpu_rl.models import cells
 
-    assert moe._gmm_gate(98304, 2688, 1856) == (False, False)  # a CPU
+    assert moe._gmm_gate(12288, 2688, 1856) == (False, False)  # a CPU
     monkeypatch.setattr(cells, "_PALLAS_MODE", "interpret")
-    assert moe._gmm_gate(98304, 2688, 1856) == (True, True)
+    assert moe._gmm_gate(12288, 2688, 1856) == (True, True)
     monkeypatch.setattr(cells, "_PALLAS_MODE", "off")
-    assert moe._gmm_gate(98304, 2688, 1856) == (False, False)
-    # the published widths tile: both projections, and their transposes
-    assert moe._gmm_tiles(98304, 2688, 1856) == (256, 896, 512)
-    assert moe._gmm_tiles(98304, 1856, 2688) == (256, 1856, 512)
+    assert moe._gmm_gate(12288, 2688, 1856) == (False, False)
+    # the published widths tile at the cell's chunk: both projections, and their transposes
+    assert moe._gmm_tiles(12288, 2688, 1856) == (256, 896, 512)
+    assert moe._gmm_tiles(12288, 1856, 2688) == (256, 1856, 512)
     assert moe._gmm_tiles(100, 2688, 1856) is None and moe._gmm_tiles(256, 100, 128) is None

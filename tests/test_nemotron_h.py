@@ -174,6 +174,7 @@ def test_ppo_loss_and_every_gradient_match_the_reference(family, actor, system, 
         float(r["stats"]["rows"]) for r in system(actor, batch)[2])
     assert 1.0 <= float(scalars["moe-rows-max-over-mean"]) <= 4.0
     assert 0.0 < float(scalars["moe-held-share"]) < 1.0 > float(scalars["moe-no-held-share"])
+    assert float(scalars["moe-chunks"]) == 1.0  # every layer's held rows fit one chunk
 
     def sys_loss(p):
         from tpu_rl.algos.ppo import td_target_and_gae
@@ -368,6 +369,7 @@ def test_each_on_policy_algorithm_runs_one_update(algo, monkeypatch):
     state, metrics = jax.jit(step)(state, Batch.from_mapping(make_batch(13)), jax.random.key(1))
     assert np.isfinite(float(metrics["loss"])) and float(metrics["nonfinite-updates"]) == 0
     assert float(metrics["diag"]["scalars"]["moe-rows"]) > 0
+    assert float(metrics["diag"]["scalars"]["moe-chunks"]) == 1.0
     moved = jax.tree_util.tree_map_with_path(
         lambda path, a, b: (jax.tree_util.keystr(path), float(np.abs(a - np.asarray(b)).max())),
         before, state.params["actor"])
@@ -377,26 +379,68 @@ def test_each_on_policy_algorithm_runs_one_update(algo, monkeypatch):
 
 
 def test_the_diagnostics_publish_the_routing_counters():
-    """``diag["scalars"]`` through the accumulator and ``derive``: the
-    per-update means that ``learn.jsonl`` and the ``learner-diag-moe-*``
-    gauges carry."""
+    """``diag["scalars"]`` through the accumulator, ``derive``, ``publish`` and
+    ``learn_record``: the per-update means that ``learn.jsonl`` and the
+    ``learner-diag-moe-*`` gauges carry, the walk's trips among them."""
     from tpu_rl.obs import learn
+    from tpu_rl.obs.registry import MetricsRegistry
 
     routes = [{"stats": {"rows": jnp.float32(60), "rows-max": jnp.float32(30),
                          "rows-mean": jnp.float32(15), "held-share": jnp.float32(0.25),
-                         "no-held-share": jnp.float32(0.5)}},
+                         "no-held-share": jnp.float32(0.5), "chunks": jnp.float32(2)}},
               {"stats": {"rows": jnp.float32(40), "rows-max": jnp.float32(10),
                          "rows-mean": jnp.float32(10), "held-share": jnp.float32(0.15),
-                         "no-held-share": jnp.float32(0.7)}}]
-    assert learn.route_scalars([]) == {}
+                         "no-held-share": jnp.float32(0.7), "chunks": jnp.float32(1)}}]
+    assert learn.route_scalars([]) == {}  # a family without expert layers: no key at all
+    assert float(learn.route_scalars(routes)["moe-chunks"]) == 1.5  # the mean per expert layer
     diag = {"rows": {"ent": jnp.ones((2,))}, "scalars": learn.route_scalars(routes)}
     acc = learn.DiagAccumulator()
     acc.add(diag, jnp.zeros((2,)))
     acc.add(diag, jnp.zeros((2,)))
-    doc = acc.drain(4)["global"]
-    assert doc["moe-rows"] == 100.0 and doc["moe-rows-max-over-mean"] == pytest.approx(1.5)
-    assert doc["moe-held-share"] == pytest.approx(0.2) and doc["moe-no-held-share"] == pytest.approx(0.6)
-    assert learn.learn_record(4, {"n_updates": 2, "global": doc, "buckets": {}})["moe-rows"] == 100.0
+    doc = acc.drain(4)
+    glob = doc["global"]
+    assert glob["moe-rows"] == 100.0 and glob["moe-rows-max-over-mean"] == pytest.approx(1.5)
+    assert glob["moe-held-share"] == pytest.approx(0.2) and glob["moe-no-held-share"] == pytest.approx(0.6)
+    assert glob["moe-chunks"] == pytest.approx(1.5)
+    record = learn.learn_record(4, {"n_updates": 2, "global": glob, "buckets": {}})
+    assert record["moe-rows"] == 100.0 and record["moe-chunks"] == pytest.approx(1.5)
+    reg = MetricsRegistry(role="learner", pid=0, host="h")
+    learn.publish(reg, doc)
+    gauges = {name: value for name, _, value in reg.snapshot()["gauges"]}
+    assert gauges["learner-diag-moe-chunks"] == pytest.approx(1.5)
+    assert gauges["learner-diag-moe-rows"] == 100.0
+
+
+@pytest.mark.parametrize("model", ["nemotron_h", "lstm"])
+def test_the_trips_are_in_the_diagnostics_of_a_family_with_expert_layers_only(model, monkeypatch):
+    """One update's ``diag["scalars"]`` and the ``learn.jsonl`` record made of
+    it: ``moe-chunks`` beside ``moe-rows`` where the family routes, neither
+    where it does not."""
+    from tpu_rl.obs import learn
+
+    if model == "nemotron_h":
+        cfg = config(learn_diag=True, arch={**ARCH, "hybrid_override_pattern": "ME"})
+        eager = ModelFamily.init_params
+        monkeypatch.setattr(ModelFamily, "init_params", lambda self, key, seq_len=2: jax.jit(
+            lambda k: eager(self, k, seq_len))(key))
+    else:
+        cfg = Config.from_dict(dict(
+            algo="PPO", obs_shape=(OBS,), action_space=ACTIONS, batch_size=B, seq_len=T,
+            hidden_size=16, learn_diag=True))
+    _, state, step = get_algo("PPO").build(cfg, jax.random.key(0))
+    lay = BatchLayout.from_config(cfg)
+    batch = make_batch(15)
+    batch["hx"] = np.zeros((B, T, lay.hx), np.float32)
+    batch["cx"] = np.zeros((B, T, lay.cx), np.float32)
+    _, metrics = jax.jit(step)(state, Batch.from_mapping(batch), jax.random.key(1))
+    acc = learn.DiagAccumulator()
+    acc.add(metrics["diag"], jnp.zeros((B,)))
+    record = learn.learn_record(1, acc.drain(1))
+    routed = model == "nemotron_h"
+    assert ("moe-chunks" in metrics["diag"]["scalars"]) == routed
+    assert ("moe-chunks" in record) == ("moe-rows" in record) == routed
+    if routed:
+        assert record["moe-chunks"] == 1.0 and record["moe-rows"] > 0
 
 
 def test_what_the_family_refuses():

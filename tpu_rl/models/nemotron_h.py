@@ -23,7 +23,11 @@ rank of: ``published_n_routed_experts`` experts over ``chips`` ranks, this one
 ``rank``; ``n_routed_experts`` is what one rank holds. The block adds the
 shared expert's output and the held experts' part of the routed sum; the
 absent experts' part is left out (no exchange, nothing in its place). Without
-the key every expert is held.
+the key every expert is held. In training the held assignments, sorted by
+expert, are walked in chunks of ``moe.chunk_rows(tokens, top_k, held, experts)``
+rows — twice what a fair router sends this rank — as often as the routing's
+own counts say: once most updates for a rank that holds a sixteenth, and as
+often as it takes, dropping nothing, when more arrives.
 
 ``unroll_routed`` also returns, per expert layer, the experts each step chose
 and the routing counters (``ops/moe.route_stats``): the learner's diagnostics
@@ -126,11 +130,12 @@ class ExpertBlock(nn.Module):
         routing: the chosen experts (B, T, top_k) and the counters."""
         rows = u.reshape(-1, self.hidden)
         choice, weight = self._route(rows)
+        chunk = moe.chunk_rows(rows.shape[0], self.top_k, self.held, self.n_experts)
         routed = moe.routed_experts(
-            rows, choice, weight, self.w_in, self.w_out, self.first, self.dtype)
+            rows, choice, weight, self.w_in, self.w_out, self.first, self.dtype, chunk=chunk)
         route = {
             "choice": choice.reshape(*u.shape[:-1], self.top_k),
-            "stats": moe.route_stats(choice, self.first, self.held),
+            "stats": moe.route_stats(choice, self.first, self.held, chunk),
         }
         return self._shared(u) + routed.reshape(u.shape), route
 

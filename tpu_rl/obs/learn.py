@@ -104,8 +104,9 @@ def route_scalars(routes: list) -> dict[str, jax.Array]:
     expert layers handed back (``ops/moe.route_stats``): routed rows computed
     (summed over the layers); per layer, averaged: rows of the fullest held
     expert, of the mean one, their ratio, the share of assignments that fell
-    on held experts and the share of tokens with no held expert. Empty for a
-    family without expert layers."""
+    on held experts, the share of tokens with no held expert and the trips of
+    the block's walk over its held rows (``moe-chunks``: 1.0 when every layer
+    took one). Empty for a family without expert layers."""
     if not routes:
         return {}
     stats = [r["stats"] for r in routes]
@@ -119,6 +120,7 @@ def route_scalars(routes: list) -> dict[str, jax.Array]:
         ) / len(stats),
         "moe-held-share": mean("held-share"),
         "moe-no-held-share": mean("no-held-share"),
+        "moe-chunks": mean("chunks"),
     }
 
 

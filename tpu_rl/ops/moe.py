@@ -18,14 +18,26 @@ chosen s + 1e-20)``. The discrete choice carries no gradient, ``s`` does.
 
 Dispatch (``routed_experts``), with static shapes and without dropping a token
 whatever the imbalance: the ``tokens x top_k`` assignments are sorted by held
-expert (those on absent experts last), the tokens' rows gathered in that order
-into a buffer sized for what can arrive — every assignment —, one grouped
-matmul per projection runs over the ragged groups, and the rows go back to
-their tokens' slots, weighted. Rows past the held total are never computed
-and never read: the grouped matmul's grid ends with the last held row, and the
-combine and the dispatch's backward select held assignments only. Gather and
-its inverse are each other's transposes (``_dispatch`` / ``_collect``), so the
-backward pass gathers too and never scatter-adds.
+expert (those on absent experts last), and the block **walks the held part of
+that order in chunks of ``C`` rows, ``ceil(held rows / C)`` times** — a trip
+count the device reads from the routing's own counts (a ``fori_loop`` with a
+traced bound: no conditional, nothing compiled per count). A trip gathers its
+``C`` assignments' token rows, runs one grouped matmul per projection over the
+chunk's part of each group (a group's rows stay contiguous inside a chunk, and
+a group may straddle an edge), and adds the weighted rows into the ``(N, d)``
+float32 result at their tokens. Every buffer, gather, cast and activation is
+``C`` rows tall; ``C`` (``chunk_rows``) is a ``ROW_TILE`` multiple near twice
+the rows expected from the static shapes, so a rank that holds a sixteenth of
+the experts takes one trip most updates and routing that puts every assignment
+here takes ``N k / C``. Rows past the held total inside the last chunk are
+never computed and never read: the grouped matmul's grid ends with the last
+held row, and the combine and the backward **select** live rows. The walk is
+one ``custom_vjp`` (``_walk``) that keeps its inputs only: the backward is the
+same walk — gather the tokens' rows and the result's cotangent, recompute the
+hidden rows, the two transposed products per projection, add into the tokens'
+gradient — with the weights' gradients accumulated across trips in float32
+(inside ``tgmm`` where the kernel runs). ``route_stats`` counts the trips
+(``chunks``).
 
 The grouped matmul (``grouped_matmul``): on a TPU, at widths whose tiles the
 kernel takes, ``pallas.ops.tpu.megablox`` (scope ``moe_gmm_pallas``), with the
@@ -69,11 +81,20 @@ def route(u, kernel, bias, top_k: int, scale: float):
     return choice.astype(jnp.int32), scale * chosen
 
 
-def route_stats(choice, first: int, held: int) -> dict:
+def chunk_rows(n: int, k: int, held: int, n_experts: int) -> int:
+    """Rows a trip of ``routed_experts``'s walk takes (``C``), from static
+    shapes alone: the ``ROW_TILE`` multiple next above twice the rows a fair
+    router sends here (``n k held / n_experts``), and never more than all
+    ``n k`` assignments."""
+    tiles = -(-2 * n * k * held // (n_experts * ROW_TILE))
+    return ROW_TILE * max(1, min(tiles, -(-n * k // ROW_TILE)))
+
+
+def route_stats(choice, first: int, held: int, chunk: int) -> dict:
     """Counters of one block's routing, as float32 scalars (in-jit, no
     gradient): rows computed, rows of the fullest held expert and of the mean
     one, the share of assignments on held experts, the share of tokens with
-    none."""
+    none, and the trips the walk takes at ``chunk`` rows each."""
     local = choice - first
     mine = (local >= 0) & (local < held)
     counts = jnp.sum(
@@ -86,6 +107,7 @@ def route_stats(choice, first: int, held: int) -> dict:
         "rows-mean": rows / held,
         "held-share": rows / choice.size,
         "no-held-share": jnp.mean(1.0 - jnp.any(mine, axis=-1).astype(jnp.float32)),
+        "chunks": jnp.ceil(rows / chunk),
     }
 
 
@@ -141,8 +163,10 @@ def _gmm_pallas_fwd(lhs, rhs, sizes, interpret):
     return _gmm_pallas(lhs, rhs, sizes, interpret), (lhs, rhs, sizes)
 
 
-def _gmm_pallas_bwd(interpret, residual, g):
-    lhs, rhs, sizes = residual
+def _gmm_grads(lhs, rhs, sizes, g, interpret: bool, acc=None):
+    """Both gradients of ``_gmm_pallas(lhs, rhs, sizes)`` for the cotangent
+    ``g``: ``lhs``'s (rows past the groups' total unwritten), and ``rhs``'s —
+    added to ``acc`` (G, k, n) float32 inside the kernel where one is given."""
     m, k = lhs.shape
     n = rhs.shape[2]
     g = g.astype(lhs.dtype)
@@ -151,9 +175,15 @@ def _gmm_pallas_bwd(interpret, residual, g):
     )
     tm, _, tn = _tiles(m, k, n)
     d_rhs = _megablox_tgmm(
-        lhs.swapaxes(0, 1), g, sizes, rhs.dtype, (tm, min(k, 512), tn), interpret=interpret,
+        lhs.swapaxes(0, 1), g, sizes, rhs.dtype if acc is None else acc.dtype,
+        (tm, min(k, 512), tn), existing_out=acc, interpret=interpret,
     )
-    return d_lhs, d_rhs, None
+    return d_lhs, d_rhs
+
+
+def _gmm_pallas_bwd(interpret, residual, g):
+    lhs, rhs, sizes = residual
+    return (*_gmm_grads(lhs, rhs, sizes, g, interpret), None)
 
 
 _gmm_pallas.defvjp(_gmm_pallas_fwd, _gmm_pallas_bwd)
@@ -174,85 +204,146 @@ def grouped_matmul(lhs, rhs, sizes, kernel: tuple[bool, bool] | None = None):
         return _gmm_pallas(lhs, rhs, sizes, interpret)
 
 
-# ------------------------------------------------------- dispatch and combine
-@jax.custom_vjp
-def _dispatch(u, order, place, mine):
-    """Rows of ``u`` (N, d) in the order the experts take them: (N k, d).
-    ``order`` is a permutation of the N k assignments (assignment ``a`` is
-    token ``a // k``, slot ``a % k``), ``place`` its inverse, ``mine`` (N, k)
-    the assignments on held experts: only their rows' gradients are read."""
-    return u[order // (order.shape[0] // u.shape[0])]
+def grouped_grads(lhs, rhs, sizes, g, acc, kernel: tuple[bool, bool] | None = None):
+    """What ``grouped_matmul(lhs, rhs, sizes)``'s transpose gives for ``g``:
+    ``lhs``'s gradient (rows past the groups' total as the product leaves
+    them) and ``acc`` (G, k, n) float32 plus ``rhs``'s."""
+    m, k = lhs.shape
+    use, interpret = kernel or _gmm_gate(m, k, rhs.shape[2])
+    if not use:
+        _, transpose = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes), lhs, rhs)
+        d_lhs, d_rhs = transpose(g.astype(lhs.dtype))
+        return d_lhs, acc + d_rhs
+    with jax.named_scope("moe_gmm_pallas"):
+        return _gmm_grads(lhs, rhs, sizes, g, interpret, acc)
 
 
-def _dispatch_fwd(u, order, place, mine):
-    return _dispatch(u, order, place, mine), (place, mine)
-
-
-def _dispatch_bwd(residual, g):
-    place, mine = residual
-    slots = g[place].reshape(*mine.shape, g.shape[-1])
-    return jnp.where(mine[..., None], slots, 0).sum(axis=1), None, None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _collect(rows, order, place):
-    """The inverse permutation: rows (N k, d) in the experts' order back to
-    assignment order (token-major, slot-minor)."""
-    return rows[place]
-
-
-def _collect_fwd(rows, order, place):
-    return rows[place], order
-
-
-def _collect_bwd(order, g):
-    return g[order], None, None
-
-
-_collect.defvjp(_collect_fwd, _collect_bwd)
-
-
+# ------------------------------------------------------------------- the walk
 def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def routed_experts(u, choice, weight, w_in, w_out, first: int, dtype=None, kernel=None):
+@jax.named_scope("moe_dispatch")
+def _trip(c, order, sizes, chunk: int):
+    """Trip ``c`` of the walk over the sorted assignments: the assignments at
+    sorted positions ``[c chunk, (c + 1) chunk)``, each group's rows among
+    them, and which of the positions hold a held assignment."""
+    lo = c * chunk
+    ends = jnp.cumsum(sizes)
+    part = jnp.clip(ends, lo, lo + chunk) - jnp.clip(ends - sizes, lo, lo + chunk)
+    live = lo + jnp.arange(chunk, dtype=jnp.int32) < ends[-1]
+    return jax.lax.dynamic_slice(order, (lo,), (chunk,)), part, live
+
+
+def _trips(sizes, chunk: int):
+    return (jnp.sum(sizes) + chunk - 1) // chunk
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _walk(u, weight, w_in, w_out, order, sizes, chunk: int, kernel):
+    """``u`` (N, d), ``weight`` (N, k) float32, ``w_in`` (H, d, f) and
+    ``w_out`` (H, f, d) in the operands' dtype; ``order`` the assignments
+    (token ``a // k``, slot ``a % k``) sorted by held expert, padded to whole
+    chunks; ``sizes`` (H,) the held experts' rows. (N, d) float32."""
+    n, k = weight.shape
+    x, flat = u.astype(w_in.dtype), weight.reshape(-1)
+
+    def trip(c, y):
+        at, part, live = _trip(c, order, sizes, chunk)
+        with jax.named_scope("moe_dispatch"):
+            tok = at // k
+            rows = x[tok]
+        with jax.named_scope("moe_experts"):
+            hidden = _relu2(grouped_matmul(rows, w_in, part, kernel))
+            out = grouped_matmul(hidden.astype(x.dtype), w_out, part, kernel)
+        with jax.named_scope("moe_combine"):
+            # select, not a product with a zero weight: a row past the held
+            # total is unwritten and may hold anything
+            add = jnp.where(live[:, None], flat[at][:, None] * out.astype(jnp.float32), 0.0)
+            return y.at[jnp.where(live, tok, n)].add(add, mode="drop")
+
+    return jax.lax.fori_loop(
+        0, _trips(sizes, chunk), trip, jnp.zeros((n, u.shape[1]), jnp.float32))
+
+
+def _walk_fwd(u, weight, w_in, w_out, order, sizes, chunk, kernel):
+    return _walk(u, weight, w_in, w_out, order, sizes, chunk, kernel), (
+        u, weight, w_in, w_out, order, sizes)
+
+
+def _walk_bwd(chunk, kernel, residual, dy):
+    """The same walk: with ``h = relu(x W_in)^2`` recomputed per trip and
+    ``t = dy W_out^T`` at the chunk's tokens, ``d weight = <h, t>``,
+    ``d W_out += (weight h)^T dy``, ``d W_in += x^T p`` and ``d u += p W_in^T``
+    for ``p = weight t 2 relu(x W_in)``."""
+    u, weight, w_in, w_out, order, sizes = residual
+    n, k = weight.shape
+    x, g, flat = u.astype(w_in.dtype), dy.astype(w_in.dtype), weight.reshape(-1)
+
+    def trip(c, carry):
+        d_x, d_flat, d_in, d_out = carry
+        at, part, live = _trip(c, order, sizes, chunk)
+        with jax.named_scope("moe_dispatch"):
+            tok = at // k
+            rows = x[tok]
+        with jax.named_scope("moe_combine"):
+            dy_rows, wt = g[tok], flat[at][:, None]
+        with jax.named_scope("moe_experts"):
+            pre = grouped_matmul(rows, w_in, part, kernel)
+            hidden = _relu2(pre)
+            t, d_out = grouped_grads(
+                (wt * hidden).astype(x.dtype), w_out, part, dy_rows, d_out, kernel)
+            t = t.astype(jnp.float32)
+            p = (wt * t * 2.0 * jax.nn.relu(pre)).astype(x.dtype)
+            d_rows, d_in = grouped_grads(rows, w_in, part, p, d_in, kernel)
+        with jax.named_scope("moe_combine"):
+            d_wt = jnp.sum(hidden.astype(jnp.float32) * t, axis=-1)
+            # distinct indices all: a dead row's lies past the end and is dropped
+            d_flat = d_flat.at[jnp.where(live, at, n * k + jnp.arange(chunk))].set(
+                d_wt, mode="drop", unique_indices=True)
+        with jax.named_scope("moe_dispatch"):
+            d_x = d_x.at[jnp.where(live, tok, n)].add(
+                jnp.where(live[:, None], d_rows.astype(jnp.float32), 0.0), mode="drop")
+        return d_x, d_flat, d_in, d_out
+
+    d_x, d_flat, d_in, d_out = jax.lax.fori_loop(0, _trips(sizes, chunk), trip, (
+        jnp.zeros(u.shape, jnp.float32), jnp.zeros(n * k, jnp.float32),
+        jnp.zeros(w_in.shape, jnp.float32), jnp.zeros(w_out.shape, jnp.float32)))
+    # the barrier ties the weights' gradients, cast to the operands' dtype as
+    # one product's would be, to the tokens': without it the casts are fused
+    # into the optimizer's pass and every layer's float32 accumulators live
+    # through the whole backward
+    with jax.named_scope("moe_experts"):
+        d_in, d_out = d_in.astype(w_in.dtype), d_out.astype(w_out.dtype)
+    d_x, d_in, d_out = jax.lax.optimization_barrier((d_x.astype(u.dtype), d_in, d_out))
+    return d_x, d_flat.reshape(n, k).astype(weight.dtype), d_in, d_out, None, None
+
+
+_walk.defvjp(_walk_fwd, _walk_bwd)
+
+
+def routed_experts(u, choice, weight, w_in, w_out, first: int, dtype=None, kernel=None,
+                   chunk: int | None = None):
     """The held experts' part of the block's output for ``u`` (N, d):
     ``sum over chosen and held e of weight_e * relu(u W_in[e])^2 W_out[e]``,
     float32. ``choice`` (N, k) global expert ids, ``weight`` (N, k);
     ``w_in`` (H, d, f), ``w_out`` (H, f, d): experts ``first .. first + H``.
-    Matmul operands in ``dtype``."""
+    Matmul operands in ``dtype``. ``chunk``: the rows a trip of the walk takes
+    (``chunk_rows``, a ``ROW_TILE`` multiple); every assignment where the
+    caller does not know the router's width."""
     n, k = choice.shape
     held = w_in.shape[0]
     cd = dtype or jnp.float32
+    chunk = chunk or chunk_rows(n, k, held, held)
     with jax.named_scope("moe_dispatch"):
         local = (choice - first).reshape(-1)
-        mine = (local >= 0) & (local < held)
-        key = jnp.where(mine, local, held)  # absent experts' assignments last
+        key = jnp.where((local >= 0) & (local < held), local, held)  # absent experts' last
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        place = jnp.zeros_like(order).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
         sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0).astype(jnp.int32)
-        pad = (-n * k) % ROW_TILE  # whole row tiles; never inside a group
-        mine = mine.reshape(n, k)
-        rows = _dispatch(u.astype(cd), order, place, mine)
-        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        order = jnp.pad(order, (0, (-n * k) % chunk))  # whole chunks; the pad is never live
     with jax.named_scope("moe_experts"):
-        # rows past the held total are never computed and never read: the
-        # combine below and the dispatch's backward select held assignments
-        hidden = _relu2(grouped_matmul(rows, w_in.astype(cd), sizes, kernel))
-        out = grouped_matmul(hidden.astype(cd), w_out.astype(cd), sizes, kernel)
-    with jax.named_scope("moe_combine"):
-        slots = _collect(out[: n * k], order, place).reshape(n, k, -1)
-        # select, not a product with a zero weight: an unwritten row may hold
-        # anything, and a product's gradient would multiply it by zero
-        kept = jnp.where(mine[..., None], slots.astype(jnp.float32), 0.0)
-        return jnp.einsum(
-            "nk,nkd->nd", jnp.where(mine, weight, 0.0), kept,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        w_in, w_out = w_in.astype(cd), w_out.astype(cd)
+    return _walk(u, weight.astype(jnp.float32), w_in, w_out, order, sizes, chunk, kernel)
 
 
 def routed_experts_dense(u, choice, weight, w_in, w_out, first: int, dtype=None):
