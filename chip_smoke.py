@@ -385,12 +385,15 @@ TOL_BF16 = 3e-2
 def kernel_checks(
     lstm_shapes=((128, 5, 64, "auto"), (256, 16, 256, "auto"), (1024, 16, 1024, "force")),
     act_shapes=((8, 4, 64, 2), (256, 4, 64, 2), (8, 64, 1024, 8), (256, 64, 1024, 8)),
-    # (B, T, heads, key/value heads, head dim, softmax scale, with gradients):
-    # tf-longctx's layer, a forward-only row, granite-4.0-h-micro's layer
+    # (B, T, heads, key/value heads, head dim, softmax scale, with gradients,
+    # sliding window): tf-longctx's layer, a forward-only row,
+    # granite-4.0-h-micro's layer, smallthinker-21b-a3b's window layer (one of
+    # its two rows: the reference's scores are 1.9 GB a block of 1,024 queries)
     flash_shapes=(
-        (16, 2048, 8, 8, 64, None, True),
-        (1, 512, 8, 8, 64, None, False),
-        (2, 4096, 32, 8, 64, 1.0 / 64, True),
+        (16, 2048, 8, 8, 64, None, True, None),
+        (1, 512, 8, 8, 64, None, False, None),
+        (2, 4096, 32, 8, 64, 1.0 / 64, True, None),
+        (1, 16384, 28, 4, 128, 128**-0.5, True, 4096),
     ),
     # (B, T, heads, head dim, groups, state, chunk): granite-4.0-h-micro's
     # scan, then nemotron-3-nano-30b-a3b's (8 B/C groups, chunks of 128; two of
@@ -401,10 +404,19 @@ def kernel_checks(
     # routed experts, ragged groups of ~768 rows an expert
     gmm_shapes=((6144, 8, 2688, 1856),),
     # (tokens, choices, experts held, experts, in, out, share of the tokens
-    # that choose among the held experts only): nemotron-3-nano-30b-a3b's
+    # that choose among the held experts only, gated): nemotron-3-nano-30b-a3b's
     # expert block at a quarter of its tokens, once under a fair routing (one
-    # trip of the walk) and once with most assignments held (several)
-    moe_shapes=((4096, 6, 8, 128, 2688, 1856, 0.0), (4096, 6, 8, 128, 2688, 1856, 0.75)),
+    # trip of the walk) and once with most assignments held (several); then
+    # smallthinker-21b-a3b's gated block at an eighth of its tokens, likewise,
+    # and the walk its cell times: a layer's 49,152 held rows (a quarter of the
+    # tokens, every assignment held) in the cell's own chunks of 4,096 rows,
+    # twelve trips forward and backward. A ninth entry is the chunk where it
+    # is not ``moe.chunk_rows``'s for the row's own shapes.
+    moe_shapes=(
+        (4096, 6, 8, 128, 2688, 1856, 0.0, False), (4096, 6, 8, 128, 2688, 1856, 0.75, False),
+        (4096, 6, 16, 64, 2560, 768, 0.0, True), (4096, 6, 16, 64, 2560, 768, 0.75, True),
+        (8192, 6, 16, 64, 2560, 768, 1.0, True, 4096),
+    ),
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -503,12 +515,13 @@ def kernel_checks(
 
     # ---- the library's splash kernel with the dispatch's tiles vs full attention
     from tpu_rl.parallel.sequence import (
+        _masked_block_scores,
         _splash_block_sizes,
         flash_attention_tpu,
         full_attention,
     )
 
-    for B, T, NH, NKV, D, sm_scale, grad in flash_shapes:
+    for B, T, NH, NKV, D, sm_scale, grad, window in flash_shapes:
         q = f32(B, T, NH, D).astype(jnp.bfloat16)
         k, v = (f32(B, T, NKV, D).astype(jnp.bfloat16) for _ in range(2))
         firsts = np.zeros((B, T), np.int32)
@@ -521,13 +534,28 @@ def kernel_checks(
 
         def flash(q, k, v, n=B):
             return flash_attention_tpu(
-                q[:n], k[:n], v[:n], pos[:n], seg[:n], causal=True, sm_scale=sm_scale
+                q[:n], k[:n], v[:n], pos[:n], seg[:n], causal=True, sm_scale=sm_scale,
+                window=window,
             )
 
         def full(q, k, v, n=B):  # f32 reference on the same bf16 inputs
             q, k, v = (x[:n].astype(jnp.float32) for x in (q, k, v))
             k, v = (jnp.repeat(x, NH // NKV, axis=2) for x in (k, v))
-            return full_attention(q, k, v, pos[:n], seg[:n], causal=True, sm_scale=sm_scale)
+            if T <= 4096:
+                return full_attention(
+                    q, k, v, pos[:n], seg[:n], causal=True, sm_scale=sm_scale, window=window)
+
+            # (n, H, T, T) scores do not fit: full_attention's own masked
+            # scores, 1,024 queries at a time against every key
+            @jax.checkpoint
+            def queries(at):
+                cut = lambda x: jax.lax.dynamic_slice_in_dim(x[:n], at, 1024, axis=1)  # noqa: E731
+                scores = _masked_block_scores(
+                    cut(q), k, cut(pos), pos[:n], cut(seg), seg[:n], sm_scale, True, window)
+                return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
+
+            out = jax.lax.map(queries, jnp.arange(0, T, 1024))  # (T / 1024, n, 1024, H, D)
+            return out.transpose(1, 0, 2, 3, 4).reshape(n, T, NH, D)
 
         def grads(impl, n):
             def loss(q, k, v):
@@ -541,7 +569,8 @@ def kernel_checks(
         got_fn, ref_fn = (grads(flash, B), grads(full, n_ref)) if grad else (flash, full)
         bs = _splash_block_sizes(T)
         case(
-            f"flash {'fwd+bwd' if grad else 'fwd'} B{B}/T{T}/H{NH}:{NKV}/D{D} bf16 "
+            f"flash {'fwd+bwd' if grad else 'fwd'} B{B}/T{T}/H{NH}:{NKV}/D{D}"
+            f"{f'/window{window}' if window else ''} bf16 "
             f"(tiles {bs and (bs.block_q, bs.block_kv, bs.block_q_dkv, bs.block_kv_dkv)})",
             got_fn, ref_fn, (q, k, v), TOL_BF16, TOL_BF16,
             # off-TPU the dispatch substitutes full attention by design
@@ -603,33 +632,44 @@ def kernel_checks(
     # ---- the walk over the held assignments vs every held expert under a mask
     from tpu_rl.ops import moe
 
-    for n, k, held, total, d, f, held_only in moe_shapes:
+    for n, k, held, total, d, f, held_only, gated, *chunk in moe_shapes:
         fair = np.stack([rng.permutation(total)[:k] for _ in range(n)])
         here = np.stack([rng.permutation(held)[:k] for _ in range(n)])
         choice = jnp.asarray(np.where(rng.random((n, 1)) < held_only, here, fair), jnp.int32)
-        chunk = moe.chunk_rows(n, k, held, total)
+        chunk = chunk[0] if chunk else moe.chunk_rows(n, k, held, total, d)
         trips = int(moe.route_stats(choice, 0, held, chunk)["chunks"])
         mix = f32(n, d)
+        # The gate's relu has a kink: where rounding an operand to bf16 moves a
+        # pre-activation across zero, that element's gradient takes the other
+        # branch, an O(1) change no tolerance holds (a fifth of the largest
+        # gradient at 300 rows). The gated rows hand both sides operands that
+        # bf16 holds exactly, so what is compared is the walk's arithmetic.
+        as_run = (lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)) if gated else (lambda x: x)
 
-        def block(dense, u, weight, w_in, w_out):
-            """The block's output and, for a fixed cotangent, all four gradients."""
-            def weighted(*a):
+        def block(dense, u, weight, w_in, w_out, *w_gate):
+            """The block's output and, for a fixed cotangent, every gradient
+            (four; five with the gated form's third leaf)."""
+            def weighted(u, weight, w_in, w_out, *w_gate):
+                gate = dict(w_gate=w_gate[0]) if w_gate else {}
                 if dense:
-                    y = moe.routed_experts_dense(a[0], choice, *a[1:], 0)
+                    y = moe.routed_experts_dense(u, choice, weight, w_in, w_out, 0, **gate)
                 else:
                     y = moe.routed_experts(
-                        a[0], choice, *a[1:], 0, jnp.bfloat16, (True, interpret), chunk)
+                        u, choice, weight, w_in, w_out, 0, jnp.bfloat16, (True, interpret),
+                        chunk, **gate)
                 return (y * mix).sum(), y
             (_, y), grads = jax.value_and_grad(
-                weighted, argnums=(0, 1, 2, 3), has_aux=True)(u, weight, w_in, w_out)
+                weighted, argnums=tuple(range(4 + len(w_gate))), has_aux=True,
+            )(u, weight, w_in, w_out, *w_gate)
             return y, grads
 
         case(
-            f"moe walk fwd+bwd N{n}/k{k}/held{held}of{total}/d{d}/f{f} bf16 "
+            f"moe {'gated ' * gated}walk fwd+bwd N{n}/k{k}/held{held}of{total}/d{d}/f{f} bf16 "
             f"(chunk {chunk}, {trips} trip{'s' * (trips != 1)})",
             functools.partial(block, False), functools.partial(block, True),
-            (f32(n, d), jnp.asarray(rng.random((n, k)) + 0.1, jnp.float32),
-             f32(held, d, f) * d**-0.5, f32(held, f, d) * f**-0.5), TOL_BF16, TOL_BF16,
+            (as_run(f32(n, d)), jnp.asarray(rng.random((n, k)) + 0.1, jnp.float32),
+             as_run(f32(held, d, f) * d**-0.5), as_run(f32(held, f, d) * f**-0.5),
+             *([as_run(f32(held, d, f) * d**-0.5)] if gated else [])), TOL_BF16, TOL_BF16,
         )
     return rows
 

@@ -53,17 +53,24 @@ def test_kernel_checks_pass_tiny_interpreted():
         lstm_shapes=((8, 3, 16, "auto"), (16, 3, 16, "force")),
         act_shapes=((8, 4, 16, 2),),
         flash_shapes=(
-            (2, 128, 2, 2, 16, None, True),
-            (1, 128, 2, 2, 16, None, False),
-            (2, 128, 4, 2, 16, 1.0 / 64, True),
+            (2, 128, 2, 2, 16, None, True, None),
+            (1, 128, 2, 2, 16, None, False, None),
+            (2, 128, 4, 2, 16, 1.0 / 64, True, None),
+            (1, 128, 4, 2, 16, 0.25, True, 48),
         ),
         ssd_shapes=((2, 32, 4, 8, 1, 16, 8), (2, 32, 4, 8, 2, 16, 8)),
         gmm_shapes=((512, 4, 64, 48),),
-        moe_shapes=((300, 3, 4, 16, 64, 48, 0.0), (300, 3, 4, 16, 64, 48, 0.75)),
+        moe_shapes=((300, 3, 4, 16, 64, 48, 0.0, False), (300, 3, 4, 16, 64, 48, 0.75, False),
+                    (300, 3, 4, 16, 64, 48, 0.0, True), (300, 3, 4, 16, 64, 48, 0.75, True),
+                    (300, 3, 4, 16, 64, 48, 1.0, True, 256)),
         interpret=True,
     )
-    assert len(rows) == 11
-    assert "1 trip)" in rows[-2]["kernel"] and "2 trips)" in rows[-1]["kernel"]
+    assert len(rows) == 15
+    assert "/window48 " in rows[6]["kernel"]
+    for one, several in (rows[-5:-3], rows[-3:-1]):
+        assert "1 trip)" in one["kernel"] and "2 trips)" in several["kernel"]
+    assert "gated" in rows[-2]["kernel"] and "gated" not in rows[-4]["kernel"]
+    assert "(chunk 256, 4 trips)" in rows[-1]["kernel"]  # the chunk handed over, every row held
     assert all(r["ok"] for r in rows), rows
 
 

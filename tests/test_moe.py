@@ -235,12 +235,26 @@ def test_a_trip_takes_its_part_of_every_group():
 
 
 def test_the_chunk_is_twice_the_rows_a_fair_router_sends():
-    # the cell: 16,384 tokens, 6 choices, 8 held of 128 -> 6,144 rows expected
-    assert moe.chunk_rows(16384, 6, 8, 128) == 12288 == 48 * moe.ROW_TILE
-    assert 6 * 16384 // moe.chunk_rows(16384, 6, 8, 128) == 8  # what all-held costs
-    assert moe.chunk_rows(16384, 6, 128, 128) == 6 * 16384  # every expert held: every row
-    assert moe.chunk_rows(40, 3, 4, 16) == moe.ROW_TILE  # never less than a row tile
-    assert moe.chunk_rows(1000, 3, 4, 16) == 1536  # 750 expected, doubled, whole tiles
+    # the cell: 16,384 tokens of width 2,688, 6 choices, 8 held of 128 -> 6,144 rows expected
+    assert moe.chunk_rows(16384, 6, 8, 128, 2688) == 12288 == 48 * moe.ROW_TILE
+    assert 6 * 16384 // moe.chunk_rows(16384, 6, 8, 128, 2688) == 8  # what all-held costs
+    assert moe.chunk_rows(2048, 6, 128, 128, 2688) == 6 * 2048  # every expert held: every row
+    assert moe.chunk_rows(40, 3, 4, 16, 64) == moe.ROW_TILE  # never less than a row tile
+    assert moe.chunk_rows(1000, 3, 4, 16, 64) == 1536  # 750 expected, doubled, whole tiles
+
+
+@pytest.mark.parametrize("n, d, held, total, want", [
+    (16384, 2688, 8, 128, 12288),  # 176 MB: nemotron's cell, its one trip
+    (20480, 2688, 8, 128, 15360),  # 220 MB: a fifth window keeps one trip (3.4 ms measured)
+    (65536, 1024, 16, 64, 196608),  # 256 MiB to the byte: still as the routing suggests
+    (32768, 2560, 16, 64, 4096),  # 335 MB: smallthinker's cell, 98,304 by the doubled share
+    (32768, 2560, 1, 128, 3072),  # ... where the share is less than a short trip, the share
+], ids=["nemotron", "five-windows", "at-the-bound", "smallthinker", "large-result-small-share"])
+def test_a_large_result_is_walked_in_short_trips(n, d, held, total, want):
+    """The bound is on the (n, d) float32 result the trips add into, where the
+    chip's scatter-add changed speed (``moe.ONE_TRIP_BYTES``), not on the rows."""
+    assert moe.chunk_rows(n, 6, held, total, d) == want
+    assert want <= moe.WALK_ROWS or 4 * n * d <= moe.ONE_TRIP_BYTES
 
 
 def test_route_stats_count_rows_per_held_expert():

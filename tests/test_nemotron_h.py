@@ -128,9 +128,9 @@ def test_bfloat16_matches_the_reference_on_the_systems_choices(actor, plain):
         assert (np.asarray(theirs["margin"])[differ] < 2e-2).all()
 
 
-def ref_ppo_loss(p, batch, cfg):
+def ref_ppo_loss(p, batch, cfg, forward=lambda p, b: reference.forward(p, b, PARAMS)):
     """``benchmarks/reference/losses.ppo`` in jax.numpy, so it has a gradient."""
-    logits, value = reference.forward(p, batch, PARAMS)
+    logits, value = forward(p, batch)
     g, lam, eps = cfg.gamma, cfg.lmbda, cfg.eps_clip
     log_prob = jnp.take_along_axis(logits, batch["act"].astype(jnp.int32), axis=-1)
     entropy = -(jnp.exp(logits) * logits).sum(-1, keepdims=True)

@@ -304,6 +304,61 @@ class TestFlashImpl:
                 np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-5, err_msg=name
             )
 
+    @pytest.mark.parametrize("window", [160, 128, 512], ids=["window-160", "one-tile", "whole-T"])
+    def test_splash_with_a_window_matches_full_attention(self, rng, window):
+        """T 512 in tiles of 128, 4 : 2 heads, 4 segments a row, so seams fall
+        inside a band and behind it. At 160 keys a query's band crosses two tile
+        edges (the diagonal tile and up to two behind it, the fourth row's
+        first tile never visited); at 128 one. Forward and the three gradients."""
+        import dataclasses
+
+        from jax.experimental.pallas.ops.tpu.splash_attention import (
+            splash_attention_mask as masks,
+            splash_attention_mask_info as mask_info,
+        )
+        from tpu_rl.parallel.sequence import _splash_mha, _splash_block_sizes
+
+        T, H, D, n_kv = 512, 4, 64, 2
+        q, k, v, pos, seg = _inputs(rng, T=T, H=H, D=D, n_segments=4)
+        k, v = k[:, :, :n_kv], v[:, :, :n_kv]
+        rule = _splash_block_sizes(T)
+        tiles = dataclasses.replace(rule, **{
+            f.name: 128 for f in dataclasses.fields(rule)
+            if f.name.startswith("block_") and getattr(rule, f.name) is not None
+        })
+        cot = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
+
+        def splash(q, k, v):
+            out = _splash_mha(
+                q, k, v, seg, causal=True, scale=float(1.0 / np.sqrt(D)),
+                block_sizes=tiles, interpret=True, window=window,
+            )
+            return (out * cot).sum(), out
+
+        def full(q, k, v):
+            kr, vr = (jnp.repeat(x, H // n_kv, axis=2) for x in (k, v))
+            out = full_attention(q, kr, vr, pos, seg, causal=True, window=window)
+            return (out * cot).sum(), out
+
+        grad = lambda f: jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        got_g, got = grad(splash)
+        want_g, want = grad(full)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+        for name, g, w in zip(("dq", "dk", "dv"), got_g, want_g):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-5, err_msg=name
+            )
+        # the band's tiles alone are in the kernel's grid: what the window saves
+        visited = lambda m: int((np.asarray(mask_info.process_mask(  # noqa: E731
+            masks.MultiHeadMask([m]), (128, 128), is_dkv=False)[0].block_mask) > 0).sum())
+        causal = visited(masks.CausalMask((T, T)))
+        band = visited(masks.LocalMask((T, T), (window - 1, 0), 0))
+        assert causal == 10 and band == {160: 9, 128: 7, 512: 10}[window]
+        if window < T:
+            whole = full_attention(q, jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2),
+                                   pos, seg, causal=True)
+            assert float(jnp.abs(whole - want).max()) > 1e-3  # the window cuts something
+
     def test_falls_back_to_full_off_tpu(self, rng):
         from tpu_rl.parallel.sequence import flash_attention_tpu
 

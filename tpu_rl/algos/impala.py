@@ -20,6 +20,7 @@ from tpu_rl.config import Config
 from tpu_rl.heal.guards import guarded, update_ok
 from tpu_rl.models.families import ModelFamily
 from tpu_rl.obs.learn import (
+    attention_scalars,
     module_grad_norms,
     route_scalars,
     rows_mean,
@@ -110,7 +111,7 @@ def make_train_step(cfg: Config, family: ModelFamily):
                     "err": rows_mean(err),
                     "err2": rows_mean(jnp.square(err)),
                 },
-                "scalars": route_scalars(routes),
+                "scalars": {**route_scalars(routes), **attention_scalars(routes)},
             }
         return loss, metrics
 

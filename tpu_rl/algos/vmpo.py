@@ -34,6 +34,7 @@ from tpu_rl.config import Config
 from tpu_rl.heal.guards import guarded, update_ok
 from tpu_rl.models.families import ModelFamily
 from tpu_rl.obs.learn import (
+    attention_scalars,
     module_grad_norms,
     route_scalars,
     rows_mean,
@@ -165,6 +166,7 @@ def make_train_step(cfg: Config, family: ModelFamily):
                     "eta": jax.lax.stop_gradient(eta),
                     "vmpo-alpha": jax.lax.stop_gradient(alpha),
                     **route_scalars(routes),
+                    **attention_scalars(routes),
                 },
             }
         return loss, metrics

@@ -133,7 +133,23 @@ def _check_nemotron_arch(arch: dict | None) -> None:
     assert arch["mamba_num_heads"] % arch["n_groups"] == 0
     assert arch["num_attention_heads"] % arch["num_key_value_heads"] == 0
     assert arch["conv_kernel"] >= 2 and arch["chunk_size"] >= 1
-    held = arch["n_routed_experts"]
+    _check_expert_share(arch, arch["n_routed_experts"], arch["num_experts_per_tok"])
+
+
+# The keys of a SmallThinker ``config.json`` that shape the policy core
+# (``models/smallthinker.py``); ``expert_parallel`` as above, with
+# ``moe_num_primary_experts`` the count one rank holds.
+SMALLTHINKER_ARCH_KEYS = (
+    "hidden_size", "num_hidden_layers", "rms_norm_eps", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "rope_theta", "rope_layout",
+    "sliding_window_layout", "sliding_window_size", "moe_ffn_hidden_size",
+    "moe_num_primary_experts", "moe_num_active_primary_experts",
+    "moe_primary_router_apply_softmax", "norm_topk_prob",
+)
+
+
+def _check_expert_share(arch: dict, held: int, top_k: int) -> None:
+    """``arch["expert_parallel"]`` against the experts one rank holds."""
     share = arch.get("expert_parallel")
     if share:
         total, chips, rank = (
@@ -145,11 +161,41 @@ def _check_nemotron_arch(arch: dict | None) -> None:
         assert 0 <= rank < chips, f"rank {rank} of {chips}"
     else:
         total = held
-    assert 1 <= arch["num_experts_per_tok"] <= total, arch["num_experts_per_tok"]
+    assert 1 <= top_k <= total, top_k
+
+
+def _check_smallthinker_arch(arch: dict | None) -> None:
+    """What ``model="smallthinker"`` can build: every layer grouped-query
+    attention (global without positions, or a sliding window with rotary
+    ones, as the two layouts say) and gated ``relu`` experts under a softmax
+    router over the chosen logits; no shared expert, no bias anywhere."""
+    assert isinstance(arch, dict), "model='smallthinker' needs arch (config.json keys)"
+    missing = [k for k in SMALLTHINKER_ARCH_KEYS if k not in arch]
+    assert not missing, f"arch lacks {missing}"
+    depth = arch["num_hidden_layers"]
+    for key in ("rope_layout", "sliding_window_layout"):
+        layout = arch[key]
+        assert len(layout) == depth >= 1 and set(layout) <= {0, 1}, (
+            f"{key} {layout!r}: one 0 or 1 for each of the {depth} layers"
+        )
+    assert arch["sliding_window_size"] >= 1, arch["sliding_window_size"]
+    assert arch.get("rope_scaling") is None, "rotary scaling is not built"
+    assert arch["head_dim"] % 2 == 0, "rotate-half pairs the head's two halves"
+    assert arch["moe_primary_router_apply_softmax"] and arch["norm_topk_prob"], (
+        "the router is the softmax over the chosen logits"
+    )
+    assert arch["num_attention_heads"] % arch["num_key_value_heads"] == 0
+    _check_expert_share(
+        arch, arch["moe_num_primary_experts"], arch["moe_num_active_primary_experts"]
+    )
 
 
 # The families built from a published config.json in ``Config.arch``.
-ARCH_CHECKS = {"granite_hybrid": _check_granite_arch, "nemotron_h": _check_nemotron_arch}
+ARCH_CHECKS = {
+    "granite_hybrid": _check_granite_arch,
+    "nemotron_h": _check_nemotron_arch,
+    "smallthinker": _check_smallthinker_arch,
+}
 
 
 @dataclass
@@ -175,7 +221,9 @@ class Config:
     # TPU-native long-context capability; on-policy algos only) or
     # "granite_hybrid" (Mamba-2 + GQA attention layers of a published
     # GraniteMoeHybrid config.json, given whole in ``arch``) or "nemotron_h"
-    # (Mamba-2, attention and sparse-expert layers of a NemotronH config.json).
+    # (Mamba-2, attention and sparse-expert layers of a NemotronH config.json)
+    # or "smallthinker" (global and sliding-window attention, each layer with
+    # gated sparse experts, of a SmallThinker config.json).
     model: str = "lstm"
     n_heads: int = 4
     n_layers: int = 2
@@ -187,7 +235,7 @@ class Config:
     act_ctx: int = 0
     # A published architecture's own config.json, under its published key
     # names (model="granite_hybrid": GRANITE_ARCH_KEYS above; "nemotron_h":
-    # NEMOTRON_ARCH_KEYS). One
+    # NEMOTRON_ARCH_KEYS; "smallthinker": SMALLTHINKER_ARCH_KEYS). One
     # mapping instead of a Config field per width: the widths of a catalog
     # model are its source's to name, not this file's.
     arch: dict | None = None
@@ -805,7 +853,7 @@ class Config:
             ARCH_CHECKS[self.model](self.arch)
         else:
             assert self.arch is None, (
-                f"arch is read by model='granite_hybrid' and 'nemotron_h' only, "
+                f"arch is read by model={sorted(ARCH_CHECKS)} only, "
                 f"not {self.model!r}"
             )
         # bfloat16 is wired for both backbones: the transformer via flax

@@ -202,7 +202,7 @@ def _act_transformer_window(
 
 def _act_granite_hybrid(actor, params, obs, h, c, key):
     """One recurrent step of the hybrid families (granite_hybrid,
-    nemotron_h): ``h`` holds the Mamba layers'
+    nemotron_h, smallthinker): ``h`` holds the Mamba layers'
     states and convolution tails, ``c`` the attention layers' K/V rings and a
     step counter (``models/granite_hybrid.py``). The worker zeroes both at
     episode starts, so no state crosses episodes."""
@@ -280,11 +280,14 @@ def build_family(cfg: Config, mesh=None) -> ModelFamily:
             store_carry=False,
         )
 
-    if cfg.model == "nemotron_h":
-        from tpu_rl.models.nemotron_h import NemotronHActorCritic, carry_widths
+    if cfg.model in ("nemotron_h", "smallthinker"):
+        if cfg.model == "nemotron_h":
+            from tpu_rl.models.nemotron_h import NemotronHActorCritic as core, carry_widths
+        else:
+            from tpu_rl.models.smallthinker import SmallThinkerActorCritic as core, carry_widths
 
         ctx = cfg.effective_act_ctx
-        actor = NemotronHActorCritic(
+        actor = core(
             n_actions=n, arch=cfg.arch, act_ctx=ctx,
             dtype=jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else None,
         )

@@ -336,10 +336,32 @@ class Mamba2Mixer(nn.Module):
         return self._out(y.reshape(-1, self.inner), z), state, window[:, 1:]
 
 
+@jax.named_scope("attn_rope")
+def rope(x, pos, theta: float):
+    """Rotary positions over the whole head, rotate-half pairing (feature
+    ``i`` with ``i + D/2``), no scaling: ``x`` (B, ..., H, D) with ``pos``
+    (B, ...) int. Angles, sines and the rotation in float32; ``x``'s dtype
+    comes back."""
+    D = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    angle = pos.astype(jnp.float32)[..., None, None] * inv  # (B, ..., 1, D/2)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
 class GQAttention(nn.Module):
-    """Grouped-query attention without positions (``nope``), causal and
-    masked to the episode. Widths as fields: the head size need not be
-    ``hidden / n_q``."""
+    """Grouped-query attention, causal and masked to the episode. Widths as
+    fields: the head size need not be ``hidden / n_q``. Two more fields, both
+    off by default (granite's and nemotron's layers: no positions, ``nope``,
+    and the whole episode so far): ``rope_theta`` rotates q and k (``rope``),
+    ``window`` keeps the last ``window`` keys, the query's own among them.
+
+    The rotation's position is the step's index in the training window, and
+    in acting the steps of the episode so far: the same scores, because the
+    rotation enters a score only through ``q_pos - k_pos`` and the episode
+    mask kills every pair that crosses a seam — within an episode the two
+    count from different origins and differ by a constant."""
 
     hidden: int
     n_q: int
@@ -348,6 +370,8 @@ class GQAttention(nn.Module):
     scale: float  # of the scores, before the softmax
     bias: bool = False
     dtype: Any = None
+    rope_theta: float | None = None
+    window: int | None = None
 
     def setup(self):
         proj = dict(use_bias=self.bias, dtype=self.dtype)
@@ -365,13 +389,20 @@ class GQAttention(nn.Module):
             for p in (self.k_proj, self.v_proj)
         )
         pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        o = flash_attention_tpu(q, k, v, pos, seg, causal=True, sm_scale=self.scale)
+        if self.rope_theta is not None:
+            q, k = rope(q, pos, self.rope_theta), rope(k, pos, self.rope_theta)
+        o = flash_attention_tpu(
+            q, k, v, pos, seg, causal=True, sm_scale=self.scale, window=self.window
+        )
         return self.o_proj(o.reshape(B, T, -1))
 
     def step(self, u, k_cache, v_cache, count):
         """One acting step over a K/V ring of ``ctx`` slots (B, ctx, kv, D);
-        ``count`` (B,) int: steps of this episode already cached. Without
-        positions the ring is an exact sliding window."""
+        ``count`` (B,) int: steps of this episode already cached. The ring is
+        an exact sliding window of ``ctx`` keys (a ``window`` layer's ring has
+        ``window`` slots): without positions because a key carries none, with
+        them because a key is stored as rotated at its own step and a score
+        reads only the difference to the query's."""
         B = u.shape[0]
         ctx = k_cache.shape[1]
         rep = self.n_q // self.n_kv
@@ -379,6 +410,9 @@ class GQAttention(nn.Module):
         k_new, v_new = (
             p(u).reshape(B, 1, self.n_kv, self.head_dim) for p in (self.k_proj, self.v_proj)
         )
+        if self.rope_theta is not None:
+            q = rope(q, count[:, None], self.rope_theta)
+            k_new = rope(k_new, count[:, None], self.rope_theta)
         write = (jnp.arange(ctx)[None] == jnp.mod(count, ctx)[:, None])[:, :, None, None]
         k_cache = jnp.where(write, k_new.astype(k_cache.dtype), k_cache)
         v_cache = jnp.where(write, v_new.astype(v_cache.dtype), v_cache)
@@ -468,9 +502,8 @@ class GraniteHybridActorCritic(nn.Module):
         self.h_width, self.c_width = carry_widths(a, self.act_ctx)
         self.state_shape = (a["mamba_n_heads"], a["mamba_d_head"], a["mamba_d_state"])
         self.tail_shape = (a["mamba_d_conv"] - 1, _conv_channels(a))
-        self.kv_shape = (
-            self.act_ctx, a["num_key_value_heads"], a["hidden_size"] // a["num_attention_heads"]
-        )
+        ring = (self.act_ctx, a["num_key_value_heads"], a["hidden_size"] // a["num_attention_heads"])
+        self.kv_shapes = [ring] * a["layer_types"].count("attention")
 
     def _embed(self, obs):
         return self.arch["embedding_multiplier"] * self.embed(obs).astype(jnp.float32)
@@ -482,6 +515,8 @@ class GraniteHybridActorCritic(nn.Module):
 
     def _unpack_h(self, h):
         """(B, h_width) -> one (state, tail) per Mamba layer, float32."""
+        if not self.h_width:
+            return []
         n_state, n_tail = int(np.prod(self.state_shape)), int(np.prod(self.tail_shape))
         per = h.reshape(h.shape[0], -1, n_state + n_tail)
         return [
@@ -491,6 +526,17 @@ class GraniteHybridActorCritic(nn.Module):
             )
             for i in range(per.shape[1])
         ]
+
+    def _unpack_c(self, c):
+        """(B, c_width) -> one (k, v) ring per attention layer, each of its
+        own ``kv_shapes`` entry, and the step counter (B,) int."""
+        rings, at = [], 0
+        for shape in self.kv_shapes:
+            n = int(np.prod(shape))
+            rings.append(tuple(
+                c[:, at + i * n: at + (i + 1) * n].reshape(-1, *shape) for i in (0, 1)))
+            at += 2 * n
+        return rings, c[:, -1].astype(jnp.int32)
 
     @staticmethod
     def _pack(pairs, B):
@@ -532,19 +578,17 @@ class GraniteHybridActorCritic(nn.Module):
     def act(self, obs, h, c):
         """One step for every row of ``obs`` (B, obs_dim)."""
         B = obs.shape[0]
-        count = c[:, -1].astype(jnp.int32)
-        kv = c[:, :-1].reshape(B, -1, 2, *self.kv_shape)  # (B, attention layers, k|v, ...)
+        rings, count = self._unpack_c(c)
         x = self._embed(obs)
-        mamba, attn = iter(self._unpack_h(h)), 0
+        mamba, rings = iter(self._unpack_h(h)), iter(rings)
         carried, caches = [], []
         for layer in self.layers:
             if layer.kind == "mamba":
                 x, state, tail = layer.step(x, *next(mamba))
                 carried.append((state, tail))
             elif layer.kind == "attention":
-                x, k, v = layer.step(x, kv[:, attn, 0], kv[:, attn, 1], count)
+                x, k, v = layer.step(x, *next(rings), count)
                 caches.append((k, v))
-                attn += 1
             else:  # a layer that carries nothing from step to step
                 (x,) = layer.step(x)
         logits, value = self._heads(x)

@@ -24,8 +24,8 @@ rank of: ``published_n_routed_experts`` experts over ``chips`` ranks, this one
 shared expert's output and the held experts' part of the routed sum; the
 absent experts' part is left out (no exchange, nothing in its place). Without
 the key every expert is held. In training the held assignments, sorted by
-expert, are walked in chunks of ``moe.chunk_rows(tokens, top_k, held, experts)``
-rows — twice what a fair router sends this rank — as often as the routing's
+expert, are walked in chunks of ``moe.chunk_rows(tokens, top_k, held, experts,
+hidden)`` rows — twice what a fair router sends this rank — as often as the routing's
 own counts say: once most updates for a rank that holds a sixteenth, and as
 often as it takes, dropping nothing, when more arrives.
 
@@ -58,10 +58,11 @@ def layer_kinds(arch: dict) -> list[str]:
     return [KINDS[c] for c in arch["hybrid_override_pattern"]]
 
 
-def expert_share(arch: dict) -> tuple[int, int, int]:
+def expert_share(arch: dict, held_key: str = "n_routed_experts") -> tuple[int, int, int]:
     """(experts the router scores, experts held here, global id of the first
-    held): the rank's share of ``arch["expert_parallel"]``, or everything."""
-    held = arch["n_routed_experts"]
+    held): the rank's share of ``arch["expert_parallel"]``, or everything.
+    ``held_key``: the source's name for the count of routed experts."""
+    held = arch[held_key]
     share = arch.get("expert_parallel")
     if not share:
         return held, held, 0
@@ -98,6 +99,12 @@ _expert_init = nn.initializers.variance_scaling(
 
 
 class ExpertBlock(nn.Module):
+    """This model's block by default: a sigmoid router with a correction
+    bias, ``relu2`` experts and a shared one. The fields after ``dtype`` give
+    the other published form (``models/smallthinker.py``): ``gated`` experts
+    (a third leaf, ``w_gate``), a ``softmax`` score without bias or scale,
+    and ``shared_width`` 0 for no shared expert."""
+
     hidden: int
     n_experts: int  # the router's width: every published expert
     held: int  # routed experts this rank holds ...
@@ -107,44 +114,60 @@ class ExpertBlock(nn.Module):
     shared_width: int
     scale: float
     dtype: Any = None
+    gated: bool = False
+    score: str = "sigmoid"
 
     def setup(self):
         self.router = self.param(
             "router", nn.initializers.lecun_normal(), (self.hidden, self.n_experts))
-        self.router_bias = self.param("router_bias", _correction_bias_init, (self.n_experts,))
-        self.w_in = self.param("w_in", _expert_init, (self.held, self.hidden, self.expert_width))
+        self.router_bias = (
+            self.param("router_bias", _correction_bias_init, (self.n_experts,))
+            if self.score == "sigmoid" else None
+        )
+        first = (self.held, self.hidden, self.expert_width)
+        self.w_gate = self.param("w_gate", _expert_init, first) if self.gated else None
+        self.w_in = self.param("w_in", _expert_init, first)
         self.w_out = self.param("w_out", _expert_init, (self.held, self.expert_width, self.hidden))
-        dense = dict(use_bias=False, dtype=self.dtype)
-        self.shared_in = nn.Dense(self.shared_width, name="shared_in", **dense)
-        self.shared_out = nn.Dense(self.hidden, name="shared_out", **dense)
+        if self.shared_width:
+            dense = dict(use_bias=False, dtype=self.dtype)
+            self.shared_in = nn.Dense(self.shared_width, name="shared_in", **dense)
+            self.shared_out = nn.Dense(self.hidden, name="shared_out", **dense)
 
     def _route(self, rows):
-        return moe.route(rows, self.router, self.router_bias, self.top_k, self.scale)
+        return moe.route(
+            rows, self.router, self.router_bias, self.top_k, self.scale, self.score)
 
-    @jax.named_scope("moe_shared")
-    def _shared(self, u):
-        return self.shared_out(jnp.square(jax.nn.relu(self.shared_in(u)))).astype(jnp.float32)
+    def _add_shared(self, u, routed):
+        """The block's output for ``u`` from its rows' routed part."""
+        if not self.shared_width:
+            return routed.reshape(u.shape)
+        with jax.named_scope("moe_shared"):
+            shared = self.shared_out(jnp.square(jax.nn.relu(self.shared_in(u))))
+        return shared.astype(jnp.float32) + routed.reshape(u.shape)
 
-    def __call__(self, u):
+    def __call__(self, u, scored=None):
         """``u`` (B, T, d). Returns the block's output (float32) and its
-        routing: the chosen experts (B, T, top_k) and the counters."""
+        routing: the chosen experts (B, T, top_k) and the counters. The router
+        reads ``scored`` (B, T, d) where the model routes on another state
+        than the experts compute on."""
         rows = u.reshape(-1, self.hidden)
-        choice, weight = self._route(rows)
-        chunk = moe.chunk_rows(rows.shape[0], self.top_k, self.held, self.n_experts)
+        choice, weight = self._route(rows if scored is None else scored.reshape(rows.shape))
+        chunk = moe.chunk_rows(rows.shape[0], self.top_k, self.held, self.n_experts, self.hidden)
         routed = moe.routed_experts(
-            rows, choice, weight, self.w_in, self.w_out, self.first, self.dtype, chunk=chunk)
+            rows, choice, weight, self.w_in, self.w_out, self.first, self.dtype, chunk=chunk,
+            w_gate=self.w_gate)
         route = {
             "choice": choice.reshape(*u.shape[:-1], self.top_k),
             "stats": moe.route_stats(choice, self.first, self.held, chunk),
         }
-        return self._shared(u) + routed.reshape(u.shape), route
+        return self._add_shared(u, routed), route
 
-    def step(self, u):
+    def step(self, u, scored=None):
         """One acting step: ``u`` (B, d)."""
-        choice, weight = self._route(u)
+        choice, weight = self._route(u if scored is None else scored)
         routed = moe.routed_experts_dense(
-            u, choice, weight, self.w_in, self.w_out, self.first, self.dtype)
-        return self._shared(u) + routed
+            u, choice, weight, self.w_in, self.w_out, self.first, self.dtype, self.w_gate)
+        return self._add_shared(u, routed)
 
 
 class NemotronLayer(nn.Module):
@@ -220,7 +243,8 @@ class NemotronHActorCritic(GraniteHybridActorCritic):
         self.h_width, self.c_width = carry_widths(a, self.act_ctx)
         self.state_shape = (a["mamba_num_heads"], a["mamba_head_dim"], a["ssm_state_size"])
         self.tail_shape = (a["conv_kernel"] - 1, _conv_channels(a))
-        self.kv_shape = (self.act_ctx, a["num_key_value_heads"], a["head_dim"])
+        ring = (self.act_ctx, a["num_key_value_heads"], a["head_dim"])
+        self.kv_shapes = [ring] * layer_kinds(a).count("attention")
 
     def _embed(self, obs):
         return self.embed(obs).astype(jnp.float32)

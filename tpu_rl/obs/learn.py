@@ -124,6 +124,19 @@ def route_scalars(routes: list) -> dict[str, jax.Array]:
     }
 
 
+def attention_scalars(routes: list) -> dict[str, jax.Array]:
+    """Query-key pairs the attention masks kept this update, from what the
+    layers handed back beside their routing (``attn-pairs``: kind -> count;
+    ``models/smallthinker.py``): ``attn-pairs-global`` and
+    ``attn-pairs-window``, each summed over the layers of its kind. Empty
+    for a family that counts none."""
+    out: dict[str, jax.Array] = {}
+    for r in routes:
+        for kind, pairs in r.get("attn-pairs", {}).items():
+            out[f"attn-pairs-{kind}"] = out.get(f"attn-pairs-{kind}", 0.0) + pairs
+    return out
+
+
 def tree_delta_norm(new: Any, old: Any) -> jax.Array:
     """Global norm of ``new - old`` over a param pytree (the applied update's
     magnitude; exactly 0 when a guard skipped the update)."""

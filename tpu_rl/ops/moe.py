@@ -5,16 +5,31 @@ the first; how many: the leading axis of its weights). It routes every token
 over *all* the model's experts, as the published router does, and computes
 
     y = shared(u) + sum over the token's chosen experts that are held here of
-        w_e * W2_e relu(W1_e u)^2
+        w_e * expert_e(u)
+
+with one of two published expert forms, each without bias:
+
+    ``relu2``  W_out relu(W_in u)^2                      two leaves an expert
+    ``reglu``  W_out (relu(W_gate u) * W_in u)           three (gated)
 
 What the absent experts would add is left out: in a deployment the other ranks
 compute it and an exchange brings it home; here there is no exchange and no
-code that stands in for one.
+code that stands in for one. The shared expert is the caller's (a family that
+has none adds none).
 
-Router (``route``, float32 throughout): ``s = sigmoid(W_r u)``; the choice is
-the ``top_k`` largest of ``s + b`` (``b``: a correction bias that only the
-choice reads, so no gradient reaches it); ``w = scale * s_e / (sum of the
-chosen s + 1e-20)``. The discrete choice carries no gradient, ``s`` does.
+Router (``route``, float32 throughout), one of two published scores over
+``l = W_r r`` (``r``: what the router reads, the block's input or another
+state the caller hands over):
+
+    ``sigmoid``  ``s = sigmoid(l)``; the choice is the ``top_k`` largest of
+                 ``s + b`` (``b``: a correction bias that only the choice
+                 reads, so no gradient reaches it); ``w = scale * s_e / (sum
+                 of the chosen s + 1e-20)``
+    ``softmax``  the choice is the ``top_k`` largest of ``l``; ``w`` is the
+                 softmax over the chosen logits (= the softmax over all,
+                 renormalised over the chosen); no bias, no scale
+
+The discrete choice carries no gradient, the chosen scores do.
 
 Dispatch (``routed_experts``), with static shapes and without dropping a token
 whatever the imbalance: the ``tokens x top_k`` assignments are sorted by held
@@ -28,15 +43,19 @@ a group may straddle an edge), and adds the weighted rows into the ``(N, d)``
 float32 result at their tokens. Every buffer, gather, cast and activation is
 ``C`` rows tall; ``C`` (``chunk_rows``) is a ``ROW_TILE`` multiple near twice
 the rows expected from the static shapes, so a rank that holds a sixteenth of
-the experts takes one trip most updates and routing that puts every assignment
-here takes ``N k / C``. Rows past the held total inside the last chunk are
-never computed and never read: the grouped matmul's grid ends with the last
+the experts takes one trip most updates and routing that puts every
+assignment here ``N k / C``; where the ``(N, d)`` result is larger than
+``ONE_TRIP_BYTES`` (a quarter of the experts at 32,768 tokens of width 2,560)
+a trip is at most ``WALK_ROWS`` tall, a dozen trips a layer. Rows past the
+held total inside the last chunk are never computed and never read: the grouped matmul's grid ends with the last
 held row, and the combine and the backward **select** live rows. The walk is
 one ``custom_vjp`` (``_walk``) that keeps its inputs only: the backward is the
 same walk — gather the tokens' rows and the result's cotangent, recompute the
 hidden rows, the two transposed products per projection, add into the tokens'
 gradient — with the weights' gradients accumulated across trips in float32
-(inside ``tgmm`` where the kernel runs). ``route_stats`` counts the trips
+(inside ``tgmm`` where the kernel runs). The expert form is the walk's static
+parameter (``EXPERT_FORMS``: the hidden rows from the first products, and their
+cotangents); everything else is shared. ``route_stats`` counts the trips
 (``chunks``).
 
 The grouped matmul (``grouped_matmul``): on a TPU, at widths whose tiles the
@@ -47,8 +66,9 @@ which is also the kernel's oracle. The gate is ``models/cells.py``'s
 ``"off"`` forces ``ragged_dot``).
 
 Scopes, for the device trace: ``moe_route``, ``moe_dispatch``, ``moe_experts``
-(``moe_gmm_pallas`` inside it when the kernel was taken), ``moe_combine``,
-``moe_shared``; the caller wraps the block in ``moe``.
+(``moe_gmm_pallas`` inside it when the kernel was taken), ``moe_combine``;
+the caller wraps the block in ``moe`` and its shared expert, where it has
+one, in ``moe_shared``.
 """
 
 from __future__ import annotations
@@ -64,30 +84,53 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as _megablox_tgmm
 # hundred rows an update at the cell's batch: a taller tile would be mostly
 # another group's rows, masked.
 ROW_TILE = 256
+# A trip ends in a scatter-add of its ``C`` rows into the ``(N, d)`` float32
+# result, and on a TPU v5e that scatter-add has two speeds: ~2.5-4.5 ms, growing
+# slowly with ``C``, and a flat ~15 ms. Which one it gets follows the result's
+# size, not the trip's: into 8,192 x 4,096, 16,384 x 2,688, 20,480 x 2,688,
+# 24,576 x 2,048 and 65,536 x 1,024 floats (134-268 MB) every chunk tried, up to
+# 40,960 rows, took the fast one; into 32,768 x 2,560 (335 MB) 4,096 rows took
+# 2.6 ms and 5,120 to 16,384 rows 14.9-15.8; into 49,152 x 2,560 (503 MB) 1,024
+# to 4,096 rows 2.0-3.1 (PERF.md section 6, PR 32: one layer's walk over 49,000
+# held rows of 32,768 tokens took 84 ms forward + backward in chunks of 4,096
+# rows against 243 / 190 / 162 / 109 / 82 at 8,192 / 12,288 / 16,384 / 32,768 /
+# 49,152). So a result of at most ``ONE_TRIP_BYTES`` is walked in trips as tall
+# as the routing suggests, a larger one at most ``WALK_ROWS`` at a time.
+ONE_TRIP_BYTES = 256 << 20
+WALK_ROWS = 4_096
 
 
 # ------------------------------------------------------------------ the router
 @jax.named_scope("moe_route")
-def route(u, kernel, bias, top_k: int, scale: float):
+def route(u, kernel, bias, top_k: int, scale: float, score: str = "sigmoid"):
     """``u`` (N, d); ``kernel`` (d, E); ``bias`` (E,). Returns the chosen
-    experts (N, top_k) int32 and their weights (N, top_k) float32."""
-    s = jax.nn.sigmoid(jnp.dot(
+    experts (N, top_k) int32 and their weights (N, top_k) float32.
+    ``score="softmax"`` reads neither ``bias`` nor ``scale``."""
+    logits = jnp.dot(
         u.astype(jnp.float32), kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
-    ))
+    )
+    if score == "softmax":
+        _, choice = jax.lax.top_k(jax.lax.stop_gradient(logits), top_k)
+        chosen = jnp.take_along_axis(logits, choice, axis=-1)
+        return choice.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
+    s = jax.nn.sigmoid(logits)
     _, choice = jax.lax.top_k(jax.lax.stop_gradient(s + bias), top_k)
     chosen = jnp.take_along_axis(s, choice, axis=-1)
     chosen = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
     return choice.astype(jnp.int32), scale * chosen
 
 
-def chunk_rows(n: int, k: int, held: int, n_experts: int) -> int:
+def chunk_rows(n: int, k: int, held: int, n_experts: int, d: int) -> int:
     """Rows a trip of ``routed_experts``'s walk takes (``C``), from static
     shapes alone: the ``ROW_TILE`` multiple next above twice the rows a fair
-    router sends here (``n k held / n_experts``), and never more than all
-    ``n k`` assignments."""
+    router sends here (``n k held / n_experts``; never more than all ``n k``
+    assignments) — one trip most updates —, and at most ``WALK_ROWS`` where
+    the ``(n, d)`` float32 result the trips add into is larger than
+    ``ONE_TRIP_BYTES``: many short trips."""
     tiles = -(-2 * n * k * held // (n_experts * ROW_TILE))
-    return ROW_TILE * max(1, min(tiles, -(-n * k // ROW_TILE)))
+    rows = ROW_TILE * max(1, min(tiles, -(-n * k // ROW_TILE)))
+    return rows if 4 * n * d <= ONE_TRIP_BYTES else min(rows, WALK_ROWS)
 
 
 def route_stats(choice, first: int, held: int, chunk: int) -> dict:
@@ -219,8 +262,28 @@ def grouped_grads(lhs, rhs, sizes, g, acc, kernel: tuple[bool, bool] | None = No
 
 
 # ------------------------------------------------------------------- the walk
-def _relu2(x):
-    return jnp.square(jax.nn.relu(x))
+# An expert form: its hidden rows from the products of its first projections
+# (one a leaf of ``w_in``), and those products' cotangents from the hidden
+# rows' cotangent ``t``.
+def _relu2(pre):
+    return jnp.square(jax.nn.relu(pre[0]))
+
+
+def _relu2_bwd(pre, t):
+    return (t * 2.0 * jax.nn.relu(pre[0]),)
+
+
+def _reglu(pre):
+    gate, up = pre
+    return jax.nn.relu(gate) * up
+
+
+def _reglu_bwd(pre, t):
+    gate, up = pre
+    return jnp.where(gate > 0, t * up, 0.0), t * jax.nn.relu(gate)
+
+
+EXPERT_FORMS = {"relu2": (_relu2, _relu2_bwd), "reglu": (_reglu, _reglu_bwd)}
 
 
 @jax.named_scope("moe_dispatch")
@@ -239,14 +302,16 @@ def _trips(sizes, chunk: int):
     return (jnp.sum(sizes) + chunk - 1) // chunk
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _walk(u, weight, w_in, w_out, order, sizes, chunk: int, kernel):
-    """``u`` (N, d), ``weight`` (N, k) float32, ``w_in`` (H, d, f) and
-    ``w_out`` (H, f, d) in the operands' dtype; ``order`` the assignments
-    (token ``a // k``, slot ``a % k``) sorted by held expert, padded to whole
-    chunks; ``sizes`` (H,) the held experts' rows. (N, d) float32."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _walk(u, weight, w_in, w_out, order, sizes, chunk: int, kernel, form: str):
+    """``u`` (N, d), ``weight`` (N, k) float32, ``w_in`` the form's first
+    projections (a tuple of (H, d, f) leaves) and ``w_out`` (H, f, d) in the
+    operands' dtype; ``order`` the assignments (token ``a // k``, slot
+    ``a % k``) sorted by held expert, padded to whole chunks; ``sizes`` (H,)
+    the held experts' rows; ``form`` a key of ``EXPERT_FORMS``. (N, d) float32."""
     n, k = weight.shape
-    x, flat = u.astype(w_in.dtype), weight.reshape(-1)
+    x, flat = u.astype(w_out.dtype), weight.reshape(-1)
+    act, _ = EXPERT_FORMS[form]
 
     def trip(c, y):
         at, part, live = _trip(c, order, sizes, chunk)
@@ -254,7 +319,7 @@ def _walk(u, weight, w_in, w_out, order, sizes, chunk: int, kernel):
             tok = at // k
             rows = x[tok]
         with jax.named_scope("moe_experts"):
-            hidden = _relu2(grouped_matmul(rows, w_in, part, kernel))
+            hidden = act(tuple(grouped_matmul(rows, w, part, kernel) for w in w_in))
             out = grouped_matmul(hidden.astype(x.dtype), w_out, part, kernel)
         with jax.named_scope("moe_combine"):
             # select, not a product with a zero weight: a row past the held
@@ -266,19 +331,22 @@ def _walk(u, weight, w_in, w_out, order, sizes, chunk: int, kernel):
         0, _trips(sizes, chunk), trip, jnp.zeros((n, u.shape[1]), jnp.float32))
 
 
-def _walk_fwd(u, weight, w_in, w_out, order, sizes, chunk, kernel):
-    return _walk(u, weight, w_in, w_out, order, sizes, chunk, kernel), (
+def _walk_fwd(u, weight, w_in, w_out, order, sizes, chunk, kernel, form):
+    return _walk(u, weight, w_in, w_out, order, sizes, chunk, kernel, form), (
         u, weight, w_in, w_out, order, sizes)
 
 
-def _walk_bwd(chunk, kernel, residual, dy):
-    """The same walk: with ``h = relu(x W_in)^2`` recomputed per trip and
-    ``t = dy W_out^T`` at the chunk's tokens, ``d weight = <h, t>``,
-    ``d W_out += (weight h)^T dy``, ``d W_in += x^T p`` and ``d u += p W_in^T``
-    for ``p = weight t 2 relu(x W_in)``."""
+def _walk_bwd(chunk, kernel, form, residual, dy):
+    """The same walk: with the hidden rows ``h`` recomputed per trip from the
+    first products ``x W_in`` and ``t = dy W_out^T`` at the chunk's tokens,
+    ``d weight = <h, t>``, ``d W_out += (weight h)^T dy``, and per first
+    projection ``d W_in += x^T p`` and ``d u += p W_in^T`` for ``p`` the
+    form's cotangent of that product under ``weight t`` (``relu2``:
+    ``weight t 2 relu(x W_in)``)."""
     u, weight, w_in, w_out, order, sizes = residual
     n, k = weight.shape
-    x, g, flat = u.astype(w_in.dtype), dy.astype(w_in.dtype), weight.reshape(-1)
+    x, g, flat = u.astype(w_out.dtype), dy.astype(w_out.dtype), weight.reshape(-1)
+    act, act_bwd = EXPERT_FORMS[form]
 
     def trip(c, carry):
         d_x, d_flat, d_in, d_out = carry
@@ -289,32 +357,39 @@ def _walk_bwd(chunk, kernel, residual, dy):
         with jax.named_scope("moe_combine"):
             dy_rows, wt = g[tok], flat[at][:, None]
         with jax.named_scope("moe_experts"):
-            pre = grouped_matmul(rows, w_in, part, kernel)
-            hidden = _relu2(pre)
+            pre = tuple(grouped_matmul(rows, w, part, kernel) for w in w_in)
+            hidden = act(pre)
             t, d_out = grouped_grads(
                 (wt * hidden).astype(x.dtype), w_out, part, dy_rows, d_out, kernel)
             t = t.astype(jnp.float32)
-            p = (wt * t * 2.0 * jax.nn.relu(pre)).astype(x.dtype)
-            d_rows, d_in = grouped_grads(rows, w_in, part, p, d_in, kernel)
+            d_rows, d_in = [], list(d_in)
+            for i, p in enumerate(act_bwd(pre, wt * t)):
+                d, d_in[i] = grouped_grads(
+                    rows, w_in[i], part, p.astype(x.dtype), d_in[i], kernel)
+                d_rows.append(d)
+            d_in = tuple(d_in)
         with jax.named_scope("moe_combine"):
             d_wt = jnp.sum(hidden.astype(jnp.float32) * t, axis=-1)
             # distinct indices all: a dead row's lies past the end and is dropped
             d_flat = d_flat.at[jnp.where(live, at, n * k + jnp.arange(chunk))].set(
                 d_wt, mode="drop", unique_indices=True)
         with jax.named_scope("moe_dispatch"):
-            d_x = d_x.at[jnp.where(live, tok, n)].add(
-                jnp.where(live[:, None], d_rows.astype(jnp.float32), 0.0), mode="drop")
+            d_x = d_x.at[jnp.where(live, tok, n)].add(jnp.where(
+                live[:, None], functools.reduce(jnp.add, [d.astype(jnp.float32) for d in d_rows]),
+                0.0), mode="drop")
         return d_x, d_flat, d_in, d_out
 
     d_x, d_flat, d_in, d_out = jax.lax.fori_loop(0, _trips(sizes, chunk), trip, (
         jnp.zeros(u.shape, jnp.float32), jnp.zeros(n * k, jnp.float32),
-        jnp.zeros(w_in.shape, jnp.float32), jnp.zeros(w_out.shape, jnp.float32)))
+        tuple(jnp.zeros(w.shape, jnp.float32) for w in w_in),
+        jnp.zeros(w_out.shape, jnp.float32)))
     # the barrier ties the weights' gradients, cast to the operands' dtype as
     # one product's would be, to the tokens': without it the casts are fused
     # into the optimizer's pass and every layer's float32 accumulators live
     # through the whole backward
     with jax.named_scope("moe_experts"):
-        d_in, d_out = d_in.astype(w_in.dtype), d_out.astype(w_out.dtype)
+        d_in = tuple(d.astype(w.dtype) for d, w in zip(d_in, w_in))
+        d_out = d_out.astype(w_out.dtype)
     d_x, d_in, d_out = jax.lax.optimization_barrier((d_x.astype(u.dtype), d_in, d_out))
     return d_x, d_flat.reshape(n, k).astype(weight.dtype), d_in, d_out, None, None
 
@@ -323,10 +398,12 @@ _walk.defvjp(_walk_fwd, _walk_bwd)
 
 
 def routed_experts(u, choice, weight, w_in, w_out, first: int, dtype=None, kernel=None,
-                   chunk: int | None = None):
+                   chunk: int | None = None, w_gate=None):
     """The held experts' part of the block's output for ``u`` (N, d):
     ``sum over chosen and held e of weight_e * relu(u W_in[e])^2 W_out[e]``,
-    float32. ``choice`` (N, k) global expert ids, ``weight`` (N, k);
+    float32 — or, with ``w_gate`` (H, d, f), of the gated
+    ``weight_e * (relu(u W_gate[e]) * u W_in[e]) W_out[e]``.
+    ``choice`` (N, k) global expert ids, ``weight`` (N, k);
     ``w_in`` (H, d, f), ``w_out`` (H, f, d): experts ``first .. first + H``.
     Matmul operands in ``dtype``. ``chunk``: the rows a trip of the walk takes
     (``chunk_rows``, a ``ROW_TILE`` multiple); every assignment where the
@@ -334,19 +411,23 @@ def routed_experts(u, choice, weight, w_in, w_out, first: int, dtype=None, kerne
     n, k = choice.shape
     held = w_in.shape[0]
     cd = dtype or jnp.float32
-    chunk = chunk or chunk_rows(n, k, held, held)
+    chunk = chunk or chunk_rows(n, k, held, held, u.shape[-1])
     with jax.named_scope("moe_dispatch"):
         local = (choice - first).reshape(-1)
         key = jnp.where((local >= 0) & (local < held), local, held)  # absent experts' last
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0).astype(jnp.int32)
         order = jnp.pad(order, (0, (-n * k) % chunk))  # whole chunks; the pad is never live
+    first_projections = (w_in,) if w_gate is None else (w_gate, w_in)
     with jax.named_scope("moe_experts"):
-        w_in, w_out = w_in.astype(cd), w_out.astype(cd)
-    return _walk(u, weight.astype(jnp.float32), w_in, w_out, order, sizes, chunk, kernel)
+        first_projections = tuple(w.astype(cd) for w in first_projections)
+        w_out = w_out.astype(cd)
+    return _walk(
+        u, weight.astype(jnp.float32), first_projections, w_out, order, sizes, chunk, kernel,
+        "relu2" if w_gate is None else "reglu")
 
 
-def routed_experts_dense(u, choice, weight, w_in, w_out, first: int, dtype=None):
+def routed_experts_dense(u, choice, weight, w_in, w_out, first: int, dtype=None, w_gate=None):
     """The same sum with every held expert applied to every row under a mask:
     the acting form (a few rows a step), and the sparse form's oracle."""
     held = w_in.shape[0]
@@ -355,8 +436,10 @@ def routed_experts_dense(u, choice, weight, w_in, w_out, first: int, dtype=None)
         jnp.where(choice[..., None] - first == jnp.arange(held), weight[..., None], 0.0),
         axis=-2,
     )  # (N, H): the weight of held expert e for this row, 0 where not chosen
-    hidden = _relu2(jnp.einsum(
-        "nd,edf->nef", u.astype(cd), w_in.astype(cd), preferred_element_type=jnp.float32))
+    act, _ = EXPERT_FORMS["relu2" if w_gate is None else "reglu"]
+    hidden = act(tuple(
+        jnp.einsum("nd,edf->nef", u.astype(cd), w.astype(cd), preferred_element_type=jnp.float32)
+        for w in ((w_in,) if w_gate is None else (w_gate, w_in))))
     out = jnp.einsum(
         "nef,efd->ned", hidden.astype(cd), w_out.astype(cd), preferred_element_type=jnp.float32)
     return jnp.einsum("ne,ned->nd", gate, out)

@@ -94,25 +94,34 @@ def _splash_block_sizes(T: int):
     )
 
 
-def _splash_mha(q, k, v, seg, *, causal, scale, block_sizes, interpret=False):
+def _splash_mha(q, k, v, seg, *, causal, scale, block_sizes, interpret=False, window=None):
     """The splash kernel on this module's layout: q (B, T, H, D), k and v
     (B, T, Hkv, D) with ``H % Hkv == 0`` (every key/value head serves
     ``H // Hkv`` consecutive query heads, unrepeated), seg (B, T). Causal by
     index plus same-segment, built per trace: the block-sparse mask info is
-    numpy work on a (T / tile)^2 grid. The kernel takes no softmax scale, so
-    ``scale`` is folded into q first — exact in bf16 for a power of two (both
-    registered cells: 1/8 and 1/64); a general scale rounds q once more.
+    numpy work on a (T / tile)^2 grid. With ``window`` (causal only) a query
+    sees the ``window`` keys that end with itself (the library's ``LocalMask``):
+    the mask info then names the tiles inside the band alone, so the kernel
+    never visits a tile that lies wholly behind it. The kernel takes no
+    softmax scale, so ``scale`` is folded into q first — exact in bf16 for a
+    power of two (tf-longctx: 1/8; granite: 1/64); a general scale (head size
+    128: 128^-0.5) rounds q once more.
     ``interpret=True`` runs the same construction on the CPU (tier-1 tests)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         CausalMask,
         FullMask,
+        LocalMask,
         MultiHeadMask,
         SegmentIds,
         make_splash_mha,
     )
 
     T, H = q.shape[1], q.shape[2]
-    head_mask = (CausalMask if causal else FullMask)((T, T))
+    if window is None:
+        head_mask = (CausalMask if causal else FullMask)((T, T))
+    else:
+        assert causal, "a window is the causal one: the keys that end with the query"
+        head_mask = LocalMask((T, T), window_size=(window - 1, 0), offset=0)
     splash = make_splash_mha(
         MultiHeadMask([head_mask] * H),
         block_sizes=block_sizes,
@@ -142,14 +151,17 @@ def make_sp_mesh(n_data: int, n_seq: int, devices=None) -> Mesh:
 
 
 # --------------------------------------------------------------------- core
-def _masked_block_scores(q, k, q_pos, k_pos, q_seg, k_seg, scale, causal):
+def _masked_block_scores(q, k, q_pos, k_pos, q_seg, k_seg, scale, causal, window=None):
     """(B, H, Tq, Tk) masked logits for one Q-block/K-block pair. Always
     float32: bf16 inputs hit the MXU, accumulation stays full-precision
-    (the canonical TPU mixed-precision pattern)."""
+    (the canonical TPU mixed-precision pattern). ``window``: a query sees
+    the keys at most ``window - 1`` positions behind it."""
     scores = _qk_scores_dot(q, k, _contract_dtype(q)) * jnp.float32(scale)
     mask = q_seg[:, None, :, None] == k_seg[:, None, None, :]
     if causal:
         mask &= q_pos[:, None, :, None] >= k_pos[:, None, None, :]
+    if window is not None:
+        mask &= q_pos[:, None, :, None] - k_pos[:, None, None, :] < window
     return jnp.where(mask, scores, _NEG_INF)
 
 
@@ -456,12 +468,15 @@ def full_attention(
     axis_name: str | None = None,
     causal: bool = True,
     sm_scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Single-device reference implementation (same contract, no sharding).
     This is also the implementation the transformer uses when no seq mesh is
-    in scope. ``sm_scale``: the softmax scale, ``1/sqrt(head_dim)`` if None."""
+    in scope. ``sm_scale``: the softmax scale, ``1/sqrt(head_dim)`` if None.
+    ``window``: a sliding window of that many keys, the query's own included
+    (``q_pos - k_pos < window``); None = the whole episode so far."""
     scale = 1.0 / np.sqrt(q.shape[-1]) if sm_scale is None else sm_scale
-    scores = _masked_block_scores(q, k, q_pos, q_pos, seg, seg, scale, causal)
+    scores = _masked_block_scores(q, k, q_pos, q_pos, seg, seg, scale, causal, window)
     p = jax.nn.softmax(scores, axis=-1)
     # Output in q.dtype, matching ring/blockwise (which cast their f32
     # accumulators back); for f32 inputs this is exactly the old behavior.
@@ -671,6 +686,7 @@ def flash_attention_tpu(
     axis_name: str | None = None,
     causal: bool = True,
     sm_scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Single-device fused attention via the splash kernel that ships with
     JAX (``jax.experimental.pallas.ops.tpu.splash_attention``: Mosaic forward
@@ -685,6 +701,10 @@ def flash_attention_tpu(
     the segment mask kills every cross-segment pair anyway
     (``tests/test_sequence_parallel.py::TestFlashImpl`` runs this kernel in
     interpret mode against :func:`full_attention`, forward and gradients).
+    ``window`` (a sliding window of that many keys, the query's own included)
+    is masked by index too, ``q - k < window``, against ``q_pos - k_pos <
+    window`` in :func:`full_attention`: the same pairs wherever positions step
+    by one inside a segment, as every caller's do.
 
     Off-TPU (CPU tests, the virtual mesh), or at a length the kernel cannot
     tile (``T % 128``), this falls back to :func:`full_attention` —
@@ -711,13 +731,15 @@ def flash_attention_tpu(
         rep = q.shape[2] // k.shape[2]
         if rep > 1:
             k, v = (jnp.repeat(x, rep, axis=2) for x in (k, v))
-        return full_attention(q, k, v, q_pos, seg, causal=causal, sm_scale=sm_scale)
+        return full_attention(
+            q, k, v, q_pos, seg, causal=causal, sm_scale=sm_scale, window=window
+        )
     scale = float(1.0 / np.sqrt(q.shape[-1]) if sm_scale is None else sm_scale)
 
     @jax.named_scope("attn_flash_pallas")
     def kernel(q, k, v, seg):
         return _splash_mha(
-            q, k, v, seg, causal=causal, scale=scale, block_sizes=bs
+            q, k, v, seg, causal=causal, scale=scale, block_sizes=bs, window=window
         )
 
     if mesh is None:

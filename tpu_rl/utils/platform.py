@@ -35,7 +35,8 @@ _CACHE_DIR = os.path.join(
 # module answers both "what did the gate choose" and "did Mosaic get it".
 _MOSAIC_TARGET = "tpu_custom_call"
 _PATH_SCOPES = re.compile(
-    r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_full|ssd_scan|ssd_pallas|moe_experts|moe_gmm_pallas)\b"
+    r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_full|attn_window|attn_global"
+    r"|attn_rope|ssd_scan|ssd_pallas|moe_experts|moe_gmm_pallas)\b"
 )
 
 
