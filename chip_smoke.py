@@ -409,14 +409,17 @@ def kernel_checks(
     # trip of the walk) and once with most assignments held (several); then
     # smallthinker-21b-a3b's gated block at an eighth of its tokens, likewise,
     # and the walk its cell times: a layer's 49,152 held rows (a quarter of the
-    # tokens, every assignment held) in the cell's own chunks of 4,096 rows,
-    # twelve trips forward and backward. A ninth entry is the chunk where it
-    # is not ``moe.chunk_rows``'s for the row's own shapes.
+    # tokens, every assignment held) in the cell's own trips of ``WALK_ROWS``,
+    # forward and backward.
     moe_shapes=(
         (4096, 6, 8, 128, 2688, 1856, 0.0, False), (4096, 6, 8, 128, 2688, 1856, 0.75, False),
         (4096, 6, 16, 64, 2560, 768, 0.0, True), (4096, 6, 16, 64, 2560, 768, 0.75, True),
-        (8192, 6, 16, 64, 2560, 768, 1.0, True, 4096),
+        (8192, 6, 16, 64, 2560, 768, 1.0, True),
     ),
+    # (rows of a trip, tokens, width, experts held): the walk's row-add at the
+    # two expert cells' shapes (smallthinker-21b-a3b's result at a 4,096-row
+    # trip, nemotron-3-nano-30b-a3b's one trip), ragged groups, a dead tail
+    row_add_shapes=((4096, 32768, 2560, 16), (12288, 16384, 2688, 8)),
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -433,7 +436,15 @@ def kernel_checks(
     f32 = lambda *s: jnp.asarray(rng.standard_normal(s).astype(np.float32))  # noqa: E731
     rows = []
 
-    def case(name, fn, ref, args, tol, tol_same, mosaic=True):
+    def best_ms(f, args, repeats=5):
+        times = []
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(*args))
+            times.append(time.perf_counter() - t0)
+        return round(1e3 * min(times), 3)
+
+    def case(name, fn, ref, args, tol, tol_same, mosaic=True, timed=False):
         row = {"kernel": name, "tol": tol, "tol_vs_default": tol_same}
         t0 = time.time()
         try:
@@ -447,6 +458,8 @@ def kernel_checks(
                 err_vs_default=_rel_err(got, jax.jit(ref)(*args)),
                 mosaic_calls=n_mosaic,
             )
+            if timed:  # host clock over a device round trip: the two beside each other
+                row.update(ms=best_ms(jfn, args), ms_ref=best_ms(jax.jit(ref), args))
             row["ok"] = (
                 row["err"] <= tol
                 and row["err_vs_default"] <= tol_same
@@ -629,14 +642,39 @@ def kernel_checks(
             gmm_grads((True, interpret), jnp.bfloat16), gmm_grads((False, False), jnp.float32),
             (lhs, rhs), TOL_BF16, TOL_BF16,
         )
-    # ---- the walk over the held assignments vs every held expert under a mask
-    from tpu_rl.ops import moe
+    # ---- the walk's row-add vs XLA's scatter-add, eight trips into one result
+    from tpu_rl.ops import moe, pallas_moe
 
-    for n, k, held, total, d, f, held_only, gated, *chunk in moe_shapes:
+    for C, n, d, G in row_add_shapes:
+        held = C - C // 7  # the rest of the last chunk is dead and holds NaN
+        cuts = np.sort(rng.integers(0, held + 1, G - 1))
+        part = np.diff(np.concatenate([[0], cuts, [held]]))
+        tok = np.concatenate([np.sort(rng.permutation(n)[:p]) for p in part] + [np.zeros(C - held)])
+        tok, part = jnp.asarray(tok, jnp.int32), jnp.asarray(part, jnp.int32)
+        live = jnp.arange(C) < held
+        add = jnp.where(live[:, None], f32(C, d), jnp.nan)
+
+        def added(adder, add):
+            def trip(c, y):
+                rows = add * (1.0 + c)
+                if not adder[0]:  # the scatter-add reads every row: the walk selects for it
+                    rows = jnp.where(live[:, None], rows, 0.0)
+                return moe.add_rows(y, rows, tok, part, live, adder)
+            return jax.lax.fori_loop(0, 8, trip, moe._result((n, d), adder)).reshape(n, d)
+
+        case(
+            f"row_add {C} rows ({held} live) into {n}x{d} f32, {G} groups, 8 trips "
+            f"(tile {pallas_moe.tile_rows(C, d)})",
+            functools.partial(added, (True, interpret)), functools.partial(added, (False, False)),
+            (add,), TOL_ACT_SAME, TOL_ACT_SAME, timed=True,
+        )
+    # ---- the walk over the held assignments vs every held expert under a mask
+
+    for n, k, held, total, d, f, held_only, gated in moe_shapes:
         fair = np.stack([rng.permutation(total)[:k] for _ in range(n)])
         here = np.stack([rng.permutation(held)[:k] for _ in range(n)])
         choice = jnp.asarray(np.where(rng.random((n, 1)) < held_only, here, fair), jnp.int32)
-        chunk = chunk[0] if chunk else moe.chunk_rows(n, k, held, total, d)
+        chunk = moe.chunk_rows(n, k, held, total)
         trips = int(moe.route_stats(choice, 0, held, chunk)["chunks"])
         mix = f32(n, d)
         # The gate's relu has a kink: where rounding an operand to bf16 moves a

@@ -62,15 +62,18 @@ def test_kernel_checks_pass_tiny_interpreted():
         gmm_shapes=((512, 4, 64, 48),),
         moe_shapes=((300, 3, 4, 16, 64, 48, 0.0, False), (300, 3, 4, 16, 64, 48, 0.75, False),
                     (300, 3, 4, 16, 64, 48, 0.0, True), (300, 3, 4, 16, 64, 48, 0.75, True),
-                    (300, 3, 4, 16, 64, 48, 1.0, True, 256)),
+                    (300, 3, 4, 16, 64, 48, 1.0, True)),
+        row_add_shapes=((512, 300, 128, 4),),
         interpret=True,
     )
-    assert len(rows) == 15
+    assert len(rows) == 16
+    assert rows[10]["kernel"].startswith("row_add 512 rows (439 live) into 300x128")
+    assert rows[10]["ms"] > 0 and rows[10]["ms_ref"] > 0 and rows[10]["err"] == 0
     assert "/window48 " in rows[6]["kernel"]
     for one, several in (rows[-5:-3], rows[-3:-1]):
         assert "1 trip)" in one["kernel"] and "2 trips)" in several["kernel"]
     assert "gated" in rows[-2]["kernel"] and "gated" not in rows[-4]["kernel"]
-    assert "(chunk 256, 4 trips)" in rows[-1]["kernel"]  # the chunk handed over, every row held
+    assert "(chunk 512, 2 trips)" in rows[-1]["kernel"]  # every row held: the doubled fair share twice
     assert all(r["ok"] for r in rows), rows
 
 

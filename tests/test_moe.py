@@ -4,16 +4,18 @@ Pallas kernel in the interpreter against the body, both operands' gradients),
 and the walk over the sorted held assignments in row chunks (gather, grouped
 products, add into the tokens) against every held expert applied densely
 under a mask: under forced imbalance, at every count of trips, with groups
-that straddle a chunk's edge."""
+that straddle a chunk's edge; and the walk's row-add kernel
+(``tpu_rl/ops/pallas_moe.py``) in the interpreter against XLA's scatter-add."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_rl.ops import moe
+from tpu_rl.ops import moe, pallas_moe
 
 D, F, HELD, TOTAL, K = 32, 24, 4, 16, 3
+WIDE = 128  # a width the row-add kernel takes: a lane multiple
 
 
 def close(got, want, tol=1e-5):
@@ -105,10 +107,10 @@ def test_the_bias_moves_a_choice_and_gets_no_gradient():
     assert not np.asarray(d_bias).any() and np.abs(np.asarray(d_kernel)).max() > 1e-4
 
 
-def expert_weights(seed: int):
+def expert_weights(seed: int, d: int = D):
     rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.standard_normal((HELD, D, F)) / np.sqrt(D), jnp.float32),
-            jnp.asarray(rng.standard_normal((HELD, F, D)) / np.sqrt(F), jnp.float32))
+    return (jnp.asarray(rng.standard_normal((HELD, d, F)) / np.sqrt(d), jnp.float32),
+            jnp.asarray(rng.standard_normal((HELD, F, d)) / np.sqrt(F), jnp.float32))
 
 
 def assignments(kind: str, n: int, first: int):
@@ -146,10 +148,10 @@ def sparse_and_dense(u, choice, weight, w_in, w_out, first, kernel, chunk):
             out_and_grads(moe.routed_experts_dense))
 
 
-def assert_sparse_is_dense(choice, weight, first, kernel, chunk):
-    u = jnp.asarray(np.random.default_rng(7).standard_normal((choice.shape[0], D)), jnp.float32)
+def assert_sparse_is_dense(choice, weight, first, kernel, chunk, d=D):
+    u = jnp.asarray(np.random.default_rng(7).standard_normal((choice.shape[0], d)), jnp.float32)
     (got, grads), (want, ref_grads) = sparse_and_dense(
-        u, choice, weight, *expert_weights(8), first, kernel, chunk)
+        u, choice, weight, *expert_weights(8, d), first, kernel, chunk)
     close(got, want, 1e-4)
     for g, r in zip(grads, ref_grads):
         close(g, r, 1e-4)
@@ -235,26 +237,26 @@ def test_a_trip_takes_its_part_of_every_group():
 
 
 def test_the_chunk_is_twice_the_rows_a_fair_router_sends():
-    # the cell: 16,384 tokens of width 2,688, 6 choices, 8 held of 128 -> 6,144 rows expected
-    assert moe.chunk_rows(16384, 6, 8, 128, 2688) == 12288 == 48 * moe.ROW_TILE
-    assert 6 * 16384 // moe.chunk_rows(16384, 6, 8, 128, 2688) == 8  # what all-held costs
-    assert moe.chunk_rows(2048, 6, 128, 128, 2688) == 6 * 2048  # every expert held: every row
-    assert moe.chunk_rows(40, 3, 4, 16, 64) == moe.ROW_TILE  # never less than a row tile
-    assert moe.chunk_rows(1000, 3, 4, 16, 64) == 1536  # 750 expected, doubled, whole tiles
+    # the cell: 16,384 tokens, 6 choices, 8 held of 128 -> 6,144 rows expected
+    assert moe.chunk_rows(16384, 6, 8, 128) == 12288 == 48 * moe.ROW_TILE
+    assert 6 * 16384 // moe.chunk_rows(16384, 6, 8, 128) == 8  # what all-held costs
+    assert moe.chunk_rows(2048, 6, 128, 128) == 6 * 2048  # every expert held: every row
+    assert moe.chunk_rows(40, 3, 4, 16) == moe.ROW_TILE  # never less than a row tile
+    assert moe.chunk_rows(1000, 3, 4, 16) == 1536  # 750 expected, doubled, whole tiles
 
 
-@pytest.mark.parametrize("n, d, held, total, want", [
-    (16384, 2688, 8, 128, 12288),  # 176 MB: nemotron's cell, its one trip
-    (20480, 2688, 8, 128, 15360),  # 220 MB: a fifth window keeps one trip (3.4 ms measured)
-    (65536, 1024, 16, 64, 196608),  # 256 MiB to the byte: still as the routing suggests
-    (32768, 2560, 16, 64, 4096),  # 335 MB: smallthinker's cell, 98,304 by the doubled share
-    (32768, 2560, 1, 128, 3072),  # ... where the share is less than a short trip, the share
-], ids=["nemotron", "five-windows", "at-the-bound", "smallthinker", "large-result-small-share"])
-def test_a_large_result_is_walked_in_short_trips(n, d, held, total, want):
-    """The bound is on the (n, d) float32 result the trips add into, where the
-    chip's scatter-add changed speed (``moe.ONE_TRIP_BYTES``), not on the rows."""
-    assert moe.chunk_rows(n, 6, held, total, d) == want
-    assert want <= moe.WALK_ROWS or 4 * n * d <= moe.ONE_TRIP_BYTES
+@pytest.mark.parametrize("n, held, total, want", [
+    (16384, 8, 128, 12288),  # nemotron's cell: the doubled share, its one trip
+    (20480, 8, 128, min(15360, moe.WALK_ROWS)),  # a fifth window: one trip, if the cap allows
+    (65536, 16, 64, moe.WALK_ROWS),  # 196,608 by the doubled share
+    (32768, 16, 64, moe.WALK_ROWS),  # smallthinker's cell: 98,304 by the doubled share
+    (32768, 1, 128, 3072),  # ... where the share is less than the cap, the share
+], ids=["nemotron", "five-windows", "many-tokens", "smallthinker", "small-share"])
+def test_one_cap_bounds_a_trip(n, held, total, want):
+    """``WALK_ROWS`` alone caps a trip, whatever the size of the result the
+    trips add into: under it a trip is as tall as the routing suggests."""
+    assert moe.chunk_rows(n, 6, held, total) == want <= moe.WALK_ROWS
+    assert want % moe.ROW_TILE == 0 and not hasattr(moe, "ONE_TRIP_BYTES")
 
 
 def test_route_stats_count_rows_per_held_expert():
@@ -265,22 +267,25 @@ def test_route_stats_count_rows_per_held_expert():
         "chunks": 2.0})
 
 
-def test_what_an_unwritten_row_holds_goes_nowhere(monkeypatch):
+@pytest.mark.parametrize("d, kernel", [(D, None), (WIDE, (True, True))],
+                         ids=["scatter-add", "row-add-kernel"])
+def test_what_an_unwritten_row_holds_goes_nowhere(monkeypatch, d, kernel):
     """The kernel never writes the rows past the held total, so in the last
     chunk they may hold anything. With NaN put there after each product and
     each transposed product, the block's output and every gradient are what
     they were: the combine and the backward select live rows, they do not
-    multiply by zero."""
+    multiply by zero — and the row-add kernel ends with the last live row."""
     n, first = 400, 8
-    u = jnp.asarray(np.random.default_rng(7).standard_normal((n, D)), jnp.float32)
-    w_in, w_out = expert_weights(8)
+    u = jnp.asarray(np.random.default_rng(7).standard_normal((n, d)), jnp.float32)
+    w_in, w_out = expert_weights(8, d)
+    assert moe._row_add_gate(CHUNK, d, kernel)[0] == (kernel is not None)
     choice, weight = assignments("random", n, first)
     assert float(moe.route_stats(choice, first, HELD, CHUNK)["rows"]) % CHUNK  # a dead tail
 
     def value_and_grads():
         return jax.value_and_grad(
             lambda u, wt, a, b: jnp.sum(
-                moe.routed_experts(u, choice, wt, a, b, first, chunk=CHUNK) ** 2),
+                moe.routed_experts(u, choice, wt, a, b, first, kernel=kernel, chunk=CHUNK) ** 2),
             argnums=(0, 1, 2, 3))(u, weight, w_in, w_out)
 
     want, ref_grads = value_and_grads()
@@ -303,6 +308,103 @@ def test_what_an_unwritten_row_holds_goes_nowhere(monkeypatch):
     for g, r in zip(grads, ref_grads):
         assert np.isfinite(np.asarray(g)).all()
         close(g, r)
+
+
+# ------------------------------------------------------------- the row-add
+ROWS = 32  # a trip of the cases below; the kernel's tiles are 8 rows there
+
+
+def tokens(rng, sizes, n):
+    """Per group ascending distinct tokens, as the stable sort leaves them."""
+    return [np.sort(rng.permutation(n)[:size]) for size in sizes]
+
+
+def row_add_case(name: str):
+    """(tokens of each group, width): the sorted held assignments of one
+    layer's routing with ``k = 1``, walked in trips of ``ROWS``."""
+    rng = np.random.default_rng(12)
+    if name == "one-group":
+        return tokens(rng, [20], 64), WIDE
+    if name == "a-token-twice-either-side-of-a-group-edge-in-one-tile":
+        return [np.asarray([1, 3, 4, 7, 9]), np.asarray([7, 9, 30]), np.asarray([9])], WIDE
+    if name == "empty-groups":
+        return tokens(rng, [0, 9, 0, 0, 7, 0], 64), WIDE
+    if name == "a-group-straddles-a-chunk-edge":
+        return tokens(rng, [10, 30, 5, 40], 64), WIDE
+    if name == "dead-rows-hold-nan":
+        return tokens(rng, [3, 9], 64), WIDE
+    assert name == "a-width-the-gate-refuses"
+    return tokens(rng, [10, 30, 5], 64), D
+
+
+@pytest.mark.parametrize("name", [
+    "one-group", "a-token-twice-either-side-of-a-group-edge-in-one-tile", "empty-groups",
+    "a-group-straddles-a-chunk-edge", "dead-rows-hold-nan", "a-width-the-gate-refuses"])
+def test_the_row_add_kernel_is_xlas_scatter_add(name):
+    """``add_rows`` trip by trip as the walk calls it, the kernel in the
+    interpreter against ``.at[].add(mode="drop")``: every dead row of a last
+    chunk holds NaN (and token 0), which must reach nothing."""
+    groups, d = row_add_case(name)
+    n, sizes = 64, jnp.asarray([len(g) for g in groups], jnp.int32)
+    held = int(sizes.sum())
+    trips = -(-held // ROWS)
+    order = jnp.zeros(trips * ROWS, jnp.int32).at[:held].set(jnp.asarray(np.concatenate(groups)))
+    rng = np.random.default_rng(13)
+    rows = jnp.asarray(rng.standard_normal((trips * ROWS, d)), jnp.float32).at[held:].set(jnp.nan)
+    start = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+    chosen = moe._row_add_gate(ROWS, d, (True, True))
+    assert chosen == (d == WIDE, True) and moe._row_add_gate(ROWS, d, (False, False))[0] is False
+    assert moe._row_add_gate(ROWS, WIDE, None) == (False, False)  # a CPU: the gate's own answer
+
+    def walked(adder):
+        y = start.reshape(moe._result((n, d), adder).shape)
+        for c in range(trips):
+            tok, part, live = moe._trip(c, order, sizes, ROWS)
+            add = jnp.where(live[:, None], rows[c * ROWS:(c + 1) * ROWS], 0.0)
+            if adder[0]:  # the kernel is handed the rows as they are, NaN and all
+                y = pallas_moe.row_add(y, rows[c * ROWS:(c + 1) * ROWS], tok, part, True, tile=8)
+            else:
+                y = moe.add_rows(y, add, tok, part, live, adder)
+        return y.reshape(n, d)
+
+    want = np.asarray(start).copy()
+    np.add.at(want, np.concatenate(groups), np.asarray(rows[:held]))
+    close(walked((False, False)), want, 1e-6)
+    close(walked(chosen), want, 1e-6)
+    if name.startswith("a-token-twice"):
+        assert 7 in groups[0][:8] and 7 in groups[1] and len(groups[0]) < 8  # one tile, two groups
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["relu2", "reglu"])
+def test_the_walk_with_the_row_add_kernel_is_the_walk_without(gated):
+    """Output and every gradient of ``routed_experts`` at a width the kernel
+    takes, the kernels in the interpreter against ``ragged_dot`` and XLA's
+    scatter-add, over several trips with a dead tail."""
+    n, first = 400, 8
+    choice, weight = assignments("random", n, first)
+    rng = np.random.default_rng(14)
+    u, probe = (jnp.asarray(rng.standard_normal((n, WIDE)), jnp.float32) for _ in range(2))
+    w_in, w_out = expert_weights(8, WIDE)
+    w_gate = expert_weights(15, WIDE)[0] if gated else None
+    assert float(moe.route_stats(choice, first, HELD, CHUNK)["chunks"]) >= 2
+
+    def value_and_grads(kernel):
+        assert moe._row_add_gate(CHUNK, WIDE, kernel)[0] == kernel[0]
+
+        def loss(u, wt, a, b, g):
+            y = moe.routed_experts(u, choice, wt, a, b, first, kernel=kernel, chunk=CHUNK, w_gate=g)
+            return jnp.sum(probe * y), y
+
+        argnums = (0, 1, 2, 3, 4) if gated else (0, 1, 2, 3)
+        (_, y), grads = jax.jit(jax.value_and_grad(loss, argnums=argnums, has_aux=True))(
+            u, weight, w_in, w_out, w_gate)
+        return y, grads
+
+    (got, grads), (want, ref_grads) = value_and_grads((True, True)), value_and_grads((False, False))
+    close(got, want, 1e-5)
+    for g, r in zip(grads, ref_grads):
+        assert float(jnp.abs(r).max()) > 0
+        close(g, r, 1e-5 * (1.0 + float(jnp.abs(r).max())))
 
 
 def test_the_walk_traces_no_conditional():

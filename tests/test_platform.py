@@ -221,3 +221,29 @@ def test_compile_cache_placement(tmp_path):
 
     _, ret, cfg, _ = _cache_probe(REPO, JAX_PLATFORMS="cpu")
     assert ret == cfg == "None"
+
+
+@pytest.mark.parametrize("mode, width, found", [
+    ("interpret", 128, True), ("off", 128, False), ("auto", 128, False), ("interpret", 96, False),
+], ids=["kernel", "off", "a-cpu", "a-width-the-kernel-refuses"])
+def test_a_lowered_walk_names_the_row_add_kernel_where_the_gate_took_it(monkeypatch, mode, width, found):
+    """``moe_row_add_pallas`` is in a lowered module's paths exactly when
+    ``ops/moe.py``'s gate handed the walk's row-add to the Pallas kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_rl.models import cells
+    from tpu_rl.ops import moe
+    from tpu_rl.utils.platform import program_paths
+
+    monkeypatch.setattr(cells, "_PALLAS_MODE", mode)
+    n, k, held, f = 64, 2, 2, 64
+    choice = jnp.arange(n * k, dtype=jnp.int32).reshape(n, k) % 4
+
+    def loss(u, weight, w_in, w_out):
+        return jnp.sum(moe.routed_experts(u, choice, weight, w_in, w_out, 0, chunk=256))
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        jnp.zeros((n, width)), jnp.ones((n, k)), jnp.zeros((held, width, f)),
+        jnp.zeros((held, f, width)))
+    assert ("moe_row_add_pallas" in program_paths(lowered)["paths"]) is found

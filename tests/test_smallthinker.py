@@ -419,7 +419,7 @@ def test_the_gated_walk_against_the_dense_form(kernel, chunk):
     u, choice, weight, (w_gate, w_in, w_out) = gated_case(60)
     probe = jnp.asarray(np.random.default_rng(61).standard_normal((N, D)), jnp.float32)
     held_rows = int(((choice >= FIRST) & (choice < FIRST + HELD)).sum())
-    trips = -(-held_rows // (chunk or moe.chunk_rows(N, K, HELD, HELD, D)))
+    trips = -(-held_rows // (chunk or moe.chunk_rows(N, K, HELD, HELD)))
     assert (trips == 1) if chunk is None else (trips >= 2)
 
     def walked(u, weight, w_gate, w_in, w_out):

@@ -24,8 +24,8 @@ rank of: ``published_n_routed_experts`` experts over ``chips`` ranks, this one
 shared expert's output and the held experts' part of the routed sum; the
 absent experts' part is left out (no exchange, nothing in its place). Without
 the key every expert is held. In training the held assignments, sorted by
-expert, are walked in chunks of ``moe.chunk_rows(tokens, top_k, held, experts,
-hidden)`` rows — twice what a fair router sends this rank — as often as the routing's
+expert, are walked in chunks of ``moe.chunk_rows(tokens, top_k, held, experts)`` rows —
+twice what a fair router sends this rank, under one cap — as often as the routing's
 own counts say: once most updates for a rank that holds a sixteenth, and as
 often as it takes, dropping nothing, when more arrives.
 
@@ -152,7 +152,7 @@ class ExpertBlock(nn.Module):
         than the experts compute on."""
         rows = u.reshape(-1, self.hidden)
         choice, weight = self._route(rows if scored is None else scored.reshape(rows.shape))
-        chunk = moe.chunk_rows(rows.shape[0], self.top_k, self.held, self.n_experts, self.hidden)
+        chunk = moe.chunk_rows(rows.shape[0], self.top_k, self.held, self.n_experts)
         routed = moe.routed_experts(
             rows, choice, weight, self.w_in, self.w_out, self.first, self.dtype, chunk=chunk,
             w_gate=self.w_gate)

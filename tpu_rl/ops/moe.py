@@ -44,9 +44,8 @@ float32 result at their tokens. Every buffer, gather, cast and activation is
 ``C`` rows tall; ``C`` (``chunk_rows``) is a ``ROW_TILE`` multiple near twice
 the rows expected from the static shapes, so a rank that holds a sixteenth of
 the experts takes one trip most updates and routing that puts every
-assignment here ``N k / C``; where the ``(N, d)`` result is larger than
-``ONE_TRIP_BYTES`` (a quarter of the experts at 32,768 tokens of width 2,560)
-a trip is at most ``WALK_ROWS`` tall, a dozen trips a layer. Rows past the
+assignment here ``N k / C``, and at most ``WALK_ROWS``: a rank that holds a
+quarter of the experts walks a layer in a few trips. Rows past the
 held total inside the last chunk are never computed and never read: the grouped matmul's grid ends with the last
 held row, and the combine and the backward **select** live rows. The walk is
 one ``custom_vjp`` (``_walk``) that keeps its inputs only: the backward is the
@@ -58,6 +57,13 @@ parameter (``EXPERT_FORMS``: the hidden rows from the first products, and their
 cotangents); everything else is shared. ``route_stats`` counts the trips
 (``chunks``).
 
+The add into the tokens (``add_rows``), in the forward's combine and the
+backward's gradient of the tokens: where the grouped matmul's gate takes its
+kernel and ``d`` is a lane multiple, ``pallas_moe.row_add`` (scope
+``moe_row_add_pallas``) — the result stays in HBM as lane rows, a grid step
+moves one group's rows of a tile by DMA, rows past the held total are skipped
+by count —; elsewhere XLA's ``.at[].add(mode="drop")``, the kernel's oracle.
+
 The grouped matmul (``grouped_matmul``): on a TPU, at widths whose tiles the
 kernel takes, ``pallas.ops.tpu.megablox`` (scope ``moe_gmm_pallas``), with the
 transposed product for the weights' gradient; elsewhere ``jax.lax.ragged_dot``,
@@ -66,8 +72,9 @@ which is also the kernel's oracle. The gate is ``models/cells.py``'s
 ``"off"`` forces ``ragged_dot``).
 
 Scopes, for the device trace: ``moe_route``, ``moe_dispatch``, ``moe_experts``
-(``moe_gmm_pallas`` inside it when the kernel was taken), ``moe_combine``;
-the caller wraps the block in ``moe`` and its shared expert, where it has
+(``moe_gmm_pallas`` inside it when the kernel was taken), ``moe_combine``
+(``moe_row_add_pallas`` inside it and inside the backward's ``moe_dispatch``
+when the row-add kernel was taken); the caller wraps the block in ``moe`` and its shared expert, where it has
 one, in ``moe_shared``.
 """
 
@@ -80,24 +87,21 @@ import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _megablox_gmm
 from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as _megablox_tgmm
 
+from tpu_rl.ops import pallas_moe
+
 # Rows a grid step of the grouped matmul takes. A held expert sees a few
 # hundred rows an update at the cell's batch: a taller tile would be mostly
 # another group's rows, masked.
 ROW_TILE = 256
-# A trip ends in a scatter-add of its ``C`` rows into the ``(N, d)`` float32
-# result, and on a TPU v5e that scatter-add has two speeds: ~2.5-4.5 ms, growing
-# slowly with ``C``, and a flat ~15 ms. Which one it gets follows the result's
-# size, not the trip's: into 8,192 x 4,096, 16,384 x 2,688, 20,480 x 2,688,
-# 24,576 x 2,048 and 65,536 x 1,024 floats (134-268 MB) every chunk tried, up to
-# 40,960 rows, took the fast one; into 32,768 x 2,560 (335 MB) 4,096 rows took
-# 2.6 ms and 5,120 to 16,384 rows 14.9-15.8; into 49,152 x 2,560 (503 MB) 1,024
-# to 4,096 rows 2.0-3.1 (PERF.md section 6, PR 32: one layer's walk over 49,000
-# held rows of 32,768 tokens took 84 ms forward + backward in chunks of 4,096
-# rows against 243 / 190 / 162 / 109 / 82 at 8,192 / 12,288 / 16,384 / 32,768 /
-# 49,152). So a result of at most ``ONE_TRIP_BYTES`` is walked in trips as tall
-# as the routing suggests, a larger one at most ``WALK_ROWS`` at a time.
-ONE_TRIP_BYTES = 256 << 20
-WALK_ROWS = 4_096
+# The most rows a trip takes. A trip's buffers (gathered rows, hidden rows,
+# the float32 addend) are this tall, and the last trip of a walk is computed
+# whole however few of its rows are live. On a TPU v5e one layer's walk over
+# 49,248 held rows of 32,768 tokens at width 2,560 (gated experts of width
+# 768, forward + backward) took 62.7 / 58.2 / 57.7 / 56.9 / 60.4 ms in trips of
+# 4,096 / 8,192 / 12,288 / 16,384 / 24,576 rows, and the update program built
+# on it 8.84 / 8.84 / 8.77 / 8.77 GB of temporaries at the first four
+# (PERF.md section 6, PR 33).
+WALK_ROWS = 16_384
 
 
 # ------------------------------------------------------------------ the router
@@ -121,16 +125,13 @@ def route(u, kernel, bias, top_k: int, scale: float, score: str = "sigmoid"):
     return choice.astype(jnp.int32), scale * chosen
 
 
-def chunk_rows(n: int, k: int, held: int, n_experts: int, d: int) -> int:
+def chunk_rows(n: int, k: int, held: int, n_experts: int) -> int:
     """Rows a trip of ``routed_experts``'s walk takes (``C``), from static
     shapes alone: the ``ROW_TILE`` multiple next above twice the rows a fair
     router sends here (``n k held / n_experts``; never more than all ``n k``
-    assignments) — one trip most updates —, and at most ``WALK_ROWS`` where
-    the ``(n, d)`` float32 result the trips add into is larger than
-    ``ONE_TRIP_BYTES``: many short trips."""
+    assignments) — one trip most updates —, and at most ``WALK_ROWS``."""
     tiles = -(-2 * n * k * held // (n_experts * ROW_TILE))
-    rows = ROW_TILE * max(1, min(tiles, -(-n * k // ROW_TILE)))
-    return rows if 4 * n * d <= ONE_TRIP_BYTES else min(rows, WALK_ROWS)
+    return min(ROW_TILE * max(1, min(tiles, -(-n * k // ROW_TILE))), WALK_ROWS)
 
 
 def route_stats(choice, first: int, held: int, chunk: int) -> dict:
@@ -261,6 +262,35 @@ def grouped_grads(lhs, rhs, sizes, g, acc, kernel: tuple[bool, bool] | None = No
         return _gmm_grads(lhs, rhs, sizes, g, interpret, acc)
 
 
+# ------------------------------------------------------------------ the row-add
+def _row_add_gate(chunk: int, d: int, kernel: tuple[bool, bool] | None) -> tuple[bool, bool]:
+    """(use the Pallas row-add, interpret): the grouped matmul's gate (or the
+    caller's choice for it) at a width the row-add takes."""
+    use, interpret = kernel or _gmm_gate(chunk, d, d)
+    return use and pallas_moe.tile_rows(chunk, d) is not None, interpret
+
+
+def _result(shape, adder: tuple[bool, bool]):
+    """Zeros the trips add into: ``(N, d)`` float32, or its lane rows
+    ``(N d / 128, 128)`` where the kernel adds (``pallas_moe.row_add``: a
+    token's row contiguous in HBM)."""
+    n, d = shape
+    lanes = pallas_moe.LANES
+    return jnp.zeros((n * d // lanes, lanes) if adder[0] else (n, d), jnp.float32)
+
+
+def add_rows(y, add, tok, part, live, adder: tuple[bool, bool]):
+    """``y`` with the live rows of ``add`` (C, d) float32 added at their tokens
+    ``tok``; ``part`` the groups' rows among the ``C`` (their sum: the live
+    count). XLA's scatter-add (a dead row's index lies past the end and is
+    dropped), or the kernel under the scope ``moe_row_add_pallas``."""
+    use, interpret = adder
+    if not use:
+        return y.at[jnp.where(live, tok, y.shape[0])].add(add, mode="drop")
+    with jax.named_scope("moe_row_add_pallas"):
+        return pallas_moe.row_add(y, add, tok, part, interpret)
+
+
 # ------------------------------------------------------------------- the walk
 # An expert form: its hidden rows from the products of its first projections
 # (one a leaf of ``w_in``), and those products' cotangents from the hidden
@@ -312,6 +342,7 @@ def _walk(u, weight, w_in, w_out, order, sizes, chunk: int, kernel, form: str):
     n, k = weight.shape
     x, flat = u.astype(w_out.dtype), weight.reshape(-1)
     act, _ = EXPERT_FORMS[form]
+    adder = _row_add_gate(chunk, u.shape[1], kernel)
 
     def trip(c, y):
         at, part, live = _trip(c, order, sizes, chunk)
@@ -325,10 +356,10 @@ def _walk(u, weight, w_in, w_out, order, sizes, chunk: int, kernel, form: str):
             # select, not a product with a zero weight: a row past the held
             # total is unwritten and may hold anything
             add = jnp.where(live[:, None], flat[at][:, None] * out.astype(jnp.float32), 0.0)
-            return y.at[jnp.where(live, tok, n)].add(add, mode="drop")
+            return add_rows(y, add, tok, part, live, adder)
 
     return jax.lax.fori_loop(
-        0, _trips(sizes, chunk), trip, jnp.zeros((n, u.shape[1]), jnp.float32))
+        0, _trips(sizes, chunk), trip, _result(u.shape, adder)).reshape(u.shape)
 
 
 def _walk_fwd(u, weight, w_in, w_out, order, sizes, chunk, kernel, form):
@@ -347,6 +378,7 @@ def _walk_bwd(chunk, kernel, form, residual, dy):
     n, k = weight.shape
     x, g, flat = u.astype(w_out.dtype), dy.astype(w_out.dtype), weight.reshape(-1)
     act, act_bwd = EXPERT_FORMS[form]
+    adder = _row_add_gate(chunk, u.shape[1], kernel)
 
     def trip(c, carry):
         d_x, d_flat, d_in, d_out = carry
@@ -374,13 +406,13 @@ def _walk_bwd(chunk, kernel, form, residual, dy):
             d_flat = d_flat.at[jnp.where(live, at, n * k + jnp.arange(chunk))].set(
                 d_wt, mode="drop", unique_indices=True)
         with jax.named_scope("moe_dispatch"):
-            d_x = d_x.at[jnp.where(live, tok, n)].add(jnp.where(
+            d_x = add_rows(d_x, jnp.where(
                 live[:, None], functools.reduce(jnp.add, [d.astype(jnp.float32) for d in d_rows]),
-                0.0), mode="drop")
+                0.0), tok, part, live, adder)
         return d_x, d_flat, d_in, d_out
 
     d_x, d_flat, d_in, d_out = jax.lax.fori_loop(0, _trips(sizes, chunk), trip, (
-        jnp.zeros(u.shape, jnp.float32), jnp.zeros(n * k, jnp.float32),
+        _result(u.shape, adder), jnp.zeros(n * k, jnp.float32),
         tuple(jnp.zeros(w.shape, jnp.float32) for w in w_in),
         jnp.zeros(w_out.shape, jnp.float32)))
     # the barrier ties the weights' gradients, cast to the operands' dtype as
@@ -390,7 +422,8 @@ def _walk_bwd(chunk, kernel, form, residual, dy):
     with jax.named_scope("moe_experts"):
         d_in = tuple(d.astype(w.dtype) for d, w in zip(d_in, w_in))
         d_out = d_out.astype(w_out.dtype)
-    d_x, d_in, d_out = jax.lax.optimization_barrier((d_x.astype(u.dtype), d_in, d_out))
+    d_x, d_in, d_out = jax.lax.optimization_barrier(
+        (d_x.reshape(u.shape).astype(u.dtype), d_in, d_out))
     return d_x, d_flat.reshape(n, k).astype(weight.dtype), d_in, d_out, None, None
 
 
@@ -411,7 +444,7 @@ def routed_experts(u, choice, weight, w_in, w_out, first: int, dtype=None, kerne
     n, k = choice.shape
     held = w_in.shape[0]
     cd = dtype or jnp.float32
-    chunk = chunk or chunk_rows(n, k, held, held, u.shape[-1])
+    chunk = chunk or chunk_rows(n, k, held, held)
     with jax.named_scope("moe_dispatch"):
         local = (choice - first).reshape(-1)
         key = jnp.where((local >= 0) & (local < held), local, held)  # absent experts' last
