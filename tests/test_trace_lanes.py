@@ -247,12 +247,15 @@ def _run_learner(tmp_path, port, n_updates, **kw):
     from tpu_rl.types import BATCH_FIELDS
 
     B = 16
-    cfg = small_config(
-        env="CartPole-v1", algo="PPO", batch_size=B, seq_len=16, hidden_size=64,
-        learner_device="cpu", result_dir=str(tmp_path / "run"),
-        model_dir=str(tmp_path / "models"), model_save_interval=8,
-        loss_log_interval=4, telemetry_interval_s=0.05, **kw,
-    )
+    cfg = small_config(**{
+        **dict(
+            env="CartPole-v1", algo="PPO", batch_size=B, seq_len=16, hidden_size=64,
+            learner_device="cpu", result_dir=str(tmp_path / "run"),
+            model_dir=str(tmp_path / "models"), model_save_interval=8,
+            loss_log_interval=4, telemetry_interval_s=0.05,
+        ),
+        **kw,
+    })
     layout = BatchLayout.from_config(cfg)
     handles = alloc_handles(layout, capacity=B)
     store = OnPolicyStore(handles, layout)

@@ -16,6 +16,7 @@ from tpu_rl.algos import impala, ppo, sac, vmpo
 from tpu_rl.algos.base import make_train_state
 from tpu_rl.config import Config, is_off_policy
 from tpu_rl.models.families import ALGOS, ModelFamily, build_family
+from tpu_rl.obs.trace import span_of
 
 
 @dataclass(frozen=True)
@@ -24,12 +25,21 @@ class AlgoSpec:
     on_policy: bool  # on-policy ring vs off-policy replay (main.py:310-321)
     make_train_step: Callable[[Config, ModelFamily], Callable]
 
-    def build(self, cfg: Config, key: jax.Array, mesh=None):
+    def build(self, cfg: Config, key: jax.Array, mesh=None, span=None):
         """Returns (family, initial_state, train_step). ``mesh`` is only
-        needed for sequence-parallel transformer families."""
-        family = build_family(cfg, mesh=mesh)
-        state = make_train_state(cfg, family, key)
-        return family, state, self.make_train_step(cfg, family)
+        needed for sequence-parallel transformer families. ``span`` is the
+        caller's ``TraceRecorder.span``, for a chip owner that times its
+        start-up: the three lines are three spans of its lane ``startup``
+        (``train-state`` is the eager ``init_params`` and the optimizer's
+        ``init``: minutes at a catalog model's widths in a cold cache)."""
+        span = span_of(None) if span is None else span
+        with span("family", tid="startup"):
+            family = build_family(cfg, mesh=mesh)
+        with span("train-state", tid="startup"):
+            state = make_train_state(cfg, family, key)
+        with span("step-build", tid="startup"):
+            train_step = self.make_train_step(cfg, family)
+        return family, state, train_step
 
 
 _REGISTRY: dict[str, AlgoSpec] = {

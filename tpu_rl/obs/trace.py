@@ -26,6 +26,41 @@ Two kinds of process use it:
   ``Task Environment`` plane states as its start), so ring and capture hold
   the same instants. Without a capture the annotation is inert (< 1 us).
 
+Lanes of a chip owner (the learner): ``main`` (every statement between two
+dispatches), ``feeder``, ``publisher``, ``ckpt-writer``, ``exporter``,
+``profiler`` — and, for the time before the loop, two more:
+
+- ``startup``: every statement of ``LearnerService.run`` from its entry to
+  its loop lies under one of ``init-multihost``, ``imports``, ``mesh``,
+  ``backend-open``, ``family``, ``train-state``, ``step-build``, ``restore``,
+  ``place``, ``wire``, ``inference-start``, ``feed-start`` (in that order,
+  once a process; the first broadcast between the last two is a
+  ``main/publish``).
+- ``xla``: every phase of a compilation that took at least 10 ms, named
+  ``trace``, ``lower`` or ``backend``, with ``args`` ``fun`` (the program),
+  ``cache`` (``hit`` / ``miss`` of the persistent compile cache, on backend
+  phases) and ``thread`` — added by ``utils.platform.CompileClock`` from
+  ``jax.monitoring``'s time spans, so it lies beside the span that caused it.
+
+A long run's ring forgets its start, so the role's bring-up record keeps it:
+``result_dir/backend-<role>.json`` gains ``startup`` when the first
+``log-sync`` has returned (``run_entry_unix_s``, ``loop_entry_unix_s``,
+``first_sync_end_unix_s``, ``ring_wrapped``, and ``spans``: every ring entry
+so far as ``[lane, name, start_unix_s, seconds, args]``,
+:meth:`TraceRecorder.entries`) and, at close, ``compiles`` (``programs``: per
+program name its compilations, seconds tracing / lowering / in the backend,
+cache hits and misses; ``events``: the timed phases of the whole run). **A
+slow respawn is read from these two keys**: ``run_entry`` to ``loop_entry``
+is the lane ``startup`` (which site took the seconds: ``restore`` is
+orbax's import and the checkpoint's read, ``train-state`` the eager init it
+overwrites, ``wire`` holds the tensorboard writer's import),
+``loop_entry`` to ``first_sync_end`` the first update with its compilation,
+and ``compiles.programs`` says for each program whether the persistent cache
+answered (``hits``) or XLA compiled it again (``misses``, ``backend_s``).
+After the first ``log-sync`` any compilation of 10 ms or more is one line of
+the role's log: ``[learner] compiled <program> in <s> s (cache hit|miss|off)
+under main/<span> update <n>``.
+
 Cost model: a span is one clock pair, one small object, one deque append
 under a lock, and the annotation's enter/exit. The annotation's name is
 built once per site (:meth:`TraceRecorder._site`), never per call.
@@ -71,7 +106,8 @@ class Span:
     entry)."""
 
     __slots__ = (
-        "_rec", "_site", "_ann", "_args", "t0", "secs", "bucket", "timed", "keep",
+        "_rec", "_site", "_ann", "_args", "_outer", "t0", "secs", "bucket", "timed",
+        "keep",
     )
 
     def __init__(self, rec, site, ann, args):
@@ -79,6 +115,7 @@ class Span:
         self._site = site
         self._ann = ann
         self._args = args
+        self._outer = None
         self.t0 = 0.0
         self.secs = 0.0
         self.bucket = site.bucket
@@ -88,6 +125,9 @@ class Span:
     def __enter__(self):
         if self._ann is not None:
             self._ann.__enter__()
+        open_ = self._rec._open
+        self._outer = open_.get(self._site.tid)
+        open_[self._site.tid] = self
         self.t0 = self._rec.now()
         return self
 
@@ -95,6 +135,7 @@ class Span:
         rec = self._rec
         site = self._site
         self.secs = secs = rec.now() - self.t0
+        rec._open[site.tid] = self._outer
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         if self.keep:
@@ -125,6 +166,7 @@ class TraceRecorder:
         self._lock = threading.Lock()
         self.n_recorded = 0
         self._sites: dict = {}
+        self._open: dict = {}  # lane -> its innermost span still open
         # Set by the owner once it has them; a span site that names a timer
         # window or a ledger bucket records into these on exit.
         self.timer = None
@@ -188,8 +230,26 @@ class TraceRecorder:
         site = self._sites[(tid, name)] = _Site(name, tid, timer, bucket)
         return site
 
+    def open_span(self, tid: str = "main") -> tuple[str, dict | None] | None:
+        """``(name, args)`` of the lane's innermost span that is open now, or
+        None: what a listener on another subject (a compilation) says it
+        happened under."""
+        sp = self._open.get(tid)
+        return None if sp is None else (sp._site.name, sp._args)
+
     def __len__(self) -> int:
         return len(self._events)
+
+    def entries(self) -> tuple[list, bool]:
+        """Every span the ring holds as ``[lane, name, start_unix_s, seconds,
+        args]`` — the form that outlives the ring in ``backend-<role>.json``
+        — and whether the ring has already let go of any."""
+        events, n = self.spans_since(0)
+        anchor = self.wall_anchor_ns / 1e9
+        return (
+            [[tid, name, anchor + rel, dur, args] for name, rel, dur, tid, args in events],
+            n > len(events),
+        )
 
     def spans_since(self, seq: int) -> tuple[list, int]:
         """Spans recorded after the first ``seq`` (those the ring still

@@ -282,27 +282,22 @@ class LearnerService:
 
     # ------------------------------------------------------------------ run
     def run(self) -> None:
+        # The process's clock from here: every second between this stamp and
+        # the loop's first iteration lies under one span of the lane
+        # "startup" (or, for the first broadcast, of "main").
+        run_entry = time.time()
         cfg = self.cfg
-        if cfg.multihost:
-            # Must precede any backend use in this process; afterwards
-            # jax.devices() spans every host in the slice.
-            from tpu_rl.parallel.multihost import init_multihost
-
-            init_multihost(**cfg.multihost)
-
-        import jax
-
-        from tpu_rl.algos.registry import get_algo
-        from tpu_rl.checkpoint import Checkpointer
-
-        # Span tracing (tpu_rl.obs.trace): every statement of the loop below
-        # runs inside one named span of the "main" lane; the feeder,
-        # publisher and checkpoint-writer threads have lanes of their own. A
-        # span is a jax.profiler.TraceAnnotation (on the device trace's clock
-        # whenever any capture is open), a ring entry, and — where the site
-        # names them — the ExecutionTimer window and the goodput bucket. The
-        # ring and its trace.json export (a thread of the recorder's own)
-        # exist only with a result_dir; the annotations always do.
+        # Span tracing (tpu_rl.obs.trace): every statement of this method
+        # runs inside one named span — of the lane "startup" up to the loop,
+        # of the lane "main" inside it; the feeder, publisher and
+        # checkpoint-writer threads have lanes of their own, compilations
+        # the lane "xla" (utils.platform.CompileClock). A span is a
+        # jax.profiler.TraceAnnotation (on the device trace's clock whenever
+        # any capture is open), a ring entry, and — where the site names
+        # them — the ExecutionTimer window and the goodput bucket. The ring
+        # and its trace.json export (a thread of the recorder's own) exist
+        # only with a result_dir; the annotations always do. Built first: it
+        # needs no backend, only the pid.
         from tpu_rl.obs import TraceRecorder, flightrec
 
         tracer = self._tracer = TraceRecorder(
@@ -311,367 +306,389 @@ class LearnerService:
             role="learner",
             annotate=True,
         )
-        if cfg.result_dir is not None:
-            flightrec.install(
-                "learner", cfg.result_dir, tracer=tracer, cfg=cfg
-            )
-            tracer.start_export(os.path.join(cfg.result_dir, "trace.json"))
-        layout = BatchLayout.from_config(cfg)
-        store = make_store(cfg, layout, handles=self.handles)
-        off_policy = is_off_policy(cfg.algo)
-        rng = np.random.default_rng(self.seed)
+        span = tracer.span
+        with span("init-multihost", tid="startup"):
+            if cfg.multihost:
+                # Must precede any backend use in this process; afterwards
+                # jax.devices() spans every host in the slice.
+                from tpu_rl.parallel.multihost import init_multihost
 
-        chain = max(1, cfg.learner_chain)
-        if self.max_updates is not None and chain > self.max_updates:
-            # A budget smaller than the chain would otherwise complete
-            # "successfully" with ZERO updates (the pre-dispatch budget
-            # check fires before the first dispatch). Clamp so a small
-            # budget performs real updates; callers wanting a hard error
-            # should validate their own run plans.
-            print(
-                f"[learner] learner_chain {chain} exceeds max_updates "
-                f"{self.max_updates}; clamping chain to "
-                f"{max(1, self.max_updates)}", flush=True,
-            )
-            chain = max(1, self.max_updates)
+                init_multihost(**cfg.multihost)
 
-        # Compile target meshes first: the family needs the mesh when the
-        # transformer's ring/Ulysses attention is sequence-sharded, and the
-        # bring-up record names the devices this learner runs on.
-        mesh = None
-        if cfg.mesh_seq > 1:
-            from tpu_rl.parallel import make_sp_mesh
+        with span("imports", tid="startup"):
+            import jax
 
-            mesh = make_sp_mesh(cfg.mesh_data, cfg.mesh_seq)
-        elif cfg.mesh_data > 1 or chain > 1:
-            # chain > 1 rides the same GSPMD wrapper even on one device
-            # (make_mesh(1)): the chained lax.scan program is what
-            # amortizes per-dispatch overhead, mesh width is orthogonal.
-            from tpu_rl.parallel.mesh import make_mesh
+            from tpu_rl.algos.registry import get_algo
+            from tpu_rl.checkpoint import Checkpointer, resume_fingerprint
+            from tpu_rl.utils.platform import BackendRecord
 
-            mesh = make_mesh(cfg.mesh_data)
-        from tpu_rl.utils.platform import BackendRecord
+            if cfg.result_dir is not None:
+                flightrec.install(
+                    "learner", cfg.result_dir, tracer=tracer, cfg=cfg
+                )
+                tracer.start_export(os.path.join(cfg.result_dir, "trace.json"))
 
-        backend = BackendRecord("learner", cfg, mesh)
+        with span("mesh", tid="startup"):
+            layout = BatchLayout.from_config(cfg)
+            store = make_store(cfg, layout, handles=self.handles)
+            off_policy = is_off_policy(cfg.algo)
+            rng = np.random.default_rng(self.seed)
+
+            chain = max(1, cfg.learner_chain)
+            if self.max_updates is not None and chain > self.max_updates:
+                # A budget smaller than the chain would otherwise complete
+                # "successfully" with ZERO updates (the pre-dispatch budget
+                # check fires before the first dispatch). Clamp so a small
+                # budget performs real updates; callers wanting a hard error
+                # should validate their own run plans.
+                print(
+                    f"[learner] learner_chain {chain} exceeds max_updates "
+                    f"{self.max_updates}; clamping chain to "
+                    f"{max(1, self.max_updates)}", flush=True,
+                )
+                chain = max(1, self.max_updates)
+
+            # Compile target meshes first: the family needs the mesh when the
+            # transformer's ring/Ulysses attention is sequence-sharded, and
+            # the bring-up record names the devices this learner runs on.
+            mesh = None
+            if cfg.mesh_seq > 1:
+                from tpu_rl.parallel import make_sp_mesh
+
+                mesh = make_sp_mesh(cfg.mesh_data, cfg.mesh_seq)
+            elif cfg.mesh_data > 1 or chain > 1:
+                # chain > 1 rides the same GSPMD wrapper even on one device
+                # (make_mesh(1)): the chained lax.scan program is what
+                # amortizes per-dispatch overhead, mesh width is orthogonal.
+                from tpu_rl.parallel.mesh import make_mesh
+
+                mesh = make_mesh(cfg.mesh_data)
+
+        with span("backend-open", tid="startup"):
+            backend = BackendRecord("learner", cfg, mesh, tracer=tracer)
+            init_key = jax.random.key(self.seed)  # the first program to run
+        # Spans "family", "train-state", "step-build" of the lane "startup".
         spec = get_algo(cfg.algo)
         family, state, train_step = spec.build(
-            cfg,
-            jax.random.key(self.seed),
-            mesh=mesh if cfg.mesh_seq > 1 else None,
+            cfg, init_key, mesh=mesh if cfg.mesh_seq > 1 else None, span=span
         )
+        del init_key  # a device buffer (512 B of the chip's peak) nobody reads again
 
-        # ---- checkpoint resume (newest COMMITTED index wins) ----
-        # Full-run resume: train state + update index + learner PRNG key +
-        # run epoch, refused on config-fingerprint mismatch unless
-        # cfg.resume_force. A torn (uncommitted) save is invisible here by
-        # construction (tpu_rl/checkpoint.py's marker protocol).
-        from tpu_rl.checkpoint import resume_fingerprint
-
-        ckpt = None
-        start_idx = 0
-        resumed_key_data = None
-        fingerprint = resume_fingerprint(cfg)
-        if cfg.model_dir:
-            ckpt = self._ckpt = Checkpointer(
-                cfg.model_dir,
-                cfg.algo,
-                keep=cfg.ckpt_keep,
-                async_save=cfg.ckpt_async,
-                tracer=tracer,
-            )
-            restored = ckpt.restore_run(
-                state, fingerprint=fingerprint, force=cfg.resume_force
-            )
-            if restored is not None:
-                state, start_idx, meta = restored
-                self.run_epoch = int(meta.get("epoch", 0)) + 1
-                resumed_key_data = meta.get("key")
-                print(
-                    f"[learner] resumed from checkpoint idx {start_idx} "
-                    f"(run epoch {self.run_epoch})"
+        with span("restore", tid="startup"):
+            # ---- checkpoint resume (newest COMMITTED index wins) ----
+            # Full-run resume: train state + update index + learner PRNG key +
+            # run epoch, refused on config-fingerprint mismatch unless
+            # cfg.resume_force. A torn (uncommitted) save is invisible here by
+            # construction (tpu_rl/checkpoint.py's marker protocol).
+            ckpt = None
+            start_idx = 0
+            resumed_key_data = None
+            fingerprint = resume_fingerprint(cfg)
+            if cfg.model_dir:
+                ckpt = self._ckpt = Checkpointer(
+                    cfg.model_dir,
+                    cfg.algo,
+                    keep=cfg.ckpt_keep,
+                    async_save=cfg.ckpt_async,
+                    tracer=tracer,
                 )
-                self._record_resume(start_idx)
-        # Publish the epoch into the cross-respawn mailbox BEFORE the first
-        # broadcast: storage (its mp.Array outlives child respawns) learns
-        # the new fence before any worker can act on the new weights, which
-        # makes stale-epoch rejection deterministic instead of a race.
-        sa = self.stat_array
-        if sa is not None and len(sa) > SLOT_RUN_EPOCH:
-            sa[SLOT_RUN_EPOCH] = float(self.run_epoch + 1)  # 0 = unknown
+                restored = ckpt.restore_run(
+                    state, fingerprint=fingerprint, force=cfg.resume_force
+                )
+                if restored is not None:
+                    state, start_idx, meta = restored
+                    self.run_epoch = int(meta.get("epoch", 0)) + 1
+                    resumed_key_data = meta.get("key")
+                    print(
+                        f"[learner] resumed from checkpoint idx {start_idx} "
+                        f"(run epoch {self.run_epoch})"
+                    )
+                    self._record_resume(start_idx)
+            # Publish the epoch into the cross-respawn mailbox BEFORE the first
+            # broadcast: storage (its mp.Array outlives child respawns) learns
+            # the new fence before any worker can act on the new weights, which
+            # makes stale-epoch rejection deterministic instead of a race.
+            sa = self.stat_array
+            if sa is not None and len(sa) > SLOT_RUN_EPOCH:
+                sa[SLOT_RUN_EPOCH] = float(self.run_epoch + 1)  # 0 = unknown
 
-        # ---- compile: single-chip jit, data-parallel, or data x seq mesh ----
-        # _wrap is reused by the entropy-anneal switch below, which rebuilds
-        # the raw train step with the post-switch cfg and must re-apply the
-        # same mesh/jit wrapping.
-        self._place_global = None
-        self._chain_mesh = None
-        self._batch_sharding = None  # eager-placement target (prefetch feed)
-        self._device = jax.devices()[0]
-        if cfg.mesh_seq > 1:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        with span("place", tid="startup"):
+            # ---- compile: single-chip jit, data-parallel, or data x seq mesh ----
+            # _wrap is reused by the entropy-anneal switch below, which rebuilds
+            # the raw train step with the post-switch cfg and must re-apply the
+            # same mesh/jit wrapping.
+            self._place_global = None
+            self._chain_mesh = None
+            self._batch_sharding = None  # eager-placement target (prefetch feed)
+            self._device = jax.devices()[0]
+            if cfg.mesh_seq > 1:
+                from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from tpu_rl.parallel.dp import make_sp_train_step, replicate
-            from tpu_rl.parallel.sequence import DATA_AXIS, SEQ_AXIS
+                from tpu_rl.parallel.dp import make_sp_train_step, replicate
+                from tpu_rl.parallel.sequence import DATA_AXIS, SEQ_AXIS
 
-            def _wrap(step, wcfg):
-                return make_sp_train_step(step, mesh, wcfg)
+                def _wrap(step, wcfg):
+                    return make_sp_train_step(step, mesh, wcfg)
 
-            state = replicate(state, mesh)
-            self._batch_sharding = NamedSharding(mesh, P(DATA_AXIS, SEQ_AXIS))
-            self._setup_multihost_feed(self._batch_sharding)
-        elif mesh is not None:
-            from tpu_rl.parallel.dp import make_parallel_train_step, replicate
-            from tpu_rl.parallel.mesh import batch_sharding
+                state = replicate(state, mesh)
+                self._batch_sharding = NamedSharding(mesh, P(DATA_AXIS, SEQ_AXIS))
+                self._setup_multihost_feed(self._batch_sharding)
+            elif mesh is not None:
+                from tpu_rl.parallel.dp import make_parallel_train_step, replicate
+                from tpu_rl.parallel.mesh import batch_sharding
 
-            if chain > 1:
-                self._chain_mesh = mesh
+                if chain > 1:
+                    self._chain_mesh = mesh
 
-            def _wrap(step, wcfg):
-                return make_parallel_train_step(step, mesh, wcfg, chain=chain)
+                def _wrap(step, wcfg):
+                    return make_parallel_train_step(step, mesh, wcfg, chain=chain)
 
-            state = replicate(state, mesh)
-            if chain == 1:
-                # chain > 1 places via shard_chained_batch in _assemble;
-                # chain == 1 places eagerly against the DP batch sharding.
-                self._batch_sharding = batch_sharding(mesh)
-            self._setup_multihost_feed(batch_sharding(mesh))
-        else:
-
-            def _wrap(step, wcfg):
-                return jax.jit(step, donate_argnums=(0,))
-
-        train_step = _wrap(train_step, cfg)
-
-        # Two-phase entropy/lr anneal switch point (Config.entropy_anneal;
-        # same semantics as the inline harness, examples/train_inline.py).
-        # "at" is an ABSOLUTE update index — checked with >= against the
-        # global counter, so a run resumed past the switch re-enters the
-        # cold phase on its first update instead of undoing the anneal.
-        # "frac" is relative to THIS run's max_updates budget.
-        anneal = cfg.entropy_anneal
-        anneal_at = None
-        anneal_absolute = False
-        if anneal is not None:
-            if "at" in anneal:
-                anneal_at = max(1, int(anneal["at"]))
-                anneal_absolute = True
-            elif self.max_updates is not None:
-                anneal_at = max(1, int(float(anneal["frac"]) * self.max_updates))
+                state = replicate(state, mesh)
+                if chain == 1:
+                    # chain > 1 places via shard_chained_batch in _assemble;
+                    # chain == 1 places eagerly against the DP batch sharding.
+                    self._batch_sharding = batch_sharding(mesh)
+                self._setup_multihost_feed(batch_sharding(mesh))
             else:
-                print(
-                    "[learner] entropy_anneal uses 'frac' but the run has no "
-                    "max_updates budget; anneal disabled", flush=True,
-                )
 
-        # Fault injection (tpu_rl.chaos): delay:learner shims the model
-        # broadcast sends. None unless a chaos_spec names this site.
-        chaos = None
-        if cfg.chaos_spec:
-            from tpu_rl.chaos import maybe_transport_chaos
+                def _wrap(step, wcfg):
+                    return jax.jit(step, donate_argnums=(0,))
 
-            chaos = maybe_transport_chaos(cfg, "learner")
-        pub = Pub("*", self.model_port, bind=True, hwm=MODEL_HWM, chaos=chaos)
-        # Async broadcast rides the same switch as the feed pipeline so
-        # learner_prefetch=0 is a FULLY serial A/B baseline.
-        self._publisher = (
-            AsyncPublisher(pub, tracer) if cfg.learner_prefetch > 0 else None
-        )
-        writer = make_writer(cfg.result_dir)
-        logger = LearnerLogger(writer, cfg.algo)
-        # Telemetry plane (tpu_rl.obs): the learner ships its own registry
-        # snapshots to the storage-side aggregator over the stat channel —
-        # the same port every other role's telemetry already converges on.
-        # None when disabled: the hot loop then pays one `is None` check per
-        # update and opens no extra socket (pinned by tests/test_obs.py).
-        from tpu_rl.obs.goodput import (
-            CKPT,
-            COMPUTE,
-            H2D,
-            IDLE,
-            QUEUE_WAIT,
-            RECOMPILE,
-            ROLLBACK,
-            WIRE,
-            GoodputLedger,
-        )
+            train_step = _wrap(train_step, cfg)
 
-        telem_reg = telem_pub = None
-        telem_last = float("-inf")
-        self._perf = None
-        self.ledger = None
-        # With prefetch the pop wait is residual feed latency (queue-wait);
-        # the synchronous feed does the shm copy + H2D inside get(), so the
-        # same span is h2d there.
-        wait_bucket = QUEUE_WAIT if cfg.learner_prefetch > 0 else H2D
-        if cfg.telemetry_enabled and self.stat_port is not None:
-            from tpu_rl.obs import MetricsRegistry
-            from tpu_rl.obs.perf import PerfTracker
+        with span("wire", tid="startup"):
+            # Two-phase entropy/lr anneal switch point (Config.entropy_anneal;
+            # same semantics as the inline harness, examples/train_inline.py).
+            # "at" is an ABSOLUTE update index — checked with >= against the
+            # global counter, so a run resumed past the switch re-enters the
+            # cold phase on its first update instead of undoing the anneal.
+            # "frac" is relative to THIS run's max_updates budget.
+            anneal = cfg.entropy_anneal
+            anneal_at = None
+            anneal_absolute = False
+            if anneal is not None:
+                if "at" in anneal:
+                    anneal_at = max(1, int(anneal["at"]))
+                    anneal_absolute = True
+                elif self.max_updates is not None:
+                    anneal_at = max(1, int(float(anneal["frac"]) * self.max_updates))
+                else:
+                    print(
+                        "[learner] entropy_anneal uses 'frac' but the run has no "
+                        "max_updates budget; anneal disabled", flush=True,
+                    )
 
-            telem_reg = MetricsRegistry(role="learner")
-            # Goodput ledger (tpu_rl.obs.goodput): exhaustive wall-clock
-            # attribution for THIS thread only — feeder / async-ckpt-writer /
-            # async-publisher lanes overlap the device step and would
-            # double-count, so only "main"-lane span sites name a bucket.
-            self.ledger = tracer.ledger = GoodputLedger("learner")
-            # Live performance plane (tpu_rl.obs.perf): FLOPs/MFU from a
-            # one-time AOT cost analysis of train_step, recompile and
-            # device-memory watermarks on the emit cadence. None when
-            # telemetry is off — the hot loop pays one `is None` check.
-            self._perf = PerfTracker()
-            # Storage telemetry hop: loopback by construction (learner and
-            # storage share the host), so transport="shm"/"auto" routes it
-            # through the shm channel instead of a TCP loopback socket.
-            telem_pub = make_data_pub(
-                cfg, "127.0.0.1", self.stat_port, bind=False
+            # Fault injection (tpu_rl.chaos): delay:learner shims the model
+            # broadcast sends. None unless a chaos_spec names this site.
+            chaos = None
+            if cfg.chaos_spec:
+                from tpu_rl.chaos import maybe_transport_chaos
+
+                chaos = maybe_transport_chaos(cfg, "learner")
+            pub = Pub("*", self.model_port, bind=True, hwm=MODEL_HWM, chaos=chaos)
+            # Async broadcast rides the same switch as the feed pipeline so
+            # learner_prefetch=0 is a FULLY serial A/B baseline.
+            self._publisher = (
+                AsyncPublisher(pub, tracer) if cfg.learner_prefetch > 0 else None
             )
-        # Profiler capture gate (tpu_rl.obs.perf.ProfilerCapture): ONE
-        # serialized gate for the config window below, `kill -USR2 <pid>`
-        # (mirroring the flight recorder's SIGUSR1), and the telemetry
-        # server's /prof?ms=N. Its flight-recorder crash hook guarantees
-        # stop_trace() on fatal exceptions, so the capture meant to explain
-        # a crash is flushed instead of dying with the process.
-        prof_capture = self._prof_capture = None
-        if cfg.profile_dir is not None or cfg.result_dir is not None:
-            from tpu_rl.obs.perf import ProfilerCapture
-
-            prof_capture = self._prof_capture = ProfilerCapture(
-                cfg.profile_dir or os.path.join(cfg.result_dir, "prof"),
-                tracer=tracer,
+            writer = make_writer(cfg.result_dir)
+            logger = LearnerLogger(writer, cfg.algo)
+            # Telemetry plane (tpu_rl.obs): the learner ships its own registry
+            # snapshots to the storage-side aggregator over the stat channel —
+            # the same port every other role's telemetry already converges on.
+            # None when disabled: the hot loop then pays one `is None` check per
+            # update and opens no extra socket (pinned by tests/test_obs.py).
+            from tpu_rl.obs.goodput import (
+                CKPT,
+                COMPUTE,
+                H2D,
+                IDLE,
+                QUEUE_WAIT,
+                RECOMPILE,
+                ROLLBACK,
+                WIRE,
+                GoodputLedger,
             )
-            prof_capture.install_sigusr2()
-        # One timed window per DISPATCH; a chained dispatch carries
-        # chain x (seq x batch) transitions. Kept on self: the lane gauges
-        # below and chip_smoke.py read the steady-state windowed rates after
-        # run() — the window excludes idle polls and dilutes the first
-        # dispatch's compile across the deque.
-        timer = self.timer = tracer.timer = ExecutionTimer(
-            num_transition=cfg.seq_len * cfg.batch_size * chain
-        )
-        key = jax.random.key(self.seed + 1)
-        if resumed_key_data is not None:
-            # Continue the checkpointed RNG stream instead of replaying the
-            # seed's: a resumed run keeps sampling fresh subkeys.
-            import jax.numpy as jnp
 
-            try:
-                key = jax.random.wrap_key_data(
-                    jnp.asarray(resumed_key_data, dtype=jnp.uint32)
+            telem_reg = telem_pub = None
+            telem_last = float("-inf")
+            self._perf = None
+            self.ledger = None
+            # With prefetch the pop wait is residual feed latency (queue-wait);
+            # the synchronous feed does the shm copy + H2D inside get(), so the
+            # same span is h2d there.
+            wait_bucket = QUEUE_WAIT if cfg.learner_prefetch > 0 else H2D
+            if cfg.telemetry_enabled and self.stat_port is not None:
+                from tpu_rl.obs import MetricsRegistry
+                from tpu_rl.obs.perf import PerfTracker
+
+                telem_reg = MetricsRegistry(role="learner")
+                # Goodput ledger (tpu_rl.obs.goodput): exhaustive wall-clock
+                # attribution for THIS thread only — feeder / async-ckpt-writer /
+                # async-publisher lanes overlap the device step and would
+                # double-count, so only "main"-lane span sites name a bucket.
+                self.ledger = tracer.ledger = GoodputLedger("learner")
+                # Live performance plane (tpu_rl.obs.perf): FLOPs/MFU from a
+                # one-time AOT cost analysis of train_step, recompile and
+                # device-memory watermarks on the emit cadence. None when
+                # telemetry is off — the hot loop pays one `is None` check.
+                self._perf = PerfTracker()
+                # Storage telemetry hop: loopback by construction (learner and
+                # storage share the host), so transport="shm"/"auto" routes it
+                # through the shm channel instead of a TCP loopback socket.
+                telem_pub = make_data_pub(
+                    cfg, "127.0.0.1", self.stat_port, bind=False
                 )
-            except (TypeError, ValueError):
-                print(
-                    "[learner] checkpointed PRNG key unreadable; keeping "
-                    "the seed-derived stream", flush=True,
+            # Profiler capture gate (tpu_rl.obs.perf.ProfilerCapture): ONE
+            # serialized gate for the config window below, `kill -USR2 <pid>`
+            # (mirroring the flight recorder's SIGUSR1), and the telemetry
+            # server's /prof?ms=N. Its flight-recorder crash hook guarantees
+            # stop_trace() on fatal exceptions, so the capture meant to explain
+            # a crash is flushed instead of dying with the process.
+            prof_capture = self._prof_capture = None
+            if cfg.profile_dir is not None or cfg.result_dir is not None:
+                from tpu_rl.obs.perf import ProfilerCapture
+
+                prof_capture = self._prof_capture = ProfilerCapture(
+                    cfg.profile_dir or os.path.join(cfg.result_dir, "prof"),
+                    tracer=tracer,
                 )
+                prof_capture.install_sigusr2()
+            # One timed window per DISPATCH; a chained dispatch carries
+            # chain x (seq x batch) transitions. Kept on self: the lane gauges
+            # below and chip_smoke.py read the steady-state windowed rates after
+            # run() — the window excludes idle polls and dilutes the first
+            # dispatch's compile across the deque.
+            timer = self.timer = tracer.timer = ExecutionTimer(
+                num_transition=cfg.seq_len * cfg.batch_size * chain
+            )
+            key = jax.random.key(self.seed + 1)
+            if resumed_key_data is not None:
+                # Continue the checkpointed RNG stream instead of replaying the
+                # seed's: a resumed run keeps sampling fresh subkeys.
+                import jax.numpy as jnp
 
-        def _ckpt_meta() -> dict:
-            # Captures the loop's live `key` binding: the meta snapshot is
-            # taken at save-call time, consistent with the state snapshot.
-            return {
-                "epoch": self.run_epoch,
-                "key": np.asarray(jax.random.key_data(key)).tolist(),
-                "fingerprint": fingerprint,
-            }
+                try:
+                    key = jax.random.wrap_key_data(
+                        jnp.asarray(resumed_key_data, dtype=jnp.uint32)
+                    )
+                except (TypeError, ValueError):
+                    print(
+                        "[learner] checkpointed PRNG key unreadable; keeping "
+                        "the seed-derived stream", flush=True,
+                    )
 
-        # SEED-style centralized inference (act_mode="remote"): serve
-        # batched acting from THIS process on the learner's device. Params
-        # reach the service as a device-side snapshot after every update —
-        # zero broadcast staleness, no host copy, no wire. The service
-        # shares `timer`, so inference-batch-size / inference-step-time land
-        # on the learner's tensorboard alongside the hot-loop timings.
-        if cfg.act_mode == "remote" and self.inference_port is not None:
-            if cfg.inference_replicas > 1:
-                # Fleet mode: the in-learner service is replica 0 —
-                # continuous batching + the ver-keyed swap, so its replies
-                # respect the same version monotonicity the standalone
-                # replicas give (learner versions only ever rise, so every
-                # in-process swap applies).
-                from tpu_rl.fleet import InferenceReplica as InferenceService
-            else:
-                from tpu_rl.runtime.inference_service import InferenceService
+            def _ckpt_meta() -> dict:
+                # Captures the loop's live `key` binding: the meta snapshot is
+                # taken at save-call time, consistent with the state snapshot.
+                return {
+                    "epoch": self.run_epoch,
+                    "key": np.asarray(jax.random.key_data(key)).tolist(),
+                    "fingerprint": fingerprint,
+                }
 
-            self._inference = InferenceService(
-                cfg,
-                family,
-                self._actor_snapshot(state),
-                self.inference_port,
-                timer=timer,
-                seed=self.seed,
-                version=start_idx,
-            ).start()
-            self._inference.wait_ready()
+        with span("inference-start", tid="startup"):
+            # SEED-style centralized inference (act_mode="remote"): serve
+            # batched acting from THIS process on the learner's device. Params
+            # reach the service as a device-side snapshot after every update —
+            # zero broadcast staleness, no host copy, no wire. The service
+            # shares `timer`, so inference-batch-size / inference-step-time land
+            # on the learner's tensorboard alongside the hot-loop timings.
+            if cfg.act_mode == "remote" and self.inference_port is not None:
+                if cfg.inference_replicas > 1:
+                    # Fleet mode: the in-learner service is replica 0 —
+                    # continuous batching + the ver-keyed swap, so its replies
+                    # respect the same version monotonicity the standalone
+                    # replicas give (learner versions only ever rise, so every
+                    # in-process swap applies).
+                    from tpu_rl.fleet import InferenceReplica as InferenceService
+                else:
+                    from tpu_rl.runtime.inference_service import InferenceService
+
+                self._inference = InferenceService(
+                    cfg,
+                    family,
+                    self._actor_snapshot(state),
+                    self.inference_port,
+                    timer=timer,
+                    seed=self.seed,
+                    version=start_idx,
+                ).start()
+                self._inference.wait_ready()
 
         # First broadcast so workers act with the resumed/initial policy
         # rather than their own random init. It answers any join request
         # already pending (a respawned learner typically finds the flag
         # raised: storage re-registered every worker while it was booting).
-        span = tracer.span
         with span("publish", bucket=WIRE):
             self._publish(pub, state, ver=start_idx)
             self._consume_join_flag()
         last_pub_m = time.monotonic()
 
-        if (
-            self.max_updates is not None
-            and chain > 1
-            and self.max_updates % chain
-        ):
-            print(
-                f"[learner] max_updates {self.max_updates} is not a multiple "
-                f"of learner_chain {chain}; budget rounds DOWN to "
-                f"{self.max_updates // chain * chain} updates", flush=True,
-            )
-        # Self-healing plane (tpu_rl.heal): the guards already run inside
-        # train_step (cfg.update_guard, folded in at make_train_step time);
-        # here lives the host side — a lazy on-device accumulator over the
-        # per-dispatch "nonfinite-updates" metric (one jnp add per update,
-        # read back only at the loss-log cadence) plus the divergence
-        # watchdog + rollback budget when enabled. The watchdog needs a
-        # checkpointer to roll back to, so it stays off without model_dir.
-        track_nf = cfg.update_guard
-        nf_acc = 0.0  # device scalar after the first guarded dispatch
-        nf_base = 0.0  # cumulative count at the last rollback (host float)
-        watchdog = budget = None
-        if cfg.watchdog_enabled and ckpt is not None:
-            from tpu_rl.heal import DivergenceWatchdog, RollbackBudget
+        with span("feed-start", tid="startup"):
+            if (
+                self.max_updates is not None
+                and chain > 1
+                and self.max_updates % chain
+            ):
+                print(
+                    f"[learner] max_updates {self.max_updates} is not a multiple "
+                    f"of learner_chain {chain}; budget rounds DOWN to "
+                    f"{self.max_updates // chain * chain} updates", flush=True,
+                )
+            # Self-healing plane (tpu_rl.heal): the guards already run inside
+            # train_step (cfg.update_guard, folded in at make_train_step time);
+            # here lives the host side — a lazy on-device accumulator over the
+            # per-dispatch "nonfinite-updates" metric (one jnp add per update,
+            # read back only at the loss-log cadence) plus the divergence
+            # watchdog + rollback budget when enabled. The watchdog needs a
+            # checkpointer to roll back to, so it stays off without model_dir.
+            track_nf = cfg.update_guard
+            nf_acc = 0.0  # device scalar after the first guarded dispatch
+            nf_base = 0.0  # cumulative count at the last rollback (host float)
+            watchdog = budget = None
+            if cfg.watchdog_enabled and ckpt is not None:
+                from tpu_rl.heal import DivergenceWatchdog, RollbackBudget
 
-            watchdog = DivergenceWatchdog(
-                window=cfg.watchdog_window,
-                z_max=cfg.watchdog_z,
-                sustain=cfg.watchdog_sustain,
-                nonfinite_max=cfg.watchdog_nonfinite,
-            )
-            budget = RollbackBudget(
-                max_rollbacks=cfg.max_rollbacks,
-                window_s=cfg.rollback_window_s,
-            )
-        # Learning-dynamics plane (tpu_rl.obs.learn): fold every dispatch's
-        # in-jit diag pytree into an on-device accumulator bucketed by the
-        # batch's policy staleness (the per-slot version sidecar the store
-        # reads back); host readback only at the loss-log cadence below.
-        # Must exist BEFORE the feed: the feeder thread's _assemble_device
-        # detaches the sidecar into _diag_vers.
-        diag_acc = diag_vers = None
-        _stale_rows = _learn_record = _publish_diag = None
-        if cfg.learn_diag:
-            from collections import deque as _deque
+                watchdog = DivergenceWatchdog(
+                    window=cfg.watchdog_window,
+                    z_max=cfg.watchdog_z,
+                    sustain=cfg.watchdog_sustain,
+                    nonfinite_max=cfg.watchdog_nonfinite,
+                )
+                budget = RollbackBudget(
+                    max_rollbacks=cfg.max_rollbacks,
+                    window_s=cfg.rollback_window_s,
+                )
+            # Learning-dynamics plane (tpu_rl.obs.learn): fold every dispatch's
+            # in-jit diag pytree into an on-device accumulator bucketed by the
+            # batch's policy staleness (the per-slot version sidecar the store
+            # reads back); host readback only at the loss-log cadence below.
+            # Must exist BEFORE the feed: the feeder thread's _assemble_device
+            # detaches the sidecar into _diag_vers.
+            diag_acc = diag_vers = None
+            _stale_rows = _learn_record = _publish_diag = None
+            if cfg.learn_diag:
+                from collections import deque as _deque
 
-            from tpu_rl.obs.learn import (
-                DiagAccumulator,
-                host_stale_rows as _stale_rows,
-                learn_record as _learn_record,
-                publish as _publish_diag,
-            )
+                from tpu_rl.obs.learn import (
+                    DiagAccumulator,
+                    host_stale_rows as _stale_rows,
+                    learn_record as _learn_record,
+                    publish as _publish_diag,
+                )
 
-            diag_acc = self._diag = DiagAccumulator()
-            diag_vers = self._diag_vers = _deque()
-        # The feed: a background prefetch pipeline (default) or the inline
-        # synchronous path (learner_prefetch=0). Either way the loop below
-        # pops ONE device-ready dispatch batch per iteration.
-        feed = self._make_feed(store, rng, chain)
-        idx = start_idx
-        # The profiler window opens once and closes once: None until its
-        # capture opens, True while it runs, False for the rest of the run.
-        profiling = None if cfg.profile_dir is not None else False
+                diag_acc = self._diag = DiagAccumulator()
+                diag_vers = self._diag_vers = _deque()
+            # The feed: a background prefetch pipeline (default) or the inline
+            # synchronous path (learner_prefetch=0). Either way the loop below
+            # pops ONE device-ready dispatch batch per iteration.
+            feed = self._make_feed(store, rng, chain)
+            idx = start_idx
+            # The profiler window opens once and closes once: None until its
+            # capture opens, True while it runs, False for the rest of the run.
+            profiling = None if cfg.profile_dir is not None else False
+        loop_entry = time.time()
         try:
             # Between one dispatch and the next, every statement below runs
             # inside exactly one span of the "main" lane (none nests in
@@ -735,7 +752,9 @@ class LearnerService:
                 with span("rng-split"):
                     batch, feed_secs = item
                     key, sub_key = jax.random.split(key)
-                with span("program-record"):
+                # What a compilation under either span is reported with.
+                update = {"update": idx + chain}
+                with span("program-record", args=update):
                     rc0 = self._perf.recompiles if self._perf is not None else 0
                     if self._perf is not None:
                         # Identity check after the first call; first sight of
@@ -749,7 +768,7 @@ class LearnerService:
                 # dispatch, not device time: it reads as the device's only
                 # when the dispatch queue is full and the call blocks.
                 with span(
-                    "dispatch", args={"update": idx + chain},
+                    "dispatch", args=update,
                     timer="learner-step-time", bucket=COMPUTE,
                 ) as sp_step:
                     state, metrics = train_step(state, batch, sub_key)
@@ -859,7 +878,7 @@ class LearnerService:
                         telem_last = time.monotonic()
                         self._emit_telemetry(telem_reg, telem_pub, timer, idx)
                 if _crossed(prev_idx, idx, cfg.loss_log_interval):
-                    with span("log-sync"):
+                    with span("log-sync") as sp_sync:
                         # The loop's one blocking read-back of the pipeline:
                         # wait for this update, then fetch its scalars.
                         jax.block_until_ready(metrics)
@@ -871,6 +890,14 @@ class LearnerService:
                         if track_nf:
                             self.n_nonfinite_updates = float(nf_acc)
                     with span("log-write"):
+                        # The first time, an update has just finished on the
+                        # device and the start-up is over: the record keeps
+                        # what the ring holds of it (a long run's ring
+                        # forgets), and from here on a compilation is
+                        # reported by name. A no-op ever after.
+                        backend.record_startup(
+                            run_entry, loop_entry, sp_sync.t0 + sp_sync.secs
+                        )
                         print(
                             f"[learner] update {idx}  "
                             + "  ".join(

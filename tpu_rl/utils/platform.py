@@ -18,6 +18,8 @@ from __future__ import annotations
 import json
 import os
 import re
+import threading
+import time
 
 # Fixed in-checkout cache location (a directory that moves never hits):
 # <repo>/.jax_cache.
@@ -73,41 +75,133 @@ def enable_compile_cache() -> str | None:
     return _CACHE_DIR
 
 
-class CompileClock:
-    """Backend-compile seconds (cache retrievals included) and persistent
-    cache hits/misses of this process, from ``jax.monitoring`` events, since
-    construction."""
+# jax.monitoring's three compile phases, by the name they go under here.
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_VERDICTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+# A phase this long is a span of its own (lane ``xla`` of the role's
+# recorder, ``compiles.events`` of its record); a shorter one only enters its
+# program's aggregate. The eager init of a catalog model emits thousands of
+# sub-millisecond phases, which would wash the ring: one smallthinker set-up
+# makes 2,945 listener calls and 18-22 ring entries (tf-longctx: 1,959 and
+# 15-19; my chip runs, PR 34, PERF.md section 5).
+XLA_SPAN_MIN_S = 0.010
+MAX_COMPILE_EVENTS = 512
+_JIT_WRAP = re.compile(r"^p?jit\((.*)\)$")
 
-    def __init__(self):
+
+class CompileClock:
+    """What this process compiled since construction, from ``jax.monitoring``:
+    one aggregate per program name (``programs``: backend compilations,
+    seconds tracing, lowering and in the backend — a cache retrieval is
+    inside the last — persistent-cache hits and misses) and, for every phase
+    of at least ``XLA_SPAN_MIN_S``, a timed event: in ``events`` (the first
+    ``MAX_COMPILE_EVENTS``) and, with a recorder, as a span of its lane
+    ``xla`` with ``args`` ``fun`` / ``cache`` / ``thread``. A cache verdict
+    has no name of its own: it belongs to the backend phase that closes next
+    on its thread. Once ``announce`` is set, a backend compilation of that
+    length also prints one line: program, seconds, verdict, and the ``main``
+    span (with its ``update``) it happened under."""
+
+    def __init__(self, tracer=None, role: str = ""):
         import jax
 
-        self.seconds = 0.0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        self.programs: dict[str, dict] = {}
+        self.events: list[list] = []
+        self.n_events_dropped = 0
+        self.n_calls = 0  # listener calls, whatever their length
+        self.announce = False
+        self._tracer = tracer
+        self._role = role
+        self._lock = threading.Lock()
+        self._verdicts = threading.local()  # .seen: since the last backend phase
+        jax.monitoring.register_event_time_span_listener(self._phase)
         jax.monitoring.register_event_listener(self._event)
 
-    def _duration(self, event: str, duration: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
     def _event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
+        verdict = _CACHE_VERDICTS.get(event)
+        if verdict is not None:
+            self._verdicts.__dict__.setdefault("seen", []).append(verdict)
+
+    def _phase(self, event: str, start: float, end: float, fun_name: str = "", **_kw) -> None:
+        kind = _COMPILE_PHASES.get(event)
+        if kind is None:
+            return
+        # The tracer says "f" where the lowering and the backend say "jit(f)".
+        wrapped = _JIT_WRAP.match(fun_name)
+        name = wrapped.group(1) if wrapped else fun_name
+        secs = end - start
+        verdicts = self._verdicts.__dict__.pop("seen", ()) if kind == "backend" else ()
+        verdict = verdicts[-1] if verdicts else None
+        thread = threading.current_thread().name
+        with self._lock:
+            self.n_calls += 1
+            row = self.programs.get(name)
+            if row is None:
+                row = self.programs[name] = {
+                    "count": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                    "hits": 0, "misses": 0,
+                }
+            row[f"{kind}_s"] += secs
+            if kind == "backend":
+                row["count"] += 1
+                row["hits"] += verdicts.count("hit")
+                row["misses"] += verdicts.count("miss")
+            if secs < XLA_SPAN_MIN_S:
+                return
+            if len(self.events) < MAX_COMPILE_EVENTS:
+                self.events.append([kind, name, start, secs, verdict, thread])
+            else:
+                self.n_events_dropped += 1
+        tracer = self._tracer
+        if tracer is not None:
+            # jax stamps time.time(); a chip owner's ring runs on that clock.
+            tracer.add(
+                kind, tracer.now() - (time.time() - start), secs, tid="xla",
+                args={"fun": name, "cache": verdict, "thread": thread},
+            )
+        if self.announce and kind == "backend":
+            under = tracer.open_span("main") if tracer is not None else None
+            where = "no open main span"
+            if under is not None:
+                where = f"under main/{under[0]} update {(under[1] or {}).get('update')}"
+            print(
+                f"[{self._role}] compiled {name} in {secs:.3f} s "
+                f"(cache {verdict or 'off'}) {where}",
+                flush=True,
+            )
 
     def stats(self) -> dict:
+        with self._lock:
+            rows = list(self.programs.values())
         return {
-            "compile_s": round(self.seconds, 3),
-            "cache_hits": self.cache_hits,
-            "cache_misses": self.cache_misses,
+            "compile_s": round(sum(r["backend_s"] for r in rows), 3),
+            "cache_hits": sum(r["hits"] for r in rows),
+            "cache_misses": sum(r["misses"] for r in rows),
         }
+
+    def record(self) -> dict:
+        """``compiles`` of ``backend-<role>.json``: the aggregate, never cut,
+        and the timed events ``[phase, program, start_unix_s, seconds,
+        verdict, thread]``."""
+        with self._lock:
+            return {
+                "listener_calls": self.n_calls,
+                "programs": {k: dict(v) for k, v in self.programs.items()},
+                "events": [list(e) for e in self.events],
+                "events_dropped": self.n_events_dropped,
+            }
 
     def close(self) -> None:
         import jax
 
-        jax.monitoring.unregister_event_duration_listener(self._duration)
+        jax.monitoring.unregister_event_time_span_listener(self._phase)
         jax.monitoring.unregister_event_listener(self._event)
 
 
@@ -153,13 +247,19 @@ class BackendRecord:
     """An accelerator-owning role's bring-up, opened first thing after any
     multihost init: compile cache, device check, one start-up log line, and
     the same facts in ``result_dir/backend-<role>.json`` — rewritten as the
-    run learns which kernel paths its main program took and, at close, what
-    it spent compiling. ``chip_smoke.py`` asserts on the file instead of
-    trusting an exit code."""
+    run learns which kernel paths its main program took, when its first
+    update has finished on the device (``startup``: the recorder's ring so
+    far, which a long run's ring forgets) and, at close, what it spent
+    compiling (three totals and ``compiles``, :meth:`CompileClock.record`).
+    ``chip_smoke.py`` asserts on the file instead of trusting an exit code;
+    the benchmark's ``setup.*`` metrics read ``startup`` and ``compiles``.
+    ``tracer`` is the role's :class:`~tpu_rl.obs.trace.TraceRecorder`, if it
+    has one: compilations then are spans of its lane ``xla`` too."""
 
-    def __init__(self, role: str, cfg, mesh=None):
+    def __init__(self, role: str, cfg, mesh=None, tracer=None):
         cache = enable_compile_cache()
-        self._clock = CompileClock()
+        self._tracer = tracer
+        self._clock = CompileClock(tracer, role)
         require_accelerator(role, cpu_ok=(cfg.learner_device == "cpu"))
         self._result_dir = cfg.result_dir
         self.info = {"role": role, **backend_info(mesh), "compile_cache": cache}
@@ -187,11 +287,35 @@ class BackendRecord:
         )
         self._write()
 
+    def record_startup(
+        self, run_entry: float, loop_entry: float, first_sync_end: float
+    ) -> None:
+        """Called when the role's first blocking read-back has returned (the
+        first instant the program knows an update finished on the device),
+        with three unix stamps: from here on a compilation is news (the
+        clock announces it), and ``startup`` — every span the ring holds,
+        the start-up's lanes ``startup`` and ``xla`` among them — is written
+        into the record, once."""
+        if self._clock is None or self._clock.announce:
+            return
+        self._clock.announce = True
+        if self._result_dir is None or self._tracer is None:
+            return
+        spans, wrapped = self._tracer.entries()
+        self.info["startup"] = {
+            "run_entry_unix_s": run_entry,
+            "loop_entry_unix_s": loop_entry,
+            "first_sync_end_unix_s": first_sync_end,
+            "ring_wrapped": wrapped,
+            "spans": spans,
+        }
+        self._write()
+
     def close(self) -> None:
         """Idempotent (loops close on every exit path)."""
         if self._clock is None:
             return
-        self.info.update(self._clock.stats())
+        self.info.update(self._clock.stats(), compiles=self._clock.record())
         self._clock.close()
         self._clock = None
         self._write()
