@@ -420,6 +420,15 @@ def kernel_checks(
     # two expert cells' shapes (smallthinker-21b-a3b's result at a 4,096-row
     # trip, nemotron-3-nano-30b-a3b's one trip), ragged groups, a dead tail
     row_add_shapes=((4096, 32768, 2560, 16), (12288, 16384, 2688, 8)),
+    # (T, heads, key/value heads, head dim, softmax scale, sliding window, mean
+    # episode length, tile edge or None for the rule's): one row of
+    # smallthinker-21b-a3b's window at its window layer and its global one, seams
+    # drawn as its traffic draws them, the block masks read from them against
+    # the static call
+    seam_shapes=(
+        (16384, 28, 4, 128, 128**-0.5, 4096, 8192, None),
+        (16384, 28, 4, 128, 128**-0.5, None, 8192, None),
+    ),
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -444,14 +453,16 @@ def kernel_checks(
             times.append(time.perf_counter() - t0)
         return round(1e3 * min(times), 3)
 
-    def case(name, fn, ref, args, tol, tol_same, mosaic=True, timed=False):
+    def case(name, fn, ref, args, tol, tol_same, mosaic=True, timed=False, ref_is_kernel=False):
         row = {"kernel": name, "tol": tol, "tol_vs_default": tol_same}
         t0 = time.time()
         try:
             jfn = jax.jit(fn)
             n_mosaic = program_paths(jfn.lower(*args))["mosaic_calls"]
             got = jax.block_until_ready(jfn(*args))
-            with jax.default_matmul_precision("highest"):
+            # a Mosaic kernel as the reference keeps its own precision: the
+            # compiler refuses bf16 operands under "highest"
+            with jax.default_matmul_precision(None if ref_is_kernel else "highest"):
                 want = jax.block_until_ready(jax.jit(ref)(*args))
             row.update(
                 err=_rel_err(got, want),
@@ -588,6 +599,56 @@ def kernel_checks(
             got_fn, ref_fn, (q, k, v), TOL_BF16, TOL_BF16,
             # off-TPU the dispatch substitutes full attention by design
             mosaic=jax.default_backend() == "tpu",
+        )
+
+    # ---- the splash kernels stepping over seam-empty tiles vs the same call
+    # on the static masks: output and the three gradients equal to the bit, both timed
+    import dataclasses
+
+    from jax.experimental.pallas.ops.tpu.splash_attention import SegmentIds
+
+    from tpu_rl.parallel import sequence
+
+    for T, NH, NKV, D, sm_scale, window, episode, edge in seam_shapes:
+        q = (f32(NH, T, D) * sm_scale).astype(jnp.bfloat16)  # the kernel's layout, one row
+        k, v = (f32(NKV, T, D).astype(jnp.bfloat16) for _ in range(2))
+        firsts = rng.random((1, T)) < 1.0 / episode  # benchmarks/traffic.firsts' draw
+        firsts[0, [0, T // 3]] = True  # and one seam whatever the draw
+        seg = jnp.asarray(np.cumsum(firsts, axis=1), jnp.int32)
+        w_o = f32(NH, T, D)
+        bs = _splash_block_sizes(T)
+        if edge is not None:
+            bs = dataclasses.replace(bs, **{
+                f.name: edge for f in dataclasses.fields(bs)
+                if f.name.startswith("block_") and getattr(bs, f.name) is not None})
+        band = sequence.band_tiles(T, bs.block_q, window)
+        n_band = int(band.sum())
+        n_run = int((band & ~np.asarray(sequence.seam_empty_tiles(seg, bs.block_q))[0]).sum())
+
+        def one_row(skip):
+            def fn(q, k, v, seg):
+                splash = sequence._splash_kernel(
+                    T, NH, causal=True, window=window, block_sizes=bs, interpret=interpret)
+                if skip:  # the masks traced from seg, as in the update program
+                    splash = sequence._skip_seams(
+                        splash, sequence.seam_empty_tiles(seg, bs.block_q)[0])
+                ids = SegmentIds(q=seg[0], kv=seg[0])
+
+                def loss(q, k, v):
+                    out = splash(q, k, v, segment_ids=ids)
+                    return (out.astype(jnp.float32) * w_o).sum(), out
+
+                grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+                return (out, *grads)
+
+            return fn
+
+        case(
+            f"flash seam-skipping fwd+bwd T{T}/H{NH}:{NKV}/D{D}"
+            f"{f'/window{window}' if window else ''} bf16 vs the static masks "
+            f"(tiles of {bs.block_q}: {n_run} of the band's {n_band} run)",
+            one_row(True), one_row(False), (q, k, v, seg), 0.0, 0.0, timed=True,
+            ref_is_kernel=True,
         )
 
     # ---- the Pallas scan pair vs the jnp body of ssd_chunked, every gradient

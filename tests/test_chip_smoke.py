@@ -64,9 +64,16 @@ def test_kernel_checks_pass_tiny_interpreted():
                     (300, 3, 4, 16, 64, 48, 0.0, True), (300, 3, 4, 16, 64, 48, 0.75, True),
                     (300, 3, 4, 16, 64, 48, 1.0, True)),
         row_add_shapes=((512, 300, 128, 4),),
+        seam_shapes=((512, 4, 2, 16, 0.25, 160, 128, 128), (512, 4, 2, 16, 0.25, None, 128, 128)),
         interpret=True,
     )
-    assert len(rows) == 16
+    assert len(rows) == 18
+    for row, band in zip(rows[7:9], (9, 10)):  # the seam-skipping rows follow the flash rows
+        assert row["kernel"].startswith("flash seam-skipping") and row["err_vs_default"] == 0
+        run = int(row["kernel"].split(": ")[1].split(" of")[0])
+        assert f"of the band's {band} run" in row["kernel"] and 4 <= run < band
+        assert row["ms"] > 0 and row["ms_ref"] > 0
+    rows = rows[:7] + rows[9:]
     assert rows[10]["kernel"].startswith("row_add 512 rows (439 live) into 300x128")
     assert rows[10]["ms"] > 0 and rows[10]["ms_ref"] > 0 and rows[10]["err"] == 0
     assert "/window48 " in rows[6]["kernel"]

@@ -264,8 +264,6 @@ class TestFlashImpl:
         per head), head dim 64, 4 segments a row. Segment-relative positions
         must still equal causal-by-global-index: positions are monotone within
         a segment and the segment mask kills every cross-segment pair."""
-        import dataclasses
-
         from tpu_rl.parallel.sequence import _splash_mha, _splash_block_sizes
 
         T, H, D = 256, 4, 64
@@ -275,11 +273,7 @@ class TestFlashImpl:
             pos = _segment_relative(seg)
             assert int((np.asarray(pos) == 0).sum(axis=1).min()) >= 4
         scale = 1.0 / np.sqrt(D) if sm_scale is None else sm_scale
-        rule = _splash_block_sizes(T)
-        tiles = dataclasses.replace(rule, **{
-            f.name: 128 for f in dataclasses.fields(rule)
-            if f.name.startswith("block_") and getattr(rule, f.name) is not None
-        })
+        tiles = _tiles_of(_splash_block_sizes(T), 128)
         cot = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
 
         def splash(q, k, v):
@@ -310,8 +304,6 @@ class TestFlashImpl:
         inside a band and behind it. At 160 keys a query's band crosses two tile
         edges (the diagonal tile and up to two behind it, the fourth row's
         first tile never visited); at 128 one. Forward and the three gradients."""
-        import dataclasses
-
         from jax.experimental.pallas.ops.tpu.splash_attention import (
             splash_attention_mask as masks,
             splash_attention_mask_info as mask_info,
@@ -321,11 +313,7 @@ class TestFlashImpl:
         T, H, D, n_kv = 512, 4, 64, 2
         q, k, v, pos, seg = _inputs(rng, T=T, H=H, D=D, n_segments=4)
         k, v = k[:, :, :n_kv], v[:, :, :n_kv]
-        rule = _splash_block_sizes(T)
-        tiles = dataclasses.replace(rule, **{
-            f.name: 128 for f in dataclasses.fields(rule)
-            if f.name.startswith("block_") and getattr(rule, f.name) is not None
-        })
+        tiles = _tiles_of(_splash_block_sizes(T), 128)
         cot = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
 
         def splash(q, k, v):
@@ -413,3 +401,294 @@ class TestFlashImpl:
         lx, vx, _ = fam_x.actor_unroll(params["actor"], obs, None, firsts)
         np.testing.assert_array_equal(np.asarray(lx), np.asarray(lf))
         np.testing.assert_array_equal(np.asarray(vx), np.asarray(vf))
+
+
+# ------------------------------------------------- seam-empty tiles (PR 35)
+def _tiles_of(rule, edge):
+    """The production block sizes with every edge set to ``edge``."""
+    import dataclasses
+
+    return dataclasses.replace(rule, **{
+        f.name: edge for f in dataclasses.fields(rule)
+        if f.name.startswith("block_") and getattr(rule, f.name) is not None
+    })
+
+
+def _seg_from_seams(T, rows):
+    """(B, T) monotone segment ids: row b starts an episode at 0 and at every
+    step of ``rows[b]``."""
+    fir = np.zeros((len(rows), T), np.int32)
+    fir[:, 0] = 1
+    for b, seams in enumerate(rows):
+        fir[b, list(seams)] = 1
+    return np.cumsum(fir, axis=1).astype(np.int32)
+
+
+def _empty_twin(seg, edge):
+    """``seam_empty_tiles`` as loops over numpy rows and blocks."""
+    B, T = seg.shape
+    n = T // edge
+    out = np.zeros((B, n, n), bool)
+    for b in range(B):
+        for i in range(n):
+            qs = seg[b, i * edge:(i + 1) * edge]
+            for j in range(n):
+                ks = seg[b, j * edge:(j + 1) * edge]
+                out[b, i, j] = ks.max() < qs.min() or ks.min() > qs.max()
+    return out
+
+
+def _tiles_with_a_pair(seg, edge):
+    """Brute force over the (T, T) same-segment mask: the tiles that hold at
+    least one query-key pair of equal ids."""
+    B, T = seg.shape
+    n = T // edge
+    same = seg[:, :, None] == seg[:, None, :]
+    return same.reshape(B, n, edge, n, edge).any(axis=(2, 4))
+
+
+# T 512 in tiles of 128: seams by row
+SEAMS = {
+    "no-seam": [(), ()],
+    "inside-a-tile": [(200,), (200,)],
+    "on-a-tile-edge": [(256,), (128, 384)],
+    "every-few-steps": [range(5, 512, 7), range(3, 512, 11)],
+    "two-rows-differ": [(130, 300), (256,)],
+}
+
+
+class TestSeamSkipping:
+    """PR 35: where the splash grid has ``_SEAM_BLOCKS`` blocks an edge or more,
+    ``_splash_mha`` zeroes each row's block-mask entries for the tiles in
+    which no query and key share a segment (``seam_empty_tiles``), so the
+    kernels step over them. Interpret mode on the CPU: the result must equal
+    the static call's to the bit, since the in-kernel segment mask gave those
+    tiles no weight before. The production gate is eight blocks an edge; the
+    cases here run the rule at its own minimum, three (4 x 4 grids of
+    128-tiles), and two tests hold the production value."""
+
+    @pytest.fixture(autouse=True)
+    def three_blocks_engage(self, monkeypatch):
+        from tpu_rl.parallel import sequence
+
+        self.production_gate = sequence._SEAM_BLOCKS
+        monkeypatch.setattr(sequence, "_SEAM_BLOCKS", 3)
+
+    @pytest.mark.parametrize("ids", [*SEAMS, "non-monotone", "random-monotone"])
+    @pytest.mark.parametrize("edge", [128, 32])
+    def test_empty_tiles_against_the_twin_and_a_brute_force_mask(self, rng, ids, edge):
+        from tpu_rl.parallel.sequence import seam_empty_tiles
+
+        T = 512
+        if ids == "non-monotone":  # ids that come back: sound, not complete
+            seg = rng.integers(0, 3, size=(2, T // 16)).repeat(16, axis=1).astype(np.int32)
+            seg[1] = np.where(np.arange(T) < 256, 7, np.where(np.arange(T) % 2, 9, 5))
+        elif ids == "random-monotone":
+            seg = _seg_from_seams(T, [rng.choice(np.arange(1, T), 6, replace=False) for _ in range(3)])
+        else:
+            seg = _seg_from_seams(T, SEAMS[ids])
+        got = np.asarray(seam_empty_tiles(jnp.asarray(seg), edge))
+        assert got.dtype == bool and got.shape == (seg.shape[0], T // edge, T // edge)
+        np.testing.assert_array_equal(got, _empty_twin(seg, edge))
+        np.testing.assert_array_equal(seam_empty_tiles(seg, edge), got)  # numpy in, numpy out
+        holds_a_pair = _tiles_with_a_pair(seg, edge)
+        assert not (got & holds_a_pair).any()  # sound: never a kept pair in a tile called empty
+        assert not got[:, np.arange(T // edge), np.arange(T // edge)].any()  # the diagonal
+        if ids == "non-monotone":
+            assert (~got & ~holds_a_pair).any()  # ranges meet, ids do not: computed, masked inside
+        else:
+            np.testing.assert_array_equal(got, ~holds_a_pair)  # complete for monotone ids
+        if ids == "no-seam":
+            assert not got.any()
+
+    @pytest.mark.parametrize("T,edge,window", [
+        (16384, 1024, None), (16384, 1024, 4096), (4096, 1024, None), (2048, 1024, None),
+        (512, 128, 160), (512, 128, 128), (512, 128, 512), (1024, 128, 129), (1024, 128, 1),
+    ])
+    def test_band_tiles_are_the_librarys_block_mask(self, T, edge, window):
+        """The static band the counters use against what the kernels read:
+        the fused backward's unshrunk [query block, key block] mask."""
+        from jax.experimental.pallas.ops.tpu.splash_attention import (
+            splash_attention_mask as masks,
+            splash_attention_mask_info as mask_info,
+        )
+        from tpu_rl.parallel.sequence import band_tiles
+
+        mask = (masks.CausalMask((T, T)) if window is None
+                else masks.LocalMask((T, T), (window - 1, 0), 0))
+        info, _ = mask_info.process_mask_dkv(
+            masks.MultiHeadMask([mask]), (edge, edge), shrink_grid=False)
+        np.testing.assert_array_equal(band_tiles(T, edge, window), np.asarray(info.block_mask)[0] > 0)
+        if (T, window) == (16384, None):
+            assert band_tiles(T, edge, window).sum() == 136
+        if (T, window) == (16384, 4096):
+            assert band_tiles(T, edge, window).sum() == 70
+
+    @pytest.mark.parametrize("T,edge,window", [
+        (16384, 1024, None), (16384, 1024, 4096), (4096, 1024, None), (512, 128, 160),
+        (512, 128, 128), (1024, 128, 300),
+    ])
+    def test_the_prefetch_indices_follow_the_librarys_rule(self, T, edge, window):
+        """``_next_computed`` on the library's own block masks gives back the
+        library's ``data_next``, in the forward's (shrunk) grid walked query
+        block by query block and in the fused backward's walked key block by
+        key block; with tiles zeroed, every step names the block of the next
+        step that computes."""
+        from jax.experimental.pallas.ops.tpu.splash_attention import (
+            splash_attention_mask as masks,
+            splash_attention_mask_info as mask_info,
+        )
+        from tpu_rl.parallel.sequence import _next_computed
+
+        mask = (masks.CausalMask((T, T)) if window is None
+                else masks.LocalMask((T, T), (window - 1, 0), 0))
+        heads = masks.MultiHeadMask([mask] * 2)
+        fwd, _ = mask_info.process_mask(heads, (edge, edge), head_shards=1, q_seq_shards=1)
+        dkv, _ = mask_info.process_mask_dkv(
+            heads, (edge, edge), head_shards=1, q_seq_shards=1, shrink_grid=False)
+        for info, walk in ((fwd, lambda x: x), (dkv, np.transpose)):
+            block_mask, data_next = (walk(np.asarray(x)[0]) for x in (info.block_mask, info.data_next))
+            got = np.asarray(_next_computed(jnp.asarray(block_mask), jnp.asarray(data_next)))
+            np.testing.assert_array_equal(got, data_next)
+            fewer = block_mask.copy().reshape(-1)
+            computed = np.flatnonzero(fewer)
+            fewer[computed[1::2]] = 0  # every other computed tile emptied
+            got = np.asarray(_next_computed(
+                jnp.asarray(fewer.reshape(block_mask.shape)), jnp.asarray(data_next))).reshape(-1)
+            left = np.flatnonzero(fewer)
+            for at in range(fewer.size):
+                nxt = left[left >= at][0] if (left >= at).any() else left[0]
+                assert got[at] == data_next.reshape(-1)[nxt], (at, nxt)
+
+    @pytest.mark.parametrize("window", [None, 160], ids=["global", "window-160"])
+    @pytest.mark.parametrize("case", [*SEAMS, "grouped-14:2"])
+    def test_seam_skipping_equals_the_static_call_to_the_bit(self, rng, monkeypatch, case, window):
+        """Forward and dq / dk / dv, T 512 in tiles of 128 (a 4 x 4 grid; the
+        window's forward grid shrunk to 3 slots a row), two rows under the
+        batch; 28:4-shaped heads (seven query heads a key head) in the grouped
+        case. Against the static call: equal to the bit. Against
+        ``full_attention``: to float tolerance."""
+        from tpu_rl.parallel import sequence
+        from tpu_rl.parallel.sequence import _splash_mha, _splash_block_sizes, seam_empty_tiles
+
+        T, D = 512, 64
+        H, n_kv = (14, 2) if case == "grouped-14:2" else (4, 2)
+        q, k, v, pos, _ = _inputs(rng, T=T, H=H, D=D)
+        k, v = k[:, :, :n_kv], v[:, :, :n_kv]
+        seg = jnp.asarray(_seg_from_seams(T, SEAMS.get(case, SEAMS["two-rows-differ"])))
+        pos = _segment_relative(seg)
+        tiles = _tiles_of(_splash_block_sizes(T), 128)
+        cot = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
+
+        def splash(q, k, v, seg):
+            out = _splash_mha(
+                q, k, v, seg, causal=True, scale=float(1.0 / np.sqrt(D)),
+                block_sizes=tiles, interpret=True, window=window,
+            )
+            return (out * cot).sum(), out
+
+        def full(q, k, v, seg):
+            kr, vr = (jnp.repeat(x, H // n_kv, axis=2) for x in (k, v))
+            out = full_attention(q, kr, vr, pos, seg, causal=True, window=window)
+            return (out * cot).sum(), out
+
+        # seg an argument: the block masks are traced, as in the update program
+        grad = lambda f: jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))(q, k, v, seg)  # noqa: E731
+        got_g, got = grad(splash)
+        monkeypatch.setattr(sequence, "_SEAM_BLOCKS", 10 ** 9)
+        static_g, static = grad(lambda *a: splash(*a))  # a new function: a new trace
+        want_g, want = grad(full)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(static))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+        for name, g, s, w in zip(("dq", "dk", "dv"), got_g, static_g, want_g):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(s), err_msg=name)
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-5, err_msg=name)
+        skipped = int(np.asarray(seam_empty_tiles(seg, 128))[:, np.tril_indices(4)[0],
+                                                              np.tril_indices(4)[1]].sum())
+        assert (skipped == 0) == (case == "no-seam")
+        if case == "every-few-steps":  # episodes shorter than a tile: a tile two blocks under
+            assert skipped >= 2 * 3  # the diagonal is always empty, the one next to it hardly ever
+
+    def test_without_a_causal_mask_the_tiles_ahead_are_skipped_too(self, rng, monkeypatch):
+        """``causal=False``: a key block wholly in later episodes is as empty as
+        one wholly in earlier ones (``lo[j] > hi[i]``)."""
+        from tpu_rl.parallel import sequence
+        from tpu_rl.parallel.sequence import _splash_mha, _splash_block_sizes
+
+        T, D = 512, 64
+        q, k, v, pos, _ = _inputs(rng, T=T, H=2, D=D)
+        seg = jnp.asarray(_seg_from_seams(T, SEAMS["two-rows-differ"]))
+        call = lambda: jax.jit(lambda q, k, v, seg: _splash_mha(  # noqa: E731
+            q, k, v, seg, causal=False, scale=0.125,
+            block_sizes=_tiles_of(_splash_block_sizes(T), 128), interpret=True))(q, k, v, seg)
+        got = call()
+        monkeypatch.setattr(sequence, "_SEAM_BLOCKS", 10 ** 9)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(call()))
+        want = full_attention(q, k, v, pos, seg, causal=False)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("T,edge,traced", [
+        (256, 128, False), (2048, 1024, False), (4096, 1024, False), (896, 128, False),
+        (1024, 128, True), (16384, 1024, True)])
+    def test_a_grid_under_the_gate_takes_the_static_call(self, monkeypatch, T, edge, traced):
+        """Chosen by shape, at the production gate: under eight blocks an edge
+        (``tf-longctx``: T 2,048 in tiles of 1,024; granite and nemotron: 4,096)
+        the program is the static call's, with no mask info read from the
+        segment ids; from eight on (smallthinker: 16) the block masks are traced."""
+        from tpu_rl.parallel import sequence
+        from tpu_rl.parallel.sequence import _splash_mha, _splash_block_sizes
+
+        assert self.production_gate == 8
+        monkeypatch.setattr(sequence, "_SEAM_BLOCKS", self.production_gate)
+        assert _splash_block_sizes(T).block_q == edge or edge == 128
+        tiles = _tiles_of(_splash_block_sizes(T), edge)
+        shapes = [jax.ShapeDtypeStruct((2, T, 4, 64), jnp.float32)] * 3 + [
+            jax.ShapeDtypeStruct((2, T), jnp.int32)]
+
+        def program():
+            def f(q, k, v, seg):
+                return _splash_mha(q, k, v, seg, causal=True, scale=0.125, block_sizes=tiles,
+                                   interpret=True).sum()
+            return str(jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(*shapes))
+
+        here = program()
+        monkeypatch.setattr(sequence, "_SEAM_BLOCKS", 10 ** 9)
+        static = program()
+        assert "reduce_min" not in static
+        assert (here != static) == traced and ("reduce_min" in here) == traced
+
+    @pytest.mark.parametrize("window", [None, 160, 128], ids=["global", "window-160", "one-tile"])
+    @pytest.mark.parametrize("case", list(SEAMS))
+    def test_the_tile_counters_against_a_count(self, case, window):
+        from tpu_rl.parallel.sequence import attention_tiles, band_tiles
+
+        T, edge = 512, 128
+        seg = _seg_from_seams(T, SEAMS[case])
+        band = band_tiles(T, edge, window)
+        want_run = sum(int((band & ~e).sum()) for e in _empty_twin(seg, edge))
+        run, total = jax.jit(lambda s: attention_tiles(s, window, edge))(jnp.asarray(seg))
+        assert (float(run), float(total)) == (want_run, 2 * band.sum())
+        assert run.dtype == total.dtype == jnp.float32
+        if case == "no-seam":
+            assert float(run) == float(total)
+        if case == "two-rows-differ" and window is None:
+            assert (float(run), float(total)) == (13.0, 20.0)
+
+    @pytest.mark.parametrize("T,edge_in,blocks", [(2048, None, 2), (4096, None, 4), (16384, None, 16),
+                                                  (256, 128, 2), (32, None, 1)])
+    def test_the_tile_counters_follow_the_kernels_gate_and_edge(self, monkeypatch, T, edge_in, blocks):
+        """The edge is the kernel's own (gcd(1024, T)); where the grid is too
+        small for the traced masks every band tile runs, whatever the seams."""
+        from tpu_rl.parallel import sequence
+        from tpu_rl.parallel.sequence import attention_tiles, band_tiles
+
+        monkeypatch.setattr(sequence, "_SEAM_BLOCKS", self.production_gate)
+        edge = edge_in or T // blocks
+        seg = _seg_from_seams(T, [(T // 2,)])  # empties the tile under the diagonal of a 2 x 2 grid
+        run, total = attention_tiles(jnp.asarray(seg), None, edge_in)
+        band = band_tiles(T, edge)
+        assert band.shape == (blocks, blocks) and float(total) == band.sum()
+        static = blocks < self.production_gate
+        want = band.sum() if static else (band & ~_empty_twin(seg, edge)[0]).sum()
+        assert float(run) == want and (static or want < band.sum())

@@ -351,9 +351,52 @@ def test_the_pair_counters_reach_the_diagnostics(actor, system):
     assert float(scalars["attn-pairs-window"]) == 3 * per_window  # three window layers
     assert 0.5 < per_window / per_global < 0.9  # the window cuts pairs on this batch
     # beside the routing, not in it: the routing's counters are the expert block's alone
-    assert set(scalars) == {"attn-pairs-global", "attn-pairs-window"}
+    assert set(scalars) == {f"attn-{what}-{kind}" for what in ("pairs", "tiles-run", "tiles-band")
+                            for kind in ("global", "window")}
     assert not any(key.startswith("attn") for key in learn.route_scalars(routes))
     assert learn.attention_scalars([{"stats": {}}]) == {}  # a family that counts no pairs
+
+
+@pytest.mark.parametrize("kind,layers", [("global", 1), ("window", 3)])
+def test_the_tile_counters_reach_the_diagnostics(actor, system, kind, layers):
+    """At T 32 the kernels' grid is one tile (edge gcd(1024, 32)): under the
+    blocks the traced masks are taken from, so every band tile runs and the layer
+    hands back rows x 1 for both counters, summed over the layers of its kind."""
+    from tpu_rl.obs import learn
+
+    routes = system(actor, make_batch(16, firsts=(13,)))[2]
+    assert [set(r["attn-tiles-run"]) for r in routes] == [{"global"}, *[{"window"}] * 3]
+    scalars = learn.attention_scalars(routes)
+    assert float(scalars[f"attn-tiles-run-{kind}"]) == layers * B
+    assert float(scalars[f"attn-tiles-band-{kind}"]) == layers * B
+
+
+@pytest.mark.parametrize("window", [None, 12], ids=["global", "window"])
+def test_the_tile_counters_fold_like_the_layers_own_counts(window):
+    """``attention_scalars`` over four layers' records against the numpy count
+    of one layer's tiles at an edge small enough for a 8 x 8 grid: the sum
+    over the layers of a kind, run and band apart."""
+    from tpu_rl.obs import learn
+    from tpu_rl.parallel.sequence import attention_tiles, band_tiles, seam_empty_tiles
+
+    edge = 4
+    fir = np.zeros((2, T), np.int32)
+    fir[:, 0] = 1
+    fir[0, [9, 20]] = 1
+    fir[1, [16]] = 1
+    seg = np.cumsum(fir, axis=1).astype(np.int32)
+    band = band_tiles(T, edge, window)
+    want_run = sum(int((band & ~e).sum()) for e in seam_empty_tiles(seg, edge))
+    run, total = attention_tiles(jnp.asarray(seg), window, edge)
+    assert (float(run), float(total)) == (want_run, 2 * band.sum()) and want_run < 2 * band.sum()
+    kind = "global" if window is None else "window"
+    layer = {"attn-pairs": {kind: 1.0}, "attn-tiles-run": {kind: run}, "attn-tiles-band": {kind: total}}
+    other = {"attn-tiles-run": {"other": jnp.float32(5)}, "attn-tiles-band": {"other": jnp.float32(7)}}
+    scalars = learn.attention_scalars([layer, other, layer, layer])
+    assert float(scalars[f"attn-tiles-run-{kind}"]) == 3 * want_run
+    assert float(scalars[f"attn-tiles-band-{kind}"]) == 3 * 2 * band.sum()
+    assert (float(scalars["attn-tiles-run-other"]), float(scalars["attn-tiles-band-other"])) == (5, 7)
+    assert float(scalars[f"attn-pairs-{kind}"]) == 3.0
 
 
 # --------------------------------------------------------------- the rotation

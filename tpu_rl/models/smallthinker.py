@@ -34,8 +34,11 @@ slots, a window layer's ``sliding_window_size`` whatever ``act_ctx`` is; a
 RoPE layer's ring stores its keys as rotated at their own step.
 
 ``unroll_routed`` also returns each layer's routing record, and beside the
-routing in it the query-key pairs the layer's mask kept (``attn-pairs``:
-``global`` or ``window`` -> the count; ``obs/learn.attention_scalars``).
+routing in it what the layer's attention mask did (``global`` or ``window`` ->
+the count; ``obs/learn.attention_scalars``): the query-key pairs it kept
+(``attn-pairs``), and of the splash kernels' grid the tiles of the static band
+(``attn-tiles-band``) and those of them that no seam emptied, which the
+kernels compute (``attn-tiles-run``; ``parallel/sequence.attention_tiles``).
 """
 
 from __future__ import annotations
@@ -48,6 +51,7 @@ import jax.numpy as jnp
 
 from tpu_rl.models.granite_hybrid import GQAttention, RMSNorm
 from tpu_rl.models.nemotron_h import ExpertBlock, NemotronHActorCritic, expert_share
+from tpu_rl.parallel.sequence import attention_tiles
 
 
 def ring_slots(arch: dict, ctx: int) -> list[int]:
@@ -117,6 +121,8 @@ class SmallThinkerLayer(nn.Module):
         with jax.named_scope("moe"):
             mixed, route = self.experts(self.post_norm(x), scored=a)
         route["attn-pairs"] = {self.span: kept_pairs(seg, self.window)}
+        run, band = attention_tiles(seg, self.window)
+        route["attn-tiles-run"], route["attn-tiles-band"] = {self.span: run}, {self.span: band}
         return x + mixed, route
 
     def step(self, x, k_cache, v_cache, count):

@@ -125,15 +125,18 @@ def route_scalars(routes: list) -> dict[str, jax.Array]:
 
 
 def attention_scalars(routes: list) -> dict[str, jax.Array]:
-    """Query-key pairs the attention masks kept this update, from what the
-    layers handed back beside their routing (``attn-pairs``: kind -> count;
-    ``models/smallthinker.py``): ``attn-pairs-global`` and
-    ``attn-pairs-window``, each summed over the layers of its kind. Empty
-    for a family that counts none."""
+    """What the attention masks did this update, from what the layers handed
+    back beside their routing (counter -> kind -> count;
+    ``models/smallthinker.py``), each summed over the layers of its kind:
+    the query-key pairs kept (``attn-pairs-global``, ``attn-pairs-window``),
+    the tiles of the splash kernels' static band (``attn-tiles-band-*``) and
+    those of them the kernels computed because no seam emptied them
+    (``attn-tiles-run-*``). Empty for a family that counts none."""
     out: dict[str, jax.Array] = {}
     for r in routes:
-        for kind, pairs in r.get("attn-pairs", {}).items():
-            out[f"attn-pairs-{kind}"] = out.get(f"attn-pairs-{kind}", 0.0) + pairs
+        for counter in ("attn-pairs", "attn-tiles-run", "attn-tiles-band"):
+            for kind, count in r.get(counter, {}).items():
+                out[f"{counter}-{kind}"] = out.get(f"{counter}-{kind}", 0.0) + count
     return out
 
 

@@ -45,6 +45,11 @@ SEQ_AXIS = "seq"
 _NEG_INF = -1e30  # finite -inf stand-in: keeps exp()/max() NaN-free
 
 
+def _splash_edge(T: int) -> int:
+    """The edge of the splash kernel's tiles at sequence length T."""
+    return math.gcd(1024, T)
+
+
 def _splash_block_sizes(T: int):
     """Tiles of the splash kernel (``jax.experimental.pallas.ops.tpu
     .splash_attention.BlockSizes``) for sequence length T; None = the kernel
@@ -83,7 +88,7 @@ def _splash_block_sizes(T: int):
         return None
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes, QKVLayout
 
-    edge = math.gcd(1024, T)
+    edge = _splash_edge(T)
     compute = min(512, edge)
     return BlockSizes(
         block_q=edge, block_kv=edge, block_kv_compute=compute,
@@ -94,6 +99,128 @@ def _splash_block_sizes(T: int):
     )
 
 
+# The splash grid's edge, in blocks, from which each row's block masks are read
+# from its segment ids. The rule needs three (the diagonal tile is never empty,
+# the one next to it only when a seam falls on the very edge between them). It
+# is eight because the traced masks cost a walk over the rows
+# (:func:`_splash_rows_skipping_seams`), and at a 4 x 4 grid that walk over
+# nemotron-3-nano's four rows raised the cell's peak device memory by 2% (the
+# update program's temporaries +0.8 GiB compiled for a v5e; PERF.md section 6,
+# PR 35) for 3 of 10 tiles a seam can empty; at 16 x 16 it is 105 of 136.
+_SEAM_BLOCKS = 8
+
+
+def seam_empty_tiles(seg, edge: int):
+    """Tiles of the (T / edge)^2 attention grid in which no query and key
+    share a segment: seg (B, T) -> (B, T / edge, T / edge) bool, indexed
+    [row, query block, key block]. From each block's smallest and largest id:
+    two blocks whose id ranges do not meet hold no equal pair. Sound for any
+    ids; complete for the monotone ids every caller passes
+    (:func:`segment_ids_from_firsts`), where a range has no gaps. The diagonal
+    is never empty. O(T); takes ``jnp`` and ``numpy`` arrays alike."""
+    blocks = seg.reshape(seg.shape[0], seg.shape[1] // edge, edge)
+    lo, hi = blocks.min(axis=2), blocks.max(axis=2)
+    return (hi[:, None, :] < lo[:, :, None]) | (lo[:, None, :] > hi[:, :, None])
+
+
+def band_tiles(T: int, edge: int, window: int | None = None) -> np.ndarray:
+    """Tiles of that grid the static causal mask keeps, (T / edge, T / edge)
+    bool [query block, key block]: on or under the diagonal and, with
+    ``window``, holding a pair less than ``window`` steps apart. The nonzero
+    entries of the library's block mask (``tests/test_sequence_parallel.py``
+    holds the two together)."""
+    i = np.arange(T // edge)
+    behind = i[:, None] - i[None, :]  # key blocks behind the query's
+    band = behind >= 0
+    if window is not None:
+        band &= (behind - 1) * edge + 1 < window
+    return band
+
+
+def attention_tiles(seg, window: int | None = None, edge: int | None = None):
+    """What the splash kernels do with a batch of windows, counted from
+    ``seg`` (B, T): ``(run, band)`` — the tiles they compute (in the static
+    band and not emptied by a seam) and the static band's, each summed over
+    the rows, float32. ``edge``: the tile's, :func:`_splash_edge`'s by
+    default. Where the grid is too small for :func:`_splash_mha` to read the
+    seams, every band tile runs."""
+    T = seg.shape[1]
+    edge = _splash_edge(T) if edge is None else edge
+    band = band_tiles(T, edge, window)
+    total = jnp.float32(seg.shape[0] * int(band.sum()))
+    if T // edge < _SEAM_BLOCKS:
+        return total, total
+    run = jnp.asarray(band) & ~seam_empty_tiles(seg, edge)
+    return jnp.sum(run.astype(jnp.float32)), total
+
+
+def _next_computed(block_mask, data_next):
+    """``data_next`` of one head's (rows, columns) grid for a block mask with
+    more zeros than the one it was made for: the kernels walk the grid row by
+    row and, at a step they skip, prefetch the block ``data_next`` names —
+    so each step names the block of the next step that computes (its own if
+    it does, the first one's after the last), as the library does for its
+    static zeros, and a skipped tile costs no fetch."""
+    n = block_mask.size
+    at = jnp.where(block_mask.reshape(n) > 0, jnp.arange(n), n)
+    nxt = jax.lax.cummin(at, reverse=True)
+    nxt = jnp.where(nxt == n, jnp.min(at) % n, nxt)
+    return data_next.reshape(n)[nxt].reshape(data_next.shape)
+
+
+def _skip_seams(splash, empty):
+    """``splash`` (a ``SplashAttentionKernel`` over static mask info) with the
+    tiles of ``empty`` (T / edge, T / edge; one row of
+    :func:`seam_empty_tiles`) zeroed in both block masks, so none of the three
+    kernels computes them, and the prefetch indices following
+    (:func:`_next_computed`), so none fetches their blocks. Zeros are only
+    added: an entry of 1 or 2 and the in-kernel segment mask mean what they
+    meant, and a wrong "not empty" costs time, never correctness. The
+    forward's grid is shrunk to the band — a column is a slot, and the static
+    ``data_next`` says which key block the slot holds; the fused backward's is
+    the whole [query block, key block] grid, walked key block by key block."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel
+
+    fwd, dkv = splash.fwd_mask_info, splash.dkv_mask_info
+    assert splash.dq_mask_info is None and dkv.block_mask.shape[1:] == empty.shape
+    slot_block = fwd.data_next[0].astype(jnp.int32)  # (query blocks, slots)
+    in_slot = jnp.take_along_axis(empty, slot_block, axis=1)
+
+    def zeroed(info, e, walk):
+        mask = jnp.where(e[None], 0, info.block_mask).astype(info.block_mask.dtype)
+        nxt = walk(_next_computed(walk(mask[0]), walk(info.data_next[0])))
+        return info._replace(block_mask=mask, data_next=nxt[None].astype(info.data_next.dtype))
+
+    return splash_attention_kernel.SplashAttentionKernel(
+        zeroed(fwd, in_slot, lambda x: x), None, zeroed(dkv, empty, jnp.transpose),
+        **splash.kwargs)
+
+
+def _splash_kernel(T, H, *, causal, window, block_sizes, interpret):
+    """The library's kernel object over the static mask of ``H`` equal heads:
+    causal (or full), and inside ``window`` where one is given."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        CausalMask,
+        FullMask,
+        LocalMask,
+        MultiHeadMask,
+        make_splash_mha,
+    )
+
+    if window is None:
+        head_mask = (CausalMask if causal else FullMask)((T, T))
+    else:
+        assert causal, "a window is the causal one: the keys that end with the query"
+        head_mask = LocalMask((T, T), window_size=(window - 1, 0), offset=0)
+    return make_splash_mha(
+        MultiHeadMask([head_mask] * H),
+        block_sizes=block_sizes,
+        head_shards=1,
+        q_seq_shards=1,
+        interpret=interpret,
+    )
+
+
 def _splash_mha(q, k, v, seg, *, causal, scale, block_sizes, interpret=False, window=None):
     """The splash kernel on this module's layout: q (B, T, H, D), k and v
     (B, T, Hkv, D) with ``H % Hkv == 0`` (every key/value head serves
@@ -101,34 +228,26 @@ def _splash_mha(q, k, v, seg, *, causal, scale, block_sizes, interpret=False, wi
     index plus same-segment, built per trace: the block-sparse mask info is
     numpy work on a (T / tile)^2 grid. With ``window`` (causal only) a query
     sees the ``window`` keys that end with itself (the library's ``LocalMask``):
-    the mask info then names the tiles inside the band alone, so the kernel
-    never visits a tile that lies wholly behind it. The kernel takes no
-    softmax scale, so ``scale`` is folded into q first — exact in bf16 for a
-    power of two (tf-longctx: 1/8; granite: 1/64); a general scale (head size
-    128: 128^-0.5) rounds q once more.
+    the mask info then names the tiles inside the band alone. The forward's
+    grid is shrunk to them, so it never visits a tile that lies wholly behind
+    the band; the fused backward's is not (``shrink_grid=not
+    use_fused_bwd_kernel``): it steps over every tile of the grid and computes
+    the band's. Where the grid has ``_SEAM_BLOCKS`` blocks an edge or more,
+    each row's block masks are read from its segment ids
+    (:func:`_splash_rows_skipping_seams`): a band tile in which no query and
+    key share a segment is stepped over too, forward and backward, instead of
+    computed and masked whole inside the kernel. A smaller grid keeps the
+    static masks and lowers to the program it always did.
+    The kernel takes no softmax scale, so ``scale`` is folded into q first —
+    exact in bf16 for a power of two (tf-longctx: 1/8; granite: 1/64); a
+    general scale (head size 128: 128^-0.5) rounds q once more.
     ``interpret=True`` runs the same construction on the CPU (tier-1 tests)."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        CausalMask,
-        FullMask,
-        LocalMask,
-        MultiHeadMask,
-        SegmentIds,
-        make_splash_mha,
-    )
+    from jax.experimental.pallas.ops.tpu.splash_attention import SegmentIds
 
-    T, H = q.shape[1], q.shape[2]
-    if window is None:
-        head_mask = (CausalMask if causal else FullMask)((T, T))
-    else:
-        assert causal, "a window is the causal one: the keys that end with the query"
-        head_mask = LocalMask((T, T), window_size=(window - 1, 0), offset=0)
-    splash = make_splash_mha(
-        MultiHeadMask([head_mask] * H),
-        block_sizes=block_sizes,
-        head_shards=1,
-        q_seq_shards=1,
-        interpret=interpret,
-    )
+    build = dict(causal=causal, window=window, block_sizes=block_sizes, interpret=interpret)
+    if q.shape[1] // block_sizes.block_q >= _SEAM_BLOCKS:
+        return _splash_rows_skipping_seams(q, k, v, seg, scale=scale, **build)
+    splash = _splash_kernel(q.shape[1], q.shape[2], **build)
     # our layout (B, T, H, D) -> kernel layout (H, T, D), one batch row a call
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q * scale, k, v))
     seg32 = seg.astype(jnp.int32)
@@ -136,6 +255,37 @@ def _splash_mha(q, k, v, seg, *, causal, scale, block_sizes, interpret=False, wi
         lambda q, k, v, s: splash(q, k, v, segment_ids=SegmentIds(q=s, kv=s))
     )(qt, kt, vt, seg32)
     return o.transpose(0, 2, 1, 3)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "scale", "block_sizes", "interpret", "window"))
+def _splash_rows_skipping_seams(q, k, v, seg, *, causal, scale, block_sizes, interpret, window):
+    """:func:`_splash_mha` with each row's block masks read from its segment
+    ids. One splash call a row, in a Python loop: a ``jax.vmap`` over the
+    traced scalar-prefetch operands makes Pallas loop over the rows inside one
+    ``while`` whose state holds every row's dq partials and takes a copy of
+    each kernel output (7.8 GiB of temporaries a layer at T 16,384 against
+    this loop's 2.2 and the static call's 3.8, compiled for a v5e), and with
+    one call a row only one row's partials are alive at a time. Under one
+    ``jax.jit``: an eager caller dispatches one program, and the layers of a
+    model that share shapes and window are traced once."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import SegmentIds
+
+    splash = _splash_kernel(
+        q.shape[1], q.shape[2], causal=causal, window=window, block_sizes=block_sizes,
+        interpret=interpret)
+    seg32 = seg.astype(jnp.int32)
+    empty = seam_empty_tiles(seg32, block_sizes.block_q)
+    rows = [
+        # our layout (T, H, D) -> kernel layout (H, T, D) and back, row by row:
+        # each a pass XLA fuses, none over a stacked batch
+        _skip_seams(splash, empty[b])(
+            *(x.transpose(1, 0, 2) for x in (q[b] * scale, k[b], v[b])),
+            segment_ids=SegmentIds(q=seg32[b], kv=seg32[b]),
+        ).transpose(1, 0, 2)
+        for b in range(q.shape[0])
+    ]
+    return jnp.stack(rows)
 
 
 def make_sp_mesh(n_data: int, n_seq: int, devices=None) -> Mesh:
