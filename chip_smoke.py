@@ -380,6 +380,17 @@ TOL_F32 = 2e-2
 TOL_LSTM_SAME = 5e-3
 TOL_ACT_SAME = 1e-5
 TOL_BF16 = 3e-2
+# a whole mixer in bf16 against the float32 reference: projections, the kernel
+# or the scan, and projections again, every gradient through all three
+TOL_MIXER_BF16 = 6e-2
+# The widths of Qwen3-Next-80B-A3B-Instruct's two mixers as published
+# (benchmarks/configs/qwen3-next-80b-a3b.json holds the whole configuration).
+QWEN3_NEXT_MIXERS = dict(
+    hidden_size=2048, rms_norm_eps=1e-6, linear_num_key_heads=16, linear_num_value_heads=32,
+    linear_key_head_dim=128, linear_value_head_dim=128, linear_conv_kernel_dim=4,
+    num_attention_heads=16, num_key_value_heads=2, head_dim=256, rope_theta=10000000,
+    partial_rotary_factor=0.25,
+)
 
 
 def kernel_checks(
@@ -425,10 +436,21 @@ def kernel_checks(
     # smallthinker-21b-a3b's window at its window layer and its global one, seams
     # drawn as its traffic draws them, the block masks read from them against
     # the static call
+    # then one row of qwen3-next-80b-a3b's window at its full-attention layer: the
+    # same skip at heads of 256
     seam_shapes=(
         (16384, 28, 4, 128, 128**-0.5, 4096, 8192, None),
         (16384, 28, 4, 128, 128**-0.5, None, 8192, None),
+        (8192, 16, 2, 256, 256**-0.5, None, 2048, None),
     ),
+    # (mixer, B, T): qwen3-next-80b-a3b's Gated-DeltaNet mixer (a window of one
+    # span of sixteen chunks and a ragged second one) and its gated attention
+    # mixer (heads of 256, q/k norm, partial RoPE, the output gate) at the widths
+    # of ``qwen3_next_widths``, forward and every gradient against
+    # benchmarks/reference/qwen3_next.py (the step recurrence; dense masked
+    # attention, whose scores at T 8,192 would not fit beside their gradients)
+    qwen3_next_shapes=(("linear", 1, 1536), ("attention", 1, 4096)),
+    qwen3_next_widths=QWEN3_NEXT_MIXERS,
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -749,7 +771,7 @@ def kernel_checks(
             """The block's output and, for a fixed cotangent, every gradient
             (four; five with the gated form's third leaf)."""
             def weighted(u, weight, w_in, w_out, *w_gate):
-                gate = dict(w_gate=w_gate[0]) if w_gate else {}
+                gate = dict(w_gate=w_gate[0], form="reglu") if w_gate else {}
                 if dense:
                     y = moe.routed_experts_dense(u, choice, weight, w_in, w_out, 0, **gate)
                 else:
@@ -769,6 +791,48 @@ def kernel_checks(
             (as_run(f32(n, d)), jnp.asarray(rng.random((n, k)) + 0.1, jnp.float32),
              as_run(f32(held, d, f) * d**-0.5), as_run(f32(held, f, d) * f**-0.5),
              *([as_run(f32(held, d, f) * d**-0.5)] if gated else [])), TOL_BF16, TOL_BF16,
+        )
+
+    # ---- qwen3_next's two mixers in bf16 vs the plain float32 reference
+    from benchmarks.reference import qwen3_next as plain
+    from tpu_rl.models.qwen3_next import _conv_channels, build_mixer
+
+    arch = dict(qwen3_next_widths)
+    for kind, B, T in qwen3_next_shapes:
+        mixer = build_mixer(arch, kind, jnp.bfloat16).clone(name=None)
+        u = f32(B, T, arch["hidden_size"])
+        firsts = rng.random((B, T)) < 4.0 / T  # ~4 episode seams a window
+        firsts[:, [T // 3, T // 3 + 1]] = True  # and two in one chunk whatever the draw
+        seg = jnp.asarray(np.cumsum(firsts, axis=1).astype(np.int32))
+        first = jnp.asarray(firsts)
+        carry = ()
+        if kind == "linear":
+            state = (arch["linear_num_value_heads"], arch["linear_key_head_dim"],
+                     arch["linear_value_head_dim"])
+            carry = (jnp.zeros((B, *state)),
+                     jnp.zeros((B, arch["linear_conv_kernel_dim"] - 1, _conv_channels(arch))))
+
+        params = jax.jit(lambda key: mixer.init(key, u, seg, *carry)["params"])(
+            jax.random.key(SEED))
+        w_y = f32(B, T, arch["hidden_size"])
+
+        def system(p, u):
+            y = mixer.apply({"params": p}, u, seg, *carry)
+            y = y[0] if kind == "linear" else y
+            return (y * w_y).sum(), y
+
+        def reference(p, u):
+            y = (plain.linear_attention if kind == "linear" else plain.attention)(
+                u, first, p, arch)
+            return (y * w_y).sum(), y
+
+        case(
+            f"qwen3_next {kind} mixer fwd+bwd B{B}/T{T} bf16 vs the plain reference "
+            f"({int(firsts.sum())} seams)",
+            jax.value_and_grad(system, argnums=(0, 1), has_aux=True),
+            jax.value_and_grad(reference, argnums=(0, 1), has_aux=True),
+            (params, u), TOL_MIXER_BF16, TOL_MIXER_BF16,
+            mosaic=kind == "attention" and jax.default_backend() == "tpu",
         )
     return rows
 

@@ -65,9 +65,18 @@ def test_kernel_checks_pass_tiny_interpreted():
                     (300, 3, 4, 16, 64, 48, 1.0, True)),
         row_add_shapes=((512, 300, 128, 4),),
         seam_shapes=((512, 4, 2, 16, 0.25, 160, 128, 128), (512, 4, 2, 16, 0.25, None, 128, 128)),
+        qwen3_next_shapes=(("linear", 2, 96), ("attention", 2, 128)),
+        qwen3_next_widths=dict(
+            hidden_size=64, rms_norm_eps=1e-6, linear_num_key_heads=2, linear_num_value_heads=4,
+            linear_key_head_dim=16, linear_value_head_dim=16, linear_conv_kernel_dim=4,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=32, rope_theta=10000000,
+            partial_rotary_factor=0.25),
         interpret=True,
     )
-    assert len(rows) == 18
+    assert len(rows) == 20
+    for row, kind in zip(rows[-2:], ("linear", "attention")):  # the two mixers come last
+        assert row["kernel"].startswith(f"qwen3_next {kind} mixer fwd+bwd") and row["err"] > 0
+    rows = rows[:-2]
     for row, band in zip(rows[7:9], (9, 10)):  # the seam-skipping rows follow the flash rows
         assert row["kernel"].startswith("flash seam-skipping") and row["err_vs_default"] == 0
         run = int(row["kernel"].split(": ")[1].split(" of")[0])

@@ -392,7 +392,8 @@ def test_the_walk_with_the_row_add_kernel_is_the_walk_without(gated):
         assert moe._row_add_gate(CHUNK, WIDE, kernel)[0] == kernel[0]
 
         def loss(u, wt, a, b, g):
-            y = moe.routed_experts(u, choice, wt, a, b, first, kernel=kernel, chunk=CHUNK, w_gate=g)
+            y = moe.routed_experts(u, choice, wt, a, b, first, kernel=kernel, chunk=CHUNK, w_gate=g,
+                                   form="reglu" if gated else "relu2")
             return jnp.sum(probe * y), y
 
         argnums = (0, 1, 2, 3, 4) if gated else (0, 1, 2, 3)

@@ -467,11 +467,12 @@ def test_the_gated_walk_against_the_dense_form(kernel, chunk):
 
     def walked(u, weight, w_gate, w_in, w_out):
         return jnp.sum(probe * moe.routed_experts(
-            u, choice, weight, w_in, w_out, FIRST, kernel=kernel, chunk=chunk, w_gate=w_gate))
+            u, choice, weight, w_in, w_out, FIRST, kernel=kernel, chunk=chunk, w_gate=w_gate,
+            form="reglu"))
 
     def dense(u, weight, w_gate, w_in, w_out):
         return jnp.sum(probe * moe.routed_experts_dense(
-            u, choice, weight, w_in, w_out, FIRST, w_gate=w_gate))
+            u, choice, weight, w_in, w_out, FIRST, w_gate=w_gate, form="reglu"))
 
     args = (u, weight, w_gate, w_in, w_out)
     got, got_grads = jax.jit(jax.value_and_grad(walked, argnums=range(5)))(*args)
@@ -482,7 +483,7 @@ def test_the_gated_walk_against_the_dense_form(kernel, chunk):
         close(g, w, 1e-4 * (1.0 + float(jnp.abs(w).max())))
     # by the definition, an assignment at a time, at a few tokens
     y = moe.routed_experts(u, choice, weight, w_in, w_out, FIRST, kernel=kernel, chunk=chunk,
-                           w_gate=w_gate)
+                           w_gate=w_gate, form="reglu")
     for n in (0, 7, N - 1):
         want_row = sum(
             float(weight[n, j]) * (np.maximum(u[n] @ w_gate[e - FIRST], 0) * (u[n] @ w_in[e - FIRST]))

@@ -190,11 +190,54 @@ def _check_smallthinker_arch(arch: dict | None) -> None:
     )
 
 
+# The keys of a Qwen3-Next ``config.json`` that shape the policy core
+# (``models/qwen3_next.py``); ``expert_parallel`` as above, with
+# ``num_experts`` the count one rank holds.
+QWEN3_NEXT_ARCH_KEYS = (
+    "hidden_size", "num_hidden_layers", "full_attention_interval", "rms_norm_eps",
+    "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
+    "linear_value_head_dim", "linear_conv_kernel_dim", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "rope_theta", "partial_rotary_factor",
+    "moe_intermediate_size", "shared_expert_intermediate_size", "num_experts",
+    "num_experts_per_tok", "norm_topk_prob", "decoder_sparse_step", "mlp_only_layers",
+)
+
+
+def _check_qwen3_next_arch(arch: dict | None) -> None:
+    """What ``model="qwen3_next"`` can build: Gated-DeltaNet linear-attention
+    layers with a gated full-attention layer (q/k norm, partial rotary
+    positions) every ``full_attention_interval``-th, every layer followed by
+    ``swiglu`` experts under a softmax router over the chosen logits and a
+    gated shared expert; no bias anywhere, no dense MLP layer, no window."""
+    assert isinstance(arch, dict), "model='qwen3_next' needs arch (config.json keys)"
+    missing = [k for k in QWEN3_NEXT_ARCH_KEYS if k not in arch]
+    assert not missing, f"arch lacks {missing}"
+    assert arch["num_hidden_layers"] >= 1 and arch["full_attention_interval"] >= 1
+    assert not arch["mlp_only_layers"], "dense MLP layers are not built"
+    assert arch["decoder_sparse_step"] == 1, "every layer has an expert block"
+    assert not arch.get("use_sliding_window", False), "the full layers have no window"
+    assert arch.get("rope_scaling") is None, "rotary scaling is not built"
+    assert arch.get("hidden_act", "silu") == "silu", arch.get("hidden_act")
+    assert not arch.get("attention_bias", False), "attention projections have no bias"
+    assert arch["norm_topk_prob"], "the router is the softmax over the chosen logits"
+    rotary = arch["head_dim"] * arch["partial_rotary_factor"]
+    assert rotary == int(rotary) and int(rotary) % 2 == 0 and 0 < rotary <= arch["head_dim"], (
+        f"partial_rotary_factor {arch['partial_rotary_factor']}: rotate-half pairs the halves "
+        f"of the first {rotary} features"
+    )
+    assert arch["linear_num_value_heads"] % arch["linear_num_key_heads"] == 0
+    assert arch["num_attention_heads"] % arch["num_key_value_heads"] == 0
+    assert arch["linear_conv_kernel_dim"] >= 2, arch["linear_conv_kernel_dim"]
+    assert arch["shared_expert_intermediate_size"] >= 1, "one gated shared expert is built"
+    _check_expert_share(arch, arch["num_experts"], arch["num_experts_per_tok"])
+
+
 # The families built from a published config.json in ``Config.arch``.
 ARCH_CHECKS = {
     "granite_hybrid": _check_granite_arch,
     "nemotron_h": _check_nemotron_arch,
     "smallthinker": _check_smallthinker_arch,
+    "qwen3_next": _check_qwen3_next_arch,
 }
 
 
@@ -223,7 +266,9 @@ class Config:
     # GraniteMoeHybrid config.json, given whole in ``arch``) or "nemotron_h"
     # (Mamba-2, attention and sparse-expert layers of a NemotronH config.json)
     # or "smallthinker" (global and sliding-window attention, each layer with
-    # gated sparse experts, of a SmallThinker config.json).
+    # gated sparse experts, of a SmallThinker config.json) or "qwen3_next"
+    # (Gated-DeltaNet linear attention and gated full attention, each layer
+    # with sparse experts and a gated shared one, of a Qwen3-Next config.json).
     model: str = "lstm"
     n_heads: int = 4
     n_layers: int = 2
@@ -235,7 +280,8 @@ class Config:
     act_ctx: int = 0
     # A published architecture's own config.json, under its published key
     # names (model="granite_hybrid": GRANITE_ARCH_KEYS above; "nemotron_h":
-    # NEMOTRON_ARCH_KEYS; "smallthinker": SMALLTHINKER_ARCH_KEYS). One
+    # NEMOTRON_ARCH_KEYS; "smallthinker": SMALLTHINKER_ARCH_KEYS;
+    # "qwen3_next": QWEN3_NEXT_ARCH_KEYS). One
     # mapping instead of a Config field per width: the widths of a catalog
     # model are its source's to name, not this file's.
     arch: dict | None = None

@@ -202,9 +202,10 @@ def _act_transformer_window(
 
 def _act_granite_hybrid(actor, params, obs, h, c, key):
     """One recurrent step of the hybrid families (granite_hybrid,
-    nemotron_h, smallthinker): ``h`` holds the Mamba layers'
-    states and convolution tails, ``c`` the attention layers' K/V rings and a
-    step counter (``models/granite_hybrid.py``). The worker zeroes both at
+    nemotron_h, smallthinker, qwen3_next): ``h`` holds the recurrent layers'
+    (Mamba-2, linear attention) states and convolution tails, ``c`` the
+    attention layers' K/V rings and a step counter
+    (``models/granite_hybrid.py``). The worker zeroes both at
     episode starts, so no state crosses episodes."""
     logits, _value, (h2, c2) = actor.apply(params["actor"], obs, h, c, method="act")
     a = D.categorical_sample(key, logits)
@@ -280,11 +281,13 @@ def build_family(cfg: Config, mesh=None) -> ModelFamily:
             store_carry=False,
         )
 
-    if cfg.model in ("nemotron_h", "smallthinker"):
+    if cfg.model in ("nemotron_h", "smallthinker", "qwen3_next"):
         if cfg.model == "nemotron_h":
             from tpu_rl.models.nemotron_h import NemotronHActorCritic as core, carry_widths
-        else:
+        elif cfg.model == "smallthinker":
             from tpu_rl.models.smallthinker import SmallThinkerActorCritic as core, carry_widths
+        else:
+            from tpu_rl.models.qwen3_next import Qwen3NextActorCritic as core, carry_widths
 
         ctx = cfg.effective_act_ctx
         actor = core(

@@ -58,6 +58,12 @@ from tpu_rl.ops import pallas_ssd
 from tpu_rl.parallel.sequence import flash_attention_tpu, segment_ids_from_firsts
 
 
+# The kinds of layer that carry a state and a convolution tail from step to
+# step (Mamba-2's; ``models/qwen3_next.py``'s linear attention): ``h`` packs
+# one pair per such layer. An ``"attention"`` layer carries a K/V ring in ``c``.
+RECURRENT = ("mamba", "linear")
+
+
 def carry_widths(arch: dict, ctx: int) -> tuple[int, int]:
     """Widths of the flattened acting carry ``(h, c)``."""
     conv_ch = _conv_channels(arch)
@@ -84,27 +90,32 @@ def _rms_norm(x, scale, eps):
 class RMSNorm(nn.Module):
     eps: float
     dtype: Any = None  # output dtype (statistics are float32)
+    zero_centered: bool = False  # the leaf starts at 0 and scales by 1 + itself
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        if self.zero_centered:
+            scale = 1.0 + self.param("scale", nn.initializers.zeros, (x.shape[-1],))
+        else:
+            scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         return _rms_norm(x, scale, self.eps).astype(self.dtype or jnp.float32)
 
 
-@jax.named_scope("ssd_conv")
-def seam_conv(xbc, tail, seg, weight, bias):
+def seam_conv(xbc, tail, seg, weight, bias, scope: str = "ssd_conv"):
     """Causal depthwise convolution whose taps stop at an episode seam.
     ``xbc`` (B, T, C); ``tail`` (B, K-1, C) the steps before the window
-    (segment 0); ``seg`` (B, T) int; ``weight`` (K, C). Float32."""
+    (segment 0); ``seg`` (B, T) int; ``weight`` (K, C). Float32. ``scope``
+    names it in the device trace (Mamba-2's by default)."""
     K = weight.shape[0]
     T = xbc.shape[1]
-    xp = jnp.concatenate([tail, xbc], axis=1).astype(jnp.float32)
-    segp = jnp.concatenate([jnp.zeros_like(seg[:, : K - 1]), seg], axis=1)
-    out = jnp.broadcast_to(bias, xbc.shape).astype(jnp.float32)
-    for k in range(K):
-        same = segp[:, k : k + T] == seg
-        out = out + jnp.where(same[..., None], xp[:, k : k + T], 0.0) * weight[k]
-    return out
+    with jax.named_scope(scope):
+        xp = jnp.concatenate([tail, xbc], axis=1).astype(jnp.float32)
+        segp = jnp.concatenate([jnp.zeros_like(seg[:, : K - 1]), seg], axis=1)
+        out = jnp.broadcast_to(bias, xbc.shape).astype(jnp.float32)
+        for k in range(K):
+            same = segp[:, k : k + T] == seg
+            out = out + jnp.where(same[..., None], xp[:, k : k + T], 0.0) * weight[k]
+        return out
 
 
 def _ssd_kernel_block(b: int, h: int, p: int, g: int, n: int, Q: int) -> tuple[int | None, bool]:
@@ -337,17 +348,23 @@ class Mamba2Mixer(nn.Module):
 
 
 @jax.named_scope("attn_rope")
-def rope(x, pos, theta: float):
-    """Rotary positions over the whole head, rotate-half pairing (feature
-    ``i`` with ``i + D/2``), no scaling: ``x`` (B, ..., H, D) with ``pos``
-    (B, ...) int. Angles, sines and the rotation in float32; ``x``'s dtype
-    comes back."""
+def rope(x, pos, theta: float, rotary_dim: int | None = None):
+    """Rotary positions, rotate-half pairing, no scaling: ``x`` (B, ..., H, D)
+    with ``pos`` (B, ...) int. Over the whole head (feature ``i`` with
+    ``i + D/2``), or with ``rotary_dim`` over the head's first ``rotary_dim``
+    features alone (``i`` with ``i + rotary_dim/2``, frequencies
+    ``theta^(-2i / rotary_dim)``) while the others pass as they are. Angles,
+    sines and the rotation in float32; ``x``'s dtype comes back."""
+    passed = None
+    if rotary_dim is not None:
+        x, passed = x[..., :rotary_dim], x[..., rotary_dim:]
     D = x.shape[-1]
     inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
     angle = pos.astype(jnp.float32)[..., None, None] * inv  # (B, ..., 1, D/2)
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+    turned = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+    return turned if passed is None else jnp.concatenate([turned, passed], axis=-1)
 
 
 class GQAttention(nn.Module):
@@ -356,6 +373,13 @@ class GQAttention(nn.Module):
     off by default (granite's and nemotron's layers: no positions, ``nope``,
     and the whole episode so far): ``rope_theta`` rotates q and k (``rope``),
     ``window`` keeps the last ``window`` keys, the query's own among them.
+    Three more, off by default too (``models/qwen3_next.py`` sets all three):
+    ``rotary_dim`` rotates each head's first ``rotary_dim`` features alone;
+    ``qk_norm`` (an epsilon) puts a zero-centred RMSNorm over each head of q
+    and of k before the rotation (leaves ``q_norm``, ``k_norm``); ``gated``
+    doubles ``q_proj`` — each head's columns are its query, then its gate —
+    and multiplies the attention's output by ``sigmoid(gate)`` before
+    ``o_proj``.
 
     The rotation's position is the step's index in the training window, and
     in acting the steps of the episode so far: the same scores, because the
@@ -372,29 +396,59 @@ class GQAttention(nn.Module):
     dtype: Any = None
     rope_theta: float | None = None
     window: int | None = None
+    rotary_dim: int | None = None
+    qk_norm: float | None = None
+    gated: bool = False
 
     def setup(self):
         proj = dict(use_bias=self.bias, dtype=self.dtype)
-        self.q_proj = nn.Dense(self.n_q * self.head_dim, name="q_proj", **proj)
+        self.q_proj = nn.Dense(
+            (2 if self.gated else 1) * self.n_q * self.head_dim, name="q_proj", **proj)
+        if self.qk_norm is not None:
+            norm = dict(eps=self.qk_norm, dtype=self.dtype, zero_centered=True)
+            self.q_norm = RMSNorm(name="q_norm", **norm)
+            self.k_norm = RMSNorm(name="k_norm", **norm)
         self.k_proj = nn.Dense(self.n_kv * self.head_dim, name="k_proj", **proj)
         self.v_proj = nn.Dense(self.n_kv * self.head_dim, name="v_proj", **proj)
         self.o_proj = nn.Dense(self.hidden, name="o_proj", **proj)
 
+    @nn.nowrap
+    def _queries(self, u, heads: tuple):
+        """``q_proj(u)`` as heads ``(..., *heads, head_dim)`` and, where the
+        layer is gated, each head's gate beside its query (else None)."""
+        q = self.q_proj(u)
+        if not self.gated:
+            return q.reshape(*u.shape[:-1], *heads, self.head_dim), None
+        q, gate = jnp.split(q.reshape(*u.shape[:-1], *heads, 2 * self.head_dim), 2, axis=-1)
+        return q, gate
+
+    @nn.nowrap
+    def _positioned(self, q, k, pos):
+        """q and k normed per head and rotated, as the fields say."""
+        if self.qk_norm is not None:
+            q, k = self.q_norm(q), self.k_norm(k)
+        if self.rope_theta is not None:
+            q, k = (rope(x, pos, self.rope_theta, self.rotary_dim) for x in (q, k))
+        return q, k
+
+    @staticmethod
+    def _gate(o, gate):
+        return o if gate is None else o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+
     def __call__(self, u, seg):
         B, T, _ = u.shape
-        q = self.q_proj(u).reshape(B, T, self.n_q, self.head_dim)
+        q, gate = self._queries(u, (self.n_q,))
         # every key/value head serves n_q // n_kv consecutive query heads
         k, v = (
             p(u).reshape(B, T, self.n_kv, self.head_dim)
             for p in (self.k_proj, self.v_proj)
         )
         pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        if self.rope_theta is not None:
-            q, k = rope(q, pos, self.rope_theta), rope(k, pos, self.rope_theta)
+        q, k = self._positioned(q, k, pos)
         o = flash_attention_tpu(
             q, k, v, pos, seg, causal=True, sm_scale=self.scale, window=self.window
         )
-        return self.o_proj(o.reshape(B, T, -1))
+        return self.o_proj(self._gate(o, gate).reshape(B, T, -1))
 
     def step(self, u, k_cache, v_cache, count):
         """One acting step over a K/V ring of ``ctx`` slots (B, ctx, kv, D);
@@ -406,13 +460,11 @@ class GQAttention(nn.Module):
         B = u.shape[0]
         ctx = k_cache.shape[1]
         rep = self.n_q // self.n_kv
-        q = self.q_proj(u).reshape(B, self.n_kv, rep, self.head_dim)
+        q, gate = self._queries(u, (self.n_kv, rep))
         k_new, v_new = (
             p(u).reshape(B, 1, self.n_kv, self.head_dim) for p in (self.k_proj, self.v_proj)
         )
-        if self.rope_theta is not None:
-            q = rope(q, count[:, None], self.rope_theta)
-            k_new = rope(k_new, count[:, None], self.rope_theta)
+        q, k_new = self._positioned(q, k_new, count[:, None])
         write = (jnp.arange(ctx)[None] == jnp.mod(count, ctx)[:, None])[:, :, None, None]
         k_cache = jnp.where(write, k_new.astype(k_cache.dtype), k_cache)
         v_cache = jnp.where(write, v_new.astype(v_cache.dtype), v_cache)
@@ -425,7 +477,8 @@ class GQAttention(nn.Module):
             "bgrt,btgd->bgrd", w.astype(q.dtype), v_cache.astype(q.dtype),
             preferred_element_type=jnp.float32,
         )
-        return self.o_proj(o.reshape(B, -1).astype(q.dtype)), k_cache, v_cache
+        o = self._gate(o.astype(q.dtype), gate)
+        return self.o_proj(o.reshape(B, -1)), k_cache, v_cache
 
 
 class HybridLayer(nn.Module):
@@ -514,7 +567,7 @@ class GraniteHybridActorCritic(nn.Module):
         return jax.nn.log_softmax(logits), self.value_head(h)
 
     def _unpack_h(self, h):
-        """(B, h_width) -> one (state, tail) per Mamba layer, float32."""
+        """(B, h_width) -> one (state, tail) per recurrent layer, float32."""
         if not self.h_width:
             return []
         n_state, n_tail = int(np.prod(self.state_shape)), int(np.prod(self.tail_shape))
@@ -561,12 +614,12 @@ class GraniteHybridActorCritic(nn.Module):
         mamba = iter(self._unpack_h(h0))
         carried, extras = [], []
         for layer in self.layers:
-            if layer.kind == "mamba":
-                x, state, tail = layer(x, seg, *next(mamba))
+            if layer.kind in RECURRENT:
+                x, state, tail, *more = layer(x, seg, *next(mamba))
                 carried.append((state, tail))
             else:
                 x, *more = layer(x, seg)
-                extras.extend(more)
+            extras.extend(more)
         logits, value = self._heads(x)
         return logits, value, (self._pack(carried, B), c0), extras
 
@@ -583,7 +636,7 @@ class GraniteHybridActorCritic(nn.Module):
         mamba, rings = iter(self._unpack_h(h)), iter(rings)
         carried, caches = [], []
         for layer in self.layers:
-            if layer.kind == "mamba":
+            if layer.kind in RECURRENT:
                 x, state, tail = layer.step(x, *next(mamba))
                 carried.append((state, tail))
             elif layer.kind == "attention":

@@ -100,10 +100,15 @@ _expert_init = nn.initializers.variance_scaling(
 
 class ExpertBlock(nn.Module):
     """This model's block by default: a sigmoid router with a correction
-    bias, ``relu2`` experts and a shared one. The fields after ``dtype`` give
-    the other published form (``models/smallthinker.py``): ``gated`` experts
-    (a third leaf, ``w_gate``), a ``softmax`` score without bias or scale,
-    and ``shared_width`` 0 for no shared expert."""
+    bias, ``relu2`` experts and a shared one of the same form. The fields
+    after ``dtype`` give the other published blocks: ``form`` (a key of
+    ``moe.EXPERT_FORMS``) is the routed experts' and the shared expert's alike
+    — ``reglu`` (``models/smallthinker.py``) and ``swiglu``
+    (``models/qwen3_next.py``) are gated, a third leaf ``w_gate`` and a third
+    shared projection ``shared_gate`` —; ``score`` ``softmax`` routes without
+    bias or scale; ``shared_width`` 0 is no shared expert; ``shared_gated``
+    weighs the shared expert's output by ``sigmoid(w_s^T u)`` (a ``(d, 1)``
+    leaf ``shared_weight``)."""
 
     hidden: int
     n_experts: int  # the router's width: every published expert
@@ -114,8 +119,9 @@ class ExpertBlock(nn.Module):
     shared_width: int
     scale: float
     dtype: Any = None
-    gated: bool = False
+    form: str = "relu2"
     score: str = "sigmoid"
+    shared_gated: bool = False
 
     def setup(self):
         self.router = self.param(
@@ -125,13 +131,18 @@ class ExpertBlock(nn.Module):
             if self.score == "sigmoid" else None
         )
         first = (self.held, self.hidden, self.expert_width)
-        self.w_gate = self.param("w_gate", _expert_init, first) if self.gated else None
+        gated = self.form != "relu2"
+        self.w_gate = self.param("w_gate", _expert_init, first) if gated else None
         self.w_in = self.param("w_in", _expert_init, first)
         self.w_out = self.param("w_out", _expert_init, (self.held, self.expert_width, self.hidden))
         if self.shared_width:
             dense = dict(use_bias=False, dtype=self.dtype)
+            if gated:
+                self.shared_gate = nn.Dense(self.shared_width, name="shared_gate", **dense)
             self.shared_in = nn.Dense(self.shared_width, name="shared_in", **dense)
             self.shared_out = nn.Dense(self.hidden, name="shared_out", **dense)
+            if self.shared_gated:
+                self.shared_weight = nn.Dense(1, name="shared_weight", **dense)
 
     def _route(self, rows):
         return moe.route(
@@ -142,8 +153,13 @@ class ExpertBlock(nn.Module):
         if not self.shared_width:
             return routed.reshape(u.shape)
         with jax.named_scope("moe_shared"):
-            shared = self.shared_out(jnp.square(jax.nn.relu(self.shared_in(u))))
-        return shared.astype(jnp.float32) + routed.reshape(u.shape)
+            act, _ = moe.EXPERT_FORMS[self.form]
+            gate = () if self.form == "relu2" else (self.shared_gate,)
+            first = tuple(p(u) for p in (*gate, self.shared_in))
+            shared = self.shared_out(act(first)).astype(jnp.float32)
+            if self.shared_gated:
+                shared = jax.nn.sigmoid(self.shared_weight(u).astype(jnp.float32)) * shared
+        return shared + routed.reshape(u.shape)
 
     def __call__(self, u, scored=None):
         """``u`` (B, T, d). Returns the block's output (float32) and its
@@ -155,7 +171,7 @@ class ExpertBlock(nn.Module):
         chunk = moe.chunk_rows(rows.shape[0], self.top_k, self.held, self.n_experts)
         routed = moe.routed_experts(
             rows, choice, weight, self.w_in, self.w_out, self.first, self.dtype, chunk=chunk,
-            w_gate=self.w_gate)
+            w_gate=self.w_gate, form=self.form)
         route = {
             "choice": choice.reshape(*u.shape[:-1], self.top_k),
             "stats": moe.route_stats(choice, self.first, self.held, chunk),
@@ -166,7 +182,8 @@ class ExpertBlock(nn.Module):
         """One acting step: ``u`` (B, d)."""
         choice, weight = self._route(u if scored is None else scored)
         routed = moe.routed_experts_dense(
-            u, choice, weight, self.w_in, self.w_out, self.first, self.dtype, self.w_gate)
+            u, choice, weight, self.w_in, self.w_out, self.first, self.dtype, self.w_gate,
+            self.form)
         return self._add_shared(u, routed)
 
 

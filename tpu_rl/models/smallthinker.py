@@ -109,7 +109,7 @@ class SmallThinkerLayer(nn.Module):
         self.experts = ExpertBlock(
             hidden=a["hidden_size"], n_experts=n_experts, held=held, first=first,
             top_k=a["moe_num_active_primary_experts"], expert_width=a["moe_ffn_hidden_size"],
-            shared_width=0, scale=1.0, dtype=self.dtype, gated=True, score="softmax",
+            shared_width=0, scale=1.0, dtype=self.dtype, form="reglu", score="softmax",
             name="experts",
         )
 

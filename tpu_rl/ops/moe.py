@@ -7,10 +7,12 @@ over *all* the model's experts, as the published router does, and computes
     y = shared(u) + sum over the token's chosen experts that are held here of
         w_e * expert_e(u)
 
-with one of two published expert forms, each without bias:
+with one of three published expert forms (``EXPERT_FORMS``, chosen by name),
+each without bias:
 
-    ``relu2``  W_out relu(W_in u)^2                      two leaves an expert
-    ``reglu``  W_out (relu(W_gate u) * W_in u)           three (gated)
+    ``relu2``   W_out relu(W_in u)^2                     two leaves an expert
+    ``reglu``   W_out (relu(W_gate u) * W_in u)          three (gated)
+    ``swiglu``  W_out (silu(W_gate u) * W_in u)          three (gated)
 
 What the absent experts would add is left out: in a deployment the other ranks
 compute it and an exchange brings it home; here there is no exchange and no
@@ -313,7 +315,29 @@ def _reglu_bwd(pre, t):
     return jnp.where(gate > 0, t * up, 0.0), t * jax.nn.relu(gate)
 
 
-EXPERT_FORMS = {"relu2": (_relu2, _relu2_bwd), "reglu": (_reglu, _reglu_bwd)}
+def _swiglu(pre):
+    gate, up = pre
+    return jax.nn.silu(gate) * up
+
+
+def _swiglu_bwd(pre, t):
+    gate, up = pre
+    s = jax.nn.sigmoid(gate)
+    return t * up * s * (1.0 + gate * (1.0 - s)), t * gate * s
+
+
+EXPERT_FORMS = {
+    "relu2": (_relu2, _relu2_bwd),
+    "reglu": (_reglu, _reglu_bwd),
+    "swiglu": (_swiglu, _swiglu_bwd),
+}
+
+
+def _first_projections(form: str, w_in, w_gate) -> tuple:
+    """The form's first projections in the order its functions read them."""
+    assert form in EXPERT_FORMS, f"expert form {form!r}: one of {sorted(EXPERT_FORMS)}"
+    assert (w_gate is None) == (form == "relu2"), f"{form} experts with w_gate {w_gate is not None}"
+    return (w_in,) if w_gate is None else (w_gate, w_in)
 
 
 @jax.named_scope("moe_dispatch")
@@ -431,11 +455,11 @@ _walk.defvjp(_walk_fwd, _walk_bwd)
 
 
 def routed_experts(u, choice, weight, w_in, w_out, first: int, dtype=None, kernel=None,
-                   chunk: int | None = None, w_gate=None):
+                   chunk: int | None = None, w_gate=None, form: str = "relu2"):
     """The held experts' part of the block's output for ``u`` (N, d):
     ``sum over chosen and held e of weight_e * relu(u W_in[e])^2 W_out[e]``,
-    float32 — or, with ``w_gate`` (H, d, f), of the gated
-    ``weight_e * (relu(u W_gate[e]) * u W_in[e]) W_out[e]``.
+    float32 — or, at a gated ``form`` (``reglu``, ``swiglu``) with ``w_gate``
+    (H, d, f), of ``weight_e * (act(u W_gate[e]) * u W_in[e]) W_out[e]``.
     ``choice`` (N, k) global expert ids, ``weight`` (N, k);
     ``w_in`` (H, d, f), ``w_out`` (H, f, d): experts ``first .. first + H``.
     Matmul operands in ``dtype``. ``chunk``: the rows a trip of the walk takes
@@ -451,16 +475,16 @@ def routed_experts(u, choice, weight, w_in, w_out, first: int, dtype=None, kerne
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0).astype(jnp.int32)
         order = jnp.pad(order, (0, (-n * k) % chunk))  # whole chunks; the pad is never live
-    first_projections = (w_in,) if w_gate is None else (w_gate, w_in)
+    first_projections = _first_projections(form, w_in, w_gate)
     with jax.named_scope("moe_experts"):
         first_projections = tuple(w.astype(cd) for w in first_projections)
         w_out = w_out.astype(cd)
     return _walk(
-        u, weight.astype(jnp.float32), first_projections, w_out, order, sizes, chunk, kernel,
-        "relu2" if w_gate is None else "reglu")
+        u, weight.astype(jnp.float32), first_projections, w_out, order, sizes, chunk, kernel, form)
 
 
-def routed_experts_dense(u, choice, weight, w_in, w_out, first: int, dtype=None, w_gate=None):
+def routed_experts_dense(u, choice, weight, w_in, w_out, first: int, dtype=None, w_gate=None,
+                         form: str = "relu2"):
     """The same sum with every held expert applied to every row under a mask:
     the acting form (a few rows a step), and the sparse form's oracle."""
     held = w_in.shape[0]
@@ -469,10 +493,11 @@ def routed_experts_dense(u, choice, weight, w_in, w_out, first: int, dtype=None,
         jnp.where(choice[..., None] - first == jnp.arange(held), weight[..., None], 0.0),
         axis=-2,
     )  # (N, H): the weight of held expert e for this row, 0 where not chosen
-    act, _ = EXPERT_FORMS["relu2" if w_gate is None else "reglu"]
+    first_projections = _first_projections(form, w_in, w_gate)
+    act, _ = EXPERT_FORMS[form]
     hidden = act(tuple(
         jnp.einsum("nd,edf->nef", u.astype(cd), w.astype(cd), preferred_element_type=jnp.float32)
-        for w in ((w_in,) if w_gate is None else (w_gate, w_in))))
+        for w in first_projections))
     out = jnp.einsum(
         "nef,efd->ned", hidden.astype(cd), w_out.astype(cd), preferred_element_type=jnp.float32)
     return jnp.einsum("ne,ned->nd", gate, out)
