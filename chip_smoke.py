@@ -451,6 +451,11 @@ def kernel_checks(
     # attention, whose scores at T 8,192 would not fit beside their gradients)
     qwen3_next_shapes=(("linear", 1, 1536), ("attention", 1, 4096)),
     qwen3_next_widths=QWEN3_NEXT_MIXERS,
+    # (B, T, key heads, value heads, key size, value size, chunk): the delta
+    # rule's scan alone at qwen3-next-80b-a3b's widths and the cell's batch,
+    # ~4 seams a window: the Pallas pair against the jax.numpy body, forward
+    # and every gradient, both forms timed (forward + backward, host clock)
+    gdn_shapes=((2, 8192, 16, 32, 128, 128, 64),),
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -832,7 +837,38 @@ def kernel_checks(
             jax.value_and_grad(system, argnums=(0, 1), has_aux=True),
             jax.value_and_grad(reference, argnums=(0, 1), has_aux=True),
             (params, u), TOL_MIXER_BF16, TOL_MIXER_BF16,
-            mosaic=kind == "attention" and jax.default_backend() == "tpu",
+            mosaic=jax.default_backend() == "tpu",  # the splash kernels; the delta rule's pair
+        )
+
+    # ---- the Pallas delta-rule pair vs the jax.numpy body, every gradient
+    from tpu_rl.ops.gated_delta import gated_delta_chunked
+    from tpu_rl.ops.pallas_gdn import head_block as gdn_head_block
+
+    for B, T, HK, HV, DK, DV, Q in gdn_shapes:
+        q, k = f32(B, T, HK, DK).astype(jnp.bfloat16), f32(B, T, HK, DK).astype(jnp.bfloat16)
+        v = f32(B, T, HV, DV).astype(jnp.bfloat16)
+        g, beta = -jax.nn.softplus(f32(B, T, HV) - 3.0), jax.nn.sigmoid(f32(B, T, HV))
+        state0 = f32(B, HV, DK, DV) * DK**-0.5
+        firsts = rng.random((B, T)) < 4.0 / T  # ~4 episode seams a window
+        firsts[:, [T // 3, T // 3 + 1]] = True  # and two in one chunk whatever the draw
+        seg = jnp.asarray(np.cumsum(firsts, axis=1).astype(np.int32))
+        w_o, w_last = f32(B, T, HV, DV), f32(B, HV, DK, DV)
+        hb = HV if interpret else gdn_head_block(HV, HK, DK, DV, Q)
+
+        def delta_loss(kernel, q, k, v, g, beta, state0):
+            o, last = gated_delta_chunked(
+                q, k, v, g, beta, seg, state0, Q, jnp.bfloat16, kernel=kernel)
+            return (o * w_o).sum() + (last * w_last).sum(), (o, last)
+
+        def delta_grads(kernel):
+            return jax.value_and_grad(
+                lambda *a: delta_loss(kernel, *a), argnums=tuple(range(6)), has_aux=True)
+
+        case(
+            f"gdn fwd+bwd B{B}/T{T}/H{HK}:{HV}x{DK}:{DV}/Q{Q} bf16 ({int(firsts.sum())} seams, "
+            f"head block {hb}) vs the jax.numpy body",
+            delta_grads((hb, interpret)), delta_grads((None, False)),
+            (q, k, v, g, beta, state0), TOL_BF16, TOL_BF16, timed=True,
         )
     return rows
 

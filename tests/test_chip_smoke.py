@@ -71,9 +71,13 @@ def test_kernel_checks_pass_tiny_interpreted():
             linear_key_head_dim=16, linear_value_head_dim=16, linear_conv_kernel_dim=4,
             num_attention_heads=4, num_key_value_heads=2, head_dim=32, rope_theta=10000000,
             partial_rotary_factor=0.25),
+        gdn_shapes=((2, 40, 2, 4, 16, 16, 8),),
         interpret=True,
     )
-    assert len(rows) == 20
+    assert len(rows) == 21
+    delta = rows.pop()  # the delta rule's pair against the jax.numpy body comes last
+    assert delta["kernel"].startswith("gdn fwd+bwd B2/T40/H2:4x16:16/Q8 bf16") and delta["ok"]
+    assert 0 < delta["err"] and delta["ms"] > 0 and delta["ms_ref"] > 0
     for row, kind in zip(rows[-2:], ("linear", "attention")):  # the two mixers come last
         assert row["kernel"].startswith(f"qwen3_next {kind} mixer fwd+bwd") and row["err"] > 0
     rows = rows[:-2]

@@ -66,6 +66,9 @@ def small_chunks():
 
 @pytest.fixture(params=["auto", "interpret"], ids=["jnp", "pallas"])
 def kernel_form(request, monkeypatch):
+    """The form of the delta rule's scan and of the experts' products a test's
+    programs are traced in (read while tracing: a test jits what it runs
+    inside this fixture's scope, under a function of its own)."""
     monkeypatch.setattr(cells, "_PALLAS_MODE", request.param)
     return request.param
 
@@ -136,7 +139,7 @@ SEAMS = {
 
 
 @pytest.mark.parametrize("seams", SEAMS.values(), ids=SEAMS.keys())
-def test_the_chunked_rule_equals_the_step_recurrence(seams):
+def test_the_chunked_rule_equals_the_step_recurrence(seams, kernel_form):
     """Outputs, the last state and all six gradients, float32 at ``highest``:
     chunks of 8 in spans of 2 over 32 steps, the second row's seams shifted by
     three steps so that the rows differ."""
@@ -170,7 +173,7 @@ def test_the_chunked_rule_equals_the_step_recurrence(seams):
 
 
 @pytest.mark.parametrize("steps", [5, 21, 40], ids=["under-a-chunk", "a-ragged-span", "a-ragged-window"])
-def test_the_chunked_rule_pads_a_window_that_is_no_whole_span(steps):
+def test_the_chunked_rule_pads_a_window_that_is_no_whole_span(steps, kernel_form):
     q, k, v, g, beta, state0 = rule_inputs(4, steps)
     first = jnp.zeros((B, steps), bool).at[:, steps // 2].set(True)
     seg = jnp.cumsum(first.astype(jnp.int32), axis=1)
@@ -182,7 +185,7 @@ def test_the_chunked_rule_pads_a_window_that_is_no_whole_span(steps):
     close(last, want_last, 2e-6)
 
 
-def test_bf16_operands_keep_the_state_and_the_decays_in_float32():
+def test_bf16_operands_keep_the_state_and_the_decays_in_float32(kernel_form):
     q, k, v, g, beta, state0 = rule_inputs(5, T)
     seg = jnp.zeros((B, T), jnp.int32)
     o, last = gated_delta.gated_delta_chunked(q, k, v, g, beta, seg, state0, CHUNK, jnp.bfloat16)
@@ -347,12 +350,13 @@ def test_the_ranks_parts_add_up_to_the_uncut_layer(monkeypatch, kind, chips, for
     close(all_held, after + mixed, 3e-4)
 
 
-def test_acting_step_by_step_equals_the_unroll(family, actor, system):
+def test_acting_step_by_step_equals_the_unroll(family, actor, kernel_form):
     """``family.act`` over the linear layers' states and convolution tails and
     the full layer's K/V ring, with the worker's zeroing at episode starts: an
     episode of 21 steps after one of 11, across chunks and spans."""
     batch = make_batch(9, firsts=(0, 11))
-    _, logits, _ = system(actor, batch)
+    logits = jax.jit(lambda p, b: policy_outputs_routed(
+        family, {"actor": p}, Batch.from_mapping(b))[3])(actor, batch)
     assert family.carry_widths == (3 * (4 * 16 * 16 + 3 * 128), 2 * T * 2 * 32 + 1)
     h = jnp.zeros((B, family.carry_widths[0]))
     c = jnp.zeros((B, family.carry_widths[1]))
@@ -366,14 +370,14 @@ def test_acting_step_by_step_equals_the_unroll(family, actor, system):
     assert float(c[0, -1]) == T - 11 and float(jnp.abs(h).max()) > 0
 
 
-def test_the_unroll_hands_back_the_carry_acting_would_reach(family, actor):
+def test_the_unroll_hands_back_the_carry_acting_would_reach(family, actor, kernel_form):
     """The state and the convolution tail after the window's last step, from
     the unroll, against the acting loop's: what the next window would start
     from."""
     batch = make_batch(10, firsts=(5,))
     obs, firsts = jnp.asarray(batch["obs"]), jnp.asarray(batch["is_fir"])
     carry0 = (jnp.zeros((B, 1)), jnp.zeros((B, 1)))
-    _, _, (h_unroll, _) = jax.jit(family.actor_unroll)(actor, obs, carry0, firsts)
+    _, _, (h_unroll, _) = jax.jit(lambda *a: family.actor_unroll(*a))(actor, obs, carry0, firsts)
     h = jnp.zeros((B, family.carry_widths[0]))
     c = jnp.zeros((B, family.carry_widths[1]))
     act = jax.jit(family.act)
@@ -440,7 +444,8 @@ def test_the_update_program_names_its_paths(family, actor, monkeypatch):
     lowered = lower()
     paths = set(program_paths(lowered)["paths"])
     assert {"gdn_scan", "attn_full", "attn_global", "attn_rope", "moe_experts"} <= paths
-    assert not {"moe_gmm_pallas", "ssd_scan", "attn_window"} & paths  # a CPU: ragged_dot
+    # a CPU: ragged_dot, and the delta rule's jax.numpy body
+    assert not {"moe_gmm_pallas", "gdn_pallas", "ssd_scan", "attn_window"} & paths
     text = lowered.as_text(debug_info=True)
     for scope in ("/gdn/linear_attn/", "gdn_conv", "gdn/linear_attn/gdn_scan", "/moe/",
                   "moe_route/", "moe_dispatch/", "moe_combine/", "experts._add_shared/moe_shared",
@@ -448,7 +453,10 @@ def test_the_update_program_names_its_paths(family, actor, monkeypatch):
         assert scope in text, scope
     assert "ssd_conv" not in text
     monkeypatch.setattr(cells, "_PALLAS_MODE", "interpret")
-    assert {"moe_experts", "moe_gmm_pallas", "gdn_scan"} <= set(program_paths(lower())["paths"])
+    lowered = lower()
+    assert {"moe_experts", "moe_gmm_pallas", "gdn_scan", "gdn_pallas"} <= set(
+        program_paths(lowered)["paths"])
+    assert "gdn/linear_attn/gdn_scan/gdn_pallas" in lowered.as_text(debug_info=True)
 
 
 def test_the_attention_counters_reach_the_diagnostics_under_the_span_global(actor, system):

@@ -26,7 +26,17 @@ the chunk's first seam on),
     O  = D_.0 * (Q̂ S0) + tril(Q̂ K̂^T * D) V'           diagonal kept
     S' = D_C0 S0 + (K̂ * D_C.)^T V'                     C: the chunk's last step
 
-``A`` is the inverse of a unit lower-triangular matrix: ``sum_k (-N)^k`` by
+Two forms compute it, and ``gated_delta_chunked`` chooses by what it can
+observe (``_kernel_block``: ``cells.set_pallas_mode``, the platform of the
+program being traced, whether the batch tiles a registered data mesh, lane
+multiples, VMEM): on a TPU at the published widths one Pallas kernel per pass
+(``ops/pallas_gdn.py``, scope ``gdn_pallas`` inside ``gdn_scan``; under a data
+mesh a ``shard_map`` island), everywhere else — the CPU, the tests' small
+widths, init and act traces — the ``jax.numpy`` body below (``_chunked_jnp``),
+which is also the kernels' oracle.
+
+In the ``jax.numpy`` body ``A`` is the inverse of a unit lower-triangular
+matrix: ``sum_k (-N)^k`` by
 repeated squaring (``_unit_lower_inverse``: matmuls only, float32 at the
 highest precision, its own transpose rule so that the backward keeps ``A``
 alone). The window is walked in spans of ``SPAN_CHUNKS`` chunks. Within a span
@@ -37,13 +47,18 @@ the backward pass rematerialises the span. Decays, cumulative sums, the
 inverse and the carried state are float32; the operands of every other product
 are ``dtype`` with float32 accumulation, as ``models/granite_hybrid._ssd_jnp``
 does for Mamba-2. A seam is a mask on every decay factor (never ``-inf``
-inside a cumulative sum). The backward is JAX's transpose of this program.
+inside a cumulative sum). The backward is JAX's transpose of this program;
+the kernels' is their own (``jax.custom_vjp``), at the same precision.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from tpu_rl.ops import pallas_gdn
 
 L2_EPS = 1e-6
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -111,15 +126,78 @@ def gated_delta_step(q, k, v, g, beta, state):
 SPAN_CHUNKS = 16
 
 
+def _kernel_block(b: int, hv: int, hk: int, dk: int, dv: int, Q: int) -> tuple[int | None, bool]:
+    """(value heads per grid step of the Pallas pair, interpret), or (None,
+    False) for the ``jax.numpy`` body: the gate of ``models/cells.py``
+    (``set_pallas_mode``, the platform of the program being traced) applied to
+    the scan, as ``models/granite_hybrid._ssd_kernel_block`` applies it to
+    Mamba-2's. The CPU, sizes that are no lane multiples and a batch that does
+    not tile a registered data mesh (init and act traces: a Mosaic call has no
+    SPMD rule outside its island) keep the ``jax.numpy`` form."""
+    from tpu_rl.models import cells
+
+    mode = cells._PALLAS_MODE
+    if mode == "off":
+        return None, False
+    if mode == "interpret":  # any width: every head at once where no block tiles
+        return pallas_gdn.head_block(hv, hk, dk, dv, Q) or hv, True
+    platform, n_data = cells._program_devices()
+    if platform != "tpu" or b % n_data:
+        return None, False
+    return pallas_gdn.head_block(hv, hk, dk, dv, Q), False
+
+
+def _kernels(q, k, v, g, beta, seg, state0, chunk, dtype, hb, interpret):
+    """The Pallas pair (``ops/pallas_gdn.py``); under a
+    registered data mesh whose width the batch tiles, as a ``shard_map``
+    island over the ``"data"`` axis, as Mamba-2's kernels run there."""
+    from tpu_rl.models import cells
+
+    scan = functools.partial(
+        pallas_gdn.delta_window, chunk=chunk, dtype=dtype, hb=hb, interpret=interpret)
+    mesh = cells._DATA_MESH
+    if mesh is not None and q.shape[0] % cells._program_devices()[1] == 0:
+        from jax.sharding import PartitionSpec as P
+
+        from tpu_rl.parallel.mesh import DATA_AXIS
+
+        rows = P(DATA_AXIS)  # every operand: its leading (batch) dim
+        # no collectives inside; pallas out_shapes carry no vma annotations
+        scan = jax.shard_map(
+            scan, mesh=mesh, in_specs=(rows,) * 7, out_specs=(rows, rows), check_vma=False)
+    with jax.named_scope("gdn_pallas"):  # the backward's ops carry it too
+        return scan(q, k, v, g, beta, seg, state0)
+
+
 @jax.named_scope("gdn_scan")
-def gated_delta_chunked(q, k, v, g, beta, seg, state0, chunk: int, dtype=None):
+def gated_delta_chunked(q, k, v, g, beta, seg, state0, chunk: int, dtype=None, kernel=None):
     """The rule over a whole window in matmul form.
 
     ``q``, ``k`` (b, T, h_k, d_k) as projected (normalised here); ``v``
     (b, T, h_v, d_v); ``g`` (log decay, <= 0) and ``beta`` (b, T, h_v)
     float32; ``seg`` (b, T) int, 0 = the episode ``state0`` (b, h_v, d_k,
     d_v) belongs to. Returns ``o`` (b, T, h_v, d_v) float32 and the state
-    after the last step. The window is walked in spans of ``SPAN_CHUNKS``
+    after the last step. ``kernel``: ``(value heads a grid step of the Pallas
+    pair or None for the jax.numpy body, interpret)`` where the caller and
+    not the gate chooses (tests, ``chip_smoke.py``)."""
+    b, T, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    hb, interpret = kernel or _kernel_block(b, hv, hk, dk, dv, chunk)
+    if hb is None:
+        return _chunked_jnp(q, k, v, g, beta, seg, state0, chunk, dtype)
+    pad = (-T) % chunk
+    if pad:  # g = 0, beta = 0: the state passes through, nothing is written
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta)
+        )
+        seg = jnp.concatenate([seg, jnp.repeat(seg[:, -1:], pad, axis=1)], axis=1)
+    o, last = _kernels(q, k, v, g, beta, seg, state0, chunk, dtype, hb, interpret)
+    return o[:, :T], last
+
+
+def _chunked_jnp(q, k, v, g, beta, seg, state0, chunk: int, dtype):
+    """``gated_delta_chunked`` as ``einsum``s and ``lax.scan``s: the CPU's path
+    and the kernels' oracle. The window is walked in spans of ``SPAN_CHUNKS``
     chunks (``lax.scan``), each span rematerialised in the backward pass."""
     b, T = q.shape[:2]
     span = chunk * min(SPAN_CHUNKS, -(-T // chunk))

@@ -31,16 +31,16 @@ _CACHE_DIR = os.path.join(
 # Pallas TPU kernels lower to this custom-call target; scan / XLA paths never
 # emit it. Kernel and fallback paths are wrapped in the named scopes below
 # (models/cells.py, models/granite_hybrid.py: ``ssd_pallas`` inside
-# ``ssd_scan`` when the scan took its kernels, ops/gated_delta.py: ``gdn_scan``,
-# a ``jax.numpy`` program on every platform, ops/moe.py: ``moe_gmm_pallas``
-# inside ``moe_experts`` likewise, and ``moe_row_add_pallas`` where a trip's
+# ``ssd_scan`` when the scan took its kernels, ops/gated_delta.py: ``gdn_pallas``
+# inside ``gdn_scan`` likewise, ops/moe.py: ``moe_gmm_pallas`` inside
+# ``moe_experts`` likewise, and ``moe_row_add_pallas`` where a trip's
 # rows are added into the tokens by ops/pallas_moe.py's kernel,
 # ops/pallas_act.py, parallel/sequence.py), so one lowered
 # module answers both "what did the gate choose" and "did Mosaic get it".
 _MOSAIC_TARGET = "tpu_custom_call"
 _PATH_SCOPES = re.compile(
     r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_full|attn_window|attn_global"
-    r"|attn_rope|ssd_scan|ssd_pallas|gdn_scan|moe_experts|moe_gmm_pallas|moe_row_add_pallas)\b"
+    r"|attn_rope|ssd_scan|ssd_pallas|gdn_scan|gdn_pallas|moe_experts|moe_gmm_pallas|moe_row_add_pallas)\b"
 )
 
 
