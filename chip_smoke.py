@@ -391,6 +391,13 @@ QWEN3_NEXT_MIXERS = dict(
     num_attention_heads=16, num_key_value_heads=2, head_dim=256, rope_theta=10000000,
     partial_rotary_factor=0.25,
 )
+# The widths of GLM-4.7-Flash's latent attention as published
+# (benchmarks/configs/glm-4.7-flash.json holds the whole configuration).
+GLM4_MOE_LITE_MIXER = dict(
+    hidden_size=2048, rms_norm_eps=1e-5, num_attention_heads=20, q_lora_rank=768,
+    kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+    rope_theta=1000000,
+)
 
 
 def kernel_checks(
@@ -456,6 +463,14 @@ def kernel_checks(
     # ~4 seams a window: the Pallas pair against the jax.numpy body, forward
     # and every gradient, both forms timed (forward + backward, host clock)
     gdn_shapes=((2, 8192, 16, 32, 128, 128, 64),),
+    # (row, B, T): glm-4.7-flash's latent attention at ``glm_widths``. "mixer":
+    # the training form (low-rank projections, the shared key's broadcast, the
+    # splash kernels at 20 : 20 heads of 256 on the cell's 16 x 16 grid), forward
+    # and every gradient against benchmarks/reference/glm4_moe_lite.py (dense
+    # masked attention, 1,024 queries at a time). "step": the absorbed acting
+    # form stepped over a latent ring of T slots against the training form
+    glm_shapes=(("mixer", 1, 16384), ("step", 2, 1024)),
+    glm_widths=GLM4_MOE_LITE_MIXER,
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -869,6 +884,65 @@ def kernel_checks(
             f"head block {hb}) vs the jax.numpy body",
             delta_grads((hb, interpret)), delta_grads((None, False)),
             (q, k, v, g, beta, state0), TOL_BF16, TOL_BF16, timed=True,
+        )
+
+    # ---- glm4_moe_lite's latent attention: the training form in bf16 vs the
+    # plain float32 reference, and the absorbed acting form vs the training form
+    from benchmarks.reference import glm4_moe_lite as plain_mla
+    from tpu_rl.models.glm4_moe_lite import build_mixer as build_mla, ring_width
+
+    mla_arch = dict(glm_widths)
+    for row, B, T in glm_shapes:
+        mixer = build_mla(mla_arch, jnp.bfloat16)
+        u = f32(B, T, mla_arch["hidden_size"])
+        firsts = rng.random((B, T)) < 2.0 / T  # ~2 episode seams a window, as the cell's mix
+        firsts[:, [T // 3, T // 3 + 1]] = True  # and two in one tile whatever the draw
+        firsts[1:] = firsts[:1]  # the rows of a stepped batch start their episodes together
+        seg = jnp.asarray(np.cumsum(firsts, axis=1).astype(np.int32))
+        first = jnp.asarray(firsts)
+        params = jax.jit(lambda key: mixer.init(key, u, seg)["params"])(jax.random.key(SEED))
+        w_y = f32(B, T, mla_arch["hidden_size"])
+        if row == "mixer":
+            def system(p, u):
+                y = mixer.apply({"params": p}, u, seg)
+                return (y * w_y).sum(), y
+
+            def reference(p, u):
+                y = plain_mla.latent_attention(u, first, p, mla_arch)
+                return (y * w_y).sum(), y
+
+            case(
+                f"glm4_moe_lite mla mixer fwd+bwd B{B}/T{T} bf16 vs the plain reference "
+                f"({int(firsts.sum())} seams)",
+                jax.value_and_grad(system, argnums=(0, 1), has_aux=True),
+                jax.value_and_grad(reference, argnums=(0, 1), has_aux=True),
+                (params, u), TOL_MIXER_BF16, TOL_MIXER_BF16,
+                mosaic=jax.default_backend() == "tpu",  # the splash kernels
+            )
+            continue
+
+        def stepped(p, u):
+            """``step`` over the window, ring and counter zeroed at episode
+            starts as the worker zeroes the carry."""
+            def one(carry, at):
+                ring, count = carry
+                u_t, first_t = at
+                ring = jnp.where(first_t, 0.0, ring)
+                count = jnp.where(first_t, 0, count)
+                y, ring = mixer.apply({"params": p}, u_t, ring, count, method="step")
+                return (ring, count + 1), y
+
+            ring0 = jnp.zeros((B, T, ring_width(mla_arch)))
+            _, y = jax.lax.scan(
+                one, (ring0, jnp.zeros((B,), jnp.int32)), (u.swapaxes(0, 1), first[0]))
+            return y.swapaxes(0, 1)
+
+        case(
+            f"glm4_moe_lite mla step B{B}/T{T} bf16 over a latent ring of "
+            f"{ring_width(mla_arch)} a slot vs the unroll ({int(firsts[0].sum())} seams)",
+            stepped, lambda p, u: mixer.apply({"params": p}, u, seg), (params, u),
+            TOL_MIXER_BF16, TOL_MIXER_BF16, mosaic=False,
+            ref_is_kernel=jax.default_backend() == "tpu",
         )
     return rows
 

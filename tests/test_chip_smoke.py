@@ -72,9 +72,18 @@ def test_kernel_checks_pass_tiny_interpreted():
             num_attention_heads=4, num_key_value_heads=2, head_dim=32, rope_theta=10000000,
             partial_rotary_factor=0.25),
         gdn_shapes=((2, 40, 2, 4, 16, 16, 8),),
+        glm_shapes=(("mixer", 2, 128), ("step", 2, 128)),
+        glm_widths=dict(
+            hidden_size=64, rms_norm_eps=1e-5, num_attention_heads=4, q_lora_rank=24,
+            kv_lora_rank=16, qk_nope_head_dim=24, qk_rope_head_dim=8, v_head_dim=32,
+            rope_theta=1000000),
         interpret=True,
     )
-    assert len(rows) == 21
+    assert len(rows) == 23
+    step, mixer = rows.pop(), rows.pop()  # latent attention's two rows come last
+    assert mixer["kernel"].startswith("glm4_moe_lite mla mixer fwd+bwd B2/T128 bf16")
+    assert step["kernel"].startswith("glm4_moe_lite mla step B2/T128 bf16 over a latent ring of 24")
+    assert mixer["ok"] and step["ok"] and mixer["err"] > 0 and step["err"] > 0
     delta = rows.pop()  # the delta rule's pair against the jax.numpy body comes last
     assert delta["kernel"].startswith("gdn fwd+bwd B2/T40/H2:4x16:16/Q8 bf16") and delta["ok"]
     assert 0 < delta["err"] and delta["ms"] > 0 and delta["ms_ref"] > 0

@@ -232,12 +232,65 @@ def _check_qwen3_next_arch(arch: dict | None) -> None:
     _check_expert_share(arch, arch["num_experts"], arch["num_experts_per_tok"])
 
 
+# The keys of a GLM-4.7-Flash (``glm4_moe_lite``) ``config.json`` that shape
+# the policy core (``models/glm4_moe_lite.py``); ``expert_parallel`` as above,
+# with ``n_routed_experts`` the count one rank holds.
+GLM4_MOE_LITE_ARCH_KEYS = (
+    "hidden_size", "num_hidden_layers", "first_k_dense_replace", "rms_norm_eps",
+    "num_attention_heads", "num_key_value_heads", "q_lora_rank", "kv_lora_rank",
+    "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "rope_theta",
+    "partial_rotary_factor", "intermediate_size", "moe_intermediate_size",
+    "n_routed_experts", "n_shared_experts", "num_experts_per_tok", "norm_topk_prob",
+    "routed_scaling_factor", "topk_method", "n_group", "topk_group",
+)
+
+
+def _check_glm4_moe_lite_arch(arch: dict | None) -> None:
+    """What ``model="glm4_moe_lite"`` can build: every layer multi-head latent
+    attention (queries and keys/values through normed low-rank latents, one
+    rotated key shared by all heads) at equal query/key and value head sizes;
+    the first ``first_k_dense_replace`` layers with a dense SwiGLU MLP, the
+    others with ``swiglu`` experts under the sigmoid router with its
+    correction bias (``noaux_tc`` in one group) and ungated shared experts; no
+    bias anywhere, no rotary scaling, no multi-token prediction."""
+    assert isinstance(arch, dict), "model='glm4_moe_lite' needs arch (config.json keys)"
+    missing = [k for k in GLM4_MOE_LITE_ARCH_KEYS if k not in arch]
+    assert not missing, f"arch lacks {missing}"
+    depth, dense = arch["num_hidden_layers"], arch["first_k_dense_replace"]
+    assert 0 <= dense < depth, (
+        f"first_k_dense_replace {dense} of {depth} layers: an expert layer has to follow"
+    )
+    qk, v = arch["qk_nope_head_dim"] + arch["qk_rope_head_dim"], arch["v_head_dim"]
+    assert qk == v, (
+        f"qk_nope_head_dim + qk_rope_head_dim = {qk} is not v_head_dim = {v}: a latent path "
+        "with unequal query/value head sizes is not built"
+    )
+    assert arch["num_key_value_heads"] == arch["num_attention_heads"], (
+        "latent attention is multi-head: every head has its own keys and values"
+    )
+    assert arch["q_lora_rank"], "queries without a latent (q_lora_rank null) are not built"
+    assert arch["qk_rope_head_dim"] % 2 == 0, "rotate-half pairs the rotated part's two halves"
+    assert arch["partial_rotary_factor"] == 1, "the whole rotated part is rotated"
+    assert arch.get("rope_scaling") is None, "rotary scaling is not built"
+    assert arch.get("hidden_act", "silu") == "silu", arch.get("hidden_act")
+    assert not arch.get("attention_bias", False), "attention projections have no bias"
+    assert not arch.get("num_nextn_predict_layers", 0), "multi-token prediction is not built"
+    assert arch["topk_method"] == "noaux_tc", f"topk_method {arch['topk_method']!r}"
+    assert arch["n_group"] == 1 and arch["topk_group"] == 1, (
+        "the router's group stage is not built"
+    )
+    assert arch["norm_topk_prob"], "the chosen scores are always normalised"
+    assert arch["n_shared_experts"] >= 1, "an expert layer has its shared expert"
+    _check_expert_share(arch, arch["n_routed_experts"], arch["num_experts_per_tok"])
+
+
 # The families built from a published config.json in ``Config.arch``.
 ARCH_CHECKS = {
     "granite_hybrid": _check_granite_arch,
     "nemotron_h": _check_nemotron_arch,
     "smallthinker": _check_smallthinker_arch,
     "qwen3_next": _check_qwen3_next_arch,
+    "glm4_moe_lite": _check_glm4_moe_lite_arch,
 }
 
 
@@ -268,7 +321,9 @@ class Config:
     # or "smallthinker" (global and sliding-window attention, each layer with
     # gated sparse experts, of a SmallThinker config.json) or "qwen3_next"
     # (Gated-DeltaNet linear attention and gated full attention, each layer
-    # with sparse experts and a gated shared one, of a Qwen3-Next config.json).
+    # with sparse experts and a gated shared one, of a Qwen3-Next config.json)
+    # or "glm4_moe_lite" (multi-head latent attention, a leading dense layer,
+    # then sparse experts with a shared one, of a GLM-4.7-Flash config.json).
     model: str = "lstm"
     n_heads: int = 4
     n_layers: int = 2
@@ -281,7 +336,8 @@ class Config:
     # A published architecture's own config.json, under its published key
     # names (model="granite_hybrid": GRANITE_ARCH_KEYS above; "nemotron_h":
     # NEMOTRON_ARCH_KEYS; "smallthinker": SMALLTHINKER_ARCH_KEYS;
-    # "qwen3_next": QWEN3_NEXT_ARCH_KEYS). One
+    # "qwen3_next": QWEN3_NEXT_ARCH_KEYS; "glm4_moe_lite":
+    # GLM4_MOE_LITE_ARCH_KEYS). One
     # mapping instead of a Config field per width: the widths of a catalog
     # model are its source's to name, not this file's.
     arch: dict | None = None

@@ -202,11 +202,12 @@ def _act_transformer_window(
 
 def _act_granite_hybrid(actor, params, obs, h, c, key):
     """One recurrent step of the hybrid families (granite_hybrid,
-    nemotron_h, smallthinker, qwen3_next): ``h`` holds the recurrent layers'
-    (Mamba-2, linear attention) states and convolution tails, ``c`` the
-    attention layers' K/V rings and a step counter
-    (``models/granite_hybrid.py``). The worker zeroes both at
-    episode starts, so no state crosses episodes."""
+    nemotron_h, smallthinker, qwen3_next, glm4_moe_lite): ``h`` holds the
+    recurrent layers' (Mamba-2, linear attention) states and convolution
+    tails, ``c`` the attention layers' rings — keys and values, or for latent
+    attention one ring of the compressed key/value latent beside the shared
+    rotated key — and a step counter (``models/granite_hybrid.py``). The
+    worker zeroes both at episode starts, so no state crosses episodes."""
     logits, _value, (h2, c2) = actor.apply(params["actor"], obs, h, c, method="act")
     a = D.categorical_sample(key, logits)
     log_prob = D.categorical_log_prob(logits, a)
@@ -281,13 +282,15 @@ def build_family(cfg: Config, mesh=None) -> ModelFamily:
             store_carry=False,
         )
 
-    if cfg.model in ("nemotron_h", "smallthinker", "qwen3_next"):
+    if cfg.model in ("nemotron_h", "smallthinker", "qwen3_next", "glm4_moe_lite"):
         if cfg.model == "nemotron_h":
             from tpu_rl.models.nemotron_h import NemotronHActorCritic as core, carry_widths
         elif cfg.model == "smallthinker":
             from tpu_rl.models.smallthinker import SmallThinkerActorCritic as core, carry_widths
-        else:
+        elif cfg.model == "qwen3_next":
             from tpu_rl.models.qwen3_next import Qwen3NextActorCritic as core, carry_widths
+        else:
+            from tpu_rl.models.glm4_moe_lite import Glm4MoeLiteActorCritic as core, carry_widths
 
         ctx = cfg.effective_act_ctx
         actor = core(

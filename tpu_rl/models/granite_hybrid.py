@@ -540,6 +540,9 @@ class GraniteHybridActorCritic(nn.Module):
     act_ctx: int  # slots of the acting K/V ring
     dtype: Any = None  # matmul operand dtype; the residual stream is float32
     remat: bool = True  # tests only: the gradients must not depend on it
+    # arrays an attention layer's acting ring holds: keys and values (latent
+    # attention, ``models/glm4_moe_lite.py``: one, the latent beside the shared key)
+    ring_parts = 2
 
     def setup(self):
         a = self.arch
@@ -581,14 +584,16 @@ class GraniteHybridActorCritic(nn.Module):
         ]
 
     def _unpack_c(self, c):
-        """(B, c_width) -> one (k, v) ring per attention layer, each of its
-        own ``kv_shapes`` entry, and the step counter (B,) int."""
+        """(B, c_width) -> one ring per attention layer — ``ring_parts``
+        arrays, each of the layer's own ``kv_shapes`` entry — and the step
+        counter (B,) int."""
         rings, at = [], 0
         for shape in self.kv_shapes:
             n = int(np.prod(shape))
             rings.append(tuple(
-                c[:, at + i * n: at + (i + 1) * n].reshape(-1, *shape) for i in (0, 1)))
-            at += 2 * n
+                c[:, at + i * n: at + (i + 1) * n].reshape(-1, *shape)
+                for i in range(self.ring_parts)))
+            at += self.ring_parts * n
         return rings, c[:, -1].astype(jnp.int32)
 
     @staticmethod
@@ -640,8 +645,8 @@ class GraniteHybridActorCritic(nn.Module):
                 x, state, tail = layer.step(x, *next(mamba))
                 carried.append((state, tail))
             elif layer.kind == "attention":
-                x, k, v = layer.step(x, *next(rings), count)
-                caches.append((k, v))
+                x, *ring = layer.step(x, *next(rings), count)
+                caches.append(ring)
             else:  # a layer that carries nothing from step to step
                 (x,) = layer.step(x)
         logits, value = self._heads(x)
