@@ -450,6 +450,17 @@ def kernel_checks(
         (16384, 28, 4, 128, 128**-0.5, None, 8192, None),
         (8192, 16, 2, 256, 256**-0.5, None, 2048, None),
     ),
+    # (T, heads, key/value heads, head dim, softmax scale, sliding window, mean
+    # episode length, tile edge or None for the rule's): one row of
+    # smallthinker-21b-a3b's window at its global layer and its window layer and
+    # one of glm-4.7-flash's, seams drawn as their traffic draws them: the
+    # repo's own backward over the band's tiles (ops/pallas_attn_bwd.py) against
+    # the plain reference, and timed beside the library's on the same masks
+    attn_bwd_shapes=(
+        (16384, 28, 4, 128, 128**-0.5, None, 8192, None),
+        (16384, 28, 4, 128, 128**-0.5, 4096, 8192, None),
+        (16384, 20, 20, 256, 256**-0.5, None, 8192, None),
+    ),
     # (mixer, B, T): qwen3-next-80b-a3b's Gated-DeltaNet mixer (a window of one
     # span of sixteen chunks and a ragged second one) and its gated attention
     # mixer (heads of 256, q/k norm, partial RoPE, the output gate) at the widths
@@ -651,21 +662,27 @@ def kernel_checks(
 
     from tpu_rl.parallel import sequence
 
-    for T, NH, NKV, D, sm_scale, window, episode, edge in seam_shapes:
-        q = (f32(NH, T, D) * sm_scale).astype(jnp.bfloat16)  # the kernel's layout, one row
-        k, v = (f32(NKV, T, D).astype(jnp.bfloat16) for _ in range(2))
-        firsts = rng.random((1, T)) < 1.0 / episode  # benchmarks/traffic.firsts' draw
-        firsts[0, [0, T // 3]] = True  # and one seam whatever the draw
+    def drawn_row(T, episode, edge, window):
+        """One row's segment ids with seams drawn as benchmarks/traffic.firsts
+        draws them (and one whatever the draw), the tiles, and of the static
+        band's tiles how many run."""
+        firsts = rng.random((1, T)) < 1.0 / episode
+        firsts[0, [0, T // 3]] = True
         seg = jnp.asarray(np.cumsum(firsts, axis=1), jnp.int32)
-        w_o = f32(NH, T, D)
         bs = _splash_block_sizes(T)
         if edge is not None:
             bs = dataclasses.replace(bs, **{
                 f.name: edge for f in dataclasses.fields(bs)
                 if f.name.startswith("block_") and getattr(bs, f.name) is not None})
         band = sequence.band_tiles(T, bs.block_q, window)
-        n_band = int(band.sum())
         n_run = int((band & ~np.asarray(sequence.seam_empty_tiles(seg, bs.block_q))[0]).sum())
+        return seg, bs, n_run, int(band.sum())
+
+    for T, NH, NKV, D, sm_scale, window, episode, edge in seam_shapes:
+        q = (f32(NH, T, D) * sm_scale).astype(jnp.bfloat16)  # the kernel's layout, one row
+        k, v = (f32(NKV, T, D).astype(jnp.bfloat16) for _ in range(2))
+        seg, bs, n_run, n_band = drawn_row(T, episode, edge, window)
+        w_o = f32(NH, T, D)
 
         def one_row(skip):
             def fn(q, k, v, seg):
@@ -692,6 +709,83 @@ def kernel_checks(
             one_row(True), one_row(False), (q, k, v, seg), 0.0, 0.0, timed=True,
             ref_is_kernel=True,
         )
+
+    # ---- the repo's own backward over the band's tiles vs the plain reference
+    # (output and the three gradients), its dq no farther from it than the
+    # library's fused backward on the same masks, the two timed side by side
+    for T, NH, NKV, D, sm_scale, window, episode, edge in attn_bwd_shapes:
+        q = (f32(T, NH, D) * sm_scale).astype(jnp.bfloat16)  # one row
+        k, v = (f32(T, NKV, D).astype(jnp.bfloat16) for _ in range(2))
+        seg, bs, n_run, n_band = drawn_row(T, episode, edge, window)
+        pos = jnp.arange(T)[None]
+        w_o = f32(T, NH, D)
+
+        def with_grads(attend):
+            def fn(q, k, v, seg):
+                def loss(q, k, v):
+                    out = attend(q, k, v, seg)
+                    return (out.astype(jnp.float32) * w_o).sum(), out
+
+                grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+                return (out, *grads)
+
+            return jax.jit(fn)
+
+        def one_row(row):
+            def attend(q, k, v, seg):
+                splash = sequence._splash_kernel(
+                    T, NH, causal=True, window=window, block_sizes=bs, interpret=interpret)
+                empty = sequence.seam_empty_tiles(seg, bs.block_q)[0]
+                return row((True, window, 0), splash, q, k, v, seg[0], empty)
+
+            return with_grads(attend)
+
+        def plain(q, k, v, seg):  # float32 on the same bf16 inputs, 1,024 queries at a time
+            q, k, v = (x.astype(jnp.float32)[None] for x in (q, k, v))
+            k, v = (jnp.repeat(x, NH // NKV, axis=2) for x in (k, v))
+
+            @jax.checkpoint
+            def queries(at):
+                cut = lambda x: jax.lax.dynamic_slice_in_dim(x, at, min(T, 1024), axis=1)  # noqa: E731
+                scores = _masked_block_scores(
+                    cut(q), k, cut(pos), pos, cut(seg), seg, 1.0, True, window)
+                return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
+
+            out = jax.lax.map(queries, jnp.arange(0, T, min(T, 1024)))  # (chunks, 1, 1024, H, D)
+            return out.reshape(T, NH, D)
+
+        row = {"kernel": f"attn bwd over the band T{T}/H{NH}:{NKV}/D{D}"
+                         f"{f'/window{window}' if window else ''} bf16 vs the plain reference "
+                         f"(tiles of {bs.block_q}: {n_run} of the band's {n_band} run)",
+               "tol": TOL_BF16}
+        t0 = time.time()
+        try:
+            own, lib = one_row(sequence._seam_row), one_row(sequence._seam_row_forward)
+            paths = program_paths(own.lower(q, k, v, seg))
+            got, theirs = (jax.block_until_ready(f(q, k, v, seg)) for f in (own, lib))
+            with jax.default_matmul_precision("highest"):
+                want = jax.block_until_ready(with_grads(plain)(q, k, v, seg))
+            mean = lambda a, w: float(np.abs(np.asarray(a, np.float32) - np.asarray(w)).mean())  # noqa: E731
+            row.update(
+                err=_rel_err(got, want), err_lib=_rel_err(theirs, want),
+                err_dq=_rel_err(got[1], want[1]), err_dq_lib=_rel_err(theirs[1], want[1]),
+                mean_dq=mean(got[1], want[1]), mean_dq_lib=mean(theirs[1], want[1]),
+                # forward and dk, dv walk the tiles in the library's order (recorded, not
+                # required: an earlier form of the kernel held it in the interpreter alone)
+                same_out_dk_dv=all(
+                    bool((g == t).all()) for g, t in zip(got[:1] + got[2:], theirs[:1] + theirs[2:])),
+                mosaic_calls=paths["mosaic_calls"],
+                ms=best_ms(own, (q, k, v, seg)), ms_ref=best_ms(lib, (q, k, v, seg)),
+            )
+            row["ok"] = (
+                row["err"] <= TOL_BF16 and row["mean_dq"] <= row["mean_dq_lib"]
+                and "attn_bwd_pallas" in paths["paths"]
+            )
+        except Exception as e:  # noqa: BLE001 — report every kernel
+            row.update(ok=False, error=f"{type(e).__name__}: {str(e)[:1500]}")
+        row["wall_s"] = round(time.time() - t0, 1)
+        rows.append(row)
+        print(f"[kernels] {json.dumps(row)}", flush=True)
 
     # ---- the Pallas scan pair vs the jnp body of ssd_chunked, every gradient
     from tpu_rl.models.granite_hybrid import ssd_chunked

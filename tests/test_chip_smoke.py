@@ -65,6 +65,8 @@ def test_kernel_checks_pass_tiny_interpreted():
                     (300, 3, 4, 16, 64, 48, 1.0, True)),
         row_add_shapes=((512, 300, 128, 4),),
         seam_shapes=((512, 4, 2, 16, 0.25, 160, 128, 128), (512, 4, 2, 16, 0.25, None, 128, 128)),
+        attn_bwd_shapes=((512, 4, 2, 16, 0.25, None, 128, 128), (512, 4, 2, 16, 0.25, 160, 128, 128),
+                         (512, 2, 2, 32, 32**-0.5, None, 128, 128)),
         qwen3_next_shapes=(("linear", 2, 96), ("attention", 2, 128)),
         qwen3_next_widths=dict(
             hidden_size=64, rms_norm_eps=1e-6, linear_num_key_heads=2, linear_num_value_heads=4,
@@ -79,7 +81,7 @@ def test_kernel_checks_pass_tiny_interpreted():
             rope_theta=1000000),
         interpret=True,
     )
-    assert len(rows) == 23
+    assert len(rows) == 26
     step, mixer = rows.pop(), rows.pop()  # latent attention's two rows come last
     assert mixer["kernel"].startswith("glm4_moe_lite mla mixer fwd+bwd B2/T128 bf16")
     assert step["kernel"].startswith("glm4_moe_lite mla step B2/T128 bf16 over a latent ring of 24")
@@ -95,7 +97,12 @@ def test_kernel_checks_pass_tiny_interpreted():
         run = int(row["kernel"].split(": ")[1].split(" of")[0])
         assert f"of the band's {band} run" in row["kernel"] and 4 <= run < band
         assert row["ms"] > 0 and row["ms_ref"] > 0
-    rows = rows[:7] + rows[9:]
+    for row, band in zip(rows[9:12], (10, 9, 10)):  # the repo's own backward over the same bands
+        assert row["kernel"].startswith("attn bwd over the band T512/H") and row["ok"], row
+        assert f"of the band's {band} run" in row["kernel"] and row["same_out_dk_dv"]
+        assert 0 < row["err"] <= row["tol"] and row["mean_dq"] <= row["mean_dq_lib"]
+        assert row["ms"] > 0 and row["ms_ref"] > 0 and row["mosaic_calls"] == 0  # interpreted
+    rows = rows[:7] + rows[12:]
     assert rows[10]["kernel"].startswith("row_add 512 rows (439 live) into 300x128")
     assert rows[10]["ms"] > 0 and rows[10]["ms_ref"] > 0 and rows[10]["err"] == 0
     assert "/window48 " in rows[6]["kernel"]

@@ -231,12 +231,14 @@ def test_the_routing_counters_count_expert_layers_only(family, actor, system):
     assert float(scalars["moe-held-share"]) == pytest.approx(
         np.mean([float(r["stats"]["held-share"]) for r in routes]))
     attn = learn.attention_scalars(routes)
-    assert set(attn) == {f"attn-{what}-global" for what in ("pairs", "tiles-run", "tiles-band")}
+    assert set(attn) == {
+        f"attn-{what}-global" for what in ("pairs", "tiles-run", "tiles-band", "bwd-steps")}
     fir = batch["is_fir"][..., 0] > 0
     episode = np.cumsum(fir, axis=1)
     kept = sum(int(((e[:, None] == e[None, :]) & np.tri(T, dtype=bool)).sum()) for e in episode)
     assert float(attn["attn-pairs-global"]) == 3 * kept
     assert float(attn["attn-tiles-run-global"]) == float(attn["attn-tiles-band-global"]) == 3 * B
+    assert float(attn["attn-bwd-steps-global"]) == 3 * B  # three layers, a grid of one tile
     step = make_train_step(config(learn_diag=True), family)
     params = {"actor": actor}
     state = TrainState(step=jnp.zeros((), jnp.int32), params=params,
@@ -530,7 +532,7 @@ def test_the_dense_layer_is_whole_on_every_rank():
     assert p["gate_proj"]["kernel"].shape == p["up_proj"]["kernel"].shape == (64, 160)
     outs = [layer_of(rank, 0, chips=2).apply({"params": p}, x, seg) for rank in (0, 1)]
     close(outs[0][0], outs[1][0], 0)
-    assert set(outs[0][1]) == {"attn-pairs", "attn-tiles-run", "attn-tiles-band"}
+    assert set(outs[0][1]) == {"attn-pairs", "attn-tiles-run", "attn-tiles-band", "attn-bwd-steps"}
     u = reference.norm(x, p["input_norm"]["scale"], 1e-5)
     after = x + reference.latent_attention(u, jnp.asarray(seam), p["attention"], ARCH)
     h = reference.norm(after, p["post_norm"]["scale"], 1e-5)
@@ -609,13 +611,17 @@ TRANSFORMER = dict(algo="PPO", model="transformer", hidden_size=64, n_heads=4, n
 # sha256 of each family's update program as the commit before this family
 # (5ca21d4) lowered it at its test module's tiny widths: StableHLO without
 # locations, on the CPU (no Mosaic body). A PR that changes one of these
-# programs on purpose records the new digest here and says so.
+# programs on purpose records the new digest here and says so. PR 40:
+# ``smallthinker`` and ``qwen3_next`` recorded anew — their ``diag`` gained the
+# counter ``attn-bwd-steps-*``; with the counter left out of
+# ``obs/learn.ATTENTION_COUNTERS`` both lowered to the digests they had
+# (af779e4d…, c6f9c2a0…).
 BEFORE = {
     "transformer": "d449a158cdd0db48fccbcb732ec4709a96007cc9d621caa371e8c38a3ddbd59d",
     "granite_hybrid": "eee00c53d8985a43ec43a3a4c5f2b2e56c52534ec9055996c07ee232f796ff42",
     "nemotron_h": "901598e2fff52894f473edc00aa7092f0c9b70f6945ba94964cc5305f196ad83",
-    "smallthinker": "af779e4d3e811db290cf26daafdc845b9ca9dad627285b920daf9f0915e8f157",
-    "qwen3_next": "c6f9c2a0d8a15440ea3e3603a41bdb41032e4a37dbb29ba17036f5a53f7b752b",
+    "smallthinker": "888419d5ee711a69cf5ab4040cbb4b0695ee6df9a4cb941dd1d7dd2f3d5f57ad",
+    "qwen3_next": "18cfc983f487bc49095d47eee04d7aec343d9dd8b15cf94256a2646985293951",
 }
 
 

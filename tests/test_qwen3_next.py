@@ -466,12 +466,14 @@ def test_the_attention_counters_reach_the_diagnostics_under_the_span_global(acto
     routes = system(actor, batch)[2]
     assert [set(r.get("attn-pairs", {})) for r in routes] == [set(), set(), set(), {"global"}]
     scalars = learn.attention_scalars(routes)
-    assert set(scalars) == {f"attn-{what}-global" for what in ("pairs", "tiles-run", "tiles-band")}
+    assert set(scalars) == {
+        f"attn-{what}-global" for what in ("pairs", "tiles-run", "tiles-band", "bwd-steps")}
     fir = batch["is_fir"][..., 0] > 0
     episode = np.cumsum(fir, axis=1)
     kept = sum(int(((e[:, None] == e[None, :]) & np.tri(T, dtype=bool)).sum()) for e in episode)
     assert float(scalars["attn-pairs-global"]) == kept
     assert float(scalars["attn-tiles-run-global"]) == float(scalars["attn-tiles-band-global"]) == B
+    assert float(scalars["attn-bwd-steps-global"]) == B  # one full layer, a grid of one tile
 
 
 # --------------------------------------- each new field against a line by hand

@@ -667,9 +667,10 @@ class TestSeamSkipping:
         seg = _seg_from_seams(T, SEAMS[case])
         band = band_tiles(T, edge, window)
         want_run = sum(int((band & ~e).sum()) for e in _empty_twin(seg, edge))
-        run, total = jax.jit(lambda s: attention_tiles(s, window, edge))(jnp.asarray(seg))
+        run, total, steps = jax.jit(lambda s: attention_tiles(s, window, edge))(jnp.asarray(seg))
         assert (float(run), float(total)) == (want_run, 2 * band.sum())
-        assert run.dtype == total.dtype == jnp.float32
+        assert float(steps) == float(total)  # the backward's grid is the band
+        assert run.dtype == total.dtype == steps.dtype == jnp.float32
         if case == "no-seam":
             assert float(run) == float(total)
         if case == "two-rows-differ" and window is None:
@@ -686,9 +687,227 @@ class TestSeamSkipping:
         monkeypatch.setattr(sequence, "_SEAM_BLOCKS", self.production_gate)
         edge = edge_in or T // blocks
         seg = _seg_from_seams(T, [(T // 2,)])  # empties the tile under the diagonal of a 2 x 2 grid
-        run, total = attention_tiles(jnp.asarray(seg), None, edge_in)
+        run, total, steps = attention_tiles(jnp.asarray(seg), None, edge_in)
         band = band_tiles(T, edge)
         assert band.shape == (blocks, blocks) and float(total) == band.sum()
         static = blocks < self.production_gate
+        # under the gate the library's fused backward steps over the rectangle
+        assert float(steps) == (blocks * blocks if static else band.sum())
         want = band.sum() if static else (band & ~_empty_twin(seg, edge)[0]).sum()
         assert float(run) == want and (static or want < band.sum())
+
+
+# ------------------------- the repo's own backward over the band's tiles (PR 40)
+def _brute_force_steps(band, empty):
+    """The walk ``band_steps`` should list, as loops: key block by key block,
+    query blocks ascending, the tiles of ``band`` that ``empty`` leaves."""
+    n_q, n_kv = band.shape
+    kept = [(j, i) for j in range(n_kv) for i in range(n_q) if band[i, j] and not empty[i, j]]
+    flags = []
+    for at, (j, _) in enumerate(kept):
+        first = at == 0 or kept[at - 1][0] != j
+        last = at == len(kept) - 1 or kept[at + 1][0] != j
+        flags.append(1 + 2 * first + 4 * last)
+    return kept, flags
+
+
+class TestBandBackward:
+    """PR 40: where ``_splash_mha`` reads each row's block masks from its
+    segment ids, the backward is ``ops/pallas_attn_bwd.py``'s: a grid as long
+    as the static band, walked by a scalar-prefetched list of the tiles no seam
+    emptied, one head's dq added in float32 in a resident block. Interpret mode
+    on the CPU; the gate at three blocks an edge as in :class:`TestSeamSkipping`."""
+
+    @pytest.fixture(autouse=True)
+    def three_blocks_engage(self, monkeypatch):
+        from tpu_rl.parallel import sequence
+
+        self.production_gate = sequence._SEAM_BLOCKS
+        monkeypatch.setattr(sequence, "_SEAM_BLOCKS", 3)
+
+    @pytest.mark.parametrize("T,edge,window,seams", [
+        (16384, 1024, None, (5000, 11000)), (16384, 1024, 4096, (5000, 11000)),
+        (8192, 1024, None, (2048, 4096, 6000)), (16384, 1024, None, ()),
+        (512, 128, 160, (200,)), (512, 128, None, (256,)), (512, 128, 128, (128, 384)),
+        (1024, 128, 300, tuple(range(5, 1024, 7))), (1024, 128, 1, (130, 300)),
+    ])
+    def test_the_step_list_against_a_brute_force_walk(self, T, edge, window, seams):
+        """Every tile of ``band_tiles & ~seam_empty_tiles`` once, in key-block
+        order with query blocks ascending; the first / last flags where a key
+        block's steps begin and end; then a tail that computes nothing and
+        repeats the last computing step's indices."""
+        from tpu_rl.ops.pallas_attn_bwd import band_steps
+        from tpu_rl.parallel.sequence import band_tiles, seam_empty_tiles
+
+        seg = _seg_from_seams(T, [seams])
+        band = band_tiles(T, edge, window)
+        empty = _empty_twin(seg, edge)[0] if T <= 1024 else np.asarray(seam_empty_tiles(seg, edge))[0]
+        kv_of, q_of, flags = (np.asarray(x) for x in jax.jit(
+            lambda e: band_steps(band, e))(jnp.asarray(empty)))
+        assert kv_of.dtype == q_of.dtype == flags.dtype == np.int32
+        assert kv_of.shape == q_of.shape == flags.shape == (band.sum(),)
+        if (T, window) in ((16384, None), (16384, 4096), (8192, None)):
+            assert band.sum() == {(16384, None): 136, (16384, 4096): 70, (8192, None): 36}[T, window]
+        kept, want_flags = _brute_force_steps(band, empty)
+        n = len(kept)
+        assert n == (band & ~empty).sum() and (n < band.sum()) == bool(seams and (band & empty).any())
+        assert list(zip(kv_of[:n], q_of[:n])) == kept and list(flags[:n]) == want_flags
+        assert (flags[n:] == 0).all()  # the tail: no fetch, no write
+        assert (kv_of[n:] == kept[-1][0]).all() and (q_of[n:] == kept[-1][1]).all()
+        # a key block's steps are consecutive, and every key block has some: dk and dv
+        # of every block are written, once
+        assert sorted({j for j, _ in kept}) == list(range(T // edge))
+        assert sum(f & 2 > 0 for f in flags) == sum(f & 4 > 0 for f in flags) == T // edge
+
+    @pytest.mark.parametrize("D", [64, 128], ids=["head-64", "head-128"])
+    @pytest.mark.parametrize("window", [None, 160], ids=["global", "window-160"])
+    @pytest.mark.parametrize("case", [*SEAMS, "grouped-14:2", "equal-heads"])
+    def test_no_farther_from_full_attention_than_the_librarys_backward(self, rng, case, window, D):
+        """bf16 inputs, as the cells': dq / dk / dv of the new backward and of
+        the library's fused one on the same traced masks, each against
+        ``full_attention`` in float32 on the same inputs. out, dk and dv walk
+        the tiles in the library's order and are its to the bit; dq is rounded
+        once, not once a key block: no farther in the mean, within one bf16
+        step of the library's."""
+        from tpu_rl.parallel import sequence
+        from tpu_rl.parallel.sequence import _splash_block_sizes
+
+        T = 512
+        H, n_kv = {"grouped-14:2": (14, 2), "equal-heads": (2, 2)}.get(case, (4, 2))
+        q, k, v, _, _ = _inputs(rng, B=1, T=T, H=H, D=D)
+        scale = float(1.0 / np.sqrt(D))
+        q, k, v = ((x[0] * s).astype(jnp.bfloat16)  # one row, (T, H, D)
+                   for x, s in ((q, scale), (k[:, :, :n_kv], 1.0), (v[:, :, :n_kv], 1.0)))
+        seg = jnp.asarray(_seg_from_seams(T, SEAMS.get(case, SEAMS["two-rows-differ"]))[:1])
+        pos = _segment_relative(seg)
+        tiles = _tiles_of(_splash_block_sizes(T), 128)
+        cot = jnp.asarray(rng.normal(size=(T, H, D)).astype(np.float32))
+
+        def grads(attend):
+            def loss(q, k, v, seg):
+                return (attend(q, k, v, seg).astype(jnp.float32) * cot).sum()
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v, seg)
+
+        def splash(row):
+            def attend(q, k, v, seg):
+                kernel = sequence._splash_kernel(
+                    T, H, causal=True, window=window, block_sizes=tiles, interpret=True)
+                empty = sequence.seam_empty_tiles(seg, 128)[0]
+                return row((True, window, 0), kernel, q, k, v, seg[0], empty)
+            return attend
+
+        def full(q, k, v, seg):
+            q, k, v = (x.astype(jnp.float32)[None] for x in (q, k, v))
+            k, v = (jnp.repeat(x, H // n_kv, axis=2) for x in (k, v))
+            return full_attention(q, k, v, pos, seg, causal=True, sm_scale=1.0, window=window)[0]
+
+        own = grads(splash(sequence._seam_row))
+        lib = grads(splash(sequence._seam_row_forward))
+        want = grads(full)
+        f32 = lambda x: np.asarray(x, np.float32)  # noqa: E731
+        for name, g, t, w in zip(("dq", "dk", "dv"), own, lib, want):
+            assert g.dtype == t.dtype == jnp.bfloat16 and g.shape == t.shape == w.shape, name
+            far, far_lib = (np.abs(f32(x) - f32(w)) for x in (g, t))
+            assert far.mean() <= far_lib.mean(), name
+            assert far.max() <= 3e-2 * np.abs(f32(w)).max(), name
+            if name != "dq":
+                np.testing.assert_array_equal(f32(g), f32(t), err_msg=name)
+        # the library rounds a partial a key block at the partial's size, which a sum
+        # may fall under: one bf16 step at the size of dq, not of the element
+        assert np.abs(f32(own[0]) - f32(lib[0])).max() <= 2.0 ** -7 * np.abs(f32(lib[0])).max()
+
+    @pytest.mark.parametrize("heads", [(4, 2), (2, 2)], ids=["grouped-4:2", "equal-heads"])
+    def test_inner_steps_of_fewer_keys_than_a_tile(self, rng, heads):
+        """``block_kv_dkv_compute`` under ``block_kv_dkv`` (512 of 1,024 in the
+        cells): a tile's keys in two inner steps, dk and dv gathered at their
+        rows of the scratch, dq added twice. Against the library's backward at
+        the same blocks (dk, dv to the bit) and ``full_attention``."""
+        import dataclasses
+
+        from tpu_rl.parallel.sequence import _splash_mha, _splash_block_sizes
+
+        T, D = 1024, 64
+        H, n_kv = heads
+        q, k, v, _, _ = _inputs(rng, T=T, H=H, D=D)
+        k, v = k[:, :, :n_kv], v[:, :, :n_kv]
+        seg = jnp.asarray(_seg_from_seams(T, [(300, 700), (512,)]))
+        pos = _segment_relative(seg)
+        tiles = dataclasses.replace(
+            _tiles_of(_splash_block_sizes(T), 256), block_kv_compute=128, block_kv_dkv_compute=128)
+        cot = jnp.asarray(rng.normal(size=q.shape).astype(np.float32))
+
+        def splash(q, k, v, seg):
+            out = _splash_mha(q, k, v, seg, causal=True, scale=0.125, block_sizes=tiles,
+                              interpret=True)
+            return (out * cot).sum()
+
+        def full(q, k, v, seg):
+            kr, vr = (jnp.repeat(x, H // n_kv, axis=2) for x in (k, v))
+            return (full_attention(q, kr, vr, pos, seg, causal=True) * cot).sum()
+
+        grad = lambda f: jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v, seg)  # noqa: E731
+        own, want = grad(splash), grad(full)
+        text = str(jax.make_jaxpr(jax.grad(splash, argnums=(0, 1, 2)))(q, k, v, seg))
+        assert "attn_bwd_band" in text and "splash_mha_dkv" not in text
+        for name, g, w in zip(("dq", "dk", "dv"), own, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-5,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("B", [2, 1], ids=["two-rows", "one-row"])
+    @pytest.mark.parametrize("window", [None, 4096], ids=["global", "window-4096"])
+    def test_no_dq_partials_and_no_sum_over_key_blocks(self, window, B):
+        """The engaged program at a cell's shape (T 16,384 in tiles of 1,024,
+        28 : 4 heads of 128, the production gate): the backward is one
+        ``attn_bwd_band`` call a row whose grid is (heads, the band's tiles);
+        no array has a leading axis of T / bkv = 16 key blocks over (H, T, D),
+        and no ``reduce_sum`` runs over one. Where several rows are walked a
+        call declares one more output of the partials' size that the kernel never
+        writes and of which one element is read (``_splash_rows_skipping_seams``
+        says why); a single row declares none."""
+        from tpu_rl.parallel import sequence
+        from tpu_rl.parallel.sequence import _splash_mha, _splash_block_sizes
+
+        sequence._SEAM_BLOCKS = self.production_gate  # monkeypatch restores it
+        T, H, n_kv, D = 16384, 28, 4, 128
+        tiles = _splash_block_sizes(T)
+        shapes = [jax.ShapeDtypeStruct((B, T, h, D), jnp.bfloat16) for h in (H, n_kv, n_kv)]
+        shapes.append(jax.ShapeDtypeStruct((B, T), jnp.int32))
+
+        def f(q, k, v, seg):
+            out = _splash_mha(q, k, v, seg, causal=True, scale=D ** -0.5, block_sizes=tiles,
+                              interpret=True, window=window)
+            return out.astype(jnp.float32).sum()
+
+        jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(*shapes)
+        blocks = T // tiles.block_kv_dkv
+        steps = 136 if window is None else 70
+        calls, partials, sums, own = [], [], [], []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                for var in (*eqn.invars, *eqn.outvars):
+                    shape = getattr(var.aval, "shape", ())
+                    if len(shape) == 4 and shape[0] == blocks and shape[2] == T:
+                        partials.append((eqn.primitive.name, shape))
+                if eqn.primitive.name == "reduce_sum" and eqn.invars[0].aval.shape[:1] == (blocks,) \
+                        and 0 in eqn.params["axes"] and eqn.invars[0].aval.ndim == 4:
+                    sums.append(eqn)
+                if eqn.primitive.name == "pallas_call":
+                    calls.append((eqn.params["name"], eqn.params["grid_mapping"].grid))
+                    if eqn.params["name"] == "attn_bwd_band":
+                        own.append(eqn)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jaxpr.jaxpr)
+        assert not partials and not sums
+        assert [grid for name, grid in calls if name == "attn_bwd_band"] == [(H, steps)] * B, calls
+        assert not any("dkv" in name for name, _ in calls)  # the library's backward is gone
+        assert sum("splash_mha_fwd" in name for name, _ in calls) == B
+        for eqn in own:  # dq, dk, dv where they lie in (T, heads * D); then the ballast, dropped
+            shapes_out = [v.aval.shape for v in eqn.outvars]
+            assert shapes_out[:3] == [(T, H * D), (T, n_kv * D), (T, n_kv * D)]
+            if B == 1:
+                assert len(shapes_out) == 3
+            else:
+                assert shapes_out[3:] == [(blocks * H, T, D)]

@@ -351,8 +351,9 @@ def test_the_pair_counters_reach_the_diagnostics(actor, system):
     assert float(scalars["attn-pairs-window"]) == 3 * per_window  # three window layers
     assert 0.5 < per_window / per_global < 0.9  # the window cuts pairs on this batch
     # beside the routing, not in it: the routing's counters are the expert block's alone
-    assert set(scalars) == {f"attn-{what}-{kind}" for what in ("pairs", "tiles-run", "tiles-band")
-                            for kind in ("global", "window")}
+    assert set(scalars) == {
+        f"attn-{what}-{kind}" for what in ("pairs", "tiles-run", "tiles-band", "bwd-steps")
+        for kind in ("global", "window")}
     assert not any(key.startswith("attn") for key in learn.route_scalars(routes))
     assert learn.attention_scalars([{"stats": {}}]) == {}  # a family that counts no pairs
 
@@ -369,6 +370,7 @@ def test_the_tile_counters_reach_the_diagnostics(actor, system, kind, layers):
     scalars = learn.attention_scalars(routes)
     assert float(scalars[f"attn-tiles-run-{kind}"]) == layers * B
     assert float(scalars[f"attn-tiles-band-{kind}"]) == layers * B
+    assert float(scalars[f"attn-bwd-steps-{kind}"]) == layers * B  # a grid of one tile
 
 
 @pytest.mark.parametrize("window", [None, 12], ids=["global", "window"])
@@ -387,14 +389,17 @@ def test_the_tile_counters_fold_like_the_layers_own_counts(window):
     seg = np.cumsum(fir, axis=1).astype(np.int32)
     band = band_tiles(T, edge, window)
     want_run = sum(int((band & ~e).sum()) for e in seam_empty_tiles(seg, edge))
-    run, total = attention_tiles(jnp.asarray(seg), window, edge)
+    run, total, steps = attention_tiles(jnp.asarray(seg), window, edge)
     assert (float(run), float(total)) == (want_run, 2 * band.sum()) and want_run < 2 * band.sum()
+    assert float(steps) == 2 * band.sum()  # an 8 x 8 grid: the backward walks the band
     kind = "global" if window is None else "window"
-    layer = {"attn-pairs": {kind: 1.0}, "attn-tiles-run": {kind: run}, "attn-tiles-band": {kind: total}}
+    layer = {"attn-pairs": {kind: 1.0}, "attn-tiles-run": {kind: run}, "attn-tiles-band": {kind: total},
+             "attn-bwd-steps": {kind: steps}}
     other = {"attn-tiles-run": {"other": jnp.float32(5)}, "attn-tiles-band": {"other": jnp.float32(7)}}
     scalars = learn.attention_scalars([layer, other, layer, layer])
     assert float(scalars[f"attn-tiles-run-{kind}"]) == 3 * want_run
     assert float(scalars[f"attn-tiles-band-{kind}"]) == 3 * 2 * band.sum()
+    assert float(scalars[f"attn-bwd-steps-{kind}"]) == 3 * 2 * band.sum()
     assert (float(scalars["attn-tiles-run-other"]), float(scalars["attn-tiles-band-other"])) == (5, 7)
     assert float(scalars[f"attn-pairs-{kind}"]) == 3.0
 

@@ -42,3 +42,44 @@ def test_the_row_add_kernel_compiles_at_the_cells_shapes(one_chip, rows, tokens,
     assert "tpu_custom_call" in text and "moe_row_add" in text
     # the result is updated in place: no second copy of it among the temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * tokens * width
+
+
+@pytest.mark.parametrize("rows, T, heads, kv_heads, D, window, band", [
+    (2, 16384, 28, 4, 128, None, 136), (2, 16384, 28, 4, 128, 4096, 70),
+    (1, 16384, 20, 20, 256, None, 136), (2, 8192, 16, 2, 256, None, 36),
+], ids=["smallthinker-global", "smallthinker-window", "glm", "qwen3-next"])
+def test_the_attention_backward_compiles_at_the_cells_shapes(
+        one_chip, rows, T, heads, kv_heads, D, window, band):
+    """Forward (the library's kernel on traced masks) and the repo's own
+    backward over the band (``ops/pallas_attn_bwd.py``) for a v5e: a head's
+    float32 dq, a key head's dk / dv scratch and the blocks of a step have to
+    fit the VMEM the call asks for, and a head is a column block of the
+    gradients' ``(T, heads * D)``. No dq-partial buffer: the temporaries of two
+    rows stay under one row's partials of the library's backward."""
+    from tpu_rl.parallel import sequence
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def grads(q, k, v, seg):
+        def loss(q, k, v):
+            out = sequence._splash_mha(
+                q, k, v, seg, causal=True, scale=D ** -0.5,
+                block_sizes=sequence._splash_block_sizes(T), window=window)
+            return out.astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        lowered = jax.jit(grads).lower(
+            shaped((rows, T, heads, D), jnp.bfloat16), shaped((rows, T, kv_heads, D), jnp.bfloat16),
+            shaped((rows, T, kv_heads, D), jnp.bfloat16), shaped((rows, T), jnp.int32))
+        text = lowered.as_text()
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+    assert text.count("tpu_custom_call") >= 2 and "attn_bwd_band" in text  # forward, backward
+    assert f"tensor<{band}xi32>" in text  # the step list: as long as the static band
+    partials = T // 1024 * heads * T * D * 2  # what one row's dq partials took; two rows declare it
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * partials

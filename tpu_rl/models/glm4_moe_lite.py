@@ -53,8 +53,8 @@ ring per layer and a step counter.
 ``unroll_routed`` returns one routing record per *expert* layer (the choices
 and ``ops/moe.route_stats``) and, in the records, what every layer's attention
 mask did under the span name ``global`` (``attn-pairs``, ``attn-tiles-run``,
-``attn-tiles-band``), as ``models/smallthinker.py`` does; a dense layer's
-counts ride with the first expert layer's record.
+``attn-tiles-band``, ``attn-bwd-steps``), as ``models/smallthinker.py`` does; a
+dense layer's counts ride with the first expert layer's record.
 """
 
 from __future__ import annotations
@@ -68,6 +68,7 @@ import jax.numpy as jnp
 from tpu_rl.models.granite_hybrid import RMSNorm, rope
 from tpu_rl.models.nemotron_h import ExpertBlock, NemotronHActorCritic, expert_share
 from tpu_rl.models.smallthinker import kept_pairs
+from tpu_rl.obs.learn import ATTENTION_COUNTERS
 from tpu_rl.parallel.sequence import attention_tiles, flash_attention_tpu
 
 def ring_width(arch: dict) -> int:
@@ -227,9 +228,8 @@ class Glm4MoeLiteLayer(nn.Module):
         did and (an expert layer) its routing."""
         with jax.named_scope("mla"):
             x = x + self.attention(self.input_norm(x), seg)
-        run, band = attention_tiles(seg)
-        record = {"attn-pairs": {"global": kept_pairs(seg, None)},
-                  "attn-tiles-run": {"global": run}, "attn-tiles-band": {"global": band}}
+        counts = (kept_pairs(seg, None), *attention_tiles(seg))
+        record = {c: {"global": n} for c, n in zip(ATTENTION_COUNTERS, counts)}
         if self.dense:
             return self._mlp(x), record
         with jax.named_scope("moe"):

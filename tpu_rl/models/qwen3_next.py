@@ -46,8 +46,8 @@ stored normed and rotated at their own step) and a step counter.
 
 ``unroll_routed`` also returns each layer's routing record and, beside a full
 layer's, what its attention mask did under the span name ``global``
-(``attn-pairs``, ``attn-tiles-run``, ``attn-tiles-band``), as
-``models/smallthinker.py`` does.
+(``attn-pairs``, ``attn-tiles-run``, ``attn-tiles-band``, ``attn-bwd-steps``),
+as ``models/smallthinker.py`` does.
 """
 
 from __future__ import annotations
@@ -69,6 +69,7 @@ from tpu_rl.models.granite_hybrid import (
 from tpu_rl.models.nemotron_h import ExpertBlock, NemotronHActorCritic, expert_share
 from tpu_rl.models.smallthinker import kept_pairs
 from tpu_rl.ops.gated_delta import gated_delta_chunked, gated_delta_step
+from tpu_rl.obs.learn import ATTENTION_COUNTERS
 from tpu_rl.parallel.sequence import attention_tiles
 
 # Steps a chunk of the training form takes: the family's convention, not a
@@ -245,9 +246,8 @@ class Qwen3NextLayer(nn.Module):
         with jax.named_scope("moe"):
             mixed, route = self.experts(self.post_norm(x))
         if self.kind == "attention":
-            run, band = attention_tiles(seg)
-            route["attn-pairs"] = {"global": kept_pairs(seg, None)}
-            route["attn-tiles-run"], route["attn-tiles-band"] = {"global": run}, {"global": band}
+            counts = (kept_pairs(seg, None), *attention_tiles(seg))
+            route.update({c: {"global": n} for c, n in zip(ATTENTION_COUNTERS, counts)})
         return (x + mixed, *carry, route)
 
     def step(self, x, *carry):

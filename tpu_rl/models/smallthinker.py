@@ -37,8 +37,9 @@ RoPE layer's ring stores its keys as rotated at their own step.
 routing in it what the layer's attention mask did (``global`` or ``window`` ->
 the count; ``obs/learn.attention_scalars``): the query-key pairs it kept
 (``attn-pairs``), and of the splash kernels' grid the tiles of the static band
-(``attn-tiles-band``) and those of them that no seam emptied, which the
-kernels compute (``attn-tiles-run``; ``parallel/sequence.attention_tiles``).
+(``attn-tiles-band``), those of them that no seam emptied, which the
+kernels compute (``attn-tiles-run``), and the grid steps the backward takes a
+head (``attn-bwd-steps``; ``parallel/sequence.attention_tiles``).
 """
 
 from __future__ import annotations
@@ -51,6 +52,7 @@ import jax.numpy as jnp
 
 from tpu_rl.models.granite_hybrid import GQAttention, RMSNorm
 from tpu_rl.models.nemotron_h import ExpertBlock, NemotronHActorCritic, expert_share
+from tpu_rl.obs.learn import ATTENTION_COUNTERS
 from tpu_rl.parallel.sequence import attention_tiles
 
 
@@ -120,9 +122,8 @@ class SmallThinkerLayer(nn.Module):
             x = x + self.attention(a, seg)
         with jax.named_scope("moe"):
             mixed, route = self.experts(self.post_norm(x), scored=a)
-        route["attn-pairs"] = {self.span: kept_pairs(seg, self.window)}
-        run, band = attention_tiles(seg, self.window)
-        route["attn-tiles-run"], route["attn-tiles-band"] = {self.span: run}, {self.span: band}
+        counts = (kept_pairs(seg, self.window), *attention_tiles(seg, self.window))
+        route.update({c: {self.span: n} for c, n in zip(ATTENTION_COUNTERS, counts)})
         return x + mixed, route
 
     def step(self, x, k_cache, v_cache, count):

@@ -124,17 +124,24 @@ def route_scalars(routes: list) -> dict[str, jax.Array]:
     }
 
 
+# What an attention layer hands back beside its routing: the kept pairs, then
+# ``parallel/sequence.attention_tiles``' three counts in its order.
+ATTENTION_COUNTERS = ("attn-pairs", "attn-tiles-run", "attn-tiles-band", "attn-bwd-steps")
+
+
 def attention_scalars(routes: list) -> dict[str, jax.Array]:
     """What the attention masks did this update, from what the layers handed
     back beside their routing (counter -> kind -> count;
     ``models/smallthinker.py``), each summed over the layers of its kind:
     the query-key pairs kept (``attn-pairs-global``, ``attn-pairs-window``),
-    the tiles of the splash kernels' static band (``attn-tiles-band-*``) and
+    the tiles of the splash kernels' static band (``attn-tiles-band-*``),
     those of them the kernels computed because no seam emptied them
-    (``attn-tiles-run-*``). Empty for a family that counts none."""
+    (``attn-tiles-run-*``), and the grid steps the backward took a head
+    (``attn-bwd-steps-*``: run over steps is the share of its steps that
+    compute). Empty for a family that counts none."""
     out: dict[str, jax.Array] = {}
     for r in routes:
-        for counter in ("attn-pairs", "attn-tiles-run", "attn-tiles-band"):
+        for counter in ATTENTION_COUNTERS:
             for kind, count in r.get(counter, {}).items():
                 out[f"{counter}-{kind}"] = out.get(f"{counter}-{kind}", 0.0) + count
     return out

@@ -139,19 +139,22 @@ def band_tiles(T: int, edge: int, window: int | None = None) -> np.ndarray:
 
 def attention_tiles(seg, window: int | None = None, edge: int | None = None):
     """What the splash kernels do with a batch of windows, counted from
-    ``seg`` (B, T): ``(run, band)`` — the tiles they compute (in the static
-    band and not emptied by a seam) and the static band's, each summed over
-    the rows, float32. ``edge``: the tile's, :func:`_splash_edge`'s by
-    default. Where the grid is too small for :func:`_splash_mha` to read the
-    seams, every band tile runs."""
+    ``seg`` (B, T): ``(run, band, steps)`` — the tiles they compute (in the
+    static band and not emptied by a seam), the static band's, and the grid
+    steps the backward takes a head, each summed over the rows, float32.
+    ``edge``: the tile's, :func:`_splash_edge`'s by default. Where the grid is
+    too small for :func:`_splash_mha` to read the seams, every band tile runs
+    and the library's fused backward steps over the whole (T / edge)^2
+    rectangle; from ``_SEAM_BLOCKS`` blocks an edge on the backward is the
+    repo's own and its grid is the band."""
     T = seg.shape[1]
     edge = _splash_edge(T) if edge is None else edge
     band = band_tiles(T, edge, window)
     total = jnp.float32(seg.shape[0] * int(band.sum()))
     if T // edge < _SEAM_BLOCKS:
-        return total, total
+        return total, total, jnp.float32(seg.shape[0] * band.size)
     run = jnp.asarray(band) & ~seam_empty_tiles(seg, edge)
-    return jnp.sum(run.astype(jnp.float32)), total
+    return jnp.sum(run.astype(jnp.float32)), total, total
 
 
 def _next_computed(block_mask, data_next):
@@ -171,14 +174,18 @@ def _next_computed(block_mask, data_next):
 def _skip_seams(splash, empty):
     """``splash`` (a ``SplashAttentionKernel`` over static mask info) with the
     tiles of ``empty`` (T / edge, T / edge; one row of
-    :func:`seam_empty_tiles`) zeroed in both block masks, so none of the three
-    kernels computes them, and the prefetch indices following
+    :func:`seam_empty_tiles`) zeroed in both block masks, so neither of the
+    library's kernels computes them, and the prefetch indices following
     (:func:`_next_computed`), so none fetches their blocks. Zeros are only
     added: an entry of 1 or 2 and the in-kernel segment mask mean what they
     meant, and a wrong "not empty" costs time, never correctness. The
     forward's grid is shrunk to the band — a column is a slot, and the static
-    ``data_next`` says which key block the slot holds; the fused backward's is
-    the whole [query block, key block] grid, walked key block by key block."""
+    ``data_next`` says which key block the slot holds. The third mask info is
+    the library's fused backward's, over the whole [query block, key block]
+    grid walked key block by key block: only a row whose backward stays the
+    library's reads it (:func:`_splash_rows_skipping_seams`); the repo's own
+    backward walks a list of the band's tiles instead
+    (``ops/pallas_attn_bwd.band_steps``, from the same ``empty``)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel
 
     fwd, dkv = splash.fwd_mask_info, splash.dkv_mask_info
@@ -230,14 +237,17 @@ def _splash_mha(q, k, v, seg, *, causal, scale, block_sizes, interpret=False, wi
     sees the ``window`` keys that end with itself (the library's ``LocalMask``):
     the mask info then names the tiles inside the band alone. The forward's
     grid is shrunk to them, so it never visits a tile that lies wholly behind
-    the band; the fused backward's is not (``shrink_grid=not
-    use_fused_bwd_kernel``): it steps over every tile of the grid and computes
-    the band's. Where the grid has ``_SEAM_BLOCKS`` blocks an edge or more,
-    each row's block masks are read from its segment ids
-    (:func:`_splash_rows_skipping_seams`): a band tile in which no query and
-    key share a segment is stepped over too, forward and backward, instead of
-    computed and masked whole inside the kernel. A smaller grid keeps the
-    static masks and lowers to the program it always did.
+    the band; the library's fused backward's is not (``shrink_grid=not
+    use_fused_bwd_kernel``): it steps over every tile of the grid, computes the
+    band's, and leaves dq as one partial a key block for XLA to sum. Where the
+    grid has ``_SEAM_BLOCKS`` blocks an edge or more, each row's block masks
+    are read from its segment ids (:func:`_splash_rows_skipping_seams`): a band
+    tile in which no query and key share a segment is stepped over in the
+    forward instead of computed and masked whole inside the kernel, and the
+    backward is the repo's own (``ops/pallas_attn_bwd.py``), whose grid *is*
+    the band: one step a band tile, the tiles a seam emptied left out of the
+    walk, dq added in float32 where it lands. A smaller grid keeps the static
+    masks and the library's backward and lowers to the program it always did.
     The kernel takes no softmax scale, so ``scale`` is folded into q first —
     exact in bf16 for a power of two (tf-longctx: 1/8; granite: 1/64); a
     general scale (head size 128: 128^-0.5) rounds q once more.
@@ -263,29 +273,108 @@ def _splash_rows_skipping_seams(q, k, v, seg, *, causal, scale, block_sizes, int
     """:func:`_splash_mha` with each row's block masks read from its segment
     ids. One splash call a row, in a Python loop: a ``jax.vmap`` over the
     traced scalar-prefetch operands makes Pallas loop over the rows inside one
-    ``while`` whose state holds every row's dq partials and takes a copy of
-    each kernel output (7.8 GiB of temporaries a layer at T 16,384 against
-    this loop's 2.2 and the static call's 3.8, compiled for a v5e), and with
-    one call a row only one row's partials are alive at a time. Under one
-    ``jax.jit``: an eager caller dispatches one program, and the layers of a
-    model that share shapes and window are traced once."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import SegmentIds
+    ``while`` whose state takes a copy of each kernel output (7.8 GiB of
+    temporaries a layer at T 16,384 against this loop's 2.2 and the static
+    call's 3.8, compiled for a v5e, PR 35), and with one call a row only one
+    row's gradients are alive at a time. Under one ``jax.jit``: an eager
+    caller dispatches one program, and the layers of a model that share shapes
+    and window are traced once.
 
+    The forward of a row is the library's kernel on the masks
+    :func:`_skip_seams` builds; its backward is the repo's own
+    (:func:`_seam_row_bwd`, ``ops/pallas_attn_bwd.py``) over the band's tiles
+    alone, unless a head's dq does not fit the core's VMEM
+    (``pallas_attn_bwd.fits``), where the library's fused backward stays."""
+    from tpu_rl.ops import pallas_attn_bwd
+
+    T, D = q.shape[1], q.shape[3]
     splash = _splash_kernel(
-        q.shape[1], q.shape[2], causal=causal, window=window, block_sizes=block_sizes,
+        T, q.shape[2], causal=causal, window=window, block_sizes=block_sizes,
         interpret=interpret)
     seg32 = seg.astype(jnp.int32)
     empty = seam_empty_tiles(seg32, block_sizes.block_q)
+    own_bwd = pallas_attn_bwd.fits(
+        T, D, q.shape[2] // k.shape[2], block_sizes.block_q_dkv, block_sizes.block_kv_dkv,
+        block_sizes.block_kv_dkv_compute, q.dtype.itemsize)
+
+    # Where several rows are walked, a row's backward still declares the HBM the
+    # library's declared for its dq partials — T / bkv arrays of (H, T, D), never
+    # written, one element read — because XLA's TPU pipeline packs a program only
+    # when its first schedule overflows the chip, and without every row's
+    # partials smallthinker-21b-a3b's does not: 10.85 GB at the program's peak
+    # for a v5e against the parent's 8.83, and 8.83 with them declared (PERF.md
+    # section 6, PR 40: from 14 of its 16 blocks on; 12: 10.86; 17: 8.87). One row
+    # has no second row's buffers to be kept beside (glm-4.7-flash) and declares none.
+    ballast = T // block_sizes.block_kv_dkv if q.shape[0] > 1 else 0
+    mask = (causal, window, ballast)
     rows = [
-        # our layout (T, H, D) -> kernel layout (H, T, D) and back, row by row:
-        # each a pass XLA fuses, none over a stacked batch
-        _skip_seams(splash, empty[b])(
-            *(x.transpose(1, 0, 2) for x in (q[b] * scale, k[b], v[b])),
-            segment_ids=SegmentIds(q=seg32[b], kv=seg32[b]),
-        ).transpose(1, 0, 2)
+        (_seam_row if own_bwd else _seam_row_forward)(
+            mask, splash, q[b] * scale, k[b], v[b], seg32[b], empty[b])
         for b in range(q.shape[0])
     ]
     return jnp.stack(rows)
+
+
+def _library_row(splash, q, k, v, seg, empty, save_residuals):
+    """One row, q (T, H, D), through the library's kernels on the masks
+    ``empty`` leaves: our layout -> the kernels' (H, T, D) and back, each a
+    pass XLA fuses, none over a stacked batch. ``save_residuals``: the
+    logsumexp (H, T) beside the output."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import SegmentIds
+
+    kernel = _skip_seams(splash, empty)
+    kernel.kwargs["save_residuals"] = save_residuals
+    out = kernel(*(x.transpose(1, 0, 2) for x in (q, k, v)), segment_ids=SegmentIds(q=seg, kv=seg))
+    if save_residuals:
+        out, (logsumexp,) = out
+        return out.transpose(1, 0, 2), logsumexp
+    return out.transpose(1, 0, 2)
+
+
+def _seam_row_forward(mask, splash, q, k, v, seg, empty):
+    """A row whose backward, if taken, is the library's. ``mask``: (causal,
+    window, ballast), what :func:`_seam_row`'s backward needs that is no array."""
+    return _library_row(splash, q, k, v, seg, empty, False)
+
+
+_seam_row = jax.custom_vjp(_seam_row_forward, nondiff_argnums=(0,))
+
+
+def _seam_row_fwd(mask, splash, q, k, v, seg, empty):
+    out, logsumexp = _library_row(splash, q, k, v, seg, empty, True)
+    return out, (splash, q, k, v, seg, empty, out, logsumexp)
+
+
+def _seam_row_bwd(mask, res, do):
+    """dq, dk, dv of one row from the forward's ``(out, logsumexp)``: ``di``
+    as the library computes it, then one kernel over the steps
+    :func:`tpu_rl.ops.pallas_attn_bwd.band_steps` lists — the grid *is* the
+    band — under the scope ``attn_bwd_pallas``."""
+    from tpu_rl.ops import pallas_attn_bwd
+
+    causal, window, ballast = mask
+    splash, q, k, v, seg, empty, out, logsumexp = res
+    info, bs = splash.dkv_mask_info, splash.kwargs["block_sizes"]
+    assert info.partial_mask_blocks is None and info.block_mask.shape[0] == 1
+    T = q.shape[0]
+    band = band_tiles(T, bs.block_q_dkv, window) if causal else np.ones(empty.shape, bool)
+    with jax.named_scope("attn_bwd_pallas"):
+        # out lies as the forward's kernel wrote it, (H, T, D): do is brought there once,
+        # in its own dtype, for di and for the kernel (left as (T, H, D), XLA re-lays both
+        # operands of this sum in float32)
+        out, do = out.transpose(1, 0, 2), do.transpose(1, 0, 2)
+        di = jnp.einsum("hsd,hsd->hs", out.astype(jnp.float32), do.astype(jnp.float32))
+        dq, dk, dv = pallas_attn_bwd.attention_bwd(
+            q, k, v, seg, logsumexp, do, di, pallas_attn_bwd.band_steps(band, empty),
+            q_sequence=jnp.arange(T) if info.q_sequence is None else info.q_sequence,
+            mask_function=splash.kwargs["mask_function"],
+            mask_value=splash.kwargs["mask_value"], block_q=bs.block_q_dkv,
+            block_kv=bs.block_kv_dkv, block_kv_compute=bs.block_kv_dkv_compute,
+            ballast=ballast, interpret=splash.kwargs["interpret"])
+    return None, dq, dk, dv, None, None
+
+
+_seam_row.defvjp(_seam_row_fwd, _seam_row_bwd)
 
 
 def make_sp_mesh(n_data: int, n_seq: int, devices=None) -> Mesh:

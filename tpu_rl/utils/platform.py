@@ -36,13 +36,15 @@ _CACHE_DIR = os.path.join(
 # ``moe_experts`` likewise, and ``moe_row_add_pallas`` where a trip's
 # rows are added into the tokens by ops/pallas_moe.py's kernel,
 # models/glm4_moe_lite.py: ``mla`` around the latent-attention mixer,
-# ops/pallas_act.py, parallel/sequence.py), so one lowered
+# ops/pallas_act.py, parallel/sequence.py: ``attn_bwd_pallas`` inside
+# ``attn_flash_pallas`` where the backward is ops/pallas_attn_bwd.py's walk
+# over the band's tiles), so one lowered
 # module answers both "what did the gate choose" and "did Mosaic get it".
 _MOSAIC_TARGET = "tpu_custom_call"
 _PATH_SCOPES = re.compile(
-    r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_full|attn_window|attn_global"
-    r"|attn_rope|mla|ssd_scan|ssd_pallas|gdn_scan|gdn_pallas|moe_experts|moe_gmm_pallas"
-    r"|moe_row_add_pallas)\b"
+    r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_bwd_pallas|attn_full|attn_window"
+    r"|attn_global|attn_rope|mla|ssd_scan|ssd_pallas|gdn_scan|gdn_pallas|moe_experts"
+    r"|moe_gmm_pallas|moe_row_add_pallas)\b"
 )
 
 
