@@ -4,8 +4,8 @@ Three phases, exits nonzero on any failure — the ``make heal-smoke`` CI
 gate:
 
 1. **In-process guard math** — with clean data, guard-on training is
-   bit-identical to guard-off (the ``lax.cond`` true branch runs exactly
-   the pre-guard update); with a NaN in the batch, guard-on leaves params
+   bit-identical to guard-off (every leaf's select takes the applied
+   value, which the pre-guard update computed); with a NaN in the batch, guard-on leaves params
    bitwise untouched and counts every skipped sub-update.
 2. **NaN chaos run** — the smallest real cluster under a data-fault plan
    that poisons one worker's rollout values (``nan:``/``spike:`` on obs/

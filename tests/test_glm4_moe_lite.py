@@ -615,13 +615,16 @@ TRANSFORMER = dict(algo="PPO", model="transformer", hidden_size=64, n_heads=4, n
 # ``smallthinker`` and ``qwen3_next`` recorded anew — their ``diag`` gained the
 # counter ``attn-bwd-steps-*``; with the counter left out of
 # ``obs/learn.ATTENTION_COUNTERS`` both lowered to the digests they had
-# (af779e4d…, c6f9c2a0…).
+# (af779e4d…, c6f9c2a0…). PR 41: all five recorded anew — the update's tail
+# changed in every program (the guard a select and no ``cond``, the module
+# norms from the raw gradients, the diagnostics' sums under ``opt_update``);
+# no model file was touched.
 BEFORE = {
-    "transformer": "d449a158cdd0db48fccbcb732ec4709a96007cc9d621caa371e8c38a3ddbd59d",
-    "granite_hybrid": "eee00c53d8985a43ec43a3a4c5f2b2e56c52534ec9055996c07ee232f796ff42",
-    "nemotron_h": "901598e2fff52894f473edc00aa7092f0c9b70f6945ba94964cc5305f196ad83",
-    "smallthinker": "888419d5ee711a69cf5ab4040cbb4b0695ee6df9a4cb941dd1d7dd2f3d5f57ad",
-    "qwen3_next": "18cfc983f487bc49095d47eee04d7aec343d9dd8b15cf94256a2646985293951",
+    "transformer": "8b9c8c0764ab242a3da73822e1ea003dae077495da61006398cca64864b2a7d9",
+    "granite_hybrid": "17b496ea1eb174484e74ab740489fb01f84614423ea1b2bb87c5bfa2d17b5e29",
+    "nemotron_h": "4e1709b9bd0a4ebc69dc7e12d43e7acf242dd683ff00f104163cc48fa097514a",
+    "smallthinker": "f267e0c3ebc64d3cdc8240c7bd886a68b27fe6e183e7360f33b4acb96b571f8a",
+    "qwen3_next": "409273e605b448ff61c5f707fdc53a715cbabc46767dd103aad7ace85f409026",
 }
 
 

@@ -49,8 +49,9 @@ def _param_trees(state):
 # ------------------------------------------------------------- in-jit guards
 @pytest.mark.parametrize("algo", ALL_ALGOS)
 def test_guard_on_clean_is_bit_identical(algo):
-    """With finite data the guard's lax.cond true branch is literally the
-    pre-guard update: every state leaf must match guard-off bitwise."""
+    """With finite data every leaf's select takes the applied value, which
+    is literally the pre-guard update: every state leaf must match guard-off
+    bitwise."""
     cfg_on = _algo_cfg(algo, update_guard=True)
     cfg_off = _algo_cfg(algo, update_guard=False)
     fam, s_on, step_on = get_algo(algo).build(cfg_on, jax.random.PRNGKey(0))
@@ -77,6 +78,53 @@ def test_guard_contains_nonfinite_update(algo):
     assert float(m["nonfinite-updates"]) == float(cfg.K_epoch)
     # step still advances: the dispatch happened, the update was skipped
     assert int(s1.step) == int(state.step) + 1
+
+
+@pytest.mark.parametrize("algo", ALL_ALGOS)
+def test_guard_selects_and_never_branches(algo):
+    """The guard is a select inside the optimizer's pass: no ``cond`` at any
+    depth of the jitted step's jaxpr (a conditional is a fusion edge: behind
+    one the diagnostics' norms and a copy of every donated parameter are
+    passes over the weights of their own)."""
+    cfg = _algo_cfg(algo, update_guard=True)
+    fam, state, train_step = get_algo(algo).build(cfg, jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(jax.jit(train_step))(
+        state, make_batch(cfg, fam), jax.random.PRNGKey(1)
+    )
+
+    def primitives(jx):
+        for eqn in jx.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from primitives(sub)
+
+    names = set(primitives(jaxpr.jaxpr))
+    assert "select_n" in names
+    assert "cond" not in names
+
+
+@pytest.mark.parametrize("algo", ALL_ALGOS)
+def test_guard_select_does_not_leak_the_unselected_side(algo):
+    """Every observation NaN: the applied side holds NaN in every parameter
+    leaf (shown with the guard off), and the guarded step still leaves every
+    state leaf bitwise untouched, with ``update-norm`` exactly 0."""
+    cfg = _algo_cfg(algo, update_guard=True, learn_diag=True)
+    fam, state, train_step = get_algo(algo).build(cfg, jax.random.PRNGKey(0))
+    batch = make_batch(cfg, fam)
+    bad = batch.replace(obs=jnp.full_like(batch.obs, jnp.nan))
+    _, _, unguarded = get_algo(algo).build(
+        _algo_cfg(algo, update_guard=False), jax.random.PRNGKey(0)
+    )
+    s_off, _ = jax.jit(unguarded)(state, bad, jax.random.PRNGKey(1))
+    applied = s_off.params if hasattr(s_off, "params") else (
+        s_off.actor_params, s_off.critic_params
+    )
+    for leaf in jax.tree_util.tree_leaves(applied):
+        assert np.isnan(np.asarray(leaf)).any(), algo
+    s1, m = jax.jit(train_step)(state, bad, jax.random.PRNGKey(1))
+    _assert_trees_identical(_param_trees(s1), _param_trees(state), algo)
+    assert float(m["nonfinite-updates"]) == float(cfg.K_epoch)
+    assert float(m["diag"]["scalars"]["update-norm"]) == 0.0
 
 
 def test_guard_skip_count_rides_chained_dispatch():

@@ -21,11 +21,9 @@ from tpu_rl.heal.guards import guarded, update_ok
 from tpu_rl.models.families import ModelFamily
 from tpu_rl.obs.learn import (
     attention_scalars,
-    module_grad_norms,
     route_scalars,
     rows_mean,
-    tree_delta_norm,
-    tree_norm,
+    update_scalars,
 )
 from tpu_rl.ops.losses import clip_subtree_by_global_norm, smooth_l1
 from tpu_rl.ops.returns import vtrace
@@ -120,44 +118,43 @@ def make_train_step(cfg: Config, family: ModelFamily):
     def train_step(state: TrainState, batch: Batch, key: jax.Array):
         params0 = state.params
         metrics = {}
-        grads = None
+        raw = scale = None
         nf = 0.0
         for _ in range(cfg.K_epoch):
-            (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            (_, metrics), raw = jax.value_and_grad(loss_fn, has_aux=True)(
                 state.params, batch
             )
-            grads, gnorm = clip_subtree_by_global_norm(grads, cfg.max_grad_norm)
-            if guard:
-                ok = update_ok(metrics["loss"], gnorm)
+            with jax.named_scope("opt_update"):  # as algos/ppo.py's
+                grads, gnorm, scale = clip_subtree_by_global_norm(
+                    raw, cfg.max_grad_norm
+                )
+                if guard:
+                    ok = update_ok(metrics["loss"], gnorm)
 
-                def _apply(grads=grads, state=state):
+                    def _apply(grads=grads, state=state):
+                        updates, opt_state = opt.update(
+                            grads, state.opt_state, state.params
+                        )
+                        return optax.apply_updates(state.params, updates), opt_state
+
+                    params, opt_state = guarded(
+                        ok, _apply, (state.params, state.opt_state)
+                    )
+                    nf = nf + (1.0 - ok.astype(jnp.float32))
+                else:
                     updates, opt_state = opt.update(
                         grads, state.opt_state, state.params
                     )
-                    return optax.apply_updates(state.params, updates), opt_state
-
-                params, opt_state = guarded(
-                    ok, _apply, (state.params, state.opt_state)
-                )
-                nf = nf + (1.0 - ok.astype(jnp.float32))
-            else:
-                updates, opt_state = opt.update(grads, state.opt_state, state.params)
-                params = optax.apply_updates(state.params, updates)
+                    params = optax.apply_updates(state.params, updates)
             state = state.replace(params=params, opt_state=opt_state)
             metrics["grad-norm"] = gnorm
         if guard:
             metrics["nonfinite-updates"] = nf
         if cfg.learn_diag:
-            metrics["diag"]["scalars"].update(
-                {
-                    f"grad-norm-{k}": v
-                    for k, v in module_grad_norms(grads).items()
-                }
-            )
-            metrics["diag"]["scalars"]["update-norm"] = tree_delta_norm(
-                state.params, params0
-            )
-            metrics["diag"]["scalars"]["param-norm"] = tree_norm(state.params)
+            with jax.named_scope("opt_update"):
+                metrics["diag"]["scalars"].update(
+                    update_scalars(raw, scale, state.params, params0)
+                )
         return state.replace(step=state.step + 1), metrics
 
     return train_step

@@ -101,27 +101,30 @@ def make_train_step(cfg: Config, family: ModelFamily):
                 ent_neg = jnp.sum((probs * logp)[:, :-1], axis=-1)
             return loss_policy, ent_neg
 
-        (loss_policy, ent_neg), g_actor = jax.value_and_grad(
+        (loss_policy, ent_neg), raw_actor = jax.value_and_grad(
             actor_loss, has_aux=True
         )(state.actor_params)
-        g_actor, gn_actor = clip_subtree_by_global_norm(g_actor, cfg.max_grad_norm)
-        if guard:
-            ok_a = update_ok(loss_policy, gn_actor)
+        with jax.named_scope("opt_update"):  # as algos/ppo.py's
+            g_actor, gn_actor, sc_actor = clip_subtree_by_global_norm(
+                raw_actor, cfg.max_grad_norm
+            )
+            if guard:
+                ok_a = update_ok(loss_policy, gn_actor)
 
-            def _apply_actor():
+                def _apply_actor():
+                    up, actor_opt = opt_actor.update(
+                        g_actor, state.actor_opt, state.actor_params
+                    )
+                    return optax.apply_updates(state.actor_params, up), actor_opt
+
+                actor_params, actor_opt = guarded(
+                    ok_a, _apply_actor, (state.actor_params, state.actor_opt)
+                )
+            else:
                 up, actor_opt = opt_actor.update(
                     g_actor, state.actor_opt, state.actor_params
                 )
-                return optax.apply_updates(state.actor_params, up), actor_opt
-
-            actor_params, actor_opt = guarded(
-                ok_a, _apply_actor, (state.actor_params, state.actor_opt)
-            )
-        else:
-            up, actor_opt = opt_actor.update(
-                g_actor, state.actor_opt, state.actor_params
-            )
-            actor_params = optax.apply_updates(state.actor_params, up)
+                actor_params = optax.apply_updates(state.actor_params, up)
 
         # ---- 2) temperature update (sac/learning.py:64-74). Documented
         # divergence: the reference computes +alpha*(logpi + target), whose
@@ -201,38 +204,41 @@ def make_train_step(cfg: Config, family: ModelFamily):
                 q2[:, :-1], td_target
             )
 
-        loss_value, g_critic = jax.value_and_grad(critic_loss)(state.critic_params)
-        g_critic, gn_critic = clip_subtree_by_global_norm(g_critic, cfg.max_grad_norm)
-        if guard:
-            ok_c = update_ok(loss_value, gn_critic)
+        loss_value, raw_critic = jax.value_and_grad(critic_loss)(state.critic_params)
+        with jax.named_scope("opt_update"):
+            g_critic, gn_critic, sc_critic = clip_subtree_by_global_norm(
+                raw_critic, cfg.max_grad_norm
+            )
+            if guard:
+                ok_c = update_ok(loss_value, gn_critic)
 
-            def _apply_critic():
+                def _apply_critic():
+                    up, critic_opt = opt_critic.update(
+                        g_critic, state.critic_opt, state.critic_params
+                    )
+                    cp = optax.apply_updates(state.critic_params, up)
+                    # Polyak tracks only APPLIED critic steps: a skipped update
+                    # must leave the target frozen too, or the twin targets
+                    # drift toward a never-taken critic.
+                    return cp, critic_opt, polyak_update(
+                        cp, state.target_critic_params, cfg.tau
+                    )
+
+                critic_params, critic_opt, target_critic_params = guarded(
+                    ok_c,
+                    _apply_critic,
+                    (state.critic_params, state.critic_opt, state.target_critic_params),
+                )
+            else:
                 up, critic_opt = opt_critic.update(
                     g_critic, state.critic_opt, state.critic_params
                 )
-                cp = optax.apply_updates(state.critic_params, up)
-                # Polyak tracks only APPLIED critic steps: a skipped update
-                # must leave the target frozen too, or the twin targets
-                # drift toward a never-taken critic.
-                return cp, critic_opt, polyak_update(
-                    cp, state.target_critic_params, cfg.tau
+                critic_params = optax.apply_updates(state.critic_params, up)
+
+                # ---- 4) Polyak target update (a real one — see module docstring)
+                target_critic_params = polyak_update(
+                    critic_params, state.target_critic_params, cfg.tau
                 )
-
-            critic_params, critic_opt, target_critic_params = guarded(
-                ok_c,
-                _apply_critic,
-                (state.critic_params, state.critic_opt, state.target_critic_params),
-            )
-        else:
-            up, critic_opt = opt_critic.update(
-                g_critic, state.critic_opt, state.critic_params
-            )
-            critic_params = optax.apply_updates(state.critic_params, up)
-
-            # ---- 4) Polyak target update (a real one — see module docstring)
-            target_critic_params = polyak_update(
-                critic_params, state.target_critic_params, cfg.tau
-            )
 
         metrics = {
             "loss": cfg.policy_loss_coef * loss_policy
@@ -272,12 +278,18 @@ def make_train_step(cfg: Config, family: ModelFamily):
             lr = sg(lr)
             w = jnp.exp(lr)
             # optimization_barrier: the diag's extra reductions over
-            # td_target / the critic grads must not refuse into the update's
-            # own kernels (measured: without the barrier XLA reassociates
-            # the critic update by ~1 ulp, breaking the bitwise contract).
-            ob = jax.lax.optimization_barrier
-            tq_rows = ob(td_target)
-            g_diag = ob({"actor": g_actor, "critic": g_critic})
+            # td_target must not refuse into the update's own kernels
+            # (measured: without the barrier XLA reassociates the critic
+            # update by ~1 ulp, breaking the bitwise contract). The module
+            # norms need none: they read the raw gradients with each clip's
+            # factor, so the clipped ones keep one reader, their optimizer
+            # (obs/learn.py).
+            tq_rows = jax.lax.optimization_barrier(td_target)
+            with jax.named_scope("opt_update"):
+                g_norms = module_grad_norms(
+                    {"actor": raw_actor, "critic": raw_critic},
+                    {"actor": sc_actor, "critic": sc_critic},
+                )
             metrics["diag"] = {
                 "rows": {
                     "ent": rows_mean(sg(ent_rows)),
@@ -289,10 +301,7 @@ def make_train_step(cfg: Config, family: ModelFamily):
                 },
                 "scalars": {
                     "alpha": jnp.exp(log_alpha),
-                    **{
-                        f"grad-norm-{k}": v
-                        for k, v in module_grad_norms(g_diag).items()
-                    },
+                    **{f"grad-norm-{k}": v for k, v in g_norms.items()},
                 },
             }
         if guard:
@@ -327,10 +336,11 @@ def make_train_step(cfg: Config, family: ModelFamily):
             params1 = (
                 state.actor_params, state.critic_params, state.log_alpha,
             )
-            metrics["diag"]["scalars"]["update-norm"] = tree_delta_norm(
-                params1, params0
-            )
-            metrics["diag"]["scalars"]["param-norm"] = tree_norm(params1)
+            with jax.named_scope("opt_update"):
+                metrics["diag"]["scalars"]["update-norm"] = tree_delta_norm(
+                    params1, params0
+                )
+                metrics["diag"]["scalars"]["param-norm"] = tree_norm(params1)
         return state.replace(step=state.step + 1), metrics
 
     return train_step

@@ -35,11 +35,9 @@ from tpu_rl.heal.guards import guarded, update_ok
 from tpu_rl.models.families import ModelFamily
 from tpu_rl.obs.learn import (
     attention_scalars,
-    module_grad_norms,
     route_scalars,
     rows_mean,
-    tree_delta_norm,
-    tree_norm,
+    update_scalars,
 )
 from tpu_rl.ops.distributions import categorical_kl
 from tpu_rl.ops.losses import clip_subtree_by_global_norm, smooth_l1
@@ -176,60 +174,60 @@ def make_train_step(cfg: Config, family: ModelFamily):
     def train_step(state: TrainState, batch: Batch, key: jax.Array):
         params0 = state.params
         metrics = {}
-        grads = None
+        raw = scale = None
         nf = 0.0
         for e in range(cfg.K_epoch):
             ekey = jax.random.fold_in(key, e)
-            (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            (_, metrics), raw = jax.value_and_grad(loss_fn, has_aux=True)(
                 state.params, batch, ekey
             )
-            grads, gnorm = clip_subtree_by_global_norm(
-                grads, cfg.max_grad_norm, subtree="actor"
-            )
-            if guard:
-                ok = update_ok(metrics["loss"], gnorm)
+            with jax.named_scope("opt_update"):  # as algos/ppo.py's
+                grads, gnorm, scale = clip_subtree_by_global_norm(
+                    raw, cfg.max_grad_norm, subtree="actor"
+                )
+                if guard:
+                    ok = update_ok(metrics["loss"], gnorm)
 
-                def _apply(grads=grads, state=state):
+                    def _apply(grads=grads, state=state):
+                        updates, opt_state = opt.update(
+                            grads, state.opt_state, state.params
+                        )
+                        params = optax.apply_updates(state.params, updates)
+                        # The eta floor projection belongs to the applied
+                        # side: a skipped update must leave params bitwise
+                        # untouched.
+                        params["log_eta"] = jnp.maximum(
+                            params["log_eta"], jnp.log(1e-6)
+                        )
+                        return params, opt_state
+
+                    params, opt_state = guarded(
+                        ok, _apply, (state.params, state.opt_state)
+                    )
+                    nf = nf + (1.0 - ok.astype(jnp.float32))
+                else:
                     updates, opt_state = opt.update(
                         grads, state.opt_state, state.params
                     )
                     params = optax.apply_updates(state.params, updates)
-                    # The eta floor projection belongs to the apply branch:
-                    # a skipped update must leave params bitwise untouched.
+                    # Projected floor on the temperature: eta -> 0 makes the
+                    # psi weights one-hot and the advantage ratios arbitrarily
+                    # large. Projection after the step (not clipping inside
+                    # the loss, which would zero the dual's gradient and
+                    # freeze it below the floor).
                     params["log_eta"] = jnp.maximum(
                         params["log_eta"], jnp.log(1e-6)
                     )
-                    return params, opt_state
-
-                params, opt_state = guarded(
-                    ok, _apply, (state.params, state.opt_state)
-                )
-                nf = nf + (1.0 - ok.astype(jnp.float32))
-            else:
-                updates, opt_state = opt.update(grads, state.opt_state, state.params)
-                params = optax.apply_updates(state.params, updates)
-                # Projected floor on the temperature: eta -> 0 makes the psi
-                # weights one-hot and the advantage ratios arbitrarily large.
-                # Projection after the step (not clipping inside the loss, which
-                # would zero the dual's gradient and freeze it below the floor).
-                params["log_eta"] = jnp.maximum(
-                    params["log_eta"], jnp.log(1e-6)
-                )
             state = state.replace(params=params, opt_state=opt_state)
             metrics["grad-norm"] = gnorm
         if guard:
             metrics["nonfinite-updates"] = nf
         if cfg.learn_diag:
-            metrics["diag"]["scalars"].update(
-                {
-                    f"grad-norm-{k}": v
-                    for k, v in module_grad_norms(grads).items()
-                }
-            )
-            metrics["diag"]["scalars"]["update-norm"] = tree_delta_norm(
-                state.params, params0
-            )
-            metrics["diag"]["scalars"]["param-norm"] = tree_norm(state.params)
+            with jax.named_scope("opt_update"):
+                metrics["diag"]["scalars"].update(
+                    # the clip scaled the actor subtree alone
+                    update_scalars(raw, {"actor": scale}, state.params, params0)
+                )
         return state.replace(step=state.step + 1), metrics
 
     return train_step

@@ -712,11 +712,12 @@ class Config:
     # weights+ver immediately instead of waiting out rebroadcast_idle_s.
     membership_lease_s: float = 15.0
     # ---- self-healing plane (tpu_rl.heal) ----
-    # In-jit non-finite update guards: every algo's train_step wraps its
-    # optimizer apply in a lax.cond over isfinite(loss) & isfinite(grad
-    # global-norm) — a bad update leaves params/opt state untouched and
-    # counts into the per-step "nonfinite-updates" metric. Guard off =
-    # literally the unguarded code (bit-identity pinned in tests).
+    # In-jit non-finite update guards: every algo's train_step selects,
+    # leaf by leaf, between its optimizer apply and the incoming state on
+    # isfinite(loss) & isfinite(grad global-norm) — a bad update leaves
+    # params/opt state untouched and counts into the per-step
+    # "nonfinite-updates" metric. Guard off = literally the unguarded code
+    # (bit-identity pinned in tests).
     update_guard: bool = True
     # Host-side divergence watchdog at the learner: EWMA/z-score over loss,
     # grad-norm and fleet mean return at the loss-log cadence, plus a

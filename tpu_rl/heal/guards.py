@@ -2,17 +2,21 @@
 
 Both helpers trace into the algo's jitted update, so the rules of the
 hot-path checker apply: allocation-free by construction (a scalar ``&``
-and a two-branch ``lax.cond`` whose operands are the already-materialized
-update closures), no Python-level formatting, no containers.
+and one select a leaf, which XLA folds into the optimizer's own pass over
+that leaf), no Python-level formatting, no containers. No conditional:
+a branch is a fusion edge, and XLA would write out the clipped gradients
+before it, run the diagnostics' norms as passes of their own after it and
+copy every donated parameter that is read past it.
 
 The guard contract every algo implements with these:
 
 - ``cfg.update_guard`` off -> the update code is literally the pre-guard
   code (bit-identity is pinned per-algo in ``tests/test_heal.py``).
-- guard on, clean step -> ``lax.cond`` takes the apply branch, which
-  computes exactly the ungated ops -> still bit-identical.
-- guard on, non-finite loss or global grad-norm -> the fallback branch
-  returns the *incoming* params/opt state untouched and the step's
+- guard on, clean step -> every leaf selects the applied value, which
+  the ungated ops computed -> still bit-identical.
+- guard on, non-finite loss or global grad-norm -> every leaf selects the
+  *incoming* params/opt state, untouched whatever the applied side holds
+  (a select does not propagate the unselected side's NaN), and the step's
   ``nonfinite-updates`` metric counts one skipped update.
 """
 
@@ -28,13 +32,8 @@ def update_ok(loss, gnorm):
 
 
 def guarded(ok, apply_fn, fallback):
-    """Apply ``apply_fn()`` when ``ok`` else return ``fallback`` untouched.
-
-    ``apply_fn`` is an argless closure over the loop-local grads/state so
-    the taken branch computes exactly the ops the unguarded code would.
-    """
-
-    def _skip():
-        return fallback
-
-    return jax.lax.cond(ok, apply_fn, _skip)
+    """``apply_fn()`` where ``ok``, else ``fallback`` untouched: leaf by
+    leaf, one select. ``apply_fn`` is an argless closure over the loop-local
+    grads/state that returns a tree of ``fallback``'s structure; a skipped
+    update's arithmetic is computed and dropped."""
+    return jax.tree.map(lambda new, old: jnp.where(ok, new, old), apply_fn(), fallback)

@@ -76,12 +76,20 @@ def rows_mean(x: jax.Array) -> jax.Array:
     return jnp.mean(x.reshape(x.shape[0], -1), axis=1)
 
 
-def module_grad_norms(grads: Any) -> dict[str, jax.Array]:
+def module_grad_norms(grads: Any, scale: Any = 1.0) -> dict[str, jax.Array]:
     """Global grad norm split by module group — ``torso`` (any path part
     containing "body": the shared MLP/conv torsos, SAC's obs/act bodies),
     ``cell`` (the recurrent core), ``heads`` (everything else: output heads,
     dual variables like log_eta/log_alpha). Static path walk, so this is
-    free to call under jit."""
+    free to call under jit.
+
+    ``grads`` are the gradients *before* the clip and ``scale`` the clip's
+    factor (``ops/losses.clip_subtree_by_global_norm``): one for the whole
+    tree, or a dict from top-level key to that subtree's factor (a key it
+    lacks was not clipped). The norms are the clipped gradients' to
+    rounding, and ``g * scale`` keeps one reader: with a second one XLA
+    contracts the optimizer's pass otherwise, and ``learn_diag`` would move
+    a bit of the state."""
     sq = {"torso": 0.0, "cell": 0.0, "heads": 0.0}
     for path, leaf in jax.tree_util.tree_leaves_with_path(grads):
         group = "heads"
@@ -95,7 +103,8 @@ def module_grad_norms(grads: Any) -> dict[str, jax.Array]:
             if key == "cell":
                 group = "cell"
                 break
-        sq[group] = sq[group] + jnp.sum(jnp.square(leaf.astype(jnp.float32)))
+        s = scale.get(getattr(path[0], "key", None), 1.0) if isinstance(scale, dict) else scale
+        sq[group] = sq[group] + jnp.sum(jnp.square(leaf.astype(jnp.float32))) * (s * s)
     return {k: jnp.sqrt(v) for k, v in sq.items()}
 
 
@@ -161,6 +170,19 @@ def tree_norm(tree: Any) -> jax.Array:
     import optax
 
     return optax.global_norm(tree)
+
+
+def update_scalars(grads: Any, scale: Any, new: Any, old: Any) -> dict[str, jax.Array]:
+    """One update's ``diag`` scalars from the raw gradients, the clip's
+    factor and the parameters after and before it. Call it under the
+    optimizer's named scope: nothing stands between these sums and the
+    optimizer's pass, so XLA folds them into it and the device trace books
+    them there."""
+    return {
+        **{f"grad-norm-{k}": v for k, v in module_grad_norms(grads, scale).items()},
+        "update-norm": tree_delta_norm(new, old),
+        "param-norm": tree_norm(new),
+    }
 
 
 def stale_bucket_index(stale: jax.Array) -> jax.Array:

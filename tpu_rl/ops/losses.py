@@ -24,6 +24,10 @@ def clip_subtree_by_global_norm(grads, max_norm: float, subtree: str | None = No
     V-MPO's Lagrange temperatures (``v_mpo/learning.py:111-114`` clips
     ``model.actor.parameters()`` while ``log_eta``/``log_alpha`` share the
     optimizer, ``learner.py:331-338``). ``subtree=None`` clips everything.
+
+    Returns ``(clipped, gnorm, scale)``: the clip's factor goes to
+    ``obs/learn.module_grad_norms`` with the *raw* gradients, so that the
+    clipped ones have one reader, the optimizer.
     """
     if subtree is None:
         tree = grads
@@ -34,7 +38,7 @@ def clip_subtree_by_global_norm(grads, max_norm: float, subtree: str | None = No
     scale = jnp.minimum(1.0, max_norm / (gnorm + 1e-6))
     clipped = jax.tree_util.tree_map(lambda g: g * scale, tree)
     if subtree is None:
-        return clipped, gnorm
+        return clipped, gnorm, scale
     out = dict(grads)
     out[subtree] = clipped
-    return out, gnorm
+    return out, gnorm, scale
