@@ -788,7 +788,7 @@ def kernel_checks(
         print(f"[kernels] {json.dumps(row)}", flush=True)
 
     # ---- the Pallas scan pair vs the jnp body of ssd_chunked, every gradient
-    from tpu_rl.models.granite_hybrid import ssd_chunked
+    from tpu_rl.models.mamba2 import ssd_chunked
     from tpu_rl.ops.pallas_ssd import head_block
 
     for B, T, H, P, G, N, Q in ssd_shapes:
@@ -909,7 +909,7 @@ def kernel_checks(
 
     # ---- qwen3_next's two mixers in bf16 vs the plain float32 reference
     from benchmarks.reference import qwen3_next as plain
-    from tpu_rl.models.qwen3_next import _conv_channels, build_mixer
+    from tpu_rl.models.qwen3_next import Qwen3NextActorCritic, build_mixer
 
     arch = dict(qwen3_next_widths)
     for kind, B, T in qwen3_next_shapes:
@@ -920,11 +920,10 @@ def kernel_checks(
         seg = jnp.asarray(np.cumsum(firsts, axis=1).astype(np.int32))
         first = jnp.asarray(firsts)
         carry = ()
-        if kind == "linear":
-            state = (arch["linear_num_value_heads"], arch["linear_key_head_dim"],
-                     arch["linear_value_head_dim"])
-            carry = (jnp.zeros((B, *state)),
-                     jnp.zeros((B, arch["linear_conv_kernel_dim"] - 1, _conv_channels(arch))))
+        if kind == "linear":  # the state and the tail the family says such a layer carries
+            _, shapes = Qwen3NextActorCritic.acting_state(
+                {**arch, "num_hidden_layers": 1, "full_attention_interval": 2}, T)[0]
+            carry = tuple(jnp.zeros((B, *shape)) for shape in shapes)
 
         params = jax.jit(lambda key: mixer.init(key, u, seg, *carry)["params"])(
             jax.random.key(SEED))

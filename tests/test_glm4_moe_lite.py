@@ -31,8 +31,9 @@ from tpu_rl.config import GLM4_MOE_LITE_ARCH_KEYS, Config
 from tpu_rl.data.layout import BatchLayout
 from tpu_rl.models import cells
 from tpu_rl.models.families import ModelFamily, build_family
-from tpu_rl.models.glm4_moe_lite import Glm4MoeLiteLayer, MLAttention, carry_widths
-from tpu_rl.models.granite_hybrid import rope
+from tpu_rl.models.backbone import state_widths
+from tpu_rl.models.glm4_moe_lite import Glm4MoeLiteActorCritic, Glm4MoeLiteLayer, MLAttention
+from tpu_rl.models.layers import rope
 from tpu_rl.parallel.sequence import full_attention
 from tpu_rl.types import Batch
 
@@ -437,7 +438,8 @@ def test_acting_step_by_step_equals_the_unroll(family, actor):
     batch = make_batch(9, firsts=(0, 11))
     logits = jax.jit(lambda p, b: policy_outputs_routed(
         family, {"actor": p}, Batch.from_mapping(b))[3])(actor, batch)
-    assert family.carry_widths == carry_widths(ARCH, T) == (0, 3 * T * (16 + 8) + 1)
+    widths = state_widths(Glm4MoeLiteActorCritic.acting_state(ARCH, T))
+    assert family.carry_widths == widths == (0, 3 * T * (16 + 8) + 1)
     h = jnp.zeros((B, family.carry_widths[0]))
     c = jnp.zeros((B, family.carry_widths[1]))
     act = jax.jit(family.act)
@@ -618,13 +620,16 @@ TRANSFORMER = dict(algo="PPO", model="transformer", hidden_size=64, n_heads=4, n
 # (af779e4d…, c6f9c2a0…). PR 41: all five recorded anew — the update's tail
 # changed in every program (the guard a select and no ``cond``, the module
 # norms from the raw gradients, the diagnostics' sums under ``opt_update``);
-# no model file was touched.
+# no model file was touched. PR 42: ``glm4_moe_lite``'s own joins them, taken on
+# the parent commit (4aa4e3c) before any model file moved; the five above it
+# were not edited.
 BEFORE = {
     "transformer": "8b9c8c0764ab242a3da73822e1ea003dae077495da61006398cca64864b2a7d9",
     "granite_hybrid": "17b496ea1eb174484e74ab740489fb01f84614423ea1b2bb87c5bfa2d17b5e29",
     "nemotron_h": "4e1709b9bd0a4ebc69dc7e12d43e7acf242dd683ff00f104163cc48fa097514a",
     "smallthinker": "f267e0c3ebc64d3cdc8240c7bd886a68b27fe6e183e7360f33b4acb96b571f8a",
     "qwen3_next": "409273e605b448ff61c5f707fdc53a715cbabc46767dd103aad7ace85f409026",
+    "glm4_moe_lite": "8adba8ef85184cacb1a698214cc811f36182bea9c6f1867eb6ac2884f75959dd",
 }
 
 

@@ -24,7 +24,8 @@ from tpu_rl.config import Config
 from tpu_rl.data.layout import BatchLayout
 from tpu_rl.models import cells
 from tpu_rl.models.families import ModelFamily, build_family
-from tpu_rl.models.nemotron_h import ExpertBlock, NemotronHActorCritic
+from tpu_rl.models.layers import ExpertBlock
+from tpu_rl.models.nemotron_h import NemotronHActorCritic
 from tpu_rl.types import Batch
 
 SHARE = dict(published_n_routed_experts=16, chips=4, rank=1)

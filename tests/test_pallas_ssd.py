@@ -10,8 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_rl.models import cells, granite_hybrid
-from tpu_rl.models.granite_hybrid import ssd_chunked
+from tpu_rl.models import cells, mamba2
+from tpu_rl.models.mamba2 import ssd_chunked
 from tpu_rl.ops import pallas_ssd
 
 CHUNK = 8
@@ -184,7 +184,7 @@ def test_the_gate(monkeypatch, mode, platform, data, vmem, shape, want):
     monkeypatch.setattr(cells, "_PALLAS_MODE", mode)
     monkeypatch.setattr(cells, "_program_devices", lambda: (platform, data))
     monkeypatch.setattr(pallas_ssd, "_vmem_limit", lambda: 3 * vmem * 2**20 // 4)
-    assert granite_hybrid._ssd_kernel_block(**shape) == want
+    assert mamba2._ssd_kernel_block(**shape) == want
     hb = want[0]
     if hb is not None and not want[1]:  # what the kernels then need is inside what the call asks for
         need = pallas_ssd._vmem_bytes(hb, shape["p"], shape["n"], shape["Q"],

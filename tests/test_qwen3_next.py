@@ -27,8 +27,7 @@ from tpu_rl.config import Config
 from tpu_rl.data.layout import BatchLayout
 from tpu_rl.models import cells, qwen3_next
 from tpu_rl.models.families import ModelFamily, build_family
-from tpu_rl.models.granite_hybrid import GQAttention, RMSNorm, rope
-from tpu_rl.models.nemotron_h import ExpertBlock
+from tpu_rl.models.layers import ExpertBlock, GQAttention, RMSNorm, rope
 from tpu_rl.models.qwen3_next import Qwen3NextLayer
 from tpu_rl.ops import gated_delta, moe
 from tpu_rl.parallel.sequence import full_attention

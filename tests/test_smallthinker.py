@@ -28,8 +28,8 @@ from tpu_rl.config import Config
 from tpu_rl.data.layout import BatchLayout
 from tpu_rl.models import cells
 from tpu_rl.models.families import ModelFamily, build_family
-from tpu_rl.models.granite_hybrid import rope
-from tpu_rl.models.smallthinker import SmallThinkerLayer, kept_pairs
+from tpu_rl.models.layers import kept_pairs, rope
+from tpu_rl.models.smallthinker import SmallThinkerLayer
 from tpu_rl.ops import moe
 from tpu_rl.parallel.sequence import full_attention
 from tpu_rl.types import Batch

@@ -37,7 +37,7 @@ def policy_outputs(family: ModelFamily, params, batch: Batch):
 
 def policy_outputs_routed(family: ModelFamily, params, batch: Batch):
     """``policy_outputs`` and, fifth, each expert layer's routing (the chosen
-    experts per step and the counters; ``models/nemotron_h.py``): an empty
+    experts per step and the counters; ``models/backbone.py``): an empty
     list for a family without expert layers."""
     carry0 = (batch.hx[:, 0], batch.cx[:, 0])
     routes = []

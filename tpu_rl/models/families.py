@@ -9,6 +9,7 @@ jits against plain ``(params, arrays)`` signatures.
 
 from __future__ import annotations
 
+import importlib
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Callable
@@ -17,7 +18,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tpu_rl.config import Config
+from tpu_rl.config import ARCH_CHECKS, Config
 from tpu_rl.ops import distributions as D
 from tpu_rl.models.policies import (
     ContinuousActorCritic,
@@ -200,14 +201,13 @@ def _act_transformer_window(
     return a[..., None].astype(jnp.float32), last, log_prob[..., None], h2, c2
 
 
-def _act_granite_hybrid(actor, params, obs, h, c, key):
-    """One recurrent step of the hybrid families (granite_hybrid,
-    nemotron_h, smallthinker, qwen3_next, glm4_moe_lite): ``h`` holds the
-    recurrent layers' (Mamba-2, linear attention) states and convolution
-    tails, ``c`` the attention layers' rings — keys and values, or for latent
-    attention one ring of the compressed key/value latent beside the shared
-    rotated key — and a step counter (``models/granite_hybrid.py``). The
-    worker zeroes both at episode starts, so no state crosses episodes."""
+def _act_backbone(actor, params, obs, h, c, key):
+    """One acting step of a catalog family (``models/backbone.py``): ``h``
+    holds the recurrent layers' (Mamba-2, linear attention) states and
+    convolution tails, ``c`` the attention layers' rings — keys and values, or
+    for latent attention one ring of the compressed key/value latent beside
+    the shared rotated key — and a step counter. The worker zeroes both at
+    episode starts, so no state crosses episodes."""
     logits, _value, (h2, c2) = actor.apply(params["actor"], obs, h, c, method="act")
     a = D.categorical_sample(key, logits)
     log_prob = D.categorical_log_prob(logits, a)
@@ -264,45 +264,18 @@ def build_family(cfg: Config, mesh=None) -> ModelFamily:
         )
         return fam
 
-    if cfg.model == "granite_hybrid":
-        from tpu_rl.models.granite_hybrid import GraniteHybridActorCritic, carry_widths
+    if cfg.model in ARCH_CHECKS:  # a catalog family: one file, models/<cfg.model>.py
+        from tpu_rl.models.backbone import state_widths
 
-        assert cfg.algo in ("PPO", "IMPALA", "V-MPO"), (
-            "granite_hybrid backbone supports the discrete on-policy algorithms"
-        )
+        core = importlib.import_module(f"tpu_rl.models.{cfg.model}").ActorCritic
         ctx = cfg.effective_act_ctx
-        actor = GraniteHybridActorCritic(
-            n_actions=n, arch=cfg.arch, act_ctx=ctx,
-            dtype=jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else None,
-        )
+        actor = core(n_actions=n, arch=cfg.arch, act_ctx=ctx, dtype=kw["dtype"])
         return ModelFamily(
             cfg.algo, False, False, actor, None, obs_dim, n, cfg.arch["hidden_size"],
-            act=partial(_act_granite_hybrid, actor),
-            act_carry_widths=carry_widths(cfg.arch, ctx),
+            act=partial(_act_backbone, actor),
+            act_carry_widths=state_widths(core.acting_state(cfg.arch, ctx)),
             store_carry=False,
-        )
-
-    if cfg.model in ("nemotron_h", "smallthinker", "qwen3_next", "glm4_moe_lite"):
-        if cfg.model == "nemotron_h":
-            from tpu_rl.models.nemotron_h import NemotronHActorCritic as core, carry_widths
-        elif cfg.model == "smallthinker":
-            from tpu_rl.models.smallthinker import SmallThinkerActorCritic as core, carry_widths
-        elif cfg.model == "qwen3_next":
-            from tpu_rl.models.qwen3_next import Qwen3NextActorCritic as core, carry_widths
-        else:
-            from tpu_rl.models.glm4_moe_lite import Glm4MoeLiteActorCritic as core, carry_widths
-
-        ctx = cfg.effective_act_ctx
-        actor = core(
-            n_actions=n, arch=cfg.arch, act_ctx=ctx,
-            dtype=jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else None,
-        )
-        return ModelFamily(
-            cfg.algo, False, False, actor, None, obs_dim, n, cfg.arch["hidden_size"],
-            act=partial(_act_granite_hybrid, actor),
-            act_carry_widths=carry_widths(cfg.arch, ctx),
-            store_carry=False,
-            route_unroll=partial(actor.apply, method="unroll_routed"),
+            route_unroll=partial(actor.apply, method="unroll_routed") if core.routed else None,
         )
 
     if cfg.algo in ("PPO", "IMPALA", "V-MPO"):

@@ -3,13 +3,11 @@ latent attention; the first ``first_k_dense_replace`` layers follow it with a
 dense SwiGLU MLP, the others with a sparse-expert block and its shared expert.
 
 Widths come from ``Config.arch``, the model's own ``config.json`` under its
-published key names (``config.GLM4_MOE_LITE_ARCH_KEYS``). The unroll / act
-loops, the acting carry's packing, the observation projection and the heads
-are ``models/nemotron_h.py``'s, the expert block its ``ExpertBlock`` at this
-family's combination of fields (``swiglu`` experts under the sigmoid router
-with its correction bias and scale, an ungated shared expert). As there, an
-observation projection replaces the token embedding and a policy and a value
-head replace the LM head.
+published key names (``config.GLM4_MOE_LITE_ARCH_KEYS``). The trunk (the
+embedding, the unroll and act loops, the acting carry, the heads) is
+``models/backbone.py``'s; the experts are ``models/layers.py``'s ``ExpertBlock``
+(``swiglu`` under the sigmoid router with its correction bias and scale, an
+ungated shared expert); latent attention is this file's.
 
     x = Dense(obs)
     per layer i:  x = x + MLA(N(x))
@@ -52,9 +50,8 @@ ring per layer and a step counter.
 
 ``unroll_routed`` returns one routing record per *expert* layer (the choices
 and ``ops/moe.route_stats``) and, in the records, what every layer's attention
-mask did under the span name ``global`` (``attn-pairs``, ``attn-tiles-run``,
-``attn-tiles-band``, ``attn-bwd-steps``), as ``models/smallthinker.py`` does; a
-dense layer's counts ride with the first expert layer's record.
+mask did under the span name ``global`` (``layers.attention_counts``); a dense
+layer's counts ride with the first expert layer's record (the trunk's rule).
 """
 
 from __future__ import annotations
@@ -65,20 +62,14 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tpu_rl.models.granite_hybrid import RMSNorm, rope
-from tpu_rl.models.nemotron_h import ExpertBlock, NemotronHActorCritic, expert_share
-from tpu_rl.models.smallthinker import kept_pairs
-from tpu_rl.obs.learn import ATTENTION_COUNTERS
-from tpu_rl.parallel.sequence import attention_tiles, flash_attention_tpu
+from tpu_rl.models.backbone import Backbone, ring
+from tpu_rl.models.layers import ExpertBlock, RMSNorm, attention_counts, expert_share, rope
+from tpu_rl.parallel.sequence import flash_attention_tpu
+
 
 def ring_width(arch: dict) -> int:
     """Numbers a step leaves in a layer's latent ring."""
     return arch["kv_lora_rank"] + arch["qk_rope_head_dim"]
-
-
-def carry_widths(arch: dict, ctx: int) -> tuple[int, int]:
-    """Widths of the flattened acting carry ``(h, c)``."""
-    return 0, arch["num_hidden_layers"] * ctx * ring_width(arch) + 1
 
 
 class MLAttention(nn.Module):
@@ -191,7 +182,6 @@ class Glm4MoeLiteLayer(nn.Module):
     arch: dict
     index: int
     dtype: Any = None
-    kind = "attention"  # to the unroll / act loops: a ring, no state
 
     def setup(self):
         a = self.arch
@@ -228,8 +218,7 @@ class Glm4MoeLiteLayer(nn.Module):
         did and (an expert layer) its routing."""
         with jax.named_scope("mla"):
             x = x + self.attention(self.input_norm(x), seg)
-        counts = (kept_pairs(seg, None), *attention_tiles(seg))
-        record = {c: {"global": n} for c, n in zip(ATTENTION_COUNTERS, counts)}
+        record = attention_counts(seg, None, "global")
         if self.dense:
             return self._mlp(x), record
         with jax.named_scope("moe"):
@@ -246,29 +235,13 @@ class Glm4MoeLiteLayer(nn.Module):
             return x + self.experts.step(self.post_norm(x)), ring
 
 
-class Glm4MoeLiteActorCritic(NemotronHActorCritic):
-    ring_parts = 1  # a latent ring is one array: [c_kv ; k^r] a step
+class Glm4MoeLiteActorCritic(Backbone):
+    Layer = Glm4MoeLiteLayer
 
-    def setup(self):
-        a = self.arch
-        self.embed = nn.Dense(a["hidden_size"], name="embed", dtype=self.dtype)
-        layer = nn.remat(Glm4MoeLiteLayer) if self.remat else Glm4MoeLiteLayer
-        self.layers = [
-            layer(a, i, self.dtype, name=f"layer{i}") for i in range(a["num_hidden_layers"])
-        ]
-        self.norm_f = RMSNorm(a["rms_norm_eps"], name="norm_f")
-        self.logits_head = nn.Dense(self.n_actions, name="logits")
-        self.value_head = nn.Dense(1, name="value")
-        self.h_width, self.c_width = carry_widths(a, self.act_ctx)
-        self.kv_shapes = [(self.act_ctx, ring_width(a))] * a["num_hidden_layers"]
+    @staticmethod
+    def acting_state(arch, ctx):
+        # a latent ring is one array: [c_kv ; k^r] a step
+        return [ring((ctx, ring_width(arch)))] * arch["num_hidden_layers"]
 
-    def unroll_routed(self, obs, carry0, firsts):
-        """The unroll, and one record per expert layer in layer order: its
-        routing and its attention counters, the first also carrying the
-        dense layers' attention counters."""
-        *out, records = self._unroll(obs, carry0, firsts)
-        routes = [r for r in records if "choice" in r]
-        for dense in (r for r in records if "choice" not in r):  # attention counters alone
-            for counter, kinds in dense.items():
-                routes[0][counter] = {"global": routes[0][counter]["global"] + kinds["global"]}
-        return tuple(out), routes
+
+ActorCritic = Glm4MoeLiteActorCritic

@@ -3,13 +3,11 @@ every gated full-attention layer, each followed by a sparse-expert block with a
 gated shared expert.
 
 Widths come from ``Config.arch``, the model's own ``config.json`` under its
-published key names (``config.QWEN3_NEXT_ARCH_KEYS``). The unroll / act loops,
-the acting carry and its packing are ``GraniteHybridActorCritic``'s (a linear
-layer carries what a Mamba-2 layer does: a state and a convolution tail),
-attention is its ``GQAttention`` with the three fields this family sets, the
-expert block, the observation projection and the heads
-``models/nemotron_h.py``'s. As there, an observation projection replaces the
-token embedding and a policy and a value head replace the LM head.
+published key names (``config.QWEN3_NEXT_ARCH_KEYS``). The trunk (the
+embedding, the unroll and act loops, the acting carry, the heads) is
+``models/backbone.py``'s; attention is ``models/layers.py``'s ``GQAttention``
+with the three fields this family sets, the experts its ``ExpertBlock``; the
+linear mixer is this file's.
 
     x = Dense(obs)
     per layer i:  x = x + Mixer_i(N(x));  x = x + Experts(N(x))
@@ -44,10 +42,9 @@ value size, float32) and the last ``linear_conv_kernel_dim - 1`` inputs of its
 convolution; ``c`` one K/V ring of ``act_ctx`` slots per full layer (keys
 stored normed and rotated at their own step) and a step counter.
 
-``unroll_routed`` also returns each layer's routing record and, beside a full
+``unroll_routed`` also returns each layer's routing record and, in a full
 layer's, what its attention mask did under the span name ``global``
-(``attn-pairs``, ``attn-tiles-run``, ``attn-tiles-band``, ``attn-bwd-steps``),
-as ``models/smallthinker.py`` does.
+(``layers.attention_counts``).
 """
 
 from __future__ import annotations
@@ -58,19 +55,18 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tpu_rl.models.granite_hybrid import (
+from tpu_rl.models.backbone import Backbone, recurrent, ring
+from tpu_rl.models.layers import (
+    ExpertBlock,
     GQAttention,
     RMSNorm,
-    _a_log_init,
-    _dt_bias_init,
     _rms_norm,
+    attention_counts,
+    expert_share,
     seam_conv,
 )
-from tpu_rl.models.nemotron_h import ExpertBlock, NemotronHActorCritic, expert_share
-from tpu_rl.models.smallthinker import kept_pairs
+from tpu_rl.models.mamba2 import _a_log_init, _dt_bias_init
 from tpu_rl.ops.gated_delta import gated_delta_chunked, gated_delta_step
-from tpu_rl.obs.learn import ATTENTION_COUNTERS
-from tpu_rl.parallel.sequence import attention_tiles
 
 # Steps a chunk of the training form takes: the family's convention, not a
 # key of its config.json.
@@ -84,23 +80,6 @@ def layer_kinds(arch: dict) -> list[str]:
         "attention" if (i + 1) % every == 0 else "linear"
         for i in range(arch["num_hidden_layers"])
     ]
-
-
-def _conv_channels(arch: dict) -> int:
-    keys = arch["linear_num_key_heads"] * arch["linear_key_head_dim"]
-    return 2 * keys + arch["linear_num_value_heads"] * arch["linear_value_head_dim"]
-
-
-def carry_widths(arch: dict, ctx: int) -> tuple[int, int]:
-    """Widths of the flattened acting carry ``(h, c)``, laid out as
-    ``granite_hybrid.carry_widths`` lays them out."""
-    per_linear = (
-        arch["linear_num_value_heads"] * arch["linear_key_head_dim"] * arch["linear_value_head_dim"]
-        + (arch["linear_conv_kernel_dim"] - 1) * _conv_channels(arch)
-    )
-    per_full = 2 * ctx * arch["num_key_value_heads"] * arch["head_dim"]
-    kinds = layer_kinds(arch)
-    return kinds.count("linear") * per_linear, kinds.count("attention") * per_full + 1
 
 
 class GatedDeltaNet(nn.Module):
@@ -213,7 +192,7 @@ class Qwen3NextLayer(nn.Module):
     each behind a zero-centred RMSNorm."""
 
     arch: dict
-    kind: str  # "linear" | "attention"; to the unroll / act loops: what it carries
+    kind: str  # "linear" | "attention"
     dtype: Any = None
 
     def setup(self):
@@ -246,8 +225,7 @@ class Qwen3NextLayer(nn.Module):
         with jax.named_scope("moe"):
             mixed, route = self.experts(self.post_norm(x))
         if self.kind == "attention":
-            counts = (kept_pairs(seg, None), *attention_tiles(seg))
-            route.update({c: {"global": n} for c, n in zip(ATTENTION_COUNTERS, counts)})
+            route.update(attention_counts(seg, None, "global"))
         return (x + mixed, *carry, route)
 
     def step(self, x, *carry):
@@ -259,20 +237,20 @@ class Qwen3NextLayer(nn.Module):
         return (x, *carry)
 
 
-class Qwen3NextActorCritic(NemotronHActorCritic):
-    def setup(self):
-        a = self.arch
-        self.embed = nn.Dense(a["hidden_size"], name="embed", dtype=self.dtype)
-        layer = nn.remat(Qwen3NextLayer) if self.remat else Qwen3NextLayer
-        self.layers = [
-            layer(a, kind, self.dtype, name=f"layer{i}") for i, kind in enumerate(layer_kinds(a))
-        ]
-        self.norm_f = RMSNorm(a["rms_norm_eps"], zero_centered=True, name="norm_f")
-        self.logits_head = nn.Dense(self.n_actions, name="logits")
-        self.value_head = nn.Dense(1, name="value")
-        self.h_width, self.c_width = carry_widths(a, self.act_ctx)
-        self.state_shape = (
-            a["linear_num_value_heads"], a["linear_key_head_dim"], a["linear_value_head_dim"])
-        self.tail_shape = (a["linear_conv_kernel_dim"] - 1, _conv_channels(a))
-        ring = (self.act_ctx, a["num_key_value_heads"], a["head_dim"])
-        self.kv_shapes = [ring] * layer_kinds(a).count("attention")
+class Qwen3NextActorCritic(Backbone):
+    Layer = Qwen3NextLayer
+    layer_args = staticmethod(layer_kinds)
+    zero_centered = True
+
+    @staticmethod
+    def acting_state(arch, ctx):
+        keys = arch["linear_num_key_heads"] * arch["linear_key_head_dim"]
+        heads, value_dim = arch["linear_num_value_heads"], arch["linear_value_head_dim"]
+        linear = recurrent(
+            (heads, arch["linear_key_head_dim"], value_dim),
+            (arch["linear_conv_kernel_dim"] - 1, 2 * keys + heads * value_dim))
+        kv = (ctx, arch["num_key_value_heads"], arch["head_dim"])
+        return [linear if kind == "linear" else ring(kv, kv) for kind in layer_kinds(arch)]
+
+
+ActorCritic = Qwen3NextActorCritic

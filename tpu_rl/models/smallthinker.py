@@ -4,13 +4,10 @@ layouts make layer 0 of every four global and without positions (NoPE) and
 the other three a sliding window over rotated queries and keys (RoPE).
 
 Widths come from ``Config.arch``, the model's own ``config.json`` under its
-published key names (``config.SMALLTHINKER_ARCH_KEYS``). The unroll / act
-loops, the acting carry and its packing are ``GraniteHybridActorCritic``'s,
-attention is its ``GQAttention`` (positions and window as fields), the expert
-block, the observation projection and the heads ``models/nemotron_h.py``'s
-(the block at its other published form). As there, an
-observation projection replaces the token embedding and a policy and a value
-head replace the LM head.
+published key names (``config.SMALLTHINKER_ARCH_KEYS``). The trunk (the
+embedding, the unroll and act loops, the acting carry, the heads) is
+``models/backbone.py``'s; attention is ``models/layers.py``'s ``GQAttention``
+(positions and window as fields), the experts its ``ExpertBlock`` (gated).
 
     x = Dense(obs)
     per layer i:
@@ -33,13 +30,9 @@ ring per layer and a step counter. A global layer's ring has ``act_ctx``
 slots, a window layer's ``sliding_window_size`` whatever ``act_ctx`` is; a
 RoPE layer's ring stores its keys as rotated at their own step.
 
-``unroll_routed`` also returns each layer's routing record, and beside the
-routing in it what the layer's attention mask did (``global`` or ``window`` ->
-the count; ``obs/learn.attention_scalars``): the query-key pairs it kept
-(``attn-pairs``), and of the splash kernels' grid the tiles of the static band
-(``attn-tiles-band``), those of them that no seam emptied, which the
-kernels compute (``attn-tiles-run``), and the grid steps the backward takes a
-head (``attn-bwd-steps``; ``parallel/sequence.attention_tiles``).
+``unroll_routed`` also returns each layer's routing record and, in it, what
+the layer's attention mask did under its span name, ``global`` or ``window``
+(``layers.attention_counts``).
 """
 
 from __future__ import annotations
@@ -48,12 +41,15 @@ from typing import Any
 
 import flax.linen as nn
 import jax
-import jax.numpy as jnp
 
-from tpu_rl.models.granite_hybrid import GQAttention, RMSNorm
-from tpu_rl.models.nemotron_h import ExpertBlock, NemotronHActorCritic, expert_share
-from tpu_rl.obs.learn import ATTENTION_COUNTERS
-from tpu_rl.parallel.sequence import attention_tiles
+from tpu_rl.models.backbone import Backbone, ring
+from tpu_rl.models.layers import (
+    ExpertBlock,
+    GQAttention,
+    RMSNorm,
+    attention_counts,
+    expert_share,
+)
 
 
 def ring_slots(arch: dict, ctx: int) -> list[int]:
@@ -64,25 +60,6 @@ def ring_slots(arch: dict, ctx: int) -> list[int]:
     ]
 
 
-def carry_widths(arch: dict, ctx: int) -> tuple[int, int]:
-    """Widths of the flattened acting carry ``(h, c)``."""
-    per_slot = 2 * arch["num_key_value_heads"] * arch["head_dim"]
-    return 0, sum(ring_slots(arch, ctx)) * per_slot + 1
-
-
-def kept_pairs(seg, window: int | None):
-    """Query-key pairs the mask of one attention layer keeps over a batch of
-    windows, from ``seg`` (B, T) alone: a query sees the steps of its episode
-    so far, its own among them, and of those at most ``window``. Float32."""
-    t = jnp.arange(seg.shape[1], dtype=jnp.int32)
-    starts = jnp.concatenate(
-        [jnp.ones_like(seg[:, :1], bool), seg[:, 1:] != seg[:, :-1]], axis=1)
-    seen = t - jax.lax.cummax(jnp.where(starts, t, 0), axis=1) + 1
-    if window is not None:
-        seen = jnp.minimum(seen, window)
-    return jnp.sum(seen.astype(jnp.float32))
-
-
 class SmallThinkerLayer(nn.Module):
     """One published layer: attention of the kind the layouts give layer
     ``index``, then the expert block routed on the state before attention."""
@@ -90,7 +67,6 @@ class SmallThinkerLayer(nn.Module):
     arch: dict
     index: int
     dtype: Any = None
-    kind = "attention"  # to the unroll / act loops: a K/V ring, no state
 
     def setup(self):
         a = self.arch
@@ -122,8 +98,7 @@ class SmallThinkerLayer(nn.Module):
             x = x + self.attention(a, seg)
         with jax.named_scope("moe"):
             mixed, route = self.experts(self.post_norm(x), scored=a)
-        counts = (kept_pairs(seg, self.window), *attention_tiles(seg, self.window))
-        route.update({c: {self.span: n} for c, n in zip(ATTENTION_COUNTERS, counts)})
+        route.update(attention_counts(seg, self.window, self.span))
         return x + mixed, route
 
     def step(self, x, k_cache, v_cache, count):
@@ -136,19 +111,13 @@ class SmallThinkerLayer(nn.Module):
         return x, k_cache, v_cache
 
 
-class SmallThinkerActorCritic(NemotronHActorCritic):
-    def setup(self):
-        a = self.arch
-        self.embed = nn.Dense(a["hidden_size"], name="embed", dtype=self.dtype)
-        layer = nn.remat(SmallThinkerLayer) if self.remat else SmallThinkerLayer
-        self.layers = [
-            layer(a, i, self.dtype, name=f"layer{i}") for i in range(a["num_hidden_layers"])
-        ]
-        self.norm_f = RMSNorm(a["rms_norm_eps"], name="norm_f")
-        self.logits_head = nn.Dense(self.n_actions, name="logits")
-        self.value_head = nn.Dense(1, name="value")
-        self.h_width, self.c_width = carry_widths(a, self.act_ctx)
-        self.kv_shapes = [
-            (slots, a["num_key_value_heads"], a["head_dim"])
-            for slots in ring_slots(a, self.act_ctx)
-        ]
+class SmallThinkerActorCritic(Backbone):
+    Layer = SmallThinkerLayer
+
+    @staticmethod
+    def acting_state(arch, ctx):
+        heads, size = arch["num_key_value_heads"], arch["head_dim"]
+        return [ring((slots, heads, size), (slots, heads, size)) for slots in ring_slots(arch, ctx)]
+
+
+ActorCritic = SmallThinkerActorCritic

@@ -141,7 +141,7 @@ ATTENTION_COUNTERS = ("attn-pairs", "attn-tiles-run", "attn-tiles-band", "attn-b
 def attention_scalars(routes: list) -> dict[str, jax.Array]:
     """What the attention masks did this update, from what the layers handed
     back beside their routing (counter -> kind -> count;
-    ``models/smallthinker.py``), each summed over the layers of its kind:
+    ``models/layers.attention_counts``), each summed over the layers of its kind:
     the query-key pairs kept (``attn-pairs-global``, ``attn-pairs-window``),
     the tiles of the splash kernels' static band (``attn-tiles-band-*``),
     those of them the kernels computed because no seam emptied them

@@ -45,7 +45,7 @@ one ``lax.scan`` over the chunks carries the state and yields each chunk's
 entering state and ``V'``; ``O`` is again computed for all chunks at once, and
 the backward pass rematerialises the span. Decays, cumulative sums, the
 inverse and the carried state are float32; the operands of every other product
-are ``dtype`` with float32 accumulation, as ``models/granite_hybrid._ssd_jnp``
+are ``dtype`` with float32 accumulation, as ``models/mamba2._ssd_jnp``
 does for Mamba-2. A seam is a mask on every decay factor (never ``-inf``
 inside a cumulative sum). The backward is JAX's transpose of this program;
 the kernels' is their own (``jax.custom_vjp``), at the same precision.
@@ -130,7 +130,7 @@ def _kernel_block(b: int, hv: int, hk: int, dk: int, dv: int, Q: int) -> tuple[i
     """(value heads per grid step of the Pallas pair, interpret), or (None,
     False) for the ``jax.numpy`` body: the gate of ``models/cells.py``
     (``set_pallas_mode``, the platform of the program being traced) applied to
-    the scan, as ``models/granite_hybrid._ssd_kernel_block`` applies it to
+    the scan, as ``models/mamba2._ssd_kernel_block`` applies it to
     Mamba-2's. The CPU, sizes that are no lane multiples and a batch that does
     not tile a registered data mesh (init and act traces: a Mosaic call has no
     SPMD rule outside its island) keep the ``jax.numpy`` form."""

@@ -1,6 +1,6 @@
 """Chunked Mamba-2 scan (SSD) as one Pallas TPU kernel per pass.
 
-``models/granite_hybrid.ssd_chunked`` in its ``jnp`` form builds every
+``models/mamba2.ssd_chunked`` in its ``jnp`` form builds every
 token x inner intermediate of the chunked recurrence as an HLO result of its
 own (``dtx``, ``xw``, ``y``, ``y_in`` in float32, their bf16 copies, two layout
 copies, the per-chunk states in both dtypes): ~2.2 GB through HBM per layer

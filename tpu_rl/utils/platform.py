@@ -30,7 +30,7 @@ _CACHE_DIR = os.path.join(
 
 # Pallas TPU kernels lower to this custom-call target; scan / XLA paths never
 # emit it. Kernel and fallback paths are wrapped in the named scopes below
-# (models/cells.py, models/granite_hybrid.py: ``ssd_pallas`` inside
+# (models/cells.py, models/mamba2.py: ``ssd_pallas`` inside
 # ``ssd_scan`` when the scan took its kernels, ops/gated_delta.py: ``gdn_pallas``
 # inside ``gdn_scan`` likewise, ops/moe.py: ``moe_gmm_pallas`` inside
 # ``moe_experts`` likewise, and ``moe_row_add_pallas`` where a trip's
