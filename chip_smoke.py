@@ -398,6 +398,9 @@ GLM4_MOE_LITE_MIXER = dict(
     kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
     rope_theta=1000000,
 )
+# The widths of LFM2-24B-A2B's gated short convolution as published
+# (benchmarks/configs/lfm2-24b-a2b.json holds the whole configuration).
+LFM2_MOE_MIXER = dict(hidden_size=2048, conv_L_cache=3)
 
 
 def kernel_checks(
@@ -482,6 +485,18 @@ def kernel_checks(
     # form stepped over a latent ring of T slots against the training form
     glm_shapes=(("mixer", 1, 16384), ("step", 2, 1024)),
     glm_widths=GLM4_MOE_LITE_MIXER,
+    # (row, B, T): lfm2-24b-a2b's gated short convolution at ``lfm2_widths``.
+    # "mixer": the training form at the cell's batch (in_proj, the gates'
+    # product, the seam-stopped taps, out_proj), forward and every gradient
+    # against benchmarks/reference/lfm2_moe.py (three shifted, seam-masked
+    # products). "step": the acting form stepped over its two-row tail against
+    # the training form
+    lfm2_shapes=(("mixer", 4, 8192), ("step", 2, 1024)),
+    lfm2_widths=LFM2_MOE_MIXER,
+    # as ``attn_bwd_shapes``: one row of lfm2-24b-a2b's window at its attention
+    # layer (heads of 64: the three gradients leave the kernel head-major);
+    # run last, so that the rows above keep the inputs they were drawn
+    lfm2_attn_bwd_shapes=((8192, 32, 8, 64, 64**-0.5, None, 2048, None),),
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -713,79 +728,82 @@ def kernel_checks(
     # ---- the repo's own backward over the band's tiles vs the plain reference
     # (output and the three gradients), its dq no farther from it than the
     # library's fused backward on the same masks, the two timed side by side
-    for T, NH, NKV, D, sm_scale, window, episode, edge in attn_bwd_shapes:
-        q = (f32(T, NH, D) * sm_scale).astype(jnp.bfloat16)  # one row
-        k, v = (f32(T, NKV, D).astype(jnp.bfloat16) for _ in range(2))
-        seg, bs, n_run, n_band = drawn_row(T, episode, edge, window)
-        pos = jnp.arange(T)[None]
-        w_o = f32(T, NH, D)
+    def attn_bwd_rows(shapes, family=""):
+        for T, NH, NKV, D, sm_scale, window, episode, edge in shapes:
+            q = (f32(T, NH, D) * sm_scale).astype(jnp.bfloat16)  # one row
+            k, v = (f32(T, NKV, D).astype(jnp.bfloat16) for _ in range(2))
+            seg, bs, n_run, n_band = drawn_row(T, episode, edge, window)
+            pos = jnp.arange(T)[None]
+            w_o = f32(T, NH, D)
 
-        def with_grads(attend):
-            def fn(q, k, v, seg):
-                def loss(q, k, v):
-                    out = attend(q, k, v, seg)
-                    return (out.astype(jnp.float32) * w_o).sum(), out
+            def with_grads(attend):
+                def fn(q, k, v, seg):
+                    def loss(q, k, v):
+                        out = attend(q, k, v, seg)
+                        return (out.astype(jnp.float32) * w_o).sum(), out
 
-                grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
-                return (out, *grads)
+                    grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+                    return (out, *grads)
 
-            return jax.jit(fn)
+                return jax.jit(fn)
 
-        def one_row(row):
-            def attend(q, k, v, seg):
-                splash = sequence._splash_kernel(
-                    T, NH, causal=True, window=window, block_sizes=bs, interpret=interpret)
-                empty = sequence.seam_empty_tiles(seg, bs.block_q)[0]
-                return row((True, window, 0), splash, q, k, v, seg[0], empty)
+            def one_row(row):
+                def attend(q, k, v, seg):
+                    splash = sequence._splash_kernel(
+                        T, NH, causal=True, window=window, block_sizes=bs, interpret=interpret)
+                    empty = sequence.seam_empty_tiles(seg, bs.block_q)[0]
+                    return row((True, window, 0), splash, q, k, v, seg[0], empty)
 
-            return with_grads(attend)
+                return with_grads(attend)
 
-        def plain(q, k, v, seg):  # float32 on the same bf16 inputs, 1,024 queries at a time
-            q, k, v = (x.astype(jnp.float32)[None] for x in (q, k, v))
-            k, v = (jnp.repeat(x, NH // NKV, axis=2) for x in (k, v))
+            def plain(q, k, v, seg):  # float32 on the same bf16 inputs, 1,024 queries at a time
+                q, k, v = (x.astype(jnp.float32)[None] for x in (q, k, v))
+                k, v = (jnp.repeat(x, NH // NKV, axis=2) for x in (k, v))
 
-            @jax.checkpoint
-            def queries(at):
-                cut = lambda x: jax.lax.dynamic_slice_in_dim(x, at, min(T, 1024), axis=1)  # noqa: E731
-                scores = _masked_block_scores(
-                    cut(q), k, cut(pos), pos, cut(seg), seg, 1.0, True, window)
-                return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
+                @jax.checkpoint
+                def queries(at):
+                    cut = lambda x: jax.lax.dynamic_slice_in_dim(x, at, min(T, 1024), axis=1)  # noqa: E731
+                    scores = _masked_block_scores(
+                        cut(q), k, cut(pos), pos, cut(seg), seg, 1.0, True, window)
+                    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
 
-            out = jax.lax.map(queries, jnp.arange(0, T, min(T, 1024)))  # (chunks, 1, 1024, H, D)
-            return out.reshape(T, NH, D)
+                out = jax.lax.map(queries, jnp.arange(0, T, min(T, 1024)))  # (chunks, 1, 1024, H, D)
+                return out.reshape(T, NH, D)
 
-        row = {"kernel": f"attn bwd over the band T{T}/H{NH}:{NKV}/D{D}"
-                         f"{f'/window{window}' if window else ''} bf16 vs the plain reference "
-                         f"(tiles of {bs.block_q}: {n_run} of the band's {n_band} run)",
-               "tol": TOL_BF16}
-        t0 = time.time()
-        try:
-            own, lib = one_row(sequence._seam_row), one_row(sequence._seam_row_forward)
-            paths = program_paths(own.lower(q, k, v, seg))
-            got, theirs = (jax.block_until_ready(f(q, k, v, seg)) for f in (own, lib))
-            with jax.default_matmul_precision("highest"):
-                want = jax.block_until_ready(with_grads(plain)(q, k, v, seg))
-            mean = lambda a, w: float(np.abs(np.asarray(a, np.float32) - np.asarray(w)).mean())  # noqa: E731
-            row.update(
-                err=_rel_err(got, want), err_lib=_rel_err(theirs, want),
-                err_dq=_rel_err(got[1], want[1]), err_dq_lib=_rel_err(theirs[1], want[1]),
-                mean_dq=mean(got[1], want[1]), mean_dq_lib=mean(theirs[1], want[1]),
-                # forward and dk, dv walk the tiles in the library's order (recorded, not
-                # required: an earlier form of the kernel held it in the interpreter alone)
-                same_out_dk_dv=all(
-                    bool((g == t).all()) for g, t in zip(got[:1] + got[2:], theirs[:1] + theirs[2:])),
-                mosaic_calls=paths["mosaic_calls"],
-                ms=best_ms(own, (q, k, v, seg)), ms_ref=best_ms(lib, (q, k, v, seg)),
-            )
-            row["ok"] = (
-                row["err"] <= TOL_BF16 and row["mean_dq"] <= row["mean_dq_lib"]
-                and "attn_bwd_pallas" in paths["paths"]
-            )
-        except Exception as e:  # noqa: BLE001 — report every kernel
-            row.update(ok=False, error=f"{type(e).__name__}: {str(e)[:1500]}")
-        row["wall_s"] = round(time.time() - t0, 1)
-        rows.append(row)
-        print(f"[kernels] {json.dumps(row)}", flush=True)
+            row = {"kernel": f"{family}attn bwd over the band T{T}/H{NH}:{NKV}/D{D}"
+                             f"{f'/window{window}' if window else ''} bf16 vs the plain reference "
+                             f"(tiles of {bs.block_q}: {n_run} of the band's {n_band} run)",
+                   "tol": TOL_BF16}
+            t0 = time.time()
+            try:
+                own, lib = one_row(sequence._seam_row), one_row(sequence._seam_row_forward)
+                paths = program_paths(own.lower(q, k, v, seg))
+                got, theirs = (jax.block_until_ready(f(q, k, v, seg)) for f in (own, lib))
+                with jax.default_matmul_precision("highest"):
+                    want = jax.block_until_ready(with_grads(plain)(q, k, v, seg))
+                mean = lambda a, w: float(np.abs(np.asarray(a, np.float32) - np.asarray(w)).mean())  # noqa: E731
+                row.update(
+                    err=_rel_err(got, want), err_lib=_rel_err(theirs, want),
+                    err_dq=_rel_err(got[1], want[1]), err_dq_lib=_rel_err(theirs[1], want[1]),
+                    mean_dq=mean(got[1], want[1]), mean_dq_lib=mean(theirs[1], want[1]),
+                    # forward and dk, dv walk the tiles in the library's order (recorded, not
+                    # required: an earlier form of the kernel held it in the interpreter alone)
+                    same_out_dk_dv=all(
+                        bool((g == t).all()) for g, t in zip(got[:1] + got[2:], theirs[:1] + theirs[2:])),
+                    mosaic_calls=paths["mosaic_calls"],
+                    ms=best_ms(own, (q, k, v, seg)), ms_ref=best_ms(lib, (q, k, v, seg)),
+                )
+                row["ok"] = (
+                    row["err"] <= TOL_BF16 and row["mean_dq"] <= row["mean_dq_lib"]
+                    and "attn_bwd_pallas" in paths["paths"]
+                )
+            except Exception as e:  # noqa: BLE001 — report every kernel
+                row.update(ok=False, error=f"{type(e).__name__}: {str(e)[:1500]}")
+            row["wall_s"] = round(time.time() - t0, 1)
+            rows.append(row)
+            print(f"[kernels] {json.dumps(row)}", flush=True)
+
+    attn_bwd_rows(attn_bwd_shapes)
 
     # ---- the Pallas scan pair vs the jnp body of ssd_chunked, every gradient
     from tpu_rl.models.mamba2 import ssd_chunked
@@ -1037,6 +1055,61 @@ def kernel_checks(
             TOL_MIXER_BF16, TOL_MIXER_BF16, mosaic=False,
             ref_is_kernel=jax.default_backend() == "tpu",
         )
+
+    # ---- lfm2_moe's gated short convolution: the training form in bf16 vs the
+    # plain float32 reference, and the acting form vs the training form
+    from benchmarks.reference import lfm2_moe as plain_conv
+    from tpu_rl.models.lfm2_moe import ShortConv
+
+    hidden, taps = lfm2_widths["hidden_size"], lfm2_widths["conv_L_cache"]
+    for row, B, T in lfm2_shapes:
+        mixer = ShortConv(hidden, taps, jnp.bfloat16)
+        u = f32(B, T, hidden)
+        firsts = rng.random((B, T)) < 4.0 / T  # ~4 episode seams a window, as the cell's mix
+        firsts[:, [T // 3, T // 3 + 1, T - 1]] = True  # two in a row and one at the last step
+        firsts[1:] = firsts[:1]  # the rows of a stepped batch start their episodes together
+        seg = jnp.asarray(np.cumsum(firsts, axis=1).astype(np.int32))
+        first = jnp.asarray(firsts)
+        tail0 = jnp.zeros((B, taps - 1, hidden))
+        params = jax.jit(lambda key: mixer.init(key, u, seg, tail0)["params"])(jax.random.key(SEED))
+        w_y = f32(B, T, hidden)
+        if row == "mixer":
+            def system(p, u):
+                y, _ = mixer.apply({"params": p}, u, seg, tail0)
+                return (y * w_y).sum(), y
+
+            def reference(p, u):
+                y = plain_conv.short_conv(u, first, p, lfm2_widths)
+                return (y * w_y).sum(), y
+
+            case(
+                f"lfm2_moe shortconv mixer fwd+bwd B{B}/T{T} bf16 vs the plain reference "
+                f"({int(firsts.sum())} seams)",
+                jax.value_and_grad(system, argnums=(0, 1), has_aux=True),
+                jax.value_and_grad(reference, argnums=(0, 1), has_aux=True),
+                (params, u), TOL_MIXER_BF16, TOL_MIXER_BF16, mosaic=False,
+            )
+            continue
+
+        def stepped(p, u):
+            """``step`` over the window, the tail zeroed at episode starts as
+            the worker zeroes the carry; the last tail beside the outputs."""
+            def one(tail, at):
+                u_t, first_t = at
+                y, tail = mixer.apply(
+                    {"params": p}, u_t, jnp.where(first_t, 0.0, tail), method="step")
+                return tail, y
+
+            tail, y = jax.lax.scan(one, tail0, (u.swapaxes(0, 1), first[0]))
+            return y.swapaxes(0, 1), tail
+
+        case(
+            f"lfm2_moe step B{B}/T{T} bf16 over a tail of {taps - 1} rows vs the unroll "
+            f"({int(firsts[0].sum())} seams)",
+            stepped, lambda p, u: mixer.apply({"params": p}, u, seg, tail0), (params, u),
+            TOL_MIXER_BF16, TOL_MIXER_BF16, mosaic=False,
+        )
+    attn_bwd_rows(lfm2_attn_bwd_shapes, "lfm2_moe ")
     return rows
 
 

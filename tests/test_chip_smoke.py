@@ -79,9 +79,19 @@ def test_kernel_checks_pass_tiny_interpreted():
             hidden_size=64, rms_norm_eps=1e-5, num_attention_heads=4, q_lora_rank=24,
             kv_lora_rank=16, qk_nope_head_dim=24, qk_rope_head_dim=8, v_head_dim=32,
             rope_theta=1000000),
+        lfm2_shapes=(("mixer", 2, 128), ("step", 2, 128)),
+        lfm2_widths=dict(hidden_size=64, conv_L_cache=3),
+        lfm2_attn_bwd_shapes=((512, 8, 2, 16, 0.25, None, 128, 128),),
         interpret=True,
     )
-    assert len(rows) == 26
+    assert len(rows) == 29
+    # lfm2_moe's three rows come last: a narrow head's gradients leave the backward head-major
+    narrow, step, mixer = rows.pop(), rows.pop(), rows.pop()
+    assert narrow["kernel"].startswith("lfm2_moe attn bwd over the band T512/H8:2/D16 bf16")
+    assert narrow["ok"] and narrow["same_out_dk_dv"] and narrow["mean_dq"] <= narrow["mean_dq_lib"]
+    assert mixer["kernel"].startswith("lfm2_moe shortconv mixer fwd+bwd B2/T128 bf16")
+    assert step["kernel"].startswith("lfm2_moe step B2/T128 bf16 over a tail of 2 rows")
+    assert mixer["ok"] and step["ok"] and mixer["err"] > 0 and step["err"] == 0  # the same numbers
     step, mixer = rows.pop(), rows.pop()  # latent attention's two rows come last
     assert mixer["kernel"].startswith("glm4_moe_lite mla mixer fwd+bwd B2/T128 bf16")
     assert step["kernel"].startswith("glm4_moe_lite mla step B2/T128 bf16 over a latent ring of 24")

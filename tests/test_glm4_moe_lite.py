@@ -622,7 +622,9 @@ TRANSFORMER = dict(algo="PPO", model="transformer", hidden_size=64, n_heads=4, n
 # norms from the raw gradients, the diagnostics' sums under ``opt_update``);
 # no model file was touched. PR 42: ``glm4_moe_lite``'s own joins them, taken on
 # the parent commit (4aa4e3c) before any model file moved; the five above it
-# were not edited.
+# were not edited. PR 43: ``lfm2_moe``'s own joins them as that PR left it; the
+# six above it hold through the trunk's new statement of a carry
+# (``backbone.tail``) and ``GQAttention.qk_norm_zero_centered``.
 BEFORE = {
     "transformer": "8b9c8c0764ab242a3da73822e1ea003dae077495da61006398cca64864b2a7d9",
     "granite_hybrid": "17b496ea1eb174484e74ab740489fb01f84614423ea1b2bb87c5bfa2d17b5e29",
@@ -630,6 +632,7 @@ BEFORE = {
     "smallthinker": "f267e0c3ebc64d3cdc8240c7bd886a68b27fe6e183e7360f33b4acb96b571f8a",
     "qwen3_next": "409273e605b448ff61c5f707fdc53a715cbabc46767dd103aad7ace85f409026",
     "glm4_moe_lite": "8adba8ef85184cacb1a698214cc811f36182bea9c6f1867eb6ac2884f75959dd",
+    "lfm2_moe": "15b0774f9969df58a3b6f2286eed0dddc3c44289ea7c0775688cc38bd04dc8b5",
 }
 
 

@@ -30,6 +30,8 @@ TREES = {
     "smallthinker": "26703d59ccd3c3fc76256cc5e7f0e27ef81ee9502f79a0ab68c7090ad295e45e",
     "qwen3_next": "8cb5c7de1180e1383abd2cc6c9c86273b158796ef51a05ad1f2c6fa4161c6da3",
     "glm4_moe_lite": "d40ba6cb6c1a42cef0f74c09ce8a7ebbef547a4c0512a3fb09c243032ce1d16d",
+    # PR 43: the family's own, as the PR that brought it built it
+    "lfm2_moe": "4be6e9e331cc53d1dba893b33df63e32f20a09ce00a80b923ff6dafd57a85513",
 }
 
 

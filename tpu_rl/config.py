@@ -284,6 +284,53 @@ def _check_glm4_moe_lite_arch(arch: dict | None) -> None:
     _check_expert_share(arch, arch["n_routed_experts"], arch["num_experts_per_tok"])
 
 
+# The keys of an LFM2-MoE (``lfm2_moe``) ``config.json`` that shape the policy
+# core (``models/lfm2_moe.py``); ``expert_parallel`` as above, with
+# ``num_experts`` the count one rank holds.
+LFM2_MOE_ARCH_KEYS = (
+    "hidden_size", "num_hidden_layers", "layer_types", "num_dense_layers", "norm_eps",
+    "conv_L_cache", "conv_bias", "num_attention_heads", "num_key_value_heads",
+    "rope_parameters", "intermediate_size", "moe_intermediate_size", "num_experts",
+    "num_experts_per_tok", "norm_topk_prob", "routed_scaling_factor", "use_expert_bias",
+)
+LFM2_MOE_LAYER_TYPES = ("conv", "full_attention")
+
+
+def _check_lfm2_moe_arch(arch: dict | None) -> None:
+    """What ``model="lfm2_moe"`` can build: each layer a gated short
+    convolution (``conv``) or grouped-query attention with plain per-head q/k
+    norms and rotary positions over the whole head (``full_attention``), as
+    ``layer_types`` says; the first ``num_dense_layers`` layers with a dense
+    SwiGLU MLP, the others with ``swiglu`` experts under the sigmoid router
+    with its expert bias and no shared expert; no bias anywhere, no rotary
+    scaling."""
+    assert isinstance(arch, dict), "model='lfm2_moe' needs arch (config.json keys)"
+    missing = [k for k in LFM2_MOE_ARCH_KEYS if k not in arch]
+    assert not missing, f"arch lacks {missing}"
+    depth, dense, kinds = arch["num_hidden_layers"], arch["num_dense_layers"], arch["layer_types"]
+    assert len(kinds) == depth >= 1, f"layer_types names {len(kinds)} layers of {depth}"
+    unknown = sorted(set(kinds) - set(LFM2_MOE_LAYER_TYPES))
+    assert not unknown, f"layer_types {unknown}: a layer is one of {LFM2_MOE_LAYER_TYPES}"
+    assert 0 <= dense < depth, (
+        f"num_dense_layers {dense} of {depth} layers: an expert layer has to follow"
+    )
+    assert not arch["conv_bias"], "the convolution and its projections have no bias"
+    assert arch["conv_L_cache"] >= 2, f"conv_L_cache {arch['conv_L_cache']}: a tail of none"
+    heads, kv = arch["num_attention_heads"], arch["num_key_value_heads"]
+    assert heads % kv == 0 and arch["hidden_size"] % heads == 0, (heads, kv)
+    assert arch.get("head_dim", arch["hidden_size"] // heads) == arch["hidden_size"] // heads, (
+        "a head is hidden_size / num_attention_heads wide"
+    )
+    assert arch["hidden_size"] // heads % 2 == 0, "rotate-half pairs a head's two halves"
+    rotary = arch["rope_parameters"]
+    assert "rope_theta" in rotary and rotary.get("rope_type", "default") == "default", (
+        f"rope_parameters {rotary!r}: rotary scaling is not built"
+    )
+    assert arch["norm_topk_prob"], "the chosen scores are always normalised"
+    assert arch["use_expert_bias"], "the router is the sigmoid one with its expert bias"
+    _check_expert_share(arch, arch["num_experts"], arch["num_experts_per_tok"])
+
+
 # The families built from a published config.json in ``Config.arch``.
 ARCH_CHECKS = {
     "granite_hybrid": _check_granite_arch,
@@ -291,6 +338,7 @@ ARCH_CHECKS = {
     "smallthinker": _check_smallthinker_arch,
     "qwen3_next": _check_qwen3_next_arch,
     "glm4_moe_lite": _check_glm4_moe_lite_arch,
+    "lfm2_moe": _check_lfm2_moe_arch,
 }
 
 
@@ -323,7 +371,9 @@ class Config:
     # (Gated-DeltaNet linear attention and gated full attention, each layer
     # with sparse experts and a gated shared one, of a Qwen3-Next config.json)
     # or "glm4_moe_lite" (multi-head latent attention, a leading dense layer,
-    # then sparse experts with a shared one, of a GLM-4.7-Flash config.json).
+    # then sparse experts with a shared one, of a GLM-4.7-Flash config.json)
+    # or "lfm2_moe" (gated short convolutions around grouped-query attention,
+    # a leading dense layer, then sparse experts, of an LFM2-MoE config.json).
     model: str = "lstm"
     n_heads: int = 4
     n_layers: int = 2
@@ -337,7 +387,7 @@ class Config:
     # names (model="granite_hybrid": GRANITE_ARCH_KEYS above; "nemotron_h":
     # NEMOTRON_ARCH_KEYS; "smallthinker": SMALLTHINKER_ARCH_KEYS;
     # "qwen3_next": QWEN3_NEXT_ARCH_KEYS; "glm4_moe_lite":
-    # GLM4_MOE_LITE_ARCH_KEYS). One
+    # GLM4_MOE_LITE_ARCH_KEYS; "lfm2_moe": LFM2_MOE_ARCH_KEYS). One
     # mapping instead of a Config field per width: the widths of a catalog
     # model are its source's to name, not this file's.
     arch: dict | None = None

@@ -10,10 +10,11 @@ differs: ``Layer`` and ``layer_args`` (the layer's class and what tells one
 layer from the others), ``eps_key`` / ``zero_centered`` (the final norm),
 ``routed``, and ``acting_state(arch, ctx)``: per layer, in order, what it
 carries from step to step — ``recurrent(state shape, tail shape)``,
-``ring(shape, ...)`` or ``NOTHING``. Everything about the acting carry follows
-from that one statement. The carry is worker-local (``store_carry=False``) and
-flat: ``h`` holds the recurrent layers' ``[state ; tail]`` in layer order,
-float32; ``c`` the rings' arrays in layer order and, last, one step counter,
+``tail(shape)``, ``ring(shape, ...)`` or ``NOTHING``. Everything about the
+acting carry follows from that one statement. The carry is worker-local
+(``store_carry=False``) and flat: ``h`` holds the recurrent layers' ``[state ;
+tail]`` (a convolution layer's tail alone) in layer order, float32; ``c`` the
+rings' arrays in layer order and, last, one step counter,
 as the transformer family packs its caches. A training window starts from the
 ``h`` it is handed (zeros when the batch carries a placeholder) and from an
 empty attention context — the truncation ``models/transformer.py`` documents.
@@ -23,6 +24,7 @@ per token.
 
 from __future__ import annotations
 
+import itertools
 import math
 from typing import Any
 
@@ -38,6 +40,12 @@ def recurrent(state: tuple, tail: tuple) -> tuple:
     """A layer that carries a state and its convolution's last inputs
     (Mamba-2's; ``models/qwen3_next.py``'s linear attention), packed into ``h``."""
     return "h", (state, tail)
+
+
+def tail(shape: tuple) -> tuple:
+    """A layer whose mixer is a convolution and nothing else
+    (``models/lfm2_moe.py``'s): it carries its last inputs and no state."""
+    return "h", (shape,)
 
 
 def ring(*arrays: tuple) -> tuple:
@@ -98,17 +106,18 @@ class Backbone(nn.Module):
         return jax.nn.log_softmax(self.logits_head(h)), self.value_head(h)
 
     def _unpack_h(self, h):
-        """(B, h_width) -> one (state, tail) per recurrent layer, float32, by one
-        reshape: a family's recurrent layers carry the same shapes."""
+        """(B, h_width) -> per recurrent layer what it carries, (state, tail)
+        or (tail,), float32, by one reshape: a family's recurrent layers carry
+        the same shapes."""
         if not self.h_width:
             return []
-        ((state_shape, tail_shape),) = {s for where, s in self.carried if where == "h"}
-        n_state, n_tail = math.prod(state_shape), math.prod(tail_shape)
-        per = h.reshape(h.shape[0], -1, n_state + n_tail)
+        (shapes,) = {s for where, s in self.carried if where == "h"}
+        ends = list(itertools.accumulate(math.prod(shape) for shape in shapes))
+        per = h.reshape(h.shape[0], -1, ends[-1])
         return [
-            (
-                per[:, i, :n_state].reshape(-1, *state_shape),
-                per[:, i, n_state:].reshape(-1, *tail_shape),
+            tuple(
+                per[:, i, start:end].reshape(-1, *shape)
+                for start, end, shape in zip([0, *ends], ends, shapes)
             )
             for i in range(per.shape[1])
         ]
@@ -149,10 +158,11 @@ class Backbone(nn.Module):
         x = self._embed(obs)
         states = iter(self._unpack_h(h0))
         carried, extras = [], []
-        for layer, (where, _) in zip(self.layers, self.carried):
+        for layer, (where, shapes) in zip(self.layers, self.carried):
             if where == "h":
-                x, state, tail, *more = layer(x, seg, *next(states))
-                carried.append((state, tail))
+                x, *more = layer(x, seg, *next(states))
+                carried.append(more[: len(shapes)])
+                more = more[len(shapes):]
             else:
                 x, *more = layer(x, seg)
             extras.extend(more)
@@ -186,8 +196,8 @@ class Backbone(nn.Module):
         carried, caches = [], []
         for layer, (where, _) in zip(self.layers, self.carried):
             if where == "h":
-                x, state, tail = layer.step(x, *next(states))
-                carried.append((state, tail))
+                x, *carry = layer.step(x, *next(states))
+                carried.append(carry)
             elif where == "c":
                 x, *cache = layer.step(x, *next(rings), count)
                 caches.append(cache)

@@ -83,8 +83,10 @@ class GQAttention(nn.Module):
     ``window`` keeps the last ``window`` keys, the query's own among them.
     Three more, off by default too (``models/qwen3_next.py`` sets all three):
     ``rotary_dim`` rotates each head's first ``rotary_dim`` features alone;
-    ``qk_norm`` (an epsilon) puts a zero-centred RMSNorm over each head of q
-    and of k before the rotation (leaves ``q_norm``, ``k_norm``); ``gated``
+    ``qk_norm`` (an epsilon) puts an RMSNorm over each head of q and of k
+    before the rotation (leaves ``q_norm``, ``k_norm``), zero-centred unless
+    ``qk_norm_zero_centered`` is off (``models/lfm2_moe.py``: the plain form,
+    over heads of 64 rotated whole); ``gated``
     doubles ``q_proj`` — each head's columns are its query, then its gate —
     and multiplies the attention's output by ``sigmoid(gate)`` before
     ``o_proj``.
@@ -107,13 +109,15 @@ class GQAttention(nn.Module):
     rotary_dim: int | None = None
     qk_norm: float | None = None
     gated: bool = False
+    qk_norm_zero_centered: bool = True
 
     def setup(self):
         proj = dict(use_bias=self.bias, dtype=self.dtype)
         self.q_proj = nn.Dense(
             (2 if self.gated else 1) * self.n_q * self.head_dim, name="q_proj", **proj)
         if self.qk_norm is not None:
-            norm = dict(eps=self.qk_norm, dtype=self.dtype, zero_centered=True)
+            norm = dict(
+                eps=self.qk_norm, dtype=self.dtype, zero_centered=self.qk_norm_zero_centered)
             self.q_norm = RMSNorm(name="q_norm", **norm)
             self.k_norm = RMSNorm(name="k_norm", **norm)
         self.k_proj = nn.Dense(self.n_kv * self.head_dim, name="k_proj", **proj)

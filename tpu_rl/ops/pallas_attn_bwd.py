@@ -30,8 +30,12 @@ operands of the inputs' dtype with float32 accumulation, ``exp(qk −
 logsumexp)``, the same mask function over the query and key indices, the same
 segment-id mask and ``mask_value``, inner steps of ``block_kv_compute`` keys,
 q / k / v as ``(head dim, T)``. dq, dk and dv are written in the caller's
-``(T, heads, D)`` layout, a head a column block of ``(T, heads * D)``. The
-forward stays the library's kernel.
+``(T, heads, D)`` layout, a head a column block of ``(T, heads * D)`` — where
+a head fills whole 128-lane columns; a narrower head (64) cannot be a column
+block of its own (Pallas' TPU lowering refuses a block whose last dimension is
+neither a multiple of 128 nor the array's), so its three gradients are written
+head-major, ``(heads, T, D)``, and re-laid once by XLA. The forward stays the
+library's kernel.
 """
 
 from __future__ import annotations
@@ -87,6 +91,7 @@ def _vmem_bytes(T: int, D: int, group: int, bq: int, bkv: int, bkc: int, itemsiz
     float32 arrays by the count of the library's kernel). 60 MiB at T 16,384
     and 28 : 4 heads of 128 in bf16, 67 MiB at 20 : 20 heads of 256, 64 MiB at
     T 8,192 and 16 : 2 heads of 256."""
+    D = max(D, _LANES)  # a narrower head's rows are padded to the lanes
     blocks = itemsize * D * (T + 2 * bq + 4 * bkv)  # dq; q, do; k, v, dk, dv
     blocks += 4 * (bkv * _LANES + 4 * _SUBLANES * bq)  # segment ids, indices, logsumexp, di
     scratch = 4 * D * (T + 2 * (T if group > 1 else bkv))
@@ -166,7 +171,8 @@ def attention_bwd(q, k, v, seg, logsumexp, do, di, steps, *, q_sequence, mask_fu
     as k and v, each rounded once from float32. q, k and v enter as
     ``(heads, D, T)`` (one re-laying each, XLA's, as the library's kernels take
     them); the three gradients are written where they lie, a head a column
-    block of ``(T, heads * D)``. A key block's dk and dv take
+    block of ``(T, heads * D)`` (head-major and re-laid after the call where
+    ``D`` is no multiple of 128). A key block's dk and dv take
     the tiles of a key head's query heads one after another, each head's query
     blocks ascending — the library's order, so they are the library's to the
     bit. ``ballast``: that many ``(H, T, D)`` arrays of q's dtype declared as
@@ -190,6 +196,8 @@ def attention_bwd(q, k, v, seg, logsumexp, do, di, steps, *, q_sequence, mask_fu
         # index (their first's), so no unwritten block is copied out
         return jnp.where(h % group == group - 1, kv_of[s], 0), h // group
 
+    columns = D % _LANES == 0  # a head is a column block of the caller's (T, heads * D)
+
     in_specs = [
         pl.BlockSpec((None, D, bq), tile),  # q as (H, D, T)
         pl.BlockSpec((None, D, bkv), key_tile),
@@ -201,16 +209,29 @@ def attention_bwd(q, k, v, seg, logsumexp, do, di, steps, *, q_sequence, mask_fu
         pl.BlockSpec((None, _SUBLANES, bq), tile),  # di
         pl.BlockSpec((_SUBLANES, bq), row_tile),  # the queries' indices
     ]
-    out_specs = [
-        pl.BlockSpec((T, D), lambda h, s, *_: (0, h)),
-        pl.BlockSpec((bkv, D), dkv_tile),
-        pl.BlockSpec((bkv, D), dkv_tile),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((T, H * D), q.dtype),
-        jax.ShapeDtypeStruct((T, heads_kv * D), k.dtype),
-        jax.ShapeDtypeStruct((T, heads_kv * D), v.dtype),
-    ]
+    if columns:
+        out_specs = [
+            pl.BlockSpec((T, D), lambda h, s, *_: (0, h)),
+            pl.BlockSpec((bkv, D), dkv_tile),
+            pl.BlockSpec((bkv, D), dkv_tile),
+        ]
+        out_shape = [
+            jax.ShapeDtypeStruct((T, H * D), q.dtype),
+            jax.ShapeDtypeStruct((T, heads_kv * D), k.dtype),
+            jax.ShapeDtypeStruct((T, heads_kv * D), v.dtype),
+        ]
+    else:
+        dkv_major = lambda *at: (*dkv_tile(*at)[::-1], 0)  # noqa: E731
+        out_specs = [
+            pl.BlockSpec((None, T, D), lambda h, s, *_: (h, 0, 0)),
+            pl.BlockSpec((None, bkv, D), dkv_major),
+            pl.BlockSpec((None, bkv, D), dkv_major),
+        ]
+        out_shape = [
+            jax.ShapeDtypeStruct((H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((heads_kv, T, D), k.dtype),
+            jax.ShapeDtypeStruct((heads_kv, T, D), v.dtype),
+        ]
     if ballast:
         out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         out_shape.append(jax.ShapeDtypeStruct((ballast * H, T, D), q.dtype))
@@ -240,7 +261,10 @@ def attention_bwd(q, k, v, seg, logsumexp, do, di, steps, *, q_sequence, mask_fu
         rows8(seg), jnp.broadcast_to(seg[:, None], (T, _LANES)),
         rows8(logsumexp), do, rows8(di), rows8(q_sequence.astype(jnp.int32)),
     )
-    dq = dq.reshape(q.shape)
+    if columns:
+        dq, dk, dv = dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    else:
+        dq, dk, dv = (x.transpose(1, 0, 2) for x in (dq, dk, dv))
     for x in held:  # the ballast: one element read by a sum no compiler can drop (ids are positive)
         dq = dq + jnp.where(seg[0] < 0, x[0, 0, 0], 0).astype(dq.dtype)
-    return dq, dk.reshape(k.shape), dv.reshape(v.shape)
+    return dq, dk, dv
