@@ -460,3 +460,143 @@ def test_slo_equality_comparator():
     # The longest-first op scan still resolves <= and >= correctly.
     assert parse_slo_spec("gauge:x<=3")[0].op == "<="
     assert parse_slo_spec("gauge:x>=3")[0].op == ">="
+
+
+# ------------- the watchdog behind a dispatch (ISSUE 44): the learner's loop
+def _tripping(at_calls):
+    """A ``prepare`` for ``_run_learner``: every crossing that can hides its
+    books behind the next dispatch, and the watchdog trips at these of its
+    calls (1-based), whatever the signals say."""
+    from tests.test_trace_lanes import _with_feed
+
+    calls = []
+
+    def prepare(svc):
+        _with_feed(patient=True)(svc)
+
+        def tripped(watchdog, losses, _diag_doc, _nf_base):
+            calls.append(dict(losses))
+            watchdog.last_reason = f"test trip at call {len(calls)}"
+            return len(calls) in at_calls
+
+        svc._watchdog_tripped = tripped
+
+    return prepare, calls
+
+
+def _heal_run(tmp_path, port, n_updates, at_calls, **kw):
+    import json
+
+    from tests.test_trace_lanes import _lanes_of, _learn_lines, _run_learner
+
+    prepare, calls = _tripping(at_calls)
+    svc, cfg = _run_learner(
+        tmp_path, port, n_updates=n_updates, prepare=prepare,
+        watchdog_enabled=True, loss_log_interval=2, **kw,
+    )
+    main = sorted(_lanes_of(tmp_path / "run")["main"], key=lambda e: e["ts"])
+    lines = _learn_lines(tmp_path / "run")
+    try:
+        with open(tmp_path / "run" / "learner_rollback.jsonl") as f:
+            rollbacks = [json.loads(line) for line in f]
+    except FileNotFoundError:
+        rollbacks = []
+    return svc, cfg, main, lines, rollbacks, calls
+
+
+def _committed(cfg):
+    from tpu_rl.checkpoint import _ckpt_dirs
+
+    return [idx for idx, _path in _ckpt_dirs(cfg.model_dir, cfg.algo)]
+
+
+def _assert_books_closed(svc, main):
+    syncs = [e for e in main if e["name"] == "log-sync"]
+    writes = [e for e in main if e["name"] == "log-write"]
+    assert len(syncs) == len(writes)
+    # the two counters add up to the logged updates
+    assert svc.n_log_behind_dispatch + sum(svc.n_log_inline.values()) == len(syncs)
+    from tests.test_trace_lanes import _counters
+
+    counters = _counters(svc)
+    assert counters["learner-log-behind-dispatch"] == svc.n_log_behind_dispatch
+    assert counters["learner-log-inline"] == sum(svc.n_log_inline.values())
+    assert counters["learner-rollbacks"] == svc.n_rollbacks
+
+
+@pytest.mark.timeout(300)
+def test_a_trip_seen_one_dispatch_late_restores_the_committed_state(tmp_path):
+    """Update 10's books are closed behind dispatch 11: the watchdog trips
+    there, the previous committed checkpoint comes back with its index, and
+    update 11 goes with the state it came from — its dispatch, its fold."""
+    svc, cfg, main, lines, rollbacks, calls = _heal_run(
+        tmp_path, 29811, 14, at_calls={5}, model_save_interval=4,
+    )
+    assert svc.n_rollbacks == 1
+    # saves at 4 and 8 were committed; the previous one is restored, the
+    # newer one (it may hold the divergence) discarded
+    assert [r["idx"] for r in rollbacks] == [4]
+    updates = [e["args"]["update"] for e in main if e["name"] == "dispatch"]
+    assert updates == list(range(1, 12)) + list(range(5, 15))
+    names = [e["name"] for e in main]
+    at = names.index("rollback")
+    late = max(i for i in range(at) if names[i] == "dispatch")
+    assert main[late]["args"]["update"] == 11
+    sync = max(i for i in range(at) if names[i] == "log-sync")
+    assert main[sync]["args"]["update"] == 10 and sync < late
+    assert names[late + 1 : at + 1][-4:] == ["log-write", "diag-drain", "watchdog", "rollback"]
+    # the iteration starts over: no publish, save or log of update 11
+    assert names[at + 1] == "feed-wait"
+    # a line holds the updates it held: none counts the discarded update
+    assert [r["idx"] for r in lines] == [2, 4, 6, 8, 10, 6, 8, 10, 12, 14]
+    assert all(r["n_updates"] == 2.0 for r in lines)
+    assert len(calls) == 10 and svc.n_nonfinite_updates == 0.0
+    _assert_books_closed(svc, main)
+    assert svc.n_log_behind_dispatch == 5  # 2, 6, 10 and, again, 6, 10
+    assert svc.n_log_inline == {"save": 4, "stop": 1, "empty feed": 0}
+    assert _committed(cfg)[-1] == 14  # the run's last state, saved on the way out
+
+
+@pytest.mark.timeout(300)
+def test_a_save_and_a_trip_on_one_update_never_commit_the_unverified_state(tmp_path):
+    """Where a save is due the order is read, verify, save: the trip at
+    update 6 is seen before 6 could be committed."""
+    svc, cfg, main, lines, rollbacks, calls = _heal_run(
+        tmp_path, 29813, 10, at_calls={3}, model_save_interval=2,
+    )
+    assert svc.n_rollbacks == 1 and [r["idx"] for r in rollbacks] == [2]
+    names = [e["name"] for e in main]
+    at = names.index("rollback")
+    assert names[at - 4 : at] == ["log-sync", "log-write", "diag-drain", "watchdog"]
+    assert main[at - 4]["args"]["update"] == 6
+    assert names[at + 1] == "feed-wait"  # and no ckpt-save of the restored index
+    # every save stands right behind the watchdog's look at that very update
+    saves = [i for i, n in enumerate(names) if n == "ckpt-save"]
+    assert len(saves) == 2 + 4  # 2, 4; then 4, 6, 8, 10 of the second pass
+    for i in saves:
+        assert names[i - 4 : i] == ["log-sync", "log-write", "diag-drain", "watchdog"]
+    updates = [e["args"]["update"] for e in main if e["name"] == "dispatch"]
+    assert updates == list(range(1, 7)) + list(range(3, 11))
+    assert svc.n_log_behind_dispatch == 0 and svc.n_log_inline["save"] == 3 + 4
+    _assert_books_closed(svc, main)
+
+
+@pytest.mark.timeout(300)
+def test_a_spent_rollback_budget_stops_the_loop_with_the_books_closed(tmp_path, capsys):
+    svc, cfg, main, lines, rollbacks, calls = _heal_run(
+        tmp_path, 29815, 40, at_calls={5, 8}, model_save_interval=4, max_rollbacks=1,
+    )
+    # the first trip (update 10, seen behind dispatch 11) spends the budget;
+    # from there every crossing is read in line, so the second trip (the
+    # third look after the restore: update 10 again) stops the loop at once
+    assert svc.n_rollbacks == 1 and [r["idx"] for r in rollbacks] == [4]
+    assert "rollback budget exhausted (1/1" in capsys.readouterr().out
+    names = [e["name"] for e in main]
+    assert names[-4:] == ["log-sync", "log-write", "diag-drain", "watchdog"]
+    updates = [e["args"]["update"] for e in main if e["name"] == "dispatch"]
+    assert updates == list(range(1, 12)) + list(range(5, 11))
+    assert [r["idx"] for r in lines] == [2, 4, 6, 8, 10, 6, 8, 10]
+    assert len(calls) == 8 and svc.last_losses == calls[-1]
+    _assert_books_closed(svc, main)
+    assert svc.n_log_behind_dispatch == 3  # 2, 6, 10: before the budget was spent
+    assert svc.n_log_inline == {"save": 3, "stop": 2, "empty feed": 0}

@@ -240,7 +240,10 @@ class _NullPub:
         pass
 
 
-def _run_learner(tmp_path, port, n_updates, **kw):
+def _run_learner(tmp_path, port, n_updates, *, produce=None, prepare=None, **kw):
+    """One real ``LearnerService`` over the shm store, fed the same seeded
+    window for ever. ``produce(store, window, stop)`` replaces the producer
+    thread's body; ``prepare(svc)`` sees the service before it runs."""
     from tpu_rl.data.layout import BatchLayout
     from tpu_rl.data.shm_ring import OnPolicyStore, alloc_handles
     from tpu_rl.runtime.learner_service import LearnerService
@@ -275,6 +278,8 @@ def _run_learner(tmp_path, port, n_updates, **kw):
     stop = threading.Event()
 
     def feed():
+        if produce is not None:
+            return produce(store, window, stop)
         while not stop.is_set():
             if not store.put(window):
                 time.sleep(0.0005)
@@ -285,6 +290,8 @@ def _run_learner(tmp_path, port, n_updates, **kw):
         cfg, handles, model_port=port, stop_event=stop, max_updates=n_updates,
         seed=0, stat_port=port + 1,
     )
+    if prepare is not None:
+        prepare(svc)
     try:
         svc.run()
     finally:
@@ -293,10 +300,9 @@ def _run_learner(tmp_path, port, n_updates, **kw):
     return svc, cfg
 
 
-@pytest.mark.timeout(300)
-def test_learner_main_lane_is_exhaustive_and_the_ledger_still_sums(tmp_path):
-    svc, cfg = _run_learner(tmp_path, 29731, n_updates=40)
-    doc = json.loads((tmp_path / "run" / "trace.json").read_text())
+def _lanes_of(run_dir) -> dict:
+    """``trace.json``'s spans by lane name."""
+    doc = json.loads((run_dir / "trace.json").read_text())
     lanes = {
         e["tid"]: e["args"]["name"] for e in doc["traceEvents"]
         if e["ph"] == "M" and e["name"] == "thread_name"
@@ -305,6 +311,73 @@ def test_learner_main_lane_is_exhaustive_and_the_ledger_still_sums(tmp_path):
     for e in doc["traceEvents"]:
         if e["ph"] == "X":
             by_lane.setdefault(lanes[e["tid"]], []).append(e)
+    return by_lane
+
+
+def _learn_lines(run_dir) -> list:
+    """``learn.jsonl``'s records, in the order they were appended."""
+    try:
+        with open(run_dir / "learn.jsonl") as f:
+            return [json.loads(line) for line in f]
+    except FileNotFoundError:
+        return []
+
+
+def _counters(svc) -> dict:
+    """The learner's counters as its next telemetry snapshot would hold them."""
+    from tpu_rl.obs import MetricsRegistry
+
+    reg = MetricsRegistry(role="learner")
+    svc._emit_telemetry(reg, _NullPub(), svc.timer, 0)
+    return {name: value for name, _labels, value in reg.snapshot()["counters"]}
+
+
+class _Feed:
+    """The learner's feed, with a word to say where the loop asks it how
+    many batches are ready (under ``log-sync``: a crossing's question).
+    ``patient``: a ``get`` never comes back empty, so what a crossing set
+    aside is closed behind the next dispatch and nowhere else."""
+
+    def __init__(self, inner, svc, at_crossing, patient):
+        self._inner, self._svc, self._at_crossing = inner, svc, at_crossing
+        self._patient = patient
+        self.crossings = 0
+        self.hold = False  # the next get() finds nothing
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def qsize(self):
+        if self._svc._tracer.open_span("main")[0] != "log-sync":
+            return self._inner.qsize()  # the queue-depth gauge's reading
+        self.crossings += 1
+        return self._at_crossing(self, self._inner.qsize())
+
+    def get(self, timeout):
+        if self.hold:
+            self.hold = False
+            return None
+        item = self._inner.get(timeout)
+        while item is None and self._patient and not self._svc._stopped():
+            item = self._inner.get(timeout)
+        return item
+
+
+def _with_feed(at_crossing=lambda _feed, _n: 1, patient=False):
+    """A ``prepare`` for :func:`_run_learner` that wraps the service's feed
+    in a :class:`_Feed`; by default every crossing hears "one batch ready"."""
+
+    def prepare(svc):
+        make = svc._make_feed
+        svc._make_feed = lambda *a: _Feed(make(*a), svc, at_crossing, patient)
+
+    return prepare
+
+
+@pytest.mark.timeout(300)
+def test_learner_main_lane_is_exhaustive_and_the_ledger_still_sums(tmp_path):
+    svc, cfg = _run_learner(tmp_path, 29731, n_updates=40)
+    by_lane = _lanes_of(tmp_path / "run")
     assert {"main", "feeder", "publisher", "ckpt-writer", "exporter"} <= set(by_lane)
     assert {e["name"] for e in by_lane["publisher"]} == {"publish-d2h", "publish-send"}
     assert {e["name"] for e in by_lane["ckpt-writer"]} == {"ckpt-d2h", "ckpt-write"}
@@ -336,19 +409,71 @@ def test_learner_main_lane_is_exhaustive_and_the_ledger_still_sums(tmp_path):
     assert snap["buckets"]["ckpt"] > 0
     # the learner's registry says how the broadcast's latest-wins slot was
     # used: one snapshot per update and the first broadcast, sent or superseded
-    from tpu_rl.obs import MetricsRegistry
-
-    reg = MetricsRegistry(role="learner")
-    svc._emit_telemetry(reg, _NullPub(), svc.timer, 40)
-    counters = {name: value for name, _labels, value in reg.snapshot()["counters"]}
+    counters = _counters(svc)
     assert counters["learner-publish-snapshots"] == 41
     assert 1 <= counters["learner-publish-sent"] <= 41
+    # ten logged updates, each with its books closed once: behind the next
+    # dispatch, or in line at the crossing (five saves due, the last a stop too)
+    assert counters["learner-log-behind-dispatch"] == svc.n_log_behind_dispatch
+    assert counters["learner-log-inline"] == sum(svc.n_log_inline.values()) >= 5
+    assert counters["learner-log-behind-dispatch"] + counters["learner-log-inline"] == 10
+
+
+BEFORE_A_DISPATCH = {"feed-wait", "rng-split", "program-record"}
+
+
+@pytest.mark.timeout(300)
+def test_after_a_log_sync_only_the_next_dispatch_stands_before_the_chip(tmp_path):
+    """ISSUE 44: a log-sync empties the pipeline, so between its end and the
+    next dispatch the main lane does what that dispatch needs and nothing
+    else; the logged update's books (``log-write``, ``diag-drain``) are
+    closed once the chip has its next update — unless a save is due, the
+    loop is stopping or no batch is ready, and then in today's order."""
+    svc, cfg = _run_learner(
+        tmp_path, 29771, n_updates=26, loss_log_interval=2, model_save_interval=8
+    )
+    main = sorted(_lanes_of(tmp_path / "run")["main"], key=lambda e: e["ts"])
+    dispatches = [e for e in main if e["name"] == "dispatch"]
+    assert [e["args"]["update"] for e in dispatches] == list(range(1, 27))
+    assert all(a["ts"] + a["dur"] <= b["ts"] + 1e-3 for a, b in zip(main, main[1:]))
+    syncs = [e for e in main if e["name"] == "log-sync"]
+    assert [e["args"]["update"] for e in syncs] == list(range(2, 27, 2))
+    behind = 0
+    for sync in syncs:
+        i = sync["args"]["update"]
+        after = [e for e in main if e["ts"] >= sync["ts"] + sync["dur"] - 1e-3 and e is not sync]
+        nxt = next((e for e in after if e["name"] == "dispatch"), None)
+        between = [e["name"] for e in after if nxt is None or e["ts"] < nxt["ts"]]
+        write = next(e for e in after if e["name"] == "log-write")
+        drain = next(e for e in after if e["name"] == "diag-drain")
+        if i % 8 == 0 or i == 26:  # a save due, the budget spent: today's order
+            assert between[:2] == ["log-write", "diag-drain"], (i, between)
+        elif between[0] == "log-write":  # the feed had no batch ready
+            assert between[1] == "diag-drain", (i, between)
+        else:
+            behind += 1
+            assert set(between) <= BEFORE_A_DISPATCH, (i, between)
+            # the last launch before the sync was its own update's, the next
+            # is the one the books hide behind
+            assert nxt["args"]["update"] == i + 1
+            assert nxt["ts"] <= write["ts"] <= drain["ts"], (i, between)
+            later = next(
+                (e for e in dispatches if e["args"]["update"] == i + 2), None
+            )
+            assert later is None or drain["ts"] + drain["dur"] <= later["ts"] + 1e-3
+    assert behind == svc.n_log_behind_dispatch >= 1
+    assert svc.n_log_inline["save"] == 3 and svc.n_log_inline["stop"] == 1
+    assert behind + sum(svc.n_log_inline.values()) == len(syncs)
+    # a line with index i follows update i's read-back, in order, each once
+    lines = _learn_lines(tmp_path / "run")
+    assert [r["idx"] for r in lines] == list(range(2, 27, 2))
+    assert all(r["n_updates"] == 2.0 for r in lines)
 
 
 @pytest.mark.timeout(300)
 def test_the_profiler_window_opens_one_capture(tmp_path):
     svc, cfg = _run_learner(
-        tmp_path, 29741, n_updates=3 + 4 + 10,
+        tmp_path, 29741, n_updates=3 + 4 + 10, loss_log_interval=1,
         profile_dir=str(tmp_path / "prof"), profile_start=3, profile_steps=4,
     )
     assert svc._prof_capture.n_captures == 1
@@ -360,3 +485,26 @@ def test_the_profiler_window_opens_one_capture(tmp_path):
     assert [s.args["update"] for s in ring.lane("main") if s.name == "dispatch"] == dispatched
     window = [s for s in ring.lane("main") if s.name == "profiler-window"]
     assert len(window) == 2  # the start's span and the stop's, and no third
+    # ``hostplane.clock_ok`` on the ring, against a device as the syncs saw
+    # it (every update logs: an execution begins when it was launched and the
+    # chip was free, and ends when the log-sync that waited for it returned).
+    # Each log-sync waits for the newest launched update, so the check holds
+    # as it is written; had a sync waited for update i with i + 1 already
+    # launched (a lagged read-back), it would have ended before i + 1 did.
+    assert ring.clock_ok(trace.Trace([_device_as_the_syncs_saw_it(ring, lag=0)]))
+    assert not ring.clock_ok(trace.Trace([_device_as_the_syncs_saw_it(ring, lag=1)]))
+
+
+def _device_as_the_syncs_saw_it(host, lag):
+    """One execution a ``dispatch`` of the lane: from its launch (or the end
+    of the execution before it) to the end of the ``log-sync`` of update
+    ``its own + lag``; where the lane holds no such sync, a microsecond."""
+    syncs = {s.args["update"]: s for s in host.lane("main") if s.name == "log-sync"}
+    runs, edge = [], 0.0
+    for d in (s for s in host.lane("main") if s.name == "dispatch"):
+        start = max(d.end, edge)
+        sync = syncs.get(d.args["update"] + lag)
+        edge = max(sync.end, start) if sync is not None else start + 1e3
+        runs.append(Event("jit_step", start, edge - start))
+    assert len(syncs) >= 3 and len(runs) >= 4
+    return DeviceTrace("/device:TPU:0", modules=runs)

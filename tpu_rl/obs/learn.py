@@ -254,6 +254,15 @@ def make_accumulate():
     return jax.jit(accumulate, donate_argnums=(0,))
 
 
+def make_accumulate_first():
+    """The fold of a line's first dispatch as one program: the zeros are made
+    inside it, not eagerly on the host (one ``jnp.zeros`` a channel, 0.4 ms
+    each on a TPU's host). With the chip at work, the donating fold of such
+    zeros returned only when the running update had finished — 430 ms of
+    the main lane in granite's cell (PERF.md section 6, PR 44)."""
+    return jax.jit(lambda diag, stale: accumulate(init_acc(diag), diag, stale))
+
+
 # ---------------------------------------------------- host-side derived math
 def ess_normalized(w_mean: float, w2_mean: float) -> float:
     """Normalized importance-weight effective sample size
@@ -372,27 +381,44 @@ def learn_record(idx: int, derived: Mapping[str, Any]) -> dict:
 class DiagAccumulator:
     """Host-side wrapper owning the device accumulator and its jitted fold:
     ``add(diag, stale)`` per dispatch (lazy — one extra device program, no
-    sync), ``drain(idx)`` at the log cadence (the only readback) returning
-    the derived document and resetting the sums. Constructed only when
-    ``Config.learn_diag`` is on and the algo emitted a ``diag`` — callers
-    guard on ``is None`` like every other plane."""
+    sync); at the log cadence ``take()`` hands the sums over without reading
+    them (the next ``add`` starts afresh) and ``read(sums)`` is the only
+    readback, returning the derived document — ``drain(idx)`` is the two in
+    one place. Constructed only when ``Config.learn_diag`` is on and the algo
+    emitted a ``diag`` — callers guard on ``is None`` like every other
+    plane."""
 
     def __init__(self):
         self._acc = None
         self._fold = make_accumulate()
+        self._first = make_accumulate_first()
 
     def add(self, diag: Mapping[str, Any], stale: jax.Array) -> None:
         if self._acc is None:
-            self._acc = init_acc(diag)
-        self._acc = self._fold(self._acc, diag, stale)
+            self._acc = self._first(diag, stale)
+        else:
+            self._acc = self._fold(self._acc, diag, stale)
+
+    def take(self) -> dict | None:
+        """Hand over the on-device sums as they stand — no sync, no
+        readback — and start afresh: what was folded so far is the caller's
+        to ``read`` whenever it likes, what is folded next is a new line's.
+        None when nothing was accumulated since the last hand-over."""
+        acc, self._acc = self._acc, None
+        return acc
+
+    @staticmethod
+    def read(acc: dict | None) -> dict | None:
+        """Block on + read back handed-over sums and derive the document.
+        None for no sums, or sums of no update."""
+        if acc is None:
+            return None
+        host = jax.device_get(acc)
+        if float(host["n-updates"]) <= 0:
+            return None
+        return derive(host)
 
     def drain(self, idx: int) -> dict | None:
         """Block on + read back the accumulated sums, derive, reset. Returns
         None when nothing was accumulated since the last drain."""
-        if self._acc is None:
-            return None
-        host = jax.device_get(self._acc)
-        if float(host["n-updates"]) <= 0:
-            return None
-        self._acc = init_acc(host)
-        return derive(host)
+        return self.read(self.take())

@@ -46,7 +46,7 @@ A long run's ring forgets its start, so the role's bring-up record keeps it:
 ``result_dir/backend-<role>.json`` gains ``startup`` when the first
 ``log-sync`` has returned (``run_entry_unix_s``, ``loop_entry_unix_s``,
 ``first_sync_end_unix_s``, ``ring_wrapped``, and ``spans``: every ring entry
-so far as ``[lane, name, start_unix_s, seconds, args]``,
+that began by then as ``[lane, name, start_unix_s, seconds, args]``,
 :meth:`TraceRecorder.entries`) and, at close, ``compiles`` (``programs``: per
 program name its compilations, seconds tracing / lowering / in the backend,
 cache hits and misses; ``events``: the timed phases of the whole run). **A
@@ -57,8 +57,9 @@ overwrites, ``wire`` holds the tensorboard writer's import),
 ``loop_entry`` to ``first_sync_end`` the first update with its compilation,
 and ``compiles.programs`` says for each program whether the persistent cache
 answered (``hits``) or XLA compiled it again (``misses``, ``backend_s``).
-After the first ``log-sync`` any compilation of 10 ms or more is one line of
-the role's log: ``[learner] compiled <program> in <s> s (cache hit|miss|off)
+Once the first logged update's books are closed (behind the dispatch that
+follows the first ``log-sync``, or at the sync itself where nothing can hide
+them) any compilation of 10 ms or more is one line of the role's log: ``[learner] compiled <program> in <s> s (cache hit|miss|off)
 under main/<span> update <n>``.
 
 Cost model: a span is one clock pair, one small object, one deque append

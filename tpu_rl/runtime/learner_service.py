@@ -279,6 +279,11 @@ class LearnerService:
         self._diag = None
         self._diag_vers = None
         self.last_losses: dict = {}  # newest loss-log readback
+        # Logged updates by where their books were closed: behind the next
+        # dispatch (the chip at work meanwhile), or in line at the crossing,
+        # by what the loop saw there.
+        self.n_log_behind_dispatch = 0
+        self.n_log_inline = {"save": 0, "stop": 0, "empty feed": 0}
 
     # ------------------------------------------------------------------ run
     def run(self) -> None:
@@ -688,13 +693,122 @@ class LearnerService:
             # The profiler window opens once and closes once: None until its
             # capture opens, True while it runs, False for the rest of the run.
             profiling = None if cfg.profile_dir is not None else False
+            # A logged update's books: (its index, its scalars' handles, the
+            # non-finite count's array as of it, the diag sums handed over,
+            # the end of its log-sync). The log-sync empties the pipeline,
+            # so nothing the next dispatch does not need runs in front of
+            # it: the books are set aside at the crossing and closed right
+            # after that dispatch has been issued, while the chip works.
+            books = None
+
+            def _close_books(cause: str | None = None) -> str | None:
+                """Everything a logged update's read-back owes besides the
+                wait itself, in one order wherever it runs: the scalars'
+                transfer, ``log-write``, ``diag-drain``, ``watchdog`` and the
+                ``rollback`` it asks for. ``cause`` says why the books are
+                closed in line at the crossing; None means behind the next
+                dispatch. Returns "stop" (the rollback budget is spent),
+                "rolled" (state, index and key are the restored ones: the
+                caller starts its iteration over) or None."""
+                nonlocal books, state, idx, key, nf_acc, nf_base, last_pub_m
+                b_idx, b_metrics, b_nf, b_diag, sync_end = books
+                books = None
+                if cause is None:
+                    self.n_log_behind_dispatch += 1
+                else:
+                    self.n_log_inline[cause] += 1
+                with span("log-write"):
+                    # One transfer of handles the log-sync saw finished (it
+                    # does not wait for the program now running: 1.6 ms for
+                    # twelve scalars either way, PERF.md section 6, PR 44).
+                    # Kept on self (harnesses read it after run()) and
+                    # printed, like the colocated loop's update line.
+                    scalars, nf = jax.device_get((b_metrics, b_nf))
+                    del b_metrics  # freed under a span, as every array is
+                    self.last_losses = {k: float(v) for k, v in scalars.items()}
+                    if track_nf:
+                        self.n_nonfinite_updates = float(nf)
+                    # The first time, an update has just finished on the
+                    # device and the start-up is over: the record keeps
+                    # what the ring holds of it (a long run's ring
+                    # forgets), and from here on a compilation is
+                    # reported by name. A no-op ever after.
+                    backend.record_startup(run_entry, loop_entry, sync_end)
+                    print(
+                        f"[learner] update {b_idx}  "
+                        + "  ".join(
+                            f"{k} {v:.4f}" for k, v in self.last_losses.items()
+                        ),
+                        flush=True,
+                    )
+                    logger.log_losses(b_idx, self.last_losses)
+                    logger.log_timers(b_idx, timer)
+                    self._log_fleet_stat(logger)
+                    logger.flush()
+                diag_doc = None
+                if b_diag is not None:
+                    with span("diag-drain"):
+                        # The plane's ONLY readback: derive the handed-over
+                        # sums into gauges + the learn.jsonl audit line.
+                        diag_doc = diag_acc.read(b_diag)
+                        del b_diag
+                        if diag_doc is not None:
+                            if telem_reg is not None:
+                                _publish_diag(telem_reg, diag_doc)
+                            if cfg.result_dir is not None:
+                                from tpu_rl.obs.audit import append_jsonl
+
+                                append_jsonl(
+                                    cfg.result_dir,
+                                    "learn.jsonl",
+                                    _learn_record(b_idx, diag_doc),
+                                )
+                if watchdog is None:
+                    return None
+                with span("watchdog"):
+                    tripped = self._watchdog_tripped(
+                        watchdog, self.last_losses, diag_doc, nf_base
+                    )
+                if not tripped:
+                    return None
+                if budget.exhausted():
+                    print(
+                        f"[learner] rollback budget exhausted "
+                        f"({budget.used}/{cfg.max_rollbacks} in "
+                        f"{cfg.rollback_window_s:.0f}s): "
+                        f"{watchdog.last_reason}; stopping cleanly", flush=True,
+                    )
+                    return "stop"
+                with span("rollback", bucket=ROLLBACK):
+                    # An update dispatched since the crossing goes with the
+                    # state it came from: wait it out (the restore's arrays
+                    # must not land beside a running program's scratch),
+                    # then drop what it folded.
+                    jax.block_until_ready(state)
+                    rolled = self._rollback(
+                        ckpt, state, mesh, pub, fingerprint, key,
+                        watchdog.last_reason,
+                    )
+                    if rolled is None:
+                        return None
+                    state, idx, key = rolled
+                    nf_acc = b_nf
+                    if diag_acc is not None:
+                        diag_acc.take()
+                    last_pub_m = time.monotonic()
+                    watchdog.reset()
+                    nf_base = self.n_nonfinite_updates
+                    budget.record()
+                return "rolled"
+
         loop_entry = time.time()
         try:
             # Between one dispatch and the next, every statement below runs
             # inside exactly one span of the "main" lane (none nests in
             # another), so an idle gap of the device has a name. Sites that
             # feed a timer window or a goodput bucket say so; what names no
-            # bucket is the ledger's "overhead".
+            # bucket is the ledger's "overhead". Between a log-sync and the
+            # next dispatch lie feed-wait, rng-split and program-record only.
             while not self._stopped():
                 # A dispatch always advances the counter by `chain`, so stop
                 # before one that would exceed the budget (never overshoot;
@@ -719,6 +833,14 @@ class LearnerService:
                         sp_wait.timed = False
                         sp_wait.bucket = IDLE
                 if item is None:
+                    if books is not None:
+                        # No dispatch to hide behind after all: close them
+                        # now, before the poll.
+                        verdict = _close_books("empty feed")
+                        if verdict == "stop":
+                            break
+                        if verdict == "rolled":
+                            continue
                     with span("idle-poll", bucket=IDLE):
                         if self.heartbeat is not None:
                             self.heartbeat.value = time.time()
@@ -846,6 +968,16 @@ class LearnerService:
                             f"{cfg.entropy_coef}, lr -> {cfg.lr}", flush=True,
                         )
 
+                if books is not None:
+                    # The chip has its next update: now the last logged
+                    # one's books. A rollback restores the committed state
+                    # and throws this iteration's update away with the state
+                    # it came from.
+                    verdict = _close_books()
+                    if verdict == "stop":
+                        break
+                    if verdict == "rolled":
+                        continue
                 if profiling is not False:
                     # Window is relative to THIS run's updates (resume-safe).
                     # start() returns None when a /prof or SIGUSR2 capture
@@ -877,101 +1009,10 @@ class LearnerService:
                     with span("telemetry-emit"):
                         telem_last = time.monotonic()
                         self._emit_telemetry(telem_reg, telem_pub, timer, idx)
-                if _crossed(prev_idx, idx, cfg.loss_log_interval):
-                    with span("log-sync") as sp_sync:
-                        # The loop's one blocking read-back of the pipeline:
-                        # wait for this update, then fetch its scalars.
-                        jax.block_until_ready(metrics)
-                        # Kept on self (harnesses read it after run()) and
-                        # printed, like the colocated loop's update line.
-                        self.last_losses = {
-                            k: float(v) for k, v in metrics.items()
-                        }
-                        if track_nf:
-                            self.n_nonfinite_updates = float(nf_acc)
-                    with span("log-write"):
-                        # The first time, an update has just finished on the
-                        # device and the start-up is over: the record keeps
-                        # what the ring holds of it (a long run's ring
-                        # forgets), and from here on a compilation is
-                        # reported by name. A no-op ever after.
-                        backend.record_startup(
-                            run_entry, loop_entry, sp_sync.t0 + sp_sync.secs
-                        )
-                        print(
-                            f"[learner] update {idx}  "
-                            + "  ".join(
-                                f"{k} {v:.4f}"
-                                for k, v in self.last_losses.items()
-                            ),
-                            flush=True,
-                        )
-                        logger.log_losses(idx, self.last_losses)
-                        logger.log_timers(idx, timer)
-                        self._log_fleet_stat(logger)
-                        logger.flush()
-                    diag_doc = None
-                    if diag_acc is not None:
-                        with span("diag-drain"):
-                            # The plane's ONLY readback: derive the
-                            # accumulated sums into gauges + the learn.jsonl
-                            # audit line, then reset the on-device
-                            # accumulator.
-                            diag_doc = diag_acc.drain(idx)
-                            if diag_doc is not None:
-                                if telem_reg is not None:
-                                    _publish_diag(telem_reg, diag_doc)
-                                if cfg.result_dir is not None:
-                                    from tpu_rl.obs.audit import append_jsonl
-
-                                    append_jsonl(
-                                        cfg.result_dir,
-                                        "learn.jsonl",
-                                        _learn_record(idx, diag_doc),
-                                    )
-                    if watchdog is not None:
-                        with span("watchdog"):
-                            tripped = self._watchdog_tripped(
-                                watchdog, self.last_losses, diag_doc, nf_base
-                            )
-                        if tripped:
-                            if budget.exhausted():
-                                print(
-                                    f"[learner] rollback budget exhausted "
-                                    f"({budget.used}/{cfg.max_rollbacks} in "
-                                    f"{cfg.rollback_window_s:.0f}s): "
-                                    f"{watchdog.last_reason}; stopping "
-                                    f"cleanly", flush=True,
-                                )
-                                break
-                            with span("rollback", bucket=ROLLBACK):
-                                rolled = self._rollback(
-                                    ckpt, state, mesh, pub, fingerprint, key,
-                                    watchdog.last_reason,
-                                )
-                                if rolled is not None:
-                                    state, idx, key = rolled
-                                    last_pub_m = time.monotonic()
-                                    watchdog.reset()
-                                    nf_base = self.n_nonfinite_updates
-                                    budget.record()
-                            if rolled is not None:
-                                # Skip this iteration's save branch: the
-                                # restored index is already committed on
-                                # disk, re-saving it would race the
-                                # just-finished restore.
-                                continue
-                if ckpt is not None and _crossed(
-                    prev_idx, idx, cfg.model_save_interval
-                ):
-                    # Async mode: snapshot + enqueue only; the D2H, orbax
-                    # write, commit marker, and GC run on the writer thread
-                    # (lane "ckpt-writer"). This span is the synchronous
-                    # remnant of the save, or the full blocking write when
-                    # async is off.
-                    with span("ckpt-save", bucket=CKPT):
-                        ckpt.save(state, idx, meta=_ckpt_meta())
                 with span("heartbeat"):
+                    # Before the read-back, not after it: once a log-sync
+                    # has returned the chip is empty, and nothing but the
+                    # next dispatch stands between it and its work.
                     self._note_ckpt(timer)
                     if self.heartbeat is not None:
                         self.heartbeat.value = time.time()
@@ -995,8 +1036,71 @@ class LearnerService:
                             f"{cfg.stop_at_reward}: solved, stopping at "
                             f"update {idx}", flush=True,
                         )
+                save_due = ckpt is not None and _crossed(
+                    prev_idx, idx, cfg.model_save_interval
+                )
+                if _crossed(prev_idx, idx, cfg.loss_log_interval):
+                    with span("log-sync", args={"update": idx}) as sp_sync:
+                        # The loop's one blocking read-back of the pipeline:
+                        # wait for the update just dispatched, hand its diag
+                        # sums over (the next diag-fold starts a new line's)
+                        # and do nothing else in front of an empty chip —
+                        # but ask where the books can be closed. Today's
+                        # order — read, verify, save — where a state is
+                        # about to be committed (never one the watchdog has
+                        # not seen), where the loop is about to stop, and
+                        # where no batch is ready: a line is never held for
+                        # the feed.
+                        jax.block_until_ready(metrics)
+                        taken = diag_acc.take() if diag_acc is not None else None
+                        if save_due:
+                            cause = "save"
+                        elif (
+                            solved
+                            or self._stopped()
+                            or (
+                                self.max_updates is not None
+                                and idx - start_idx + chain > self.max_updates
+                            )
+                            or (budget is not None and budget.exhausted())
+                        ):
+                            cause = "stop"
+                        elif feed.qsize() == 0:
+                            cause = "empty feed"
+                        else:
+                            cause = None
+                    books = (idx, metrics, nf_acc, taken, sp_sync.t0 + sp_sync.secs)
+                    if cause is not None:
+                        verdict = _close_books(cause)
+                        if verdict == "stop":
+                            break
+                        if verdict == "rolled":
+                            # Skip this iteration's save branch: the
+                            # restored index is already committed on
+                            # disk, re-saving it would race the
+                            # just-finished restore.
+                            continue
+                if save_due:
+                    # Async mode: snapshot + enqueue only; the D2H, orbax
+                    # write, commit marker, and GC run on the writer thread
+                    # (lane "ckpt-writer"). This span is the synchronous
+                    # remnant of the save, or the full blocking write when
+                    # async is off.
+                    with span("ckpt-save", bucket=CKPT):
+                        ckpt.save(state, idx, meta=_ckpt_meta())
                 if solved:
                     break
+            if books is not None:
+                # Stopped between a crossing and the dispatch its books
+                # would have been closed behind.
+                _close_books("stop")
+            inline = self.n_log_inline
+            print(
+                f"[learner] logged updates: {self.n_log_behind_dispatch} "
+                f"closed behind the next dispatch, {sum(inline.values())} in "
+                f"line ({inline['save']} save due, {inline['stop']} stopping, "
+                f"{inline['empty feed']} feed empty)", flush=True,
+            )
         finally:
             # Feeder first (stops shm sampling), then the publisher (joins
             # its thread, flushing the final snapshot — the Pub socket is
@@ -1401,6 +1505,15 @@ class LearnerService:
                 self.n_nonfinite_updates
             )
         reg.counter("learner-rollbacks").set_total(self.n_rollbacks)
+        # Logged updates whose books were closed behind the next dispatch,
+        # and those closed in line at the crossing (a save due, a stop, an
+        # empty feed): together, the logged updates.
+        reg.counter("learner-log-behind-dispatch").set_total(
+            self.n_log_behind_dispatch
+        )
+        reg.counter("learner-log-inline").set_total(
+            sum(self.n_log_inline.values())
+        )
         perf = self._perf
         if perf is not None:
             # Performance plane: analytical FLOPs per dispatch, achieved

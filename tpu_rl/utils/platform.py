@@ -299,15 +299,17 @@ class BackendRecord:
         """Called when the role's first blocking read-back has returned (the
         first instant the program knows an update finished on the device),
         with three unix stamps: from here on a compilation is news (the
-        clock announces it), and ``startup`` — every span the ring holds,
-        the start-up's lanes ``startup`` and ``xla`` among them — is written
-        into the record, once."""
+        clock announces it), and ``startup`` — every span the ring holds
+        that began by ``first_sync_end`` (the caller may have dispatched
+        again since), the start-up's lanes ``startup`` and ``xla`` among them
+        — is written into the record, once."""
         if self._clock is None or self._clock.announce:
             return
         self._clock.announce = True
         if self._result_dir is None or self._tracer is None:
             return
         spans, wrapped = self._tracer.entries()
+        spans = [s for s in spans if s[2] <= first_sync_end]
         self.info["startup"] = {
             "run_entry_unix_s": run_entry,
             "loop_entry_unix_s": loop_entry,
