@@ -272,7 +272,7 @@ def phase_learner(
     pallas_mode: str | None = None,
 ) -> dict:
     """The production ``LearnerService`` in THIS process, fed through the
-    real shm store by a feeder thread (no children): consume -> assemble ->
+    real shm store by a feeder thread (no children): lease -> assemble ->
     H2D -> train step, ``updates`` times (the first carries the compile)."""
     import numpy as np
 
@@ -343,6 +343,11 @@ def phase_learner(
     check(svc.n_nonfinite_updates == 0, "non-finite updates")
     steps = svc.timer.elapsed.get("learner-step-time", ())
     check(len(steps) == updates, f"{len(steps)} dispatches, wanted {updates}")
+    # on-policy and unchained: every batch placed from the store's memory
+    check(
+        svc.n_feed["leased"] >= updates and svc.n_feed["copied"] == 0,
+        f"feed took {svc.n_feed}, wanted every batch leased",
+    )
     return {**res, "updates": updates, "loss": svc.last_losses.get("loss")}
 
 

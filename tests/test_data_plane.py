@@ -3,6 +3,7 @@
 
 import multiprocessing as mp
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -182,42 +183,143 @@ def mk_window(layout, tag: float):
 class TestOnPolicyStore:
     def test_fill_consume_reset_roundtrip(self, layout):
         cfg = small_config()
+        B = cfg.batch_size
         store = make_store(cfg, layout)
-        assert isinstance(store, OnPolicyStore)
-        for i in range(cfg.batch_size):
+        assert isinstance(store, OnPolicyStore) and store.generations == 2
+        for i in range(B):
             assert store.consume() is None
             assert store.put(mk_window(layout, float(i)))
-        assert not store.put(mk_window(layout, 99.0))  # full
-        out = store.consume()
-        assert out is not None
-        assert out["obs"].shape == (cfg.batch_size, layout.seq_len, layout.obs)
-        np.testing.assert_array_equal(
-            out["rew"][:, 0, 0], np.arange(cfg.batch_size, dtype=np.float32)
-        )
+        # the first generation is sealed; the writer goes on in the second
+        for i in range(B, 2 * B):
+            assert store.put(mk_window(layout, float(i)))
+        assert not store.put(mk_window(layout, 99.0))  # both full
+        assert store.size == 2 * B
+        for first in (0, B):  # oldest first, each whole
+            out = store.consume()
+            assert out is not None
+            assert out["obs"].shape == (B, layout.seq_len, layout.obs)
+            np.testing.assert_array_equal(
+                out["rew"][:, 0, 0], np.arange(first, first + B, dtype=np.float32)
+            )
         assert store.size == 0  # reset after consume
+        assert store.consume() is None
 
     def test_generation_guard_rewrites_across_consume(self, layout):
-        """A put that straddles a consume lands in the NEW generation (the
-        reference race: reset while storage is mid-make_batch)."""
+        """A put that straddles a consume of the generation it writes into
+        lands in that generation anew (the reference race: reset while
+        storage is mid-make_batch). Only ``consume(need=k)`` of a generation
+        still being filled can do that: a sealed one is not the writer's."""
         cfg = small_config()
         handles = alloc_handles(layout, cfg.batch_size)
         writer = OnPolicyStore(handles, layout)
         reader = OnPolicyStore(handles, layout)
-        for i in range(cfg.batch_size):
+        for i in range(3):
             writer.put(mk_window(layout, float(i)))
 
         # Simulate a straddling put: interpose a consume between the writer's
-        # slot write and its publish step by driving the protocol manually.
+        # claim of its slot and its publish step by driving the protocol
+        # manually.
         win = mk_window(layout, 777.0)
-        with handles.lock:
-            gen, slot = handles.gen.value, handles.count.value
-        assert slot == cfg.batch_size  # full: real put would return False...
-        out = reader.consume()  # ...but consume resets first
-        assert out is not None and handles.gen.value == gen + 1
-        assert writer.put(win)  # now lands in generation gen+1, slot 0
+        epoch, gen, slot = writer._claim()
+        assert (gen, slot) == (0, 3)
+        out = reader.consume(need=3)  # takes the three, resets the generation
+        assert out is not None and handles.gen.value == epoch + 1
+        assert not writer._publish(epoch, slot + 1)  # the write is void...
+        assert writer.put(win)  # ...and made again, at slot 0
         assert writer.size == 1
         nxt = reader.consume(need=1)
         assert nxt is not None and nxt["rew"][0, 0, 0] == 777.0
+
+    @pytest.mark.parametrize("generations", [1, 2, 3])
+    def test_writer_is_short_only_with_every_generation_sealed(
+        self, layout, generations
+    ):
+        """The writer's short count says one thing: every generation is with
+        the reader or waiting for it. A release frees one at once."""
+        B = 4
+        handles = alloc_handles(layout, B, generations=generations)
+        writer = OnPolicyStore(handles, layout)
+        reader = OnPolicyStore(handles, layout)
+        wins = [mk_window(layout, float(i)) for i in range(generations * B + 3)]
+        assert writer.put_many(wins) == generations * B
+        leased = reader.lease()
+        assert leased is not None
+        assert not writer.put(wins[-1])  # leased is not free
+        assert reader.size == generations * B
+        np.testing.assert_array_equal(
+            leased["rew"][:, 0, 0], np.arange(B, dtype=np.float32)
+        )
+        reader.release()
+        assert writer.put_many(wins[generations * B:]) == 3
+        assert writer.size == (generations - 1) * B + 3
+
+    def test_a_lease_is_views_and_the_writer_stays_out_of_it(self, layout):
+        """The leased batch is the shared memory itself (no copy), with the
+        ``ver`` sidecar's rows beside it, and stays as it is while the writer
+        fills the other generation and is refused at this one."""
+        B = 4
+        handles = alloc_handles(layout, B)
+        writer = OnPolicyStore(handles, layout)
+        reader = OnPolicyStore(handles, layout)
+        assert reader.lease() is None  # nothing sealed yet
+        writer.put_many([mk_window(layout, float(i)) for i in range(B)], vers=[5, 6, 7, 8])
+        got = reader.lease()
+        for f in BATCH_FIELDS:
+            assert np.shares_memory(got[f], reader.views[f])
+            assert got[f].shape == (B, layout.seq_len, layout.width(f))
+        assert np.shares_memory(got["ver"], reader.slot_vers)
+        assert got["ver"].tolist() == [5, 6, 7, 8]
+        with pytest.raises(RuntimeError, match="lease"):
+            reader.lease()  # one at a time
+        before = {f: got[f].copy() for f in BATCH_FIELDS}
+        assert writer.put_many(
+            [mk_window(layout, 100.0 + i) for i in range(B + 2)]
+        ) == B  # the other generation takes B, the leased one nothing
+        for f in BATCH_FIELDS:
+            np.testing.assert_array_equal(got[f], before[f])
+        assert got["ver"].tolist() == [5, 6, 7, 8]
+        reader.release()
+        reader.release()  # nothing out: nothing happens
+        nxt = reader.lease()
+        np.testing.assert_array_equal(nxt["rew"][:, 0, 0], 100.0 + np.arange(B))
+        assert nxt["ver"].tolist() == [-1] * B
+
+    def test_a_lease_left_by_a_reader_that_stopped_is_handed_out_again(self, layout):
+        """A reader that dies with a lease out leaves its generation sealed:
+        the writer is short and returns at once, never blocked, and the next
+        reader over the same handles gets that batch first."""
+        B = 4
+        handles = alloc_handles(layout, B)
+        writer = OnPolicyStore(handles, layout)
+        dead = OnPolicyStore(handles, layout)
+        writer.put_many([mk_window(layout, float(i)) for i in range(2 * B)])
+        assert dead.lease() is not None
+        del dead  # no release
+        t0 = time.monotonic()
+        assert writer.put_many([mk_window(layout, 50.0)]) == 0
+        assert time.monotonic() - t0 < 1.0
+        reader = OnPolicyStore(handles, layout)
+        for first in (0, B):
+            out = reader.consume()
+            np.testing.assert_array_equal(
+                out["rew"][:, 0, 0], np.arange(first, first + B, dtype=np.float32)
+            )
+        assert writer.put_many([mk_window(layout, 50.0)]) == 1
+
+    def test_handles_from_before_the_ring_say_so(self, layout):
+        """``ShmHandles`` keeps constructing without the new fields (one
+        generation, as a replay ring wants); an on-policy store over such
+        handles says what is missing instead of running half a protocol."""
+        import dataclasses
+
+        new = alloc_handles(layout, 4, generations=1)
+        old = dataclasses.replace(new, ring=None)
+        fields = {f.name: getattr(new, f.name) for f in dataclasses.fields(new)}
+        del fields["generations"], fields["ring"]
+        assert type(new)(**fields).generations == 1
+        assert ReplayStore(old, layout).capacity == 4
+        with pytest.raises(ValueError, match="ring"):
+            OnPolicyStore(old, layout)
 
     def test_cross_process_visibility(self, layout):
         cfg = small_config()

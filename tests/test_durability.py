@@ -118,6 +118,44 @@ def test_async_save_equivalent_to_sync(tmp_path):
     ck.close()
 
 
+def test_async_writer_lets_go_of_a_snapshot_once_it_is_written(tmp_path, monkeypatch):
+    """The device-side snapshot of an async save is a copy of the train
+    state: once it is on the host it must be garbage — before the disk write,
+    and not held by the writer's frame until the next save comes (and then
+    beside that save's snapshot)."""
+    import gc
+    import weakref
+
+    from tpu_rl import checkpoint
+
+    seen = []
+    real = checkpoint._snapshot
+
+    def snapshot(state):
+        snap = real(state)
+        seen.append(weakref.ref(snap["params"]["actor"]["w"]))
+        return snap
+
+    monkeypatch.setattr(checkpoint, "_snapshot", snapshot)
+    ck = Checkpointer(str(tmp_path), "PPO", async_save=True)
+    write, gone_at_write = ck._write, []
+
+    def write_seen(host_state, idx, meta):
+        gc.collect()
+        gone_at_write.append(seen[-1]() is None)  # on the host: let go of
+        write(host_state, idx, meta)
+
+    ck._write = write_seen
+    import jax
+
+    ck.save(jax.tree.map(jax.numpy.asarray, _state(7.0)), 100)
+    ck.flush(timeout=60.0)
+    gc.collect()
+    assert len(seen) == 1 and seen[0]() is None
+    assert gone_at_write == [True]
+    ck.close()
+
+
 def test_async_latest_wins_drops_stale_queue(tmp_path):
     """Saves enqueued faster than the writer drains collapse to the newest
     (n_skipped counts the drops); close() drains the tail save."""

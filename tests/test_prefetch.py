@@ -116,6 +116,82 @@ def test_prefetch_rejects_bad_depth():
         PrefetchPipeline(lambda: None, lambda r: r, depth=0)
 
 
+@pytest.mark.timeout(60)
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_prefetch_takes_the_queue_slot_before_it_fetches(depth):
+    """Placed and not yet taken never exceeds ``depth``, the batch in
+    placement included: with nobody taking, the feeder builds ``depth``
+    dispatches and fetches no further one until a ``get`` makes room."""
+    built = []
+
+    def assemble(raws):
+        built.append(raws[0])
+        return raws[0]
+
+    counter = iter(range(100))
+    pipe = PrefetchPipeline(lambda: next(counter), assemble, chain=1, depth=depth)
+    deadline = time.time() + 10
+    while pipe.qsize() < depth and time.time() < deadline:
+        time.sleep(0.005)
+    time.sleep(0.1)  # room for a feeder that would run ahead to do so
+    assert built == list(range(depth)) and pipe.qsize() == depth
+    assert pipe.get(timeout=1.0)[0] == 0
+    deadline = time.time() + 10
+    while len(built) < depth + 1 and time.time() < deadline:
+        time.sleep(0.005)
+    time.sleep(0.1)
+    assert built == list(range(depth + 1))  # one slot freed, one more built
+    pipe.close()
+
+
+@pytest.mark.timeout(60)
+def test_prefetch_releases_each_batch_once_it_is_queued_and_on_its_way_out():
+    """``release`` (the store's, when batches are leased) follows the
+    batch's put into the queue — never its assembly alone — once per
+    dispatch, in order; a feeder that stops gives back what it holds."""
+    log = []
+    counter = iter(range(100))
+    go = threading.Event()  # the feeder starts with the pipeline's __init__
+
+    def fetch():
+        if not go.is_set():
+            return None
+        n = next(counter)
+        log.append(("fetch", n))
+        return n
+
+    def assemble(raws):
+        log.append(("assemble", raws[0]))
+        return raws[0]
+
+    pipe = PrefetchPipeline(
+        fetch, assemble, chain=1, depth=2, release=lambda: log.append(("release",))
+    )
+    put = pipe._q.put
+    pipe._q.put = lambda item: (log.append(("put", item[0])), put(item))
+    go.set()
+    got = [pipe.get(timeout=5.0)[0] for _ in range(5)]
+    assert got == list(range(5))
+    pipe.close()
+    for n in range(5):
+        i = log.index(("fetch", n))
+        assert log[i : i + 4] == [
+            ("fetch", n), ("assemble", n), ("put", n), ("release",)
+        ]
+    assert log[-1] == ("release",)  # the way out
+
+
+def test_synchronous_feed_releases_after_assembly():
+    log = []
+    feed = SynchronousFeed(
+        lambda: log.append("fetch") or 7,
+        lambda raws: log.append("assemble") or raws[0],
+        release=lambda: log.append("release"),
+    )
+    assert feed.get()[0] == 7
+    assert log == ["fetch", "assemble", "release"]
+
+
 # --------------------------------------------------------- synchronous feed
 def test_synchronous_feed_accumulates_chain_across_none():
     """A starving store (fetch -> None) must preserve already-accumulated
@@ -258,6 +334,101 @@ def test_a_sharded_batch_goes_from_the_host_to_each_chip():
     np.testing.assert_array_equal(np.asarray(one.obs), raw["obs"])
 
 
+@pytest.mark.parametrize(
+    "algo, chain, how",
+    [("PPO", 1, "lease"), ("PPO", 2, "consume"), ("IMPALA", 1, "lease"),
+     ("SAC", 1, "sample"), ("SAC", 2, "sample")],
+)
+def test_the_feed_leases_where_it_holds_one_raw_batch_at_a_time(algo, chain, how):
+    """What the feed sees decides: on-policy and unchained, the store's
+    hand-over is a lease (and the feed is given the release); a chained
+    dispatch, which holds ``chain`` raw batches at once, and a replay sample
+    are copies. The two counters say which it was."""
+    from tpu_rl.runtime.learner_service import LearnerService
+
+    calls = []
+
+    class StubStore:
+        def lease(self):
+            calls.append("lease")
+            return {"stub": 1}
+
+        def consume(self):
+            calls.append("consume")
+            return {"stub": 1}
+
+        def sample(self, batch, rng):
+            calls.append("sample")
+            return {"stub": 1}
+
+        def release(self):
+            calls.append("release")
+
+    cfg = small_config(algo=algo, batch_size=4, learner_prefetch=0)
+    svc = LearnerService(cfg, handles=None, model_port=0)
+    svc._assemble_device = lambda raws: raws  # the placement is another test's
+    feed = svc._make_feed(StubStore(), np.random.default_rng(0), chain)
+    assert feed.get() is not None
+    leased = how == "lease"
+    assert calls == [how] * chain + ["release"] * leased
+    assert svc.n_feed == {"leased": chain * leased, "copied": chain * (not leased)}
+
+
+@pytest.mark.timeout(60)
+@pytest.mark.parametrize("mesh_data", [1, 4])
+def test_a_placed_batch_does_not_change_when_the_writer_refills_its_generation(
+    mesh_data,
+):
+    """The CPU backend's ``device_put`` may alias an aligned host buffer, and
+    a leased batch *is* the store's shared memory: what the learner was
+    handed must stay what it was when the lease is released and the writer
+    fills that generation again."""
+    import jax
+
+    from tpu_rl.data.layout import BatchLayout
+    from tpu_rl.data.shm_ring import OnPolicyStore, alloc_handles
+    from tpu_rl.obs.trace import TraceRecorder
+    from tpu_rl.parallel.mesh import batch_sharding, make_mesh
+    from tpu_rl.runtime.learner_service import LearnerService
+    from tpu_rl.types import BATCH_FIELDS
+
+    B = 8
+    # widths of 16 floats: rows of 64 bytes, which the backend may alias
+    cfg = small_config(algo="PPO", batch_size=B, seq_len=4, obs_shape=(16,))
+    layout = BatchLayout.from_config(cfg)
+    store = OnPolicyStore(alloc_handles(layout, B), layout)
+    rng = np.random.default_rng(3)
+
+    def windows():
+        return [
+            {
+                f: rng.standard_normal((4, layout.width(f))).astype(np.float32)
+                for f in BATCH_FIELDS
+            }
+            for _ in range(B)
+        ]
+
+    svc = LearnerService(cfg, handles=None, model_port=0)
+    svc._place_global = svc._chain_mesh = None
+    svc._tracer = TraceRecorder(capacity=0)
+    svc._device = jax.devices()[0]
+    svc._batch_sharding = batch_sharding(make_mesh(mesh_data)) if mesh_data > 1 else None
+    svc._leased = True
+
+    placed, want = [], []
+    for _ in range(6):  # each generation three times over
+        first = windows()
+        assert store.put_many(first, vers=list(range(B))) == B
+        raw = store.lease()
+        assert all(np.shares_memory(raw[f], store.views[f]) for f in BATCH_FIELDS)
+        want.append({f: np.stack([w[f] for w in first]) for f in BATCH_FIELDS})
+        placed.append(svc._assemble_device([raw]))
+        store.release()
+    for batch, was in zip(placed, want):
+        for f in BATCH_FIELDS:
+            np.testing.assert_array_equal(np.asarray(getattr(batch, f)), was[f])
+
+
 # ------------------------------------------------- service-level equivalence
 def _run_service_to_checkpoint(tmp_path, tag, port, prefetch, chain=2):
     """Run a LearnerService through the REAL OnPolicyStore shm path on a
@@ -333,18 +504,23 @@ def _run_service_to_checkpoint(tmp_path, tag, port, prefetch, chain=2):
 
 
 @pytest.mark.timeout(300)
-def test_pipelined_matches_synchronous_bit_exact(tmp_path):
+@pytest.mark.parametrize("chain", [2, 1])
+def test_pipelined_matches_synchronous_bit_exact(tmp_path, chain):
     """The acceptance bar: learner_prefetch=2 and learner_prefetch=0 produce
     BIT-IDENTICAL final params on the same window stream — the pipeline
-    changes timing, never data, order, or the key schedule."""
+    changes timing, never data, order, or the key schedule. Unchained, both
+    feeds lease their batches from the store; chained, both copy them out."""
     import jax
 
-    sync_state, _ = _run_service_to_checkpoint(
-        tmp_path, "sync", port=29850, prefetch=0
+    sync_state, sync_svc = _run_service_to_checkpoint(
+        tmp_path, "sync", port=29850 + 4 * chain, prefetch=0, chain=chain
     )
     pipe_state, pipe_svc = _run_service_to_checkpoint(
-        tmp_path, "pipe", port=29851, prefetch=2
+        tmp_path, "pipe", port=29851 + 4 * chain, prefetch=2, chain=chain
     )
+    how = "leased" if chain == 1 else "copied"
+    for svc in (sync_svc, pipe_svc):
+        assert svc.n_feed[how] >= 4 and sum(svc.n_feed.values()) == svc.n_feed[how]
     want = jax.tree_util.tree_leaves(sync_state.params)
     have = jax.tree_util.tree_leaves(pipe_state.params)
     assert want and len(want) == len(have)

@@ -125,18 +125,23 @@ def test_onpolicy_put_many_matches_sequential_put():
     layout = _layout()
     rng = np.random.default_rng(7)
     cap = 8
-    wins = _mk_windows(layout, rng, cap + 3)  # 3 past capacity
+    wins = _mk_windows(layout, rng, 2 * cap + 3)  # 3 past both generations
     s_many = OnPolicyStore(alloc_handles(layout, cap), layout)
     s_seq = OnPolicyStore(alloc_handles(layout, cap), layout)
     accepted = s_many.put_many(wins)
     seq_accepted = sum(s_seq.put(w) for w in wins)
     # Partial accept: the in-order head lands, the tail is rejected — exactly
     # like sequential puts against a filling store.
-    assert accepted == seq_accepted == cap
-    assert s_many.size == s_seq.size == cap
+    assert accepted == seq_accepted == 2 * cap
+    assert s_many.size == s_seq.size == 2 * cap
     for f in BATCH_FIELDS:
         np.testing.assert_array_equal(s_many.views[f], s_seq.views[f])
-    # Consume resets; the rejected tail then lands at the front of gen 2.
+        # each window lies in its slot as it was handed in
+        np.testing.assert_array_equal(
+            s_many.views[f], np.stack([w[f] for w in wins[:accepted]])
+        )
+    # Consume frees the older generation; the rejected tail then lands at
+    # its front.
     assert s_many.consume() is not None
     assert s_many.put_many(wins[accepted:]) == 3
     for i, w in enumerate(wins[accepted:]):
@@ -147,8 +152,8 @@ def test_onpolicy_put_many_empty_and_full():
     layout = _layout()
     store = OnPolicyStore(alloc_handles(layout, 2), layout)
     assert store.put_many([]) == 0
-    wins = _mk_windows(layout, np.random.default_rng(0), 2)
-    assert store.put_many(wins) == 2
+    wins = _mk_windows(layout, np.random.default_rng(0), 4)
+    assert store.put_many(wins) == 4  # two generations of two
     assert store.put_many(_mk_windows(layout, np.random.default_rng(1), 1)) == 0
 
 

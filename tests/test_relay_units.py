@@ -118,15 +118,16 @@ class TestStorage:
         st, layout, handles, _ = self._storage(cfg)
         store = OnPolicyStore(handles, layout)
         asm = RolloutAssembler(layout)
-        for tag in (1.0, 2.0, 3.0):
+        for tag in (1.0, 2.0, 3.0, 4.0, 5.0):
             asm.ready.append(_mk_window(layout, tag))
         st._flush(asm, store)
-        # store capacity 2: two windows landed, the third was REQUEUED
-        assert st.n_windows == 2
+        # two generations of capacity 2: four windows landed, the fifth was
+        # REQUEUED
+        assert st.n_windows == 4
         assert st.n_requeue_full == 1
         assert len(asm.ready) == 1
-        assert asm.ready[0]["rew"][0, 0] == 3.0
+        assert asm.ready[0]["rew"][0, 0] == 5.0
         # after the learner consumes, the requeued window flushes
         assert store.consume() is not None
         st._flush(asm, store)
-        assert st.n_windows == 3 and len(asm.ready) == 0
+        assert st.n_windows == 5 and len(asm.ready) == 0

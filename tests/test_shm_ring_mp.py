@@ -160,10 +160,10 @@ def _onpolicy_writer(handles, stop, n_accepted):
 
 @pytest.mark.timeout(120)
 def test_onpolicy_consume_never_yields_torn_window_under_live_writer():
-    """The race the reference ignores: consume() resets the store while the
-    writer is mid-slot-write. The generation counter must keep every consumed
-    batch free of torn or half-written windows, and accepted puts must be
-    conserved (consumed + currently-buffered == accepted)."""
+    """A live writer in another process against consume(): a sealed
+    generation is not the writer's, so every consumed batch is free of torn
+    or half-written windows, and accepted puts must be conserved (consumed +
+    currently-buffered == accepted)."""
     layout = _layout()
     capacity = 8
     handles = alloc_handles(layout, capacity, ctx=_CTX)
@@ -193,9 +193,8 @@ def test_onpolicy_consume_never_yields_torn_window_under_live_writer():
         stop.set()
         writer.join(30)
         assert not writer.is_alive()
-        leftover = store.size
-        last = store.consume(need=leftover) if leftover else None
-        if last is not None:
+        while store.size:  # a sealed generation first, then the partial one
+            last = store.consume(need=min(store.size, capacity))
             _assert_untorn(last)
             n_rows += len(_row_values(last))
         assert n_rows == n_accepted.value
@@ -208,10 +207,12 @@ def test_onpolicy_consume_never_yields_torn_window_under_live_writer():
 
 @pytest.mark.timeout(120)
 def test_onpolicy_generation_race_is_actually_hit():
-    """Force the consume-intervenes-mid-put interleaving deterministically:
-    patch the writer-side store so the consume happens between the slot write
-    and the generation re-check. put() must detect the stale generation and
-    re-write into the new one — the consumed-next batch sees the window."""
+    """Force the consume-intervenes-mid-put interleaving deterministically
+    (the race the reference ignores; here only a ``consume(need=k)`` of the
+    generation still being filled can start it): patch the writer-side store
+    so the consume happens between the slot write and the epoch re-check.
+    put() must detect the stale epoch and write again — the consumed-next
+    batch sees the window."""
     layout = _layout()
     handles = alloc_handles(layout, 4, ctx=_CTX)
     writer = OnPolicyStore(handles, layout)
@@ -237,3 +238,161 @@ def test_onpolicy_generation_race_is_actually_hit():
     got = reader.consume(need=1)
     assert got is not None
     assert (_row_values(got) == 99.0).all()
+
+
+# ------------------------------------------ two generations, two processes
+_SPAWN = mp.get_context("spawn")  # what the runner starts its roles with
+
+
+def _numbered_writer(handles, n_windows, burst, accepted, attempts, stop):
+    """Windows numbered from 0 in order, each stamped with its number as its
+    policy version, in bursts; a short count is tried again from where it
+    stopped, as storage requeues."""
+    layout = _layout()
+    store = OnPolicyStore(handles, layout)
+    i = 0
+    while i < n_windows and not stop.is_set():
+        ids = range(i, min(i + burst, n_windows))
+        n = store.put_many(
+            [_window(layout, float(k)) for k in ids], vers=list(ids)
+        )
+        i += n
+        accepted.value = i
+        attempts.value += 1
+    os._exit(0)
+
+
+def _start_numbered_writer(handles, n_windows, burst=3):
+    accepted, attempts = _SPAWN.Value("q", 0), _SPAWN.Value("q", 0)
+    stop = _SPAWN.Event()
+    proc = _SPAWN.Process(
+        target=_numbered_writer,
+        args=(handles, n_windows, burst, accepted, attempts, stop),
+        daemon=True,
+    )
+    proc.start()
+    return proc, accepted, attempts, stop
+
+
+def _wait(cond, timeout=60.0) -> bool:
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        if cond():
+            return True
+        time.sleep(0.001)
+    return cond()
+
+
+def _stop(proc, stop) -> None:
+    stop.set()
+    proc.join(10)
+    if proc.is_alive():
+        proc.terminate()
+
+
+@pytest.mark.timeout(180)
+@pytest.mark.parametrize("how", ["lease", "consume"])
+def test_two_generations_hand_every_window_over_once_in_order(how):
+    """Single writer and single reader in two (spawned) processes over two
+    generations: every window arrives exactly once, in the order written,
+    never torn, with its ``ver`` beside it — by lease (read in place while
+    the writer fills the other generation) and by copy."""
+    layout = _layout()
+    capacity, n_batches = 8, 60
+    handles = alloc_handles(layout, capacity, ctx=_SPAWN)
+    reader = OnPolicyStore(handles, layout)
+    n_windows = capacity * n_batches + 5  # the last generation stays partial
+    proc, accepted, _attempts, stop = _start_numbered_writer(handles, n_windows)
+    try:
+        expect = 0
+        while expect < capacity * n_batches:
+            got = reader.lease() if how == "lease" else reader.consume()
+            if got is None:
+                assert proc.is_alive() or reader.size, "writer died early"
+                continue
+            ids = _assert_untorn(got)
+            want = np.arange(expect, expect + capacity)
+            np.testing.assert_array_equal(ids, want)
+            np.testing.assert_array_equal(got["ver"], want)
+            if how == "lease":
+                # the writer may be a generation ahead, never in this one
+                time.sleep(0.0005)
+                np.testing.assert_array_equal(_assert_untorn(got), want)
+                reader.release()
+            expect += capacity
+        assert _wait(lambda: accepted.value == n_windows)
+        tail = reader.consume(need=5)
+        np.testing.assert_array_equal(
+            _assert_untorn(tail), np.arange(expect, n_windows)
+        )
+        assert tail["ver"].tolist() == list(range(expect, n_windows))
+        assert reader.size == 0
+    finally:
+        _stop(proc, stop)
+
+
+@pytest.mark.timeout(180)
+def test_writer_is_short_while_a_lease_is_out_and_goes_on_at_its_release():
+    """With one generation leased and the other full the writer's process
+    gets short counts — and keeps coming back for more, it is never blocked —
+    and goes on the moment the lease is released."""
+    layout = _layout()
+    capacity = 4
+    handles = alloc_handles(layout, capacity, ctx=_SPAWN)
+    reader = OnPolicyStore(handles, layout)
+    proc, accepted, attempts, stop = _start_numbered_writer(handles, 10**6)
+    try:
+        assert _wait(lambda: reader.lease() is not None)
+        # generation 0 is leased: the writer fills generation 1 and no more
+        assert _wait(lambda: accepted.value == 2 * capacity)
+        seen = attempts.value
+        assert _wait(lambda: attempts.value > seen + 50)  # short, not stuck
+        assert accepted.value == 2 * capacity and reader.size == 2 * capacity
+        reader.release()
+        assert _wait(lambda: accepted.value == 3 * capacity, timeout=10.0)
+        nxt = reader.lease()
+        np.testing.assert_array_equal(
+            _assert_untorn(nxt), np.arange(capacity, 2 * capacity)
+        )
+    finally:
+        _stop(proc, stop)
+
+
+def _lease_and_die(handles, took):
+    store = OnPolicyStore(handles, _layout())
+    while store.lease() is None:
+        time.sleep(0.001)
+    took.set()
+    os._exit(0)  # no release: a reader that stops with the lease out
+
+
+@pytest.mark.timeout(180)
+def test_a_reader_process_that_dies_with_a_lease_does_not_wedge_the_writer():
+    """The learner's process dies with a lease out (no release, no lock
+    held): the writer keeps getting its short count at once — storage drops
+    or requeues as on any full store — and the next reader over the same
+    handles starts with the batch the dead one held."""
+    layout = _layout()
+    capacity = 4
+    handles = alloc_handles(layout, capacity, ctx=_SPAWN)
+    writer = OnPolicyStore(handles, layout)
+    took = _SPAWN.Event()
+    dead = _SPAWN.Process(target=_lease_and_die, args=(handles, took), daemon=True)
+    dead.start()
+    wins = [_window(layout, float(i)) for i in range(2 * capacity + 1)]
+    assert writer.put_many(wins) == 2 * capacity
+    assert took.wait(60)
+    dead.join(30)
+    assert dead.exitcode == 0
+    t0 = time.monotonic()
+    for _ in range(100):
+        assert writer.put_many(wins[-1:]) == 0
+    assert time.monotonic() - t0 < 5.0
+    reader = OnPolicyStore(handles, layout)
+    for first in (0, capacity):
+        got = reader.lease()
+        np.testing.assert_array_equal(
+            _assert_untorn(got), np.arange(first, first + capacity)
+        )
+        reader.release()
+    assert writer.put_many(wins[-1:]) == 1

@@ -471,6 +471,124 @@ def test_after_a_log_sync_only_the_next_dispatch_stands_before_the_chip(tmp_path
 
 
 @pytest.mark.timeout(300)
+@pytest.mark.parametrize("chain", [1, 2])
+def test_the_feeder_places_into_a_slot_it_holds_and_releases_after_the_put(
+    tmp_path, capsys, monkeypatch, chain
+):
+    """ISSUE 45: the feeder lane's order is ``queue-slot`` (only while the
+    queue is full), ``fetch``, ``assemble`` with ``h2d-put`` inside it,
+    ``queue-put`` — so placed and not yet taken never exceeds
+    ``learner_prefetch``, the batch in placement included. Unchained and
+    on-policy the store's hand-over is a lease, released under ``queue-put``
+    and nowhere else while the feeder runs; chained it is a copy. The two
+    counters and ``learner.log``'s closing line say which."""
+    from tpu_rl.data.shm_ring import OnPolicyStore
+
+    depth, n_updates = 2, 24
+    seen = {"released_under": [], "most": 0, "feed": None}
+
+    def produce(store, window, stop):
+        while not stop.is_set():
+            if store.put_many([window] * 4) < 4:
+                time.sleep(0.0005)
+
+    def prepare(svc):
+        release = OnPolicyStore.release
+
+        def release_seen(store):
+            if store._lease is not None:
+                under = svc._tracer.open_span("feeder")
+                seen["released_under"].append(under and under[0])
+            release(store)
+
+        monkeypatch.setattr(OnPolicyStore, "release", release_seen)
+        make, place = svc._make_feed, svc._assemble_device
+
+        def placing(raws):
+            # the queue holds the placed and untaken; this one is on its way
+            # (the feeder starts with the pipeline: its first may come first)
+            queued = 0 if seen["feed"] is None else seen["feed"].qsize()
+            seen["most"] = max(seen["most"], queued + 1)
+            return place(raws)
+
+        def feed_seen(*a):
+            svc._assemble_device = placing
+            seen["feed"] = make(*a)
+            return seen["feed"]
+
+        svc._make_feed = feed_seen
+
+    svc, cfg = _run_learner(
+        tmp_path, 29811 + 4 * chain, n_updates=n_updates, produce=produce,
+        prepare=prepare, learner_chain=chain, learner_prefetch=depth,
+    )
+    # the first dispatch compiles: the feed gets ahead and has to wait
+    assert seen["most"] == depth
+    feeder = sorted(_lanes_of(tmp_path / "run")["feeder"], key=lambda e: e["ts"])
+    order = [e["name"] for e in feeder if e["name"] not in ("store-empty", "h2d-put")]
+    assert "queue-slot" in order
+    cycle, i = ["fetch"] * chain + ["assemble", "queue-put"], 0
+    while i < len(order):
+        i += order[i] == "queue-slot"  # at most one, in front of the fetch
+        if len(order) - i < len(cycle):
+            break  # the dispatch the stop cut short
+        assert order[i : i + len(cycle)] == cycle, (i, order[i : i + 6])
+        i += len(cycle)
+    for put in (e for e in feeder if e["name"] == "h2d-put"):
+        assert any(
+            a["name"] == "assemble" and a["ts"] <= put["ts"]
+            and put["ts"] + put["dur"] <= a["ts"] + a["dur"] + 1e-3
+            for a in feeder
+        )
+    counters = _counters(svc)
+    raws = svc.n_feed["leased" if chain == 1 else "copied"]
+    assert raws >= n_updates
+    if chain == 1:
+        assert counters["learner-feed-leased"] == raws and counters["learner-feed-copied"] == 0
+        # every lease but the one the feeder held on its way out
+        assert seen["released_under"].count("queue-put") >= raws - 1
+        assert set(seen["released_under"]) <= {"queue-put", None}
+    else:
+        assert counters["learner-feed-copied"] == raws and counters["learner-feed-leased"] == 0
+        # consume() is lease, copy, release: all of it inside the fetch
+        assert set(seen["released_under"]) == {"fetch"}
+    said = f"{raws} batches leased, 0 copied" if chain == 1 else f"0 batches leased, {raws} copied"
+    closing = [ln for ln in capsys.readouterr().out.splitlines() if "logged updates" in ln]
+    assert len(closing) == 1 and closing[0].endswith(f"the feed took {said}")
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("run_ahead", [1, 3])
+def test_the_loop_asks_for_a_batch_only_with_room_on_the_chip(
+    tmp_path, monkeypatch, run_ahead
+):
+    """ISSUE 45: the loop goes ``RUN_AHEAD`` updates ahead of the chip and
+    no further — with as many in flight it waits for the oldest (``chip-wait``,
+    a span of its own in front of ``feed-wait``) before it takes the batch
+    that update would hold placed. Never between a log-sync and the next
+    dispatch: a sync leaves nothing in flight."""
+    from tpu_rl.runtime import learner_service
+
+    monkeypatch.setattr(learner_service, "RUN_AHEAD", run_ahead)
+    svc, cfg = _run_learner(
+        tmp_path, 29831 + 4 * run_ahead, n_updates=24, loss_log_interval=6,
+        model_save_interval=100,
+    )
+    main = sorted(_lanes_of(tmp_path / "run")["main"], key=lambda e: e["ts"])
+    assert all(a["ts"] + a["dur"] <= b["ts"] + 1e-3 for a, b in zip(main, main[1:]))
+    names = [e["name"] for e in main]
+    assert names.count("dispatch") == 24
+    waits = [i for i, n in enumerate(names) if n == "chip-wait"]
+    if run_ahead == 1:
+        assert waits  # a step of this size outlasts the loop's own statements
+    for i in waits:
+        assert names[i + 1] == "feed-wait", names[i : i + 3]
+        # what stands in front of it is an update in flight, not a sync
+        before = [n for n in names[:i] if n in ("dispatch", "log-sync")]
+        assert before[-run_ahead:] == ["dispatch"] * run_ahead, (i, before[-4:])
+
+
+@pytest.mark.timeout(300)
 def test_the_profiler_window_opens_one_capture(tmp_path):
     svc, cfg = _run_learner(
         tmp_path, 29741, n_updates=3 + 4 + 10, loss_log_interval=1,

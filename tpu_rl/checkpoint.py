@@ -342,12 +342,19 @@ class Checkpointer:
                 self._inflight = True
             t0 = time.perf_counter()
             try:
-                self._write(self._to_host(snap), idx, meta)
+                host_state = self._to_host(snap)
+                # The snapshot is device memory (a copy of the train state):
+                # let go of it once it is on the host — not after the write,
+                # and not when the next save rebinds the name: this frame
+                # would hold it until then, beside the next snapshot.
+                snap = None
+                self._write(host_state, idx, meta)
                 dur: float | None = time.perf_counter() - t0
             except Exception as e:  # surfaced on the next save()/flush()
                 dur = None
                 with self._cond:
                     self._error = e
+            snap = host_state = None
             with self._cond:
                 self._inflight = False
                 if dur is not None:

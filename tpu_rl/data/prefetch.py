@@ -16,7 +16,14 @@ so the NEXT dispatch's shm copy + H2D transfer runs concurrently with the
 CURRENT ``train_step``. The learner pops device-resident batches from a
 bounded queue (depth ~2: enough to hide feed latency, small enough to bound
 both device memory — depth x batch bytes — and on-policy staleness, which
-grows by at most ``depth`` batches relative to the synchronous feed).
+grows by at most ``depth`` batches relative to the synchronous feed). The
+feeder takes the queue's slot *before* it fetches and places a batch, so
+placed and not yet taken is at most ``depth``, the one in placement included:
+a batch that has to wait waits in the store, not on the chip.
+
+A store that hands batches over by lease (``OnPolicyStore.lease``: views of
+shared memory, nothing copied) gives the feed its ``release``; the feed calls
+it once the batch is placed and queued, and on its way out.
 
 Contract (all tested in ``tests/test_prefetch.py``):
 
@@ -39,9 +46,10 @@ the ``assemble`` callable the learner supplies, so the data layer keeps its
 "never imports jax" property (see ``tpu_rl/config.py``).
 
 Both feeds time their work with the spans of the ``TraceRecorder`` the learner
-hands them (lane ``feeder``: ``fetch``, ``store-empty``, ``assemble`` — which
-encloses the ``h2d-put`` the learner's ``assemble`` callable opens — and
-``queue-put``); ``feed_secs``, the ``learner-batching-time`` sample, is the sum
+hands them (lane ``feeder``: ``queue-slot`` while the queue is full,
+``fetch``, ``store-empty``, ``assemble`` — which encloses the ``h2d-put`` the
+learner's ``assemble`` callable opens — and ``queue-put``, which the release
+sits under); ``feed_secs``, the ``learner-batching-time`` sample, is the sum
 of the fetch and assemble spans of one dispatch. Without a recorder (the unit tests) a
 private one with no ring does the timing.
 """
@@ -113,10 +121,16 @@ class SynchronousFeed:
     poll_sleep = 0.002  # caller sleeps this on a None get (store starving)
 
     def __init__(
-        self, fetch: Callable, assemble: Callable, chain: int = 1, tracer=None
+        self,
+        fetch: Callable,
+        assemble: Callable,
+        chain: int = 1,
+        tracer=None,
+        release: Callable | None = None,
     ):
         self._fetch = fetch
         self._assemble = assemble
+        self._release = release
         self._chain = max(1, chain)
         self._span = _span_of(tracer)
         self._pending: list = []
@@ -137,6 +151,8 @@ class SynchronousFeed:
         with self._span("assemble", tid=LANE) as sp:
             batch = self._assemble(self._pending)
         self._pending = []
+        if self._release is not None:
+            self._release()
         secs, self._secs = self._secs + sp.secs, 0.0
         return batch, secs
 
@@ -168,16 +184,21 @@ class PrefetchPipeline:
         idle_sleep: float = 0.002,
         name: str = "learner-prefetch",
         tracer=None,
+        release: Callable | None = None,
     ):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
         self._fetch = fetch
         self._assemble = assemble
+        self._release = release
         self._chain = max(1, chain)
         self._span = _span_of(tracer)
         self._stop_event = stop_event
         self._idle_sleep = idle_sleep
-        self._q: queue.Queue = queue.Queue(maxsize=depth)
+        # The queue's bound is its slots: the feeder takes one before it
+        # builds a dispatch, get() gives it back.
+        self._slots = threading.Semaphore(depth)
+        self._q: queue.Queue = queue.Queue()
         self._error: BaseException | None = None
         self._closed = threading.Event()
         self._dispatched = 0  # dispatch batches handed to the learner
@@ -190,13 +211,28 @@ class PrefetchPipeline:
             self._stop_event is not None and self._stop_event.is_set()
         )
 
+    def _take_slot(self) -> bool:
+        """Room in the queue for the dispatch about to be built; False when
+        stopped first. A full queue must never deadlock shutdown."""
+        if self._slots.acquire(blocking=False):
+            return True
+        with self._span("queue-slot", tid=LANE):
+            while not self._stopped():
+                if self._slots.acquire(timeout=0.05):
+                    return True
+        return False
+
     def _run(self) -> None:
         span = self._span
         pending: list = []
         feed_secs = 0.0
         empty = None  # the open store-empty span while polls come back empty
+        slot = False  # a queue slot is held for the dispatch being built
         try:
             while not self._stopped():
+                if not slot:
+                    slot = self._take_slot()
+                    continue
                 with span("fetch", tid=LANE) as sp:
                     raw = self._fetch()
                     sp.keep = raw is not None
@@ -219,21 +255,19 @@ class PrefetchPipeline:
                 with span("assemble", tid=LANE) as sp:
                     batch = self._assemble(pending)
                 pending = []
-                item = (batch, feed_secs + sp.secs)
-                feed_secs = 0.0
-                # stop-aware put: a full queue must never deadlock shutdown
                 with span("queue-put", tid=LANE):
-                    while not self._stopped():
-                        try:
-                            self._q.put(item, timeout=0.05)
-                            break
-                        except queue.Full:
-                            continue
+                    self._q.put((batch, feed_secs + sp.secs))
+                    slot = False
+                    if self._release is not None:
+                        self._release()
+                feed_secs = 0.0
         except BaseException as e:  # noqa: BLE001 — re-raised in the learner
             self._error = e
         finally:
             if empty is not None:
                 empty.__exit__(None, None, None)
+            if self._release is not None:
+                self._release()  # a lease never outlives the feeder
 
     # ------------------------------------------------------------ consumer
     def get(self, timeout: float = 0.05):
@@ -247,6 +281,7 @@ class PrefetchPipeline:
             if self._error is not None:
                 raise self._error
             return None
+        self._slots.release()
         self._dispatched += 1
         return item
 

@@ -5,12 +5,17 @@ counter (``/root/reference/agents/storage_module/shared_batch.py:19-107``),
 re-designed around the two access patterns it conflates:
 
 - **OnPolicyStore** (capacity = ``batch_size``, reference
-  ``reset_shared_on_policy_memory``): single-writer fill, consume-all-and-reset
-  reader. The reference's reader resets the counter while the writer may be
-  mid-write (benign race, SURVEY.md §5.2); here the writer validates a
-  generation counter after finishing its slot write and re-writes into the new
-  generation if a consume intervened, so a consumed batch never contains a
-  torn or misplaced trajectory.
+  ``reset_shared_on_policy_memory``): single writer, single reader, two
+  generations of ``capacity`` slots. The writer fills one; full, it is sealed
+  and the writer moves to the other if that one is free. The reader *leases*
+  the oldest sealed generation — views of the shared memory, no copy, no lock
+  held while it reads — and releases it when it is done (``consume`` is lease,
+  copy, release). A writer never touches a sealed generation, so a handed-over
+  batch cannot be torn. The reference's reader resets the counter while the
+  writer may be mid-write (benign race, SURVEY.md §5.2); that race is left
+  only where ``consume(need=k)`` takes a generation the writer is still
+  filling, and there the writer validates an epoch counter after its slot
+  write and re-writes if a consume intervened.
 - **ReplayStore** (capacity = ``buffer_size``, reference
   ``reset_shared_buffer_memory``): ring overwrite + uniform sampling. The
   reference samples slots that are concurrently being overwritten
@@ -51,20 +56,31 @@ class ShmHandles:
     # (tpu_rl.obs.learn). Optional (default None) so handle pickles from
     # before this field keep constructing.
     vers: mp.Array | None = None
+    # OnPolicy: the arrays hold ``generations`` x ``capacity`` slots, and
+    # ``ring`` counts the generations ever (sealed, released). Defaults as
+    # ``vers``: a replay ring has one generation and reads no ``ring``.
+    generations: int = 1
+    ring: mp.Array | None = None
+
+
+SEALED, RELEASED = range(2)  # ShmHandles.ring
 
 
 def alloc_handles(
-    layout: BatchLayout, capacity: int, ctx=None
+    layout: BatchLayout, capacity: int, ctx=None, generations: int = 2
 ) -> ShmHandles:
     """Allocate from an explicit mp context — default spawn, matching the
     runner's start method (reference ``main.py:64``); fork-context primitives
-    cannot be passed into spawn children."""
+    cannot be passed into spawn children. ``capacity`` is one batch; an
+    on-policy store holds ``generations`` of them (the one being filled and
+    the one being handed over), a replay ring is allocated with 1."""
     ctx = ctx or mp.get_context("spawn")
+    rows = generations * capacity
     arrays = {
-        f: ctx.Array("f", capacity * layout.seq_len * layout.width(f), lock=False)
+        f: ctx.Array("f", rows * layout.seq_len * layout.width(f), lock=False)
         for f in BATCH_FIELDS
     }
-    vers = ctx.Array("q", capacity, lock=False)
+    vers = ctx.Array("q", rows, lock=False)
     np.frombuffer(vers, dtype=np.int64)[:] = -1  # -1 = version unknown
     return ShmHandles(
         arrays=arrays,
@@ -74,6 +90,8 @@ def alloc_handles(
         lock=ctx.Lock(),
         capacity=capacity,
         vers=vers,
+        generations=generations,
+        ring=ctx.Array("q", 2, lock=False),
     )
 
 
@@ -86,9 +104,12 @@ class _StoreBase:
         self.h = handles
         self.layout = layout
         self.capacity = handles.capacity
+        self.generations = handles.generations
         self.views = {
             f: np.frombuffer(handles.arrays[f], dtype=np.float32).reshape(
-                handles.capacity, layout.seq_len, layout.width(f)
+                self.generations * handles.capacity,
+                layout.seq_len,
+                layout.width(f),
             )
             for f in BATCH_FIELDS
         }
@@ -117,71 +138,90 @@ class _StoreBase:
 
 
 class OnPolicyStore(_StoreBase):
-    """Fill-then-consume batch store (single writer, single reader)."""
+    """Fill-then-hand-over batch store (single writer, single reader) of
+    ``generations`` x ``capacity`` slots: generation ``g`` is the rows
+    ``[g * capacity, (g + 1) * capacity)`` of every view. The writer fills
+    generation ``sealed % generations`` and seals it when it is full; the
+    reader takes generation ``released % generations`` once it is sealed.
+    ``sealed - released`` generations are with the reader or waiting for it,
+    so the writer is refused exactly when that is all of them."""
+
+    def __init__(self, handles: ShmHandles, layout: BatchLayout):
+        super().__init__(handles, layout)
+        if handles.ring is None:
+            raise ValueError(
+                "these handles carry no generation ring: allocate them with "
+                "alloc_handles"
+            )
+        self.ring = np.frombuffer(handles.ring, dtype=np.int64)
+        self._lease: int | None = None  # the generation this instance holds
+
+    def _rows(self, gen: int, lo: int, hi: int) -> slice:
+        return slice(gen * self.capacity + lo, gen * self.capacity + hi)
 
     # ---------------------------------------------------------------- writer
-    # put() retry bound: a consume can reset the store mid-write, forcing a
-    # re-write into the new generation; each retry needs a fresh consume to
-    # intervene (which itself needs a full store), so in practice one retry
-    # suffices. The cap makes the no-livelock contract explicit.
+    # put() retry bound: a partial consume can reset the filling generation
+    # mid-write, forcing a re-write; each retry needs a fresh consume to
+    # intervene, so in practice one retry suffices. The cap makes the
+    # no-livelock contract explicit.
     MAX_PUT_RETRIES = 8
+
+    def _claim(self) -> tuple[int, int, int] | None:
+        """(epoch, filling generation, next free slot), or None when every
+        generation is sealed: with the reader, or waiting for it."""
+        h, ring = self.h, self.ring
+        with h.lock:
+            if ring[SEALED] - ring[RELEASED] >= self.generations:
+                return None
+            return h.gen.value, int(ring[SEALED] % self.generations), h.count.value
+
+    def _publish(self, epoch: int, filled: int) -> bool:
+        """Make the slots below ``filled`` the reader's, and seal the
+        generation once that is all of them. False if a partial consume took
+        the generation meanwhile: the write has to be made again."""
+        h = self.h
+        with h.lock:
+            if h.gen.value != epoch:
+                return False
+            if filled == self.capacity:
+                self.ring[SEALED] += 1
+                filled = 0
+            h.count.value = filled
+            return True
 
     def put(self, window: dict, ver: int = -1) -> bool:
         """Write one (seq, width)-per-field trajectory window. Returns False
-        when the current generation is full (caller drops or retries later,
-        matching the reference's ``num < mem_size`` guard,
-        ``learner_storage.py:139``) or — bounded-retry contract — when
-        consumes keep invalidating the write ``MAX_PUT_RETRIES`` times.
-        ``ver`` is the window's policy-version sidecar (-1 = unknown)."""
-        h = self.h
-        for _ in range(self.MAX_PUT_RETRIES):
-            with h.lock:
-                gen, slot = h.gen.value, h.count.value
-                if slot >= self.capacity:
-                    return False
-            self._write_slot(slot, window)
-            self._write_vers(slice(slot, slot + 1), [ver], 0, 1)
-            with h.lock:
-                if h.gen.value == gen:
-                    # No consume intervened: publish the slot.
-                    h.count.value = slot + 1
-                    return True
-            # A consume reset the store mid-write; re-write into the new
-            # generation (this is the race the reference ignores).
-        return False
+        when no generation is free (caller drops or retries later, matching
+        the reference's ``num < mem_size`` guard, ``learner_storage.py:139``)
+        or — bounded-retry contract — when partial consumes keep invalidating
+        the write ``MAX_PUT_RETRIES`` times. ``ver`` is the window's
+        policy-version sidecar (-1 = unknown)."""
+        return self.put_many([window], [ver]) == 1
 
     def put_many(self, windows: list[dict], vers: list | None = None) -> int:
-        """Write a burst of trajectory windows with one contiguous slice
-        write per field per generation (vs one slot write per window via
-        :meth:`put`). Returns how many were accepted — the tail past a full
+        """Write a burst of trajectory windows, each once, into its slot.
+        Returns how many were accepted — the tail past the last free
         generation is rejected, preserving window order, so callers requeue
         ``windows[accepted:]`` exactly as they would a single rejected put.
         ``vers`` (aligned with ``windows``) stamps each slot's
         policy-version sidecar."""
-        if not windows:
-            return 0
-        h = self.h
         written = 0
         while written < len(windows):
             for _ in range(self.MAX_PUT_RETRIES):
-                with h.lock:
-                    gen, slot = h.gen.value, h.count.value
-                    if slot >= self.capacity:
-                        return written
+                claim = self._claim()
+                if claim is None:
+                    return written
+                epoch, gen, slot = claim
                 k = min(len(windows) - written, self.capacity - slot)
-                chunk = windows[written : written + k]
-                for f in BATCH_FIELDS:
-                    # One slice write per field: numpy stacks the k windows'
-                    # (seq, width) arrays straight into the shm view.
-                    self.views[f][slot : slot + k] = [w[f] for w in chunk]
-                self._write_vers(slice(slot, slot + k), vers, written, k)
-                with h.lock:
-                    if h.gen.value == gen:
-                        h.count.value = slot + k
-                        written += k
-                        break
-                # Consume intervened mid-burst: re-write into the new
-                # generation (same retry contract as put()).
+                base = gen * self.capacity + slot
+                for i in range(k):
+                    self._write_slot(base + i, windows[written + i])
+                self._write_vers(slice(base, base + k), vers, written, k)
+                if self._publish(epoch, slot + k):
+                    written += k
+                    break
+                # A partial consume reset the generation mid-burst: re-write
+                # (this is the race the reference ignores).
             else:
                 return written
         return written
@@ -189,25 +229,68 @@ class OnPolicyStore(_StoreBase):
     # ---------------------------------------------------------------- reader
     @property
     def size(self) -> int:
+        """Windows written and not yet released: the sealed generations (a
+        leased one among them) and the filled part of the one being filled."""
+        h, ring = self.h, self.ring
+        with h.lock:
+            return int(ring[SEALED] - ring[RELEASED]) * self.capacity + h.count.value
+
+    def lease(self) -> dict[str, np.ndarray] | None:
+        """The oldest sealed generation as ``field -> (capacity, seq, width)``
+        *views* of the shared memory plus the ``ver`` sidecar's, or None when
+        none is sealed. No copy, and no lock while the caller reads: the
+        writer stays out of the generation until :meth:`release`. One lease
+        at a time; a reader that died with one leaves the generation sealed,
+        and the next reader's lease hands it out again."""
+        if self._lease is not None:
+            raise RuntimeError("lease() with the previous lease still out")
+        h, ring = self.h, self.ring
+        with h.lock:
+            if ring[SEALED] == ring[RELEASED]:
+                return None
+            self._lease = int(ring[RELEASED] % self.generations)
+        rows = self._rows(self._lease, 0, self.capacity)
+        out = {f: self.views[f][rows] for f in BATCH_FIELDS}
+        if self.slot_vers is not None:
+            # Staleness sidecar: per-row policy version, a NON-batch key
+            # (Batch.from_mapping keys off BATCH_FIELDS and drops it).
+            out["ver"] = self.slot_vers[rows]
+        return out
+
+    def release(self) -> None:
+        """Give the leased generation back to the writer. Without a lease
+        out, nothing: a feed releases on its way out whatever it held."""
+        if self._lease is None:
+            return
         with self.h.lock:
-            return self.h.count.value
+            self.ring[RELEASED] += 1
+        self._lease = None
 
     def consume(self, need: int | None = None) -> dict[str, np.ndarray] | None:
         """If at least ``need`` (default: capacity) trajectories are ready,
-        copy them out, reset the store, and return ``field -> (n, seq, width)``
-        arrays; else None (reference gate ``sh_data_num >= batch_size`` +
-        ``reset_data_num``, ``agents/learner.py:250-262``)."""
+        copy them out, reset their generation, and return ``field -> (n, seq,
+        width)`` arrays; else None (reference gate ``sh_data_num >=
+        batch_size`` + ``reset_data_num``, ``agents/learner.py:250-262``).
+        A sealed generation goes first, whole, copied outside the lock; with
+        none sealed and ``need`` below capacity, the filled part of the
+        generation the writer is in."""
         need = self.capacity if need is None else need
+        leased = self.lease()
+        if leased is not None:
+            out = {k: v.copy() for k, v in leased.items()}
+            self.release()
+            return out
         h = self.h
         with h.lock:
+            if self.ring[SEALED] != self.ring[RELEASED]:
+                return None  # sealed since the look above: the next call's
             n = h.count.value
             if n < need:
                 return None
-            out = self._read_slots(slice(0, n))
+            rows = self._rows(int(self.ring[SEALED] % self.generations), 0, n)
+            out = self._read_slots(rows)
             if self.slot_vers is not None:
-                # Staleness sidecar: per-row policy version, a NON-batch key
-                # (Batch.from_mapping keys off BATCH_FIELDS and drops it).
-                out["ver"] = self.slot_vers[:n].copy()
+                out["ver"] = self.slot_vers[rows].copy()
             h.gen.value += 1
             h.count.value = 0
         return out
@@ -334,6 +417,8 @@ def make_store(cfg, layout: BatchLayout, handles: ShmHandles | None = None):
     off_policy = is_off_policy(cfg.algo)
     capacity = cfg.buffer_size if off_policy else cfg.batch_size
     if handles is None:
-        handles = alloc_handles(layout, capacity)
+        handles = alloc_handles(
+            layout, capacity, generations=1 if off_policy else 2
+        )
     cls = ReplayStore if off_policy else OnPolicyStore
     return cls(handles, layout)

@@ -36,6 +36,7 @@ import functools
 import os
 import threading
 import time
+from collections import deque
 
 import numpy as np
 
@@ -63,6 +64,15 @@ from tpu_rl.runtime.protocol import Protocol, fits_frame
 from tpu_rl.runtime.transport import MODEL_HWM, Pub, make_data_pub
 from tpu_rl.utils.metrics import LearnerLogger, make_writer
 from tpu_rl.utils.timer import ExecutionTimer
+
+
+# Updates dispatched and not yet finished that the loop allows itself, the one
+# the chip is running included: it asks the feed for the next batch only once
+# fewer are in flight. One running and two behind it keep the chip fed over
+# any stall of this thread short of two updates; every update further ahead
+# would only hold one more placed batch in device memory for as long — a
+# batch the feed can keep, at no cost, in the store it leases from.
+RUN_AHEAD = 3
 
 
 def _crossed(prev: int, cur: int, interval: int) -> bool:
@@ -284,6 +294,12 @@ class LearnerService:
         # by what the loop saw there.
         self.n_log_behind_dispatch = 0
         self.n_log_inline = {"save": 0, "stop": 0, "empty feed": 0}
+        # Raw batches by how the store handed them to the feed: leased (views
+        # of shared memory, placed from where they lie) or copied out. The
+        # feed leases where it holds one raw batch at a time: on-policy,
+        # unchained.
+        self.n_feed = {"leased": 0, "copied": 0}
+        self._leased = False
 
     # ------------------------------------------------------------------ run
     def run(self) -> None:
@@ -700,6 +716,9 @@ class LearnerService:
             # it: the books are set aside at the crossing and closed right
             # after that dispatch has been issued, while the chip works.
             books = None
+            # One small output of each dispatched update that may not have
+            # finished yet, oldest first (RUN_AHEAD).
+            ahead: deque = deque()
 
             def _close_books(cause: str | None = None) -> str | None:
                 """Everything a logged update's read-back owes besides the
@@ -792,6 +811,7 @@ class LearnerService:
                     if rolled is None:
                         return None
                     state, idx, key = rolled
+                    ahead.clear()
                     nf_acc = b_nf
                     if diag_acc is not None:
                         diag_acc.take()
@@ -818,6 +838,17 @@ class LearnerService:
                     and idx - start_idx + chain > self.max_updates
                 ):
                     break
+                # As far ahead of the chip as the loop goes (RUN_AHEAD): wait
+                # for the oldest update in flight before asking for a batch
+                # the chip cannot reach yet. The chip is at work meanwhile.
+                while ahead and ahead[0].is_ready():
+                    ahead.popleft()
+                chip_secs = 0.0
+                if len(ahead) >= RUN_AHEAD:
+                    with span("chip-wait", bucket=COMPUTE) as sp_chip:
+                        while len(ahead) >= RUN_AHEAD:
+                            jax.block_until_ready(ahead.popleft())
+                    chip_secs = sp_chip.secs
                 # Idle polls (store starving, or the update-ratio gate
                 # holding) stay OUTSIDE the throughput timer: they process
                 # zero transitions and must not deflate the learner-FPS
@@ -894,6 +925,11 @@ class LearnerService:
                     timer="learner-step-time", bucket=COMPUTE,
                 ) as sp_step:
                     state, metrics = train_step(state, batch, sub_key)
+                    ahead.append(jax.tree.leaves(metrics)[0])
+                    # The update holds its batch for as long as it needs it:
+                    # these names would hold it until the next pop, past the
+                    # update's end and beside a checkpoint's snapshot.
+                    batch = item = None
                     if self._perf is not None and self._perf.recompiles > rc0:
                         # A dispatch that retraced spent its span in XLA, not
                         # in useful device math — divert it out of compute.
@@ -928,13 +964,14 @@ class LearnerService:
                             self._actor_snapshot(state), version=idx + chain
                         )
                 with span("account"):
-                    # The dispatch critical path — queue-wait + step, the
-                    # throughput window — drives achieved FLOPs/s too.
+                    # The dispatch critical path — chip-wait + queue-wait +
+                    # step, the throughput window — drives achieved FLOPs/s
+                    # too.
                     # learner-batching-time is the feed-side host work (shm
                     # copies + assembly + H2D placement); with prefetch it
                     # overlaps the device step, and overlap shows as
                     # queue-wait << batching-time.
-                    critical_secs = sp_wait.secs + sp_step.secs
+                    critical_secs = chip_secs + sp_wait.secs + sp_step.secs
                     if self._perf is not None:
                         self._perf.note(critical_secs)
                     timer.record("learner-batching-time", feed_secs)
@@ -1052,6 +1089,7 @@ class LearnerService:
                         # where no batch is ready: a line is never held for
                         # the feed.
                         jax.block_until_ready(metrics)
+                        ahead.clear()
                         taken = diag_acc.take() if diag_acc is not None else None
                         if save_due:
                             cause = "save"
@@ -1099,7 +1137,9 @@ class LearnerService:
                 f"[learner] logged updates: {self.n_log_behind_dispatch} "
                 f"closed behind the next dispatch, {sum(inline.values())} in "
                 f"line ({inline['save']} save due, {inline['stop']} stopping, "
-                f"{inline['empty feed']} feed empty)", flush=True,
+                f"{inline['empty feed']} feed empty); the feed took "
+                f"{self.n_feed['leased']} batches leased, "
+                f"{self.n_feed['copied']} copied", flush=True,
             )
         finally:
             # Feeder first (stops shm sampling), then the publisher (joins
@@ -1149,13 +1189,20 @@ class LearnerService:
     def _next_batch(self, store, rng) -> dict | None:
         if is_off_policy(self.cfg.algo):
             return store.sample(self.cfg.batch_size, rng)
-        return store.consume()
+        return store.lease() if self._leased else store.consume()
 
-    def _make_fetch(self, store, rng):
+    def _make_fetch(self, store, rng, chain: int = 1):
         """Raw-batch producer for the feed, with the off-policy update:data
         ratio gate folded in. The gate counts batches at FETCH time (not at
         update completion) so the prefetch pipeline cannot overdraw the data
-        budget by pre-pulling samples the learner has not yet earned."""
+        budget by pre-pulling samples the learner has not yet earned.
+
+        An on-policy feed that holds one raw batch at a time (``chain`` 1)
+        leases it from the store and places it from the shared memory; a
+        chained dispatch holds ``chain`` of them at once, more than the store
+        has generations, and a replay sample is a gather: both are copies."""
+        self._leased = chain == 1 and not is_off_policy(self.cfg.algo)
+        taken = "leased" if self._leased else "copied"
         gate = None
         if (
             is_off_policy(self.cfg.algo)
@@ -1170,8 +1217,10 @@ class LearnerService:
             ):
                 return None
             raw = self._next_batch(store, rng)
-            if raw is not None and gate is not None:
-                gate.note_fetched()
+            if raw is not None:
+                self.n_feed[taken] += 1
+                if gate is not None:
+                    gate.note_fetched()
             return raw
 
         return fetch
@@ -1182,7 +1231,8 @@ class LearnerService:
         Both produce identical batches in identical order — the sampler RNG
         and the chain accumulation live in the shared fetch/assemble
         closures — so the A/B switch changes timing only."""
-        fetch = self._make_fetch(store, rng)
+        fetch = self._make_fetch(store, rng, chain)
+        release = store.release if self._leased else None
         if self.cfg.learner_prefetch > 0:
             return PrefetchPipeline(
                 fetch,
@@ -1191,9 +1241,14 @@ class LearnerService:
                 depth=self.cfg.learner_prefetch,
                 stop_event=self.stop_event,
                 tracer=self._tracer,
+                release=release,
             )
         return SynchronousFeed(
-            fetch, self._assemble_device, chain=chain, tracer=self._tracer
+            fetch,
+            self._assemble_device,
+            chain=chain,
+            tracer=self._tracer,
+            release=release,
         )
 
     def _assemble_device(self, raws: list):
@@ -1202,19 +1257,29 @@ class LearnerService:
         instead of inside the jitted call's implicit transfer. Runs on the
         feeder thread under prefetch, inside the feed's ``assemble`` span
         (``data/prefetch.py``); the placement is its ``h2d-put`` child, so
-        the overlap with the main lane's dispatch is visible."""
+        the overlap with the main lane's dispatch is visible.
+
+        A leased batch is views of the store's shared memory, which the
+        writer refills once the feed releases the lease: ``h2d-put`` then
+        ends when the transfer does, not when it is issued, and on a backend
+        whose device memory is the host's (the CPU's, where a placed array
+        may alias the buffer it came from) the batch is placed from a copy."""
         import jax
 
+        if self._leased and self._device.platform == "cpu":
+            raws = [{k: np.array(v) for k, v in r.items()} for r in raws]
         self._pop_vers(raws)
         batch = self._assemble(raws)
         if self._place_global is not None or self._chain_mesh is not None:
             # Already placed during assembly: host_local_batch_to_global /
             # shard_chained_batch both produce global device arrays.
-            return batch
+            return jax.block_until_ready(batch) if self._leased else batch
         with self._tracer.span("h2d-put", tid="feeder"):
-            if self._batch_sharding is not None:
-                return jax.device_put(batch, self._batch_sharding)
-            return jax.device_put(batch, self._device)
+            sharded = self._batch_sharding is not None
+            placed = jax.device_put(
+                batch, self._batch_sharding if sharded else self._device
+            )
+            return jax.block_until_ready(placed) if self._leased else placed
 
     def _pop_vers(self, raws: list) -> None:
         """Detach each raw batch's ``"ver"`` staleness sidecar (a non-batch
@@ -1253,12 +1318,13 @@ class LearnerService:
             return Batch(
                 **host_local_batch_to_global(raw, self._place_global)
             )
-        if self._batch_sharding is not None:
+        if self._batch_sharding is not None or self._leased:
             # Stays on the host: ``_assemble_device`` sends each chip its
             # rows. Through ``jnp.asarray`` the whole batch would land on
             # chip 0 first and be cut up there by slicing programs that
             # queue behind every update already dispatched, so chip 0 would
-            # hold one whole batch more per update the loop is ahead by.
+            # hold one whole batch more per update the loop is ahead by. A
+            # leased batch is placed there too, under ``h2d-put``.
             return Batch(**{k: np.asarray(raw[k]) for k in BATCH_FIELDS})
         return Batch.from_mapping(raw)
 
@@ -1514,6 +1580,9 @@ class LearnerService:
         reg.counter("learner-log-inline").set_total(
             sum(self.n_log_inline.values())
         )
+        # Raw batches the store handed the feed by lease and by copy.
+        reg.counter("learner-feed-leased").set_total(self.n_feed["leased"])
+        reg.counter("learner-feed-copied").set_total(self.n_feed["copied"])
         perf = self._perf
         if perf is not None:
             # Performance plane: analytical FLOPs per dispatch, achieved

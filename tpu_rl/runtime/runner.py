@@ -429,8 +429,11 @@ def learner_role(
     layout = BatchLayout.from_config(cfg)
     from tpu_rl.config import is_off_policy
 
-    capacity = cfg.buffer_size if is_off_policy(cfg.algo) else cfg.batch_size
-    handles = alloc_handles(layout, capacity, ctx=sup.ctx)
+    off_policy = is_off_policy(cfg.algo)
+    capacity = cfg.buffer_size if off_policy else cfg.batch_size
+    handles = alloc_handles(
+        layout, capacity, ctx=sup.ctx, generations=1 if off_policy else 2
+    )
     stat_array = sup.ctx.Array("f", STAT_SLOTS, lock=False)
 
     sup.spawn(

@@ -812,8 +812,9 @@ class LearnerStorage:
         accepted = store.put_many(windows, vers=vers)
         self.n_windows += accepted
         if accepted < len(windows):
-            # On-policy store full: the learner hasn't consumed yet. Requeue
-            # the rejected tail in order and yield (reference spins on
+            # On-policy store full: both generations are the learner's (one
+            # leased or waiting, the other sealed). Requeue the rejected
+            # tail in order and yield (reference spins on
             # ``num < mem_size``, ``learner_storage.py:139``).
             assembler.requeue(
                 windows[accepted:],
