@@ -406,6 +406,12 @@ GLM4_MOE_LITE_MIXER = dict(
 # The widths of LFM2-24B-A2B's gated short convolution as published
 # (benchmarks/configs/lfm2-24b-a2b.json holds the whole configuration).
 LFM2_MOE_MIXER = dict(hidden_size=2048, conv_L_cache=3)
+# The widths of EvaByte's EVA attention as published
+# (benchmarks/configs/evabyte.json holds the whole configuration).
+EVABYTE_MIXER = dict(
+    hidden_size=4096, num_attention_heads=32, window_size=2048, chunk_size=16, rope_theta=100000,
+    init_std=0.01275,
+)
 
 
 def kernel_checks(
@@ -502,6 +508,19 @@ def kernel_checks(
     # layer (heads of 64: the three gradients leave the kernel head-major);
     # run last, so that the rows above keep the inputs they were drawn
     lfm2_attn_bwd_shapes=((8192, 32, 8, 64, 64**-0.5, None, 2048, None),),
+    # (row, B, T): evabyte's EVA attention at ``evabyte_widths``. "mixer": the
+    # training form at the cell's window (projections, rotation, the chunk
+    # pooling, the kernels over the blocks' exact keys with the logsumexp out,
+    # the summaries' read, the merge, o_proj), forward and every gradient
+    # against benchmarks/reference/evabyte.py (a summary row for every step,
+    # every query against [K ; all rows] under the mask from the definitions,
+    # 256 queries at a time). "pool": the gather and the two poolings alone
+    # against the reference's shifted products at the chunks' last steps, both
+    # timed. "step": the acting form stepped over its exact ring and summary
+    # store, across a block boundary, against the training form. Run last, so
+    # that the rows above keep the inputs they were drawn
+    evabyte_shapes=(("mixer", 1, 16384), ("pool", 1, 16384), ("step", 1, 4096)),
+    evabyte_widths=EVABYTE_MIXER,
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -1115,6 +1134,114 @@ def kernel_checks(
             TOL_MIXER_BF16, TOL_MIXER_BF16, mosaic=False,
         )
     attn_bwd_rows(lfm2_attn_bwd_shapes, "lfm2_moe ")
+
+    # ---- evabyte's EVA attention: the training form in bf16 vs the plain float32
+    # reference, the pooling alone, and the acting form vs the training form
+    from benchmarks.reference import evabyte as plain_eva
+    from tpu_rl.models.evabyte import EvaAttention, episode_grid
+
+    ew = evabyte_widths
+    hidden, n_heads, W, C = (ew[k] for k in (
+        "hidden_size", "num_attention_heads", "window_size", "chunk_size"))
+    eva_arch = dict(ew, num_key_value_heads=n_heads)
+    for row, B, T in evabyte_shapes:
+        mixer = EvaAttention(
+            hidden=hidden, heads=n_heads, block=W, chunk=C, rope_theta=float(ew["rope_theta"]),
+            init_std=ew["init_std"], dtype=jnp.bfloat16)
+        u = f32(B, T, hidden)
+        firsts = rng.random((B, T)) < 1.0 / T  # ~1 episode seam a window, as the cell's mix
+        firsts[:, T // 3 + 5] = True  # off every grid line of the window, inside a chunk
+        firsts[1:] = firsts[:1]  # the rows of a stepped batch start their episodes together
+        seg = jnp.asarray(np.cumsum(firsts, axis=1).astype(np.int32))
+        first = jnp.asarray(firsts)
+        params = jax.jit(lambda key: mixer.init(key, u[:, :8], seg[:, :8])["params"])(
+            jax.random.key(SEED))
+        seams = int(firsts[0].sum())
+        if row == "mixer":
+            w_y = f32(B, T, hidden)
+
+            def system(p, u):
+                y = mixer.apply({"params": p}, u, seg, interpret and T % 128 == 0)
+                return (y * w_y).sum(), y
+
+            def reference(p, u):
+                # eight heads at a time: o_proj sums over heads, and all 32 at once need
+                # 14.5 GiB for the gradient at T 16,384 (compiled for a described v5e)
+                y, D, G = 0.0, hidden // n_heads, min(8, n_heads)
+                for g in range(0, n_heads, G):
+                    cols = slice(g * D, (g + G) * D)
+                    part = {
+                        "pool_k": p["pool_k"][g:g + G], "pool_v": p["pool_v"][g:g + G],
+                        **{n: {"kernel": p[n]["kernel"][:, cols]}
+                           for n in ("q_proj", "k_proj", "v_proj")},
+                        "o_proj": {"kernel": p["o_proj"]["kernel"][cols]}}
+                    y = y + plain_eva.eva_attention(
+                        u, first, part, dict(eva_arch, hidden_size=G * D, num_attention_heads=G))
+                return (y * w_y).sum(), y
+
+            # the output and every gradient, not the loss itself: a sum of 67M signed
+            # terms cancels to where bf16's rounding of y is a tenth of it (PERF.md, PR 46)
+            case(
+                f"evabyte eva mixer fwd+bwd B{B}/T{T} bf16 vs the plain reference ({seams} seams)",
+                jax.grad(system, argnums=(0, 1), has_aux=True),
+                jax.grad(reference, argnums=(0, 1), has_aux=True),
+                (params, u), TOL_MIXER_BF16, TOL_MIXER_BF16,
+            )
+            continue
+        if row == "pool":
+            D = hidden // n_heads
+            k, v = (f32(B, T, n_heads, D).astype(jnp.bfloat16) for _ in range(2))
+            _, blk, _, ends = episode_grid(seg, W, C)
+            last = [np.flatnonzero(np.asarray(e)) for e in ends]
+            w_k, w_v = f32(B, T // C, n_heads, D), f32(B, T // C, n_heads, D)
+
+            def pool_system(p, k, v):
+                ks, vs, _, _ = mixer.apply({"params": p}, k, v, seg, blk, ends, method="summaries")
+                ks, vs = ks.astype(jnp.float32), vs.astype(jnp.float32)
+                return (ks * w_k).sum() + (vs * w_v).sum(), (ks, vs)
+
+            def pool_reference(p, k, v):
+                k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+                pooled = [plain_eva.pooled_rows(k, x, p[w], C, D ** -0.5)
+                          for x, w in ((k, "pool_k"), (v, "pool_v"))]
+                ks, vs = (jnp.stack([
+                    jnp.zeros((T // C, n_heads, D)).at[: len(at)].set(x[b][at])
+                    for b, at in enumerate(last)]) for x in pooled)
+                return (ks * w_k).sum() + (vs * w_v).sum(), (ks, vs)
+
+            case(
+                f"evabyte eva pool fwd+bwd B{B}/T{T}/H{n_heads}x{D}/C{C} bf16 vs shifted products "
+                f"({len(last[0])} complete chunks of {T // C}, {seams} seams)",
+                jax.value_and_grad(pool_system, argnums=(0, 1, 2), has_aux=True),
+                jax.value_and_grad(pool_reference, argnums=(0, 1, 2), has_aux=True),
+                (params, k, v), TOL_BF16, TOL_BF16, mosaic=False, timed=True,
+            )
+            continue
+
+        def stepped(p, u):
+            """``step`` over the window, the ring, the store and the counter
+            zeroed at episode starts as the worker zeroes the carry."""
+            D = hidden // n_heads
+            exact, pooled = jnp.zeros((B, W, n_heads, D)), jnp.zeros((B, T // C, n_heads, D))
+
+            def one(carry, at):
+                u_t, first_t = at
+                *carry, count = jax.tree.map(lambda x: jnp.where(first_t, 0, x), carry)
+                y, *carry = mixer.apply({"params": p}, u_t, *carry, count, method="step")
+                return (*carry, count + 1), y
+
+            _, y = jax.lax.scan(
+                one, (exact, exact, pooled, pooled, jnp.zeros((B,), jnp.int32)),
+                (u.swapaxes(0, 1), first[0]))
+            return y.swapaxes(0, 1)
+
+        case(
+            f"evabyte step B{B}/T{T} bf16 over a ring of {W} and {T // C} summaries vs the unroll "
+            f"({seams} seams, {T // W} blocks)",
+            stepped, lambda p, u: mixer.apply({"params": p}, u, seg), (params, u),
+            TOL_MIXER_BF16, TOL_MIXER_BF16, mosaic=False,
+            ref_is_kernel=jax.default_backend() == "tpu",
+        )
     return rows
 
 

@@ -624,7 +624,11 @@ TRANSFORMER = dict(algo="PPO", model="transformer", hidden_size=64, n_heads=4, n
 # the parent commit (4aa4e3c) before any model file moved; the five above it
 # were not edited. PR 43: ``lfm2_moe``'s own joins them as that PR left it; the
 # six above it hold through the trunk's new statement of a carry
-# (``backbone.tail``) and ``GQAttention.qk_norm_zero_centered``.
+# (``backbone.tail``) and ``GQAttention.qk_norm_zero_centered``. PR 46:
+# ``evabyte``'s own joins them as that PR left it; the seven above it hold
+# through the logsumexp output of the rows' walk (``flash_attention_lse``: a new
+# caller's path), ``unroll_routed`` handing a family without experts its
+# records back and ``route_scalars`` skipping records without ``stats``.
 BEFORE = {
     "transformer": "8b9c8c0764ab242a3da73822e1ea003dae077495da61006398cca64864b2a7d9",
     "granite_hybrid": "17b496ea1eb174484e74ab740489fb01f84614423ea1b2bb87c5bfa2d17b5e29",
@@ -633,6 +637,7 @@ BEFORE = {
     "qwen3_next": "409273e605b448ff61c5f707fdc53a715cbabc46767dd103aad7ace85f409026",
     "glm4_moe_lite": "8adba8ef85184cacb1a698214cc811f36182bea9c6f1867eb6ac2884f75959dd",
     "lfm2_moe": "15b0774f9969df58a3b6f2286eed0dddc3c44289ea7c0775688cc38bd04dc8b5",
+    "evabyte": "95908026b1c884e12ef56869ece71f06df0285537fd8c053279cd71326e24d7b",
 }
 
 

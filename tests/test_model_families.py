@@ -32,6 +32,8 @@ TREES = {
     "glm4_moe_lite": "d40ba6cb6c1a42cef0f74c09ce8a7ebbef547a4c0512a3fb09c243032ce1d16d",
     # PR 43: the family's own, as the PR that brought it built it
     "lfm2_moe": "4be6e9e331cc53d1dba893b33df63e32f20a09ce00a80b923ff6dafd57a85513",
+    # PR 46: the family's own, as the PR that brought it built it
+    "evabyte": "be06c64d016d901adbae8feef1bfe3f2a3ec39fb9b31b66b142ea45c6d0c741a",
 }
 
 

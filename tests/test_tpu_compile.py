@@ -85,6 +85,55 @@ def test_the_attention_backward_compiles_at_the_cells_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes < rows * partials
 
 
+def test_the_two_resolution_read_compiles_at_evabytes_shapes(one_chip, monkeypatch):
+    """An EVA layer's two calls at 32 heads of 128 over 16,384 steps, forward
+    and backward, for a v5e: the rows' walk on the (episode, block) ids with
+    the logsumexp as an output (``flash_attention_lse``) and the summaries'
+    read on a (16,384, 1,024) rectangle with key-side ids of its own and a
+    reach a query (``summary_attention_lse``); both backwards are
+    ``attn_bwd_band``, the second over a key side of another length. The
+    program's platform is steered here, as a cell's compile test steers it."""
+    import types
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_rl.models import cells
+    from tpu_rl.parallel import sequence
+
+    monkeypatch.setattr(cells, "_program_devices", lambda: ("tpu", 1))
+    monkeypatch.setattr(
+        pltpu, "get_tpu_info", lambda: types.SimpleNamespace(vmem_capacity_bytes=128 * 2**20))
+    T, N, heads, D = 16384, 1024, 32, 128
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def grads(q, k, v, ks, vs, seg, seg_k, reach):
+        def loss(q, k, v, ks, vs):
+            pos = jnp.broadcast_to(jnp.arange(T), (1, T))
+            o_e, lse_e = sequence.flash_attention_lse(q, k, v, pos, seg, sm_scale=D ** -0.5)
+            o_s, lse_s = sequence.summary_attention_lse(q, ks, vs, seg, seg_k, reach, D ** -0.5)
+            both = o_e.astype(jnp.float32).sum() + o_s.astype(jnp.float32).sum()
+            return both + jnp.logaddexp(lse_e, lse_s).sum()
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, ks, vs)
+
+    step, summary = shaped((1, T, heads, D), jnp.bfloat16), shaped((1, N, heads, D), jnp.bfloat16)
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        lowered = jax.jit(grads).lower(
+            step, step, step, summary, summary, shaped((1, T), jnp.int32),
+            shaped((1, N), jnp.int32), shaped((1, T), jnp.int32))
+        text = lowered.as_text()
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+    assert text.count("tpu_custom_call") >= 4 and text.count("attn_bwd_band") >= 2
+    # the two step lists: the square band's 136 tiles, the rectangle's 16
+    assert "tensor<136xi32>" in text and "tensor<16xi32>" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
 def test_the_update_tail_is_one_pass_over_the_weights(one_chip):
     """The tail of the on-policy update as ``algos/ppo.py`` writes it — clip,
     guard, RMSprop, apply and ``learn_diag``'s norms — on a PPO ``TrainState``

@@ -331,6 +331,45 @@ def _check_lfm2_moe_arch(arch: dict | None) -> None:
     _check_expert_share(arch, arch["num_experts"], arch["num_experts_per_tok"])
 
 
+# The keys of an EvaByte (``evabyte``) ``config.json`` that shape the policy
+# core (``models/evabyte.py``).
+EVABYTE_ARCH_KEYS = (
+    "hidden_size", "num_hidden_layers", "num_attention_heads", "num_key_value_heads",
+    "window_size", "chunk_size", "intermediate_size", "rms_norm_eps", "rope_theta",
+    "norm_add_unit_offset", "init_std", "attention_class",
+)
+
+
+def _check_evabyte_arch(arch: dict | None) -> None:
+    """What ``model="evabyte"`` can build: every layer EVA attention — the
+    exact keys of the query's own ``window_size``-step block and one pooled
+    summary for every ``chunk_size``-step chunk of the blocks before it, one
+    softmax over both — at as many key/value heads as query heads, rotary
+    positions over the whole head, and a dense SwiGLU MLP, each behind a
+    ``1 + w`` RMSNorm; no bias anywhere, no rotary scaling."""
+    assert isinstance(arch, dict), "model='evabyte' needs arch (config.json keys)"
+    missing = [k for k in EVABYTE_ARCH_KEYS if k not in arch]
+    assert not missing, f"arch lacks {missing}"
+    assert arch["attention_class"] == "eva", f"attention_class {arch['attention_class']!r}"
+    assert arch["num_hidden_layers"] >= 1, arch["num_hidden_layers"]
+    heads, hidden = arch["num_attention_heads"], arch["hidden_size"]
+    assert arch["num_key_value_heads"] == heads, (
+        "EVA attention is multi-head: every head pools its own keys and values"
+    )
+    assert hidden % heads == 0 and arch.get("head_dim") in (None, hidden // heads), (
+        f"hidden_size {hidden} is not num_attention_heads {heads} x the head size"
+    )
+    assert hidden // heads % 2 == 0, "rotate-half pairs a head's two halves"
+    block, chunk = arch["window_size"], arch["chunk_size"]
+    assert chunk >= 1 and block >= chunk and block % chunk == 0, (
+        f"window_size {block} is no whole number of chunk_size {chunk} chunks"
+    )
+    assert arch["norm_add_unit_offset"], "the norms scale by 1 + w"
+    assert arch.get("rope_scaling") is None, "rotary scaling is not built"
+    assert arch.get("hidden_act", "silu") == "silu", arch.get("hidden_act")
+    assert not arch.get("attention_bias", False), "attention projections have no bias"
+
+
 # The families built from a published config.json in ``Config.arch``.
 ARCH_CHECKS = {
     "granite_hybrid": _check_granite_arch,
@@ -339,6 +378,7 @@ ARCH_CHECKS = {
     "qwen3_next": _check_qwen3_next_arch,
     "glm4_moe_lite": _check_glm4_moe_lite_arch,
     "lfm2_moe": _check_lfm2_moe_arch,
+    "evabyte": _check_evabyte_arch,
 }
 
 
@@ -373,7 +413,10 @@ class Config:
     # or "glm4_moe_lite" (multi-head latent attention, a leading dense layer,
     # then sparse experts with a shared one, of a GLM-4.7-Flash config.json)
     # or "lfm2_moe" (gated short convolutions around grouped-query attention,
-    # a leading dense layer, then sparse experts, of an LFM2-MoE config.json).
+    # a leading dense layer, then sparse experts, of an LFM2-MoE config.json)
+    # or "evabyte" (EVA attention — a block's exact keys and pooled summaries of
+    # the chunks before it under one softmax — and a dense SwiGLU MLP in every
+    # layer, of an EvaByte config.json).
     model: str = "lstm"
     n_heads: int = 4
     n_layers: int = 2
@@ -387,7 +430,8 @@ class Config:
     # names (model="granite_hybrid": GRANITE_ARCH_KEYS above; "nemotron_h":
     # NEMOTRON_ARCH_KEYS; "smallthinker": SMALLTHINKER_ARCH_KEYS;
     # "qwen3_next": QWEN3_NEXT_ARCH_KEYS; "glm4_moe_lite":
-    # GLM4_MOE_LITE_ARCH_KEYS; "lfm2_moe": LFM2_MOE_ARCH_KEYS). One
+    # GLM4_MOE_LITE_ARCH_KEYS; "lfm2_moe": LFM2_MOE_ARCH_KEYS; "evabyte":
+    # EVABYTE_ARCH_KEYS). One
     # mapping instead of a Config field per width: the widths of a catalog
     # model are its source's to name, not this file's.
     arch: dict | None = None

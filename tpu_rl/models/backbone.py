@@ -77,7 +77,7 @@ class Backbone(nn.Module):
     Layer = None  # the family's layer: Layer(arch, one of layer_args(arch), dtype)
     eps_key = "rms_norm_eps"  # the family's name for the final norm's epsilon
     zero_centered = False  # the final norm's form (``layers.RMSNorm``)
-    routed = True  # the layers hand their routing back: ``ModelFamily.route_unroll``
+    routed = True  # the layers hand records back (routing, counters): ``ModelFamily.route_unroll``
 
     @staticmethod
     def layer_args(arch: dict):
@@ -177,9 +177,12 @@ class Backbone(nn.Module):
         routing (the choices and ``ops/moe.route_stats``) and what else the
         layer counted. What a layer without experts counted (glm4_moe_lite's
         leading dense layers: their attention) is added into the first expert
-        layer's record."""
+        layer's record; a family with no expert layer at all (evabyte) gets
+        its layers' records back as they are."""
         *out, records = self._unroll(obs, carry0, firsts)
         routes = [r for r in records if "choice" in r]
+        if not routes:
+            return tuple(out), records
         for other in (r for r in records if "choice" not in r):
             for counter, spans in other.items():
                 into = routes[0].setdefault(counter, {})

@@ -115,10 +115,11 @@ def route_scalars(routes: list) -> dict[str, jax.Array]:
     expert, of the mean one, their ratio, the share of assignments that fell
     on held experts, the share of tokens with no held expert and the trips of
     the block's walk over its held rows (``moe-chunks``: 1.0 when every layer
-    took one). Empty for a family without expert layers."""
-    if not routes:
+    took one). Empty for a family without expert layers, whose records (if
+    its layers hand any back) hold counters alone."""
+    stats = [r["stats"] for r in routes if "stats" in r]
+    if not stats:
         return {}
-    stats = [r["stats"] for r in routes]
     mean = lambda k: sum(s[k] for s in stats) / len(stats)  # noqa: E731
     return {
         "moe-rows": sum(s["rows"] for s in stats),
@@ -142,7 +143,8 @@ def attention_scalars(routes: list) -> dict[str, jax.Array]:
     """What the attention masks did this update, from what the layers handed
     back beside their routing (counter -> kind -> count;
     ``models/layers.attention_counts``), each summed over the layers of its kind:
-    the query-key pairs kept (``attn-pairs-global``, ``attn-pairs-window``),
+    the query-key pairs kept (``attn-pairs-global``, ``attn-pairs-window``; an
+    EVA layer's ``attn-pairs-block`` and ``attn-pairs-summary``),
     the tiles of the splash kernels' static band (``attn-tiles-band-*``),
     those of them the kernels computed because no seam emptied them
     (``attn-tiles-run-*``), and the grid steps the backward took a head

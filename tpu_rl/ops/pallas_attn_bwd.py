@@ -162,7 +162,8 @@ def _kernel(kv_of, q_of, flags, q_ref, k_ref, v_ref, qseg_ref, kseg_ref, lse_ref
 
 
 def attention_bwd(q, k, v, seg, logsumexp, do, di, steps, *, q_sequence, mask_function,
-                  mask_value, block_q, block_kv, block_kv_compute, ballast=0, interpret=False):
+                  mask_value, block_q, block_kv, block_kv_compute, ballast=0, interpret=False,
+                  kv_seg=None):
     """dq, dk, dv of one row in the caller's layout: q ``(T, H, D)``, k, v
     ``(T, Hkv, D)``, seg ``(T,)`` int32, do ``(H, T, D)`` (where the forward's
     output lies, beside which ``di`` was summed), logsumexp, di ``(H, T)`` float32,
@@ -178,13 +179,19 @@ def attention_bwd(q, k, v, seg, logsumexp, do, di, steps, *, q_sequence, mask_fu
     bit. ``ballast``: that many ``(H, T, D)`` arrays of q's dtype declared as
     one more output in HBM that the kernel never touches, kept alive by a sum
     that adds nothing (``parallel/sequence._splash_rows_skipping_seams`` says
-    why)."""
+    why). ``kv_seg`` ``(Tk,)``: the keys' own segment ids where they are not the
+    queries' steps (k, v ``(Tk, Hkv, D)`` with ``Tk`` any multiple of
+    ``block_kv``: the summaries an EVA layer reads, ``parallel/sequence.
+    summary_attention_lse``); None: the keys are the window's steps and share
+    ``seg``."""
     T, H, D = q.shape
-    heads_kv = k.shape[1]
+    Tk, heads_kv = k.shape[:2]
+    kv_seg = seg if kv_seg is None else kv_seg
     group = H // heads_kv
     bq, bkv, bkc = block_q, block_kv, block_kv_compute
-    assert T % bq == 0 and T % bkv == 0 and bkv % bkc == 0 and bq % _LANES == 0, (T, bq, bkv, bkc)
-    acc = pltpu.VMEM((T if group > 1 else bkv, D), _F32)
+    assert T % bq == 0 and Tk % bkv == 0 and bkv % bkc == 0 and bq % _LANES == 0, (
+        T, Tk, bq, bkv, bkc)
+    acc = pltpu.VMEM((Tk if group > 1 else bkv, D), _F32)
 
     rows8 = lambda x: jnp.broadcast_to(x[..., None, :], (*x.shape[:-1], _SUBLANES, T))  # noqa: E731
     tile = lambda h, s, kv_of, q_of, flags: (h, 0, q_of[s])  # noqa: E731
@@ -217,8 +224,8 @@ def attention_bwd(q, k, v, seg, logsumexp, do, di, steps, *, q_sequence, mask_fu
         ]
         out_shape = [
             jax.ShapeDtypeStruct((T, H * D), q.dtype),
-            jax.ShapeDtypeStruct((T, heads_kv * D), k.dtype),
-            jax.ShapeDtypeStruct((T, heads_kv * D), v.dtype),
+            jax.ShapeDtypeStruct((Tk, heads_kv * D), k.dtype),
+            jax.ShapeDtypeStruct((Tk, heads_kv * D), v.dtype),
         ]
     else:
         dkv_major = lambda *at: (*dkv_tile(*at)[::-1], 0)  # noqa: E731
@@ -229,8 +236,8 @@ def attention_bwd(q, k, v, seg, logsumexp, do, di, steps, *, q_sequence, mask_fu
         ]
         out_shape = [
             jax.ShapeDtypeStruct((H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((heads_kv, T, D), k.dtype),
-            jax.ShapeDtypeStruct((heads_kv, T, D), v.dtype),
+            jax.ShapeDtypeStruct((heads_kv, Tk, D), k.dtype),
+            jax.ShapeDtypeStruct((heads_kv, Tk, D), v.dtype),
         ]
     if ballast:
         out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
@@ -258,7 +265,7 @@ def attention_bwd(q, k, v, seg, logsumexp, do, di, steps, *, q_sequence, mask_fu
     )(
         *steps,
         heads_minor(q), heads_minor(k), heads_minor(v),
-        rows8(seg), jnp.broadcast_to(seg[:, None], (T, _LANES)),
+        rows8(seg), jnp.broadcast_to(kv_seg[:, None], (Tk, _LANES)),
         rows8(logsumexp), do, rows8(di), rows8(q_sequence.astype(jnp.int32)),
     )
     if columns:

@@ -31,6 +31,7 @@ the ring.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -203,9 +204,10 @@ def _skip_seams(splash, empty):
         **splash.kwargs)
 
 
-def _splash_kernel(T, H, *, causal, window, block_sizes, interpret):
+def _splash_kernel(T, H, *, causal, window, block_sizes, interpret, keys=None):
     """The library's kernel object over the static mask of ``H`` equal heads:
-    causal (or full), and inside ``window`` where one is given."""
+    causal (or full), and inside ``window`` where one is given. ``keys``: the
+    key side's length where it is not the queries' (``T`` by default)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         CausalMask,
         FullMask,
@@ -215,7 +217,7 @@ def _splash_kernel(T, H, *, causal, window, block_sizes, interpret):
     )
 
     if window is None:
-        head_mask = (CausalMask if causal else FullMask)((T, T))
+        head_mask = (CausalMask if causal else FullMask)((T, T if keys is None else keys))
     else:
         assert causal, "a window is the causal one: the keys that end with the query"
         head_mask = LocalMask((T, T), window_size=(window - 1, 0), offset=0)
@@ -268,8 +270,9 @@ def _splash_mha(q, k, v, seg, *, causal, scale, block_sizes, interpret=False, wi
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "block_sizes", "interpret", "window"))
-def _splash_rows_skipping_seams(q, k, v, seg, *, causal, scale, block_sizes, interpret, window):
+    jax.jit, static_argnames=("causal", "scale", "block_sizes", "interpret", "window", "lse"))
+def _splash_rows_skipping_seams(
+        q, k, v, seg, *, causal, scale, block_sizes, interpret, window, lse=False):
     """:func:`_splash_mha` with each row's block masks read from its segment
     ids. One splash call a row, in a Python loop: a ``jax.vmap`` over the
     traced scalar-prefetch operands makes Pallas loop over the rows inside one
@@ -284,7 +287,12 @@ def _splash_rows_skipping_seams(q, k, v, seg, *, causal, scale, block_sizes, int
     :func:`_skip_seams` builds; its backward is the repo's own
     (:func:`_seam_row_bwd`, ``ops/pallas_attn_bwd.py``) over the band's tiles
     alone, unless a head's dq does not fit the core's VMEM
-    (``pallas_attn_bwd.fits``), where the library's fused backward stays."""
+    (``pallas_attn_bwd.fits``), where the library's fused backward stays.
+
+    ``lse``: each row's logsumexp ``(B, H, T)`` float32 comes back beside the
+    output, differentiable too (:func:`flash_attention_lse`: a caller that
+    merges this softmax with another over further keys). Only the repo's own
+    backward takes its cotangent."""
     from tpu_rl.ops import pallas_attn_bwd
 
     T, D = q.shape[1], q.shape[3]
@@ -307,24 +315,38 @@ def _splash_rows_skipping_seams(q, k, v, seg, *, causal, scale, block_sizes, int
     # has no second row's buffers to be kept beside (glm-4.7-flash) and declares none.
     ballast = T // block_sizes.block_kv_dkv if q.shape[0] > 1 else 0
     mask = (causal, window, ballast)
+    assert own_bwd or not lse, "the library's backward takes no logsumexp cotangent"
+    row = _seam_row_lse if lse else _seam_row if own_bwd else _seam_row_forward
     rows = [
-        (_seam_row if own_bwd else _seam_row_forward)(
-            mask, splash, q[b] * scale, k[b], v[b], seg32[b], empty[b])
+        row(mask, splash, q[b] * scale, k[b], v[b], seg32[b], empty[b])
         for b in range(q.shape[0])
     ]
+    if lse:
+        return jnp.stack([o for o, _ in rows]), jnp.stack([l for _, l in rows])
     return jnp.stack(rows)
 
 
-def _library_row(splash, q, k, v, seg, empty, save_residuals):
+def _library_row(splash, q, k, v, seg, empty, save_residuals, kv_seg=None, reach=None):
     """One row, q (T, H, D), through the library's kernels on the masks
     ``empty`` leaves: our layout -> the kernels' (H, T, D) and back, each a
     pass XLA fuses, none over a stacked batch. ``save_residuals``: the
-    logsumexp (H, T) beside the output."""
+    logsumexp (H, T) beside the output. ``kv_seg``: the keys' own segment ids
+    where they are not the window's steps. ``reach`` (T,) int: the index of
+    the last key each query may read, in place of the query's own index — the
+    causal mask function then runs on every tile the row computes, none is
+    taken as wholly kept (:func:`summary_attention_lse`)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import SegmentIds
 
     kernel = _skip_seams(splash, empty)
+    if reach is not None:
+        fwd = kernel.fwd_mask_info
+        kernel.fwd_mask_info = fwd._replace(
+            block_mask=jnp.minimum(fwd.block_mask, 1).astype(fwd.block_mask.dtype),
+            q_sequence=reach)
     kernel.kwargs["save_residuals"] = save_residuals
-    out = kernel(*(x.transpose(1, 0, 2) for x in (q, k, v)), segment_ids=SegmentIds(q=seg, kv=seg))
+    out = kernel(
+        *(x.transpose(1, 0, 2) for x in (q, k, v)),
+        segment_ids=SegmentIds(q=seg, kv=seg if kv_seg is None else kv_seg))
     if save_residuals:
         out, (logsumexp,) = out
         return out.transpose(1, 0, 2), logsumexp
@@ -345,36 +367,79 @@ def _seam_row_fwd(mask, splash, q, k, v, seg, empty):
     return out, (splash, q, k, v, seg, empty, out, logsumexp)
 
 
-def _seam_row_bwd(mask, res, do):
+def _seam_row_bwd(mask, res, do, dlse=None):
     """dq, dk, dv of one row from the forward's ``(out, logsumexp)``: ``di``
     as the library computes it, then one kernel over the steps
     :func:`tpu_rl.ops.pallas_attn_bwd.band_steps` lists — the grid *is* the
-    band — under the scope ``attn_bwd_pallas``."""
+    band — under the scope ``attn_bwd_pallas``. ``dlse`` (H, T): the
+    logsumexp's own cotangent where it was an output (:func:`_seam_row_lse`).
+    A score's gradient is ``p (dp - di)`` and the logsumexp's derivative by a
+    score is ``p``, so it folds into ``di`` and the kernel is the same."""
     from tpu_rl.ops import pallas_attn_bwd
 
     causal, window, ballast = mask
-    splash, q, k, v, seg, empty, out, logsumexp = res
+    splash, q, k, v, seg, empty, out, logsumexp, *others = res
+    kv_seg, reach = others or (None, None)  # :func:`_reach_row`'s: keys that are no steps
     info, bs = splash.dkv_mask_info, splash.kwargs["block_sizes"]
     assert info.partial_mask_blocks is None and info.block_mask.shape[0] == 1
     T = q.shape[0]
-    band = band_tiles(T, bs.block_q_dkv, window) if causal else np.ones(empty.shape, bool)
+    square = causal and reach is None
+    band = band_tiles(T, bs.block_q_dkv, window) if square else np.ones(empty.shape, bool)
     with jax.named_scope("attn_bwd_pallas"):
         # out lies as the forward's kernel wrote it, (H, T, D): do is brought there once,
         # in its own dtype, for di and for the kernel (left as (T, H, D), XLA re-lays both
         # operands of this sum in float32)
         out, do = out.transpose(1, 0, 2), do.transpose(1, 0, 2)
         di = jnp.einsum("hsd,hsd->hs", out.astype(jnp.float32), do.astype(jnp.float32))
+        if dlse is not None:
+            di = di - dlse
         dq, dk, dv = pallas_attn_bwd.attention_bwd(
             q, k, v, seg, logsumexp, do, di, pallas_attn_bwd.band_steps(band, empty),
-            q_sequence=jnp.arange(T) if info.q_sequence is None else info.q_sequence,
+            q_sequence=reach if reach is not None else (
+                jnp.arange(T) if info.q_sequence is None else info.q_sequence),
             mask_function=splash.kwargs["mask_function"],
             mask_value=splash.kwargs["mask_value"], block_q=bs.block_q_dkv,
             block_kv=bs.block_kv_dkv, block_kv_compute=bs.block_kv_dkv_compute,
-            ballast=ballast, interpret=splash.kwargs["interpret"])
-    return None, dq, dk, dv, None, None
+            ballast=ballast, interpret=splash.kwargs["interpret"], kv_seg=kv_seg)
+    return (None, dq, dk, dv, *(None,) * (2 + len(others)))
 
 
 _seam_row.defvjp(_seam_row_fwd, _seam_row_bwd)
+
+
+def _seam_row_lse_forward(mask, splash, q, k, v, seg, empty):
+    """:func:`_seam_row` with the row's logsumexp (H, T) as a second output."""
+    return _library_row(splash, q, k, v, seg, empty, True)
+
+
+_seam_row_lse = jax.custom_vjp(_seam_row_lse_forward, nondiff_argnums=(0,))
+
+
+def _seam_row_lse_fwd(mask, splash, q, k, v, seg, empty):
+    out, logsumexp = _seam_row_lse_forward(mask, splash, q, k, v, seg, empty)
+    return (out, logsumexp), (splash, q, k, v, seg, empty, out, logsumexp)
+
+
+_seam_row_lse.defvjp(_seam_row_lse_fwd, lambda mask, res, ct: _seam_row_bwd(mask, res, *ct))
+
+
+def _reach_row_forward(splash, q, k, v, seg, empty, kv_seg, reach):
+    """One row's queries (T, H, D) against keys that are no steps of the window
+    (Tk, H, D) with segment ids of their own, each query reading the keys of
+    its segment up to index ``reach[t]``: the output, normalised over those
+    alone, and their logsumexp (H, T)."""
+    return _library_row(splash, q, k, v, seg, empty, True, kv_seg, reach)
+
+
+_reach_row = jax.custom_vjp(_reach_row_forward)
+
+
+def _reach_row_fwd(splash, q, k, v, seg, empty, kv_seg, reach):
+    out, logsumexp = _reach_row_forward(splash, q, k, v, seg, empty, kv_seg, reach)
+    return (out, logsumexp), (splash, q, k, v, seg, empty, out, logsumexp, kv_seg, reach)
+
+
+_reach_row.defvjp(_reach_row_fwd, lambda res, ct: _seam_row_bwd((True, None, 0), res, *ct))
 
 
 def make_sp_mesh(n_data: int, n_seq: int, devices=None) -> Mesh:
@@ -996,6 +1061,129 @@ def flash_attention_tpu(
         # the cells.py LSTM island).
         check_vma=False,
     )(q, k, v, seg)
+
+
+def flash_attention_lse(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    q_pos: jax.Array,
+    seg: jax.Array,
+    sm_scale: float | None = None,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """Causal same-segment attention (:func:`flash_attention_tpu`'s contract,
+    no window) that also hands back each query's logsumexp over the keys it
+    kept, ``(B, H, T)`` float32 — for a caller whose softmax runs over further
+    keys that are not steps of this window (``models/evabyte.py``: one
+    normaliser over a block's exact keys and the summaries of earlier chunks)
+    and merges the two parts by it. Both outputs are differentiable.
+
+    On a TPU, at a length the splash kernel tiles, this is the walk over the
+    rows with each row's block masks read from its segment ids
+    (:func:`_splash_rows_skipping_seams`) at any grid size, because only the
+    repo's own backward (``ops/pallas_attn_bwd.py``) takes the logsumexp's
+    cotangent — it folds into ``di``; the library's custom VJP has no such
+    input. Off-TPU, at a length the kernel cannot tile, under a registered
+    data mesh (no ``shard_map`` island yet: nothing runs an EVA layer over a
+    mesh) or where a head's dq does not fit the core's VMEM, the ``jnp`` form
+    under ``attn_full`` (a (T, T) score matrix a head: test sizes).
+    ``interpret`` runs the kernels' construction on the CPU."""
+    from tpu_rl.models import cells
+    from tpu_rl.ops import pallas_attn_bwd
+
+    platform, _ = cells._program_devices()
+    scale = float(1.0 / np.sqrt(q.shape[-1]) if sm_scale is None else sm_scale)
+    bs = _splash_block_sizes(q.shape[1]) if cells._DATA_MESH is None else None
+    fits = bs is not None and pallas_attn_bwd.fits(
+        q.shape[1], q.shape[3], q.shape[2] // k.shape[2], bs.block_q_dkv, bs.block_kv_dkv,
+        bs.block_kv_dkv_compute, q.dtype.itemsize)
+    if not interpret and (platform != "tpu" or not fits):
+        rep = q.shape[2] // k.shape[2]
+        if rep > 1:
+            k, v = (jnp.repeat(x, rep, axis=2) for x in (k, v))
+        with jax.named_scope("attn_full"):
+            scores = _masked_block_scores(q, k, q_pos, q_pos, seg, seg, scale, True)
+            lse = jax.nn.logsumexp(scores, axis=-1)
+            p = jnp.exp(scores - lse[..., None])
+            return _pv_dot(p, v, _contract_dtype(v)).astype(q.dtype), lse
+
+    with jax.named_scope("attn_flash_pallas"):
+        return _splash_rows_skipping_seams(
+            q, k, v, seg, causal=True, scale=scale, block_sizes=bs, interpret=interpret,
+            window=None, lse=True)
+
+
+def summary_attention_lse(q, ks, vs, seg, seg_k, reach, sm_scale, interpret=False):
+    """Queries against keys that are not the window's steps — an EVA layer's
+    chunk summaries — through the splash kernels: q (B, T, H, D); ks, vs
+    (B, N, H, D) with segment ids ``seg_k`` (B, N) of their own (an id no query
+    has marks an absent one); query ``t`` reads the keys of its segment
+    ``seg`` (B, T) with index ``<= reach[b, t]`` (B, T) int, none where that is
+    negative; ``reach[b, t] <= t`` (a summary is of steps before the query: the
+    library's static causal structure on the rectangle stands). Returns the
+    output normalised over the keys read (B, T, H, D) and their logsumexp
+    (B, H, T) float32 — hugely negative where a query reads none, so that a
+    merge by it gives this part no weight — or None where the kernels do not
+    take the shapes (off-TPU, a length or a key count they cannot tile, a
+    registered data mesh): the caller keeps its ``jnp`` form.
+
+    The mask is the library's causal one on a (T, N) rectangle with each
+    query's own index replaced by ``reach`` (the kernels take the queries'
+    indices as an operand), every computed tile under the mask function; a
+    tile no query of which reaches, or whose keys are all of earlier segments,
+    is stepped over (forward) and left out of the backward's walk, which is
+    ``ops/pallas_attn_bwd.py``'s with the keys' ids beside the queries' and
+    the logsumexp's cotangent folded into ``di``. One call a row, as
+    :func:`_splash_rows_skipping_seams`."""
+    from tpu_rl.models import cells
+    from tpu_rl.ops import pallas_attn_bwd
+
+    T, N = q.shape[1], ks.shape[1]
+    platform, n_data = cells._program_devices()
+    bs = _splash_block_sizes(T) if q.shape[0] % n_data == 0 and N % 128 == 0 else None
+    if bs is None or not (interpret or platform == "tpu") or cells._DATA_MESH is not None:
+        return None
+    edge = min(bs.block_kv, N)
+    if N % edge:
+        return None
+    compute = min(bs.block_kv_compute, edge)
+    bs = dataclasses.replace(
+        bs, block_kv=edge, block_kv_compute=compute, block_kv_dkv=edge,
+        block_kv_dkv_compute=compute)
+    if not pallas_attn_bwd.fits(
+            T, q.shape[3], 1, bs.block_q_dkv, edge, compute, q.dtype.itemsize):
+        return None
+    return _summary_rows(
+        q, ks, vs, seg, seg_k, reach, scale=float(sm_scale), block_sizes=bs, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_sizes", "interpret"))
+@jax.named_scope("attn_flash_pallas")
+def _summary_rows(q, ks, vs, seg, seg_k, reach, *, scale, block_sizes, interpret):
+    T, N = q.shape[1], ks.shape[1]
+    splash = _splash_kernel(
+        T, q.shape[2], causal=True, window=None, block_sizes=block_sizes, interpret=interpret,
+        keys=N)
+    bq, bkv = block_sizes.block_q, block_sizes.block_kv
+    seg, seg_k, reach = (x.astype(jnp.int32) for x in (seg, seg_k, reach))
+    # tile (i, j) holds no kept pair if no query of block i reaches key block j's first
+    # key, or block j's keys are all of segments before block i's queries'
+    far = reach.reshape(-1, T // bq, bq).max(axis=2)[:, :, None] < (jnp.arange(N // bkv) * bkv)
+    old = (seg_k.reshape(-1, N // bkv, bkv).max(axis=2)[:, None, :]
+           < seg.reshape(-1, T // bq, bq).min(axis=2)[:, :, None])
+    empty = far | old
+    # A query block with no tile left computes its first one all the same, every pair of it
+    # masked: the library's forward divides by the row's sum, which only a computed tile
+    # makes nonzero (the square band's diagonal is never empty; a rectangle's first query
+    # blocks — the window's opening block — reach nothing).
+    first = jnp.arange(N // bkv) == 0
+    empty &= ~(empty.all(axis=2, keepdims=True) & first)
+    rows = [
+        _reach_row(splash, q[b] * scale, ks[b], vs[b], seg[b], empty[b], seg_k[b], reach[b])
+        for b in range(q.shape[0])
+    ]
+    return jnp.stack([o for o, _ in rows]), jnp.stack([l for _, l in rows])
 
 
 ATTENTION_IMPLS = {

@@ -37,6 +37,8 @@ _CACHE_DIR = os.path.join(
 # rows are added into the tokens by ops/pallas_moe.py's kernel,
 # models/glm4_moe_lite.py: ``mla`` around the latent-attention mixer,
 # models/lfm2_moe.py: ``shortconv`` around the gated short convolution,
+# models/evabyte.py: ``eva`` around the EVA mixer and ``eva_pool`` around its
+# chunk pooling,
 # ops/pallas_act.py, parallel/sequence.py: ``attn_bwd_pallas`` inside
 # ``attn_flash_pallas`` where the backward is ops/pallas_attn_bwd.py's walk
 # over the band's tiles), so one lowered
@@ -44,7 +46,7 @@ _CACHE_DIR = os.path.join(
 _MOSAIC_TARGET = "tpu_custom_call"
 _PATH_SCOPES = re.compile(
     r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_bwd_pallas|attn_full|attn_window"
-    r"|attn_global|attn_rope|mla|shortconv|ssd_scan|ssd_pallas|gdn_scan|gdn_pallas|moe_experts"
+    r"|attn_global|attn_rope|mla|shortconv|eva|eva_pool|ssd_scan|ssd_pallas|gdn_scan|gdn_pallas|moe_experts"
     r"|moe_gmm_pallas|moe_row_add_pallas)\b"
 )
 
