@@ -517,9 +517,13 @@ def kernel_checks(
     # 256 queries at a time). "pool": the gather and the two poolings alone
     # against the reference's shifted products at the chunks' last steps, both
     # timed. "step": the acting form stepped over its exact ring and summary
-    # store, across a block boundary, against the training form. Run last, so
-    # that the rows above keep the inputs they were drawn
-    evabyte_shapes=(("mixer", 1, 16384), ("pool", 1, 16384), ("step", 1, 4096)),
+    # store, across a block boundary, against the training form. "pool-seams":
+    # "pool" on a window with three seams off every grid line, so that several
+    # candidates are absent and a group of ``chunk_size`` steps meets chunks of
+    # two episodes. Run last, so that the rows above keep the inputs they were
+    # drawn ("pool-seams" after "step", for the same reason)
+    evabyte_shapes=(
+        ("mixer", 1, 16384), ("pool", 1, 16384), ("step", 1, 4096), ("pool-seams", 1, 16384)),
     evabyte_widths=EVABYTE_MIXER,
     interpret: bool = False,
 ) -> list[dict]:
@@ -1151,6 +1155,8 @@ def kernel_checks(
         u = f32(B, T, hidden)
         firsts = rng.random((B, T)) < 1.0 / T  # ~1 episode seam a window, as the cell's mix
         firsts[:, T // 3 + 5] = True  # off every grid line of the window, inside a chunk
+        if row == "pool-seams":
+            firsts[:, [T // 5 + 3, T - T // 7 + 1]] = True
         firsts[1:] = firsts[:1]  # the rows of a stepped batch start their episodes together
         seg = jnp.asarray(np.cumsum(firsts, axis=1).astype(np.int32))
         first = jnp.asarray(firsts)
@@ -1188,7 +1194,7 @@ def kernel_checks(
                 (params, u), TOL_MIXER_BF16, TOL_MIXER_BF16,
             )
             continue
-        if row == "pool":
+        if row in ("pool", "pool-seams"):
             D = hidden // n_heads
             k, v = (f32(B, T, n_heads, D).astype(jnp.bfloat16) for _ in range(2))
             _, blk, _, ends = episode_grid(seg, W, C)

@@ -82,20 +82,25 @@ def test_kernel_checks_pass_tiny_interpreted():
         lfm2_shapes=(("mixer", 2, 128), ("step", 2, 128)),
         lfm2_widths=dict(hidden_size=64, conv_L_cache=3),
         lfm2_attn_bwd_shapes=((512, 8, 2, 16, 0.25, None, 128, 128),),
-        evabyte_shapes=(("mixer", 2, 128), ("pool", 2, 128), ("step", 2, 128)),
+        evabyte_shapes=(("mixer", 2, 128), ("pool", 2, 128), ("step", 2, 128),
+                        ("pool-seams", 2, 128)),
         evabyte_widths=dict(hidden_size=64, num_attention_heads=4, window_size=32, chunk_size=4,
                             rope_theta=100000, init_std=0.05),
         interpret=True,
     )
-    assert len(rows) == 32
-    # evabyte's three rows come last: the mixer, the pooling alone (timed), the acting form
-    step, pool, mixer = rows.pop(), rows.pop(), rows.pop()
+    assert len(rows) == 33
+    # evabyte's four rows come last: the mixer, the pooling alone (timed), the acting
+    # form, the pooling on a window with more seams and several absent candidates
+    seams, step, pool, mixer = rows.pop(), rows.pop(), rows.pop(), rows.pop()
     assert mixer["kernel"].startswith("evabyte eva mixer fwd+bwd B2/T128 bf16 vs the plain reference")
     assert pool["kernel"].startswith("evabyte eva pool fwd+bwd B2/T128/H4x16/C4 bf16")
     assert step["kernel"].startswith("evabyte step B2/T128 bf16 over a ring of 32 and 32 summaries")
     assert mixer["ok"] and pool["ok"] and step["ok"], (mixer, pool, step)
     assert mixer["err"] > 0 and pool["err"] > 0 and pool["ms"] > 0 and pool["ms_ref"] > 0
     assert "4 blocks" in step["kernel"] and " complete chunks of 32" in pool["kernel"]
+    assert seams["kernel"].startswith(pool["kernel"].split(" (")[0]) and seams["ok"], seams
+    complete = lambda row: int(row["kernel"].split(" (")[1].split()[0])  # noqa: E731
+    assert complete(seams) < complete(pool) < 32 and seams["ms"] > 0
     # lfm2_moe's three rows come last: a narrow head's gradients leave the backward head-major
     narrow, step, mixer = rows.pop(), rows.pop(), rows.pop()
     assert narrow["kernel"].startswith("lfm2_moe attn bwd over the band T512/H8:2/D16 bf16")

@@ -415,6 +415,76 @@ def test_the_candidates_static_bounds_hold_at_the_worst_case(mixer):
         assert (np.asarray(seg_c[row])[len(last):] == -1).all() and len(last) < T // C
 
 
+# The seams of each row of a window and the window's steps: what a wrong way back
+# from the chunks to the steps gets wrong.
+POOL_CASES = {
+    "no seam": (((),), T),
+    "a seam off the chunks' grid": (((37,),), T),
+    "an episode shorter than a chunk": (((40, 42),), T),
+    "an incomplete chunk before a seam and at the window's end": (((0, 38),), T),
+    "the window opens inside an episode": (((5,),), T),
+    # 0 + 1 + 30 complete chunks of 32, the last on steps 124 .. 127: the absent
+    # candidate's clipped span lies on it
+    "an absent candidate on the window's last chunk": (((3, 8),), T),
+    "two rows, each with seams of its own": (SEAMS, T),
+    "a window that is no whole number of chunks": (SEAMS, T - 2),
+}
+
+
+@pytest.mark.parametrize("case", list(POOL_CASES))
+def test_the_summaries_and_their_gradients_against_the_shifted_products(mixer, case):
+    """``summaries``' k~, v~ and the gradients of k, v, ``pool_k``, ``pool_v``
+    in float32 against ``jax.grad`` of the reference's pooled rows read at the
+    chunks' last steps: the members go to the chunks by a gather and their
+    gradients come back by its inverse, no sum — every step gets the gradient
+    of the one chunk it lies in, or none; an absent candidate is exactly zero,
+    carries the id -1, and nothing of its cotangent reaches a step."""
+    module, params, _, _ = mixer
+    seams, steps = POOL_CASES[case]
+    rows, n = len(seams), steps // C
+    fir = make_batch(0, seams, rows, steps)["is_fir"][..., 0]
+    seg = jnp.cumsum(jnp.asarray(fir, jnp.int32), axis=1)
+    _, blk, _, ends = episode_grid(seg, W, C)
+    last = [np.flatnonzero(np.asarray(e)) for e in ends]
+    rng = np.random.default_rng(11)
+    k, v = (jnp.asarray(rng.standard_normal((rows, steps, HEADS, D)), jnp.float32)
+            for _ in range(2))
+    w_k, w_v = (jnp.asarray(rng.standard_normal((rows, n, HEADS, D)), jnp.float32)
+                for _ in range(2))
+
+    def system(p, k, v):
+        ks, vs, seg_c, _ = module.apply({"params": p}, k, v, seg, blk, ends, method="summaries")
+        return (ks * w_k).sum() + (vs * w_v).sum(), (ks, vs, seg_c)
+
+    def plain(p, k, v):
+        pooled = [reference.pooled_rows(k, x, p[w], C, D ** -0.5)
+                  for x, w in ((k, "pool_k"), (v, "pool_v"))]
+        ks, vs = (jnp.stack([
+            jnp.zeros((n, HEADS, D)).at[: len(at)].set(x[b][at]) for b, at in enumerate(last)])
+            for x in pooled)
+        return (ks * w_k).sum() + (vs * w_v).sum(), (ks, vs)
+
+    pools = {name: params["params"][name] for name in ("pool_k", "pool_v")}
+    (_, (ks, vs, seg_c)), got = jax.jit(
+        jax.value_and_grad(system, argnums=(0, 1, 2), has_aux=True))(pools, k, v)
+    (_, want_rows), want = jax.jit(
+        jax.value_and_grad(plain, argnums=(0, 1, 2), has_aux=True))(pools, k, v)
+    for b, at in enumerate(last):
+        assert (np.asarray(seg_c[b])[len(at):] == -1).all()
+        assert not np.asarray(ks[b, len(at):]).any() and not np.asarray(vs[b, len(at):]).any()
+    assert any(len(at) < n for at in last) == (case != "no seam")
+    jax.tree.map(lambda a, b: close(a, b, 2e-5), (ks, vs), want_rows)
+    jax.tree.map(lambda a, b: close(a, b, 2e-5), got, want)
+    # a step in no complete chunk gets nothing, every other one something
+    inside = np.zeros((rows, steps), bool)
+    for b, at in enumerate(last):
+        for e in at:
+            inside[b, e - C + 1: e + 1] = True
+    for grad in got[1:]:
+        moved_steps = np.abs(np.asarray(grad)).max(axis=(2, 3)) > 0
+        np.testing.assert_array_equal(moved_steps, inside)
+
+
 # ------------------------------------------------------------------- acting
 def unroll_by_steps(fam, actor, batch, row, ctx=None):
     """``family.act``'s module method, step by step over one row, the carry

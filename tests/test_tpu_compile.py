@@ -134,6 +134,57 @@ def test_the_two_resolution_read_compiles_at_evabytes_shapes(one_chip, monkeypat
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
+def test_the_chunk_poolings_backward_holds_no_scatter_at_evabytes_shapes(one_chip):
+    """An EVA layer's chunk pooling (``EvaAttention.summaries`` under the
+    mixer's scope ``eva_pool``) at 32 heads of 128 over 16,384 steps in bf16,
+    value and gradients of k, v and the two pooling vectors, for a v5e. The
+    members reach the chunks by a gather; their gradients come back by its
+    inverse (``_summaries_bwd``), so the program holds no scatter — the
+    transpose of the gather was one, which XLA ran as a loop over the 1,024
+    spans —, every op of the backward lies under a scope named ``eva_pool``
+    (what ``kernel.eva_pool_ms_per_update`` and ``eva_pool_roofline`` read),
+    and no member is gathered again: the temporaries stay under the parent
+    commit's, which kept both tensors' members for its backward."""
+    import re
+
+    from tpu_rl.models.evabyte import EvaAttention, episode_grid
+
+    T, heads, D, block, chunk = 16384, 32, 128, 2048, 16
+    mixer = EvaAttention(
+        hidden=heads * D, heads=heads, block=block, chunk=chunk, rope_theta=1e5,
+        init_std=0.01275, dtype=jnp.bfloat16)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def pooled(pools, k, v, seg):
+        _, blk, _, ends = episode_grid(seg, block, chunk)
+        with jax.named_scope("eva_pool"):
+            ks, vs, _, _ = mixer.apply({"params": pools}, k, v, seg, blk, ends, method="summaries")
+        return ks.astype(jnp.float32).sum() + jnp.square(vs.astype(jnp.float32)).sum()
+
+    step = shaped((1, T, heads, D), jnp.bfloat16)
+    pools = {name: shaped((heads, D), jnp.float32) for name in ("pool_k", "pool_v")}
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        lowered = jax.jit(jax.value_and_grad(pooled, argnums=(0, 1, 2))).lower(
+            pools, step, step, shaped((1, T), jnp.int32))
+        text, named = lowered.as_text(), lowered.as_text(debug_info=True)
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+    assert "scatter" not in text and "stablehlo.gather" in text
+    # the backward's ops: under the transposed forward scope; each under the rule's own too
+    backward = [name for name in re.findall(r'loc\("([^"]*)"', named)
+                if "transpose(jvp(eva_pool))" in name]
+    assert len(backward) > 10
+    assert all("eva_pool" in name.replace("transpose(jvp(eva_pool))", "") for name in backward)
+    # 135,250,432 B at the parent commit (f94f406) for this function: the members of k and
+    # of v, 2 x 64 MiB, gathered again for the backward
+    assert compiled.memory_analysis().temp_size_in_bytes <= 135_250_432
+
+
 def test_the_update_tail_is_one_pass_over_the_weights(one_chip):
     """The tail of the on-policy update as ``algos/ppo.py`` writes it — clip,
     guard, RMSprop, apply and ``learn_diag``'s norms — on a PPO ``TrainState``

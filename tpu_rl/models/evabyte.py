@@ -52,8 +52,14 @@ the shapes (off-TPU, a candidate count they cannot tile) ``read_summaries``
 scores a block of queries at a time against the candidates that can lie before
 it, under the mask written from the definition of ``S`` — the oracle of the
 kernels' form. One normaliser; the kept pairs exactly ``E`` and ``S``; never a
-(T, T) score matrix; gradients reach ``mu``, ``phi``, k and v through the
-gather and the pooling. Static shapes throughout; nothing here reads a flag.
+(T, T) score matrix. Gradients reach ``mu``, ``phi``, k and v through the
+pooling, which has a backward of its own (``_summaries_bwd``): complete chunks
+are disjoint spans, so the members' gather has an inverse — a step lies in at
+most one chunk, the one whose rank is the number of chunks that ended before
+it — and a step's gradient is made where the step lies, from its own key and
+value and its chunk's row of the summaries' cotangents; nothing is scattered
+and no member is gathered a second time. Static shapes throughout; nothing
+here reads a flag.
 
 Acting (``step``): the worker zeroes the carry at an episode's first step, so
 the step counter is ``p``. Per layer an exact ring ``(W, H, D)`` x 2 that
@@ -114,10 +120,82 @@ def pair_counts(seg, block: int, chunk: int) -> dict:
 
 def _pool(members_k, members_x, w, scale):
     """``sum_m softmax_m(scale w.k_m) x_m`` over axis -3 (a chunk's members):
-    ``members_*`` (..., C, H, D), ``w`` (H, D). Float32."""
+    ``members_*`` (..., C, H, D), ``w`` (H, D); and the logits' logsumexp over
+    the members (..., H), from which a member's weight is made again. Float32."""
     k32 = members_k.astype(jnp.float32)
-    weights = jax.nn.softmax(scale * jnp.einsum("...chd,hd->...ch", k32, w), axis=-2)
-    return jnp.einsum("...ch,...chd->...hd", weights, members_x.astype(jnp.float32))
+    logits = scale * jnp.einsum("...chd,hd->...ch", k32, w)
+    pooled = jnp.einsum(
+        "...ch,...chd->...hd", jax.nn.softmax(logits, axis=-2), members_x.astype(jnp.float32))
+    return pooled, jax.nn.logsumexp(logits, axis=-2)
+
+
+def _summaries_forward(static, k, v, mu, phi, first, there, rank, inside):
+    """``k~``, ``v~`` (B, n, H, D) in k's dtype: the members of candidate j are
+    the steps ``first[j] .. first[j] + C - 1`` of ``k``, ``v`` (B, T, H, D) — one
+    contiguous span a chunk —, an absent candidate (``there`` false) is zero.
+    ``static``: (C, scale). ``rank``, ``inside`` (B, T): the inverse of the
+    gather, which only the backward reads (:func:`_summaries_bwd`)."""
+    return _summaries_fwd(static, k, v, mu, phi, first, there, rank, inside)[0]
+
+
+_summaries = jax.custom_vjp(_summaries_forward, nondiff_argnums=(0,))
+
+
+def _summaries_fwd(static, k, v, mu, phi, first, there, rank, inside):
+    C, scale = static
+
+    def members(x):  # (B, T, H, D) -> (B, n, C, H, D)
+        span = lambda row, s: jax.lax.dynamic_slice_in_dim(row, s, C, axis=0)  # noqa: E731
+        return jax.vmap(lambda row, ss: jax.vmap(lambda s: span(row, s))(ss))(x, first)
+
+    mk, mv = members(k), members(v)
+    (ks, lse_k), (vs, lse_v) = _pool(mk, mk, mu, scale), _pool(mk, mv, phi, scale)
+    keep = there[..., None, None]
+    out = tuple(jnp.where(keep, x, 0.0).astype(k.dtype) for x in (ks, vs))
+    # kept for the backward: k and v, which the layer holds anyway, and what is as
+    # small as a summary (float32 k~, v~ and the two logsumexps) — never the
+    # float32 members, 2 x 268 MB a layer at the published widths
+    return out, (k, v, mu, phi, ks, vs, lse_k, lse_v, rank, inside)
+
+
+def _summaries_bwd(static, res, ct):
+    """dk, dv, dmu, dphi without a scatter and without the members. Complete
+    chunks are disjoint spans, so a step of k, v lies in at most one
+    (``inside``), the ``rank``-th, and all a member's gradient needs of its
+    chunk is a row of a (B, n, ...) array: the cotangents ``g`` of ``k~``,
+    ``v~``, the two logsumexps (a member's weight is ``exp(logit - logsumexp)``,
+    its logit a product of its own key) and the two sums of the softmax's
+    backward, ``sum_m a_m (g.x_m) = g.x~``. Each step gathers those rows by its
+    rank and the gradients are written where k and v lie; an absent candidate's
+    rows are never pointed at, a step in no complete chunk gets zero. Float32
+    throughout, as the forward."""
+    _, scale = static
+    k, v, mu, phi, ks, vs, lse_k, lse_v, rank, inside = res
+    (B, T, H, D), n = k.shape, ks.shape[1]
+    with jax.named_scope("eva_pool"):
+        g_k, g_v = ct
+        small = jnp.stack(  # (B, n, 4, H): what a step needs of its chunk beside g
+            [lse_k, lse_v, jnp.sum(g_k.astype(jnp.float32) * ks, axis=-1),
+             jnp.sum(g_v.astype(jnp.float32) * vs, axis=-1)], axis=2)
+        rank = jnp.minimum(rank, n - 1)
+        at_steps = lambda x: jnp.take_along_axis(  # noqa: E731
+            x, rank.reshape(B, T, *(1,) * (x.ndim - 2)), axis=1)
+        g_k, g_v = (at_steps(g).astype(jnp.float32) for g in (g_k, g_v))
+        lse_k, lse_v, sum_k, sum_v = jnp.moveaxis(at_steps(small), 2, 0)
+        k32, v32, inside = k.astype(jnp.float32), v.astype(jnp.float32), inside[..., None]
+        dot = lambda x, y: jnp.sum(x * y, axis=-1)  # noqa: E731
+        a = jnp.where(inside, jnp.exp(scale * dot(k32, mu) - lse_k), 0.0)
+        b = jnp.where(inside, jnp.exp(scale * dot(k32, phi) - lse_v), 0.0)
+        d_k = scale * a * (dot(g_k, k32) - sum_k)  # the logits' gradients
+        d_v = scale * b * (dot(g_v, v32) - sum_v)
+        dk = a[..., None] * g_k + d_k[..., None] * mu + d_v[..., None] * phi
+        dv = b[..., None] * g_v
+        dk, dv = dk.astype(k.dtype), dv.astype(k.dtype)
+        dmu, dphi = (jnp.sum(d[..., None] * k32, axis=(0, 1)) for d in (d_k, d_v))
+    return dk, dv, dmu, dphi, None, None, None, None
+
+
+_summaries.defvjp(_summaries_fwd, _summaries_bwd)
 
 
 class EvaAttention(nn.Module):
@@ -169,26 +247,19 @@ class EvaAttention(nn.Module):
         B, T = seg.shape
         C, n = self.chunk, T // self.chunk
         nth = jnp.arange(1, n + 1, dtype=jnp.int32)
+        upto = jnp.cumsum(ends.astype(jnp.int32), axis=1)  # complete chunks up to each step
         # the step that ends the j-th complete chunk; T where there is none
-        last = jax.vmap(lambda c: jnp.searchsorted(c, nth, side="left"))(
-            jnp.cumsum(ends.astype(jnp.int32), axis=1)).astype(jnp.int32)
+        last = jax.vmap(lambda c: jnp.searchsorted(c, nth, side="left"))(upto).astype(jnp.int32)
         there = last < T
         at = jnp.minimum(last, T - 1)
         first = jnp.clip(last - (C - 1), 0, T - C)
-
-        def members(x):  # (B, T, H, D) -> (B, n, C, H, D): one contiguous span a chunk
-            span = lambda row, s: jax.lax.dynamic_slice_in_dim(row, s, C, axis=0)  # noqa: E731
-            return jax.vmap(lambda row, ss: jax.vmap(lambda s: span(row, s))(ss))(x, first)
-
-        @jax.checkpoint  # the backward keeps k and v, not their float32 members
-        def pooled(k, v, mu, phi):
-            mk, mv = members(k), members(v)
-            keep = there[..., None, None]
-            return tuple(
-                jnp.where(keep, _pool(mk, mx, w, self.scale), 0.0).astype(k.dtype)
-                for mx, w in ((mk, mu), (mv, phi)))
-
-        ks, vs = pooled(k, v, self.pool_k, self.pool_v)
+        # the way back: a step lies in a complete chunk iff one ends within the C steps
+        # from it on, and that chunk's rank is the number that ended before the step
+        rank = upto - ends
+        ahead = jnp.concatenate([upto[:, C - 1:], jnp.repeat(upto[:, -1:], C - 1, axis=1)], axis=1)
+        inside = ahead > rank
+        ks, vs = _summaries(
+            (C, self.scale), k, v, self.pool_k, self.pool_v, first, there, rank, inside)
         take = lambda x: jnp.take_along_axis(x, at, axis=1)  # noqa: E731
         return ks, vs, jnp.where(there, take(seg), -1), take(blk)
 
@@ -279,9 +350,9 @@ class EvaAttention(nn.Module):
             into = into[..., None, None]
             rounded = lambda x: x.astype(self.dtype or jnp.float32).astype(k_sum.dtype)  # noqa: E731
             k_sum = jnp.where(
-                into, rounded(_pool(mk, mk, self.pool_k, self.scale))[:, None], k_sum)
+                into, rounded(_pool(mk, mk, self.pool_k, self.scale)[0])[:, None], k_sum)
             v_sum = jnp.where(
-                into, rounded(_pool(mk, mv, self.pool_v, self.scale))[:, None], v_sum)
+                into, rounded(_pool(mk, mv, self.pool_v, self.scale)[0])[:, None], v_sum)
         # slot j of the store holds the newest complete chunk whose index is j mod n;
         # it is read if that chunk lies in a block before the query's
         done = (count + 1) // C  # complete chunks so far
