@@ -77,8 +77,23 @@ MANIFEST: dict[str, dict[str, str]] = {
         # site in the cold _site(), never here.
         "TraceRecorder.span": STRICT,
         "TraceRecorder.add": STRICT,
+        "TraceRecorder.sample": STRICT,
         "Span.__enter__": STRICT,
         "Span.__exit__": STRICT,
+    },
+    "tpu_rl/utils/platform.py": {
+        # The memory book's stamp and the owners' count-up / count-down sit
+        # beside the learner's dispatch (publish, log-sync, ckpt-save; the
+        # batch count once a dispatch): the runtime's own answer, one row and
+        # the ring's counter sample, nothing else. What a kept stamp costs
+        # more (the owners alive at it) is the cold _keep().
+        "MemoryBook.stamp": STRICT,
+        "MemoryBook.hold": STRICT,
+        "MemoryBook.drop": STRICT,
+        "MemoryBook.count": STRICT,
+    },
+    "tpu_rl/data/prefetch.py": {
+        "PrefetchPipeline.held": STRICT,
     },
     "tpu_rl/runtime/worker.py": {
         "Worker.run": FMT,

@@ -36,7 +36,10 @@ INVENTORY: dict[str, dict[str, frozenset[str]]] = {
         # sentinel; queue handoff orders the publication.
         # keep: a flag of the feeder's own open Span (obs/trace.py), an
         # object no other thread ever holds.
-        "PrefetchPipeline._run": frozenset({"_error", "keep"}),
+        # _started: the feeder's own monotonic counter of batches whose
+        # placement has begun; the learner's memory book reads it (held()),
+        # where a torn read is one batch off in one stamp.
+        "PrefetchPipeline._run": frozenset({"_error", "keep", "_started"}),
     },
     "tpu_rl/obs/trace.py": {
         # The trace.json writer: its cursor and fragments are its own

@@ -239,10 +239,15 @@ class Checkpointer:
         keep: int = 5,
         async_save: bool = False,
         tracer=None,
+        book=None,
     ):
         # The owner's TraceRecorder: a save's blocking D2H and its disk write
         # are the spans "ckpt-d2h" / "ckpt-write" of the lane "ckpt-writer".
         self._span = span_of(tracer)
+        # The owner's MemoryBook: an async save's device snapshot is one
+        # "ckpt-snapshot" from save() until the writer has it on the host,
+        # and the books are stamped there ("ckpt-d2h").
+        self._book = book
         self.model_dir = os.path.abspath(model_dir)
         self.algo = algo
         self.keep = max(1, int(keep))
@@ -322,11 +327,18 @@ class Checkpointer:
             self._record(time.perf_counter() - t0)
             return path
         snap = _snapshot(state)
+        book = self._book
+        if book is not None:
+            book.declare("ckpt-snapshot", snap)
+            book.hold("ckpt-snapshot")
         self._ensure_thread()
         with self._cond:
             if self._queued is not None:
                 self.n_skipped += 1  # latest wins: newer snapshot replaces
+                if book is not None:
+                    book.drop("ckpt-snapshot")
             self._queued = (snap, idx, meta)
+            snap = None  # the writer's from here on: this frame must not hold it
             self._cond.notify_all()
         return path
 
@@ -348,6 +360,9 @@ class Checkpointer:
                 # and not when the next save rebinds the name: this frame
                 # would hold it until then, beside the next snapshot.
                 snap = None
+                if self._book is not None:
+                    self._book.drop("ckpt-snapshot")
+                    self._book.stamp("ckpt-d2h", idx, tid=LANE)
                 self._write(host_state, idx, meta)
                 dur: float | None = time.perf_counter() - t0
             except Exception as e:  # surfaced on the next save()/flush()

@@ -159,6 +159,9 @@ class SynchronousFeed:
     def qsize(self) -> int:
         return 0
 
+    def held(self) -> int:
+        return 0  # a batch is made inside get() and handed over at once
+
     def close(self) -> None:  # interface parity; nothing to drain
         pass
 
@@ -202,6 +205,7 @@ class PrefetchPipeline:
         self._error: BaseException | None = None
         self._closed = threading.Event()
         self._dispatched = 0  # dispatch batches handed to the learner
+        self._started = 0  # dispatch batches whose placement has begun
         self._thread = threading.Thread(target=self._run, name=name, daemon=True)
         self._thread.start()
 
@@ -252,6 +256,7 @@ class PrefetchPipeline:
                 pending.append(raw)
                 if len(pending) < self._chain:
                     continue
+                self._started += 1
                 with span("assemble", tid=LANE) as sp:
                     batch = self._assemble(pending)
                 pending = []
@@ -289,6 +294,14 @@ class PrefetchPipeline:
         """Prefetched dispatches currently queued (the queue-depth gauge:
         ~depth means the feed is ahead of the chip, ~0 means behind)."""
         return self._q.qsize()
+
+    def held(self) -> int:
+        """Dispatch batches that hold device memory on the feed's side: in
+        placement, or placed and not yet taken (at most ``depth``, by the
+        slots). Two counters with one writer each — the feeder's and the
+        consumer's — read without a lock: the learner's memory book counts
+        with it, between dispatches."""
+        return self._started - self._dispatched
 
     @property
     def dispatched(self) -> int:

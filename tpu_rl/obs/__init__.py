@@ -68,6 +68,7 @@ from tpu_rl.obs.perf import (
     PEAK_FLOPS,
     PerfTracker,
     ProfilerCapture,
+    device_memory_books,
     device_memory_bytes,
     device_peak_flops,
     maybe_perf_tracker,
@@ -121,6 +122,7 @@ __all__ = [
     "append_resume",
     "channel_name",
     "derive",
+    "device_memory_books",
     "device_memory_bytes",
     "device_peak_flops",
     "diff_snapshots",

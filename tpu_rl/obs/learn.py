@@ -401,6 +401,11 @@ class DiagAccumulator:
         else:
             self._acc = self._fold(self._acc, diag, stale)
 
+    @property
+    def live(self) -> bool:
+        """Sums are held on the device (folded, not yet handed over)."""
+        return self._acc is not None
+
     def take(self) -> dict | None:
         """Hand over the on-device sums as they stand — no sync, no
         readback — and start afresh: what was folded so far is the caller's

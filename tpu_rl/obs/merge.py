@@ -121,6 +121,8 @@ def merge_traces(docs: list[dict]) -> dict:
                         )
                 if name == LEARNER_HOP:
                     train_steps.append((ts, pid, tid, dur))
+            elif ev.get("ph") == "C":  # a counter sample rides its ring's clock
+                out["ts"] = base_us + float(ev.get("ts", 0.0))
             events.append(out)
 
     if not events:
@@ -146,7 +148,7 @@ def merge_traces(docs: list[dict]) -> dict:
     # Normalize the axis so the merged trace starts near zero.
     t0 = min(ev["ts"] for ev in events if ev.get("ph") == "X")
     for ev in events:
-        if ev.get("ph") == "X":
+        if ev.get("ph") in ("X", "C"):
             ev["ts"] -= t0
 
     # Flow events: one s -> t... -> f arrow chain per trace id. Each step

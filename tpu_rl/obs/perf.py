@@ -25,10 +25,12 @@ continuously:
   whose kind is not in the table is an error. ``TPU_RL_PEAK_FLOPS`` (env,
   FLOPs/s per device) is the CPU-smoke denominator only — it's what lets
   CPU smokes exercise the MFU path, and it is ignored on any other backend.
-- :func:`device_memory_bytes` — in-use/peak watermarks from
-  ``device.memory_stats()`` (the peak counts live buffers and the programs'
-  reserved scratch); backends that report none (CPU) fall back to process
-  RSS with a module-tracked high-water mark.
+- :func:`device_memory_books` — the one reader of ``device.memory_stats()``:
+  per device the live bytes, the two lifetime peaks (live buffers; the
+  programs' reserved scratch) and the limit, None where a backend keeps no
+  books. :func:`device_memory_bytes` — the gauges' in-use/peak watermarks
+  from one such row (the peak adds the two books); backends that keep none
+  (CPU) fall back to process RSS with a module-tracked high-water mark.
 - :func:`process_self_stats` — RSS + open-fd count from ``/proc/self``
   (no psutil), cheap enough to refresh on the telemetry emit cadence.
 - :class:`ProfilerCapture` — the one gate every profiler path goes
@@ -119,30 +121,56 @@ def process_self_stats() -> tuple[float, int]:
     return rss, n_fds
 
 
-def device_memory_bytes(device=None) -> tuple[float, float]:
-    """(bytes in use, peak bytes) for the role's first device. Backends
-    whose ``memory_stats()`` is None/absent (CPU) fall back to process RSS,
-    with the peak tracked as a module-level high-water mark so the
-    watermark semantics survive the fallback."""
-    global _rss_peak
-    if device is None:
-        import jax
-
-        device = jax.devices()[0]
-    try:
-        stats = device.memory_stats()
-    except Exception:  # noqa: BLE001 — not part of the stable device API
-        stats = None
-    if stats:
-        in_use = float(stats.get("bytes_in_use", 0.0))
-        # The TPU runtime keeps two books: live buffers (peak_bytes_in_use)
-        # and the scratch reserved for running programs
-        # (peak_bytes_reserved: activations and temporaries). Both peak
-        # while an update runs, so the peak is their sum.
-        peak = float(stats.get("peak_bytes_in_use", in_use)) + float(
-            stats.get("peak_bytes_reserved", 0.0)
+def device_memory_books(devices) -> list:
+    """The runtime's own books of each of ``devices``, as ``(bytes_in_use,
+    peak_bytes_in_use, peak_bytes_reserved, bytes_limit | None)`` — or None
+    for a device whose backend keeps none (the CPU's ``memory_stats()`` is
+    None or absent). The TPU runtime keeps two: live buffers
+    (``bytes_in_use`` and its lifetime peak: parameters, optimizer state,
+    batches, snapshots) and the scratch reserved for running programs
+    (``peak_bytes_reserved``: activations and temporaries); a runtime with
+    one book reads 0 for the second. This is the repo's one call site of
+    ``device.memory_stats()``: the learner's memory book
+    (``utils.platform.MemoryBook``) and the gauges below both read here."""
+    rows = []
+    for device in devices:
+        try:
+            stats = device.memory_stats()
+        except Exception:  # noqa: BLE001 — not part of the stable device API
+            stats = None
+        if not stats:
+            rows.append(None)
+            continue
+        in_use = int(stats.get("bytes_in_use", 0))
+        limit = stats.get("bytes_limit")
+        rows.append(
+            (
+                in_use,
+                int(stats.get("peak_bytes_in_use", in_use)),
+                int(stats.get("peak_bytes_reserved", 0)),
+                None if limit is None else int(limit),
+            )
         )
-        return in_use, peak
+    return rows
+
+
+def device_memory_bytes(device=None, books=None) -> tuple[float, float]:
+    """(bytes in use, peak bytes) of one device: from ``books`` (one row of
+    :func:`device_memory_books`, where the caller has just read one), else
+    from the role's first device. Both of the runtime's books peak while an
+    update runs, so the peak is their sum. Backends that keep no books (CPU)
+    fall back to process RSS, with the peak tracked as a module-level
+    high-water mark so the watermark semantics survive the fallback."""
+    global _rss_peak
+    if books is None:
+        if device is None:
+            import jax
+
+            device = jax.devices()[0]
+        books = device_memory_books((device,))[0]
+    if books is not None:
+        in_use, peak_in_use, peak_reserved, _limit = books
+        return float(in_use), float(peak_in_use) + float(peak_reserved)
     rss, _ = process_self_stats()
     _rss_peak = max(_rss_peak, rss)
     return rss, _rss_peak

@@ -62,6 +62,16 @@ follows the first ``log-sync``, or at the sync itself where nothing can hide
 them) any compilation of 10 ms or more is one line of the role's log: ``[learner] compiled <program> in <s> s (cache hit|miss|off)
 under main/<span> update <n>``.
 
+**A counter sample** (:meth:`TraceRecorder.sample`) is the ring's other kind
+of entry: one ``(name, value)`` at an instant of the ring's clock, on a lane
+(``tid=``, spelled as ``span()`` and ``add()`` spell it). It lies in the same
+ring, so the flight recorder's dump and ``trace.json`` hold it, exported as a
+Chrome counter event (``"ph": "C"``) that Perfetto draws as a track under the
+lanes and beside an open capture's device ops. The learner's memory book
+(``utils.platform.MemoryBook``) samples ``device-mem`` — live bytes of its
+fullest chip — wherever it stamps. :meth:`TraceRecorder.entries` lists spans
+only.
+
 Cost model: a span is one clock pair, one small object, one deque append
 under a lock, and the annotation's enter/exit. The annotation's name is
 built once per site (:meth:`TraceRecorder._site`), never per call.
@@ -206,6 +216,22 @@ class TraceRecorder:
             self._events.append((name, start - self._t0, dur, tid, args))
             self.n_recorded += 1
 
+    def sample(
+        self, name: str, value: float, tid: str = "main", at: float | None = None
+    ) -> None:
+        """One counter sample: ``name`` read ``value`` at ``at`` (a reading of
+        :attr:`now`; None: this instant). A ring entry whose duration is
+        None, with the value where a span has its ``args``."""
+        if at is None:
+            at = self.now()
+        with self._lock:
+            self._events.append((name, at - self._t0, None, tid, value))
+            self.n_recorded += 1
+
+    def unix_s(self, at: float) -> float:
+        """A reading of :attr:`now` as unix seconds."""
+        return self.wall_anchor_ns / 1e9 + (at - self._t0)
+
     def span(
         self,
         name: str,
@@ -248,13 +274,18 @@ class TraceRecorder:
         events, n = self.spans_since(0)
         anchor = self.wall_anchor_ns / 1e9
         return (
-            [[tid, name, anchor + rel, dur, args] for name, rel, dur, tid, args in events],
+            [
+                [tid, name, anchor + rel, dur, args]
+                for name, rel, dur, tid, args in events
+                if dur is not None  # a counter sample is no span
+            ],
             n > len(events),
         )
 
     def spans_since(self, seq: int) -> tuple[list, int]:
-        """Spans recorded after the first ``seq`` (those the ring still
-        holds), with the new count: the exporter's incremental read."""
+        """Entries (spans and counter samples) recorded after the first
+        ``seq`` (those the ring still holds), with the new count: the
+        exporter's incremental read."""
         with self._lock:
             n = self.n_recorded
             fresh = min(n - seq, len(self._events))
@@ -264,6 +295,15 @@ class TraceRecorder:
     # ---------------------------------------------------------------- export
     def _event(self, span: tuple, tids: dict) -> dict:
         name, rel, dur, tid, args = span
+        if dur is None:  # a counter sample: ``args`` is its value
+            return {
+                "name": name,
+                "ph": "C",
+                "ts": rel * 1e6,
+                "pid": self.pid,
+                "tid": tids.setdefault(tid, len(tids)),
+                "args": {name: args},
+            }
         ev = {
             "name": name,
             "ph": "X",
@@ -313,8 +353,9 @@ class TraceRecorder:
         return meta
 
     def to_chrome(self, extra_meta: dict | None = None) -> dict:
-        """Chrome trace-event JSON object format: complete ("X") events with
-        microsecond timestamps, one named lane per recording thread. The
+        """Chrome trace-event JSON object format: complete ("X") events and
+        counter ("C") events with microsecond timestamps, one named lane per
+        recording thread. The
         top-level ``meta`` block (role/pid/host + the wall-clock anchor of
         the ring's epoch) is what makes dumps from different processes
         mergeable in principle — without it a ring's timestamps are an

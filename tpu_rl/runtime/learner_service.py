@@ -135,6 +135,11 @@ class AsyncPublisher:
     fresh state's, then the step outputs') runs both, so neither compiles
     later in the run.
 
+    ``book`` is the owner's memory book (``utils.platform.MemoryBook``): a
+    snapshot newly allocated counts one ``publish-snapshot`` up, the one this
+    thread has brought to the host counts one down, and the books are
+    stamped there (``publish-d2h``).
+
     Latest-wins slot (not a queue): under backpressure workers want the
     NEWEST weights, and per-snapshot order is irrelevant once superseded.
     The ZMQ ``Pub`` is used from this thread only after construction
@@ -143,9 +148,10 @@ class AsyncPublisher:
     A send failure re-raises out of the next ``publish()``.
     """
 
-    def __init__(self, pub: Pub, tracer):
+    def __init__(self, pub: Pub, tracer, book=None):
         self._pub = pub
         self._span = tracer.span  # lane "publisher"
+        self._book = book
         self._cond = threading.Condition()
         self._pending = None
         self._error: BaseException | None = None
@@ -167,6 +173,8 @@ class AsyncPublisher:
             snap = snapshot_tree(actor, into=superseded[0])
         else:
             snap = snapshot_tree(actor)
+            if self._book is not None:
+                self._book.hold("publish-snapshot")
             if self._to_warm:
                 self._to_warm -= 1
                 snap = snapshot_tree(actor, into=snap)
@@ -191,6 +199,9 @@ class AsyncPublisher:
                 with self._span("publish-d2h", tid="publisher"):
                     actor = jax.device_get(snap)
                 snap = None  # only the host tree is needed through the send
+                if self._book is not None:
+                    self._book.drop("publish-snapshot")
+                    self._book.stamp("publish-d2h", ver, tid="publisher")
                 # "ver" is the learner update index that produced these
                 # weights: workers echo it through their rollouts so storage
                 # can measure per-worker policy staleness (tpu_rl.obs).
@@ -257,6 +268,7 @@ class LearnerService:
         self._publisher: AsyncPublisher | None = None
         self._inference = None  # InferenceService when act_mode="remote"
         self._tracer = None  # TraceRecorder of this process, set by run()
+        self._book = None  # its MemoryBook (the bring-up record's), likewise
         self._perf = None  # PerfTracker when telemetry is on
         self._prof_capture = None  # ProfilerCapture when any capture path is
         # Idle-rebroadcast odometer: model publishes fired from the starving
@@ -387,6 +399,14 @@ class LearnerService:
 
         with span("backend-open", tid="startup"):
             backend = BackendRecord("learner", cfg, mesh, tracer=tracer)
+            # The memory book (utils.platform.MemoryBook): the runtime's
+            # books stamped at the exit of every site below where a
+            # tree-sized buffer is made or let go, and the pieces this
+            # program itself holds counted as it makes them. "run": before
+            # anything of the learner's is allocated — what the process held
+            # and had ever held when the learner got it.
+            book = self._book = backend.memory
+            book.stamp("run", tid="startup")
             init_key = jax.random.key(self.seed)  # the first program to run
         # Spans "family", "train-state", "step-build" of the lane "startup".
         spec = get_algo(cfg.algo)
@@ -396,6 +416,7 @@ class LearnerService:
         del init_key  # a device buffer (512 B of the chip's peak) nobody reads again
 
         with span("restore", tid="startup"):
+            book.stamp("train-state", tid="startup")  # the build's, read on entry
             # ---- checkpoint resume (newest COMMITTED index wins) ----
             # Full-run resume: train state + update index + learner PRNG key +
             # run epoch, refused on config-fingerprint mismatch unless
@@ -412,6 +433,7 @@ class LearnerService:
                     keep=cfg.ckpt_keep,
                     async_save=cfg.ckpt_async,
                     tracer=tracer,
+                    book=book,
                 )
                 restored = ckpt.restore_run(
                     state, fingerprint=fingerprint, force=cfg.resume_force
@@ -432,6 +454,7 @@ class LearnerService:
             sa = self.stat_array
             if sa is not None and len(sa) > SLOT_RUN_EPOCH:
                 sa[SLOT_RUN_EPOCH] = float(self.run_epoch + 1)  # 0 = unknown
+            book.stamp("restore", tid="startup")
 
         with span("place", tid="startup"):
             # ---- compile: single-chip jit, data-parallel, or data x seq mesh ----
@@ -476,6 +499,9 @@ class LearnerService:
                     return jax.jit(step, donate_argnums=(0,))
 
             train_step = _wrap(train_step, cfg)
+            book.declare("train-state", state)
+            book.hold("train-state")
+            book.stamp("place", tid="startup")
 
         with span("wire", tid="startup"):
             # Two-phase entropy/lr anneal switch point (Config.entropy_anneal;
@@ -510,7 +536,9 @@ class LearnerService:
             # Async broadcast rides the same switch as the feed pipeline so
             # learner_prefetch=0 is a FULLY serial A/B baseline.
             self._publisher = (
-                AsyncPublisher(pub, tracer) if cfg.learner_prefetch > 0 else None
+                AsyncPublisher(pub, tracer, book)
+                if cfg.learner_prefetch > 0
+                else None
             )
             writer = make_writer(cfg.result_dir)
             logger = LearnerLogger(writer, cfg.algo)
@@ -626,16 +654,21 @@ class LearnerService:
                 else:
                     from tpu_rl.runtime.inference_service import InferenceService
 
+                serving = self._actor_snapshot(state)
+                book.declare("inference-params", serving)
+                book.hold("inference-params")
                 self._inference = InferenceService(
                     cfg,
                     family,
-                    self._actor_snapshot(state),
+                    serving,
                     self.inference_port,
                     timer=timer,
                     seed=self.seed,
                     version=start_idx,
                 ).start()
+                del serving
                 self._inference.wait_ready()
+            book.stamp("inference-start", tid="startup")
 
         # First broadcast so workers act with the resumed/initial policy
         # rather than their own random init. It answers any join request
@@ -711,7 +744,7 @@ class LearnerService:
             profiling = None if cfg.profile_dir is not None else False
             # A logged update's books: (its index, its scalars' handles, the
             # non-finite count's array as of it, the diag sums handed over,
-            # the end of its log-sync). The log-sync empties the pipeline,
+            # its log-sync's span). The log-sync empties the pipeline,
             # so nothing the next dispatch does not need runs in front of
             # it: the books are set aside at the crossing and closed right
             # after that dispatch has been issued, while the chip works.
@@ -719,6 +752,23 @@ class LearnerService:
             # One small output of each dispatched update that may not have
             # finished yet, oldest first (RUN_AHEAD).
             ahead: deque = deque()
+            book.stamp("feed-start", tid="startup")
+
+            def _stamp(site: str, update: int) -> None:
+                """A stamp of the main lane: first the pieces only this
+                thread can count — a batch is alive from the start of its
+                placement (the feed's count) until the update it was
+                dispatched into has finished; the diag sums while the
+                accumulator or a logged update's books hold them."""
+                while ahead and ahead[0].is_ready():
+                    ahead.popleft()
+                book.count("batch", feed.held() + len(ahead))
+                if diag_acc is not None:
+                    book.count(
+                        "diag",
+                        diag_acc.live + (books is not None and books[3] is not None),
+                    )
+                book.stamp(site, update)
 
             def _close_books(cause: str | None = None) -> str | None:
                 """Everything a logged update's read-back owes besides the
@@ -730,7 +780,7 @@ class LearnerService:
                 "rolled" (state, index and key are the restored ones: the
                 caller starts its iteration over) or None."""
                 nonlocal books, state, idx, key, nf_acc, nf_base, last_pub_m
-                b_idx, b_metrics, b_nf, b_diag, sync_end = books
+                b_idx, b_metrics, b_nf, b_diag, sp_sync = books
                 books = None
                 if cause is None:
                     self.n_log_behind_dispatch += 1
@@ -752,7 +802,9 @@ class LearnerService:
                     # what the ring holds of it (a long run's ring
                     # forgets), and from here on a compilation is
                     # reported by name. A no-op ever after.
-                    backend.record_startup(run_entry, loop_entry, sync_end)
+                    backend.record_startup(
+                        run_entry, loop_entry, sp_sync.t0 + sp_sync.secs
+                    )
                     print(
                         f"[learner] update {b_idx}  "
                         + "  ".join(
@@ -804,14 +856,17 @@ class LearnerService:
                     # must not land beside a running program's scratch),
                     # then drop what it folded.
                     jax.block_until_ready(state)
+                    book.hold("train-state")  # the restored beside the live one
                     rolled = self._rollback(
                         ckpt, state, mesh, pub, fingerprint, key,
                         watchdog.last_reason,
                     )
+                    book.drop("train-state")
                     if rolled is None:
                         return None
                     state, idx, key = rolled
                     ahead.clear()
+                    _stamp("rollback", idx)
                     nf_acc = b_nf
                     if diag_acc is not None:
                         diag_acc.take()
@@ -917,6 +972,7 @@ class LearnerService:
                         # lower against.
                         self._perf.capture(train_step, state, batch, sub_key)
                     backend.add_program(train_step, state, batch, sub_key)
+                    book.declare("batch", batch, bound=cfg.learner_prefetch + RUN_AHEAD)
                 # learner-step-time is the host time of an asynchronous
                 # dispatch, not device time: it reads as the device's only
                 # when the dispatch queue is full and the call blocks.
@@ -960,9 +1016,12 @@ class LearnerService:
                         # Snapshot (not reference): the NEXT dispatch donates
                         # this state's buffers, and the serve thread must
                         # never act on deleted arrays.
+                        book.hold("inference-params")  # the swap in flight
                         self._inference.set_params(
                             self._actor_snapshot(state), version=idx + chain
                         )
+                        book.drop("inference-params")  # the one it replaced
+                        _stamp("inference-swap", idx + chain)
                 with span("account"):
                     # The dispatch critical path — chip-wait + queue-wait +
                     # step, the throughput window — drives achieved FLOPs/s
@@ -976,6 +1035,7 @@ class LearnerService:
                         self._perf.note(critical_secs)
                     timer.record("learner-batching-time", feed_secs)
                     timer.record_gauge("learner-queue-depth", feed.qsize())
+                    book.count("batch", feed.held() + len(ahead))
                     timer.record(
                         "learner-throughput", critical_secs,
                         check_throughput=True,
@@ -1091,6 +1151,8 @@ class LearnerService:
                         jax.block_until_ready(metrics)
                         ahead.clear()
                         taken = diag_acc.take() if diag_acc is not None else None
+                        if taken is not None:
+                            book.declare("diag", taken)
                         if save_due:
                             cause = "save"
                         elif (
@@ -1107,7 +1169,8 @@ class LearnerService:
                             cause = "empty feed"
                         else:
                             cause = None
-                    books = (idx, metrics, nf_acc, taken, sp_sync.t0 + sp_sync.secs)
+                        books = (idx, metrics, nf_acc, taken, sp_sync)
+                        _stamp("log-sync", idx)
                     if cause is not None:
                         verdict = _close_books(cause)
                         if verdict == "stop":
@@ -1126,6 +1189,7 @@ class LearnerService:
                     # async is off.
                     with span("ckpt-save", bucket=CKPT):
                         ckpt.save(state, idx, meta=_ckpt_meta())
+                        _stamp("ckpt-save", idx)
                 if solved:
                     break
             if books is not None:
@@ -1142,6 +1206,9 @@ class LearnerService:
                 f"{self.n_feed['copied']} copied", flush=True,
             )
         finally:
+            # The loop's end, for the memory book: what the shutdown makes
+            # from here on (the last save's snapshot) is not the window's.
+            _stamp("close", idx)
             # Feeder first (stops shm sampling), then the publisher (joins
             # its thread, flushing the final snapshot — the Pub socket is
             # only safe to close once no other thread can touch it).
@@ -1347,7 +1414,9 @@ class LearnerService:
         policy staleness. With the async publisher the caller only snapshots
         (one launch); the D2H, the blocking device_get and the ZMQ send run on
         the publisher thread (lane "publisher"). Callers hold the main-lane span
-        this belongs to (``publish``, ``idle-poll`` or ``rollback``)."""
+        this belongs to (``publish``, ``idle-poll`` or ``rollback``). Where a
+        broadcast was made the memory book is stamped (``publish``): the
+        snapshot has just been allocated."""
         actor = (
             state.actor_params
             if hasattr(state, "actor_params")
@@ -1358,6 +1427,8 @@ class LearnerService:
 
             nbytes = sum(x.nbytes for x in jax.tree.leaves(actor))
             self._broadcast_fits = fits_frame(nbytes)
+            if self._book is not None:
+                self._book.declare("publish-snapshot", actor)
             if not self._broadcast_fits:
                 if self.cfg.act_mode != "remote":
                     raise ValueError(
@@ -1388,6 +1459,8 @@ class LearnerService:
                     "t_tx": time.time_ns(),
                 },
             )
+        if self._book is not None:
+            self._book.stamp("publish", ver)
 
     def _consume_join_flag(self) -> bool:
         """Clear a pending join request and count it answered. A PUB frame
@@ -1600,7 +1673,11 @@ class LearnerService:
             if mfu is not None:
                 reg.gauge("learner-mfu").set(mfu)
             reg.counter("learner-xla-recompiles").set_total(perf.recompiles)
-            mem_used, mem_peak = device_memory_bytes(self._device)
+            # From the memory book's newest stamp: the loop reads the
+            # runtime's books in one place (a backend without them: RSS).
+            mem_used, mem_peak = device_memory_bytes(
+                self._device, books=self._book.last_books
+            )
             reg.gauge("learner-device-mem-bytes").set(mem_used)
             reg.gauge("learner-device-mem-peak-bytes").set(mem_peak)
             rss, n_fds = process_self_stats()

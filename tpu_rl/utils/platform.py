@@ -9,6 +9,12 @@ manager, workers, fleet replicas 1..N-1) are spawned with
 ``JAX_PLATFORMS=cpu`` in their environment (``runtime.runner.Supervisor``),
 which stock JAX honours.
 
+The record (``result_dir/backend-<role>.json``) holds, beside the devices and
+the main program's kernel paths: ``startup`` (the recorder's ring up to the
+first ``log-sync``), ``compiles`` (:class:`CompileClock`) and ``memory``
+(:class:`MemoryBook`: who held the chip's memory, stamped where tree-sized
+buffers change hands; ``benchmarks/MEMORY.md`` has the schema).
+
 jax imports are lazy: this module is imported by supervisors that must never
 initialise a backend.
 """
@@ -213,6 +219,256 @@ class CompileClock:
         jax.monitoring.unregister_event_listener(self._event)
 
 
+# The owners a chip owner's memory book knows, in the order of a stamp's
+# ``alive`` counts.
+MEMORY_OWNERS = (
+    "train-state", "batch", "publish-snapshot", "inference-params",
+    "ckpt-snapshot", "diag",
+)
+MAX_LOOP_STAMPS = 256  # kept stamps of the loop; the start-up's are all kept
+DEVICE_MEM = "device-mem"  # the recorder's counter track
+
+
+def shard_nbytes(tree) -> int:
+    """Bytes ``tree`` holds on one device: per leaf its first addressable
+    shard's ``nbytes`` (a replicated leaf is whole on every chip, a sharded
+    one holds its part), a host leaf's own."""
+    import jax
+
+    total = 0
+    for leaf in jax.tree.leaves(tree):
+        shards = getattr(leaf, "addressable_shards", None)
+        total += int(shards[0].data.nbytes if shards else getattr(leaf, "nbytes", 0))
+    return total
+
+
+class _Owner:
+    __slots__ = ("bytes_each", "bound", "alive", "alive_max")
+
+    def __init__(self):
+        self.bytes_each = None  # until the program has made one and sized it
+        self.bound = None  # what the program's own design holds ``alive`` to
+        self.alive = 0
+        self.alive_max = 0
+
+
+class MemoryBook:
+    """Who holds a chip owner's device memory, on its recorder's clock.
+
+    Two kinds of entry. **Owners** are what the program itself allocates in
+    tree-sized pieces (``MEMORY_OWNERS``): the program says how large one is
+    (``declare``: the leaves' own ``nbytes`` on one device) and counts them
+    up and down where it makes one and lets go of it (``hold`` / ``drop``, or
+    ``count`` where the number is read off a queue). **Stamps** are readings
+    of the runtime's books (``obs.perf.device_memory_books``, the fullest
+    device's row) taken at the exit of the sites where such a piece changes
+    hands — never per dispatch: ``[site, unix_s, update | None,
+    bytes_in_use, peak_bytes_in_use, peak_bytes_reserved]`` with the owners
+    alive at that instant, and a sample of the recorder's ``device-mem``
+    counter track. A backend without books stamps ``None`` in the runtime's
+    three columns (never the process's RSS) and still counts its owners.
+
+    The record (:meth:`record`, ``memory`` of ``backend-<role>.json``) keeps
+    every start-up stamp (lane ``startup``) and of the others a bounded set:
+    the first, the first of each site, each that set a new maximum of
+    ``bytes_in_use``, each at which a lifetime peak rose, and the last.
+    ``window`` is what the loop held once it ran: from the first
+    ``log-sync`` to the stamp ``close`` (the loop's end: what the shutdown
+    makes after it — a last save's snapshot — is stamped and kept, and is
+    not the window's), the stamp with the most live bytes and its owners;
+    whether a lifetime peak rose since (then the runtime's peak is the
+    window's own, not the set-up's); ``scratch_bytes``, the reserved book
+    when the first update had finished, and whether this role raised it over
+    the ``run`` stamp's reading.
+
+    ``stamp``, ``hold``, ``drop`` and ``count`` are called between
+    dispatches: beyond the runtime's own answer and the ring entry they
+    allocate nothing unless the stamp is kept (``_keep``)."""
+
+    SYNC, CLOSE = "log-sync", "close"
+
+    def __init__(self, devices, tracer=None):
+        from tpu_rl.obs.perf import device_memory_books
+
+        self._devices = tuple(devices)
+        self._tracer = tracer
+        self._read = device_memory_books
+        self._lock = threading.Lock()
+        self._owners = {name: _Owner() for name in MEMORY_OWNERS}
+        self._stamps: list[tuple] = []  # kept rows
+        self._alive: list[tuple] = []  # the owners alive at each kept row
+        self._sites: set[str] = set()
+        self.n_stamps = 0
+        self.n_dropped = 0  # qualified for keeping past MAX_LOOP_STAMPS
+        self._n_loop_kept = 0
+        self._max_in_use = -1  # over the stamps outside the lane startup
+        self._peaks = (-1, -1)  # the lifetime peaks as last read
+        self.last = None  # the newest stamp, kept or not
+        self._last_kept = True
+        self.last_books = None  # its row as the runtime gave it (the gauges')
+        self.bytes_limit = None
+        self._run = None  # the stamp "run": before the role allocated a byte
+        self._sync0 = None  # the first log-sync's stamp
+        self._window = None  # (row, alive) of the most live bytes since
+        self._end_peaks = None  # the lifetime peaks at the stamp "close"
+
+    # ---------------------------------------------------------------- owners
+    def declare(self, name: str, tree, bound=None) -> None:
+        """Size one piece of owner ``name`` from the one just made (once;
+        later calls are no-ops)."""
+        owner = self._owners[name]
+        if owner.bytes_each is None:
+            owner.bytes_each = shard_nbytes(tree)
+            owner.bound = bound
+
+    def hold(self, name: str, n: int = 1) -> None:
+        owner = self._owners[name]
+        with self._lock:
+            owner.alive += n
+            if owner.alive > owner.alive_max:
+                owner.alive_max = owner.alive
+
+    def drop(self, name: str, n: int = 1) -> None:
+        owner = self._owners[name]
+        with self._lock:
+            owner.alive = max(0, owner.alive - n)
+
+    def count(self, name: str, alive: int) -> None:
+        """Set how many of ``name`` are alive (read off the program's own
+        queues by the one thread that can see them)."""
+        owner = self._owners[name]
+        with self._lock:
+            owner.alive = alive
+            if alive > owner.alive_max:
+                owner.alive_max = alive
+
+    # ---------------------------------------------------------------- stamps
+    def stamp(self, site: str, update: int | None = None, tid: str = "main") -> None:
+        tracer = self._tracer
+        at = time.time() if tracer is None else tracer.now()
+        books = None
+        for row in self._read(self._devices):  # the fullest device's
+            if row is not None and (books is None or row[0] > books[0]):
+                books = row
+        if books is None:
+            in_use = peak = reserved = None
+        else:
+            in_use, peak, reserved, self.bytes_limit = books
+            if tracer is not None:
+                tracer.sample(DEVICE_MEM, in_use, tid=tid, at=at)
+        unix = at if tracer is None else tracer.unix_s(at)
+        row = (site, unix, update, in_use, peak, reserved)
+        with self._lock:
+            self.n_stamps += 1
+            self.last, self.last_books = row, books
+            startup = tid == "startup"
+            first_sync = self._sync0 is None and site == self.SYNC
+            keep = startup or first_sync or site not in self._sites
+            if books is not None:
+                if peak > self._peaks[0] or reserved > self._peaks[1]:
+                    self._peaks = (max(peak, self._peaks[0]), max(reserved, self._peaks[1]))
+                    keep = True
+                if not startup and in_use > self._max_in_use:
+                    self._max_in_use = in_use
+                    keep = True
+            in_window = first_sync or (
+                self._sync0 is not None and self._end_peaks is None
+            )
+            top = in_window and (
+                self._window is None
+                or (in_use is not None and in_use > self._window[0][3])
+            )
+            if in_window and site == self.CLOSE:
+                self._end_peaks = self._peaks
+            self._last_kept = False
+            if keep or top:
+                self._keep(row, startup, first_sync, keep, top)
+
+    def _keep(self, row, startup, first_sync, keep, top) -> None:
+        """The cold part of a stamp, under the lock: the owners alive at it,
+        what the window remembers, and the row's place in the record."""
+        alive = tuple(self._owners[name].alive for name in MEMORY_OWNERS)
+        if row[0] == "run" and self._run is None:
+            self._run = row
+        if first_sync:
+            self._sync0 = row
+        if top:
+            self._window = (row, alive)
+        if not keep:
+            return
+        if not startup:
+            if self._n_loop_kept >= MAX_LOOP_STAMPS:
+                self.n_dropped += 1
+                return
+            self._n_loop_kept += 1
+        self._sites.add(row[0])
+        self._stamps.append(row)
+        self._alive.append(alive)
+        self._last_kept = True
+
+    # ---------------------------------------------------------------- record
+    def record(self) -> dict:
+        """``memory`` of ``backend-<role>.json`` as it stands."""
+        with self._lock:
+            stamps = [list(r) for r in self._stamps]
+            alive = list(self._alive)
+            if self.last is not None and not self._last_kept:
+                stamps.append(list(self.last))
+                alive.append(tuple(self._owners[n].alive for n in MEMORY_OWNERS))
+            owners = {
+                name: {
+                    "bytes_each": o.bytes_each,
+                    "alive_max": o.alive_max,
+                    "bound": o.bound,
+                    "alive": [a[i] for a in alive],
+                }
+                for i, (name, o) in enumerate(self._owners.items())
+            }
+            window = None
+            if self._sync0 is not None and self._window is not None:
+                top, top_alive = self._window
+                sync_peak, sync_reserved = self._sync0[4], self._sync0[5]
+                peaks = self._end_peaks or self._peaks
+                rose = sync_peak is not None and peaks[0] > sync_peak
+                window = {
+                    "first_sync_unix_s": self._sync0[1],
+                    "stamp": list(top),
+                    "alive": dict(zip(MEMORY_OWNERS, top_alive)),
+                    "in_use_peak_rose": rose,
+                    "reserved_peak_rose": (
+                        sync_reserved is not None and peaks[1] > sync_reserved
+                    ),
+                    # The loop's live peak: the runtime's own where it rose
+                    # inside the window (exact), else the fullest stamp (a
+                    # lower bound: the set-up's peak hides the loop's).
+                    "live_peak_bytes": peaks[0] if rose else top[3],
+                    "scratch_bytes": sync_reserved,
+                    "raised_by_learner": (
+                        None if sync_reserved is None or self._run is None
+                        or self._run[5] is None else sync_reserved > self._run[5]
+                    ),
+                }
+            return {
+                "devices": len(self._devices),
+                "bytes_limit": self.bytes_limit,
+                "stamps": stamps,
+                "stamps_taken": self.n_stamps,
+                "stamps_dropped": self.n_dropped,
+                "owners": owners,
+                "window": window,
+            }
+
+
+def _local_devices(mesh) -> list:
+    """The devices of ``mesh`` this process can read the books of; without a
+    mesh the first device, which a one-chip role runs on."""
+    import jax
+
+    if mesh is None:
+        return jax.devices()[:1]
+    return [d for d in mesh.devices.flat if d.process_index == jax.process_index()]
+
+
 def backend_info(mesh=None) -> dict:
     """The devices this process runs on, as JAX reports them."""
     import jax
@@ -251,6 +507,28 @@ def program_paths(lowered) -> dict:
     }
 
 
+def _executable_memory(lowered) -> tuple | None:
+    """The compiler's own sizes of the program ``lowered`` became — from the
+    executable the jit call already holds, never by compiling: a dispatch
+    compiles the lowering it finds in jit's cache (this one, made from the
+    same arguments just before it) and leaves the executable on it. Where
+    this JAX keeps it elsewhere, or the program has not run, None."""
+    computation = getattr(lowered, "_lowering", None)
+    if getattr(computation, "_executable", None) is None:
+        return None
+    try:
+        sizes = lowered.compile().memory_analysis()
+        wrapped = _JIT_WRAP.match(getattr(computation, "_name", ""))
+        return wrapped.group(1) if wrapped else "", {
+            "temp_bytes": int(sizes.temp_size_in_bytes),
+            "argument_bytes": int(sizes.argument_size_in_bytes),
+            "output_bytes": int(sizes.output_size_in_bytes),
+            "alias_bytes": int(sizes.alias_size_in_bytes),
+        }
+    except Exception:  # noqa: BLE001 — a backend without the analysis
+        return None
+
+
 class BackendRecord:
     """An accelerator-owning role's bring-up, opened first thing after any
     multihost init: compile cache, device check, one start-up log line, and
@@ -258,11 +536,18 @@ class BackendRecord:
     run learns which kernel paths its main program took, when its first
     update has finished on the device (``startup``: the recorder's ring so
     far, which a long run's ring forgets) and, at close, what it spent
-    compiling (three totals and ``compiles``, :meth:`CompileClock.record`).
+    compiling (three totals and ``compiles``, :meth:`CompileClock.record`;
+    the main program's row gains ``memory``: the compiler's temporary,
+    argument, output and alias sizes, read from the executable the first
+    dispatch left behind, no second compilation).
     ``chip_smoke.py`` asserts on the file instead of trusting an exit code;
     the benchmark's ``setup.*`` metrics read ``startup`` and ``compiles``.
     ``tracer`` is the role's :class:`~tpu_rl.obs.trace.TraceRecorder`, if it
-    has one: compilations then are spans of its lane ``xla`` too."""
+    has one: compilations then are spans of its lane ``xla`` too. ``memory``
+    is the role's :class:`MemoryBook` over the devices of ``mesh`` this
+    process addresses (its first device without one); a role that stamps it
+    (the learner) finds ``memory`` in the record wherever ``startup`` or
+    ``compiles`` is written."""
 
     def __init__(self, role: str, cfg, mesh=None, tracer=None):
         cache = enable_compile_cache()
@@ -271,6 +556,9 @@ class BackendRecord:
         require_accelerator(role, cpu_ok=(cfg.learner_device == "cpu"))
         self._result_dir = cfg.result_dir
         self.info = {"role": role, **backend_info(mesh), "compile_cache": cache}
+        self.memory = MemoryBook(_local_devices(mesh), tracer)
+        self._lowered = None  # the main program's lowering, until it has run
+        self._program_memory = None  # (its name, the compiler's sizes)
         print(
             f"[{role}] backend {self.info['platform']} device_kind "
             f"{self.info['device_kind']!r} devices "
@@ -287,7 +575,11 @@ class BackendRecord:
         unconditionally before each dispatch."""
         if self._result_dir is None or "paths" in self.info:
             return
-        self.info.update(program_paths(jitted.lower(*args)))
+        # Kept until the first update has finished: the dispatch that follows
+        # compiles this very lowering, and its executable then answers
+        # ``memory_analysis()`` without another compilation.
+        self._lowered = jitted.lower(*args)
+        self.info.update(program_paths(self._lowered))
         print(
             f"[{self.info['role']}] program paths {self.info['paths']} "
             f"mosaic_calls {self.info['mosaic_calls']}",
@@ -308,6 +600,8 @@ class BackendRecord:
         if self._clock is None or self._clock.announce:
             return
         self._clock.announce = True
+        self._program_memory = _executable_memory(self._lowered)
+        self._lowered = None
         if self._result_dir is None or self._tracer is None:
             return
         spans, wrapped = self._tracer.entries()
@@ -319,16 +613,27 @@ class BackendRecord:
             "ring_wrapped": wrapped,
             "spans": spans,
         }
+        self._note_memory()
         self._write()
 
     def close(self) -> None:
         """Idempotent (loops close on every exit path)."""
         if self._clock is None:
             return
-        self.info.update(self._clock.stats(), compiles=self._clock.record())
+        compiles = self._clock.record()
+        if self._program_memory is not None:
+            name, sizes = self._program_memory
+            if name in compiles["programs"]:
+                compiles["programs"][name]["memory"] = sizes
+        self.info.update(self._clock.stats(), compiles=compiles)
         self._clock.close()
         self._clock = None
+        self._note_memory()
         self._write()
+
+    def _note_memory(self) -> None:
+        if self.memory.n_stamps:  # a role that keeps the book
+            self.info["memory"] = self.memory.record()
 
     def _write(self) -> None:
         if self._result_dir is None:
