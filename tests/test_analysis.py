@@ -105,6 +105,9 @@ def test_protocol_bad_fixture():
         ("PC001", 4),   # calcsize 12 != declared 10
         ("PC002", 14),  # TRACE_KINDS names Protocol.Ghost
         ("PC003", 8),   # enum values [0, 1, 3] have a gap
+        ("PC004", 17),  # Codec values [0, 1, 1]: the layout's mark is LZ4's
+        ("PC004", 23),  # PARTS_KINDS names a TRACE_KINDS member and a ghost
+        ("PC004", 23),
     ]
 
 

@@ -441,3 +441,52 @@ class TestContinuousBatching:
             cl.close()
             for s in svcs:
                 s.close()
+
+
+# ------------------------------------------- the model broadcast, received
+@pytest.mark.timeout(240)
+def test_replica_main_adopts_a_broadcast_in_the_model_layout():
+    """A standalone replica takes its weights from the learner's broadcast
+    (``Sub.drain`` -> ``set_params``): the frame is the Model layout — a head
+    and a part a leaf — and the version it carried comes back in the
+    replica's own telemetry, a client's reply says it too."""
+    from tpu_rl.fleet.replica import replica_main
+    from tpu_rl.runtime.protocol import Codec, encode
+    from tpu_rl.runtime.transport import MODEL_HWM, Pub, Sub
+
+    cfg = _fleet_config(telemetry_port=18140, telemetry_interval_s=0.2)
+    port, model_port, stat_port = BASE + 40, BASE + 41, BASE + 42
+    family = build_family(cfg)
+    host = jax.device_get(family.init_params(jax.random.key(3), seq_len=cfg.seq_len)["actor"])
+    payload = {"actor": host, "ver": 11, "epoch": 0, "t_tx": time.time_ns()}
+    frame = encode(Protocol.Model, payload)
+    assert len(frame) == 2 + len(jax.tree.leaves(host)) > 3 and frame[1][3] == Codec.PARTS
+    stat_sub = Sub("127.0.0.1", stat_port, bind=True)
+    model_pub = Pub("127.0.0.1", model_port, bind=True, hwm=MODEL_HWM)
+    stop = threading.Event()
+    rt = threading.Thread(
+        target=replica_main, daemon=True,
+        args=(cfg, 1, port, "127.0.0.1", model_port, stat_port, stop, None),
+    )
+    rt.start()
+    vers = []
+    try:
+        deadline = time.time() + 180
+        while time.time() < deadline and 11 not in vers:
+            model_pub.send(Protocol.Model, payload)  # re-send: slow joiner
+            got = stat_sub.recv(timeout_ms=200)
+            if got is not None and got[0] == Protocol.Telemetry and got[1].get("rid") == 1:
+                vers.append(got[1]["ver"])
+        assert 11 in vers, f"the replica never adopted the broadcast: {vers}"
+        cl = InferenceClient(cfg, "127.0.0.1", port, wid=0)
+        try:
+            reply = cl.act(_obs(2, cfg), np.ones(2, np.float32))
+            assert reply is not None and reply["ver"] == 11
+        finally:
+            cl.close()
+    finally:
+        stop.set()
+        rt.join(timeout=60)
+        model_pub.close()
+        stat_sub.close()
+    assert vers[0] in (-1, 11)  # random-init weights until the first adoption

@@ -61,9 +61,14 @@ def test_model_version_echo_worker_to_storage():
     )
     pub_stop = threading.Event()
 
+    t_tx = 1_700_000_000_123_456_789  # a learner's send stamp, recognisable
+
     def keep_publishing():  # re-send: ZMQ slow-joiner drops early frames
         while not pub_stop.is_set():
-            model_pub.send(Protocol.Model, {"actor": host_actor, "ver": 7})
+            model_pub.send(
+                Protocol.Model,
+                {"actor": host_actor, "ver": 7, "epoch": 0, "t_tx": t_tx},
+            )
             time.sleep(0.05)
 
     pt = threading.Thread(target=keep_publishing, daemon=True)
@@ -72,7 +77,9 @@ def test_model_version_echo_worker_to_storage():
     echoed, telemetry = [], []
     try:
         deadline = time.time() + 180
-        while time.time() < deadline and len(echoed) < 5:
+        while time.time() < deadline and not (
+            len(echoed) >= 5 and any("t0" in t["clk"] for t in telemetry)
+        ):
             got = relay_sub.recv(timeout_ms=500)
             if got is None:
                 continue
@@ -107,6 +114,10 @@ def test_model_version_echo_worker_to_storage():
     # Satellite: the worker's CLOCK-driven snapshots rode the same channel.
     assert telemetry, "worker emitted no Telemetry frames"
     assert telemetry[0]["role"] == "worker" and telemetry[0]["wid"] == 0
+    # the clock-sync echo is still paired: the broadcast's own t_tx (it rides
+    # the Model frame's description) with the worker's receive and send stamps
+    clk = next(t["clk"] for t in telemetry if "t0" in t["clk"])
+    assert clk["t0"] == t_tx and 0 < clk["t1"] <= clk["t2"]
     st._ingest(Protocol.Telemetry, telemetry[0], assembler)
     assert any(s.get("role") == "worker" for s, _ in agg.all_snapshots())
 

@@ -34,7 +34,7 @@ class GatedTracer:
         self.gate = threading.Event()
 
     @contextlib.contextmanager
-    def span(self, name, tid="main"):
+    def span(self, name, tid="main", args=None):
         if name == self.hold:
             assert self.gate.wait(timeout=30)
         yield
@@ -54,10 +54,19 @@ class BlockedPub:
         self.sent.append((proto, payload))
 
 
-def test_snapshot_survives_donation_and_the_sub_receives_the_same_bits():
-    from tpu_rl.runtime.transport import Pub, Sub
+def _until(cond, timeout=30.0):
+    """Poll ``cond`` (no assertion on how long it took)."""
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "condition never held"
+        time.sleep(0.002)
 
-    port = 29761
+
+@pytest.mark.parametrize("how", ["recv", "drain"])
+def test_snapshot_survives_donation_and_the_sub_receives_the_same_bits(how):
+    from tpu_rl.runtime.transport import MODEL_HWM, Pub, Sub
+
+    port = 29761 + (how == "drain")
     sub = Sub("127.0.0.1", port, bind=True)
     pub = Pub("127.0.0.1", port, bind=False)
     tracer = GatedTracer(hold="publish-d2h")
@@ -85,13 +94,22 @@ def test_snapshot_survives_donation_and_the_sub_receives_the_same_bits():
         assert all(x.is_deleted() for x in jax.tree.leaves(actor))
         tracer.gate.set()
 
-        while True:
-            msg = sub.recv(timeout_ms=10_000)
-            assert msg is not None
-            if msg[0] == Protocol.Model:
-                break
+        if how == "recv":
+            while True:
+                msg = sub.recv(timeout_ms=10_000)
+                assert msg is not None
+                if msg[0] == Protocol.Model:
+                    break
+        else:  # as the worker and the replica take it
+            got = []
+            _until(lambda: got.extend(
+                m for m in sub.drain(max_msgs=MODEL_HWM) if m[0] == Protocol.Model) or got)
+            msg = got[0]
+        assert sub.n_rejected == 0
         got = msg[1]
-        assert (got["ver"], got["epoch"]) == (7, 2) and got["t_tx"] > 0
+        assert set(got) == {"actor", "ver", "epoch", "t_tx"}
+        assert (got["ver"], got["epoch"]) == (7, 2)
+        assert isinstance(got["t_tx"], int) and 0 < got["t_tx"] <= time.time_ns()
         assert jax.tree.structure(got["actor"]) == jax.tree.structure(want)
         for a, b in zip(jax.tree.leaves(got["actor"]), jax.tree.leaves(want)):
             assert a.dtype == b.dtype and a.shape == b.shape
@@ -169,6 +187,36 @@ def test_latest_wins_under_a_blocked_pub_and_close_flushes():
     np.testing.assert_array_equal(
         pub.sent[-1][1]["actor"]["leaf03"], np.arange(28, dtype=np.float32).reshape(4, 7)
     )
+
+
+def test_every_snapshot_is_sent_when_the_publisher_is_given_time():
+    """sent / snapshots is the share that reaches the wire: 1 when nothing is
+    published while a send is under way. The span says what went out, and the
+    bytes are counted."""
+    from tpu_rl.runtime.protocol import encode, frame_args
+    from tpu_rl.runtime.transport import Pub
+
+    pub = Pub("127.0.0.1", 29763, bind=True)
+    tracer = TraceRecorder(capacity=256)
+    publisher = AsyncPublisher(pub, tracer)
+    n = 5
+    try:
+        for ver in range(1, n + 1):
+            publisher.publish(_tree(9, cols=19), ver=ver, epoch=1)
+            _until(lambda: publisher.n_sent == ver)  # noqa: B023
+    finally:
+        publisher.close()
+        pub.close()
+    assert publisher.n_sent == publisher.n_snapshots == n
+    sends = [e for e in tracer.entries()[0] if e[:2] == ["publisher", "publish-send"]]
+    assert len(sends) == n
+    want = frame_args(encode(Protocol.Model, {
+        "actor": jax.device_get(_tree(9, cols=19)), "ver": 1, "epoch": 1, "t_tx": time.time_ns()}))
+    assert want["parts"] == 2 + 9 and want["codec"] == "PARTS"
+    for *_, args in sends:
+        assert args == want  # bytes, parts, codec
+    assert publisher.n_bytes == n * want["bytes"]
+    assert want["bytes"] > sum(4 * (i + 1) * 19 for i in range(9))  # the leaves and the head
 
 
 def test_superseded_snapshots_are_recycled_not_piled_up():
@@ -290,7 +338,7 @@ def test_a_tree_over_the_frame_cap_is_never_snapshotted_packed_or_sent(
 
 def test_a_tree_under_the_frame_cap_is_sent_byte_for_byte_as_before(monkeypatch):
     from tpu_rl.runtime import learner_service, protocol
-    from tpu_rl.runtime.protocol import encode
+    from tpu_rl.runtime.protocol import Codec, encode
 
     actor = _tree(6, cols=43)
     nbytes = sum(x.nbytes for x in jax.tree.leaves(actor))
@@ -303,6 +351,7 @@ def test_a_tree_under_the_frame_cap_is_sent_byte_for_byte_as_before(monkeypatch)
         svc = _service(mode)
         svc._publish(pub, _State(actor), ver=9)
         assert pub.frames == [want] and svc.n_publish_oversize == 0
+        assert len(want) == 2 + 6 and want[1][3] == Codec.PARTS  # head + a part a leaf
     # and through the publisher thread: the same tree, version and epoch
     pub = BlockedPub()
     pub.gate.set()

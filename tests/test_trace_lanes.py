@@ -412,6 +412,13 @@ def test_learner_main_lane_is_exhaustive_and_the_ledger_still_sums(tmp_path):
     counters = _counters(svc)
     assert counters["learner-publish-snapshots"] == 41
     assert 1 <= counters["learner-publish-sent"] <= 41
+    # every send's span says what went to the socket (the Model layout: a head
+    # and a part a leaf, never compressed), and the counter is their sum
+    sends = [e["args"] for e in by_lane["publisher"] if e["name"] == "publish-send"]
+    assert len(sends) == counters["learner-publish-sent"]
+    assert {a["codec"] for a in sends} == {"PARTS"} and len({a["parts"] for a in sends}) == 1
+    assert sends[0]["parts"] > 3 and sends[0]["bytes"] > 0
+    assert counters["learner-publish-bytes"] == sum(a["bytes"] for a in sends)
     # ten logged updates, each with its books closed once: behind the next
     # dispatch, or in line at the crossing (five saves due, the last a stop too)
     assert counters["learner-log-behind-dispatch"] == svc.n_log_behind_dispatch

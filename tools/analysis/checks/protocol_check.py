@@ -12,6 +12,13 @@ Wire side (``tpu_rl/runtime/protocol.py``):
 - PC003: ``Protocol`` enum values must be unique and contiguous from 0 —
   ``TRACE_KINDS_MASK`` and the native validator index bitmask tables by
   proto byte.
+- PC004: the Model layout (``[proto, head, leaf 0, ..., leaf n-1]``, marked
+  by ``Codec.PARTS`` in the same 12-byte ``_HEADER`` — PC001 covers it, no
+  struct of its own): ``PARTS_KINDS``, the only kinds that may carry more
+  than three parts, must name members of the ``Protocol`` enum and share
+  none with ``TRACE_KINDS`` (a third part is a trailer or a leaf, never
+  either), and the ``Codec`` enum's values must be unique and fit the
+  header's one byte.
 
 Mailbox side (``tpu_rl/runtime/mailbox.py`` + every reader/writer):
 
@@ -40,6 +47,8 @@ PROTOCOL_FILE = "tpu_rl/runtime/protocol.py"
 STRUCT_DECLS = {"_HEADER": "HEADER_BYTES", "_TRAILER": "TRAILER_BYTES"}
 ENUM_NAME = "Protocol"
 ALLOWLIST_NAME = "TRACE_KINDS"
+PARTS_NAME = "PARTS_KINDS"
+CODEC_ENUM = "Codec"
 
 MAILBOX_FILE = "tpu_rl/runtime/mailbox.py"
 SLOT_PREFIX = "SLOT_"
@@ -126,20 +135,7 @@ def check_protocol_file(
             )
 
     # Protocol enum members.
-    members: dict[str, tuple[int, int]] = {}
-    enum_line = 1
-    for node in tree.body:
-        if isinstance(node, ast.ClassDef) and node.name == ENUM_NAME:
-            enum_line = node.lineno
-            for stmt in node.body:
-                if (
-                    isinstance(stmt, ast.Assign)
-                    and len(stmt.targets) == 1
-                    and isinstance(stmt.targets[0], ast.Name)
-                    and isinstance(stmt.value, ast.Constant)
-                    and isinstance(stmt.value.value, int)
-                ):
-                    members[stmt.targets[0].id] = (stmt.value.value, stmt.lineno)
+    members, enum_line = _enum_members(tree, ENUM_NAME)
     if not members:
         findings.append(
             Finding(
@@ -159,37 +155,96 @@ def check_protocol_file(
             )
 
     # TRACE_KINDS allowlist members must exist on the enum.
-    saw_allowlist = False
-    for node in tree.body:
-        if (
-            isinstance(node, ast.Assign)
-            and len(node.targets) == 1
-            and isinstance(node.targets[0], ast.Name)
-            and node.targets[0].id == ALLOWLIST_NAME
-        ):
-            saw_allowlist = True
-            for sub in ast.walk(node.value):
-                if (
-                    isinstance(sub, ast.Attribute)
-                    and isinstance(sub.value, ast.Name)
-                    and sub.value.id == ENUM_NAME
-                    and sub.attr not in members
-                ):
-                    findings.append(
-                        Finding(
-                            NAME, "PC002", rel_path, sub.lineno, ALLOWLIST_NAME,
-                            f"{ALLOWLIST_NAME} names {ENUM_NAME}.{sub.attr}, "
-                            f"which is not a member of {ENUM_NAME}",
-                        )
-                    )
-    if not saw_allowlist:
+    traced = _kinds(tree, ALLOWLIST_NAME)
+    if traced is None:
         findings.append(
             Finding(
                 NAME, "PC002", rel_path, 1, ALLOWLIST_NAME,
                 f"trace allowlist {ALLOWLIST_NAME} not found",
             )
         )
+    for attr, line in traced or ():
+        if attr not in members:
+            findings.append(
+                Finding(
+                    NAME, "PC002", rel_path, line, ALLOWLIST_NAME,
+                    f"{ALLOWLIST_NAME} names {ENUM_NAME}.{attr}, "
+                    f"which is not a member of {ENUM_NAME}",
+                )
+            )
+
+    # The Model layout: who may carry array parts, and its mark in the header.
+    # (A protocol file without the layout declares neither name.)
+    parted = _kinds(tree, PARTS_NAME)
+    for attr, line in parted or ():
+        if attr not in members:
+            findings.append(
+                Finding(
+                    NAME, "PC004", rel_path, line, PARTS_NAME,
+                    f"{PARTS_NAME} names {ENUM_NAME}.{attr}, "
+                    f"which is not a member of {ENUM_NAME}",
+                )
+            )
+        elif attr in {a for a, _ in traced or ()}:
+            findings.append(
+                Finding(
+                    NAME, "PC004", rel_path, line, PARTS_NAME,
+                    f"{ENUM_NAME}.{attr} is in {PARTS_NAME} and in "
+                    f"{ALLOWLIST_NAME}: its third part would be a trailer "
+                    "and a leaf at once",
+                )
+            )
+    codecs, codec_line = _enum_members(tree, CODEC_ENUM)
+    values = sorted(v for v, _ in codecs.values())
+    if values and (len(set(values)) != len(values) or not 0 <= values[0] <= values[-1] <= 255):
+        findings.append(
+            Finding(
+                NAME, "PC004", rel_path, codec_line, CODEC_ENUM,
+                f"{CODEC_ENUM} values {values} are not unique within the "
+                "header's one byte (a layout's mark would be misread)",
+            )
+        )
     return findings
+
+
+def _enum_members(tree: ast.Module, name: str) -> tuple[dict[str, tuple[int, int]], int]:
+    """Integer members of the class ``name`` -> (value, lineno), and the
+    class's own line."""
+    members: dict[str, tuple[int, int]] = {}
+    line = 1
+    for node in tree.body:
+        if isinstance(node, ast.ClassDef) and node.name == name:
+            line = node.lineno
+            for stmt in node.body:
+                if (
+                    isinstance(stmt, ast.Assign)
+                    and len(stmt.targets) == 1
+                    and isinstance(stmt.targets[0], ast.Name)
+                    and isinstance(stmt.value, ast.Constant)
+                    and isinstance(stmt.value.value, int)
+                ):
+                    members[stmt.targets[0].id] = (stmt.value.value, stmt.lineno)
+    return members, line
+
+
+def _kinds(tree: ast.Module, name: str) -> list[tuple[str, int]] | None:
+    """``Protocol.X`` attributes named in the module-level assign ``name`` ->
+    (X, lineno); None where the module has no such assign."""
+    for node in tree.body:
+        if (
+            isinstance(node, ast.Assign)
+            and len(node.targets) == 1
+            and isinstance(node.targets[0], ast.Name)
+            and node.targets[0].id == name
+        ):
+            return [
+                (sub.attr, sub.lineno)
+                for sub in ast.walk(node.value)
+                if isinstance(sub, ast.Attribute)
+                and isinstance(sub.value, ast.Name)
+                and sub.value.id == ENUM_NAME
+            ]
+    return None
 
 
 def check_mailbox_file(path: str | Path, rel_path: str) -> list[Finding]:

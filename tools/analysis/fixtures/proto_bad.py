@@ -12,3 +12,12 @@ class Protocol:
 
 
 TRACE_KINDS = frozenset({Protocol.Rollout, Protocol.Ghost})
+
+
+class Codec:
+    RAW = 0
+    LZ4 = 1
+    PARTS = 1
+
+
+PARTS_KINDS = frozenset({Protocol.Rollout, Protocol.Phantom})

@@ -11,3 +11,11 @@ class Protocol:
 
 
 TRACE_KINDS = frozenset({Protocol.Rollout})
+
+
+class Codec:
+    RAW = 0
+    PARTS = 3
+
+
+PARTS_KINDS = frozenset({Protocol.Model})

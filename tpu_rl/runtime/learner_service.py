@@ -60,7 +60,7 @@ from tpu_rl.runtime.mailbox import (
     SLOT_RUN_EPOCH,
 )
 from tpu_rl.runtime.manager import STAT_WINDOW
-from tpu_rl.runtime.protocol import Protocol, fits_frame
+from tpu_rl.runtime.protocol import Protocol, fits_frame, frame_args
 from tpu_rl.runtime.transport import MODEL_HWM, Pub, make_data_pub
 from tpu_rl.utils.metrics import LearnerLogger, make_writer
 from tpu_rl.utils.timer import ExecutionTimer
@@ -122,9 +122,11 @@ class AsyncPublisher:
     transfer is started on this thread, for the snapshot it actually takes
     from the slot (a superseded one is never transferred). The blocking
     ``jax.device_get`` — which must wait for the update that produced the
-    weights AND the transfer — plus codec + ZMQ send happen here too,
+    weights AND the transfer — plus framing + ZMQ send happen here too,
     overlapped with the learner's next dispatches; the device snapshot is
-    let go as soon as it is on the host.
+    let go as soon as it is on the host. The host tree is this thread's and
+    is never written: the frame's array parts are its leaves' own buffers
+    (``protocol.PARTS_KINDS``), and zmq holds them until they are on the wire.
 
     The loop dispatches ahead of the chip, and a buffer is allocated at
     dispatch: a snapshot still in the slot when the next one is made is
@@ -158,6 +160,7 @@ class AsyncPublisher:
         self._closed = False
         self.n_snapshots = 0  # made on the caller's lane
         self.n_sent = 0  # taken from the slot and sent; the rest were superseded
+        self.n_bytes = 0  # handed to the socket, all parts of every frame sent
         self._to_warm = 2  # placements whose recycling program has yet to run
         self._thread = threading.Thread(
             target=self._run, name="learner-publish", daemon=True
@@ -208,8 +211,11 @@ class AsyncPublisher:
                 # "epoch" is the run epoch (bumped on every checkpoint
                 # resume): workers adopt and echo it so storage can fence
                 # out frames acted under a pre-crash learner incarnation.
-                with self._span("publish-send", tid="publisher"):
-                    self._pub.send(
+                # The span's args (bytes / parts / codec of the frame) are
+                # known once it is framed: filled in before the span closes.
+                args: dict = {}
+                with self._span("publish-send", tid="publisher", args=args):
+                    sent = self._pub.send(
                         Protocol.Model,
                         {
                             "actor": actor,
@@ -223,8 +229,11 @@ class AsyncPublisher:
                             "t_tx": time.time_ns(),
                         },
                     )
+                    if sent is not None:  # chaos dropped it otherwise
+                        args.update(frame_args(sent))
                 with self._cond:
                     self.n_sent += 1
+                    self.n_bytes += args.get("bytes", 0)
             except BaseException as e:  # noqa: BLE001 — surfaces in publish()
                 self._error = e
                 return
@@ -1217,6 +1226,12 @@ class LearnerService:
             feed.close()
             if self._publisher is not None:
                 self._publisher.close()
+                print(
+                    f"[learner] weight broadcast: {self._publisher.n_sent} of "
+                    f"{self._publisher.n_snapshots} snapshots sent (the rest "
+                    f"superseded in the slot), {self._publisher.n_bytes} bytes",
+                    flush=True,
+                )
             if prof_capture is not None:
                 # Never leave a trace open (early exit / stop-event / crash)
                 # and unhook from the crash path; idempotent with the
@@ -1636,6 +1651,7 @@ class LearnerService:
                 self._publisher.n_snapshots
             )
             reg.counter("learner-publish-sent").set_total(self._publisher.n_sent)
+            reg.counter("learner-publish-bytes").set_total(self._publisher.n_bytes)
         # Self-healing plane: exported whenever the guards are compiled in
         # (update_guard default-on), so the shipped SLO example rule
         # `counter:learner-nonfinite-updates==0` always has data.

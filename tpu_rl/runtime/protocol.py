@@ -19,7 +19,12 @@ pickle+blosc2 codec (``/root/reference/utils/utils.py:229-249``), upgraded:
   zlib fallback, chosen per-process at import; both ends interoperate because
   the codec id is in the header;
 - tiny payloads skip compression (codec=raw) — the reference pays blosc on
-  every 2-float stat message.
+  every 2-float stat message;
+- the model broadcast (``PARTS_KINDS``) is framed the other way round: dense
+  float weights do not compress and are large, so every array leaf rides as a
+  wire part of its own — the host array's buffer, never copied on the way
+  out — behind a head part (header ‖ description) whose CRC runs over the
+  description and every leaf (:func:`encode`, ``Codec.PARTS``).
 """
 
 from __future__ import annotations
@@ -48,7 +53,12 @@ _ARRAY_KINDS = frozenset("biufc")
 _MAX_DEPTH = 32
 
 
-def _pack_into(obj: Any, out: list[bytes], depth: int = 0) -> None:
+def _pack_into(
+    obj: Any, out: list[bytes], depth: int = 0, leaves: list | None = None
+) -> None:
+    """``leaves`` None: arrays are packed in line (tag ``a``). A list: each
+    array is described only (tag ``p``: dtype, shape, byte length, index) and
+    its buffer is appended to ``leaves`` as a byte view, not copied."""
     if depth > _MAX_DEPTH:
         raise ValueError("payload nesting too deep")
     if obj is None:
@@ -70,24 +80,35 @@ def _pack_into(obj: Any, out: list[bytes], depth: int = 0) -> None:
     elif isinstance(obj, bytes):
         out.append(b"y" + _LEN.pack(len(obj)) + obj)
     elif isinstance(obj, (np.ndarray, np.generic)):
-        arr = np.ascontiguousarray(obj)
+        if leaves is None:
+            arr = np.ascontiguousarray(obj)
+        else:  # keeps a 0-d shape, copies only what is not contiguous
+            arr = np.asarray(obj)
+            if not arr.flags.c_contiguous:
+                arr = np.ascontiguousarray(arr)
         if arr.dtype.kind not in _ARRAY_KINDS:
             raise ValueError(f"non-numeric array dtype {arr.dtype} on wire")
         dt = arr.dtype.str.encode("ascii")  # e.g. b"<f4"
-        body = arr.tobytes()
-        out.append(
-            b"a"
-            + _LEN.pack(len(dt))
+        head = (
+            _LEN.pack(len(dt))
             + dt
             + _LEN.pack(arr.ndim)
             + b"".join(_I64.pack(s) for s in arr.shape)
-            + _LEN.pack(len(body))
-            + body
         )
+        if leaves is None:
+            body = arr.tobytes()
+            out.append(b"a" + head + _LEN.pack(len(body)) + body)
+        else:
+            if arr.nbytes > _MAX_RAW:  # the length field is a u32
+                raise ValueError(f"array of {arr.nbytes} bytes exceeds the frame cap")
+            out.append(
+                b"p" + head + _LEN.pack(arr.nbytes) + _LEN.pack(len(leaves))
+            )
+            leaves.append(memoryview(arr.reshape(-1).view(np.uint8)))
     elif isinstance(obj, (list, tuple)):
         out.append((b"l" if isinstance(obj, list) else b"u") + _LEN.pack(len(obj)))
         for item in obj:
-            _pack_into(item, out, depth + 1)
+            _pack_into(item, out, depth + 1, leaves)
     elif isinstance(obj, dict):
         out.append(b"m" + _LEN.pack(len(obj)))
         for k, v in obj.items():
@@ -95,14 +116,14 @@ def _pack_into(obj: Any, out: list[bytes], depth: int = 0) -> None:
                 raise ValueError(f"non-str dict key {type(k).__name__} on wire")
             kb = k.encode("utf-8")
             out.append(_LEN.pack(len(kb)) + kb)
-            _pack_into(v, out, depth + 1)
+            _pack_into(v, out, depth + 1, leaves)
     else:
         # jax Arrays land here (don't import jax in this host-side module):
         # anything exposing __array__ with a numeric dtype is accepted once.
         a = np.asarray(obj)
         if a.dtype.kind not in _ARRAY_KINDS:
             raise ValueError(f"unsupported wire type {type(obj).__name__}")
-        _pack_into(a, out, depth)
+        _pack_into(a, out, depth, leaves)
 
 
 def pack(obj: Any) -> bytes:
@@ -112,11 +133,12 @@ def pack(obj: Any) -> bytes:
 
 
 class _Reader:
-    __slots__ = ("buf", "pos")
+    __slots__ = ("buf", "pos", "n_leaves")
 
     def __init__(self, buf: bytes) -> None:
         self.buf = buf
         self.pos = 0
+        self.n_leaves = 0  # array parts a description has named so far
 
     def take(self, n: int) -> bytes:
         if n < 0 or self.pos + n > len(self.buf):
@@ -129,7 +151,34 @@ class _Reader:
         return _LEN.unpack(self.take(4))[0]
 
 
-def _unpack_from(r: _Reader, depth: int = 0) -> Any:
+def _array_head(r: _Reader) -> tuple[np.dtype, tuple[int, ...], int]:
+    """-> (dtype, shape, declared byte length) of a packed or described array,
+    the length held to the shape's."""
+    try:
+        dt = np.dtype(r.take(r.u32()).decode("ascii", errors="strict"))
+    except (TypeError, UnicodeDecodeError) as e:
+        # np.dtype raises TypeError for garbage strings; normalize to the
+        # module's ValueError contract so Sub.recv's reject path holds.
+        raise ValueError(f"bad wire dtype: {e}") from e
+    if dt.kind not in _ARRAY_KINDS:
+        raise ValueError(f"non-numeric array dtype {dt} on wire")
+    ndim = r.u32()
+    if ndim > 32:
+        raise ValueError("array rank too large")
+    shape = tuple(_I64.unpack(r.take(8))[0] for _ in range(ndim))
+    if any(s < 0 for s in shape):
+        raise ValueError("negative array dim")
+    nbytes = r.u32()
+    n = int(np.prod(shape, dtype=np.int64)) if shape else 1
+    if nbytes != n * dt.itemsize:
+        raise ValueError("array byte-size mismatch")
+    return dt, shape, nbytes
+
+
+def _unpack_from(r: _Reader, depth: int = 0, leaves: list | None = None) -> Any:
+    """``leaves``: the wire parts a description's ``p`` tags point into, in
+    order; such an array comes back as a read-only view of its part (the
+    caller checks the frame, then copies: :func:`_decode_parts`)."""
     if depth > _MAX_DEPTH:
         raise ValueError("payload nesting too deep")
     tag = r.take(1)
@@ -148,35 +197,32 @@ def _unpack_from(r: _Reader, depth: int = 0) -> Any:
     if tag == b"y":
         return r.take(r.u32())
     if tag == b"a":
-        try:
-            dt = np.dtype(r.take(r.u32()).decode("ascii", errors="strict"))
-        except (TypeError, UnicodeDecodeError) as e:
-            # np.dtype raises TypeError for garbage strings; normalize to the
-            # module's ValueError contract so Sub.recv's reject path holds.
-            raise ValueError(f"bad wire dtype: {e}") from e
-        if dt.kind not in _ARRAY_KINDS:
-            raise ValueError(f"non-numeric array dtype {dt} on wire")
-        ndim = r.u32()
-        if ndim > 32:
-            raise ValueError("array rank too large")
-        shape = tuple(_I64.unpack(r.take(8))[0] for _ in range(ndim))
-        if any(s < 0 for s in shape):
-            raise ValueError("negative array dim")
-        body = r.take(r.u32())
-        n = int(np.prod(shape, dtype=np.int64)) if shape else 1
-        if len(body) != n * dt.itemsize:
-            raise ValueError("array byte-size mismatch")
-        return np.frombuffer(body, dtype=dt).reshape(shape).copy()
+        dt, shape, nbytes = _array_head(r)
+        return np.frombuffer(r.take(nbytes), dtype=dt).reshape(shape).copy()
+    if tag == b"p" and leaves is not None:
+        dt, shape, nbytes = _array_head(r)
+        index = r.u32()
+        # Parts are named in order, each once: a part can neither be read
+        # twice nor left over (the caller holds the count to the parts').
+        if index >= len(leaves) or index != r.n_leaves:
+            raise ValueError(f"array part {index} out of order or missing")
+        r.n_leaves += 1
+        if len(leaves[index]) != nbytes:
+            raise ValueError(
+                f"array part {index} holds {len(leaves[index])} bytes, "
+                f"described as {nbytes}"
+            )
+        return np.frombuffer(leaves[index], dtype=dt).reshape(shape)
     if tag in (b"l", b"u"):
         n = r.u32()
-        items = [_unpack_from(r, depth + 1) for _ in range(n)]
+        items = [_unpack_from(r, depth + 1, leaves) for _ in range(n)]
         return items if tag == b"l" else tuple(items)
     if tag == b"m":
         n = r.u32()
         d = {}
         for _ in range(n):
             k = r.take(r.u32()).decode("utf-8")
-            d[k] = _unpack_from(r, depth + 1)
+            d[k] = _unpack_from(r, depth + 1, leaves)
         return d
     raise ValueError(f"unknown wire tag {tag!r}")
 
@@ -282,6 +328,10 @@ class Codec(enum.IntEnum):
     RAW = 0
     LZ4 = 1  # native/codec.cpp
     ZLIB = 2
+    # No compression but a layout's mark: the frame's array leaves ride as
+    # wire parts of their own (PARTS_KINDS). A receiver that does not know
+    # the value rejects the frame ("unknown codec") instead of misreading it.
+    PARTS = 3
 
 
 _MAGIC = 0x5452  # "TR"
@@ -303,7 +353,7 @@ _MIN_COMPRESS = 128  # bytes; below this, framing overhead beats compression
 _MAX_RAW = 1 << 30
 
 
-# What pack() adds to a tree's array bytes: names, dtypes and shapes of its
+# What framing adds to a tree's array bytes: names, dtypes and shapes of its
 # leaves, and the scalars sent with it.
 _FRAMING_SLACK = 1 << 20
 
@@ -311,13 +361,22 @@ _FRAMING_SLACK = 1 << 20
 def fits_frame(array_bytes: int) -> bool:
     """Whether a payload holding this many array bytes can be framed at all:
     every receiver rejects a frame that declares more than ``_MAX_RAW``, so a
-    sender asks before it copies anything (:func:`pack` copies the payload,
-    the codec copies it again)."""
+    sender asks before it snapshots or transfers anything."""
     return array_bytes + _FRAMING_SLACK <= _MAX_RAW
 
 # Standard IEEE CRC-32 (zlib's C implementation; interoperates with the
 # native tpurl_crc32, which implements the same polynomial).
 _crc = zlib.crc32
+
+
+def _crc_parts(desc: Any, leaves: list) -> int:
+    """One crc32 run part by part over a PARTS frame's description and its
+    leaves in order (``zlib.crc32`` lets go of the interpreter lock on large
+    buffers)."""
+    running = _crc(desc)
+    for v in leaves:
+        running = _crc(v, running)
+    return running & 0xFFFFFFFF
 
 # ------------------------------------------------------------- trace trailer
 # Rollout-lineage trace context (tpu_rl.obs): a sampled frame carries its
@@ -343,6 +402,14 @@ assert _TRAILER.size == TRAILER_BYTES, (
 # on anything else (Model, Stat, control frames) is a hostile/corrupt frame
 # and is rejected into the receiver's ``n_rejected`` path.
 TRACE_KINDS = frozenset({Protocol.Rollout, Protocol.RolloutBatch})
+
+# The only kinds framed as ``[proto, head, leaf 0, ..., leaf n-1]``
+# (``Codec.PARTS``), and so the only ones that may carry more than three
+# parts: the model broadcast goes learner -> worker directly, past no relay
+# (:func:`peek` and the native batch validator keep rejecting such a frame:
+# they count on two or three parts). Disjoint from TRACE_KINDS — a third part
+# is either a trailer or a leaf (protocol checker, PC004).
+PARTS_KINDS = frozenset({Protocol.Model})
 
 # Derived forms handed to the native batch validator (native/codec.cpp) so
 # the enum above stays the single source of truth: a bitmask over protocol
@@ -378,6 +445,13 @@ def unpack_trace(trailer: bytes) -> tuple[int, int, int, int]:
     return wid, seq, trace_id, ts
 
 
+def trailer_of(proto: Protocol, parts: list[bytes]) -> bytes | None:
+    """The trace trailer of a decoded frame, or None: a third part is a
+    trailer only on a kind that may carry one (on a PARTS_KINDS frame it is
+    the first leaf)."""
+    return parts[2] if len(parts) == 3 and proto in TRACE_KINDS else None
+
+
 def _check_trailer(proto: Protocol, parts: list[bytes]) -> None:
     """Relay-grade trailer validation (size cap = the exact struct size, kind
     allowlist, magic/version) — no payload decode, same cost class as
@@ -397,7 +471,12 @@ def encode(
 ) -> list[bytes]:
     """-> multipart message ``[proto_byte, frame]`` (reference ``encode``,
     ``utils/utils.py:244-245``), plus the optional trace-context trailer as a
-    third part (see :func:`pack_trace`)."""
+    third part (see :func:`pack_trace`). A kind in ``PARTS_KINDS`` is framed
+    by :func:`_encode_parts` instead: the split is by the kind alone."""
+    if proto in PARTS_KINDS:
+        if trace is not None:
+            raise ValueError(f"trace trailer not allowed on {proto!r}")
+        return _encode_parts(proto, payload)
     raw = pack(payload)
     if len(raw) > _MAX_RAW:  # no receiver would take it (peek / decode)
         raise ValueError(f"payload of {len(raw)} bytes exceeds the frame cap {_MAX_RAW}")
@@ -415,6 +494,73 @@ def encode(
     return [bytes([proto]), header + body, trace]
 
 
+def _encode_parts(proto: Protocol, payload: Any) -> list:
+    """-> ``[proto_byte, head, leaf 0, ..., leaf n-1]``: ``head`` is the frame
+    header followed by the payload's description (:func:`_pack_into` with
+    every array as dtype, shape, byte length and part index), and each leaf
+    is a byte view of the array's own buffer — nothing is copied here, and
+    nothing may write the arrays until the transport has let go of the views
+    (send with ``copy=False``). No compression is tried: dense float weights
+    come back from LZ4 larger than they went in. The header's crc32 runs over
+    the description and every leaf in order (:func:`_crc_parts`), its size
+    field is their total."""
+    out: list[bytes] = []
+    leaves: list[memoryview] = []
+    _pack_into(payload, out, leaves=leaves)
+    desc = b"".join(out)
+    total = len(desc) + sum(len(v) for v in leaves)
+    if total > _MAX_RAW:  # no receiver would take it
+        raise ValueError(f"payload of {total} bytes exceeds the frame cap {_MAX_RAW}")
+    header = _HEADER.pack(
+        _MAGIC, _VERSION, Codec.PARTS, total, _crc_parts(desc, leaves)
+    )
+    return [bytes([proto]), header + desc, *leaves]
+
+
+def _own(obj: Any) -> Any:
+    """The tree with every array view replaced by a copy of its own."""
+    if isinstance(obj, np.ndarray):
+        return obj.copy()
+    if isinstance(obj, dict):
+        return {k: _own(v) for k, v in obj.items()}
+    if isinstance(obj, list):
+        return [_own(v) for v in obj]
+    if isinstance(obj, tuple):
+        return tuple(_own(v) for v in obj)
+    return obj
+
+
+def _decode_parts(proto: Protocol, parts: list, raw_size: int, crc: int) -> Any:
+    """Inverse of :func:`_encode_parts`, the header's magic, version and cap
+    already checked. Everything is held to the description before an array is
+    made: the parts' count and each one's byte length, their total against
+    the header's, the crc over all of them; then one copy per leaf."""
+    if proto not in PARTS_KINDS:
+        raise ValueError(f"array parts not allowed on {proto!r}")
+    desc = memoryview(parts[1])[_HEADER.size :]
+    leaves = parts[2:]
+    if len(desc) + sum(len(v) for v in leaves) != raw_size:
+        raise ValueError("size mismatch: parts do not add up to the declared size")
+    r = _Reader(bytes(desc))
+    tree = _unpack_from(r, leaves=leaves)
+    if r.pos != len(desc):
+        raise ValueError("trailing bytes in wire payload")
+    if r.n_leaves != len(leaves):
+        raise ValueError(f"{len(leaves)} array parts, {r.n_leaves} described")
+    if _crc_parts(desc, leaves) != crc:
+        raise ValueError("frame crc mismatch")
+    return _own(tree)
+
+
+def frame_args(parts: list) -> dict:
+    """What a sender's span says of the frame it handed to its socket."""
+    return {
+        "bytes": sum(len(p) for p in parts),
+        "parts": len(parts),
+        "codec": Codec(parts[1][3]).name,
+    }
+
+
 def peek(parts: list[bytes]) -> Protocol:
     """Cheap relay-hop validation of a multipart frame: proto byte, header
     magic/version, known codec, declared-size cap — WITHOUT the CRC pass,
@@ -427,7 +573,9 @@ def peek(parts: list[bytes]) -> Protocol:
     and is rejected downstream by decode's CRC. A third part, when present,
     must be a valid trace trailer on a kind that allows one
     (:func:`_check_trailer`) — anything else is rejected here so relays never
-    amplify garbage trailers."""
+    amplify garbage trailers. A ``Codec.PARTS`` frame is rejected whatever its
+    part count (as an unknown codec here, as in the native batch validator):
+    the model broadcast passes no relay."""
     if len(parts) not in (2, 3) or len(parts[0]) != 1:
         raise ValueError(f"malformed multipart message: {len(parts)} parts")
     proto = Protocol(parts[0][0])  # ValueError on an unknown proto byte
@@ -462,22 +610,27 @@ def decode(parts: list[bytes], validated: bool = False) -> tuple[Protocol, Any]:
     crc variant (``native.validate_batch(check_crc=True)``) over a whole
     drained deque — re-hashing every body here would pay the batch's
     dominant cost a second time. Decompress + schema unpack still run."""
-    if len(parts) not in (2, 3) or len(parts[0]) != 1:
+    if len(parts) < 2 or len(parts[0]) != 1:
         raise ValueError(f"malformed multipart message: {len(parts)} parts")
     proto = Protocol(parts[0][0])
-    if not validated and len(parts) == 3:
-        _check_trailer(proto, parts)
     frame = parts[1]
     if len(frame) < _HEADER.size:
         raise ValueError("short frame")
     magic, version, codec, raw_size, crc = _HEADER.unpack_from(frame)
-    if not validated:
+    parted = codec == Codec.PARTS  # never ``validated``: no batch check knows it
+    if not validated or parted:
         if magic != _MAGIC or version != _VERSION:
             raise ValueError(f"bad frame magic/version {magic:#x}/{version}")
         if raw_size > _MAX_RAW:
             raise ValueError(
                 f"declared raw size {raw_size} exceeds cap {_MAX_RAW}"
             )
+    if parted:
+        return proto, _decode_parts(proto, parts, raw_size, crc)
+    if len(parts) > 3:
+        raise ValueError(f"malformed multipart message: {len(parts)} parts")
+    if not validated and len(parts) == 3:
+        _check_trailer(proto, parts)
     body = frame[_HEADER.size :]
     if not validated and _crc(body) & 0xFFFFFFFF != crc:
         raise ValueError("frame crc mismatch")

@@ -38,11 +38,13 @@ import zmq.asyncio
 from tpu_rl.runtime import native
 from tpu_rl.runtime.protocol import (
     MAX_PROTO,
+    PARTS_KINDS,
     TRACE_KINDS_MASK,
     Protocol,
     decode,
     encode,
     peek,
+    trailer_of,
 )
 
 # Keep only the newest model broadcast in flight (a worker that lags wants the
@@ -104,9 +106,7 @@ def _validate_traced(
             except ValueError:
                 rejected += 1
                 continue
-            out.append(
-                (proto, payload, parts[2] if len(parts) == 3 else None)
-            )
+            out.append((proto, payload, trailer_of(proto, parts)))
         return out, rejected
     for parts in frames:
         try:
@@ -114,7 +114,7 @@ def _validate_traced(
         except ValueError:
             rejected += 1
             continue
-        out.append((proto, payload, parts[2] if len(parts) == 3 else None))
+        out.append((proto, payload, trailer_of(proto, parts)))
     return out, rejected
 
 
@@ -137,16 +137,23 @@ class Pub:
 
     def send(
         self, proto: Protocol, payload: Any, trace: bytes | None = None
-    ) -> None:
+    ) -> list | None:
         """``trace`` (a ``protocol.pack_trace`` trailer) rides as the
         optional third wire part on sampled rollout frames; None (the
-        default and the sampling-off state) keeps the exact 2-part frame."""
+        default and the sampling-off state) keeps the exact 2-part frame.
+        A model broadcast's array parts are views of the payload's own
+        buffers (``protocol.PARTS_KINDS``) and go out uncopied: zmq keeps
+        each referenced until it has let go of it, and the caller does not
+        write the arrays again. Returns the parts handed to the socket
+        (``protocol.frame_args`` describes them), None where chaos dropped
+        the frame."""
         parts = encode(proto, payload, trace)
         if self._chaos is not None:
             parts = self._chaos.on_send(parts)
             if parts is None:
-                return
-        self.sock.send_multipart(parts)
+                return None
+        self.sock.send_multipart(parts, copy=proto not in PARTS_KINDS)
+        return parts
 
     def send_raw(self, parts: list[bytes]) -> None:
         """Forward already-encoded wire parts verbatim — the zero-copy relay
@@ -258,7 +265,7 @@ class Sub:
         except ValueError:
             self.n_rejected += 1
             return None
-        return proto, payload, parts[2] if len(parts) == 3 else None
+        return proto, payload, trailer_of(proto, parts)
 
     def drain_traced(
         self, max_msgs: int = 1024
