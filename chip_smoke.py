@@ -403,6 +403,14 @@ GLM4_MOE_LITE_MIXER = dict(
     kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
     rope_theta=1000000,
 )
+# The widths of Ling-3.0-flash-VL's two mixers and its router as published
+# (benchmarks/configs/ling-3.0-flash-vl.json holds the whole configuration).
+LING_FLASH_MIXERS = dict(
+    hidden_size=2560, rms_norm_eps=1e-6, num_attention_heads=32, head_dim=128, q_lora_rank=None,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    rope_theta=6000000, short_conv_kernel_size=4, kda_lower_bound=-5, num_experts=512,
+    num_experts_per_tok=8, n_group=8, topk_group=4, routed_scaling_factor=2.5,
+)
 # The widths of LFM2-24B-A2B's gated short convolution as published
 # (benchmarks/configs/lfm2-24b-a2b.json holds the whole configuration).
 LFM2_MOE_MIXER = dict(hidden_size=2048, conv_L_cache=3)
@@ -525,6 +533,23 @@ def kernel_checks(
     evabyte_shapes=(
         ("mixer", 1, 16384), ("pool", 1, 16384), ("step", 1, 4096), ("pool-seams", 1, 16384)),
     evabyte_widths=EVABYTE_MIXER,
+    # (row, B, T): ling-3.0-flash-vl's new paths at ``ling_flash_widths``. "kda-seams" /
+    # "kda-bound" / "kda-one-episode": the per-channel delta rule's chunked scan
+    # (ops/kda.py) alone at 32 heads of 128, forward and every gradient against the step
+    # recurrence (``kda_step`` under a scan whose 64-step blocks are rematerialised; the
+    # first row timed, both forms) — with ~4 seams a window and two in one chunk, with every gate at the bound of
+    # -5 for the whole window (the sub-blocks' operands at e^75), and with the whole row one
+    # episode. "mla": latent attention without a query latent at 192-wide queries and
+    # 128-wide values (the kernels on heads padded to 256), forward and every gradient
+    # against benchmarks/reference/ling_flash.py. "route": the group-limited choice
+    # (ops/moe.route: 8 groups of 64, 4 kept, top-8) on T tokens against the reference's
+    # sort, the chosen sets and the weights. Run last, so that the rows above keep the
+    # inputs they were drawn
+    ling_flash_shapes=(
+        ("kda-seams", 1, 8192), ("kda-bound", 1, 8192), ("kda-one-episode", 1, 8192),
+        ("mla", 1, 8192), ("route", 1, 8192)),
+    ling_flash_widths=LING_FLASH_MIXERS,
+    ling_flash_chunk: int = 64,
     interpret: bool = False,
 ) -> list[dict]:
     """Each kernel against its plain-jnp reference; one result row per case,
@@ -1248,6 +1273,110 @@ def kernel_checks(
             TOL_MIXER_BF16, TOL_MIXER_BF16, mosaic=False,
             ref_is_kernel=jax.default_backend() == "tpu",
         )
+
+    # ---- ling_flash: the per-channel delta rule's scan vs the step recurrence, latent
+    # attention at unequal head sizes vs the plain reference, the group-limited choice
+    from benchmarks.reference import ling_flash as plain_ling
+    from tpu_rl.models.ling_flash import build_mixer as build_ling
+    from tpu_rl.ops import kda, moe
+
+    lw = dict(ling_flash_widths)
+    H, D, bound, Q = lw["num_attention_heads"], lw["head_dim"], lw["kda_lower_bound"], ling_flash_chunk
+    for row, B, T in ling_flash_shapes:
+        firsts = rng.random((B, T)) < 4.0 / T  # ~4 episode seams a window, as the cell's mix
+        firsts[:, [T // 3, T // 3 + 1]] = True  # and two in one chunk whatever the draw
+        if row == "kda-one-episode":
+            firsts[:] = False
+        seg = jnp.asarray(np.cumsum(firsts, axis=1).astype(np.int32))
+        first = jnp.asarray(firsts)
+        if row.startswith("kda"):
+            q, k, v = (f32(B, T, H, D).astype(jnp.bfloat16) for _ in range(3))
+            g = bound * jax.nn.sigmoid(3.0 * f32(B, T, H, D) - 3.0)
+            if row == "kda-bound":
+                g = jnp.full_like(g, bound)
+            beta, state0 = jax.nn.sigmoid(f32(B, T, H)), f32(B, H, D, D) * D**-0.5
+            w_o, w_last = f32(B, T, H, D), f32(B, H, D, D)
+
+            def chunked(q, k, v, g, beta, state0):
+                return kda.kda_chunked(q, k, v, g, beta, seg, state0, Q, jnp.bfloat16)
+
+            def stepped(q, k, v, g, beta, state0):
+                """``kda_step`` over the window in rematerialised blocks of ``Q`` steps."""
+                @jax.checkpoint
+                def block(S, xs):
+                    def one(S, at):
+                        *at, first_t = at
+                        o, S = kda.kda_step(*at, jnp.where(first_t[:, None, None, None], 0.0, S))
+                        return S, o
+                    return jax.lax.scan(one, S, xs)
+
+                blocks = tuple(
+                    jnp.moveaxis(a, 1, 0).reshape(T // Q, Q, *a.shape[:1], *a.shape[2:])
+                    for a in (q, k, v, g, beta, first))
+                last, o = jax.lax.scan(block, state0, blocks)
+                return jnp.moveaxis(o.reshape(T, *o.shape[2:]), 0, 1), last
+
+            def graded(rule):
+                def loss(*a):
+                    o, last = rule(*a)
+                    return (o * w_o).sum() + (last * w_last).sum(), (o, last)
+                return jax.value_and_grad(loss, argnums=tuple(range(6)), has_aux=True)
+
+            case(
+                f"kda fwd+bwd B{B}/T{T}/H{H}x{D}/Q{Q} bf16 ({row[4:]}: {int(firsts.sum())} seams, "
+                f"mean decay a step {float(jnp.exp(g).mean()):.3f}) vs the step recurrence",
+                graded(chunked), graded(stepped), (q, k, v, g, beta, state0), TOL_BF16, TOL_BF16,
+                mosaic=False, timed=row == "kda-seams",
+            )
+        elif row == "mla":
+            mixer = build_ling(lw, "mla", jnp.bfloat16).clone(name=None)
+            u = f32(B, T, lw["hidden_size"])
+            params = jax.jit(lambda key: mixer.init(key, u, seg)["params"])(jax.random.key(SEED))
+            w_y = f32(B, T, lw["hidden_size"])
+
+            def system(p, u):
+                y = mixer.apply({"params": p}, u, seg)
+                return (y * w_y).sum(), y
+
+            def reference(p, u):
+                y = plain_ling.latent_attention(u, first, p, lw)
+                return (y * w_y).sum(), y
+
+            case(
+                f"ling_flash mla mixer fwd+bwd B{B}/T{T} bf16 at {H} heads of "
+                f"{lw['qk_nope_head_dim'] + lw['qk_rope_head_dim']}:{lw['v_head_dim']} vs the "
+                f"plain reference ({int(firsts.sum())} seams)",
+                jax.value_and_grad(system, argnums=(0, 1), has_aux=True),
+                jax.value_and_grad(reference, argnums=(0, 1), has_aux=True),
+                (params, u), TOL_MIXER_BF16, TOL_MIXER_BF16,
+                mosaic=jax.default_backend() == "tpu",  # the splash kernels
+            )
+        else:
+            E, top_k = lw["num_experts"], lw["num_experts_per_tok"]
+            groups = lw["n_group"], lw["topk_group"]
+            u, kernel = f32(B * T, lw["hidden_size"]), f32(lw["hidden_size"], E) * lw["hidden_size"]**-0.5
+            bias = 0.05 * f32(E)
+
+            def routed(u, kernel, bias):
+                choice, weight = moe.route(
+                    u, kernel, bias, top_k, lw["routed_scaling_factor"], "sigmoid", *groups)
+                order = jnp.argsort(choice, axis=-1)
+                return (jnp.take_along_axis(choice, order, -1).astype(jnp.float32),
+                        jnp.take_along_axis(weight, order, -1))
+
+            def sorted_choice(u, kernel, bias):
+                s = jax.nn.sigmoid(jnp.dot(u, kernel, precision=jax.lax.Precision.HIGHEST))
+                choice, _ = plain_ling.group_limited_choice(s + bias, top_k, *groups)
+                choice = jnp.sort(choice, axis=-1)
+                chosen = jnp.take_along_axis(s, choice, -1)
+                weight = lw["routed_scaling_factor"] * chosen / chosen.sum(-1, keepdims=True)
+                return choice.astype(jnp.float32), weight
+
+            case(
+                f"ling_flash group-limited route N{B * T}/E{E}/k{top_k}/groups {groups[1]} of "
+                f"{groups[0]} vs the reference's sort",
+                routed, sorted_choice, (u, kernel, bias), 1e-5, 1e-5, mosaic=False,  # one id: 2e-3
+            )
     return rows
 
 

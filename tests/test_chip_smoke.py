@@ -86,9 +86,30 @@ def test_kernel_checks_pass_tiny_interpreted():
                         ("pool-seams", 2, 128)),
         evabyte_widths=dict(hidden_size=64, num_attention_heads=4, window_size=32, chunk_size=4,
                             rope_theta=100000, init_std=0.05),
+        ling_flash_shapes=(("kda-seams", 2, 64), ("kda-bound", 2, 64), ("kda-one-episode", 2, 64),
+                           ("mla", 2, 128), ("route", 2, 128)),
+        ling_flash_widths=dict(
+            hidden_size=64, rms_norm_eps=1e-6, num_attention_heads=4, head_dim=16,
+            q_lora_rank=None, kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, rope_theta=6000000, short_conv_kernel_size=4, kda_lower_bound=-5,
+            num_experts=32, num_experts_per_tok=6, n_group=8, topk_group=3,
+            routed_scaling_factor=2.5),
+        ling_flash_chunk=16,
         interpret=True,
     )
-    assert len(rows) == 33
+    assert len(rows) == 38
+    # ling_flash's five rows come last: the per-channel scan three times (timed), latent
+    # attention at 24-wide queries and 16-wide values, the group-limited choice (exact)
+    route, mla, *scans = [rows.pop() for _ in range(5)]
+    assert route["kernel"].startswith("ling_flash group-limited route N256/E32/k6/groups 3 of 8")
+    assert route["ok"] and route["err"] < 1e-6 and route["tol"] == 1e-5  # an id apart reads 3e-2
+    assert mla["kernel"].startswith("ling_flash mla mixer fwd+bwd B2/T128 bf16 at 4 heads of 24:16")
+    assert mla["ok"] and mla["err"] > 0
+    for scan, what in zip(scans, ("one-episode: 0 seams", "bound: ", "seams: ")):
+        assert scan["kernel"].startswith("kda fwd+bwd B2/T64/H4x16/Q16 bf16 (" + what), scan
+        assert scan["ok"] and 0 < scan["err"] <= scan["tol"], scan
+    assert scans[2]["ms"] > 0 and scans[2]["ms_ref"] > 0 and "ms" not in scans[0]
+    assert "mean decay a step 0.007" in scans[1]["kernel"]  # e^-5
     # evabyte's four rows come last: the mixer, the pooling alone (timed), the acting
     # form, the pooling on a window with more seams and several absent candidates
     seams, step, pool, mixer = rows.pop(), rows.pop(), rows.pop(), rows.pop()

@@ -632,7 +632,13 @@ TRANSFORMER = dict(algo="PPO", model="transformer", hidden_size=64, n_heads=4, n
 # ``evabyte`` recorded anew on purpose (95908026… before) — its chunk pooling has
 # a backward of its own (``models/evabyte._summaries_bwd``: the members'
 # gradients by the inverse of the gather, no scatter); no other file of the
-# program was touched and the seven above it hold.
+# program was touched and the seven above it hold. PR 51: ``ling_flash``'s own
+# joins them as that PR left it; the eight above it hold through ``MLAttention``'s
+# move into ``models/layers.py`` with its two new fields (``q_rank=None``,
+# ``head_gate``), ``moe.route``'s group stage (``n_group`` 1 is the plain choice),
+# ``route_stats``' optional counter, ``flash_attention_tpu``'s padding of unequal
+# head sizes and the walk over spans taking another rule's span
+# (``gated_delta._chunked_jnp(span_fn=)``).
 BEFORE = {
     "transformer": "8b9c8c0764ab242a3da73822e1ea003dae077495da61006398cca64864b2a7d9",
     "granite_hybrid": "17b496ea1eb174484e74ab740489fb01f84614423ea1b2bb87c5bfa2d17b5e29",
@@ -642,6 +648,7 @@ BEFORE = {
     "glm4_moe_lite": "8adba8ef85184cacb1a698214cc811f36182bea9c6f1867eb6ac2884f75959dd",
     "lfm2_moe": "15b0774f9969df58a3b6f2286eed0dddc3c44289ea7c0775688cc38bd04dc8b5",
     "evabyte": "3491e6fa66a6cf7e9fe4a11af0bd923006d94d3d93c4b5ffe859029d89a90aba",
+    "ling_flash": "d8ef1332738c81022b04c2352c4f6eb55f2efffedf75a48b50f0f34ccab27a31",
 }
 
 

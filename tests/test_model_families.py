@@ -34,6 +34,8 @@ TREES = {
     "lfm2_moe": "4be6e9e331cc53d1dba893b33df63e32f20a09ce00a80b923ff6dafd57a85513",
     # PR 46: the family's own, as the PR that brought it built it
     "evabyte": "be06c64d016d901adbae8feef1bfe3f2a3ec39fb9b31b66b142ea45c6d0c741a",
+    # PR 51: the family's own, as the PR that brought it built it
+    "ling_flash": "0cc9d71f113ea7ce2ce6e39ce55edb8a042ec28d8ba131679013286320ef1093",
 }
 
 

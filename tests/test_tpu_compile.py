@@ -47,7 +47,8 @@ def test_the_row_add_kernel_compiles_at_the_cells_shapes(one_chip, rows, tokens,
 @pytest.mark.parametrize("rows, T, heads, kv_heads, D, window, band", [
     (2, 16384, 28, 4, 128, None, 136), (2, 16384, 28, 4, 128, 4096, 70),
     (1, 16384, 20, 20, 256, None, 136), (2, 8192, 16, 2, 256, None, 36),
-], ids=["smallthinker-global", "smallthinker-window", "glm", "qwen3-next"])
+    (2, 8192, 32, 32, 256, None, 36),  # 192-wide q, k and 128-wide v, padded to one size
+], ids=["smallthinker-global", "smallthinker-window", "glm", "qwen3-next", "ling-flash"])
 def test_the_attention_backward_compiles_at_the_cells_shapes(
         one_chip, rows, T, heads, kv_heads, D, window, band):
     """Forward (the library's kernel on traced masks) and the repo's own

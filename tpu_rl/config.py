@@ -331,6 +331,111 @@ def _check_lfm2_moe_arch(arch: dict | None) -> None:
     _check_expert_share(arch, arch["num_experts"], arch["num_experts_per_tok"])
 
 
+# The keys of a Ling-3.0-flash (``ling_flash``) ``config.json`` that shape the
+# policy core (``models/ling_flash.py``); ``expert_parallel`` as above, with
+# ``num_experts`` the count one rank holds, and the optional ``layer_offset``:
+# the published index of the first layer built (a cut that starts inside the
+# published stack keeps each layer's published mixer).
+LING_FLASH_ARCH_KEYS = (
+    "hidden_size", "num_hidden_layers", "layer_group_size", "first_k_dense_replace",
+    "rms_norm_eps", "num_attention_heads", "num_key_value_heads", "head_dim", "q_lora_rank",
+    "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "rope_theta",
+    "short_conv_kernel_size", "kda_safe_gate", "kda_lower_bound", "intermediate_size",
+    "moe_intermediate_size", "moe_shared_expert_intermediate_size", "num_experts",
+    "num_experts_per_tok", "n_group", "topk_group", "score_function",
+    "moe_router_enable_expert_bias", "routed_scaling_factor", "norm_topk_prob",
+)
+# ``ops/kda.SUB``: the steps whose pair decays are factored against one
+# reference step, which a gate at its bound raises to e^(|bound| (SUB - 1)).
+KDA_SUB_BLOCK = 16
+# What the family's published switches must say where the arch holds them.
+_LING_FLASH_FIXED = {
+    "linear_silu": True, "use_qk_norm": True, "num_kv_heads_for_linear_attn": 0,
+    "group_norm_size": 1, "gated_attention_proj_granularity_type": "head_wise",
+    "no_kda_lora": True, "use_kda_lora": False, "use_mla_nope": False, "use_nGPT": False,
+    "scale_router_input": False, "value_norm": False, "up_proj_norm": False,
+    "rope_scaling": None, "hidden_act": "silu",
+}
+
+
+def _check_ling_flash_arch(arch: dict | None) -> None:
+    """What ``model="ling_flash"`` can build: layer ``i`` (published index,
+    ``layer_offset`` + its place in the stack) with latent attention where
+    ``(i + 1) % layer_group_size == 0`` — queries without a latent, unequal
+    query/key and value head sizes, a head-wise output gate — and Kimi Delta
+    Attention elsewhere (full-rank per-channel gate bounded by
+    ``kda_lower_bound``, as many key heads as value heads, a per-head norm and a
+    head-wise gate); the first ``first_k_dense_replace`` layers of the stack
+    with a dense SwiGLU MLP, the others with ``swiglu`` experts under the
+    group-limited sigmoid router with its expert bias and one ungated shared
+    expert; no bias anywhere, no rotary scaling, no clamped experts, no
+    multi-token prediction, no tower."""
+    assert isinstance(arch, dict), "model='ling_flash' needs arch (config.json keys)"
+    missing = [k for k in LING_FLASH_ARCH_KEYS if k not in arch]
+    assert not missing, f"arch lacks {missing}"
+    for key, want in _LING_FLASH_FIXED.items():
+        assert arch.get(key, want) == want, f"{key} {arch[key]!r}: only {want!r} is built"
+    depth, dense, group = (
+        arch[k] for k in ("num_hidden_layers", "first_k_dense_replace", "layer_group_size"))
+    assert 0 <= dense < depth, (
+        f"first_k_dense_replace {dense} of {depth} layers: an expert layer has to follow"
+    )
+    assert group >= 2, f"layer_group_size {group}: a group is linear layers and one latent layer"
+    assert arch.get("layer_offset", 0) >= 0, arch.get("layer_offset")
+    heads = arch["num_attention_heads"]
+    assert arch["num_key_value_heads"] == heads, (
+        "both mixers are multi-head: every head has its own keys and values"
+    )
+    assert arch["q_lora_rank"] is None, (
+        f"q_lora_rank {arch['q_lora_rank']}: this family's queries have no latent"
+    )
+    assert arch["qk_rope_head_dim"] % 2 == 0, "rotate-half pairs the rotated part's two halves"
+    assert arch.get("rotary_dim", arch["qk_rope_head_dim"]) == arch["qk_rope_head_dim"], (
+        "the whole rotated part is rotated"
+    )
+    assert arch["short_conv_kernel_size"] >= 2, (
+        f"short_conv_kernel_size {arch['short_conv_kernel_size']}: a tail of none"
+    )
+    bound = arch["kda_lower_bound"]
+    assert arch["kda_safe_gate"] and bound < 0, (
+        f"kda_safe_gate {arch['kda_safe_gate']!r} with kda_lower_bound {bound}: the gate is the "
+        "bounded one, kda_lower_bound * sigmoid(.) with a bound below 0"
+    )
+    assert abs(bound) * KDA_SUB_BLOCK <= 85, (
+        f"kda_lower_bound {bound}: a sub-block of {KDA_SUB_BLOCK} steps at that bound raises an "
+        "operand of the chunked rule past float32 (e^88)"
+    )
+    limits = [
+        x for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list")
+        for x in arch.get(key, [])[arch.get("layer_offset", 0):][:depth]
+    ]
+    assert not any(limits), "clamped SwiGLU experts are not built"
+    assert not arch.get("num_nextn_predict_layers", 0), "multi-token prediction is not built"
+    assert arch["score_function"] == "sigmoid" and arch["moe_router_enable_expert_bias"], (
+        "the router is the sigmoid one with its expert bias"
+    )
+    assert arch["norm_topk_prob"], "the chosen scores are always normalised"
+    assert arch["moe_shared_expert_intermediate_size"] >= 1, "an expert layer has its shared expert"
+    held, top_k = arch["num_experts"], arch["num_experts_per_tok"]
+    _check_expert_share(arch, held, top_k)
+    share = arch.get("expert_parallel")
+    total = share["published_n_routed_experts"] if share else held
+    n_group, kept = arch["n_group"], arch["topk_group"]
+    assert n_group >= 1 and total % n_group == 0, (
+        f"{total} experts are no whole number of n_group {n_group} groups"
+    )
+    assert 1 <= kept <= n_group, f"topk_group {kept} of n_group {n_group}"
+    size = total // n_group
+    assert top_k <= kept * size, (
+        f"num_experts_per_tok {top_k} from {kept} kept groups of {size} experts"
+    )
+    first = share["rank"] * held if share else 0
+    assert held % size == 0 or first % size + held <= size, (
+        f"experts {first}-{first + held - 1} held in groups of {size}: a rank holds whole groups "
+        "or lies inside one"
+    )
+
+
 # The keys of an EvaByte (``evabyte``) ``config.json`` that shape the policy
 # core (``models/evabyte.py``).
 EVABYTE_ARCH_KEYS = (
@@ -379,6 +484,7 @@ ARCH_CHECKS = {
     "glm4_moe_lite": _check_glm4_moe_lite_arch,
     "lfm2_moe": _check_lfm2_moe_arch,
     "evabyte": _check_evabyte_arch,
+    "ling_flash": _check_ling_flash_arch,
 }
 
 
@@ -416,7 +522,11 @@ class Config:
     # a leading dense layer, then sparse experts, of an LFM2-MoE config.json)
     # or "evabyte" (EVA attention — a block's exact keys and pooled summaries of
     # the chunks before it under one softmax — and a dense SwiGLU MLP in every
-    # layer, of an EvaByte config.json).
+    # layer, of an EvaByte config.json) or "ling_flash" (Kimi Delta Attention —
+    # the delta rule with a decay per key channel — five layers in six around
+    # latent attention with unequal query and value heads, a leading dense
+    # layer, then experts under a group-limited router, of a Ling-3.0-flash
+    # config.json).
     model: str = "lstm"
     n_heads: int = 4
     n_layers: int = 2
@@ -431,7 +541,7 @@ class Config:
     # NEMOTRON_ARCH_KEYS; "smallthinker": SMALLTHINKER_ARCH_KEYS;
     # "qwen3_next": QWEN3_NEXT_ARCH_KEYS; "glm4_moe_lite":
     # GLM4_MOE_LITE_ARCH_KEYS; "lfm2_moe": LFM2_MOE_ARCH_KEYS; "evabyte":
-    # EVABYTE_ARCH_KEYS). One
+    # EVABYTE_ARCH_KEYS; "ling_flash": LING_FLASH_ARCH_KEYS). One
     # mapping instead of a Config field per width: the widths of a catalog
     # model are its source's to name, not this file's.
     arch: dict | None = None

@@ -7,7 +7,8 @@ published key names (``config.GLM4_MOE_LITE_ARCH_KEYS``). The trunk (the
 embedding, the unroll and act loops, the acting carry, the heads) is
 ``models/backbone.py``'s; the experts are ``models/layers.py``'s ``ExpertBlock``
 (``swiglu`` under the sigmoid router with its correction bias and scale, an
-ungated shared expert); latent attention is this file's.
+ungated shared expert) and latent attention its ``MLAttention``
+(``models/ling_flash.py`` builds it too, with two fields this family leaves off).
 
     x = Dense(obs)
     per layer i:  x = x + MLA(N(x))
@@ -29,9 +30,11 @@ Latent attention (``MLAttention``, scope ``mla``), no bias anywhere:
 
 The rotation is rotate-half over the whole ``qk_rope_head_dim``, which is the
 *last* part of a head's query and key. Training runs this expanded form, as
-the published modelling code does: ``k^r`` is broadcast to the heads and
-``flash_attention_tpu`` takes equal query/key and value head sizes
-(``config._check_glm4_moe_lite_arch`` refuses an arch whose sizes differ).
+the published modelling code does: ``k^r`` is broadcast to the heads. This
+family's query/key and value head sizes are equal
+(``config._check_glm4_moe_lite_arch`` refuses an arch whose sizes differ, or
+whose queries have no latent: ``MLAttention`` builds both, for
+``models/ling_flash.py``, and this family's published model has neither).
 
 Acting (``MLAttention.step``) runs the absorbed form over a *latent ring*:
 a step stores ``[c_kv,t ; R_t k^r_t]`` (``kv_lora_rank + qk_rope_head_dim``
@@ -60,109 +63,14 @@ from typing import Any
 
 import flax.linen as nn
 import jax
-import jax.numpy as jnp
 
 from tpu_rl.models.backbone import Backbone, ring
-from tpu_rl.models.layers import ExpertBlock, RMSNorm, attention_counts, expert_share, rope
-from tpu_rl.parallel.sequence import flash_attention_tpu
+from tpu_rl.models.layers import ExpertBlock, MLAttention, RMSNorm, attention_counts, expert_share
 
 
 def ring_width(arch: dict) -> int:
     """Numbers a step leaves in a layer's latent ring."""
     return arch["kv_lora_rank"] + arch["qk_rope_head_dim"]
-
-
-class MLAttention(nn.Module):
-    """``__call__`` (training) runs the expanded form through
-    ``flash_attention_tpu``, ``step`` (acting) the absorbed form over the
-    latent ring."""
-
-    hidden: int
-    heads: int
-    q_rank: int
-    kv_rank: int
-    nope_dim: int
-    rope_dim: int
-    v_dim: int
-    rope_theta: float
-    eps: float
-    dtype: Any = None
-
-    def setup(self):
-        proj = dict(use_bias=False, dtype=self.dtype)
-        norm = dict(eps=self.eps, dtype=self.dtype)
-        self.q_a_proj = nn.Dense(self.q_rank, name="q_a_proj", **proj)
-        self.q_a_norm = RMSNorm(name="q_a_norm", **norm)
-        self.q_b_proj = nn.Dense(
-            self.heads * (self.nope_dim + self.rope_dim), name="q_b_proj", **proj)
-        self.kv_a_proj = nn.Dense(self.kv_rank + self.rope_dim, name="kv_a_proj", **proj)
-        self.kv_a_norm = RMSNorm(name="kv_a_norm", **norm)
-        self.kv_b_proj = nn.Dense(
-            self.heads * (self.nope_dim + self.v_dim), name="kv_b_proj", **proj)
-        self.o_proj = nn.Dense(self.hidden, name="o_proj", **proj)
-        self.scale = (self.nope_dim + self.rope_dim) ** -0.5
-
-    @nn.nowrap
-    @jax.named_scope("mla_down")
-    def _latents(self, u):
-        """The normed query latent, the normed key/value latent and the shared
-        key before its rotation."""
-        c_kv, k_rope = jnp.split(self.kv_a_proj(u), [self.kv_rank], axis=-1)
-        return self.q_a_norm(self.q_a_proj(u)), self.kv_a_norm(c_kv), k_rope
-
-    @nn.nowrap
-    def _queries(self, c_q, pos):
-        """Each head's unrotated and rotated query parts ``(..., heads, .)``."""
-        with jax.named_scope("mla_up"):
-            q = self.q_b_proj(c_q).reshape(*c_q.shape[:-1], self.heads, -1)
-            q_nope, q_rope = jnp.split(q, [self.nope_dim], axis=-1)
-        return q_nope, rope(q_rope, pos, self.rope_theta)
-
-    def __call__(self, u, seg):
-        B, T, _ = u.shape
-        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        c_q, c_kv, k_rope = self._latents(u)
-        q_nope, q_rope = self._queries(c_q, pos)
-        k_rope = rope(k_rope[:, :, None, :], pos, self.rope_theta)  # one head: every head's
-        with jax.named_scope("mla_up"):
-            kv = self.kv_b_proj(c_kv).reshape(B, T, self.heads, -1)
-            k_nope, v = jnp.split(kv, [self.nope_dim], axis=-1)
-            q = jnp.concatenate([q_nope, q_rope], axis=-1)
-            k = jnp.concatenate(
-                [k_nope, jnp.broadcast_to(k_rope, (B, T, self.heads, self.rope_dim))], axis=-1)
-        o = flash_attention_tpu(q, k, v, pos, seg, causal=True, sm_scale=self.scale)
-        with jax.named_scope("mla_o"):
-            return self.o_proj(o.reshape(B, T, -1))
-
-    def step(self, u, ring, count):
-        """One acting step over a latent ring of ``ctx`` slots (B, ctx,
-        kv_rank + rope_dim); ``count`` (B,) int: steps of this episode already
-        stored. The shared key is stored as rotated at its own step: a score
-        reads only the difference to the query's."""
-        B, ctx = ring.shape[:2]
-        cd = self.dtype or jnp.float32
-        c_q, c_kv, k_rope = self._latents(u)
-        q_nope, q_rope = self._queries(c_q, count)
-        k_rope = rope(k_rope[:, None, :], count, self.rope_theta)[:, 0]
-        row = jnp.concatenate([c_kv, k_rope], axis=-1)
-        write = (jnp.arange(ctx)[None] == jnp.mod(count, ctx)[:, None])[:, :, None]
-        ring = jnp.where(write, row[:, None].astype(ring.dtype), ring)
-        latent, keys = jnp.split(ring.astype(cd), [self.kv_rank], axis=-1)
-        w_kv = self.kv_b_proj.variables["params"]["kernel"].astype(cd).reshape(
-            self.kv_rank, self.heads, -1)
-        w_uk, w_uv = jnp.split(w_kv, [self.nope_dim], axis=-1)
-        f32 = dict(preferred_element_type=jnp.float32)
-        absorbed = jnp.einsum("bhn,rhn->bhr", q_nope, w_uk, **f32).astype(cd)
-        scores = (
-            jnp.einsum("bhr,btr->bht", absorbed, latent, **f32)
-            + jnp.einsum("bhd,btd->bht", q_rope, keys, **f32)
-        ) * jnp.float32(self.scale)
-        valid = jnp.arange(ctx)[None] <= count[:, None]
-        w = jax.nn.softmax(jnp.where(valid[:, None], scores, -jnp.inf), axis=-1)
-        mixed = jnp.einsum("bht,btr->bhr", w.astype(cd), latent, **f32).astype(cd)
-        o = jnp.einsum("bhr,rhv->bhv", mixed, w_uv, **f32).astype(cd)
-        with jax.named_scope("mla_o"):
-            return self.o_proj(o.reshape(B, -1)), ring
 
 
 def build_mixer(a: dict, dtype=None, name: str | None = None) -> MLAttention:

@@ -1,7 +1,8 @@
 """What more than one catalog family builds its layers from, each with its
 widths and forms as fields so that a family's file states only its own
 arrangement: the norm, the seam-stopped convolution, rotary positions,
-grouped-query attention, the sparse-expert block (over ``ops/moe.py``) and
+grouped-query attention, multi-head latent attention, the sparse-expert block
+(over ``ops/moe.py``) and
 what an attention layer's mask did, counted from the segment ids. A module
 only one family uses stays in that family's file; Mamba-2 is ``mamba2.py``.
 """
@@ -193,6 +194,123 @@ class GQAttention(nn.Module):
         return self.o_proj(o.reshape(B, -1)), k_cache, v_cache
 
 
+class MLAttention(nn.Module):
+    """``__call__`` (training) runs the expanded form through
+    ``flash_attention_tpu``, ``step`` (acting) the absorbed form over the
+    latent ring. ``q_rank=None`` (``models/ling_flash.py``): the queries come
+    straight from the hidden state — one leaf ``q_proj``, no query latent and
+    no ``q_a_norm``. ``v_dim`` need not be ``nope_dim + rope_dim``: scores run
+    over the query/key size (their scale is its inverse root), outputs are
+    ``v_dim`` wide, and on a TPU ``flash_attention_tpu`` pads both with zero
+    features to one size the kernels take. ``head_gate``: one more leaf
+    ``g_proj`` (hidden -> heads), and each head's output is multiplied by
+    ``sigmoid`` of its scalar before ``o_proj``."""
+
+    hidden: int
+    heads: int
+    q_rank: int | None
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float
+    eps: float
+    dtype: Any = None
+    head_gate: bool = False
+
+    def setup(self):
+        proj = dict(use_bias=False, dtype=self.dtype)
+        norm = dict(eps=self.eps, dtype=self.dtype)
+        q_width = self.heads * (self.nope_dim + self.rope_dim)
+        if self.q_rank:
+            self.q_a_proj = nn.Dense(self.q_rank, name="q_a_proj", **proj)
+            self.q_a_norm = RMSNorm(name="q_a_norm", **norm)
+            self.q_b_proj = nn.Dense(q_width, name="q_b_proj", **proj)
+        else:
+            self.q_proj = nn.Dense(q_width, name="q_proj", **proj)
+        if self.head_gate:
+            self.g_proj = nn.Dense(self.heads, name="g_proj", **proj)
+        self.kv_a_proj = nn.Dense(self.kv_rank + self.rope_dim, name="kv_a_proj", **proj)
+        self.kv_a_norm = RMSNorm(name="kv_a_norm", **norm)
+        self.kv_b_proj = nn.Dense(
+            self.heads * (self.nope_dim + self.v_dim), name="kv_b_proj", **proj)
+        self.o_proj = nn.Dense(self.hidden, name="o_proj", **proj)
+        self.scale = (self.nope_dim + self.rope_dim) ** -0.5
+
+    @nn.nowrap
+    @jax.named_scope("mla_down")
+    def _latents(self, u):
+        """The normed query latent (the hidden state itself where the queries
+        have none), the normed key/value latent and the shared key before its
+        rotation."""
+        c_kv, k_rope = jnp.split(self.kv_a_proj(u), [self.kv_rank], axis=-1)
+        c_q = self.q_a_norm(self.q_a_proj(u)) if self.q_rank else u
+        return c_q, self.kv_a_norm(c_kv), k_rope
+
+    @nn.nowrap
+    def _queries(self, c_q, pos):
+        """Each head's unrotated and rotated query parts ``(..., heads, .)``."""
+        with jax.named_scope("mla_up"):
+            up = self.q_b_proj if self.q_rank else self.q_proj
+            q = up(c_q).reshape(*c_q.shape[:-1], self.heads, -1)
+            q_nope, q_rope = jnp.split(q, [self.nope_dim], axis=-1)
+        return q_nope, rope(q_rope, pos, self.rope_theta)
+
+    @nn.nowrap
+    def _gated(self, o, u):
+        """``o`` (..., heads, v_dim) under each head's gate, where there is one."""
+        if not self.head_gate:
+            return o
+        gate = jax.nn.sigmoid(self.g_proj(u).astype(jnp.float32))
+        return o * gate[..., None].astype(o.dtype)
+
+    def __call__(self, u, seg):
+        B, T, _ = u.shape
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        c_q, c_kv, k_rope = self._latents(u)
+        q_nope, q_rope = self._queries(c_q, pos)
+        k_rope = rope(k_rope[:, :, None, :], pos, self.rope_theta)  # one head: every head's
+        with jax.named_scope("mla_up"):
+            kv = self.kv_b_proj(c_kv).reshape(B, T, self.heads, -1)
+            k_nope, v = jnp.split(kv, [self.nope_dim], axis=-1)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (B, T, self.heads, self.rope_dim))], axis=-1)
+        o = flash_attention_tpu(q, k, v, pos, seg, causal=True, sm_scale=self.scale)
+        with jax.named_scope("mla_o"):
+            return self.o_proj(self._gated(o, u).reshape(B, T, -1))
+
+    def step(self, u, ring, count):
+        """One acting step over a latent ring of ``ctx`` slots (B, ctx,
+        kv_rank + rope_dim); ``count`` (B,) int: steps of this episode already
+        stored. The shared key is stored as rotated at its own step: a score
+        reads only the difference to the query's."""
+        B, ctx = ring.shape[:2]
+        cd = self.dtype or jnp.float32
+        c_q, c_kv, k_rope = self._latents(u)
+        q_nope, q_rope = self._queries(c_q, count)
+        k_rope = rope(k_rope[:, None, :], count, self.rope_theta)[:, 0]
+        row = jnp.concatenate([c_kv, k_rope], axis=-1)
+        write = (jnp.arange(ctx)[None] == jnp.mod(count, ctx)[:, None])[:, :, None]
+        ring = jnp.where(write, row[:, None].astype(ring.dtype), ring)
+        latent, keys = jnp.split(ring.astype(cd), [self.kv_rank], axis=-1)
+        w_kv = self.kv_b_proj.variables["params"]["kernel"].astype(cd).reshape(
+            self.kv_rank, self.heads, -1)
+        w_uk, w_uv = jnp.split(w_kv, [self.nope_dim], axis=-1)
+        f32 = dict(preferred_element_type=jnp.float32)
+        absorbed = jnp.einsum("bhn,rhn->bhr", q_nope, w_uk, **f32).astype(cd)
+        scores = (
+            jnp.einsum("bhr,btr->bht", absorbed, latent, **f32)
+            + jnp.einsum("bhd,btd->bht", q_rope, keys, **f32)
+        ) * jnp.float32(self.scale)
+        valid = jnp.arange(ctx)[None] <= count[:, None]
+        w = jax.nn.softmax(jnp.where(valid[:, None], scores, -jnp.inf), axis=-1)
+        mixed = jnp.einsum("bht,btr->bhr", w.astype(cd), latent, **f32).astype(cd)
+        o = jnp.einsum("bhr,rhv->bhv", mixed, w_uv, **f32).astype(cd)
+        with jax.named_scope("mla_o"):
+            return self.o_proj(self._gated(o, u).reshape(B, -1)), ring
+
+
 def kept_pairs(seg, window: int | None):
     """Query-key pairs the mask of one attention layer keeps over a batch of
     windows, from ``seg`` (B, T) alone: a query sees the steps of its episode
@@ -266,6 +384,8 @@ class ExpertBlock(nn.Module):
     form: str = "relu2"
     score: str = "sigmoid"
     shared_gated: bool = False
+    n_group: int = 1
+    topk_group: int = 1
 
     def setup(self):
         self.router = self.param(
@@ -289,8 +409,11 @@ class ExpertBlock(nn.Module):
                 self.shared_weight = nn.Dense(1, name="shared_weight", **dense)
 
     def _route(self, rows):
+        """The chosen experts, their weights and (a group-limited router) the
+        kept groups, else None."""
         return moe.route(
-            rows, self.router, self.router_bias, self.top_k, self.scale, self.score)
+            rows, self.router, self.router_bias, self.top_k, self.scale, self.score,
+            self.n_group, self.topk_group, with_groups=True)
 
     def _add_shared(self, u, routed):
         """The block's output for ``u`` from its rows' routed part."""
@@ -311,20 +434,22 @@ class ExpertBlock(nn.Module):
         reads ``scored`` (B, T, d) where the model routes on another state
         than the experts compute on."""
         rows = u.reshape(-1, self.hidden)
-        choice, weight = self._route(rows if scored is None else scored.reshape(rows.shape))
+        choice, weight, kept = self._route(
+            rows if scored is None else scored.reshape(rows.shape))
         chunk = moe.chunk_rows(rows.shape[0], self.top_k, self.held, self.n_experts)
         routed = moe.routed_experts(
             rows, choice, weight, self.w_in, self.w_out, self.first, self.dtype, chunk=chunk,
             w_gate=self.w_gate, form=self.form)
         route = {
             "choice": choice.reshape(*u.shape[:-1], self.top_k),
-            "stats": moe.route_stats(choice, self.first, self.held, chunk),
+            "stats": moe.route_stats(
+                choice, self.first, self.held, chunk, kept, self.n_experts // self.n_group),
         }
         return self._add_shared(u, routed), route
 
     def step(self, u, scored=None):
         """One acting step: ``u`` (B, d)."""
-        choice, weight = self._route(u if scored is None else scored)
+        choice, weight, _ = self._route(u if scored is None else scored)
         routed = moe.routed_experts_dense(
             u, choice, weight, self.w_in, self.w_out, self.first, self.dtype, self.w_gate,
             self.form)

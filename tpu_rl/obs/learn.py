@@ -115,13 +115,24 @@ def route_scalars(routes: list) -> dict[str, jax.Array]:
     expert, of the mean one, their ratio, the share of assignments that fell
     on held experts, the share of tokens with no held expert and the trips of
     the block's walk over its held rows (``moe-chunks``: 1.0 when every layer
-    took one). Empty for a family without expert layers, whose records (if
+    took one); under a group-limited router ``moe-group-hit-share``, the share
+    of tokens whose kept groups hold a held expert; for a family with
+    per-channel delta-rule layers ``kda-decay-floor-share``, the share of
+    (step, channel) gates within 1% of their bound, averaged over those layers
+    (a health gauge). Empty for a family without expert layers, whose records (if
     its layers hand any back) hold counters alone."""
     stats = [r["stats"] for r in routes if "stats" in r]
     if not stats:
         return {}
     mean = lambda k: sum(s[k] for s in stats) / len(stats)  # noqa: E731
+    more = {}
+    if "group-hit-share" in stats[0]:  # a group-limited router (ops/moe.route)
+        more["moe-group-hit-share"] = mean("group-hit-share")
+    floor = [r["kda-decay-floor-share"]["kda"] for r in routes if "kda-decay-floor-share" in r]
+    if floor:  # models/ling_flash.py: each layer's share over the count of such layers
+        more["kda-decay-floor-share"] = sum(floor)
     return {
+        **more,
         "moe-rows": sum(s["rows"] for s in stats),
         "moe-rows-max": mean("rows-max"),
         "moe-rows-mean": mean("rows-mean"),

@@ -49,6 +49,12 @@ are ``dtype`` with float32 accumulation, as ``models/mamba2._ssd_jnp``
 does for Mamba-2. A seam is a mask on every decay factor (never ``-inf``
 inside a cumulative sum). The backward is JAX's transpose of this program;
 the kernels' is their own (``jax.custom_vjp``), at the same precision.
+
+``ops/kda.py`` is the sibling whose decay is a vector (one factor a key
+*channel*, Kimi Delta Attention). It borrows ``l2norm``, ``_decay``,
+``_unit_lower_inverse`` and the walk over spans (``_chunked_jnp`` with its own
+``span_fn``) from here; with a decay that is constant over the channels its
+chunked form is this file's, line for line.
 """
 
 from __future__ import annotations
@@ -195,10 +201,13 @@ def gated_delta_chunked(q, k, v, g, beta, seg, state0, chunk: int, dtype=None, k
     return o[:, :T], last
 
 
-def _chunked_jnp(q, k, v, g, beta, seg, state0, chunk: int, dtype):
+def _chunked_jnp(q, k, v, g, beta, seg, state0, chunk: int, dtype, span_fn=None):
     """``gated_delta_chunked`` as ``einsum``s and ``lax.scan``s: the CPU's path
     and the kernels' oracle. The window is walked in spans of ``SPAN_CHUNKS``
-    chunks (``lax.scan``), each span rematerialised in the backward pass."""
+    chunks (``lax.scan``), each span rematerialised in the backward pass.
+    ``span_fn``: another rule's span over the same walk (``ops/kda.py``, whose
+    ``g`` has one more axis); this rule's ``_span`` by default."""
+    span_fn = span_fn or _span
     b, T = q.shape[:2]
     span = chunk * min(SPAN_CHUNKS, -(-T // chunk))
     pad = (-T) % span
@@ -215,7 +224,7 @@ def _chunked_jnp(q, k, v, g, beta, seg, state0, chunk: int, dtype):
     def one_span(carry, xs):
         state, seg_before = carry
         q, k, v, g, beta, seg = xs
-        o, state = _span(q, k, v, g, beta, seg, seg_before, state, chunk, dtype)
+        o, state = span_fn(q, k, v, g, beta, seg, seg_before, state, chunk, dtype)
         return (state, seg[:, -1]), o
 
     (last, _), o = jax.lax.scan(

@@ -31,6 +31,11 @@ state the caller hands over):
                  softmax over the chosen logits (= the softmax over all,
                  renormalised over the chosen); no bias, no scale
 
+The sigmoid router may be *group-limited* (``n_group``, ``topk_group``): the
+experts lie in ``n_group`` groups of consecutive ids, a group's score is the
+sum of its two largest ``s + b``, only the ``topk_group`` best groups' experts
+can be chosen, and the weights are formed as above (``kept_groups``).
+
 The discrete choice carries no gradient, the chosen scores do.
 
 Dispatch (``routed_experts``), with static shapes and without dropping a token
@@ -107,24 +112,54 @@ WALK_ROWS = 16_384
 
 
 # ------------------------------------------------------------------ the router
+def kept_groups(biased, n_group: int, topk_group: int):
+    """The group stage of the group-limited router (DeepSeek-V3,
+    arXiv:2412.19437, as the Ling family runs it): ``biased`` (N, E) the
+    scores the choice reads, in ``n_group`` groups of consecutive experts; a
+    group's score is the sum of its two largest; the ``topk_group`` best groups
+    are kept. Returns their ids (N, topk_group) int32."""
+    groups = biased.reshape(biased.shape[0], n_group, -1)
+    best_two, _ = jax.lax.top_k(groups, min(2, groups.shape[-1]))
+    _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    return kept.astype(jnp.int32)
+
+
 @jax.named_scope("moe_route")
-def route(u, kernel, bias, top_k: int, scale: float, score: str = "sigmoid"):
+def route(u, kernel, bias, top_k: int, scale: float, score: str = "sigmoid",
+          n_group: int = 1, topk_group: int = 1, with_groups: bool = False):
     """``u`` (N, d); ``kernel`` (d, E); ``bias`` (E,). Returns the chosen
     experts (N, top_k) int32 and their weights (N, top_k) float32.
-    ``score="softmax"`` reads neither ``bias`` nor ``scale``."""
+    ``score="softmax"`` reads neither ``bias`` nor ``scale``. With ``n_group``
+    > 1 (sigmoid only) the choice is group-limited: of the experts' ``n_group``
+    groups the ``topk_group`` best are kept (``kept_groups``, on ``s + b``) and
+    the ``top_k`` largest ``s + b`` are taken among their experts alone; the
+    weights are the chosen experts' unbiased scores as before. ``with_groups``
+    hands the kept groups' ids (N, topk_group) back as a third value. One
+    group (the default) is the plain choice and lowers to the program it
+    always did."""
     logits = jnp.dot(
         u.astype(jnp.float32), kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
     if score == "softmax":
+        assert n_group == 1, "the softmax router has no group stage"
         _, choice = jax.lax.top_k(jax.lax.stop_gradient(logits), top_k)
         chosen = jnp.take_along_axis(logits, choice, axis=-1)
-        return choice.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
+        out = choice.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
+        return (*out, None) if with_groups else out
     s = jax.nn.sigmoid(logits)
-    _, choice = jax.lax.top_k(jax.lax.stop_gradient(s + bias), top_k)
+    biased = jax.lax.stop_gradient(s + bias)
+    kept = None
+    if n_group > 1:
+        kept = kept_groups(biased, n_group, topk_group)
+        group_of = jnp.arange(biased.shape[-1], dtype=jnp.int32) // (biased.shape[-1] // n_group)
+        survives = jnp.any(group_of[None, :, None] == kept[:, None, :], axis=-1)
+        biased = jnp.where(survives, biased, -jnp.inf)
+    _, choice = jax.lax.top_k(biased, top_k)
     chosen = jnp.take_along_axis(s, choice, axis=-1)
     chosen = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
-    return choice.astype(jnp.int32), scale * chosen
+    out = choice.astype(jnp.int32), scale * chosen
+    return (*out, kept) if with_groups else out
 
 
 def chunk_rows(n: int, k: int, held: int, n_experts: int) -> int:
@@ -136,18 +171,21 @@ def chunk_rows(n: int, k: int, held: int, n_experts: int) -> int:
     return min(ROW_TILE * max(1, min(tiles, -(-n * k // ROW_TILE))), WALK_ROWS)
 
 
-def route_stats(choice, first: int, held: int, chunk: int) -> dict:
+def route_stats(choice, first: int, held: int, chunk: int, kept=None, group_size: int = 0) -> dict:
     """Counters of one block's routing, as float32 scalars (in-jit, no
     gradient): rows computed, rows of the fullest held expert and of the mean
     one, the share of assignments on held experts, the share of tokens with
-    none, and the trips the walk takes at ``chunk`` rows each."""
+    none, and the trips the walk takes at ``chunk`` rows each. Under a
+    group-limited router (``kept`` (N, topk_group): ``route``'s kept groups of
+    ``group_size`` experts each) also ``group-hit-share``: the share of tokens
+    whose kept groups hold a held expert — no other token can send a row here."""
     local = choice - first
     mine = (local >= 0) & (local < held)
     counts = jnp.sum(
         (local[..., None] == jnp.arange(held)) & mine[..., None], axis=(0, 1)
     ).astype(jnp.float32)
     rows = jnp.sum(counts)
-    return {
+    stats = {
         "rows": rows,
         "rows-max": jnp.max(counts),
         "rows-mean": rows / held,
@@ -155,6 +193,11 @@ def route_stats(choice, first: int, held: int, chunk: int) -> dict:
         "no-held-share": jnp.mean(1.0 - jnp.any(mine, axis=-1).astype(jnp.float32)),
         "chunks": jnp.ceil(rows / chunk),
     }
+    if kept is not None:
+        lo, hi = first // group_size, (first + held - 1) // group_size
+        hit = jnp.any((kept >= lo) & (kept <= hi), axis=-1)
+        stats["group-hit-share"] = jnp.mean(hit.astype(jnp.float32))
+    return stats
 
 
 # ------------------------------------------------------- the grouped products

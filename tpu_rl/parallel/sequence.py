@@ -997,7 +997,10 @@ def flash_attention_tpu(
     and single-pass backward under a custom VJP; see
     :func:`_splash_mha`). Same contract as :func:`full_attention`, and
     ``k``, ``v`` may carry fewer heads than ``q`` (grouped-query attention:
-    ``H % Hkv == 0``, unrepeated).
+    ``H % Hkv == 0``, unrepeated), and ``v``'s head size may differ from q's and
+    k's (latent attention at 192 : 128): the kernels then run on zero-padded
+    heads of one size, 256 there (:func:`full_attention` takes the two sizes
+    as they are).
 
     Masking equivalence: the kernel masks causally by global index plus
     same-segment — identical to our ``q_pos >= k_pos`` + same-segment mask
@@ -1039,12 +1042,21 @@ def flash_attention_tpu(
             q, k, v, q_pos, seg, causal=causal, sm_scale=sm_scale, window=window
         )
     scale = float(1.0 / np.sqrt(q.shape[-1]) if sm_scale is None else sm_scale)
+    d_v = v.shape[-1]
+    padded = q.shape[-1] != d_v
+    if padded:
+        # the kernels (the library's forward, ops/pallas_attn_bwd.py) take one head size:
+        # q, k and v are padded with zero features to the next lane multiple that holds
+        # both — no score and no output changes, the padding's gradients are dropped
+        width = -(-max(q.shape[-1], d_v) // 128) * 128
+        q, k, v = (jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),)) for x in (q, k, v))
 
     @jax.named_scope("attn_flash_pallas")
     def kernel(q, k, v, seg):
-        return _splash_mha(
+        o = _splash_mha(
             q, k, v, seg, causal=causal, scale=scale, block_sizes=bs, window=window
         )
+        return o[..., :d_v] if padded else o
 
     if mesh is None:
         return kernel(q, k, v, seg)

@@ -45,6 +45,8 @@ _CACHE_DIR = os.path.join(
 # models/lfm2_moe.py: ``shortconv`` around the gated short convolution,
 # models/evabyte.py: ``eva`` around the EVA mixer and ``eva_pool`` around its
 # chunk pooling,
+# models/ling_flash.py: ``kda`` around the per-channel delta-rule mixer and
+# ops/kda.py's ``kda_scan`` around its chunked scan,
 # ops/pallas_act.py, parallel/sequence.py: ``attn_bwd_pallas`` inside
 # ``attn_flash_pallas`` where the backward is ops/pallas_attn_bwd.py's walk
 # over the band's tiles), so one lowered
@@ -53,6 +55,7 @@ _MOSAIC_TARGET = "tpu_custom_call"
 _PATH_SCOPES = re.compile(
     r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_bwd_pallas|attn_full|attn_window"
     r"|attn_global|attn_rope|mla|shortconv|eva|eva_pool|ssd_scan|ssd_pallas|gdn_scan|gdn_pallas|moe_experts"
+    r"|kda|kda_scan"
     r"|moe_gmm_pallas|moe_row_add_pallas)\b"
 )
 
