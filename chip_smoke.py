@@ -537,7 +537,8 @@ def kernel_checks(
     # "kda-bound" / "kda-one-episode": the per-channel delta rule's chunked scan
     # (ops/kda.py) alone at 32 heads of 128, forward and every gradient against the step
     # recurrence (``kda_step`` under a scan whose 64-step blocks are rematerialised; the
-    # first row timed, both forms) — with ~4 seams a window and two in one chunk, with every gate at the bound of
+    # first row timed: the Pallas pair the gate takes on the chip as ``ms``, the recurrence
+    # as ``ms_ref``, the jax.numpy body as ``ms_jnp``) — with ~4 seams a window and two in one chunk, with every gate at the bound of
     # -5 for the whole window (the sub-blocks' operands at e^75), and with the whole row one
     # episode. "mla": latent attention without a query latent at 192-wide queries and
     # 128-wide values (the kernels on heads padded to 256), forward and every gradient
@@ -574,7 +575,9 @@ def kernel_checks(
             times.append(time.perf_counter() - t0)
         return round(1e3 * min(times), 3)
 
-    def case(name, fn, ref, args, tol, tol_same, mosaic=True, timed=False, ref_is_kernel=False):
+    def case(name, fn, ref, args, tol, tol_same, mosaic=True, timed=False, ref_is_kernel=False,
+             timed_too=None):
+        """``timed_too``: {name: another form of ``fn``}, each timed as ``ms_<name>``."""
         row = {"kernel": name, "tol": tol, "tol_vs_default": tol_same}
         t0 = time.time()
         try:
@@ -592,6 +595,8 @@ def kernel_checks(
             )
             if timed:  # host clock over a device round trip: the two beside each other
                 row.update(ms=best_ms(jfn, args), ms_ref=best_ms(jax.jit(ref), args))
+                for also, form in (timed_too or {}).items():
+                    row[f"ms_{also}"] = best_ms(jax.jit(form), args)
             row["ok"] = (
                 row["err"] <= tol
                 and row["err_vs_default"] <= tol_same
@@ -1297,8 +1302,11 @@ def kernel_checks(
             beta, state0 = jax.nn.sigmoid(f32(B, T, H)), f32(B, H, D, D) * D**-0.5
             w_o, w_last = f32(B, T, H, D), f32(B, H, D, D)
 
-            def chunked(q, k, v, g, beta, state0):
-                return kda.kda_chunked(q, k, v, g, beta, seg, state0, Q, jnp.bfloat16)
+            def chunked(q, k, v, g, beta, state0, kernel=(H, True) if interpret else None):
+                """The Pallas pair where the gate takes it (on the chip; here the
+                interpreter's), or with ``kernel=(None, False)`` the jax.numpy body."""
+                return kda.kda_chunked(
+                    q, k, v, g, beta, seg, state0, Q, jnp.bfloat16, kernel=kernel)
 
             def stepped(q, k, v, g, beta, state0):
                 """``kda_step`` over the window in rematerialised blocks of ``Q`` steps."""
@@ -1326,7 +1334,8 @@ def kernel_checks(
                 f"kda fwd+bwd B{B}/T{T}/H{H}x{D}/Q{Q} bf16 ({row[4:]}: {int(firsts.sum())} seams, "
                 f"mean decay a step {float(jnp.exp(g).mean()):.3f}) vs the step recurrence",
                 graded(chunked), graded(stepped), (q, k, v, g, beta, state0), TOL_BF16, TOL_BF16,
-                mosaic=False, timed=row == "kda-seams",
+                timed=row == "kda-seams",
+                timed_too={"jnp": graded(functools.partial(chunked, kernel=(None, False)))},
             )
         elif row == "mla":
             mixer = build_ling(lw, "mla", jnp.bfloat16).clone(name=None)

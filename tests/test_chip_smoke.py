@@ -98,7 +98,7 @@ def test_kernel_checks_pass_tiny_interpreted():
         interpret=True,
     )
     assert len(rows) == 38
-    # ling_flash's five rows come last: the per-channel scan three times (timed), latent
+    # ling_flash's five rows come last: the per-channel scan's Pallas pair three times, latent
     # attention at 24-wide queries and 16-wide values, the group-limited choice (exact)
     route, mla, *scans = [rows.pop() for _ in range(5)]
     assert route["kernel"].startswith("ling_flash group-limited route N256/E32/k6/groups 3 of 8")
@@ -108,7 +108,8 @@ def test_kernel_checks_pass_tiny_interpreted():
     for scan, what in zip(scans, ("one-episode: 0 seams", "bound: ", "seams: ")):
         assert scan["kernel"].startswith("kda fwd+bwd B2/T64/H4x16/Q16 bf16 (" + what), scan
         assert scan["ok"] and 0 < scan["err"] <= scan["tol"], scan
-    assert scans[2]["ms"] > 0 and scans[2]["ms_ref"] > 0 and "ms" not in scans[0]
+    # the first row timed: the Pallas pair (here the interpreter's), the recurrence, the body
+    assert all(scans[2][k] > 0 for k in ("ms", "ms_ref", "ms_jnp")) and "ms" not in scans[0]
     assert "mean decay a step 0.007" in scans[1]["kernel"]  # e^-5
     # evabyte's four rows come last: the mixer, the pooling alone (timed), the acting
     # form, the pooling on a window with more seams and several absent candidates

@@ -247,3 +247,25 @@ def test_a_lowered_walk_names_the_row_add_kernel_where_the_gate_took_it(monkeypa
         jnp.zeros((n, width)), jnp.ones((n, k)), jnp.zeros((held, width, f)),
         jnp.zeros((held, f, width)))
     assert ("moe_row_add_pallas" in program_paths(lowered)["paths"]) is found
+
+
+def test_a_file_named_after_a_scope_is_no_path():
+    """``program_paths`` reads name stacks: ``ops/kda.py`` among a module's
+    file locations (a cached trace of a jnp helper keeps the call site it was
+    first traced from, in whatever program lowers it next) is not the scope
+    ``kda``."""
+    import types
+
+    from tpu_rl.utils.platform import program_paths
+
+    text = '\n'.join([
+        '%0 = stablehlo.add %a, %b : tensor<f32> loc(#loc3)',
+        '#loc1 = loc("/root/repo/tpu_rl/ops/kda.py":136:15 to :50)',
+        '#loc2 = loc("/root/repo/tpu_rl/ops/pallas_kda.py":552:11 to 553:44)',
+        '#loc3 = loc("jit(step)/lstm_pallas/add"(#loc1))',
+    ])
+    module = types.SimpleNamespace(as_text=lambda debug_info=False: text)
+    assert program_paths(module) == {"paths": ["lstm_pallas"], "mosaic_calls": 0}
+    scoped = text.replace("lstm_pallas", "kda/kda_scan/kda_pallas")
+    module = types.SimpleNamespace(as_text=lambda debug_info=False: scoped)
+    assert program_paths(module)["paths"] == ["kda", "kda_pallas", "kda_scan"]

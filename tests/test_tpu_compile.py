@@ -135,6 +135,64 @@ def test_the_two_resolution_read_compiles_at_evabytes_shapes(one_chip, monkeypat
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
+def test_the_per_channel_delta_rules_pair_compiles_at_lings_shapes(one_chip, monkeypatch):
+    """``kda_chunked`` at ling-3.0-flash-vl's widths (1 x 8,192 steps, 32
+    heads of 128, bf16, chunks of 64), value and all six gradients, for a v5e,
+    the program's platform and the chip's own VMEM reading steered as the
+    cell's compile test steers them: the gate takes the Pallas pair
+    (``ops/pallas_kda.py``) eight heads a grid step, the program holds its two
+    Mosaic calls (the differentiated forward, the backward) under the scopes
+    the cell's readers use and no ``while`` (the jax.numpy body's walk over
+    spans), and the kernels' blocks, scratch and spills fit the call when it
+    asks for no more than the gate counted (``_vmem_bytes`` at float32
+    operands, 51.2 MiB; Mosaic's own allocation with bf16 ones is 22.60)."""
+    import types
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_rl.models import cells
+    from tpu_rl.ops import kda, pallas_kda
+    from tpu_rl.utils.platform import program_paths
+
+    monkeypatch.setattr(cells, "_program_devices", lambda: ("tpu", 1))
+    monkeypatch.setattr(
+        pltpu, "get_tpu_info", lambda: types.SimpleNamespace(vmem_capacity_bytes=128 * 2**20))
+    B, T, H, D, Q = 1, 8192, 32, 128, 64
+    assert kda._kernel_block(B, H, D, D, Q) == (8, False)
+    need = pallas_kda._vmem_bytes(8, D, D, Q)
+    assert need <= pallas_kda._vmem_limit()
+    monkeypatch.setattr(pallas_kda, "_vmem_limit", lambda: need)  # what the calls ask for
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def graded(q, k, v, g, beta, state0, seg):
+        def loss(q, k, v, g, beta, state0):
+            o, last = kda.kda_chunked(q, k, v, g, beta, seg, state0, Q, jnp.bfloat16)
+            return jnp.square(o).sum() + last.sum()
+        return jax.value_and_grad(loss, argnums=tuple(range(6)))(q, k, v, g, beta, state0)
+
+    step = shaped((B, T, H, D), jnp.bfloat16)
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        lowered = jax.jit(graded).lower(
+            step, step, step, shaped((B, T, H, D), jnp.float32), shaped((B, T, H), jnp.float32),
+            shaped((B, H, D, D), jnp.float32), shaped((B, T), jnp.int32))
+        paths = program_paths(lowered)
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+    assert {"kda_scan", "kda_pallas"} <= set(paths["paths"]) and paths["mosaic_calls"] == 2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "kdelta_fwd_res" in text and "kdelta_bwd" in text
+    assert " while(" not in text
+    # the residuals (a state every fourth chunk and each chunk's A, 64 MiB each), o, the
+    # gradients and the re-laid small operands: no (b, nc, h, Q, d_k) float32 array of the
+    # jax.numpy spans (256 MiB each)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.75 * 2**30
+
+
 def test_the_chunk_poolings_backward_holds_no_scatter_at_evabytes_shapes(one_chip):
     """An EVA layer's chunk pooling (``EvaAttention.summaries`` under the
     mixer's scope ``eva_pool``) at 32 heads of 128 over 16,384 steps in bf16,

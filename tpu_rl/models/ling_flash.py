@@ -31,7 +31,9 @@ Linear mixer (``KimiDeltaAttention``, scope ``kda``), no bias anywhere:
     a = W_a u + dt_bias                        one number a head and key channel (full rank)
     g = kda_lower_bound * sigmoid(exp(A_log_h) a)        kda_gate: the log decay, in (bound, 0)
     [b, z] = W_bz u;  beta = sigmoid(b)        one write strength and one output gate a head
-    o = KDA(l2norm(q) d_k^-1/2, l2norm(k), v, g, beta)   kda_scan (``ops/kda.py``)
+    o = KDA(l2norm(q) d_k^-1/2, l2norm(k), v, g, beta)   kda_scan (``ops/kda.py``: on a TPU
+                                               at lane-multiple widths its Pallas pair,
+                                               ``kda_pallas``; the jax.numpy body elsewhere)
     KDA(u) = W_o [sigmoid(z_h) * RMSNorm(o_h) w_n]_h     kda_out: the norm over each head's
                                                features, one weight vector for all heads
 
@@ -87,7 +89,10 @@ def layer_kinds(arch: dict) -> list[tuple[str, bool]]:
 
 
 class KimiDeltaAttention(nn.Module):
-    """``__call__`` (training) runs the chunked rule, ``step`` (acting) the
+    """``__call__`` (training) runs the chunked rule — ``ops/kda.kda_chunked``
+    chooses its form by what it can observe: one Pallas kernel per pass
+    (``ops/pallas_kda.py``) on a TPU at key and value sizes that are lane
+    multiples, the ``jax.numpy`` body everywhere else — ``step`` (acting) the
     one-step rule."""
 
     hidden: int

@@ -47,18 +47,31 @@ and so is the diagonal itself: a step's pair with itself carries no decay and
 Decays, cumulative sums, the inverse and the state are float32; the operands of
 every other product are ``dtype`` with float32 accumulation, as
 ``gated_delta``'s. A seam is a mask on every factor, never ``-inf`` inside a
-cumulative sum. The window is walked in ``gated_delta``'s spans
+cumulative sum.
+
+Two forms compute it, and ``kda_chunked`` chooses by what it can observe
+(``_kernel_block``, as ``gated_delta._kernel_block``: ``cells.set_pallas_mode``,
+the platform of the program being traced, whether the batch tiles a registered
+data mesh, lane multiples, VMEM): on a TPU at the published widths one Pallas
+kernel per pass (``ops/pallas_kda.py``, scope ``kda_pallas`` inside
+``kda_scan``; under a data mesh a ``shard_map`` island) — a file of its own,
+because the per-step factors ``ops/pallas_gdn.py`` carries as ``(chunk, 1)``
+columns are ``(chunk, d_k)`` tiles here — and everywhere else — the CPU, the
+tests' small widths, init and act traces — the ``jax.numpy`` body below, which
+is also the kernels' oracle: the window walked in ``gated_delta``'s spans
 (``_chunked_jnp`` with this file's ``_span``), each rematerialised in the
-backward pass, which is JAX's transpose of this program. There is no Pallas
-form yet: ``ops/pallas_gdn.py`` carries a chunk's per-step factors as ``(chunk,
-1)`` columns.
+backward pass, which is JAX's transpose of this program; the kernels' is their
+own (``jax.custom_vjp``), at the same precision.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from tpu_rl.ops import pallas_kda
 from tpu_rl.ops.gated_delta import _HIGHEST, _chunked_jnp, _decay, _unit_lower_inverse, l2norm
 
 # Steps of a sub-block: the pairs inside one are computed against its first
@@ -80,16 +93,74 @@ def kda_step(q, k, v, g, beta, state):
     return jnp.einsum("bhdv,bhd->bhv", state, q, precision=_HIGHEST), state
 
 
+def _kernel_block(b: int, h: int, dk: int, dv: int, Q: int) -> tuple[int | None, bool]:
+    """(heads per grid step of the Pallas pair, interpret), or (None, False)
+    for the ``jax.numpy`` body: ``gated_delta._kernel_block``'s gate on this
+    rule's kernels. The CPU, sizes that are no lane multiples and a batch that
+    does not tile a registered data mesh (init and act traces: a Mosaic call
+    has no SPMD rule outside its island) keep the ``jax.numpy`` form."""
+    from tpu_rl.models import cells
+
+    mode = cells._PALLAS_MODE
+    if mode == "off":
+        return None, False
+    hb = pallas_kda.head_block(h, dk, dv, Q, min(SUB, Q))
+    if mode == "interpret":  # any width: every head at once where no block tiles
+        return hb or h, True
+    platform, n_data = cells._program_devices()
+    if platform != "tpu" or b % n_data:
+        return None, False
+    return hb, False
+
+
+def _kernels(q, k, v, g, beta, seg, state0, chunk, dtype, hb, interpret):
+    """The Pallas pair (``ops/pallas_kda.py``); under a registered data mesh
+    whose width the batch tiles, as a ``shard_map`` island over the ``"data"``
+    axis (``gated_delta._kernels``)."""
+    from tpu_rl.models import cells
+
+    sub = min(SUB, chunk)
+    assert chunk % sub == 0, f"a chunk of {chunk} steps is no whole number of sub-blocks of {sub}"
+    scan = functools.partial(
+        pallas_kda.delta_window, chunk=chunk, dtype=dtype, sub=sub, hb=hb, interpret=interpret)
+    mesh = cells._DATA_MESH
+    if mesh is not None and q.shape[0] % cells._program_devices()[1] == 0:
+        from jax.sharding import PartitionSpec as P
+
+        from tpu_rl.parallel.mesh import DATA_AXIS
+
+        rows = P(DATA_AXIS)  # every operand: its leading (batch) dim
+        # no collectives inside; pallas out_shapes carry no vma annotations
+        scan = jax.shard_map(
+            scan, mesh=mesh, in_specs=(rows,) * 7, out_specs=(rows, rows), check_vma=False)
+    with jax.named_scope("kda_pallas"):  # the backward's ops carry it too
+        return scan(q, k, v, g, beta, seg, state0)
+
+
 @jax.named_scope("kda_scan")
-def kda_chunked(q, k, v, g, beta, seg, state0, chunk: int, dtype=None):
+def kda_chunked(q, k, v, g, beta, seg, state0, chunk: int, dtype=None, kernel=None):
     """The rule over a whole window in matmul form.
 
     ``q``, ``k`` (b, T, h, d_k) as projected (normalised here); ``v``
     (b, T, h, d_v); ``g`` (b, T, h, d_k) float32, the log decay of every key
     channel (<= 0); ``beta`` (b, T, h) float32; ``seg`` (b, T) int, 0 = the
     episode ``state0`` (b, h, d_k, d_v) belongs to. Returns ``o``
-    (b, T, h, d_v) float32 and the state after the last step."""
-    return _chunked_jnp(q, k, v, g, beta, seg, state0, chunk, dtype, span_fn=_span)
+    (b, T, h, d_v) float32 and the state after the last step. ``kernel``:
+    ``(heads a grid step of the Pallas pair or None for the jax.numpy body,
+    interpret)`` where the caller and not the gate chooses (tests,
+    ``chip_smoke.py``)."""
+    b, T, h, dk = q.shape
+    hb, interpret = kernel or _kernel_block(b, h, dk, v.shape[-1], chunk)
+    if hb is None:
+        return _chunked_jnp(q, k, v, g, beta, seg, state0, chunk, dtype, span_fn=_span)
+    pad = (-T) % chunk
+    if pad:  # g = 0, beta = 0: the state passes through, nothing is written
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta)
+        )
+        seg = jnp.concatenate([seg, jnp.repeat(seg[:, -1:], pad, axis=1)], axis=1)
+    o, last = _kernels(q, k, v, g, beta, seg, state0, chunk, dtype, hb, interpret)
+    return o[:, :T], last
 
 
 def _pairs(left, kn, gamma, sub: int, cd):

@@ -46,16 +46,18 @@ _CACHE_DIR = os.path.join(
 # models/evabyte.py: ``eva`` around the EVA mixer and ``eva_pool`` around its
 # chunk pooling,
 # models/ling_flash.py: ``kda`` around the per-channel delta-rule mixer and
-# ops/kda.py's ``kda_scan`` around its chunked scan,
+# ops/kda.py's ``kda_scan`` around its chunked scan, ``kda_pallas`` inside it
+# when the scan took its kernels (ops/pallas_kda.py),
 # ops/pallas_act.py, parallel/sequence.py: ``attn_bwd_pallas`` inside
 # ``attn_flash_pallas`` where the backward is ops/pallas_attn_bwd.py's walk
 # over the band's tiles), so one lowered
 # module answers both "what did the gate choose" and "did Mosaic get it".
 _MOSAIC_TARGET = "tpu_custom_call"
+_FILE_LOCATION = re.compile(r'"[^"\n]*":\d+:\d+')  # loc("/path/file.py":12:3 to :40)
 _PATH_SCOPES = re.compile(
     r"\b(lstm_pallas|lstm_scan|act_pallas|attn_flash_pallas|attn_bwd_pallas|attn_full|attn_window"
     r"|attn_global|attn_rope|mla|shortconv|eva|eva_pool|ssd_scan|ssd_pallas|gdn_scan|gdn_pallas|moe_experts"
-    r"|kda|kda_scan"
+    r"|kda|kda_scan|kda_pallas"
     r"|moe_gmm_pallas|moe_row_add_pallas)\b"
 )
 
@@ -504,8 +506,13 @@ def program_paths(lowered) -> dict:
     """Which kernel/fallback paths a lowered jit program took (the named
     scopes around each dispatch) and how many Mosaic custom calls it holds."""
     text = lowered.as_text(debug_info=True)
+    # the scopes are read from the name stacks, not from the file locations beside
+    # them: ``ops/kda.py`` would read as the scope ``kda``, and a cached trace of a
+    # jnp helper keeps the call site it was first traced from, whatever program
+    # lowers it next
+    named = _FILE_LOCATION.sub("", text)
     return {
-        "paths": sorted(set(_PATH_SCOPES.findall(text))),
+        "paths": sorted(set(_PATH_SCOPES.findall(named))),
         "mosaic_calls": text.count(_MOSAIC_TARGET),
     }
 
